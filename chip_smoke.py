@@ -1,0 +1,752 @@
+"""The quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py              # one TPU chip: qwen2-7b, int8 weights
+    python chip_smoke.py --cpu-tiny   # rehearsal on the CPU, `tiny` preset
+
+Starts the three processes a user starts (README "Run it"): the control-
+plane store, the JAX worker and the OpenAI frontend with the KV router.
+Sends HTTP traffic (one streaming chat completion, eight concurrent ones
+with prompts of ~300 to ~2,000 byte-tokens, one prompt twice), checks what
+came back, asks the worker what it ran on, stops the children so the chip
+is free, and then compiles both Pallas attention kernels against their
+references in a fresh child. Any failed phase makes the exit code 1 and
+suppresses the result line. On success the last line of stdout is
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+This process never imports JAX: a parent that has touched JAX holds the
+chip, and the worker it starts would then fail or hang. Without
+``--cpu-tiny`` a missing TPU is a failure, not a fallback. Text is not
+checked: random weights over a 152k vocabulary produce ids the byte
+tokenizer drops.
+
+Builder's four-chip runs (not part of the driver's check):
+
+    python chip_smoke.py --tp4   # one worker, --tp 4, bf16 weights
+    python chip_smoke.py --pd    # --role prefill + --role decode, one chip each
+
+``--inject {worker-start,bad-request,kernel-mismatch}`` breaks one phase
+on purpose; tests use it to show that a broken phase fails the run.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import random
+import shutil
+import signal
+import socket
+import subprocess
+import sys
+import threading
+import time
+import urllib.error
+import urllib.request
+from pathlib import Path
+
+HERE = Path(__file__).resolve().parent
+OUT = HERE / "chip_smoke_out"
+MODEL = "smoke"
+MAX_TOKENS = 64
+T0 = time.monotonic()
+# Prompt sizes in bytes (= byte-tokenizer tokens): eight concurrent
+# requests, the streaming one, the one sent twice. The tiny preset's
+# context is 256 tokens, so its rehearsal scales them down.
+CHIP_TRAFFIC = ((300, 500, 700, 900, 1100, 1400, 1700, 2000), 400, 1200)
+TINY_TRAFFIC = ((20, 40, 60, 80, 100, 120, 140, 160), 50, 100)
+# The programs a served request runs; each must hold the Mosaic custom
+# call on a TPU (jit names as JAX writes them into its IR dump file names).
+SERVING_PROGRAMS = ("_prefill_and_sample", "_megastep_body")
+
+# Worker flags per mode: existing CLI flags only, sized as a deployment
+# would size them. One chip: int8 weights 8.17 GB + 3072 blocks x 32
+# tokens x 57,344 B = 5.64 GB of KV (32 sequences x 3,072 tokens) of the
+# 16.9 GB the chip reports; a T=8192 prefill wave adds ~0.55 GB of
+# transients (measured, PERF.md "Bring-up on v5e").
+ONE_CHIP = ["--preset", "qwen2-7b", "--quant", "int8", "--num-kv-blocks", "3072",
+            "--max-model-len", "8192", "--max-num-seqs", "32"]
+MODES = {
+    "chip": ONE_CHIP,
+    "cpu-tiny": ["--preset", "tiny"],
+    "tp4": ["--preset", "qwen2-7b", "--tp", "4", "--num-kv-blocks", "8192",
+            "--max-model-len", "8192", "--max-num-seqs", "32"],
+    "pd": ONE_CHIP,  # for each of the two workers
+}
+
+
+def say(msg: str) -> None:
+    print(f"[chip_smoke +{time.monotonic() - T0:6.1f}s] {msg}", file=sys.stderr,
+          flush=True)
+
+
+class PhaseFailed(Exception):
+    pass
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def tail(path: Path, n: int = 40) -> str:
+    try:
+        return "\n".join(path.read_text(errors="replace").splitlines()[-n:])
+    except OSError:
+        return "(no log)"
+
+
+# -- children --------------------------------------------------------------------
+
+
+class Children:
+    """Every process the smoke starts, reaped on the way out whatever
+    happened: SIGTERM (the worker drains and releases the chip), then
+    SIGKILL for anything still alive."""
+
+    def __init__(self) -> None:
+        self.procs: list[tuple[str, subprocess.Popen, Path]] = []
+
+    def start(self, name: str, argv: list[str], env: dict) -> subprocess.Popen:
+        log = OUT / f"{name}.log"
+        with open(log, "wb") as fh:
+            proc = subprocess.Popen(
+                [sys.executable, *argv], cwd=HERE, env=env, stdout=fh,
+                stderr=subprocess.STDOUT, start_new_session=True,
+            )
+        self.procs.append((name, proc, log))
+        return proc
+
+    def check_alive(self) -> None:
+        for name, proc, log in self.procs:
+            if proc.poll() is not None:
+                raise PhaseFailed(
+                    f"{name} exited with code {proc.returncode}:\n{tail(log)}"
+                )
+
+    def stop(self) -> None:
+        # Last started, first stopped: the frontend, then the workers
+        # (which drain against a store that is still there), then the store.
+        for name, proc, _ in reversed(self.procs):
+            if proc.poll() is None:
+                proc.send_signal(signal.SIGTERM)
+            try:
+                proc.wait(30)
+            except subprocess.TimeoutExpired:
+                say(f"{name} ignored SIGTERM; killing")
+                try:
+                    os.killpg(proc.pid, signal.SIGKILL)
+                except ProcessLookupError:
+                    pass
+                proc.wait(10)
+        self.procs.clear()
+
+
+# -- HTTP ---------------------------------------------------------------------------
+
+
+def http_json(url: str, body: dict | None = None, timeout: float = 600.0):
+    data = None if body is None else json.dumps(body).encode()
+    req = urllib.request.Request(
+        url, data=data, headers={"Content-Type": "application/json"}
+    )
+    try:
+        with urllib.request.urlopen(req, timeout=timeout) as r:
+            return r.status, json.loads(r.read())
+    except urllib.error.HTTPError as e:
+        return e.code, {"error": e.read().decode(errors="replace")[:400]}
+
+
+def chat_body(prompt: str, model: str, **extra) -> dict:
+    return {
+        "model": model,
+        "messages": [{"role": "user", "content": prompt}],
+        "max_tokens": MAX_TOKENS,
+        # Random weights can sample the byte tokenizer's EOS id; the
+        # length contract below needs every request to run its budget.
+        "dyn": {"ignore_eos": True},
+        **extra,
+    }
+
+
+def prompt_of(n_bytes: int, seed: int) -> str:
+    rng = random.Random(seed)
+    words = []
+    while sum(len(w) + 1 for w in words) < n_bytes:
+        words.append("".join(rng.choices("abcdefghijklmnopqrstuvwxyz",
+                                         k=rng.randint(2, 9))))
+    return " ".join(words)[:n_bytes]
+
+
+def check_completion(status: int, body: dict, what: str) -> dict:
+    if status != 200:
+        raise PhaseFailed(f"{what}: HTTP {status}: {body}")
+    usage = body.get("usage") or {}
+    finish = body["choices"][0].get("finish_reason")
+    if usage.get("completion_tokens") != MAX_TOKENS or finish != "length":
+        raise PhaseFailed(
+            f"{what}: expected {MAX_TOKENS} completion tokens and "
+            f"finish_reason 'length', got usage={usage} finish={finish!r}"
+        )
+    return usage
+
+
+def stream_chat(url: str, body: dict) -> dict:
+    """One SSE chat completion; returns time to first content chunk,
+    chunk count and whether the stream closed with ``data: [DONE]``."""
+    req = urllib.request.Request(
+        url, data=json.dumps(dict(body, stream=True)).encode(),
+        headers={"Content-Type": "application/json"},
+    )
+    t0 = time.monotonic()
+    first = None
+    chunks = 0
+    finish = None
+    last = ""
+    with urllib.request.urlopen(req, timeout=600) as r:
+        if r.status != 200:
+            raise PhaseFailed(f"streaming request: HTTP {r.status}")
+        for raw in r:
+            line = raw.decode(errors="replace").strip()
+            if not line.startswith("data:"):
+                continue
+            last = line
+            payload = line[5:].strip()
+            if payload == "[DONE]":
+                continue
+            chunk = json.loads(payload)
+            for choice in chunk.get("choices", []):
+                if first is None:
+                    first = time.monotonic() - t0
+                chunks += 1
+                finish = choice.get("finish_reason") or finish
+    if last != "data: [DONE]":
+        raise PhaseFailed(f"stream did not end in 'data: [DONE]' but {last!r}")
+    if finish != "length":
+        raise PhaseFailed(f"stream finish_reason {finish!r}, expected 'length'")
+    return {"ttft_s": first, "chunks": chunks,
+            "total_s": time.monotonic() - t0}
+
+
+# -- phases -------------------------------------------------------------------------
+
+
+def pin_to_chip(i: int) -> dict:
+    """libtpu environment that gives one process chip ``i`` of the host
+    and ports of its own (both spellings of the visibility variable and
+    of the inter-process address, for the libtpu versions that read
+    each)."""
+    port = 8476 + i
+    return dict(
+        TPU_VISIBLE_CHIPS=str(i), TPU_VISIBLE_DEVICES=str(i),
+        TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1", TPU_PROCESS_BOUNDS="1,1,1",
+        TPU_PROCESS_ADDRESSES=f"localhost:{port}", TPU_PROCESS_PORT=str(port),
+        TPU_MESH_CONTROLLER_ADDRESS=f"localhost:{port}",
+        TPU_MESH_CONTROLLER_PORT=str(port), CLOUD_TPU_TASK_ID="0",
+        TPU_RUNTIME_METRICS_PORTS=str(8431 + i),
+    )
+
+
+def build_native_index(env: dict) -> str:
+    """Build the C++ radix index from native/radix_tree.cpp on this
+    machine (``make -B``: never a binary left in the tree by an earlier
+    build), or run the Python tree on purpose and say so."""
+    try:
+        subprocess.run(
+            ["make", "-B", "-C", str(HERE / "native")], check=True,
+            capture_output=True, timeout=180,
+        )
+        return "native C++"
+    except (OSError, subprocess.SubprocessError) as e:
+        say(f"native radix build failed ({e}); frontend told to use Python")
+        env["DYNAMO_TPU_NO_NATIVE"] = "1"
+        return "Python"
+
+
+def wait_for(predicate, children: Children, timeout: float, what: str):
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        children.check_alive()
+        got = predicate()
+        if got:
+            return got
+        time.sleep(0.5)
+    raise PhaseFailed(f"timed out after {timeout:.0f}s waiting for {what}")
+
+
+def child_env(mode: str) -> dict:
+    env = dict(os.environ, PYTHONUNBUFFERED="1",
+               DYN_FLIGHT_DIR=str(OUT / "flight"))
+    if mode == "cpu-tiny":
+        env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
+def serve_phase(mode: str, inject: str | None, report: dict) -> None:
+    env = child_env(mode)
+    report["radix_index_built"] = build_native_index(env)
+
+    store_port, http_port = free_port(), free_port()
+    env["DYN_STORE_ADDRESS"] = f"127.0.0.1:{store_port}"
+    base = f"http://127.0.0.1:{http_port}"
+    ir_dir = OUT / "ir"
+    children = Children()
+    try:
+        children.start(
+            "store", ["-m", "dynamo_tpu.runtime.store", "--port", str(store_port)],
+            env,
+        )
+        worker_flags = list(MODES[mode])
+        if inject == "worker-start":
+            worker_flags += ["--preset", "no-such-preset"]
+        workers = []
+        roles = ("prefill", "decode") if mode == "pd" else ("aggregated",)
+        t_start = time.monotonic()
+        for i, role in enumerate(roles):
+            status_port = free_port()
+            (ir_dir / role).mkdir(parents=True, exist_ok=True)
+            wenv = dict(env, DYN_SYSTEM_PORT=str(status_port),
+                        JAX_DUMP_IR_TO=str(ir_dir / role))
+            flags = ["--model-name", MODEL, *worker_flags]
+            if mode == "pd":
+                # One chip per process: each libtpu instance sees one chip
+                # and gets its own ports, or the second one to start finds
+                # the devices (or the first one's port) taken.
+                wenv.update(pin_to_chip(i))
+                flags += ["--role", role, "--max-local-prefill-length", "256"]
+            children.start(f"worker-{role}",
+                           ["-m", "dynamo_tpu.backends.jax", *flags], wenv)
+            workers.append((role, f"http://127.0.0.1:{status_port}/health",
+                            OUT / f"worker-{role}.log"))
+        children.start(
+            "frontend",
+            ["-m", "dynamo_tpu.frontend", "--http-host", "127.0.0.1",
+             "--http-port", str(http_port), "--router-mode", "kv"],
+            dict(env, DYN_SYSTEM_PORT=str(free_port())),
+        )
+
+        # Cold compile of every serving program happens before "serving
+        # model" (engine/warmup.py), so this wait is the long one.
+        for role, _, log in workers:
+            marker = "ready (model" if role == "prefill" else "serving model"
+            wait_for(lambda: marker in log.read_text(errors="replace"),
+                     children, 1000, f"'{marker}' in {log.name}")
+        report["start_to_serving_s"] = round(time.monotonic() - t_start, 1)
+        say(f"worker serving after {report['start_to_serving_s']} s")
+        wait_for(lambda: http_json(f"{base}/v1/models")[1].get("data"),
+                 children, 60, "the model at the frontend")
+
+        health = {role: http_json(url)[1] for role, url, _ in workers}
+        head = health[roles[-1]]
+        report["device"] = head["device"]
+        report["startup"] = {r: h["startup"] for r, h in health.items()}
+        say(f"device: {head['device']}")
+
+        chat = f"{base}/v1/chat/completions"
+        model = "no-such-model" if inject == "bad-request" else MODEL
+        prompt_bytes, stream_bytes, repeat_bytes = (
+            TINY_TRAFFIC if mode == "cpu-tiny" else CHIP_TRAFFIC)
+        streamed = stream_chat(chat, chat_body(prompt_of(stream_bytes, 1), model))
+        report["start_to_first_token_s"] = round(
+            time.monotonic() - t_start - streamed["total_s"]
+            + streamed["ttft_s"], 1)
+        report["stream"] = {k: round(v, 3) for k, v in streamed.items()}
+        say(f"stream ok: first token after {streamed['ttft_s']:.2f} s, "
+            f"{streamed['chunks']} chunks")
+
+        results: list = [None] * len(prompt_bytes)
+
+        def one(i: int, n: int) -> None:
+            t0 = time.monotonic()
+            status, body = http_json(chat, chat_body(prompt_of(n, 100 + i), model))
+            results[i] = (status, body, time.monotonic() - t0)
+
+        threads = [threading.Thread(target=one, args=(i, n))
+                   for i, n in enumerate(prompt_bytes)]
+        t0 = time.monotonic()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(900)
+        wall = time.monotonic() - t0
+        if any(r is None for r in results):
+            raise PhaseFailed("a concurrent request never returned")
+        usages = [check_completion(s, b, f"concurrent request {i}")
+                  for i, (s, b, _) in enumerate(results)]
+        report["concurrent"] = {
+            "requests": len(results),
+            "prompt_tokens": [u["prompt_tokens"] for u in usages],
+            "latency_s": [round(r[2], 2) for r in results],
+            "wall_s": round(wall, 2),
+            "completion_tokens_per_s": round(len(results) * MAX_TOKENS / wall, 1),
+        }
+        say(f"{len(results)} concurrent completions ok in {wall:.1f} s")
+
+        repeat = chat_body(prompt_of(repeat_bytes, 7), model)
+        check_completion(*http_json(chat, repeat), "repeat prompt, first send")
+        usage = check_completion(*http_json(chat, repeat),
+                                 "repeat prompt, second send")
+        cached = (usage.get("prompt_tokens_details") or {}).get("cached_tokens", 0)
+        if not cached > 0:
+            raise PhaseFailed(f"no cached_tokens on the repeated prompt: {usage}")
+        report["repeat_cached_tokens"] = cached
+        say(f"repeat prompt served {cached} cached tokens")
+
+        health = {role: http_json(url)[1] for role, url, _ in workers}
+        report["memory_after_requests"] = {r: h["memory"] for r, h in health.items()}
+        report["compile"] = {r: h["compile"] for r, h in health.items()}
+        children.check_alive()
+        if mode == "pd":
+            # Prompts above --max-local-prefill-length must really have
+            # been prefilled on the other chip and their KV handed over.
+            with urllib.request.urlopen(
+                workers[-1][1].replace("/health", "/metrics"), timeout=30
+            ) as r:
+                disagg = {
+                    line.split("{")[0].split()[0]: float(line.split()[-1])
+                    for line in r.read().decode().splitlines()
+                    if line.startswith("dynamo_disagg_")
+                }
+            report["disagg"] = disagg
+            if not any(v > 0 for k, v in disagg.items() if "handoffs" in k):
+                raise PhaseFailed(f"no KV handoff happened: {disagg}")
+
+        frontend_log = (OUT / "frontend.log").read_text(errors="replace")
+        if "migrating request" in frontend_log:
+            raise PhaseFailed(
+                "a request was replayed (stall or worker failure):\n"
+                + "\n".join(l for l in frontend_log.splitlines()
+                            if "migrating request" in l)[:2000]
+            )
+        used = "Python" if "kv index: Python" in frontend_log else (
+            "native C++" if "kv index: native C++" in frontend_log else None)
+        if used != report["radix_index_built"]:
+            raise PhaseFailed(
+                f"router index {used!r} but this run built "
+                f"{report['radix_index_built']!r}"
+            )
+        report["radix_index"] = used
+        report["attention"] = check_attention_lowering(
+            ir_dir, roles, head["device"]["platform"])
+    finally:
+        children.stop()
+        shutil.rmtree(ir_dir, ignore_errors=True)
+
+
+def check_attention_lowering(ir_dir: Path, roles, platform: str) -> dict:
+    """Read the programs the worker actually lowered (JAX_DUMP_IR_TO) and
+    look for the Mosaic custom call in each serving program: the lowered
+    program is the evidence, not a flag or a log line."""
+    found: dict[str, dict] = {}
+    for role in roles:
+        for program in SERVING_PROGRAMS:
+            files = sorted((ir_dir / role).glob(f"*jit_{program}*.mlir"))
+            calls = [f.read_text(errors="replace").count("tpu_custom_call")
+                     for f in files]
+            found[f"{role}:{program}"] = {"lowered": len(files),
+                                          "with_mosaic_call": sum(c > 0 for c in calls)}
+    for name, got in found.items():
+        if name == "prefill:_megastep_body":
+            continue  # a prefill-role worker never decodes under traffic
+        if not got["lowered"]:
+            raise PhaseFailed(f"no lowered program found for {name} in {ir_dir}")
+        if platform == "tpu" and got["with_mosaic_call"] != got["lowered"]:
+            raise PhaseFailed(
+                f"served attention did not lower to the Pallas kernel: {name} "
+                f"{got} (expected a tpu_custom_call in every program)"
+            )
+        if platform != "tpu" and got["with_mosaic_call"]:
+            raise PhaseFailed(f"Mosaic call in a {platform} program? {name} {got}")
+    return found
+
+
+def kernel_phase(mode: str, inject: str | None, report: dict) -> None:
+    env = child_env(mode)
+    argv = [sys.executable, str(HERE / "chip_smoke.py"), "--kernel-check-child"]
+    if inject == "kernel-mismatch":
+        argv.append("--corrupt")
+    log = OUT / "kernel_check.log"
+    with open(log, "wb") as fh:
+        proc = subprocess.run(argv, cwd=HERE, env=env, stdout=subprocess.PIPE,
+                              stderr=fh, timeout=900)
+    lines = proc.stdout.decode(errors="replace").strip().splitlines()
+    try:
+        result = json.loads(lines[-1])
+    except (IndexError, ValueError):
+        raise PhaseFailed(f"kernel check printed no result (exit "
+                          f"{proc.returncode}):\n{tail(log)}") from None
+    report["kernels"] = result
+    bad = [c for c in result["checks"] if not c["ok"]]
+    if proc.returncode != 0 or bad:
+        raise PhaseFailed(f"kernel check failed: {bad or tail(log)}")
+    kinds = [(d["platform"], d["kind"])
+             for d in (result["device"], report.get("device")) if d]
+    if len(set(kinds)) > 1:
+        raise PhaseFailed(f"kernel check ran on {result['device']}, the "
+                          f"worker on {report['device']}")
+    report.setdefault("device", result["device"])
+
+
+# -- the kernel-check child (the only code here that imports JAX) -------------------
+
+
+def kernel_check_child(corrupt: bool) -> int:
+    """Compile both Pallas attention kernels WITHOUT interpret mode on a
+    TPU and compare with the repo's references.
+
+    Tolerances. Inputs and outputs are bf16 (8 significand bits: one ulp
+    is 2^-8 relative) and both kernels keep bf16 operands on the MXU with
+    f32 accumulation, so an output of magnitude <= 4 may differ from an
+    f32-exact reference by an ulp or two of 2^-6 (0.0156 was the largest
+    difference seen on a v5e): atol 2^-5, rtol 2e-2. The
+    references (and only they: Mosaic rejects an f32 x bf16 matmul at
+    fp32 contract precision) run at ``highest`` matmul precision so that
+    they, not the kernel, are the exact side. The int8 path compares the kernel's
+    in-VMEM dequant with the reference's dequant-on-gather of the SAME
+    int8 pages and scales, so quantisation error cancels and the same
+    bound holds — in interpret mode only: on a TPU the int8-page variant
+    must raise with Mosaic's reason (ops/paged_attention.py).
+
+    On the CPU (``--cpu-tiny``) the first-party kernel runs in interpret
+    mode at a small shape; the library kernel is reported as not run (the
+    TPU interpreter cannot execute its reshaped refs)."""
+    sys.path.insert(0, str(HERE))
+    from dynamo_tpu.device import device_info, enable_compile_cache
+
+    enable_compile_cache()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.engine.kv_quant import quantize_kv
+    from dynamo_tpu.ops.paged_attention import (
+        paged_attention_pallas,
+        paged_attention_reference,
+    )
+    from dynamo_tpu.ops.ragged_attention import (
+        pallas_ragged_attention,
+        ragged_paged_attention_ref,
+    )
+
+    info = device_info()
+    on_tpu = info["platform"] == "tpu"
+    checks: list[dict] = []
+    ATOL, RTOL = 2.0 ** -5, 2e-2
+    rng = np.random.RandomState(0)
+
+    def check(name: str, run, reference) -> None:
+        """Compile and run one kernel (the compiler's refusal is a finding,
+        not a crash), then compare with its reference."""
+        t0 = time.perf_counter()
+        try:
+            got = np.asarray(jax.block_until_ready(run()), np.float32)
+        except Exception as e:  # noqa: BLE001 — recorded, the run fails
+            checks.append({"name": name, "ok": False,
+                           "error": f"{type(e).__name__}: {e}"[:1500]})
+            return
+        seconds = time.perf_counter() - t0
+        if corrupt:
+            got = got + 1.0
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(reference(), np.float32)
+        err = float(np.max(np.abs(got - want)))
+        ok = bool(np.all(np.isfinite(got))) and bool(
+            np.all(np.abs(got - want) <= ATOL + RTOL * np.abs(want)))
+        checks.append({"name": name, "ok": ok, "max_abs_err": round(err, 5),
+                       "shape": list(got.shape), "compile_run_s": round(seconds, 2)})
+
+    # (i) library ragged kernel under this repo's explicit grids, at the
+    # served geometry: 28 query heads over 4 KV heads (8 combined) x 128,
+    # 32-token pages, block tables as wide as --max-model-len 8192.
+    n_q, n_kv, d, ps, pages_per_seq, n_pages = 28, 4, 128, 32, 256, 1024
+    sm = d ** -0.5
+
+    def ragged_case(q_lens, kv_lens):
+        S, T = len(q_lens), sum(q_lens)
+        q = jnp.asarray(rng.randn(T, n_q, d), jnp.bfloat16)
+        kv = jnp.asarray(rng.randn(n_pages, ps, 2 * n_kv, d), jnp.bfloat16)
+        tables = np.zeros((S, pages_per_seq), np.int32)
+        perm, used = rng.permutation(n_pages), 0
+        for s, n in enumerate(kv_lens):
+            need = -(-n // ps)
+            tables[s, :need] = perm[used:used + need]
+            used += need
+        cu = np.concatenate([[0], np.cumsum(q_lens)]).astype(np.int32)
+        return (q, kv, jnp.asarray(kv_lens, jnp.int32), jnp.asarray(tables),
+                jnp.asarray(cu), jnp.asarray([S], jnp.int32))
+
+    def ragged_ref(q, kv, kv_lens, tables, cu, _):
+        """Reference one sequence at a time over just its live pages (the
+        whole-batch reference gathers [T, span, 2kv, d] in f32)."""
+        outs = []
+        for s in range(kv_lens.shape[0]):
+            lo, hi = int(cu[s]), int(cu[s + 1])
+            need = -(-int(kv_lens[s]) // ps)
+            outs.append(ragged_paged_attention_ref(
+                q[lo:hi], kv, kv_lens[s:s + 1], tables[s:s + 1, :need],
+                jnp.asarray([0, hi - lo], jnp.int32),
+                jnp.asarray([1], jnp.int32), sm_scale=sm,
+            ))
+        return jnp.concatenate(outs)
+
+    if on_tpu:
+        kernel = jax.jit(lambda *a: pallas_ragged_attention(*a, sm_scale=sm))
+        decode = ragged_case([1] * 32, [int(x) for x in rng.randint(100, 640, 32)])
+        # Eight sequences, T = 2048: fresh prompts and chunks that continue
+        # a cached prefix (kv_len > q_len), the chunked-prefill causality.
+        q_lens = [512, 384, 256, 256, 256, 128, 128, 128]
+        prefill = ragged_case(q_lens, [n + e for n, e in
+                                       zip(q_lens, [0, 96, 0, 320, 32, 0, 64, 512])])
+        check("library ragged kernel, decode 32 x q1",
+              lambda: kernel(*decode), lambda: ragged_ref(*decode))
+        check("library ragged kernel, prefill T=2048",
+              lambda: kernel(*prefill), lambda: ragged_ref(*prefill))
+
+        # The megastep calls the kernel inside lax.scan.
+        def scanned(q, *rest):
+            def body(carry, _):
+                return carry, pallas_ragged_attention(carry, *rest, sm_scale=sm)
+            return jax.lax.scan(body, q, None, length=2)[1][1]
+
+        check("library ragged kernel inside lax.scan",
+              lambda: jax.jit(scanned)(*decode), lambda: ragged_ref(*decode))
+    else:
+        checks.append({"name": "library ragged kernel", "ok": True,
+                       "not_run": "cpu: the TPU interpreter cannot execute it"})
+
+    # (ii) first-party decode kernel, bf16 and int8 pages, head_dim 128,
+    # block 32, 7 query heads per KV head.
+    B, bs, max_blocks, blocks = (8, 32, 16, 256) if on_tpu else (2, 32, 4, 16)
+    q = jnp.asarray(rng.randn(B, n_q, d), jnp.bfloat16)
+    k = jnp.asarray(rng.randn(n_kv, blocks * bs, d), jnp.bfloat16)
+    v = jnp.asarray(rng.randn(n_kv, blocks * bs, d), jnp.bfloat16)
+    tables = jnp.asarray(
+        rng.permutation(blocks)[:B * max_blocks].reshape(B, max_blocks), jnp.int32)
+    seq_lens = jnp.asarray(rng.randint(bs, max_blocks * bs, B), jnp.int32)
+    k8, ks = quantize_kv(k)
+    v8, vs = quantize_kv(v)
+    common = dict(block_tables=tables, seq_lens=seq_lens, block_size=bs)
+    bf16 = dict(k_cache=k, v_cache=v)
+    int8 = dict(k_cache=k8, v_cache=v8, k_scale=ks, v_scale=vs)
+    check("first-party paged kernel, bf16 pages",
+          lambda: paged_attention_pallas(q, interpret=not on_tpu, **common, **bf16),
+          lambda: paged_attention_reference(q, **common, **bf16))
+    if on_tpu:
+        # Mosaic refuses the int8-page variant (the reason is recorded in
+        # ops/paged_attention.py); what is checked is that asking for it
+        # on a TPU is an error that says so, not a quiet reference run.
+        try:
+            paged_attention_pallas(q, **common, **int8)
+            refused = None
+        except NotImplementedError as e:
+            refused = str(e)
+        checks.append({"name": "first-party paged kernel, int8 pages",
+                       "ok": bool(refused) and not corrupt,
+                       "raises_on_tpu": refused})
+    else:
+        check("first-party paged kernel, int8 pages (interpreted)",
+              lambda: paged_attention_pallas(q, interpret=True, **common, **int8),
+              lambda: paged_attention_reference(q, **common, **int8))
+
+    for c in checks:
+        print(f"  {'ok  ' if c['ok'] else 'FAIL'} {c}", file=sys.stderr, flush=True)
+    print(json.dumps({"device": info, "atol": ATOL, "rtol": RTOL, "checks": checks}))
+    return 0 if all(c["ok"] for c in checks) else 1
+
+
+# -- entry ---------------------------------------------------------------------------
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    which = ap.add_mutually_exclusive_group()
+    which.add_argument("--cpu-tiny", action="store_const", const="cpu-tiny",
+                       dest="mode", help="rehearse on the CPU with the tiny preset")
+    which.add_argument("--tp4", action="store_const", const="tp4", dest="mode",
+                       help="four chips: one worker with --tp 4, bf16 weights")
+    which.add_argument("--pd", action="store_const", const="pd", dest="mode",
+                       help="two one-chip workers: --role prefill and --role decode")
+    which.add_argument("--kernel-check-child", action="store_true",
+                       help=argparse.SUPPRESS)
+    ap.add_argument("--corrupt", action="store_true", help=argparse.SUPPRESS)
+    ap.add_argument("--inject", choices=["worker-start", "bad-request",
+                                         "kernel-mismatch"], default=None,
+                    help="break one phase on purpose (the run must fail)")
+    args = ap.parse_args()
+    if args.kernel_check_child:
+        return kernel_check_child(args.corrupt)
+    mode = args.mode or "chip"
+
+    if not (HERE / "dynamo_tpu").is_dir():
+        say("dynamo_tpu/ is not beside chip_smoke.py: nothing to smoke")
+        return 1
+    if mode != "cpu-tiny" and os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
+        say("JAX_PLATFORMS=cpu: this environment has no accelerator. The chip "
+            "check needs a TPU; pass --cpu-tiny for the CPU rehearsal.")
+        return 1
+    OUT.mkdir(exist_ok=True)
+
+    report: dict = {"mode": mode}
+    failures: list[str] = []
+    phases = [("serve", serve_phase)]
+    if mode in ("chip", "cpu-tiny"):  # the kernels are the same on four chips
+        phases.append(("kernels", kernel_phase))
+    for name, phase in phases:
+        try:
+            phase(mode, args.inject, report)
+            say(f"phase {name}: ok")
+        except PhaseFailed as e:
+            failures.append(f"{name}: {e}")
+        except Exception as e:  # noqa: BLE001 — report, then fail the run
+            failures.append(f"{name}: {type(e).__name__}: {e}")
+
+    device = report.get("device")
+    if not failures and mode != "cpu-tiny" and (device or {}).get("platform") != "tpu":
+        failures.append(f"device is {device}, not a TPU")
+    assert "jax" not in sys.modules, "the smoke's parent must never import JAX"
+    report["seconds"] = round(time.monotonic() - T0, 1)
+    (OUT / "report.json").write_text(json.dumps(report, indent=1, default=str))
+    if failures:
+        for log in sorted(OUT.glob("*.log")):
+            say(f"---- tail of {log.name} ----\n{tail(log, 25)}")
+        for f in failures:
+            say(f"FAILED {f}")
+        return 1
+
+    startup = report["startup"]
+    print(f"mode {mode}: device platform={device['platform']} "
+          f"device_kind={device['kind']!r} count={device['count']}")
+    print(f"worker start -> 'serving model': {report['start_to_serving_s']} s; "
+          f"-> first token: {report['start_to_first_token_s']} s "
+          f"(stream TTFT {report['stream']['ttft_s']} s)")
+    for role, st in startup.items():
+        comp = report["compile"][role]
+        print(f"[{role}] build {st['build_seconds']} s, warm-up "
+              f"{st.get('warmup_seconds')} s; compile {comp['total_seconds']} s "
+              f"backend + {comp['trace_lower_seconds']} s trace/lower, persistent "
+              f"cache hits {comp['cache_hits']} misses {comp['cache_misses']}")
+        for program, seconds in comp["programs"]:
+            print(f"    compile {seconds:7.2f} s  {program}")
+        print(f"[{role}] peak device bytes after init "
+              f"{[m['peak_bytes_in_use'] for m in st['memory_after_init']]}, after "
+              f"requests {[m['peak_bytes_in_use'] for m in report['memory_after_requests'][role]]}"
+              f" (limit {[m['bytes_limit'] for m in st['memory_after_init']]})")
+        print(f"[{role}] bytes per device: params {st['param_bytes_per_device']}, "
+              f"cache {st['cache_bytes_per_device']}")
+    print(f"smoke observations (not benchmark results): {report['concurrent']}")
+    print(f"repeat prompt cached_tokens={report['repeat_cached_tokens']}; router "
+          f"index: {report['radix_index']} (built this run)")
+    print(f"served attention lowering: {report['attention']}")
+    for c in report.get("kernels", {}).get("checks", ()):
+        print(f"kernel check: {c}")
+    if "disagg" in report:
+        print(f"prefill -> decode handoffs: {report['disagg']}")
+    print(f"total {report['seconds']} s")
+    print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

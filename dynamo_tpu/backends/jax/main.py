@@ -398,7 +398,11 @@ async def run_jax_worker(
     node_rank: int = 0,
     obs_publish: bool = True,
     obs_interval_s: float = 1.0,
+    warm_up: bool = False,
 ) -> None:
+    """``warm_up`` compiles the serving programs before the model is
+    registered (engine/warmup.py). The worker CLI always does; in-process
+    callers (tests on the CPU) leave it off and compile on first use."""
     if component is None:
         component = "prefill" if role == "prefill" else "backend"
     if tokenizer is None:
@@ -462,24 +466,70 @@ async def run_jax_worker(
     # first jit takes tens of seconds, and blocking the loop that long
     # starves the store lease keepalive (ttl 10s) — the worker would
     # arrive at registration with its lease already expired.
-    core, engine = await asyncio.to_thread(
-        build_engine,
-        preset,
-        engine_overrides,
-        seed=seed,
-        eos_token_ids=eos,
-        on_stored=on_stored,
-        on_removed=on_removed,
-        on_tier_stored=on_tier_stored,
-        on_tier_removed=on_tier_removed,
-        tp=tp,
-        dp=dp,
-        sp=sp,
-        pp=pp,
-        quant=quant,
-        moe_dispatch=moe_dispatch,
-        model_path=model_path,
+    from dynamo_tpu import device
+
+    compile_log = device.compile_log()
+    startup: dict[str, Any] = {}
+
+    def _build():
+        # Refuse a fallback device BEFORE minutes of CPU work on a model
+        # sized for a chip: JAX falls back to the CPU when libtpu finds
+        # no TPU, and only an explicit CPU request makes that a plan.
+        info = device.require_accelerator("jax worker")
+        t0 = time.perf_counter()
+        built = build_engine(
+            preset,
+            engine_overrides,
+            seed=seed,
+            eos_token_ids=eos,
+            on_stored=on_stored,
+            on_removed=on_removed,
+            on_tier_stored=on_tier_stored,
+            on_tier_removed=on_tier_removed,
+            tp=tp,
+            dp=dp,
+            sp=sp,
+            pp=pp,
+            quant=quant,
+            moe_dispatch=moe_dispatch,
+            model_path=model_path,
+        )
+        import jax
+
+        jax.block_until_ready((built[0].params, built[0].cache))
+        startup["build_seconds"] = round(time.perf_counter() - t0, 2)
+        startup["memory_after_init"] = device.memory_stats()
+        startup["param_bytes_per_device"] = device.bytes_per_device(
+            built[0].params
+        )
+        startup["cache_bytes_per_device"] = device.bytes_per_device(
+            built[0].cache
+        )
+        return info, built
+
+    device_info, (core, engine) = await asyncio.to_thread(_build)
+    log.info(
+        "jax worker device: platform=%s device_kind=%r devices=%d "
+        "(engine built in %.1f s; peak bytes after init %s; bytes per "
+        "device: params %s, cache %s)",
+        device_info["platform"], device_info["kind"], device_info["count"],
+        startup["build_seconds"],
+        [m["peak_bytes_in_use"] for m in startup["memory_after_init"]],
+        startup["param_bytes_per_device"], startup["cache_bytes_per_device"],
     )
+    if warm_up:
+        from dynamo_tpu.engine.warmup import warm_up as _warm_up
+
+        t0 = time.perf_counter()
+        startup["warmup_phases"] = await asyncio.to_thread(_warm_up, core)
+        startup["warmup_seconds"] = round(time.perf_counter() - t0, 2)
+    if runtime.status is not None:
+        runtime.status.health_sections.update(
+            device=lambda: device_info,
+            startup=lambda: startup,
+            compile=compile_log.snapshot,
+            memory=device.memory_stats,
+        )
 
     if core_out is not None:
         core_out.append(core)
@@ -873,8 +923,10 @@ async def run_jax_worker(
     await endpoint.serve(handler)
     await register_llm(endpoint, _model_card(model_name, tokenizer, core))
     log.info(
-        "jax %s worker %d serving model %r (preset %s, %d kv blocks)",
+        "jax %s worker %d serving model %r (preset %s, %d kv blocks) on "
+        "%s %r x%d",
         role, worker_id, model_name, preset, core.engine.num_kv_blocks,
+        device_info["platform"], device_info["kind"], device_info["count"],
     )
     if served_event is not None:
         served_event.set()
@@ -1448,8 +1500,13 @@ def main() -> None:
 
         force_cpu_devices(args.local_cpu_devices)
 
+    from dynamo_tpu.device import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+
     @dynamo_worker()
     async def entry(runtime: DistributedRuntime) -> None:
+        log.info("persistent compile cache: %s", cache_dir)
         await run_jax_worker(
             runtime,
             model_name=args.model_name,
@@ -1474,6 +1531,7 @@ def main() -> None:
             node_rank=args.node_rank,
             obs_publish=args.obs_publish == "on",
             obs_interval_s=args.obs_interval_s,
+            warm_up=True,
         )
 
     entry()

@@ -848,6 +848,16 @@ def _pp_decode_chain(
     )
 
 
+def _program(fn, **bound):
+    """``functools.partial`` that keeps the function's name. JAX names a
+    compiled program after ``__name__`` — in compile logs and events, IR
+    dumps and profiler traces — and a bare partial has none, so every
+    serving program would read ``<unknown>``."""
+    p = partial(fn, **bound)
+    p.__name__ = fn.__name__
+    return p
+
+
 class EngineCore:
     def __init__(
         self,
@@ -950,9 +960,8 @@ class EngineCore:
                 "kv_dtype=int8 on TPU: serving attention dequantizes "
                 "per-layer pages before the library kernel (capacity "
                 "win, no traffic win; transient ~1/%d of a bf16 cache "
-                "per call). The int8-page DMA kernel is the first-party "
-                "decode path (DYNAMO_TPU_PAGED_ATTN=pallas) — see "
-                "PERF.md round 10.",
+                "per call). TPOT cost against bf16 KV: not measured "
+                "(ROADMAP S4).",
                 model_cfg.num_layers,
             )
         if engine_cfg.spec_decode != "off" and pp_mesh is not None:
@@ -1315,7 +1324,7 @@ class EngineCore:
         self.on_chunk_commit = None
         # Disagg transfer accounting (imported vs dropped must be
         # distinguishable — a half-dropped transfer silently recomputes on
-        # the decode side; VERDICT r4 weak #7). Surfaced via metrics().
+        # the decode side). Surfaced via metrics().
         self.transfer_stats = {
             "transfers": 0,
             "imported_blocks": 0,
@@ -1398,7 +1407,7 @@ class EngineCore:
         self._admit_prefix_hits = 0
 
         self._prefill = jax.jit(
-            partial(_prefill_and_sample, cfg=model_cfg, engine=engine_cfg, mesh=mesh),
+            _program(_prefill_and_sample, cfg=model_cfg, engine=engine_cfg, mesh=mesh),
             static_argnames=("need_mask", "all_greedy", "want_logprobs", "want_mm"),
             donate_argnums=(1,),
         )
@@ -1414,7 +1423,7 @@ class EngineCore:
                 raise ValueError("sp_mesh (sequence parallel) and mesh (tp/dp) "
                                  "are mutually exclusive for now")
             self._ring = jax.jit(
-                partial(
+                _program(
                     _ring_prefill_and_sample,
                     cfg=model_cfg, engine=engine_cfg, sp_mesh=sp_mesh,
                 ),
@@ -1423,7 +1432,7 @@ class EngineCore:
             )
         self._ring_prefills = 0  # observability: ring-path invocations
         self._decode = jax.jit(
-            partial(_megastep_body, cfg=model_cfg, engine=engine_cfg, mesh=mesh),
+            _program(_megastep_body, cfg=model_cfg, engine=engine_cfg, mesh=mesh),
             static_argnames=("n_steps", "need_mask", "all_greedy", "want_logprobs"),
             donate_argnums=(1,),
         )
@@ -1432,7 +1441,7 @@ class EngineCore:
         # n_steps-1 scanned decode iterations in one dispatch; verify
         # accept/reject resolves on device.
         self._fused = jax.jit(
-            partial(
+            _program(
                 _megastep_fused_body, cfg=model_cfg, engine=engine_cfg,
                 mesh=mesh,
             ),
@@ -1448,7 +1457,7 @@ class EngineCore:
         # ring, verifies the fresh draft R-wide, resolves accept/reject,
         # and redrafts, so draft→verify→accept loops inside one dispatch.
         self._drafted = jax.jit(
-            partial(
+            _program(
                 _megastep_draft_body, cfg=model_cfg, engine=engine_cfg,
                 mesh=mesh,
                 ngram_max_static=engine_cfg.spec_ngram_max,
@@ -1463,7 +1472,7 @@ class EngineCore:
         self._decode_pp = None
         if pp_mesh is not None:
             self._prefill_pp = jax.jit(
-                partial(
+                _program(
                     _pp_prefill_and_sample, cfg=model_cfg, engine=engine_cfg,
                     pp_mesh=pp_mesh, n_micro=self._pp_micro,
                 ),
@@ -1471,7 +1480,7 @@ class EngineCore:
                 donate_argnums=(1,),
             )
             self._decode_pp = jax.jit(
-                partial(
+                _program(
                     _pp_decode_chain, cfg=model_cfg, engine=engine_cfg,
                     pp_mesh=pp_mesh, n_micro=self._pp_micro,
                 ),
@@ -4758,7 +4767,7 @@ class EngineCore:
                 for _ in range(self.cfg.num_layers)
             )
             self._embed_fn = jax.jit(
-                partial(embed_forward, cfg=self.cfg, engine=self.engine, mesh=self.mesh),
+                _program(embed_forward, cfg=self.cfg, engine=self.engine, mesh=self.mesh),
                 donate_argnums=(1,),
             )
         garbage = self._embed_scratch[0].shape[0] - 1

@@ -1,0 +1,115 @@
+"""Compile a worker's serving programs before it takes traffic.
+
+The engine compiles lazily: the first prefill wave in each token bucket
+and the first decode megastep at each batch width trace every layer of
+the model and compile for tens of seconds on a chip. A worker that
+registers first and compiles on its first request holds that request's
+stream silent for the whole compile; the frontend's stall deadline
+(``DYN_DATAPLANE_STALL_TIMEOUT_S``) then declares the worker dead and
+replays the stream onto the same, still compiling, worker. So the worker
+drives synthetic requests through the normal ``add_request``/``step``
+path before ``register_llm`` — the same thing bench.py does for itself —
+and the deadline stays what it is.
+
+Covered: every prefill bucket one wave can reach and every decode width
+``max_num_seqs`` can reach at the full megastep length, for the two
+sampling programs ordinary requests select (sampled without a top-k/top-p
+mask — the OpenAI default — and greedy). Left to compile on first use,
+one short program at a time: shortened megasteps at the end of a
+generation budget, masked sampling, logprobs, speculative verify rows and
+multimodal prefill. Chunked scheduling runs the same traffic, which
+compiles the mixed-step shapes that traffic happens to form.
+"""
+
+from __future__ import annotations
+
+import logging
+import time
+
+import numpy as np
+
+from dynamo_tpu.engine.core import EngineCore
+from dynamo_tpu.llm.protocols.common import (
+    PreprocessedRequest,
+    SamplingOptions,
+    StopConditions,
+)
+
+log = logging.getLogger("dynamo_tpu.engine.warmup")
+
+
+def _run(core: EngineCore, prompts: list[list[int]], max_tokens: int,
+         temperature: float, tag: str) -> None:
+    seqs = [
+        core.add_request(PreprocessedRequest(
+            model="warmup",
+            token_ids=p,
+            request_id=f"warmup-{tag}-{i}",
+            sampling=SamplingOptions(temperature=temperature, seed=i),
+            stop=StopConditions(max_tokens=max_tokens, ignore_eos=True),
+        ))
+        for i, p in enumerate(prompts)
+    ]
+    while any(s.finish is None for s in seqs):
+        core.step()
+
+
+def warm_up(core: EngineCore) -> dict[str, float]:
+    """Run the warm-up traffic; returns wall seconds per phase (compile
+    seconds per program come from :class:`dynamo_tpu.device.CompileLog`).
+
+    The allocator's KV-event callbacks are detached for the duration and
+    the warm-up blocks are dropped from the prefix cache afterwards, so
+    routers never hear of them. Scheduler counters do include the
+    warm-up's dispatches."""
+    eng = core.engine
+    rng = np.random.RandomState(0)
+    vocab = core.cfg.vocab_size
+    k = eng.megastep
+    lanes = min(eng.max_num_seqs, eng.max_waiting or eng.max_num_seqs)
+    # Longest prompt that still leaves room to generate one megastep.
+    max_prompt = eng.max_model_len - k - 2
+    bs = eng.block_size
+    # A wave that cannot be admitted would wait for blocks forever.
+    block_budget = int(eng.num_kv_blocks * 0.9)
+    phases: dict[str, float] = {}
+
+    def prompts(n: int, length: int) -> list[list[int]]:
+        return [rng.randint(1, vocab, size=length).tolist() for _ in range(n)]
+
+    alloc = core.allocator
+    saved = alloc.on_stored, alloc.on_removed
+    alloc.on_stored = lambda hashes, parent: None
+    alloc.on_removed = lambda hashes: None
+    try:
+        for temperature, name in ((1.0, "sampled"), (0.0, "greedy")):
+            prev = 0
+            for bucket in eng.prefill_buckets:
+                n = min(eng.prefill_batch, lanes)
+                length = min(bucket // n, max_prompt)
+                if n * length <= prev or n * -(-length // bs) > block_budget:
+                    break  # one wave cannot fill this bucket (or larger)
+                t0 = time.perf_counter()
+                _run(core, prompts(n, length), 1, temperature,
+                     f"{name}-prefill{bucket}")
+                phases[f"prefill T={bucket} {name}"] = time.perf_counter() - t0
+                prev = bucket
+            prev = 0
+            for width in eng.decode_buckets:
+                n = min(width, lanes)
+                length = min(bs, max_prompt)
+                if n <= prev or n * -(-(length + k + 1) // bs) > block_budget:
+                    break  # max_num_seqs (or the cache) never reaches it
+                t0 = time.perf_counter()
+                _run(core, prompts(n, length), 1 + k, temperature,
+                     f"{name}-decode{width}")
+                phases[f"decode B={width} k={k} {name}"] = (
+                    time.perf_counter() - t0
+                )
+                prev = width
+    finally:
+        core.clear_kv_cache()
+        alloc.on_stored, alloc.on_removed = saved
+    for phase, seconds in phases.items():
+        log.info("warm-up %-28s %6.1f s", phase, seconds)
+    return {p: round(s, 2) for p, s in phases.items()}

@@ -179,6 +179,10 @@ class KvIndexer:
                 inner = NativeRadixTree()  # type: ignore[assignment]
             except (RuntimeError, OSError):
                 inner = RadixTree()
+        log.info(
+            "kv index: %s radix tree",
+            "Python" if isinstance(inner, RadixTree) else "native C++",
+        )
         self.tree = GlobalKvIndex(inner, on_gap=self._request_resync)
         self._task: asyncio.Task | None = None
         self._sub = None

@@ -52,7 +52,10 @@ def _load() -> ctypes.CDLL | None:
             except (OSError, subprocess.SubprocessError) as e:
                 if not _SO.exists():
                     raise
-                log.debug("make failed (%s); loading existing %s", e, _SO.name)
+                log.warning(
+                    "make failed (%s); loading the existing %s, which may "
+                    "be older than radix_tree.cpp", e, _SO.name,
+                )
             lib = ctypes.CDLL(str(_SO))
         except (OSError, subprocess.SubprocessError) as e:
             log.warning("native radix unavailable (%s); using Python tree", e)

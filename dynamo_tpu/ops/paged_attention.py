@@ -31,6 +31,18 @@ from dynamo_tpu import knobs
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
+# What Mosaic said when the int8-page variant first met a chip (v5e, jax
+# 0.9.0 / libtpu 0.0.34, head_dim 128, block 32; chip_smoke.py's kernel
+# check). The bf16-page kernel compiles and matches its reference.
+INT8_PAGES_ON_TPU = (
+    "the first-party int8-page kernel does not compile for TPU: Mosaic "
+    "rejects the per-page scale DMA — 'Slice shape along dimension 2 must "
+    "be aligned to tiling (128), but is 32' (the [n_kv, n_blocks, "
+    "block_size] f32 scale rows are narrower than a 128-lane tile). It "
+    "runs in interpret mode only; serving int8 KV goes through "
+    "ops/ragged_attention.py. ROADMAP D6 decides between repair and removal."
+)
+
 
 def paged_attention_reference(
     q: jax.Array,            # [B, n_q, d]
@@ -237,6 +249,8 @@ def paged_attention_pallas(
     qg = q.reshape(B, n_kv, group, d)
     with_self = k_self is not None
     with_quant = k_scale is not None
+    if with_quant and not interpret:
+        raise NotImplementedError(INT8_PAGES_ON_TPU)
     self_dtype = jnp.float32 if with_quant else k_cache.dtype
     if not with_self:
         k_self = jnp.zeros((B, n_kv, d), self_dtype)
@@ -322,23 +336,30 @@ def paged_attention(
     q, k_cache, v_cache, block_tables, seq_lens, *, block_size, scale=None,
     k_self=None, v_self=None, k_scale=None, v_scale=None,
 ) -> jax.Array:
-    """Dispatch: XLA gather path by default — measured faster than the
-    current Pallas kernel at serving context lengths (the kernel's
-    (batch x head) grid runs serially per TensorCore; its page DMAs are
-    latency-bound). ``DYNAMO_TPU_PAGED_ATTN=pallas`` opts into the kernel
-    (wins when live context is a small fraction of the table span; also
-    the base for the next-round ragged multi-page kernel).
+    """Dispatch: XLA gather path by default. ``DYNAMO_TPU_PAGED_ATTN=pallas``
+    opts into the first-party kernel; asked for and unavailable (no TPU,
+    or a geometry :func:`pallas_supported` rejects) is an error, never a
+    quiet run of the reference under the kernel's name.
 
-    ``k_scale``/``v_scale`` mark int8 caches: the kernel DMAs the halved
-    int8 pages plus their per-slot scale tiles and dequantizes in-VMEM
-    after the copy — decode attention is DMA-latency-bound (PERF.md), so
-    the halved page copy is exactly where int8 can beat the bf16 path;
-    the XLA path fuses the dequant into its gather."""
-    if (
-        jax.default_backend() == "tpu"
-        and knobs.get_str("DYNAMO_TPU_PAGED_ATTN") == "pallas"
-        and pallas_supported(q.shape[-1], block_size, k_cache.dtype)
-    ):
+    ``k_scale``/``v_scale`` mark int8 caches: the XLA path fuses the
+    dequant into its gather; the kernel's int8-page variant raises on a
+    TPU (:data:`INT8_PAGES_ON_TPU`)."""
+    if knobs.get_str("DYNAMO_TPU_PAGED_ATTN") == "pallas":
+        if not pallas_supported(q.shape[-1], block_size, k_cache.dtype):
+            raise ValueError(
+                "DYNAMO_TPU_PAGED_ATTN=pallas: unsupported geometry "
+                f"head_dim={q.shape[-1]}, block_size={block_size}, "
+                f"cache dtype={k_cache.dtype} (needs head_dim % 128 == 0 "
+                "and block_size a multiple of the dtype's sublane tile: "
+                "16 for bf16, 32 for int8)"
+            )
+        backend = jax.default_backend()
+        if backend != "tpu":
+            raise RuntimeError(
+                "DYNAMO_TPU_PAGED_ATTN=pallas needs a TPU backend, got "
+                f"{backend!r} (tests drive paged_attention_pallas with "
+                "interpret=True directly)"
+            )
         return paged_attention_pallas(
             q, k_cache, v_cache, block_tables, seq_lens,
             block_size=block_size, scale=scale, k_self=k_self, v_self=v_self,

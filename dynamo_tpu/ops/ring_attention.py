@@ -19,9 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from dynamo_tpu.jax_compat import shard_map
 
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
 

@@ -52,8 +52,9 @@ def init_multihost(
 
 def force_cpu_devices(n: int) -> None:
     """Virtual-device validation mode: N CPU devices stand in for a
-    multi-chip host. The TPU PJRT plugin ignores the JAX_PLATFORMS env
-    var; the config update is the authoritative switch. Call BEFORE any
+    multi-chip host. The config update has the same effect as
+    ``JAX_PLATFORMS=cpu`` in the environment (which libtpu honours) but
+    also wins over an ambient ``JAX_PLATFORMS=tpu,cpu``. Call BEFORE any
     other jax use."""
     import jax
 

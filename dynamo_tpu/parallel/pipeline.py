@@ -49,10 +49,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
-from dynamo_tpu.jax_compat import shard_map
 from dynamo_tpu.engine.model import (
     Params,
     _dot,

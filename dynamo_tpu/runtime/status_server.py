@@ -650,6 +650,10 @@ class SystemStatusServer:
         # Store client whose connectivity /health reports (wired by
         # bind_store_gauges); None = no control-plane section.
         self.store = None
+        # Extra /health sections: name -> zero-argument callable evaluated
+        # per request (the JAX worker reports its device, start-up timings,
+        # compile log and device memory here).
+        self.health_sections: dict[str, Callable[[], object]] = {}
         self.app = web.Application()
         self.app.router.add_get("/health", self.health)
         self.app.router.add_get("/live", self.live)
@@ -698,6 +702,8 @@ class SystemStatusServer:
                 # orchestrators don't kill a working worker over a store
                 # blackout, but make the state visible.
                 payload["status"] = status = "degraded"
+        for name, section in self.health_sections.items():
+            payload[name] = section()
         return web.json_response(
             payload,
             status=200 if status in ("healthy", "degraded") else 503,

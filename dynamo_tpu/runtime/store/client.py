@@ -110,7 +110,7 @@ class StoreClient:
         self._send_lock = asyncio.Lock()
         self._closed = False
         # Reconnect state: enough to rebuild the session after a store
-        # restart or connection blip (VERDICT r3 weak #9 — the reference
+        # restart or connection blip (the reference
         # leans on etcd/NATS client reconnection; this store's client
         # owns the same responsibility). Leases re-attach under their old
         # id (worker identity embeds it) and lease-bound KV is replayed.

@@ -12,9 +12,10 @@ import os
 
 # Force CPU even when the ambient environment points at real TPU hardware
 # (tests are deterministic and cluster-free; bench.py uses the real chip).
-# The TPU PJRT plugin ignores the JAX_PLATFORMS env var, so the config
-# update below — which does win — is the load-bearing line; the env vars
-# cover subprocesses.
+# jax 0.9.0 with libtpu 0.0.34 honours JAX_PLATFORMS=cpu on a machine that
+# has a chip (checked on a v5e: jax.devices() is [CpuDevice]), so the
+# assignment covers this process and its subprocesses; the config update
+# below repeats it in case jax was imported before this file.
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:

@@ -1,0 +1,55 @@
+"""chip_smoke.py's CPU rehearsal: the same three processes, requests and
+kernel-check child as on the chip, at the `tiny` preset. Slow tier (four
+real process fleets); the chip run itself is the driver's.
+"""
+
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+pytestmark = [pytest.mark.slow, pytest.mark.e2e]
+
+REPO = Path(__file__).resolve().parents[1]
+
+
+def _smoke(*args: str) -> subprocess.CompletedProcess:
+    return subprocess.run(
+        [sys.executable, "chip_smoke.py", *args], cwd=REPO,
+        capture_output=True, text=True, timeout=600,
+    )
+
+
+def test_cpu_tiny_rehearsal_passes_and_names_its_device():
+    out = _smoke("--cpu-tiny")
+    assert out.returncode == 0, out.stderr[-3000:]
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    assert last["ok"] is True
+    # (count follows the suite's XLA_FLAGS virtual-device setting)
+    assert (last["device"]["platform"], last["device"]["kind"]) == ("cpu", "cpu")
+    report = json.loads((REPO / "chip_smoke_out" / "report.json").read_text())
+    assert report["repeat_cached_tokens"] > 0
+    assert report["radix_index"] == report["radix_index_built"]
+    assert report["startup"]["aggregated"]["warmup_phases"]
+
+
+def test_without_the_cpu_argument_a_missing_tpu_is_a_failure():
+    out = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=REPO, capture_output=True,
+        text=True, timeout=600, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode != 0
+    assert out.stdout.strip() == ""  # no result of any kind
+
+
+@pytest.mark.parametrize(
+    "phase", ["worker-start", "bad-request", "kernel-mismatch"]
+)
+def test_a_broken_phase_fails_the_run(phase):
+    out = _smoke("--cpu-tiny", "--inject", phase)
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+    assert "FAILED" in out.stderr

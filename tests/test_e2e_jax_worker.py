@@ -152,7 +152,7 @@ async def test_jax_worker_completion_e2e():
 
 async def test_jax_worker_tp_dp_sharded_e2e():
     """HTTP → router → TP×DP-sharded EngineCore on the virtual CPU mesh,
-    greedy-identical to the unsharded engine (VERDICT #1 done-criterion)."""
+    greedy-identical to the unsharded engine."""
     async with JaxCluster(tp=2, dp=2) as c:
         async with aiohttp.ClientSession() as s:
             out = await _chat(s, c.base_url, "sharded hello", max_tokens=6)
@@ -182,7 +182,7 @@ async def test_jax_worker_sequence_parallel_serving_e2e():
     """A deployed worker can enable ring prefill (--sp) without touching
     test code: HTTP -> router -> EngineCore with a sequence-parallel mesh,
     long prompt takes the dense ring-attention path, output greedy-
-    identical to the unsharded engine (VERDICT r5 #4: sequence-parallel
+    identical to the unsharded engine (sequence-parallel
     serving must be reachable from the service, not just tests)."""
     # Long enough to clear the ring threshold once chat-templated; the
     # tiny engine's largest bucket is 128 so it must stay under that.
@@ -247,7 +247,7 @@ async def test_jax_worker_pipeline_parallel_serving_e2e():
     """A deployed worker can enable pipeline parallelism (--pp) from the
     CLI surface: HTTP -> router -> EngineCore on a pp=2 mesh (GPipe
     prefill + wavefront decode), greedy-identical to the unsharded
-    engine (the row-58 lesson from VERDICT r4: a parallel mode only
+    engine (a parallel mode only
     tests can construct does not count as implemented)."""
     async with JaxCluster(pp=2) as c:
         async with aiohttp.ClientSession() as s:

@@ -400,7 +400,7 @@ def test_import_blocks_direct_skips_cached_and_accounts():
 
 def test_import_blocks_partial_drop_is_accounted():
     """Allocator exhaustion mid-import drops the tail blocks and the
-    stats record it (VERDICT r4 weak #7: 'transfer worked' vs 'transfer
+    stats record it ('transfer worked' vs 'transfer
     half-dropped' must be distinguishable)."""
     prompt = list(range(1, 41))
     p_core = make_core()

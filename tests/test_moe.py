@@ -128,7 +128,7 @@ def test_sparse_dispatch_matches_dense_reference():
 
 def test_sparse_dispatch_flops_scale_with_top_k_not_num_experts():
     """Per-token expert-MLP FLOPs must follow top_k (x capacity factor),
-    not num_experts — the point of sparse dispatch (VERDICT r3 #9)."""
+    not num_experts — the point of sparse dispatch."""
     import dataclasses
 
     cfg = dataclasses.replace(
@@ -167,8 +167,8 @@ def test_capacity_overflow_drops_tokens_not_correctness():
 def test_alltoall_dispatch_matches_replicated_and_dense():
     """Token all-to-all EP dispatch (wide-EP mode, cfg.moe_dispatch=
     'alltoall') equals the replicated-dispatch path AND the dense
-    reference on the same mesh with generous capacity (VERDICT r5 #7:
-    both dispatch modes, identical outputs)."""
+    reference on the same mesh with generous capacity (both dispatch
+    modes, identical outputs)."""
     import dataclasses
 
     cfg = dataclasses.replace(

@@ -46,7 +46,7 @@ def _spawn(argv, **env_over):
 @pytest.mark.slow
 def test_two_process_mesh_matches_single_device(tmp_path):
     """2 processes x 4 CPU devices -> one dp=2 x tp=4 mesh; greedy tokens
-    must equal the single-device engine's (VERDICT r5 #2 done-bar)."""
+    must equal the single-device engine's."""
     coord = f"127.0.0.1:{_free_port()}"
     outs = [tmp_path / "r0.json", tmp_path / "r1.json"]
     procs = [

@@ -123,7 +123,7 @@ async def _mm_chat(session, base_url, img_url, text="describe ", max_tokens=6):
 async def test_multimodal_e2e_local_encode():
     """Chat with an image_url through the full stack (no encoder fleet:
     the worker encodes in-process). Different images yield different
-    tokens; a repeated image prefix-hits (VERDICT r5 #6 done-bar)."""
+    tokens; a repeated image prefix-hits."""
     from tests.test_e2e_jax_worker import JaxCluster
 
     async with JaxCluster() as c:

@@ -128,7 +128,7 @@ async def test_peer_pull_avoids_recompute_after_offload(tmp_path):
     """Worker A caches a prompt, overflows it down to its offload tiers;
     a request EXCLUDED from A (migration semantics) lands on B, which
     pulls the prefix from A's tiers and prefix-hits instead of
-    recomputing (VERDICT r5 #8 done-bar)."""
+    recomputing."""
     prompt = list(range(1, 90))  # 11 complete 8-token blocks
     async with PeerCluster(tmp_path) as c:
         served = c.service.manager.get("peer")

@@ -121,7 +121,7 @@ async def test_client_survives_store_restart():
     """Store restart: the client reconnects with backoff, re-attaches its
     lease under the SAME id (worker identity embeds it), replays
     lease-bound registrations, and resumes subscriptions + watches
-    (VERDICT r3 weak #9 — the reference gets this from etcd/NATS client
+    (the reference gets this from etcd/NATS client
     libraries; this store's client owns it)."""
     import asyncio
 

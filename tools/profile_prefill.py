@@ -2,13 +2,12 @@
 
 Times the engine's ragged prefill program (forward_tokens + fused
 sampling) at bench shapes — bucket 2048, 16 sequences of 128 tokens —
-and compares against the compute/bandwidth floor. Decode got three
-rounds of profiling (PERF.md); TTFT p50 (~570-870 ms across bench
-configs) was never attributed. At 1B, a 2048-token wave is ~5.1 TFLOP
-(~26 ms at v5e bf16 peak) + one weight stream (~3 ms) — anything far
-above that is overhead to find.
+and compares against the compute/bandwidth floor from the device's
+published peaks. At 1B, a 2048-token wave is ~5.1 TFLOP (~26 ms at the
+v5e bf16 peak) + one weight stream (~3 ms) — anything far above that is
+overhead to find.
 
-Usage: python tools/profile_prefill.py [--bucket 2048] [--seqs 16]
+Usage: python -m tools.profile_prefill [--bucket 2048] [--seqs 16]
 """
 
 from __future__ import annotations
@@ -32,6 +31,15 @@ def main():
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--no-attn", action="store_true")
     args = ap.parse_args()
+
+    from dynamo_tpu.device import (
+        device_peaks,
+        enable_compile_cache,
+        require_accelerator,
+    )
+
+    enable_compile_cache()
+    device = require_accelerator("tools/profile_prefill.py")
 
     cfg = llama3_1b()
     T, S = args.bucket, args.seqs
@@ -90,21 +98,20 @@ def main():
             tables, cu, num_seqs, last_rows, cfg, eng, None,
         )
         # Sample on device like the engine's fused program: the host
-        # fetch is [S] ints, not [S, V] logits (8 MB of logits over the
-        # relay's ~MB/s host link would dominate the measurement).
+        # fetch is [S] ints, not [S, V] logits.
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), c
 
     fwd = jax.jit(wave, donate_argnums=(1,))
 
     cache = init_cache(cfg, eng)
     toks, cache = fwd(params, cache, tokens)
-    np.asarray(toks)  # compile + sync
+    jax.block_until_ready(toks)  # compile + sync
 
     times = []
     for _ in range(args.reps):
         t0 = time.perf_counter()
         toks, cache = fwd(params, cache, tokens)
-        np.asarray(toks)
+        jax.block_until_ready(toks)
         times.append(time.perf_counter() - t0)
     times.sort()
 
@@ -113,15 +120,16 @@ def main():
     h, i = cfg.hidden_size, cfg.intermediate_size
     per_layer = h * (cfg.q_size + 2 * cfg.kv_size) + cfg.q_size * h + 3 * h * i
     flops = 2 * T * cfg.num_layers * per_layer + 2 * S * h * cfg.vocab_size
-    peak = 197e12  # v5e bf16
-    hbm = 819e9
-    floor_flops = flops / peak * 1e3
-    floor_bw = cfg.param_bytes() / hbm * 1e3
-    print(
-        f"# bucket={T} seqs={S} per={per}: "
-        f"flops {flops/1e12:.2f} TF -> {floor_flops:.1f} ms MXU floor, "
-        f"weights {floor_bw:.1f} ms HBM floor"
-    )
+    print(f"# device={device} bucket={T} seqs={S} per={per}: "
+          f"flops {flops/1e12:.2f} TF")
+    if device["platform"] == "tpu":
+        peaks = device_peaks(device["kind"])
+        print(
+            f"# floors from published peaks: "
+            f"{flops / (peaks.bf16_tflops * 1e12) * 1e3:.1f} ms MXU, "
+            f"{cfg.param_bytes() / (peaks.hbm_gbps * 1e9) * 1e3:.1f} ms "
+            "weight stream"
+        )
     print(
         f"prefill wave: best {times[0]*1e3:.1f} ms, "
         f"median {times[len(times)//2]*1e3:.1f} ms "
