@@ -1,0 +1,106 @@
+"""BENCHMARK.json and the files it names, against the contract."""
+
+import copy
+import json
+from pathlib import Path
+
+import pytest
+
+from chipbench import manifest
+from chipbench.configs import load_config, model_fields
+
+ROOT = Path(__file__).resolve().parents[2]
+TINY = ROOT / "tests" / "chipbench" / "data" / "tiny_manifest.json"
+
+
+@pytest.fixture(scope="module")
+def man():
+    return manifest.load()
+
+
+@pytest.mark.parametrize("path", [None, TINY], ids=["BENCHMARK.json", "tiny"])
+def test_manifest_is_sound(path):
+    assert manifest.problems(manifest.load(path)) == []
+
+
+def test_file_is_small_and_command_stays_inside_paths(man):
+    assert (ROOT / "BENCHMARK.json").stat().st_size < 64 * 1024
+    assert man["command"] == ["python3", "-m", "chipbench.run"]
+    assert all(not w.startswith("/") and ".." not in w for w in man["command"])
+
+
+@pytest.mark.parametrize("kind", ["end_to_end", "per_layer"])
+def test_every_metric_has_a_reader_module(man, kind):
+    import importlib
+
+    for m in man[kind]:
+        spec = json.loads(manifest.metric_file(kind, m["name"]).read_text())
+        module = importlib.import_module(f"chipbench.readers.{spec['reader']}")
+        assert callable(module.read), m["name"]
+        assert spec["doc"]
+
+
+def test_layer_metric_moves_a_metric_its_cells_report(man):
+    for m in man["per_layer"]:
+        for cell in m.get("workloads", [w["name"] for w in man["workloads"]]):
+            reported = {e["name"] for e in manifest.metrics_of(man, "end_to_end", cell)}
+            assert m["moves"] in reported, (m["name"], cell)
+
+
+def test_layers_are_spelled_one_way(man):
+    layers = {m["layer"] for m in man["per_layer"]}
+    assert layers == {"load generator", "frontend", "scheduler",
+                      "device programs", "kernels", "device", "set-up",
+                      "demoted end-to-end"}
+
+
+def test_a_suffixed_metric_reads_with_its_base_names_file():
+    base = manifest.metric_file("per_layer", "queue_wait_ms_mean")
+    assert manifest.metric_file("per_layer", "queue_wait_ms_mean.chat") == base
+    assert manifest.metric_file("per_layer", "device_idle_share.batch").name == (
+        "device_idle_share.json")
+    own = manifest.metric_file("per_layer", "open_loop_ttft_ms_p90")
+    assert own.name == "open_loop_ttft_ms_p90.json" and own.exists()
+    assert not manifest.metric_file("per_layer", "nobody.reads_me").exists()
+
+
+@pytest.mark.parametrize("chips,topology", [(1, "one-worker"), (4, "four-replicas-kv-router")])
+def test_topology_follows_from_the_chips(chips, topology):
+    assert manifest.topology_of({"name": "any", "chips": chips}) == topology
+
+
+@pytest.mark.parametrize("fault,needle", [
+    (lambda m: m["workloads"][0].update(name="has space"), "is not a name"),
+    (lambda m: m["end_to_end"][1].update(unit="tokens per second"), "unit"),
+    (lambda m: m["end_to_end"][1].update(bound=0.2), "bound"),
+    (lambda m: m["per_layer"][0].update(moves="tpot_ms_p50"), "does not report"),
+    (lambda m: m["workloads"][0].update(traffic="no-such-mix"), "no traffic file"),
+    (lambda m: m["workloads"][0].update(topology="one-worker"), "keys"),
+    (lambda m: m["per_layer"][0].update(why="because"), "keys"),
+    (lambda m: m.update(metrics=[]), "not exactly"),
+    (lambda m: m["end_to_end"].pop(0), "no setup_s"),
+    (lambda m: m["workloads"][0].update(chips=2), "chips"),
+    (lambda m: [w.update(chips=4) for w in m["workloads"]], "four-chip"),
+    (lambda m: m["per_layer"].append(dict(m["per_layer"][0], name="nobody_reads_me")),
+     "no reader file"),
+])
+def test_faults_are_found(man, fault, needle):
+    broken = copy.deepcopy(man)
+    fault(broken)
+    assert any(needle in p for p in manifest.problems(broken)), manifest.problems(broken)
+
+
+@pytest.mark.parametrize("name,params_b", [
+    ("qwen2.5-7b-int8", 7.6e9), ("qwen2.5-1.5b-bf16", 1.54e9)])
+def test_configurations_keep_published_widths_and_depth(name, params_b):
+    cfg = load_config(name)
+    assert cfg["reduced"] == [] and cfg["source"].startswith("https://huggingface.co/Qwen/")
+    assert cfg["num_hidden_layers"] == 28 and cfg["assumed"]
+    mf = model_fields(cfg)
+    assert mf["head_dim"] == 128 and mf["attn_qkv_bias"] is True
+    from dynamo_tpu.engine import ModelConfig
+
+    model = ModelConfig(**mf)
+    assert model.param_bytes() / 2 == pytest.approx(params_b, rel=0.02)
+    entry = next(c for c in manifest.load()["configs"] if c["name"] == name)
+    assert entry["source"] == cfg["source"] and entry["reduced"] == cfg["reduced"]
