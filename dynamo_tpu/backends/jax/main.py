@@ -558,6 +558,7 @@ async def run_jax_worker(
     # rate, ...) — evaluated at scrape time against the live core.
     from dynamo_tpu.runtime.status_server import (
         bind_disagg_gauges,
+        bind_engine_counters,
         bind_fair_queue_gauges,
         bind_kv_cache_gauges,
         bind_kv_pool_gauges,
@@ -572,6 +573,9 @@ async def run_jax_worker(
     # cached discovery state.
     bind_store_gauges(runtime.status, runtime.store)
     bind_scheduler_gauges(runtime.status, core.scheduler_stats)
+    bind_engine_counters(
+        runtime.status, core.step_phase_seconds, core.scheduler_stats
+    )
     bind_spec_gauges(runtime.status, core.spec_decode_stats)
     bind_kv_cache_gauges(runtime.status, core.kv_cache_stats)
     bind_fair_queue_gauges(runtime.status, core.fair_queue_stats)

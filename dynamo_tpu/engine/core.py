@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dynamo_tpu import tracing
+from dynamo_tpu.tracing.stepclock import PHASES, StepClock
 from dynamo_tpu.engine.block_allocator import DeviceBlockAllocator, OutOfBlocksError
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
 from dynamo_tpu.engine.fair_queue import FairQueue
@@ -202,16 +203,23 @@ class _PendingFetch:
     def land_aux(self):
         """Land the side-channel int array (device-draft round
         accounting); call only after construction with ``aux``."""
-        return fetch_replicated(self.aux)  # dynalint: sync-ok — double-buffered landing point
+        self.core.clock.mark("land")
+        aux = fetch_replicated(self.aux)  # dynalint: sync-ok — double-buffered landing point
+        self.core.clock.mark("commit")
+        return aux
 
     def land(self):
         core = self.core
         if core._exec_log is not None:
             core._exec_log.append(("land", self.no))
+        # The blocking fetch is the step clock's ``land`` phase (the
+        # commit wrapper opened it; a merged plan's later parts reopen it).
+        core.clock.mark("land")
         toks = fetch_replicated(self.toks)  # dynalint: sync-ok — double-buffered landing point
         lps = self.lps
         if lps is not None:
             lps = tuple(fetch_replicated_many(lps))  # dynalint: sync-ok — batched logprob landing
+        core.clock.mark("commit")
         if self.sr is not None:
             # fetch_replicated already landed host np arrays; reshape to
             # the legacy 2-D ([S, R], [S, R, ...]) sample-width views.
@@ -263,14 +271,13 @@ class _PlannedStep:
         if self.committed:
             return []
         self.committed = True
-        t0 = time.time()
-        out = self.commit_fn()
         core = self.core
+        t0 = core.clock.mark("land")
+        out = self.commit_fn()
+        core.clock.mark("commit")  # a boundary only where nothing was landed
         core.exec_stats["commits"] += 1
-        core._tracer.record(
-            "engine_commit", t0, time.time(),
-            attrs={"outputs": len(out)}, stat=True,
-        )
+        # From the landing's start to the step clock's next boundary.
+        core.clock.close_at_next("engine_commit", t0, {"outputs": len(out)})
         return out
 
 
@@ -373,17 +380,18 @@ def _megastep_body(
         logits, cache = decode_tokens(
             params, cache, toks, block_tables, pos, act, cfg, engine, mesh,
         )
-        nxt = sample_seeded(
-            logits, seeds, counters + i, temperature, top_k, top_p,
-            need_mask=need_mask, all_greedy=all_greedy,
-        )
-        # Dead lanes pad the output with their last live token — a
-        # deterministic, pinnable value (the host stop-scan resolves the
-        # repeated stop id to the same stop position).
-        out_tok = jnp.where(act, nxt, toks)
-        lp = token_logprobs(logits, out_tok) if want_logprobs else None
-        alive = alive & ~stop_flags(nxt, watch, budgets, min_left, i)
-        pos = pos + act.astype(jnp.int32)
+        with jax.named_scope("sample"):
+            nxt = sample_seeded(
+                logits, seeds, counters + i, temperature, top_k, top_p,
+                need_mask=need_mask, all_greedy=all_greedy,
+            )
+            # Dead lanes pad the output with their last live token — a
+            # deterministic, pinnable value (the host stop-scan resolves
+            # the repeated stop id to the same stop position).
+            out_tok = jnp.where(act, nxt, toks)
+            lp = token_logprobs(logits, out_tok) if want_logprobs else None
+            alive = alive & ~stop_flags(nxt, watch, budgets, min_left, i)
+            pos = pos + act.astype(jnp.int32)
         return (out_tok, cache, alive, pos), (out_tok, lp)
 
     (_, cache, _, _), (sampled, lps) = jax.lax.scan(
@@ -442,21 +450,22 @@ def _megastep_fused_body(
         mm_embeds=mm_embeds if want_mm else None,
         mm_mask=mm_mask if want_mm else None,
     )
-    t0 = sample_seeded(
-        logits, seeds_r, counters_r, temp_r, top_k_r, top_p_r,
-        need_mask=need_mask, all_greedy=all_greedy,
-    )
-    lp0 = token_logprobs(logits, t0) if want_logprobs else None
-    S = draft.shape[0]
-    R = t0.shape[0] // S
-    t0s = t0.reshape(S, R)
-    acc, cur = resolve_verify(t0s, draft, draft_len)
-    alive0 = cont_active & ~stop_flags_prefix(
-        t0s, acc, watch, budgets, min_left
-    )
-    gen0 = jnp.where(cont_active, acc + 1, 0)   # tokens iteration 0 produced
-    pos0 = base_pos + acc                       # next write position
-    counters0 = counters_r.reshape(S, R)[:, 0]  # per-lane generated base
+    with jax.named_scope("sample"):
+        t0 = sample_seeded(
+            logits, seeds_r, counters_r, temp_r, top_k_r, top_p_r,
+            need_mask=need_mask, all_greedy=all_greedy,
+        )
+        lp0 = token_logprobs(logits, t0) if want_logprobs else None
+        S = draft.shape[0]
+        R = t0.shape[0] // S
+        t0s = t0.reshape(S, R)
+        acc, cur = resolve_verify(t0s, draft, draft_len)
+        alive0 = cont_active & ~stop_flags_prefix(
+            t0s, acc, watch, budgets, min_left
+        )
+        gen0 = jnp.where(cont_active, acc + 1, 0)   # tokens iteration 0 produced
+        pos0 = base_pos + acc                       # next write position
+        counters0 = counters_r.reshape(S, R)[:, 0]  # per-lane generated base
 
     def body(carry, _):
         tok, cache, alive, pos, gen = carry
@@ -464,18 +473,19 @@ def _megastep_fused_body(
         logits, cache = decode_tokens(
             params, cache, tok, block_tables, pos, act, cfg, engine, mesh,
         )
-        nxt = sample_seeded(
-            logits, seeds, counters0 + gen, temp, top_k, top_p,
-            need_mask=need_mask, all_greedy=all_greedy,
-        )
-        out_tok = jnp.where(act, nxt, tok)
-        lp = token_logprobs(logits, out_tok) if want_logprobs else None
-        g = gen + act.astype(jnp.int32)
-        stop = ((nxt[:, None] == watch).any(axis=1) & (g >= min_left)) | (
-            g >= budgets
-        )
-        alive = alive & ~stop
-        pos = pos + act.astype(jnp.int32)
+        with jax.named_scope("sample"):
+            nxt = sample_seeded(
+                logits, seeds, counters0 + gen, temp, top_k, top_p,
+                need_mask=need_mask, all_greedy=all_greedy,
+            )
+            out_tok = jnp.where(act, nxt, tok)
+            lp = token_logprobs(logits, out_tok) if want_logprobs else None
+            g = gen + act.astype(jnp.int32)
+            stop = ((nxt[:, None] == watch).any(axis=1) & (g >= min_left)) | (
+                g >= budgets
+            )
+            alive = alive & ~stop
+            pos = pos + act.astype(jnp.int32)
         return (out_tok, cache, alive, pos, g), (out_tok, lp)
 
     (_, cache, _, _, _), (rest, rest_lp) = jax.lax.scan(
@@ -667,11 +677,12 @@ def _ring_prefill_and_sample(
         params, cache, tokens, write_pages, write_offs, last_row,
         cfg, engine, sp_mesh,
     )
-    toks = sample_seeded(
-        logits, seeds, counters, temperature, top_k, top_p,
-        need_mask=need_mask, all_greedy=all_greedy,
-    )
-    lps = token_logprobs(logits, toks) if want_logprobs else None
+    with jax.named_scope("sample"):
+        toks = sample_seeded(
+            logits, seeds, counters, temperature, top_k, top_p,
+            need_mask=need_mask, all_greedy=all_greedy,
+        )
+        lps = token_logprobs(logits, toks) if want_logprobs else None
     return toks, lps, cache
 
 
@@ -694,11 +705,12 @@ def _prefill_and_sample(
         mm_embeds=mm_embeds if want_mm else None,
         mm_mask=mm_mask if want_mm else None,
     )
-    toks = sample_seeded(
-        logits, seeds, counters, temperature, top_k, top_p,
-        need_mask=need_mask, all_greedy=all_greedy,
-    )
-    lps = token_logprobs(logits, toks) if want_logprobs else None
+    with jax.named_scope("sample"):
+        toks = sample_seeded(
+            logits, seeds, counters, temperature, top_k, top_p,
+            need_mask=need_mask, all_greedy=all_greedy,
+        )
+        lps = token_logprobs(logits, toks) if want_logprobs else None
     return _replicate_out(toks, mesh), _replicate_out(lps, mesh), cache
 
 
@@ -719,11 +731,12 @@ def _pp_prefill_and_sample(
         mb_kv_lens, block_tables, mb_cu, num_seqs, mb_last_local,
         mb_last_mask, cfg=cfg, engine=engine, mesh=pp_mesh, n_micro=n_micro,
     )
-    toks = sample_seeded(
-        logits, seeds, counters, temperature, top_k, top_p,
-        need_mask=need_mask, all_greedy=all_greedy,
-    )
-    lps = token_logprobs(logits, toks) if want_logprobs else None
+    with jax.named_scope("sample"):
+        toks = sample_seeded(
+            logits, seeds, counters, temperature, top_k, top_p,
+            need_mask=need_mask, all_greedy=all_greedy,
+        )
+        lps = token_logprobs(logits, toks) if want_logprobs else None
     return (
         _replicate_out(toks, pp_mesh), _replicate_out(lps, pp_mesh), cache
     )
@@ -1300,10 +1313,14 @@ class EngineCore:
         self.enforce_deadlines = True
         self._max_waiting = engine_cfg.max_waiting
         self.iterations = 0
-        # Step-level spans (engine_prefill_step / engine_decode_step with
+        # Step-level stat spans (engine_megastep, engine_mixed_step, ... with
         # token counts). record() on a disabled tracer is a no-op, and the
         # collector's deque.append is atomic — safe from the engine thread.
         self._tracer = tracing.get_tracer("engine")
+        # One clock for the step loop: every phase boundary of step() is
+        # one reading of it (counters on /metrics, ``engine/<phase>``
+        # annotations in a profile — tracing/stepclock.py).
+        self.clock = StepClock(self._tracer)
         # Queue-wait stat spans live under their own service so the
         # request-waterfall sched_admit twin (TpuEngine, service
         # "engine") doesn't double-count the histogram series.
@@ -1383,6 +1400,17 @@ class EngineCore:
             # those pay the fill/drain bubble PER TOKEN).
             "pp_fused_dispatches": 0,
             "pp_forced_single": 0,
+            # Occupancy (ISSUE 24): live against padded lanes per decode
+            # dispatch and real against bucket tokens per ragged (prefill
+            # / mixed) dispatch, counted where the batch is built; lane-
+            # iterations a decode megastep issued against those that gave
+            # a client a token, counted where the chain is committed.
+            "decode_live_lanes": 0,
+            "decode_padded_lanes": 0,
+            "megastep_issued_lane_iters": 0,
+            "megastep_useful_lane_iters": 0,
+            "ragged_real_tokens": 0,
+            "ragged_bucket_tokens": 0,
         }
         # Crash/stall flight recorder (ISSUE 13): one record per step
         # with outputs — step shape, lane cursors, cumulative dispatch
@@ -1398,7 +1426,10 @@ class EngineCore:
         # the landing of step n's outputs in steady-state decode.
         self._exec_log: list[tuple[str, int]] | None = None
         self._dispatch_no = 0
-        self._t_prev_dispatch = 0.0
+        # Step-clock readings (ns) of the newest and the previous
+        # ``dispatch`` mark; 0 breaks the host_gap chain.
+        self._t_dispatch = 0
+        self._t_prev_dispatch = 0
         # Admission-time prefix-cache accounting (kv_prefix_cache_admitted_*
         # gauges). Separate from the allocator's match_prefix counters:
         # those count router/disagg probes, these count admitted sequences
@@ -1671,13 +1702,15 @@ class EngineCore:
         two track the same bottleneck but are not numerically comparable."""
         self._dispatch_no += 1
         self.exec_stats["dispatches"] += 1
-        now = time.time()
+        # Both ends are the step clock's readings at ``dispatch`` marks.
+        now = self._t_dispatch
         if self._t_prev_dispatch:
             self.exec_stats["last_host_gap_ms"] = (
-                (now - self._t_prev_dispatch) * 1e3
+                (now - self._t_prev_dispatch) * 1e-6
             )
             self._tracer.record(
-                "host_gap", self._t_prev_dispatch, now,
+                "host_gap", self.clock.wall_s(self._t_prev_dispatch),
+                self.clock.wall_s(now),
                 attrs={
                     "dispatch": self._dispatch_no,
                     "overlapped": self._inflight is not None,
@@ -1688,6 +1721,19 @@ class EngineCore:
         if self._exec_log is not None:
             self._exec_log.append(("dispatch", self._dispatch_no))
         return self._dispatch_no
+
+    def _mark_dispatch(
+        self, kind: str, lanes: int, width: int, k: int, real: int, padded: int,
+    ) -> None:
+        """Open the step clock's ``dispatch`` phase (the jitted call, until
+        it returns). Its profile annotation carries the dispatch's shape:
+        kind (prefill / decode / megastep / mixed), live lanes against
+        the padded width, fused iterations, real against padded tokens,
+        and whether a step was in flight when it was enqueued."""
+        self._t_dispatch = self.clock.mark(
+            "dispatch", kind=kind, lanes=lanes, width=width, k=k,
+            real=real, padded=padded, pipelined=self._inflight is not None,
+        )
 
     def _bucket_for(self, n: int) -> int:
         """Token-budget bucket: total ragged tokens in a prefill wave."""
@@ -1978,6 +2024,9 @@ class EngineCore:
         bs = self.engine.block_size
         total = sum(len(tl) for _, tl, _, _ in rows)
         T = self._bucket_for(total)
+        # Bucket fill: real tokens against the padded bucket they ride in.
+        self.exec_stats["ragged_real_tokens"] += total
+        self.exec_stats["ragged_bucket_tokens"] += T
         R = (
             self._spec_R
             if force_R
@@ -2088,6 +2137,7 @@ class EngineCore:
         self, rows: list[tuple[Sequence, list[int], int, int]], S: int,
         n_sample: list[int] | None = None,
         feed_rows: list[int | None] | None = None,
+        kind: str = "prefill",
     ) -> _PendingFetch:
         """Assemble and run ONE ragged forward + fused sampling over
         arbitrary rows. Each row is ``(seq, tokens, pos_start, kv_len)``:
@@ -2116,7 +2166,9 @@ class EngineCore:
 
         Returns a :class:`_PendingFetch`; ``land()`` yields the legacy
         shapes — 2-D ([S, R] tokens, [S, R, ...] logprobs) with
-        ``n_sample``, 1-D without."""
+        ``n_sample``, 1-D without. ``kind`` names the dispatch on the
+        step clock's annotation (a prefill wave, or a mixed step)."""
+        self.clock.mark("assemble")
         b = self._assemble_ragged(rows, S, n_sample, feed_rows)
         R = b.R
         tokens, positions = b.tokens, b.positions
@@ -2138,6 +2190,7 @@ class EngineCore:
                 len(rows), last_rows, self._pp_micro,
                 self.engine.garbage_block,
             )
+            self.clock.mark("h2d")
             mb_tok = jnp.asarray(plan.tokens)
             if feed_idx is not None:
                 # Device-resident feedback under pp: the microbatch plan
@@ -2151,9 +2204,7 @@ class EngineCore:
                     self._inflight.feed_tokens, mb_tok.reshape(-1),
                     jnp.asarray(fi),
                 ).reshape(plan.tokens.shape)
-            toks, lps, self.cache = self._prefill_pp(
-                self.params,
-                self.cache,
+            args = (
                 mb_tok,
                 jnp.asarray(plan.positions),
                 jnp.asarray(plan.write_pages),
@@ -2169,6 +2220,12 @@ class EngineCore:
                 jnp.asarray(temp),
                 jnp.asarray(top_k),
                 jnp.asarray(top_p),
+            )
+            self._mark_dispatch(kind, len(rows), S, 1, int(cu[len(rows)]), b.T)
+            toks, lps, self.cache = self._prefill_pp(
+                self.params,
+                self.cache,
+                *args,
                 need_mask=need_mask and not all_greedy,
                 all_greedy=all_greedy,
                 want_logprobs=want_lp,
@@ -2179,6 +2236,7 @@ class EngineCore:
             # fused sampler treats them as S*R independent lanes (with
             # R == 1 these are bit-for-bit the legacy shapes, so the
             # no-speculation program cache is untouched).
+            self.clock.mark("h2d")
             tok_in = jnp.asarray(tokens)
             if feed_idx is not None:
                 # Device-resident feedback: override the placeholder slots
@@ -2187,9 +2245,7 @@ class EngineCore:
                 tok_in = self._feed(
                     self._inflight.feed_tokens, tok_in, jnp.asarray(feed_idx)
                 )
-            toks, lps, self.cache = self._prefill(
-                self.params,
-                self.cache,
+            args = (
                 tok_in,
                 jnp.asarray(positions),
                 jnp.asarray(write_pages),
@@ -2206,11 +2262,18 @@ class EngineCore:
                 jnp.asarray(np.repeat(top_p, R)),
                 jnp.asarray(mm_embeds),
                 jnp.asarray(mm_mask),
+            )
+            self._mark_dispatch(kind, len(rows), S, 1, int(cu[len(rows)]), b.T)
+            toks, lps, self.cache = self._prefill(
+                self.params,
+                self.cache,
+                *args,
                 need_mask=need_mask and not all_greedy,
                 all_greedy=all_greedy,
                 want_logprobs=want_lp,
                 want_mm=want_mm,
             )
+        self.clock.mark("plan")
         self.exec_stats["single_step_dispatches"] += 1
         return _PendingFetch(
             self, toks, lps, sr=(S, R) if n_sample is not None else None
@@ -2252,7 +2315,10 @@ class EngineCore:
         then also carries the [3, n_steps, S] per-round accounting
         (``land_aux``)."""
         use_dd = device is not None and any(device)
+        self.clock.mark("assemble")
         b = self._assemble_ragged(rows, S, n_sample, feed_rows, force_R=use_dd)
+        self.exec_stats["decode_live_lanes"] += len(rows)
+        self.exec_stats["decode_padded_lanes"] += S
         R = b.R
         W = MEGASTEP_WATCH_W
         draft = np.full((S, R - 1), -1, np.int32)
@@ -2280,20 +2346,14 @@ class EngineCore:
                 draft[i, : len(d)] = d
                 draft_len[i] = len(d)
             self._arm_stop_inputs(seq, i, watch, budgets, min_left)
-        tok_in = jnp.asarray(b.tokens)
-        if b.feed_idx is not None:
-            tok_in = self._feed(
-                self._inflight.feed_tokens, tok_in, jnp.asarray(b.feed_idx)
-            )
         if use_dd:
             return self._dispatch_drafted(
-                rows, b, device, tok_in, draft, draft_len, cont_a,
+                rows, b, device, draft, draft_len, cont_a,
                 base_pos, watch, budgets, min_left, n_steps, kinds,
             )
-        out, lps, self.cache = self._fused(
-            self.params,
-            self.cache,
-            tok_in,
+        self.clock.mark("h2d")
+        args = (
+            self._fused_tokens(b),
             jnp.asarray(b.positions),
             jnp.asarray(b.write_pages),
             jnp.asarray(b.write_offs),
@@ -2320,12 +2380,19 @@ class EngineCore:
             jnp.asarray(watch),
             jnp.asarray(budgets),
             jnp.asarray(min_left),
+        )
+        self._mark_fused_dispatch(rows, S, b, kinds, n_steps)
+        out, lps, self.cache = self._fused(
+            self.params,
+            self.cache,
+            *args,
             n_steps=n_steps,
             need_mask=b.need_mask and not b.all_greedy,
             all_greedy=b.all_greedy,
             want_logprobs=b.want_lp,
             want_mm=b.want_mm,
         )
+        self.clock.mark("plan")
         self.exec_stats["megastep_dispatches"] += 1
         if any(k != "d" for k in kinds):
             # Count as MIXED only when the dispatch actually carried
@@ -2336,12 +2403,27 @@ class EngineCore:
             self.exec_stats["fused_mixed_dispatches"] += 1
         return _PendingFetch(self, out, lps)  # [n_steps, S, R] on land()
 
+    def _fused_tokens(self, b: "_RaggedBatch") -> jax.Array:
+        """The ragged token buffer on device, in-flight lanes' placeholders
+        overridden by the previous dispatch's sampled ids."""
+        tok_in = jnp.asarray(b.tokens)
+        if b.feed_idx is not None:
+            tok_in = self._feed(
+                self._inflight.feed_tokens, tok_in, jnp.asarray(b.feed_idx)
+            )
+        return tok_in
+
+    def _mark_fused_dispatch(self, rows, S: int, b, kinds, n_steps: int) -> None:
+        self._mark_dispatch(
+            "mixed" if any(k != "d" for k in kinds) else "megastep",
+            len(rows), S, n_steps, int(b.cu[len(rows)]), b.T,
+        )
+
     def _dispatch_drafted(
         self,
         rows: list[tuple[Sequence, list[int], int, int]],
         b,
         device: list[bool],
-        tok_in,
         draft,
         draft_len,
         cont_a,
@@ -2412,6 +2494,7 @@ class EngineCore:
             if L:
                 hist[i, H - take - L: H - take] = ctx
             hlen[i] = min(L + take, H)
+        self.clock.mark("h2d")
         hist_in = jnp.asarray(hist)
         if ring_src is not None:
             hist_in = self._feed(
@@ -2419,10 +2502,8 @@ class EngineCore:
                 hist_in.reshape(-1),
                 jnp.asarray(ring_src.reshape(-1)),
             ).reshape(S, H)
-        out, aux, lps, self.cache = self._drafted(
-            self.params,
-            self.cache,
-            tok_in,
+        args = (
+            self._fused_tokens(b),
             jnp.asarray(b.positions),
             jnp.asarray(b.write_pages),
             jnp.asarray(b.write_offs),
@@ -2456,12 +2537,19 @@ class EngineCore:
             jnp.asarray(nmin),
             jnp.asarray(nmax),
             jnp.asarray(kmax),
+        )
+        self._mark_fused_dispatch(rows, S, b, kinds, n_steps)
+        out, aux, lps, self.cache = self._drafted(
+            self.params,
+            self.cache,
+            *args,
             n_steps=n_steps,
             need_mask=b.need_mask and not b.all_greedy,
             all_greedy=b.all_greedy,
             want_logprobs=b.want_lp,
             want_mm=b.want_mm,
         )
+        self.clock.mark("plan")
         self.exec_stats["megastep_dispatches"] += 1
         if any(k != "d" for k in kinds):
             self.exec_stats["fused_mixed_dispatches"] += 1
@@ -2526,14 +2614,6 @@ class EngineCore:
                 outputs.append((seq, self._emit(seq, tok, lp)))
                 if seq.finish is not None:
                     self._finish(seq)
-            self._tracer.record(
-                "engine_prefill_step", t_disp, time.time(),
-                attrs={
-                    "seqs": len(chosen),
-                    "tokens": sum(chunk for _, _, chunk in chosen),
-                },
-                stat=True,
-            )
             return outputs
 
         return _PlannedStep(
@@ -2603,6 +2683,7 @@ class EngineCore:
 
     # dynalint: holds-lock(_step_lock) — synchronous ring path inside the step
     def _run_ring_prefill(self, seq: Sequence, T: int):
+        self.clock.mark("assemble")
         self._mark_first_sched(seq, time.time())
         bs = self.engine.block_size
         P_len = seq.prompt_len
@@ -2616,9 +2697,8 @@ class EngineCore:
         want_lp = seq.logprobs is not None
         all_greedy = seq.sampling.temperature == 0.0
         need_mask = seq.sampling.top_k > 0 or seq.sampling.top_p < 1.0
-        toks, lps, self.cache = self._ring(
-            self.params,
-            self.cache,
+        self.clock.mark("h2d")
+        args = (
             jnp.asarray(tokens),
             jnp.asarray(write_pages),
             jnp.asarray(write_offs),
@@ -2628,10 +2708,17 @@ class EngineCore:
             jnp.asarray([seq.sampling.temperature], np.float32),
             jnp.asarray([seq.sampling.top_k], np.int32),
             jnp.asarray([seq.sampling.top_p], np.float32),
+        )
+        self._mark_dispatch("prefill", 1, 1, 1, P_len, T)
+        toks, lps, self.cache = self._ring(
+            self.params,
+            self.cache,
+            *args,
             need_mask=need_mask and not all_greedy,
             all_greedy=all_greedy,
             want_logprobs=want_lp,
         )
+        self.clock.mark("land")
         self._ring_prefills += 1
         if self._ring_prefills == 1:
             log.info(
@@ -2640,6 +2727,7 @@ class EngineCore:
             )
         # dynacheck: allow-transitive-blocking(ring prefill is deliberately synchronous — sp engines keep the classic loop, and the single long prompt IS the step)
         tok = int(fetch_replicated(toks)[0])
+        self.clock.mark("commit")
         completed = seq.hashed.extend(seq.prompt)
         self._commit_completed(seq, completed)
         seq.prefilled = seq.processed = P_len
@@ -2784,8 +2872,14 @@ class EngineCore:
         flags so lanes that finish early run masked no-ops instead of
         writing K/V past their stop. Returns a pending fetch whose
         ``land()`` yields ([n_steps, B] tokens, lp arrays or None)."""
+        self.clock.mark("assemble")
         B = self._decode_width(len(seqs))
         seqs = seqs[:B]
+        # Occupancy: live lanes against the padded width (the commit side
+        # counts the lane-iterations issued and those that gave a client
+        # a token, both when the chain lands, so a window sees whole pairs).
+        self.exec_stats["decode_live_lanes"] += len(seqs)
+        self.exec_stats["decode_padded_lanes"] += B
         W = MEGASTEP_WATCH_W
         tokens = np.zeros(B, np.int32)
         positions = np.zeros(B, np.int32)
@@ -2824,59 +2918,48 @@ class EngineCore:
         )
         want_lp = any(s.logprobs is not None for s in seqs)
         all_greedy = all(s.sampling.temperature == 0.0 for s in seqs)
+        self.clock.mark("h2d")
         tok_in = self._put_batch(tokens)
         if feed_idx is not None:
             tok_in = self._feed(
                 self._inflight.feed_tokens, tok_in, jnp.asarray(feed_idx)
             )
+        args = (
+            tok_in,
+            self._put_batch(tables),
+            self._put_batch(positions),
+            self._put_batch(active),
+            self._put_batch(seeds),
+            self._put_batch(counters),
+            self._put_batch(temp),
+            self._put_batch(top_k),
+            self._put_batch(top_p),
+            self._put_batch(watch),
+            self._put_batch(budgets),
+            self._put_batch(min_left),
+        )
+        self._mark_dispatch(
+            "megastep" if n_steps > 1 else "decode",
+            len(seqs), B, n_steps, len(seqs) * n_steps, B * n_steps,
+        )
+        # On a pp engine the FUSED pp megastep: the whole wavefront chain
+        # — stage hops, sampling, stop flags — is one dispatch, armed
+        # with the same per-lane stop inputs as the single-chip body.
+        program = self._decode_pp if self.pp_mesh is not None else self._decode
+        out, lps, self.cache = program(
+            self.params,
+            self.cache,
+            *args,
+            n_steps=n_steps,
+            need_mask=need_mask and not all_greedy,
+            all_greedy=all_greedy,
+            want_logprobs=want_lp,
+        )
+        self.clock.mark("plan")
         if self.pp_mesh is not None:
-            # The FUSED pp megastep: the whole wavefront chain — stage
-            # hops, sampling, stop flags — is one dispatch, armed with
-            # the same per-lane stop inputs as the single-chip body.
-            out, lps, self.cache = self._decode_pp(
-                self.params,
-                self.cache,
-                tok_in,
-                self._put_batch(tables),
-                self._put_batch(positions),
-                self._put_batch(active),
-                self._put_batch(seeds),
-                self._put_batch(counters),
-                self._put_batch(temp),
-                self._put_batch(top_k),
-                self._put_batch(top_p),
-                self._put_batch(watch),
-                self._put_batch(budgets),
-                self._put_batch(min_left),
-                n_steps=n_steps,
-                need_mask=need_mask and not all_greedy,
-                all_greedy=all_greedy,
-                want_logprobs=want_lp,
-            )
             self.exec_stats[
                 "pp_fused_dispatches" if n_steps > 1 else "pp_forced_single"
             ] += 1
-        else:
-            out, lps, self.cache = self._decode(
-                self.params,
-                self.cache,
-                tok_in,
-                self._put_batch(tables),
-                self._put_batch(positions),
-                self._put_batch(active),
-                self._put_batch(seeds),
-                self._put_batch(counters),
-                self._put_batch(temp),
-                self._put_batch(top_k),
-                self._put_batch(top_p),
-                self._put_batch(watch),
-                self._put_batch(budgets),
-                self._put_batch(min_left),
-                n_steps=n_steps,
-                need_mask=need_mask and not all_greedy,
-                all_greedy=all_greedy,
-                want_logprobs=want_lp,
-            )
         self.exec_stats[
             "megastep_dispatches" if n_steps > 1 else "single_step_dispatches"
         ] += 1
@@ -2899,54 +2982,61 @@ class EngineCore:
 
     # dynalint: holds-lock(_step_lock) — step() locks before dispatching here
     def _step_locked(self) -> list[tuple[Sequence, LLMEngineOutput]]:
-        if self.engine.async_exec:
-            outputs = self._step_async()
-        else:
-            self.iterations += 1
-            plan = self._plan_step()
-            outputs = plan.commit() if plan is not None else []
-        if self._shed_outputs:
-            # Typed queue-expiry rejections from this step's sweeps ride
-            # the same output path as real chunks (the engine facade
-            # turns them into the wire-typed DeadlineExceededError).
-            outputs = self._shed_outputs + outputs
-            self._shed_outputs = []
-        if self._inflight is None and not (
-            self.running or self.waiting or self._inbox
-        ):
-            # Engine going idle: break the host_gap chain so the next
-            # burst's first dispatch doesn't record request inter-arrival
-            # time as per-dispatch host overhead.
-            self._t_prev_dispatch = 0.0
-        if self.flight.capacity and outputs:
-            # Flight-recorder step record (counts + cursors only; the
-            # dump is redacted by contract): one dict append per
-            # committed step, never on the plan/dispatch path.
-            self.flight.record_step(
-                i=self.iterations,
-                outputs=[
-                    {
-                        "rid": s.request_id,
-                        "emitted": len(o.token_ids),
-                        "generated": s.generated,
-                        "finish": o.finish_reason or "",
-                    }
-                    for s, o in outputs[:64]
-                ],
-                outputs_truncated=len(outputs) > 64,
-                dispatches=self.exec_stats["dispatches"],
-                megastep_dispatches=self.exec_stats["megastep_dispatches"],
-                fused_mixed_dispatches=self.exec_stats[
-                    "fused_mixed_dispatches"
-                ],
-                committed_tokens=self.exec_stats["committed_tokens"],
-                shed_total=self.sched_stats["shed_total"],
-                deadline_expired_total=self.sched_stats[
-                    "deadline_expired_total"
-                ],
-                running=len(self.running),
-            )
-        return outputs
+        # The step clock's window: the gap since the last step closes
+        # here (after the lock was taken), and reopens at the exit as
+        # ``between_steps`` or, with nothing pending, ``no_work``.
+        self.clock.step_begin()
+        try:
+            if self.engine.async_exec:
+                outputs = self._step_async()
+            else:
+                self.iterations += 1
+                plan = self._plan_step()
+                outputs = plan.commit() if plan is not None else []
+            if self._shed_outputs:
+                # Typed queue-expiry rejections from this step's sweeps ride
+                # the same output path as real chunks (the engine facade
+                # turns them into the wire-typed DeadlineExceededError).
+                outputs = self._shed_outputs + outputs
+                self._shed_outputs = []
+            if self._inflight is None and not (
+                self.running or self.waiting or self._inbox
+            ):
+                # Engine going idle: break the host_gap chain so the next
+                # burst's first dispatch doesn't record request inter-arrival
+                # time as per-dispatch host overhead.
+                self._t_prev_dispatch = 0
+            if self.flight.capacity and outputs:
+                # Flight-recorder step record (counts + cursors only; the
+                # dump is redacted by contract): one dict append per
+                # committed step, never on the plan/dispatch path.
+                self.flight.record_step(
+                    i=self.iterations,
+                    outputs=[
+                        {
+                            "rid": s.request_id,
+                            "emitted": len(o.token_ids),
+                            "generated": s.generated,
+                            "finish": o.finish_reason or "",
+                        }
+                        for s, o in outputs[:64]
+                    ],
+                    outputs_truncated=len(outputs) > 64,
+                    dispatches=self.exec_stats["dispatches"],
+                    megastep_dispatches=self.exec_stats["megastep_dispatches"],
+                    fused_mixed_dispatches=self.exec_stats[
+                        "fused_mixed_dispatches"
+                    ],
+                    committed_tokens=self.exec_stats["committed_tokens"],
+                    shed_total=self.sched_stats["shed_total"],
+                    deadline_expired_total=self.sched_stats[
+                        "deadline_expired_total"
+                    ],
+                    running=len(self.running),
+                )
+            return outputs
+        finally:
+            self.clock.step_end(self.has_work())
 
     # dynalint: holds-lock(_step_lock) — only called from _step_locked
     def _step_async(self) -> list[tuple[Sequence, LLMEngineOutput]]:
@@ -2987,6 +3077,7 @@ class EngineCore:
         optimistic overlay, so planning over an in-flight step sees the
         state that step will commit. The caller owns the iteration
         counter (a drain calls this twice for one engine step)."""
+        self.clock.mark("admit")  # a boundary only for a drain's second plan
         self._sweep_expired_holds()
 
         for seq in [s for s in self.running if s.cancelled]:
@@ -2994,7 +3085,7 @@ class EngineCore:
             self._release_blocks(seq)
 
         self._admit()
-        t_plan = time.time()
+        t_plan = self.clock.mark("plan")
         if self._sched_chunked:
             prefills = [
                 s for s in self.running if not self._eff_prefill_done(s)
@@ -3014,13 +3105,14 @@ class EngineCore:
         else:
             plan = self._plan_waves()
         if plan is not None:
-            self._tracer.record(
-                "engine_plan", t_plan, time.time(),
-                attrs={
+            # Ends at the step clock's next boundary (the landing of the
+            # previous step, or this step's exit).
+            self.clock.close_at_next(
+                "engine_plan", t_plan,
+                {
                     "iteration": self.iterations,
                     "pipelined": self._inflight is not None,
                 },
-                stat=True,
             )
         return plan
 
@@ -3030,15 +3122,10 @@ class EngineCore:
         strictly before any decode (the classic vLLM-default shape)."""
         prefills = [s for s in self.running if not self._eff_prefill_done(s)]
         if prefills:
-            t_wave = time.time()
             ring_out = self._maybe_ring_prefill(prefills)
             if ring_out is not None:
                 # The ring path runs synchronously (sp engines keep the
                 # classic loop); wrap its already-committed outputs.
-                self._tracer.record(
-                    "engine_prefill_step", t_wave, time.time(),
-                    attrs={"seqs": len(prefills), "ring": True}, stat=True,
-                )
                 return _PlannedStep(core=self, commit_fn=lambda: ring_out)
             return self._plan_prefill_wave(prefills)
         return self._plan_decode()
@@ -3215,22 +3302,15 @@ class EngineCore:
                     self._finish(seq)
                 else:
                     seq.pending = emitted[-1]
-            t_done = time.time()
-            self._tracer.record(
-                "engine_decode_step", t_decode, t_done,
-                attrs={
-                    "seqs": len(ready), "chain": n_steps,
-                    "tokens": emitted_total,
-                },
-                stat=True,
-            )
+            self.exec_stats["megastep_issued_lane_iters"] += len(ready) * n_steps
+            self.exec_stats["megastep_useful_lane_iters"] += emitted_total
             if n_steps > 1:
                 # Megastep observability: one span per multi-iteration
                 # dispatch carrying the inner-iteration count — the
                 # dispatch-amortization evidence (k iterations, one
                 # fixed overhead) bench and /traces consumers read.
                 self._tracer.record(
-                    "engine_megastep", t_decode, t_done,
+                    "engine_megastep", t_decode, time.time(),
                     attrs={
                         "seqs": len(ready), "inner_steps": n_steps,
                         "tokens": emitted_total,
@@ -3395,7 +3475,7 @@ class EngineCore:
         pend = self._dispatch_ragged(
             rows, self._decode_width(len(rows)),
             n_sample=[len(tl) for _, tl, _, _ in rows],
-            feed_rows=feed_rows,
+            feed_rows=feed_rows, kind="mixed",
         )
         # No live drafts -> every row advances exactly one token (a plain
         # decode row in verify clothing): the step pipelines like any
@@ -3562,7 +3642,7 @@ class EngineCore:
                 len(tl) if kind == "v" else 1
                 for (_, tl, _, _), kind in zip(rows, kinds)
             ],
-            feed_rows=feed_rows,
+            feed_rows=feed_rows, kind="mixed",
         )
         deterministic = n_spec_rows == 0
         adv: dict[str, tuple[int, int, int]] = {}
@@ -3894,6 +3974,11 @@ class EngineCore:
             now = time.time()
             drafted_total = accepted_total = spec_emitted = 0
             emitted_total = 0
+            # Occupancy, as _plan_megastep counts it: every row holds its
+            # lane for n_steps iterations; useful are the iterations
+            # that gave the client at least one token.
+            issued_iters = len(rows) * n_steps
+            useful_iters = 0
             dd_rounds = dd_hits = 0
             live = {id(s) for s in self.running}
             # Iteration-0 single-slot views: the k=1 commit shape the
@@ -3910,13 +3995,17 @@ class EngineCore:
                         seq, len(toks_list), toks0, lps0, i, t_step, now
                     )
                     if tok is None:
-                        continue  # mid-prompt: masked no-ops ran on device
+                        # Mid-prompt: iteration 0 was prefill work, not a
+                        # decode iteration; masked no-ops ran after it.
+                        issued_iters -= 1
+                        continue
                     if not cont[i]:
                         # Degraded lane: exactly the single-step books.
                         seq.pending = tok
                         seq.generated += 1
                         outputs.append((seq, self._emit(seq, tok, lp)))
                         emitted_total += 1
+                        useful_iters += 1
                         if seq.finish is not None:
                             self._finish(seq)
                         continue
@@ -3943,6 +4032,7 @@ class EngineCore:
                         (seq, self._emit_chunk(seq, emitted, lp_entries, finish))
                     )
                     emitted_total += len(emitted)
+                    useful_iters += k_take  # one token an iteration
                     if finish is not None:
                         seq.finish = finish
                         self._finish(seq)
@@ -4001,6 +4091,11 @@ class EngineCore:
                     )
                     emitted_total += len(emitted)
                     spec_emitted += len(emitted)
+                    taken = 0
+                    for r in range(n_steps):  # rounds that fed the client
+                        if taken < k_take and int(em[r]):
+                            useful_iters += 1
+                        taken += int(em[r])
                     if finish is not None:
                         seq.finish = finish
                         self._finish(seq)
@@ -4047,6 +4142,8 @@ class EngineCore:
                     (seq, self._emit_chunk(seq, emitted, lp_entries, finish))
                 )
                 emitted_total += len(emitted)
+                # Iteration 0 gave a + 1 tokens, each later one gave one.
+                useful_iters += 1 + max(0, k_take - a - 1)
                 if d:
                     drafted_total += d
                     accepted_total += a
@@ -4057,6 +4154,8 @@ class EngineCore:
                 else:
                     seq.pending = emitted[-1]
 
+            self.exec_stats["megastep_issued_lane_iters"] += issued_iters
+            self.exec_stats["megastep_useful_lane_iters"] += useful_iters
             t_done = time.time()
             if n_spec_rows or use_dd:
                 self.spec_stats.verify_steps += 1
@@ -4088,15 +4187,6 @@ class EngineCore:
                         "seqs": len(rows), "decode_rows": n_decode,
                         "prefill_tokens": total - decode_row_tokens,
                         "budget": budget,
-                    },
-                    stat=True,
-                )
-            else:
-                self._tracer.record(
-                    "engine_decode_step", t_step, t_done,
-                    attrs={
-                        "seqs": len(rows), "chain": n_steps,
-                        "tokens": emitted_total,
                     },
                     stat=True,
                 )
@@ -4825,6 +4915,15 @@ class EngineCore:
         km = k * self._pp_micro
         st["pp_pipe_occupancy"] = km / (km + self._pp - 1)
         return st
+
+    def step_phase_seconds(self) -> dict[tuple[str, str], float]:
+        """Cumulative engine-loop seconds keyed ``(phase, blocks)``, the
+        running phase included: the step clock's counters as /metrics
+        exports them (``dynamo_engine_step_phase_seconds_total``)."""
+        return {
+            (phase, PHASES[phase]): seconds
+            for phase, seconds in self.clock.seconds().items()
+        }
 
     def kv_cache_stats(self) -> dict:
         """Point-in-time prefix-cache gauges (status-server /metrics

@@ -388,36 +388,38 @@ def _moe_dispatch_local(xf, w_router, w_gate, w_up, w_down, cfg: ModelConfig,
     k = cfg.num_experts_per_tok
     C = _moe_capacity(N, cfg)
 
-    router = jnp.dot(xf, w_router, preferred_element_type=jnp.float32)  # [N, E]
-    vals, idx = jax.lax.top_k(router, k)
-    probs = jax.nn.softmax(vals, axis=-1)
+    with jax.named_scope("router"):
+        router = jnp.dot(xf, w_router, preferred_element_type=jnp.float32)  # [N, E]
+        vals, idx = jax.lax.top_k(router, k)
+        probs = jax.nn.softmax(vals, axis=-1)
 
-    flat_e = idx.reshape(-1) - e_offset                 # [N*k] local expert ids
-    flat_t = jnp.repeat(jnp.arange(N, dtype=jnp.int32), k)
-    flat_w = probs.reshape(-1)
-    local = (flat_e >= 0) & (flat_e < E_local)
+        flat_e = idx.reshape(-1) - e_offset                 # [N*k] local expert ids
+        flat_t = jnp.repeat(jnp.arange(N, dtype=jnp.int32), k)
+        flat_w = probs.reshape(-1)
+        local = (flat_e >= 0) & (flat_e < E_local)
 
-    # Slot of each entry within its expert's capacity batch, via one-hot
-    # cumsum (O(N*k*E_local) int work — cheap next to the expert matmuls).
-    onehot = (flat_e[:, None] == jnp.arange(E_local)[None, :]) & local[:, None]
-    pos = jnp.sum(jnp.cumsum(onehot, axis=0) * onehot, axis=1) - 1  # [N*k]
-    keep = local & (pos < C)
-    # Overflow/non-local entries land in a garbage row/slot.
-    e_c = jnp.where(keep, flat_e, E_local).astype(jnp.int32)
-    p_c = jnp.where(keep, pos, C).astype(jnp.int32)
+        # Slot of each entry within its expert's capacity batch, via one-hot
+        # cumsum (O(N*k*E_local) int work — cheap next to the expert matmuls).
+        onehot = (flat_e[:, None] == jnp.arange(E_local)[None, :]) & local[:, None]
+        pos = jnp.sum(jnp.cumsum(onehot, axis=0) * onehot, axis=1) - 1  # [N*k]
+        keep = local & (pos < C)
+        # Overflow/non-local entries land in a garbage row/slot.
+        e_c = jnp.where(keep, flat_e, E_local).astype(jnp.int32)
+        p_c = jnp.where(keep, pos, C).astype(jnp.int32)
 
-    gathered = jnp.zeros((E_local + 1, C + 1, h), xf.dtype).at[e_c, p_c].set(xf[flat_t])
-    g = gathered[:E_local, :C]                          # [E_local, C, h]
-    gate = jnp.einsum("ech,ehi->eci", g, w_gate, preferred_element_type=jnp.float32)
-    up = jnp.einsum("ech,ehi->eci", g, w_up, preferred_element_type=jnp.float32)
-    act = (jax.nn.silu(gate) * up).astype(xf.dtype)
-    down = jnp.einsum("eci,eih->ech", act, w_down, preferred_element_type=jnp.float32)
+    with jax.named_scope("experts"):
+        gathered = jnp.zeros((E_local + 1, C + 1, h), xf.dtype).at[e_c, p_c].set(xf[flat_t])
+        g = gathered[:E_local, :C]                          # [E_local, C, h]
+        gate = jnp.einsum("ech,ehi->eci", g, w_gate, preferred_element_type=jnp.float32)
+        up = jnp.einsum("ech,ehi->eci", g, w_up, preferred_element_type=jnp.float32)
+        act = (jax.nn.silu(gate) * up).astype(xf.dtype)
+        down = jnp.einsum("eci,eih->ech", act, w_down, preferred_element_type=jnp.float32)
 
-    down_pad = jnp.pad(down, ((0, 1), (0, 1), (0, 0)))  # garbage row/slot -> 0
-    entry_out = down_pad[e_c, p_c]                      # [N*k, h] f32
-    w_masked = jnp.where(keep, flat_w, 0.0)
-    out = jnp.zeros((N, h), jnp.float32).at[flat_t].add(w_masked[:, None] * entry_out)
-    return out.astype(xf.dtype)
+        down_pad = jnp.pad(down, ((0, 1), (0, 1), (0, 0)))  # garbage row/slot -> 0
+        entry_out = down_pad[e_c, p_c]                      # [N*k, h] f32
+        w_masked = jnp.where(keep, flat_w, 0.0)
+        out = jnp.zeros((N, h), jnp.float32).at[flat_t].add(w_masked[:, None] * entry_out)
+        return out.astype(xf.dtype)
 
 
 def _moe_dispatch_a2a(xl, w_router, w_gate, w_up, w_down, cfg: ModelConfig,
@@ -633,37 +635,48 @@ def dense_layer(
     on ONE layer's page array is also the perf contract: the Pallas
     attention call must see its own buffer, not a slice of a stacked
     tensor (see :func:`init_cache`). ``rope_cs`` carries the per-pass
-    precomputed rotary tables (:func:`rope_tables`)."""
+    precomputed rotary tables (:func:`rope_tables`).
+
+    The ``jax.named_scope`` sections (``qkv``, ``kv_write``, ``attn``,
+    ``o_proj``, ``mlp``; ``embed`` and ``lm_head`` around the stack) put
+    the model's own names on the device ops of a profile. They change op
+    metadata only: the lowered program and its compile-cache key stay
+    what they were."""
     T = x.shape[0]
     sm_scale = cfg.head_dim ** -0.5
     if rope_cs is None:
         rope_cs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-    y = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-    qkv = _dot(y, lp["wqkv"])
-    if "bqkv" in lp:  # Qwen2-family qkv bias (fused column order)
-        qkv = qkv + lp["bqkv"]
-    qkv = qkv.astype(x.dtype)
-    q, k, v = split_qkv(qkv, cfg, tp)
-    q = rope_apply(q.reshape(T, cfg.num_heads, cfg.head_dim), *rope_cs)
-    k = rope_apply(k.reshape(T, cfg.num_kv_heads, cfg.head_dim), *rope_cs)
-    kvn = _interleave_kv(k.reshape(T, cfg.kv_size), v, cfg)
-    cache_l = write_kv(cache_l, write_pages, write_offs, kvn)
+    with jax.named_scope("qkv"):
+        y = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        qkv = _dot(y, lp["wqkv"])
+        if "bqkv" in lp:  # Qwen2-family qkv bias (fused column order)
+            qkv = qkv + lp["bqkv"]
+        qkv = qkv.astype(x.dtype)
+        q, k, v = split_qkv(qkv, cfg, tp)
+        q = rope_apply(q.reshape(T, cfg.num_heads, cfg.head_dim), *rope_cs)
+        k = rope_apply(k.reshape(T, cfg.num_kv_heads, cfg.head_dim), *rope_cs)
+    with jax.named_scope("kv_write"):
+        kvn = _interleave_kv(k.reshape(T, cfg.kv_size), v, cfg)
+        cache_l = write_kv(cache_l, write_pages, write_offs, kvn)
     if isinstance(cache_l, dict):
         kv_pages, kv_scales = cache_l["kv"], cache_l["scale"]
     else:
         kv_pages, kv_scales = cache_l, None
-    if mesh is not None:
-        attn = sharded_ragged_attention(
-            mesh, q, kv_pages, kv_lens, block_tables, cu_q_lens,
-            num_seqs, sm_scale=sm_scale, kv_scales=kv_scales,
-        )
-    else:
-        attn = ragged_paged_attention(
-            q, kv_pages, kv_lens, block_tables, cu_q_lens, num_seqs,
-            sm_scale=sm_scale, kv_scales=kv_scales,
-        )
-    x = x + _dot(attn.reshape(T, cfg.q_size), lp["wo"]).astype(x.dtype)
-    x = x + _mlp(rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps), lp, cfg, tp, mesh)
+    with jax.named_scope("attn"):
+        if mesh is not None:
+            attn = sharded_ragged_attention(
+                mesh, q, kv_pages, kv_lens, block_tables, cu_q_lens,
+                num_seqs, sm_scale=sm_scale, kv_scales=kv_scales,
+            )
+        else:
+            attn = ragged_paged_attention(
+                q, kv_pages, kv_lens, block_tables, cu_q_lens, num_seqs,
+                sm_scale=sm_scale, kv_scales=kv_scales,
+            )
+    with jax.named_scope("o_proj"):
+        x = x + _dot(attn.reshape(T, cfg.q_size), lp["wo"]).astype(x.dtype)
+    with jax.named_scope("mlp"):
+        x = x + _mlp(rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps), lp, cfg, tp, mesh)
     return x, cache_l
 
 
@@ -697,8 +710,9 @@ def forward_tokens(
         kv_lens, block_tables, cu_q_lens, num_seqs, cfg, engine, mesh,
         mm_embeds=mm_embeds, mm_mask=mm_mask,
     )
-    last = x[last_rows]  # [S, h]
-    return _logits(last, params, cfg), cache
+    with jax.named_scope("lm_head"):
+        last = x[last_rows]  # [S, h]
+        return _logits(last, params, cfg), cache
 
 
 def forward_hidden(
@@ -727,12 +741,13 @@ def forward_hidden(
     override the token-embedding rows at multimodal placeholder
     positions with encoder output (llm/multimodal.py)."""
     tp = int(mesh.shape["tp"]) if mesh is not None else 1
-    x = params["embed"][tokens]  # [T, h]
-    if mm_embeds is not None:
-        x = jnp.where(mm_mask[:, None], mm_embeds.astype(x.dtype), x)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]  # [T, h]
+        if mm_embeds is not None:
+            x = jnp.where(mm_mask[:, None], mm_embeds.astype(x.dtype), x)
+        rope_cs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
     lp_all = params["layers"]
 
-    rope_cs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
     layer_caches = list(cache)
     for l in range(cfg.num_layers):
         lp = jax.tree.map(lambda a: a[l], lp_all)
@@ -742,7 +757,9 @@ def forward_hidden(
             tp=tp, mesh=mesh, rope_cs=rope_cs,
         )
 
-    return rms_norm(x, params["final_norm"], cfg.rms_norm_eps), tuple(layer_caches)
+    with jax.named_scope("lm_head"):  # the final norm feeds nothing else
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    return x, tuple(layer_caches)
 
 
 def forward_ring_prefill(
@@ -772,33 +789,40 @@ def forward_ring_prefill(
     from dynamo_tpu.ops.ring_attention import ring_attention
 
     T = tokens.shape[0]
-    positions = jnp.arange(T, dtype=jnp.int32)
-    x = params["embed"][tokens]  # [T, h]
+    with jax.named_scope("embed"):
+        positions = jnp.arange(T, dtype=jnp.int32)
+        x = params["embed"][tokens]  # [T, h]
+        rope_cs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
     lp_all = params["layers"]
 
-    rope_cs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
     layer_caches = list(cache)
     for l in range(cfg.num_layers):
         lp = jax.tree.map(lambda a: a[l], lp_all)
-        y = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-        qkv = _dot(y, lp["wqkv"])
-        if "bqkv" in lp:
-            qkv = qkv + lp["bqkv"]
-        qkv = qkv.astype(x.dtype)
-        q, k, v = split_qkv(qkv, cfg)
-        q = rope_apply(q.reshape(T, cfg.num_heads, cfg.head_dim), *rope_cs)
-        k = rope_apply(k.reshape(T, cfg.num_kv_heads, cfg.head_dim), *rope_cs)
-        v3 = v.reshape(T, cfg.num_kv_heads, cfg.head_dim)
-        kvn = _interleave_kv(k.reshape(T, cfg.kv_size), v, cfg)
-        layer_caches[l] = write_kv(layer_caches[l], write_pages, write_offs, kvn)
-        attn = ring_attention(q, k, v3, mesh=sp_mesh, axis_name=axis_name)
-        attn = attn.reshape(T, cfg.q_size)
-        x = x + _dot(attn, lp["wo"]).astype(x.dtype)
-        x = x + _mlp(rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps), lp, cfg, 1, None)
+        with jax.named_scope("qkv"):
+            y = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            qkv = _dot(y, lp["wqkv"])
+            if "bqkv" in lp:
+                qkv = qkv + lp["bqkv"]
+            qkv = qkv.astype(x.dtype)
+            q, k, v = split_qkv(qkv, cfg)
+            q = rope_apply(q.reshape(T, cfg.num_heads, cfg.head_dim), *rope_cs)
+            k = rope_apply(k.reshape(T, cfg.num_kv_heads, cfg.head_dim), *rope_cs)
+            v3 = v.reshape(T, cfg.num_kv_heads, cfg.head_dim)
+        with jax.named_scope("kv_write"):
+            kvn = _interleave_kv(k.reshape(T, cfg.kv_size), v, cfg)
+            layer_caches[l] = write_kv(layer_caches[l], write_pages, write_offs, kvn)
+        with jax.named_scope("attn"):
+            attn = ring_attention(q, k, v3, mesh=sp_mesh, axis_name=axis_name)
+            attn = attn.reshape(T, cfg.q_size)
+        with jax.named_scope("o_proj"):
+            x = x + _dot(attn, lp["wo"]).astype(x.dtype)
+        with jax.named_scope("mlp"):
+            x = x + _mlp(rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps), lp, cfg, 1, None)
 
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    last = jax.lax.dynamic_slice_in_dim(x, last_row, 1, axis=0)  # [1, h]
-    return _logits(last, params, cfg), tuple(layer_caches)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        last = jax.lax.dynamic_slice_in_dim(x, last_row, 1, axis=0)  # [1, h]
+        return _logits(last, params, cfg), tuple(layer_caches)
 
 
 def embed_forward(
@@ -852,13 +876,14 @@ def decode_tokens(
     advance positions on-device."""
     B = tokens.shape[0]
     bs = engine.block_size
-    page = jnp.take_along_axis(block_tables, (positions // bs)[:, None], axis=1)[:, 0]
-    write_pages = jnp.where(active, page, engine.garbage_block)
-    write_offs = positions % bs
-    kv_lens = jnp.where(active, positions + 1, 1).astype(jnp.int32)
-    cu = jnp.arange(B + 1, dtype=jnp.int32)
-    num_seqs = jnp.array([B], jnp.int32)
-    rows = jnp.arange(B, dtype=jnp.int32)
+    with jax.named_scope("kv_write"):  # where each lane's K/V row lands
+        page = jnp.take_along_axis(block_tables, (positions // bs)[:, None], axis=1)[:, 0]
+        write_pages = jnp.where(active, page, engine.garbage_block)
+        write_offs = positions % bs
+        kv_lens = jnp.where(active, positions + 1, 1).astype(jnp.int32)
+        cu = jnp.arange(B + 1, dtype=jnp.int32)
+        num_seqs = jnp.array([B], jnp.int32)
+        rows = jnp.arange(B, dtype=jnp.int32)
     return forward_tokens(
         params, cache, tokens, positions, write_pages, write_offs,
         kv_lens, block_tables, cu, num_seqs, rows, cfg, engine, mesh,
@@ -898,16 +923,17 @@ def verify_tokens(
     one-token-at-a-time decode history."""
     S, R = tokens.shape
     bs = engine.block_size
-    j = jnp.arange(R, dtype=jnp.int32)[None, :]
-    pos = positions[:, None] + j                              # [S, R]
-    live = active[:, None] & (j <= draft_len[:, None])
-    page = jnp.take_along_axis(block_tables, pos // bs, axis=1)
-    write_pages = jnp.where(live, page, engine.garbage_block).reshape(-1)
-    write_offs = (pos % bs).reshape(-1)
-    kv_lens = jnp.where(active, positions + R, R).astype(jnp.int32)
-    cu = R * jnp.arange(S + 1, dtype=jnp.int32)
-    num_seqs = jnp.array([S], jnp.int32)
-    rows = jnp.arange(S * R, dtype=jnp.int32)
+    with jax.named_scope("kv_write"):
+        j = jnp.arange(R, dtype=jnp.int32)[None, :]
+        pos = positions[:, None] + j                              # [S, R]
+        live = active[:, None] & (j <= draft_len[:, None])
+        page = jnp.take_along_axis(block_tables, pos // bs, axis=1)
+        write_pages = jnp.where(live, page, engine.garbage_block).reshape(-1)
+        write_offs = (pos % bs).reshape(-1)
+        kv_lens = jnp.where(active, positions + R, R).astype(jnp.int32)
+        cu = R * jnp.arange(S + 1, dtype=jnp.int32)
+        num_seqs = jnp.array([S], jnp.int32)
+        rows = jnp.arange(S * R, dtype=jnp.int32)
     return forward_tokens(
         params, cache, tokens.reshape(-1), pos.reshape(-1), write_pages,
         write_offs, kv_lens, block_tables, cu, num_seqs, rows, cfg,

@@ -54,6 +54,33 @@ def _run(core: EngineCore, prompts: list[list[int]], max_tokens: int,
         core.step()
 
 
+def _on_one_stack_chunk(fn, *args):
+    """Call ``fn(*args)`` with its whole Python call stack in one piece
+    of memory.
+
+    CPython (3.11 and later) keeps Python frames in 16 KiB chunks: it
+    maps a new chunk when a call does not fit in the current one and
+    unmaps it when that call returns. Tracing and lowering a model
+    recurse a few hundred frames deep, and where a chunk's end falls
+    just under a hot loop of that recursion (``jaxpr_subcomp``'s loop
+    over a layer's equations), every call the loop makes maps, faults in
+    and unmaps a chunk: measured on ``_megastep_body`` at 24 layers,
+    190,000 to 360,000 minor page faults and 1.0-2.3 s of system time in
+    3.5-5.6 s of lowering, against 24 faults and 1.5 s from here. Which
+    loop is hit depends on every frame's size from the thread's first
+    frame down (so on the thread, and on unrelated edits to the callers),
+    not on the program being lowered. A frame that reserves more than
+    half a MiB of evaluation stack cannot fit in a 16 KiB chunk, so
+    CPython gives it a chunk of 1 MiB of its own, whose remaining half
+    holds every frame below it until it returns."""
+    return fn(*args)
+
+
+_on_one_stack_chunk.__code__ = _on_one_stack_chunk.__code__.replace(
+    co_stacksize=1 << 16  # slots of 8 bytes: 512 KiB, so a 1 MiB chunk
+)
+
+
 def warm_up(core: EngineCore) -> dict[str, float]:
     """Run the warm-up traffic; returns wall seconds per phase (compile
     seconds per program come from :class:`dynamo_tpu.device.CompileLog`).
@@ -62,6 +89,10 @@ def warm_up(core: EngineCore) -> dict[str, float]:
     the warm-up blocks are dropped from the prefix cache afterwards, so
     routers never hear of them. Scheduler counters do include the
     warm-up's dispatches."""
+    return _on_one_stack_chunk(_warm_up, core)
+
+
+def _warm_up(core: EngineCore) -> dict[str, float]:
     eng = core.engine
     rng = np.random.RandomState(0)
     vocab = core.cfg.vocab_size

@@ -160,6 +160,88 @@ def bind_scheduler_gauges(
         )
 
 
+# Engine counter export: stats-dict key -> (metric name, doc). Keys match
+# EngineCore.scheduler_stats() (its exec_stats part). Unlike the gauges
+# above these are typed ``counter``: a reader takes the difference of two
+# scrapes and divides one by another (dispatches, tokens, occupancy).
+ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
+    "dispatches": (
+        "engine_dispatches",
+        "Device dispatches of step programs since start",
+    ),
+    "committed_tokens": (
+        "engine_committed_tokens",
+        "Tokens committed to client streams since start",
+    ),
+    "decode_live_lanes": (
+        "engine_decode_live_lanes",
+        "Live lanes summed over decode dispatches",
+    ),
+    "decode_padded_lanes": (
+        "engine_decode_padded_lanes",
+        "Padded batch width summed over decode dispatches",
+    ),
+    "megastep_useful_lane_iters": (
+        "engine_megastep_useful_lane_iters",
+        "Lane-iterations of decode megasteps that gave a client a token",
+    ),
+    "megastep_issued_lane_iters": (
+        "engine_megastep_issued_lane_iters",
+        "Lane-iterations decode megasteps issued (live lanes x k)",
+    ),
+    "ragged_real_tokens": (
+        "engine_ragged_real_tokens",
+        "Real tokens summed over ragged (prefill wave / mixed) dispatches",
+    ),
+    "ragged_bucket_tokens": (
+        "engine_ragged_bucket_tokens",
+        "Bucket (padded) tokens summed over ragged dispatches",
+    ),
+}
+
+
+class _EngineCounters:
+    """Scrape-time collector for the engine's cumulative counters: the
+    step clock's seconds per phase and :data:`ENGINE_COUNTERS`."""
+
+    def __init__(self, phase_seconds: Callable[[], dict], stats: Callable[[], dict]):
+        self._phase_seconds = phase_seconds
+        self._stats = stats
+
+    def collect(self):
+        from prometheus_client.core import CounterMetricFamily
+
+        phases = CounterMetricFamily(
+            "dynamo_engine_step_phase_seconds",
+            "Engine-loop wall time by step phase; the phases partition it. "
+            "blocks: what held the engine thread (host work, a wait for "
+            "the device, or no work)",
+            labels=["service", "phase", "blocks"],
+        )
+        for (phase, blocks), seconds in self._phase_seconds().items():
+            phases.add_metric(["engine", phase, blocks], seconds)
+        yield phases
+        stats = self._stats()
+        for key, (name, doc) in ENGINE_COUNTERS.items():
+            family = CounterMetricFamily(f"dynamo_{name}", doc, labels=["service"])
+            family.add_metric(["engine"], float(stats.get(key, 0) or 0))
+            yield family
+
+
+def bind_engine_counters(
+    status: "SystemStatusServer | None",
+    phase_seconds: Callable[[], dict],
+    scheduler_stats: Callable[[], dict],
+) -> None:
+    """Export ``dynamo_engine_step_phase_seconds_total{phase, blocks}``
+    (``phase_seconds`` returns ``{(phase, blocks): seconds}``) and the
+    :data:`ENGINE_COUNTERS` on a worker's /metrics. No-op when the
+    status server is disabled."""
+    if status is None:
+        return
+    status.metrics.registry.register(_EngineCounters(phase_seconds, scheduler_stats))
+
+
 # Speculative-decoding gauge export: stats-dict key -> (name, doc). Keys
 # match EngineCore.spec_decode_stats() / MockTpuEngine.spec_decode_stats()
 # (SpecStats.as_dict + "enabled").

@@ -32,6 +32,7 @@ mirrored in runtime/config.py RuntimeConfig):
 
 from __future__ import annotations
 
+import random
 import secrets
 import threading
 import time
@@ -209,12 +210,15 @@ class TraceCollector:
         # the two-field update against the engine-thread/event-loop race
         # (the ring appends stay lock-free).
         self._phase_lock = threading.Lock()
-        self._phase_totals: dict[str, list[float]] = {}
+        self._phase_totals: dict[tuple[str, str], list[float]] = {}
         # Bound metrics registries: per-phase latency histograms
         # (planner/observer.py consumes these for the TTFT/ITL
         # decomposition). Held weakly — a restarted service's dead
         # registry unbinds itself instead of accumulating forever.
         self._metrics: list[weakref.ref] = []
+        # Histogram handles, resolved once per (service, phase) and bound
+        # registry; dropped whenever the set of bound registries changes.
+        self._handles: dict[tuple[str, str], list] = {}
 
     @property
     def capacity(self) -> int:
@@ -234,41 +238,49 @@ class TraceCollector:
         self._observe(span)
 
     def _observe(self, span: Span) -> None:
-        key = f"{span.service}/{span.name}"
+        key = (span.service, span.name)
+        duration = span.duration_s
         with self._phase_lock:
             totals = self._phase_totals.get(key)
             if totals is None:
                 totals = self._phase_totals[key] = [0.0, 0.0]
             totals[0] += 1.0
-            totals[1] += span.duration_s
-        dead = False
-        for ref in self._metrics:
-            registry = ref()
-            if registry is None:
-                dead = True
-                continue
-            registry.scoped(service=span.service, phase=span.name).histogram(
-                "trace_phase_duration_seconds",
-                doc="Per-phase request latency attributed by the tracer",
-                buckets=_PHASE_BUCKETS,
-            ).observe(span.duration_s)
-        if dead:
-            self._metrics[:] = [r for r in self._metrics if r() is not None]
+            totals[1] += duration
+            # The handle cache is shared by the engine thread and the
+            # event loop, like the totals.
+            if any(ref() is None for ref in self._metrics):
+                self._metrics[:] = [r for r in self._metrics if r() is not None]
+                self._handles.clear()
+            handles = self._handles.get(key)
+            if handles is None:
+                handles = self._handles[key] = [
+                    registry.scoped(service=span.service, phase=span.name).histogram(
+                        "trace_phase_duration_seconds",
+                        doc="Per-phase request latency attributed by the tracer",
+                        buckets=_PHASE_BUCKETS,
+                    )
+                    for registry in (ref() for ref in self._metrics)
+                    if registry is not None
+                ]
+        for histogram in handles:
+            histogram.observe(duration)
 
     def bind_metrics(self, registry: Any) -> None:
         """Mirror every finished span into per-phase histograms
         (``dynamo_trace_phase_duration_seconds{service,phase}``) on the
         given :class:`~dynamo_tpu.runtime.metrics.MetricsRegistry`."""
-        live = [r for r in self._metrics if r() is not None]
-        if not any(r() is registry for r in live):
-            live.append(weakref.ref(registry))
-        self._metrics[:] = live
+        with self._phase_lock:
+            live = [r for r in self._metrics if r() is not None]
+            if not any(r() is registry for r in live):
+                live.append(weakref.ref(registry))
+            self._metrics[:] = live
+            self._handles.clear()
 
     def phase_totals(self) -> dict[str, tuple[float, float]]:
         """Cumulative ``{"service/phase": (count, sum_seconds)}`` since
         process start — the snapshot publisher's phase source."""
         with self._phase_lock:
-            return {k: (v[0], v[1]) for k, v in self._phase_totals.items()}
+            return {f"{k[0]}/{k[1]}": (v[0], v[1]) for k, v in self._phase_totals.items()}
 
     def clear(self) -> None:
         self._spans.clear()
@@ -423,16 +435,24 @@ class Tracer:
         """File an already-elapsed phase as a finished span. ``stat=True``
         routes it to the collector's stat ring (histograms only, excluded
         from ``/traces``) — for high-frequency per-step timings that would
-        otherwise evict request spans."""
+        otherwise evict request spans. A stat span belongs to no trace:
+        it carries no ids and no parent, and the one ``Span`` built for it
+        is the one the ring keeps. It is sampled at ``DYN_TRACE_SAMPLE``
+        like a root span, by a draw of its own since it has no trace id."""
+        if stat:
+            rate = _STATE.sample
+            if _STATE.enabled and (
+                rate >= 1.0 or (rate > 0.0 and random.random() < rate)
+            ):
+                self.collector.add_stat(Span(
+                    name, self.service, "", "", None, start_s, end_s,
+                    attrs if attrs is not None else {},
+                ))
+            return
         span = self.span(name, parent=parent, headers=headers, attrs=attrs)
         if span.recording:
             span.start_s = start_s
-            if stat:
-                span.end_s = end_s
-                span._collector = None
-                self.collector.add_stat(span)
-            else:
-                span.finish(end_s)
+            span.finish(end_s)
 
 
 # ---------------------------------------------------------------------------

@@ -282,7 +282,13 @@ async def test_stall_deadline_dump_captures_victim_steps(
             await rt.shutdown()
         await store.stop()
     assert received > 0
-    dumps = sorted(tmp_path.glob("flight-*stall_deadline*.json"))
+    # The dump is handed to the loop's executor (dataplane._flight_dump):
+    # give that thread a moment to finish writing.
+    for _ in range(100):
+        dumps = sorted(tmp_path.glob("flight-*stall_deadline*.json"))
+        if dumps:
+            break
+        await asyncio.sleep(0.05)
     assert dumps, "stall deadline left no flight-recorder artifact"
     payload = dump_for_rid(dumps, "r-stall")
     steps = [r for r in payload["records"] if r.get("kind") == "step"]
