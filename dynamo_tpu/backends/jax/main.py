@@ -517,6 +517,8 @@ async def run_jax_worker(
         [m["peak_bytes_in_use"] for m in startup["memory_after_init"]],
         startup["param_bytes_per_device"], startup["cache_bytes_per_device"],
     )
+    startup["engine_loop"] = "pipelined" if core.pipelined else "synchronous"
+    log.info("engine loop: %s", startup["engine_loop"])
     if warm_up:
         from dynamo_tpu.engine.warmup import warm_up as _warm_up
 
@@ -1355,10 +1357,12 @@ def main() -> None:
     )
     ap.add_argument(
         "--async-exec", default=None, choices=["on", "off"],
-        help="one-step-ahead pipelined engine loop: plan+enqueue step N+1 "
-             "while N executes, with device-resident token feedback and "
-             "double-buffered host fetch (token stream bit-identical to "
-             "'off'; default off)",
+        help="pin the engine loop: 'on' = one-step-ahead pipelined (plan+"
+             "enqueue step N+1 while N executes, device-resident token "
+             "feedback, double-buffered host fetch), 'off' = synchronous; "
+             "the token stream is bit-identical. Unset (default): the "
+             "engine chooses — pipelined, except on an sp mesh or with "
+             "host-drafted speculation",
     )
     ap.add_argument(
         "--megastep-k", type=int, default=None,

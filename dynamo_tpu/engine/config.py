@@ -202,8 +202,13 @@ class EngineConfig:
     # D2H→H2D round trip), and step N's tokens/logprobs land through a
     # double-buffered async copy consumed while N+1 runs. The token
     # stream is bit-identical on vs off (greedy AND seeded sampling).
-    # Off by default until parity is pinned on every deployment shape.
-    async_exec: bool = False
+    # None (the default): the engine chooses from what it was built with
+    # (EngineCore.pipelined) — pipelined, except on an sp mesh (ring
+    # prefill commits in place) and with host-drafted speculation (the
+    # drafter would read history one step stale and draft nothing).
+    # True / False pin a loop: the parity suites' synchronous reference,
+    # tools/async_smoke.py, `--async-exec on|off`.
+    async_exec: bool | None = None
 
     # Disaggregation: a remote-decode prefill's held blocks are released
     # if no decode worker pulls them within this window (a decode-side

@@ -58,6 +58,7 @@ from dynamo_tpu.engine.sampler import (
     LOGPROBS_K,
     device_ngram_draft,
     gather_feedback,
+    pad_feedback,
     resolve_verify,
     ring_append,
     sample_seeded,
@@ -238,10 +239,11 @@ class _PlannedStep:
     plan side assembles host arrays and enqueues the device program(s);
     the commit side lands the double-buffered outputs and applies every
     piece of host bookkeeping (block commits, cursor advances, stop
-    scans, stream emission). With ``async_exec`` off, commit runs
-    immediately after plan — the classic loop. With it on, the engine
-    keeps ONE of these in flight and plans step N+1 against the
-    optimistic ``adv`` overlays before committing step N.
+    scans, stream emission). On the synchronous loop commit runs
+    immediately after plan — the classic loop. On the pipelined loop
+    (``EngineCore.pipelined``) the engine keeps ONE of these in flight
+    and plans step N+1 against the optimistic ``adv`` overlays before
+    committing step N.
     """
 
     core: "EngineCore"
@@ -254,6 +256,8 @@ class _PlannedStep:
     # [n_steps, B]) + request_id -> flat index of each lane's newest
     # token: the next plan's token buffer gathers from here on device.
     feed_tokens: Any = None
+    # feed_tokens padded to the engine's feedback width (EngineCore._fed).
+    feed_padded: Any = None
     feed_index: dict[str, int] = field(default_factory=dict)
     # request_id -> (start, stride, count): this step's FULL per-lane
     # emission as flat indices into feed_tokens, in stream order. Set
@@ -985,10 +989,27 @@ class EngineCore:
             )
         if engine_cfg.async_exec and sp_mesh is not None:
             raise ValueError(
-                "async_exec is not wired for sp meshes yet (the ring "
-                "prefill path runs synchronously); sp engines keep the "
-                "synchronous loop"
+                "async_exec=True cannot be honoured on an sp mesh (the "
+                "ring prefill path commits in place); leave it unset and "
+                "the engine keeps the synchronous loop"
             )
+        # Which step loop serves: one algorithm (plan / dispatch /
+        # commit) whose commit is immediate or deferred one call.
+        # Deferred — one planned step in flight, the host's work hidden
+        # behind the device's — unless what the engine was built with
+        # forces "immediate": an sp mesh (ring prefill commits in
+        # place), or drafts proposed from host history (a drafter one
+        # step stale proposes nothing: the stream stays bit-identical
+        # but no verify row forms). EngineConfig.async_exec pins it.
+        host_drafted = (
+            engine_cfg.spec_decode != "off"
+            and not engine_cfg.spec_device_draft
+        )
+        self.pipelined: bool = (
+            sp_mesh is None and not host_drafted
+            if engine_cfg.async_exec is None
+            else engine_cfg.async_exec
+        )
         if engine_cfg.max_waiting < 0:
             raise ValueError(
                 f"max_waiting must be >= 0 (0 = unbounded), got "
@@ -1376,6 +1397,9 @@ class EngineCore:
         # count forced pipeline flushes (block pressure mid-plan).
         self.exec_stats = {
             "dispatches": 0,
+            # Dispatches enqueued while another step was in flight (the
+            # ``pipelined`` attribute of the engine/dispatch annotation).
+            "pipelined_dispatches": 0,
             "commits": 0,
             "drains": 0,
             "last_host_gap_ms": 0.0,
@@ -1445,8 +1469,17 @@ class EngineCore:
         # Device-resident token feedback: the next step's token buffer
         # gathers just-sampled ids straight from the previous dispatch's
         # device output (sampler.gather_feedback) — no D2H→H2D round trip
-        # on the decode critical path.
+        # on the decode critical path. The source is first padded to ONE
+        # flat width (_feed_pad: a program per output shape), so the
+        # gather compiles per token-buffer width and not per (previous
+        # width, next width) pair: serving crosses widths that warm-up's
+        # phases, one width at a time, never pair up.
         self._feed = jax.jit(gather_feedback)
+        self._feed_width = (
+            engine_cfg.megastep * self._spec_R
+            * max(engine_cfg.decode_buckets[-1], engine_cfg.prefill_batch)
+        )
+        self._feed_pad = jax.jit(pad_feedback, static_argnames=("width",))
         self.sp_mesh = sp_mesh
         self._ring = None
         if sp_mesh is not None:
@@ -1690,6 +1723,20 @@ class EngineCore:
             return None
         return self._inflight.feed_series.get(seq.request_id)
 
+    def _fed(self, host_tokens: jax.Array, src_idx: np.ndarray) -> jax.Array:
+        """``host_tokens`` with the slots ``src_idx`` names (>= 0: a flat
+        index into the in-flight step's sampled output) overridden on
+        device by those just-sampled ids — enqueued on the device
+        stream, never blocking. The in-flight output is padded once, on
+        first use, to the engine's one feedback width."""
+        plan = self._inflight
+        if plan.feed_padded is None:
+            plan.feed_padded = self._feed_pad(
+                plan.feed_tokens,
+                width=max(self._feed_width, plan.feed_tokens.size),
+            )
+        return self._feed(plan.feed_padded, host_tokens, jnp.asarray(src_idx))
+
     def _note_dispatch(self) -> int:
         """Dispatch-side bookkeeping for the pipelining invariants: the
         sequence number feeds the test hook (the async contract is that
@@ -1702,6 +1749,8 @@ class EngineCore:
         two track the same bottleneck but are not numerically comparable."""
         self._dispatch_no += 1
         self.exec_stats["dispatches"] += 1
+        if self._inflight is not None:
+            self.exec_stats["pipelined_dispatches"] += 1
         # Both ends are the step clock's readings at ``dispatch`` marks.
         now = self._t_dispatch
         if self._t_prev_dispatch:
@@ -2200,10 +2249,9 @@ class EngineCore:
                 # fold back to microbatch shape.
                 fi = np.full(plan.tokens.size, -1, np.int32)
                 fi[: feed_idx.shape[0]] = feed_idx
-                mb_tok = self._feed(
-                    self._inflight.feed_tokens, mb_tok.reshape(-1),
-                    jnp.asarray(fi),
-                ).reshape(plan.tokens.shape)
+                mb_tok = self._fed(mb_tok.reshape(-1), fi).reshape(
+                    plan.tokens.shape
+                )
             args = (
                 mb_tok,
                 jnp.asarray(plan.positions),
@@ -2242,9 +2290,7 @@ class EngineCore:
                 # Device-resident feedback: override the placeholder slots
                 # with just-sampled ids straight from the in-flight step's
                 # output — enqueued on the device stream, never blocking.
-                tok_in = self._feed(
-                    self._inflight.feed_tokens, tok_in, jnp.asarray(feed_idx)
-                )
+                tok_in = self._fed(tok_in, feed_idx)
             args = (
                 tok_in,
                 jnp.asarray(positions),
@@ -2408,9 +2454,7 @@ class EngineCore:
         overridden by the previous dispatch's sampled ids."""
         tok_in = jnp.asarray(b.tokens)
         if b.feed_idx is not None:
-            tok_in = self._feed(
-                self._inflight.feed_tokens, tok_in, jnp.asarray(b.feed_idx)
-            )
+            tok_in = self._fed(tok_in, b.feed_idx)
         return tok_in
 
     def _mark_fused_dispatch(self, rows, S: int, b, kinds, n_steps: int) -> None:
@@ -2497,10 +2541,8 @@ class EngineCore:
         self.clock.mark("h2d")
         hist_in = jnp.asarray(hist)
         if ring_src is not None:
-            hist_in = self._feed(
-                self._inflight.feed_tokens,
-                hist_in.reshape(-1),
-                jnp.asarray(ring_src.reshape(-1)),
+            hist_in = self._fed(
+                hist_in.reshape(-1), ring_src.reshape(-1)
             ).reshape(S, H)
         args = (
             self._fused_tokens(b),
@@ -2921,9 +2963,7 @@ class EngineCore:
         self.clock.mark("h2d")
         tok_in = self._put_batch(tokens)
         if feed_idx is not None:
-            tok_in = self._feed(
-                self._inflight.feed_tokens, tok_in, jnp.asarray(feed_idx)
-            )
+            tok_in = self._fed(tok_in, feed_idx)
         args = (
             tok_in,
             self._put_batch(tables),
@@ -2971,12 +3011,13 @@ class EngineCore:
         """One engine iteration; returns (sequence, output-chunk) pairs.
         A chunk with ``finish_reason`` set is the sequence's last.
 
-        With ``async_exec`` off, the step plans, dispatches, and commits
-        in place — the classic synchronous loop. With it on, the step
-        plans and dispatches iteration N+1 BEFORE committing iteration N
-        (one-step-ahead pipelining), so the returned outputs lag the
-        dispatch by exactly one call; the token stream is bit-identical
-        either way."""
+        Pipelined (``self.pipelined``, the engine's choice unless
+        ``EngineConfig.async_exec`` pins one), the step plans and
+        dispatches iteration N+1 BEFORE committing iteration N
+        (one-step-ahead), so the returned outputs lag the dispatch by
+        exactly one call. Otherwise it plans, dispatches, and commits in
+        place — the classic synchronous loop; the token stream is
+        bit-identical either way."""
         with self._step_lock:
             return self._step_locked()
 
@@ -2987,7 +3028,7 @@ class EngineCore:
         # ``between_steps`` or, with nothing pending, ``no_work``.
         self.clock.step_begin()
         try:
-            if self.engine.async_exec:
+            if self.pipelined:
                 outputs = self._step_async()
             else:
                 self.iterations += 1
@@ -4897,7 +4938,7 @@ class EngineCore:
         st["running"] = len(self.running)
         st["chunked_scheduling"] = 1 if self._sched_chunked else 0
         st["token_budget"] = self.engine.token_budget
-        st["async_exec"] = 1 if self.engine.async_exec else 0
+        st["async_exec"] = 1 if self.pipelined else 0
         st["queue_limit"] = self._max_waiting
         st["fair_enabled"] = 1 if self.engine.fair_scheduling else 0
         st.update(self.exec_stats)

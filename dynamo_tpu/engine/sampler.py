@@ -42,11 +42,20 @@ def gather_feedback(
     sampled id never round-trips D2H→H2D on the critical path. Slots
     with ``src_idx < 0`` keep the host value (prefill chunks, draft
     tokens, already-committed pendings). One tiny program per (prev
-    size, T) pair; enqueued on the device stream, so it never blocks the
-    host."""
+    size, T) pair — the engine hands it every source at one size
+    (:func:`pad_feedback`), so per T; enqueued on the device stream, so
+    it never blocks the host."""
     flat = prev_tokens.reshape(-1)
     fed = flat[jnp.clip(src_idx, 0, flat.shape[0] - 1)]
     return jnp.where(src_idx >= 0, fed, host_tokens)
+
+
+def pad_feedback(prev_tokens: jax.Array, *, width: int) -> jax.Array:
+    """A dispatch's sampled tokens, any shape, flat and zero-padded to
+    ``width``: flat indices into the output stay what they were, and
+    every consumer of the feedback sees one source shape."""
+    flat = prev_tokens.reshape(-1)
+    return jnp.pad(flat, (0, width - flat.shape[0]))
 
 
 def sample_seeded(

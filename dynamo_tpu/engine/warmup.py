@@ -14,7 +14,12 @@ and the deadline stays what it is.
 Covered: every prefill bucket one wave can reach and every decode width
 ``max_num_seqs`` can reach at the full megastep length, for the two
 sampling programs ordinary requests select (sampled without a top-k/top-p
-mask — the OpenAI default — and greedy). Left to compile on first use,
+mask — the OpenAI default — and greedy). On the pipelined loop a decode
+phase runs two megasteps, so that both kinds of output (a prefill
+wave's, a megastep's) have fed a token buffer of that width: the
+feedback gather compiles per width and its padding per output shape
+(``EngineCore._fed``), so serving may then cross widths freely. Left to
+compile on first use,
 one short program at a time: shortened megasteps at the end of a
 generation budget, masked sampling, logprobs, speculative verify rows and
 multimodal prefill. Chunked scheduling runs the same traffic, which
@@ -98,8 +103,11 @@ def _warm_up(core: EngineCore) -> dict[str, float]:
     vocab = core.cfg.vocab_size
     k = eng.megastep
     lanes = min(eng.max_num_seqs, eng.max_waiting or eng.max_num_seqs)
-    # Longest prompt that still leaves room to generate one megastep.
-    max_prompt = eng.max_model_len - k - 2
+    # Tokens a decode phase generates: the prefill's, then one megastep,
+    # or two where the second is fed from the first on the device.
+    gen = 1 + (2 * k if core.pipelined else k)
+    # Longest prompt that still leaves room to generate them.
+    max_prompt = eng.max_model_len - gen - 1
     bs = eng.block_size
     # A wave that cannot be admitted would wait for blocks forever.
     block_budget = int(eng.num_kv_blocks * 0.9)
@@ -129,10 +137,10 @@ def _warm_up(core: EngineCore) -> dict[str, float]:
             for width in eng.decode_buckets:
                 n = min(width, lanes)
                 length = min(bs, max_prompt)
-                if n <= prev or n * -(-(length + k + 1) // bs) > block_budget:
+                if n <= prev or n * -(-(length + gen) // bs) > block_budget:
                     break  # max_num_seqs (or the cache) never reaches it
                 t0 = time.perf_counter()
-                _run(core, prompts(n, length), 1 + k, temperature,
+                _run(core, prompts(n, length), gen, temperature,
                      f"{name}-decode{width}")
                 phases[f"decode B={width} k={k} {name}"] = (
                     time.perf_counter() - t0

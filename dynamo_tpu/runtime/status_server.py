@@ -67,6 +67,11 @@ SCHEDULER_GAUGES: dict[str, tuple[str, str]] = {
         "scheduler_token_budget",
         "Resolved per-step batched-token budget",
     ),
+    "async_exec": (
+        "scheduler_async_exec",
+        "1 when the engine serves with the one-step-ahead (pipelined) "
+        "loop: the loop it chose, not the option it was given",
+    ),
     # Decode megastep (PERF.md r9): the dispatch-amortization evidence.
     "megastep_k": (
         "scheduler_megastep_k",
@@ -168,6 +173,16 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
     "dispatches": (
         "engine_dispatches",
         "Device dispatches of step programs since start",
+    ),
+    "pipelined_dispatches": (
+        "engine_pipelined_dispatches",
+        "Dispatches enqueued while another step was in flight (the "
+        "one-step-ahead loop engaging)",
+    ),
+    "drains": (
+        "engine_pipeline_drains",
+        "In-flight steps committed early so a plan could preempt under "
+        "block pressure",
     ),
     "committed_tokens": (
         "engine_committed_tokens",

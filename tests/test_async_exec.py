@@ -1,6 +1,7 @@
 """Async pipelined execution loop (ISSUE 5): the one-step-ahead engine.
 
-The tentpole contract: with ``async_exec`` on, the engine plans and
+The tentpole contract: on the pipelined loop (the engine's own choice
+since ISSUE 25; ``async_exec`` pins one) the engine plans and
 enqueues step N+1 while step N executes on device (device-resident token
 feedback, optimistic cursor overlays, double-buffered host fetch) and the
 token stream stays BIT-IDENTICAL to the synchronous loop — greedy AND
@@ -61,6 +62,15 @@ def drive(core, seqs, max_steps=4000):
     return done, fins, lps
 
 
+def _every_landing_precedes_the_next_dispatch(log) -> bool:
+    """The synchronous loop's signature in the ``_exec_log`` hook."""
+    disp_pos = {n: i for i, (k, n) in enumerate(log) if k == "dispatch"}
+    land_pos = {n: i for i, (k, n) in enumerate(log) if k == "land"}
+    return bool(land_pos) and all(
+        land_pos[n] < disp_pos[n + 1] for n in land_pos if n + 1 in disp_pos
+    )
+
+
 def _mixed_workload(core):
     rng = np.random.RandomState(0)
     long_prompt = list(rng.randint(1, 200, size=200))
@@ -86,6 +96,60 @@ def test_async_constructs_on_pp_mesh():
         CFG, tiny_engine(async_exec=True), seed=0, pp_mesh=make_pp_mesh(2)
     )
     assert core.scheduler_stats()["pp_stages"] == 2
+
+
+# -- the loop the engine chooses ----------------------------------------------
+
+
+@pytest.mark.parametrize("overrides,pipelined", [
+    ({}, True),
+    ({"spec_decode": "ngram", "spec_device_draft": True, "megastep_k": 8}, True),
+    # Drafts from host history would be one step stale under the
+    # pipelined loop (no verify row forms): such engines stay synchronous.
+    ({"spec_decode": "ngram"}, False),
+    # The explicit override pins a loop whatever the engine would choose.
+    ({"async_exec": False}, False),
+    ({"spec_decode": "ngram", "async_exec": True}, True),
+])
+def test_loop_is_chosen_from_what_the_engine_was_built_with(overrides, pipelined):
+    core = EngineCore(CFG, tiny_engine(**overrides), seed=0)
+    assert core.pipelined is pipelined
+    assert core.scheduler_stats()["async_exec"] == int(pipelined)
+
+
+def test_sp_engine_builds_and_keeps_the_synchronous_loop():
+    from dynamo_tpu.ops.ring_attention import sequence_parallel_mesh
+
+    core = EngineCore(
+        CFG, tiny_engine(ring_prefill_threshold=64), seed=0,
+        sp_mesh=sequence_parallel_mesh(8),
+    )
+    assert core.pipelined is False
+    core._exec_log = []
+    seq = core.add_request(_req(list(range(1, 101)), "r", max_tokens=6, ignore_eos=True))
+    done, fins, _ = drive(core, [seq])
+    assert len(done["r"]) == 6 and fins["r"] == "length"
+    assert core._ring_prefills == 1
+    assert _every_landing_precedes_the_next_dispatch(core._exec_log)
+    assert core.exec_stats["pipelined_dispatches"] == 0
+    # Asking an sp engine for the pipelined loop outright is still refused.
+    with pytest.raises(ValueError, match="sp mesh"):
+        EngineCore(
+            CFG, tiny_engine(async_exec=True), seed=0,
+            sp_mesh=sequence_parallel_mesh(8),
+        )
+
+
+def test_host_drafted_engine_still_forms_verify_rows():
+    core = EngineCore(
+        CFG, tiny_engine(spec_decode="ngram", spec_k=4, megastep_k=1), seed=0
+    )
+    seq = core.add_request(
+        _req([3, 4, 5] * 6, "v", max_tokens=24, ignore_eos=True)
+    )
+    drive(core, [seq])
+    assert core.pipelined is False
+    assert core.spec_stats.verify_rows > 0
 
 
 # -- bit-identical parity -----------------------------------------------------
@@ -264,7 +328,9 @@ def test_steady_decode_dispatch_precedes_landing():
     device between consecutive dispatches, so the device queue is never
     empty when the host blocks (asserted via the dispatch/land event
     hook)."""
-    core = EngineCore(CFG, tiny_engine(async_exec=True, decode_chain=1), seed=0)
+    # Built with defaults: the engine chooses the pipelined loop itself.
+    core = EngineCore(CFG, tiny_engine(decode_chain=1), seed=0)
+    assert core.pipelined and core.scheduler_stats()["async_exec"] == 1
     core._exec_log = []
     seqs = [
         core.add_request(_req([1, 2, 3, 4], "a", max_tokens=20, ignore_eos=True)),
@@ -283,6 +349,9 @@ def test_steady_decode_dispatch_precedes_landing():
         if n < max_d and disp_pos.get(n + 1, 10 ** 9) > land_pos[n]
     ]
     assert violations == [], (violations, log[:12])
+    # Every dispatch but the first found a step in flight.
+    assert core.exec_stats["pipelined_dispatches"] == core.exec_stats["dispatches"] - 1
+    assert core.exec_stats["drains"] == 0
 
 
 def test_sync_loop_lands_before_next_dispatch():
@@ -292,12 +361,7 @@ def test_sync_loop_lands_before_next_dispatch():
     core._exec_log = []
     seq = core.add_request(_req([1, 2, 3], "a", max_tokens=8, ignore_eos=True))
     drive(core, [seq])
-    log = core._exec_log
-    disp_pos = {n: i for i, (k, n) in enumerate(log) if k == "dispatch"}
-    land_pos = {n: i for i, (k, n) in enumerate(log) if k == "land"}
-    assert all(
-        land_pos[n] < disp_pos[n + 1] for n in land_pos if n + 1 in disp_pos
-    )
+    assert _every_landing_precedes_the_next_dispatch(core._exec_log)
 
 
 def test_block_pressure_drains_pipeline_and_recovers():

@@ -126,8 +126,13 @@ def test_long_admit_never_stalls_decodes_beyond_chunk_count():
     long_prompt = list(np.random.RandomState(1).randint(1, 200, size=200))
 
     def run(scheduling):
+        # Synchronous loop: a step's outputs are read from that very call.
         core = EngineCore(
-            CFG, tiny_engine(scheduling=scheduling, prefill_chunk=chunk), seed=0
+            CFG,
+            tiny_engine(
+                scheduling=scheduling, prefill_chunk=chunk, async_exec=False
+            ),
+            seed=0,
         )
         d1 = core.add_request(_req([1, 2, 3, 4], "d1", max_tokens=40, ignore_eos=True))
         d2 = core.add_request(_req([5, 6, 7, 8], "d2", max_tokens=40, ignore_eos=True))
@@ -196,8 +201,11 @@ def test_sched_admit_and_chunk_spans_recorded():
 
 
 def test_scheduler_stats_gauges():
+    # Synchronous loop: the gauges of ONE committed step are read.
     core = EngineCore(
-        CFG, tiny_engine(scheduling="chunked", prefill_chunk=32), seed=0
+        CFG,
+        tiny_engine(scheduling="chunked", prefill_chunk=32, async_exec=False),
+        seed=0,
     )
     st = core.scheduler_stats()
     for key in (
@@ -231,8 +239,12 @@ def test_preempt_between_chunks_releases_exactly_once():
         ref_core, [ref_core.add_request(_req(prompt, "ref", max_tokens=5))]
     )
 
+    # Synchronous loop: the test preempts by hand between two steps,
+    # which needs a settled pipeline.
     core = EngineCore(
-        CFG, tiny_engine(scheduling="chunked", prefill_chunk=32), seed=0
+        CFG,
+        tiny_engine(scheduling="chunked", prefill_chunk=32, async_exec=False),
+        seed=0,
     )
     seq = core.add_request(_req(prompt, "L", max_tokens=5))
     core.step()  # first chunk only

@@ -298,7 +298,8 @@ def test_chain_length_respects_generation_budgets():
     """Short-budget batches must not run full decode chains (tool-call
     workloads: max_tokens=2 with decode_chain=32 used to burn 30 wasted
     fused steps per chain)."""
-    core = make_core(decode_chain=32, max_model_len=256)
+    # Synchronous loop: the budgets are read between two counted steps.
+    core = make_core(decode_chain=32, max_model_len=256, async_exec=False)
     s1 = core.add_request(_req([1, 2, 3], "a", max_tokens=2))
     s2 = core.add_request(_req([4, 5, 6], "b", max_tokens=3))
     core.step()  # prefill: each seq now has 1 generated token

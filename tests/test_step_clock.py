@@ -51,8 +51,10 @@ def _run(core, n_requests=3, max_tokens=17, pause_s=0.0):
 
 @pytest.fixture(scope="module")
 def ran_core():
-    """One tiny engine that has served a few requests, pipelined."""
-    core = EngineCore(CFG, tiny_engine(async_exec=True, megastep_k=8), seed=0)
+    """One tiny engine that has served a few requests on the loop it
+    chooses itself: pipelined."""
+    core = EngineCore(CFG, tiny_engine(megastep_k=8), seed=0)
+    assert core.pipelined
     _run(core)
     return core
 
@@ -126,6 +128,20 @@ def test_every_counter_and_label_is_on_metrics(ran_core):
         assert f"# TYPE dynamo_{name}_total counter" in text
         assert prometheus.total([text], f"dynamo_{name}_total") == stats[key], name
     assert stats["dispatches"] > 0 and stats["committed_tokens"] == 3 * 17
+    # The one-step-ahead loop's own pair: every dispatch but the first
+    # found a step in flight, and nothing forced the pipeline empty.
+    assert "dynamo_engine_pipelined_dispatches_total" in text
+    assert "dynamo_engine_pipeline_drains_total" in text
+    assert 0 < stats["pipelined_dispatches"] == stats["dispatches"] - 1
+    assert stats["drains"] == 0
+
+
+def test_a_synchronous_engine_counts_no_pipelined_dispatch():
+    core = EngineCore(CFG, tiny_engine(async_exec=False, megastep_k=8), seed=0)
+    _run(core)
+    assert core.exec_stats["dispatches"] > 0
+    assert core.exec_stats["pipelined_dispatches"] == 0
+    assert core.scheduler_stats()["async_exec"] == 0
 
 
 def test_occupancy_counters_count_where_the_batch_is_built(ran_core):

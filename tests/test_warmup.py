@@ -62,3 +62,62 @@ def test_the_call_goes_through_unchanged():
     assert _on_one_stack_chunk(lambda a, b: (b, a), 1, 2) == (2, 1)
     with pytest.raises(ZeroDivisionError):
         _on_one_stack_chunk(lambda: 1 / 0)
+
+
+# -- the pipelined loop's feedback programs (ISSUE 25) ---------------------------
+
+
+def test_serving_compiles_no_feedback_program_after_warm_up():
+    """The one-step-ahead loop feeds a step's token buffer from the step
+    before it on the device: a gather per buffer width, over a source
+    padded per output shape. Warm-up meets every width and both kinds of
+    source, so serving that crosses widths (4 <-> 8 lanes) and follows a
+    prefill wave compiles neither again."""
+    from dynamo_tpu.engine import EngineCore, tiny_engine, tiny_model
+    from dynamo_tpu.engine.warmup import warm_up
+    from dynamo_tpu.llm.protocols.common import (
+        PreprocessedRequest,
+        SamplingOptions,
+        StopConditions,
+    )
+
+    # Widths no other test's engine has: the jit caches of one function
+    # are shared by every engine in the process.
+    core = EngineCore(
+        tiny_model(),
+        tiny_engine(megastep_k=4, decode_buckets=(3, 7), max_num_seqs=7),
+        seed=0,
+    )
+    assert core.pipelined
+
+    def programs():
+        return core._feed._cache_size(), core._feed_pad._cache_size()
+
+    cold = programs()
+    phases = warm_up(core)
+    assert any(p.startswith("decode B=7") for p in phases)
+    warm = programs()
+    # a gather per decode width; a padding per output shape (a prefill
+    # wave's, and a megastep's per width)
+    assert (warm[0] - cold[0], warm[1] - cold[1]) == (2, 3)
+    fed = core.exec_stats["pipelined_dispatches"]
+
+    def req(i, n_prompt, max_tokens):
+        return core.add_request(PreprocessedRequest(
+            model="tiny", token_ids=list(range(1 + i, 1 + i + n_prompt)),
+            request_id=f"r{i}", sampling=SamplingOptions(temperature=0.0),
+            stop=StopConditions(max_tokens=max_tokens, ignore_eos=True)))
+
+    # Three lanes (width 3); three more arrive behind a prefill wave while
+    # those decode (width 7); the short ones end and the width falls back.
+    seqs = [req(i, 9, 1 + 4 * 6) for i in range(3)]
+    for _ in range(3):
+        core.step()
+    seqs += [req(i, 13, 1 + 4 * 2) for i in range(3, 6)]
+    for _ in range(400):
+        core.step()
+        if all(s.finish for s in seqs) and not core.has_work():
+            break
+    assert all(s.finish == "length" for s in seqs)
+    assert core.exec_stats["pipelined_dispatches"] > fed + 6
+    assert programs() == warm
