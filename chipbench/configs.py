@@ -2,11 +2,13 @@
 the top level, as they are run, plus how this repo serves it.
 
     source, reduced, assumed, deployment   what it is and how it was cut
-    <published keys>                       hidden_size, num_hidden_layers, ...
+    model_type                             names its module in architectures/
+    <published keys>                       the sizes, under the published names
     serve: {quant, engine: {...}}          weight type and EngineConfig overrides
 
 :func:`model_fields` maps the published keys onto the program's
-``ModelConfig`` fields; nothing else in the benchmark knows either naming.
+``ModelConfig`` fields with the key map of the configuration's
+architecture (``chipbench/architectures/<model_type>.py``).
 """
 
 from __future__ import annotations
@@ -14,23 +16,9 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-HERE = Path(__file__).resolve().parent
+from chipbench import architectures
 
-# published key -> ModelConfig field
-_KEYS = {
-    "vocab_size": "vocab_size",
-    "hidden_size": "hidden_size",
-    "intermediate_size": "intermediate_size",
-    "num_hidden_layers": "num_layers",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "rms_norm_eps",
-    "tie_word_embeddings": "tie_embeddings",
-    "attention_bias": "attn_qkv_bias",
-    "torch_dtype": "dtype",
-}
+HERE = Path(__file__).resolve().parent
 
 
 def load_config(name: str) -> dict:
@@ -42,9 +30,9 @@ def load_config(name: str) -> dict:
 
 def model_fields(cfg: dict) -> dict:
     """``ModelConfig(**model_fields(cfg))`` is the model as published."""
-    fields = {ours: cfg[theirs] for theirs, ours in _KEYS.items() if theirs in cfg}
-    fields.setdefault(
-        "head_dim", cfg["hidden_size"] // cfg["num_attention_heads"])
+    arch = architectures.of(cfg)
+    fields = arch.derived(cfg)
+    fields.update((ours, cfg[theirs]) for theirs, ours in arch.KEYS.items() if theirs in cfg)
     fields["name"] = cfg["name"]
     return fields
 

@@ -52,10 +52,24 @@ def pin_to_chip(i: int) -> dict:
     )
 
 
+# Seconds a child gets to leave after SIGTERM, and seconds a killed child
+# gets to be gone. On the v5e the worker left 3-5 s after its SIGTERM and
+# the frontend 0.1 s after its own in the open loop's cell, but 18-20 s in
+# the closed loop's, whose clients are cut with streams in flight (PERF.md,
+# Findings, PR 26): a frontend that takes longer is killed, and the worker
+# then ends the streams of a peer that died. A killed child is waited for
+# well beyond what any took so far, and one that stays is reported once
+# every other child has had both signals: the 10 s of before, uncaught,
+# ended a run with a traceback and left the store running.
+TERM_WAIT_S = 20.0
+REAP_WAIT_S = 120.0
+
+
 class Children:
     """Every process a run starts, reaped on the way out whatever
     happened: SIGTERM (the worker drains and releases the chip), then
-    SIGKILL of the whole process group for anything still alive."""
+    SIGKILL of the whole process group for anything still alive. Every
+    child gets both, however long another took."""
 
     def __init__(self, cwd: Path, log_dir: Path) -> None:
         self.cwd = cwd
@@ -81,21 +95,36 @@ class Children:
     def stop(self) -> None:
         # Last started, first stopped: the frontend, then the workers
         # (which drain against a store that is still there), then the store.
-        for _, proc, _ in reversed(self.procs):
+        left = []
+        for name, proc, _ in reversed(self.procs):
+            t0 = time.monotonic()
             if proc.poll() is None:
                 proc.send_signal(signal.SIGTERM)
             try:
-                proc.wait(20)
+                proc.wait(TERM_WAIT_S)
             except subprocess.TimeoutExpired:
                 pass
+            t1 = time.monotonic()
+            killed = proc.poll() is None
             # The group may hold grandchildren whether or not the leader
             # has gone; nothing of a run may outlive it.
             try:
                 os.killpg(proc.pid, signal.SIGKILL)
             except (ProcessLookupError, PermissionError):
                 pass
-            proc.wait(10)
+            try:
+                proc.wait(REAP_WAIT_S)
+            except subprocess.TimeoutExpired:
+                left.append(name)
+            how = f"left {t1 - t0:.1f} s after SIGTERM"
+            if killed:
+                how = (f"killed {t1 - t0:.1f} s after SIGTERM, "
+                       f"{'still there' if name in left else 'gone'} "
+                       f"{time.monotonic() - t1:.1f} s later")
+            print(f"[chipbench] {name} {how}", file=sys.stderr, flush=True)
         self.procs.clear()
+        if left:
+            raise HarnessFault(f"still running {REAP_WAIT_S:.0f} s after SIGKILL: {left}")
 
 
 def wait_for(predicate, children: Children, timeout: float, what: str,

@@ -11,17 +11,22 @@ the traced slice of the window. ``stat`` selects:
 - ``op_share``: device time of the ops whose name contains ``op`` inside
   ``module``, as % of all op time inside ``module``;
 - ``weight_floor_share``: the least a decode step could take, streaming
-  the weights once at the chip's HBM rate, as % of the measured step;
+  the weights it must read once at the chip's HBM rate, as % of the
+  measured step;
 - ``attn_roofline``: the least one decode-attention call could take,
   reading the K/V blocks in use once at the HBM rate, as % of the
   kernel's measured time per call (it is bandwidth-bound: one query token
   per sequence).
+
+The bytes of the last two are counted by the configuration's architecture
+(``chipbench/architectures``), the rates are ``chipbench/peaks.py``'s.
 """
 
 from __future__ import annotations
 
-from chipbench import peaks, stats
+from chipbench import architectures, peaks, stats
 from chipbench.configs import model_fields
+from chipbench.readers import prometheus
 
 
 def _megastep_k(ctx) -> int:
@@ -50,6 +55,20 @@ def _slice(ctx) -> tuple[float, float]:
     return a, a + ctx.harness["trace_seconds"]
 
 
+def _observed(ctx) -> architectures.Observed:
+    """The window as the worker's always-on counters saw it, for an
+    architecture whose bytes depend on the traffic. Live lanes per decode
+    dispatch is exact where every decode dispatch is a megastep (k > 1),
+    as in every cell so far, and None where the engine counted none."""
+    counter = lambda name, labels=None: prometheus.delta(  # noqa: E731
+        ctx, "worker", {"name": name, "labels": labels})
+    lanes = counter("dynamo_engine_decode_live_lanes_total")
+    dispatches = counter("dynamo_scheduler_megastep_dispatches_total")
+    return architectures.Observed(
+        decode_lanes_mean=lanes / dispatches if lanes is not None and dispatches else None,
+        counter=counter)
+
+
 def read(ctx, stat: str, module: str | None = None, op: str | None = None,
          per: str | None = None):
     tr = ctx.trace
@@ -69,12 +88,13 @@ def read(ctx, stat: str, module: str | None = None, op: str | None = None,
         inside = [(k, s) for k, s, _ in tr["ops"] if k.startswith(module + "/")]
         total = sum(s for _, s in inside)
         return 100.0 * sum(s for k, s in inside if op in k) / total if total else None
+    arch = architectures.of(ctx.config)
     mf = model_fields(ctx.config)
     pk = peaks.peaks(ctx.device_kind)
     if stat == "weight_floor_share":
         step = _module_ms(ctx, module, per_step=True)
-        floor_ms = 1000.0 * peaks.decode_weight_bytes(
-            mf, ctx.config["serve"].get("quant")) / pk.hbm_bytes_per_s
+        floor_ms = 1000.0 * arch.decode_weight_bytes(
+            mf, ctx.config["serve"].get("quant"), _observed(ctx)) / pk.hbm_bytes_per_s
         return 100.0 * floor_ms / step if step else None
     if stat == "attn_roofline":
         a, b = _slice(ctx)
@@ -102,7 +122,7 @@ def read(ctx, stat: str, module: str | None = None, op: str | None = None,
             live.append(int((r.prompt_tokens or len(r.req.prompt)) + sent))
         if not calls or not live:
             return None
-        need = peaks.attn_decode_bytes_per_layer(
+        need = arch.attn_decode_bytes_per_layer(
             live, mf, ctx.config["serve"]["engine"]["block_size"])
         return 100.0 * (need / pk.hbm_bytes_per_s) / (seconds / calls)
     raise ValueError(f"unknown trace stat {stat!r}")

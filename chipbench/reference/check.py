@@ -6,12 +6,14 @@ sends a greedy probe of token ids, with log-probabilities, through its
 own engine core (:func:`run_probe`): the engine prefills the prompt and
 decodes through its paged cache, and reports for every generated
 position the chosen token's log-probability and the top alternatives,
-computed from its raw logits. It then runs the reference's full forward
-pass over prompt + generated tokens on its own weights
-(:func:`score_request`), and the parent holds the two together with
-:func:`compare`. Sent a second time, the probe's prompt is a prefix-cache
-hit, so that path is compared as well. The HTTP path is not part of this
-check: every measured response is checked on it (``run.check_record``).
+computed from its raw logits. It then runs the full forward pass of its
+architecture's plain reference (``chipbench/architectures``, by the
+configuration's ``model_type``) over prompt + generated tokens on its own
+weights (:func:`score_request`), and the parent holds the two together
+with :func:`compare`. Sent a second time, the probe's prompt is a
+prefix-cache hit, so that path is compared as well. The HTTP path is not
+part of this check: every measured response is checked on it
+(``run.check_record``).
 
 Log-probabilities and not sampled tokens, because with random weights
 the largest logit changes on rounding; where the engine's greedy choice
@@ -20,6 +22,8 @@ is not the reference's, the two top logits must be within tolerance.
 
 from __future__ import annotations
 
+from chipbench import architectures
+from chipbench.configs import model_fields
 
 # Tolerance on a log-probability, and its reason. The engine keeps bf16
 # activations (8 significand bits: 2^-8 relative per rounding) through
@@ -35,85 +39,16 @@ from __future__ import annotations
 LOGPROB_ATOL = 0.15
 
 
-def _dequant(w):
-    """Engine weight leaf (plain, or int8 {"w", "scale"}) as float32."""
-    import jax.numpy as jnp
-
-    if isinstance(w, dict):
-        return w["w"].astype(jnp.float32) * w["scale"].astype(jnp.float32)
-    return w.astype(jnp.float32)
-
-
-def published_layout(params, l: int, mf: dict, mlp_blocks: int = 8):
-    """Layer ``l`` of the engine's parameter tree (fused, maybe int8,
-    tp=1 column order ``[q | k | v]`` and ``[gate | up]``) as the float32
-    unfused pieces the reference takes: (attention weights, mlp_norm,
-    iterator of MLP column blocks). Each piece is de-quantised when it is
-    asked for and dropped when the reference has used it."""
+def reference_logprobs(cfg: dict, params, ids: list[int], rows: list[int], **options):
+    """log-softmax [len(rows), vocab] (numpy, float32) of the plain
+    reference of the configuration's architecture on the engine's own
+    weights ``params`` at positions ``rows`` of the sequence ``ids``.
+    ``options`` are that reference's own (how finely it cuts its weights)."""
     import jax
-    import jax.numpy as jnp
-
-    lp = jax.tree.map(lambda a: a[l], params["layers"])
-    q_size = mf["num_heads"] * mf["head_dim"]
-    kv_size = mf["num_kv_heads"] * mf["head_dim"]
-    wqkv = _dequant(lp["wqkv"])
-    bqkv = (lp["bqkv"].astype(jnp.float32) if "bqkv" in lp
-            else jnp.zeros((q_size + 2 * kv_size,), jnp.float32))
-    attn = {
-        "attn_norm": lp["attn_norm"].astype(jnp.float32),
-        "wq": wqkv[:, :q_size], "wk": wqkv[:, q_size:q_size + kv_size],
-        "wv": wqkv[:, q_size + kv_size:],
-        "bq": bqkv[:q_size], "bk": bqkv[q_size:q_size + kv_size],
-        "bv": bqkv[q_size + kv_size:],
-        "wo": _dequant(lp["wo"]),
-    }
-    inter = mf["intermediate_size"]
-    edges = [inter * i // mlp_blocks for i in range(mlp_blocks + 1)]
-    cols = lambda w, a, b: _dequant(jax.tree.map(lambda x: x[..., a:b], w))  # noqa: E731
-
-    def blocks():
-        for a, b in zip(edges, edges[1:]):
-            down = lp["w_down"]
-            if isinstance(down, dict):   # scale is per output channel: all rows share it
-                w_down = down["w"][a:b].astype(jnp.float32) * down["scale"]
-            else:
-                w_down = down[a:b].astype(jnp.float32)
-            yield cols(lp["wgu"], a, b), cols(lp["wgu"], inter + a, inter + b), w_down
-
-    return attn, lp["mlp_norm"].astype(jnp.float32), blocks()
-
-
-def reference_logprobs(params, mf: dict, ids: list[int], rows: list[int],
-                       vocab_chunks: int = 16):
-    """log-softmax [len(rows), vocab] (numpy, float32) of the reference
-    on the engine's own weights ``params`` at positions ``rows`` of the
-    sequence ``ids``. ``mf`` are the ModelConfig fields."""
-    import jax
-    import jax.numpy as jnp
     import numpy as np
 
-    from chipbench.reference import qwen2
-
-    if int(params.get("fuse_tp", 1)) != 1:
-        raise ValueError("the reference reads the tp=1 fused layout only")
-    v = mf["vocab_size"]
-    edges = [v * i // vocab_chunks for i in range(vocab_chunks + 1)]
-
-    def lm_chunks():
-        for a, b in zip(edges, edges[1:]):
-            if mf.get("tie_embeddings"):
-                yield params["embed"][a:b].astype(jnp.float32).T
-            else:
-                yield _dequant(jax.tree.map(lambda x: x[..., a:b], params["lm_head"]))
-
-    logits = qwen2.forward(
-        ids, params["embed"],
-        (published_layout(params, l, mf) for l in range(mf["num_layers"])),
-        params["final_norm"].astype(jnp.float32), lm_chunks(),
-        n_heads=mf["num_heads"], n_kv=mf["num_kv_heads"],
-        head_dim=mf["head_dim"], theta=mf["rope_theta"],
-        eps=mf["rms_norm_eps"], rows=rows,
-    )
+    logits = architectures.of(cfg).reference_logits(
+        params, model_fields(cfg), ids, rows, **options)
     return np.asarray(jax.nn.log_softmax(logits, axis=-1), np.float32)
 
 
@@ -151,18 +86,59 @@ def run_probe(core, prompt_ids: list[int], max_tokens: int, top: int, tag: str) 
     }
 
 
+def score_probe(cfg: dict, params, prompt: list[int], probe: dict, **options) -> dict:
+    """The reference's side of one probe as :func:`run_probe` gave it: its
+    log-probability of every id the engine listed, its own arg-max and
+    that arg-max's log-probability, per generated position."""
+    import numpy as np
+
+    ids = prompt + probe["tokens"]
+    # Position p's logits predict token p+1: generated token j (at
+    # index len(prompt) + j) is predicted from row len(prompt) + j - 1.
+    rows = list(range(len(prompt) - 1, len(ids) - 1))
+    lp = reference_logprobs(cfg, params, ids, rows, **options)
+    best = lp.argmax(-1)
+    return {
+        "top_lps": [[float(lp[r, t]) for t in tops]
+                    for r, tops in enumerate(probe["top_ids"])],
+        "argmax": [int(b) for b in best],
+        "argmax_lp": [float(lp[r, b]) for r, b in enumerate(best)],
+        "finite": bool(np.isfinite(lp).all()),
+    }
+
+
+def _on_one_stack_chunk(fn, *args):
+    """Call ``fn(*args)`` with its whole Python call stack in one piece of
+    memory, so that ``correct_check_s`` does not depend on how deep the
+    caller happens to sit. CPython (3.11 and later) keeps frames in 16 KiB
+    chunks, mapped when a call does not fit and unmapped when it returns;
+    where a chunk's end falls just under a hot loop of JAX's tracing, every
+    call of that loop pays an mmap, a page fault and a munmap. Which loop
+    is hit depends on every frame's size from the thread's first frame
+    down: PR 26 added two frames above the reference and the check took 3 s
+    longer (PERF.md, Findings). A frame that reserves more than half a MiB
+    of evaluation stack gets a chunk of 1 MiB of its own, which holds every
+    frame below it. The program's warm-up does the same
+    (``dynamo_tpu.engine.warmup``); copied, because the yardstick takes no
+    code from the program."""
+    return fn(*args)
+
+
+_on_one_stack_chunk.__code__ = _on_one_stack_chunk.__code__.replace(
+    co_stacksize=1 << 16  # slots of 8 bytes: 512 KiB, so a 1 MiB chunk
+)
+
+
 def score_request(core, cfg: dict, body: dict) -> dict:
     """Runs in the worker. Sends the probe twice through the engine (the
     second send finds the prompt in the prefix cache), then runs the
     reference over prompt + generated tokens and returns both sides:
-    ``served`` as :func:`run_probe` gives it, and ``scored`` with the
-    reference's log-probability of every id the engine listed, its own
-    arg-max and that arg-max's log-probability, per generated position."""
-    import numpy as np
+    ``served`` as :func:`run_probe` gives it, and ``scored`` as
+    :func:`score_probe` does."""
+    return _on_one_stack_chunk(_score_request, core, cfg, body)
 
-    from chipbench.configs import model_fields
 
-    mf = model_fields(cfg)
+def _score_request(core, cfg: dict, body: dict) -> dict:
     prompt = list(body["prompt_ids"])
     served = [run_probe(core, prompt, body["max_tokens"], body["top"], tag)
               for tag in ("first", "repeat")]
@@ -171,20 +147,8 @@ def score_request(core, cfg: dict, body: dict) -> dict:
         if scored and probe["tokens"] == served[0]["tokens"] and (
                 probe["top_ids"] == served[0]["top_ids"]):
             scored.append(scored[0])   # the same sequence and the same ids asked
-            continue
-        ids = prompt + probe["tokens"]
-        # Position p's logits predict token p+1: generated token j (at
-        # index len(prompt) + j) is predicted from row len(prompt) + j - 1.
-        rows = list(range(len(prompt) - 1, len(ids) - 1))
-        lp = reference_logprobs(core.params, mf, ids, rows)
-        best = lp.argmax(-1)
-        scored.append({
-            "top_lps": [[float(lp[r, t]) for t in tops]
-                        for r, tops in enumerate(probe["top_ids"])],
-            "argmax": [int(b) for b in best],
-            "argmax_lp": [float(lp[r, b]) for r, b in enumerate(best)],
-            "finite": bool(np.isfinite(lp).all()),
-        })
+        else:
+            scored.append(score_probe(cfg, core.params, prompt, probe))
     return {"served": served, "scored": {"sequences": scored},
             "megastep_k": int(core.engine.megastep)}
 

@@ -201,7 +201,7 @@ def test_manifest_has_the_new_metrics_for_both_cells():
     assert manifest.problems(manifest.load(ROOT / TINY)) == []
 
 
-@pytest.mark.parametrize("workload,suffix", [("tiny-open-1", "chat"), ("tiny-closed-1", "batch")])
+@pytest.mark.parametrize("workload,suffix", [("tiny-open-ph", "chat"), ("tiny-closed-ph", "batch")])
 def test_new_metrics_in_the_cpu_rehearsal(workload, suffix):
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     proc = subprocess.run(

@@ -93,6 +93,7 @@ def _trace_ctx(**harness):
     return SimpleNamespace(
         config=load_config("qwen2.5-7b-int8"), device_kind="TPU v5 lite",
         harness=harness, records=[], unix_minus_monotonic=0.0,
+        scrape_open={}, scrape_close={},
         trace={"busy_s": 3.4, "window_s": 4.0, "devices": 1,
                "modules": {"_megastep_body": {"count": 30, "seconds": 3.36},
                            "_prefill_and_sample": {"count": 2, "seconds": 0.3}},

@@ -46,7 +46,6 @@ def test_the_worker_answers_with_its_megastep_length(tiny):
 @pytest.mark.parametrize("fault", ["drop_bias", "scale_wo", "shift_logprobs"])
 def test_a_fault_is_caught(tiny, fault):
     cfg, core, body, got = tiny
-    mf = model_fields(cfg)
     served = copy.deepcopy(got["served"])
     if fault == "shift_logprobs":
         # what a lower-precision engine looks like from outside
@@ -61,17 +60,8 @@ def test_a_fault_is_caught(tiny, fault):
         else:
             layers["wo"] = layers["wo"] * 1.5
         params["layers"] = layers
-        seqs = []
-        for probe in served:
-            ids = body["prompt_ids"] + probe["tokens"]
-            rows = list(range(len(body["prompt_ids"]) - 1, len(ids) - 1))
-            lp = check.reference_logprobs(params, mf, ids, rows, vocab_chunks=3)
-            best = lp.argmax(-1)
-            seqs.append({"top_lps": [[float(lp[r, t]) for t in tops]
-                                     for r, tops in enumerate(probe["top_ids"])],
-                         "argmax": [int(b) for b in best],
-                         "argmax_lp": [float(lp[r, b]) for r, b in enumerate(best)],
-                         "finite": True})
+        seqs = [check.score_probe(cfg, params, body["prompt_ids"], probe, vocab_chunks=3)
+                for probe in served]
         scored = {"sequences": seqs}
     assert not check.compare(served, scored)["ok"]
 
@@ -89,7 +79,7 @@ def test_chunked_vocab_equals_whole(tiny):
     cfg, core, body, _ = tiny
     mf = model_fields(cfg)
     ids = body["prompt_ids"]
-    a = check.reference_logprobs(core.params, mf, ids, [len(ids) - 1], vocab_chunks=1)
-    b = check.reference_logprobs(core.params, mf, ids, [len(ids) - 1], vocab_chunks=7)
+    a = check.reference_logprobs(cfg, core.params, ids, [len(ids) - 1], vocab_chunks=1)
+    b = check.reference_logprobs(cfg, core.params, ids, [len(ids) - 1], vocab_chunks=7)
     np.testing.assert_allclose(a, b, atol=1e-6)
     assert a.shape == (1, mf["vocab_size"])
