@@ -81,7 +81,7 @@ async def _serve_kv_fetch(runtime, namespace: str, component: str, core) -> None
         yield {
             wire.KV_VERSION: 2,
             wire.KV_SHAPE: [
-                core.cfg.num_layers, core.engine.block_size,
+                core.cfg.num_cache_layers, core.engine.block_size,
                 2 * core.cfg.num_kv_heads, core.cfg.head_dim,
             ],
             # "int8" pages ship as the canonical packed buffer (int8 kv
@@ -272,7 +272,7 @@ def build_engine(
             raise ValueError(f"--moe-dispatch set but preset {preset!r} is dense")
         model_cfg = dataclasses.replace(model_cfg, moe_dispatch=moe_dispatch)
     overrides = dict(engine_overrides or {})
-    if preset in ("tiny", "tiny-moe") and model_path is None:
+    if preset in ("tiny", "tiny-moe", "tiny-loop") and model_path is None:
         engine_cfg = tiny_engine(**overrides)
     else:
         # Checkpoint serving uses the full-size engine defaults (the
@@ -287,6 +287,14 @@ def build_engine(
             raise ValueError("--pp is mutually exclusive with --tp/--dp/--sp for now")
         from dynamo_tpu.parallel.pipeline import make_pp_mesh
 
+        if model_cfg.ut_steps > 1:
+            raise ValueError(
+                f"--pp {pp} with a looped model (ut_steps="
+                f"{model_cfg.ut_steps}): the pipeline stages the layer "
+                "axis once through, and a looped stack would have to go "
+                "round the stages ut_steps times; serve it with --tp or "
+                "on one chip"
+            )
         pp_mesh = make_pp_mesh(pp)
         # Fail fast with CLI-pointed errors: these used to surface as a
         # late EngineCore construction failure deep inside shard setup.
@@ -1307,8 +1315,8 @@ def main() -> None:
     ap.add_argument("--model-name", default="tiny")
     ap.add_argument(
         "--preset", default="tiny",
-        choices=["tiny", "tiny-moe", "llama3-1b", "llama3-8b", "llama3-70b",
-                 "qwen2-7b", "mixtral-8x7b"],
+        choices=["tiny", "tiny-moe", "tiny-loop", "llama3-1b", "llama3-8b",
+                 "llama3-70b", "qwen2-7b", "mixtral-8x7b", "ouro-2.6b"],
     )
     ap.add_argument("--namespace", default="dynamo")
     ap.add_argument("--component", default=None, help="defaults by role")

@@ -55,10 +55,41 @@ class ModelConfig:
     # the mode for many-host expert fleets, SURVEY.md §2.6 /
     # dsr1-wideep-h100.md:8).
     moe_dispatch: str = "replicated"
+    # Looped stack (Ouro, "Scaling Latent Reasoning via Looped Language
+    # Models"): the SAME num_layers weight layers run ut_steps times a
+    # token, the final norm after every pass, and each (pass, layer)
+    # keeps K/V of its own — num_cache_layers planes of cache for
+    # num_layers layers of weights. 1 = the classic single pass.
+    ut_steps: int = 1
+    # Sandwich norm: a second RMSNorm on each sub-layer's OUTPUT before
+    # the residual add (layers gain attn_post_norm / mlp_post_norm).
+    sandwich_norm: bool = False
+    # Looped models exit early once the gate's cumulated probability
+    # passes this; 1.0 = never (every token takes every pass). Adaptive
+    # exit makes compute per token vary and is not implemented.
+    early_exit_threshold: float = 1.0
+
+    def __post_init__(self):
+        if self.ut_steps < 1:
+            raise ValueError(f"ut_steps={self.ut_steps} must be >= 1")
+        if self.ut_steps > 1 and self.early_exit_threshold < 1.0:
+            raise NotImplementedError(
+                f"early_exit_threshold={self.early_exit_threshold} < 1 with "
+                f"ut_steps={self.ut_steps}: adaptive exit (compute per token "
+                "varies) is not implemented; every token takes every pass "
+                "only at threshold 1"
+            )
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def num_cache_layers(self) -> int:
+        """Planes of K/V a token holds: one per (pass, weight layer), in
+        slot order ``u * num_layers + l`` wherever a block leaves the
+        device. Equals num_layers for every single-pass model."""
+        return self.num_layers * self.ut_steps
 
     @property
     def jax_dtype(self):
@@ -79,9 +110,11 @@ class ModelConfig:
             h * (self.q_size + 2 * self.kv_size)  # wq, wk, wv
             + self.q_size * h                     # wo
             + 3 * h * i                           # gate, up, down
-            + 2 * h                               # norms
+            + (4 if self.sandwich_norm else 2) * h  # norms
         )
         total = v * h + self.num_layers * per_layer + h + (0 if self.tie_embeddings else h * v)
+        if self.ut_steps > 1:
+            total += h + 1                        # exit gate: w [h], b []
         bytes_per = jnp.dtype(self.jax_dtype).itemsize
         return total * bytes_per
 
@@ -95,7 +128,10 @@ class ModelConfig:
         )
         bytes_per = jnp.dtype(self.jax_dtype).itemsize
         int8_bytes = self.num_layers * proj_per_layer
-        bf16_bytes = (v * h + 2 * h * self.num_layers + h) * bytes_per
+        norms = 4 if self.sandwich_norm else 2
+        bf16_bytes = (v * h + norms * h * self.num_layers + h) * bytes_per
+        if self.ut_steps > 1:
+            bf16_bytes += (h + 1) * bytes_per     # exit gate
         if not self.tie_embeddings:
             int8_bytes += h * v  # lm_head quantized too
         return int8_bytes + bf16_bytes
@@ -367,6 +403,26 @@ def qwen2_7b() -> ModelConfig:
     )
 
 
+def ouro_2_6b() -> ModelConfig:
+    """Ouro-2.6B (ByteDance, model_type "ouro"): 48 plain multi-head
+    layers with sandwich norms, run 4 times a token — 192 planes of K/V
+    (1.5 MB a token in bf16) for 5.3 GB of weights."""
+    return ModelConfig(
+        name="ouro-2.6b",
+        vocab_size=49152,
+        hidden_size=2048,
+        intermediate_size=5632,
+        num_layers=48,
+        num_heads=16,
+        num_kv_heads=16,
+        head_dim=128,
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-6,
+        ut_steps=4,
+        sandwich_norm=True,
+    )
+
+
 def mixtral_8x7b() -> ModelConfig:
     return ModelConfig(
         name="mixtral-8x7b",
@@ -398,6 +454,25 @@ def tiny_moe(vocab_size: int = 384) -> ModelConfig:
         tie_embeddings=True,
         num_experts=4,
         num_experts_per_tok=2,
+    )
+
+
+def tiny_loop(vocab_size: int = 384) -> ModelConfig:
+    """The looped stack (Ouro's shape) at test size: 3 layers x 3 passes."""
+    return ModelConfig(
+        name="tiny-loop",
+        vocab_size=vocab_size,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=3,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-6,
+        dtype="float32",
+        ut_steps=3,
+        sandwich_norm=True,
     )
 
 
@@ -437,6 +512,8 @@ PRESETS = {
     "llama3-1b": llama3_1b,
     "qwen2-7b": qwen2_7b,
     "mixtral-8x7b": mixtral_8x7b,
+    "ouro-2.6b": ouro_2_6b,
     "tiny": tiny_model,
     "tiny-moe": tiny_moe,
+    "tiny-loop": tiny_loop,
 }

@@ -1236,49 +1236,49 @@ class EngineCore:
         # or quantized — vs the pp-stacked array / pp-stacked quantized
         # dict): a block sliced from any of them packs to the same
         # canonical bytes.
-        from dynamo_tpu.engine.kv_quant import is_quantized_cache
+        # A layer's array holds ut_steps planes of pages (model.init_cache;
+        # one plane for every model but a looped one): block b is page
+        # u*plane + b of plane u, and a block on the host or the wire
+        # carries all of them, slot u*L + l. The per-layer tuple's
+        # helpers map over a layer entry's leaves, so a plain array and
+        # an int8 {"kv", "scale"} pair take the same lines.
+        ut = model_cfg.ut_steps
 
-        def _slice_page_fn(cache, bid):
-            if isinstance(cache, tuple):
-                if is_quantized_cache(cache):  # int8: kv + scale pages
-                    return {
-                        "kv": jnp.stack([c["kv"][bid] for c in cache]),
-                        "scale": jnp.stack([c["scale"][bid] for c in cache]),
-                    }
-                return jnp.stack([c[bid] for c in cache])        # [L, ps, 2kv, d]
-            if isinstance(cache, dict):  # pp-stacked int8: same host layout
-                return {k: v[:, bid] for k, v in cache.items()}
-            return cache[:, bid]
+        def _pages_of(arr, ids):
+            """Block ids [n] -> their page in every plane of ``arr``, [ut, n]."""
+            plane = arr.shape[0] // ut
+            return ids[None, :] + plane * jnp.arange(ut, dtype=ids.dtype)[:, None]
 
         def _gather_pages_fn(cache, ids):
             if isinstance(cache, tuple):
-                if is_quantized_cache(cache):
-                    return {
-                        "kv": jnp.stack([c["kv"][ids] for c in cache], axis=1),
-                        "scale": jnp.stack(
-                            [c["scale"][ids] for c in cache], axis=1
-                        ),
-                    }  # leaves [n, L, ...]
-                return jnp.stack([c[ids] for c in cache], axis=1)  # [n, L, ...]
-            if isinstance(cache, dict):
+                def slots(*layers):  # L x [pages, ...] -> [n, ut*L, ...]
+                    rows = jnp.stack([c[_pages_of(c, ids)] for c in layers], axis=1)
+                    return jnp.moveaxis(rows, 2, 0).reshape(
+                        ids.shape[0], -1, *layers[0].shape[1:]
+                    )
+
+                return jax.tree.map(slots, *cache)
+            if isinstance(cache, dict):  # pp-stacked int8: same host layout
                 return {
                     k: jnp.moveaxis(v[:, ids], 1, 0) for k, v in cache.items()
                 }  # leaves [n, L, ...]
             return jnp.moveaxis(cache[:, ids], 1, 0)
 
+        def _slice_page_fn(cache, bid):  # one block: leaves [ut*L, ps, 2kv, d]
+            return jax.tree.map(lambda a: a[0], _gather_pages_fn(cache, bid[None]))
+
         def _scatter_pages_fn(cache, ids, pages):
             if isinstance(cache, tuple):
-                if is_quantized_cache(cache):
-                    return tuple(
-                        {
-                            "kv": c["kv"].at[ids].set(pages["kv"][:, l]),
-                            "scale": c["scale"].at[ids].set(pages["scale"][:, l]),
-                        }
-                        for l, c in enumerate(cache)
-                    )
-                return tuple(
-                    c.at[ids].set(pages[:, l]) for l, c in enumerate(cache)
-                )
+                n, L = ids.shape[0], len(cache)
+
+                def put(l):
+                    def leaf(c, p):  # p [n, ut*L, ...] -> layer l's [ut, n, ...]
+                        rows = p.reshape(n, ut, L, *p.shape[2:])[:, :, l]
+                        return c.at[_pages_of(c, ids)].set(jnp.moveaxis(rows, 1, 0))
+
+                    return jax.tree.map(leaf, cache[l], pages)
+
+                return tuple(put(l) for l in range(L))
             if isinstance(cache, dict):
                 return {
                     k: v.at[:, ids].set(jnp.moveaxis(pages[k], 0, 1))
@@ -1288,13 +1288,9 @@ class EngineCore:
 
         def _copy_pages_fn(src, dst, sids, dids):
             if isinstance(dst, tuple):
-                if is_quantized_cache(dst):
-                    return tuple(
-                        {k: d[k].at[dids].set(s[k][sids]) for k in d}
-                        for s, d in zip(src, dst)
-                    )
-                return tuple(
-                    d.at[dids].set(s[sids]) for s, d in zip(src, dst)
+                return jax.tree.map(
+                    lambda s, d: d.at[_pages_of(d, dids)].set(s[_pages_of(s, sids)]),
+                    src, dst,
                 )
             if isinstance(dst, dict):
                 return {
@@ -1435,6 +1431,9 @@ class EngineCore:
             "megastep_useful_lane_iters": 0,
             "ragged_real_tokens": 0,
             "ragged_bucket_tokens": 0,
+            # Looped stacks (ISSUE 27): passes over the layer stack, per
+            # live lane and iteration (_mark_dispatch).
+            "layer_passes": 0,
         }
         # Crash/stall flight recorder (ISSUE 13): one record per step
         # with outputs — step shape, lane cursors, cumulative dispatch
@@ -1777,10 +1776,19 @@ class EngineCore:
         """Open the step clock's ``dispatch`` phase (the jitted call, until
         it returns). Its profile annotation carries the dispatch's shape:
         kind (prefill / decode / megastep / mixed), live lanes against
-        the padded width, fused iterations, real against padded tokens,
-        and whether a step was in flight when it was enqueued."""
+        the padded width, fused iterations, the passes each iteration
+        makes over the layer stack, real against padded tokens, and
+        whether a step was in flight when it was enqueued.
+
+        Also counts ``layer_passes``: a pass over the stack for each live
+        lane of each fused iteration (a prefill wave: each sequence,
+        once). Over the committed tokens it reads ``ut_steps`` where no
+        iteration is wasted; a looped model with adaptive exit would
+        read less."""
+        ut = self.cfg.ut_steps
+        self.exec_stats["layer_passes"] += lanes * k * ut
         self._t_dispatch = self.clock.mark(
-            "dispatch", kind=kind, lanes=lanes, width=width, k=k,
+            "dispatch", kind=kind, lanes=lanes, width=width, k=k, ut_steps=ut,
             real=real, padded=padded, pipelined=self._inflight is not None,
         )
 
@@ -1943,7 +1951,7 @@ class EngineCore:
 
     def _page_geometry(self) -> tuple[int, int, int, int]:
         return (
-            self.cfg.num_layers,
+            self.cfg.num_cache_layers,
             self.engine.block_size,
             self.cfg.num_kv_heads,
             self.cfg.head_dim,
@@ -4497,7 +4505,7 @@ class EngineCore:
                 raise KeyError(f"no held blocks for request {request_id}")
             self._touch_hold(request_id)
             shape = [
-                self.cfg.num_layers,
+                self.cfg.num_cache_layers,
                 self.engine.block_size,
                 2 * self.cfg.num_kv_heads,
                 self.cfg.head_dim,
@@ -4706,7 +4714,7 @@ class EngineCore:
         import ml_dtypes  # noqa: F401  (registers bfloat16 with numpy)
 
         expected = (
-            self.cfg.num_layers,
+            self.cfg.num_cache_layers,
             self.engine.block_size,
             2 * self.cfg.num_kv_heads,
             self.cfg.head_dim,
@@ -4887,8 +4895,9 @@ class EngineCore:
         bs = self.engine.block_size
         n_pages = -(-bucket // bs)
         if getattr(self, "_embed_scratch", None) is None:
+            pages = -(-self.engine.prefill_buckets[-1] // bs) + 1
             shape = (
-                -(-self.engine.prefill_buckets[-1] // bs) + 1,
+                self.cfg.ut_steps * pages,  # a plane per pass (model.init_cache)
                 bs,
                 2 * self.cfg.num_kv_heads,
                 self.cfg.head_dim,
@@ -4901,14 +4910,14 @@ class EngineCore:
                 _program(embed_forward, cfg=self.cfg, engine=self.engine, mesh=self.mesh),
                 donate_argnums=(1,),
             )
-        garbage = self._embed_scratch[0].shape[0] - 1
+        garbage = self._embed_scratch[0].shape[0] // self.cfg.ut_steps - 1
         tokens = np.zeros(bucket, np.int32)
         tokens[:T] = token_ids
         valid = np.zeros(bucket, bool)
         valid[:T] = True
         write_pages = np.full(bucket, garbage, np.int32)
         write_pages[:T] = np.arange(T) // bs
-        tables = np.full((1, self._embed_scratch[0].shape[0] - 1), garbage, np.int32)
+        tables = np.full((1, garbage), garbage, np.int32)
         tables[0, :n_pages] = np.arange(n_pages)
         pooled, self._embed_scratch = self._embed_fn(
             self.params,
@@ -4952,6 +4961,16 @@ class EngineCore:
         # k*M + pp - 1 wavefront rounds (1.0 on non-pp engines: the
         # degenerate pp=1 pipe has no bubble).
         st["pp_stages"] = self._pp
+        # What the loop costs the cache: planes of K/V per token (layers
+        # x passes) and their bytes at the cache's dtype.
+        from dynamo_tpu.engine.kv_quant import kv_page_bytes
+
+        st["kv_cache_layers"] = self.cfg.num_cache_layers
+        st["kv_bytes_per_token"] = kv_page_bytes(
+            self.cfg.num_cache_layers, 1, self.cfg.num_kv_heads,
+            self.cfg.head_dim, self.engine.kv_dtype,
+            np.dtype(self.cfg.jax_dtype).itemsize,
+        )
         k = max(1, self.engine.megastep)
         km = k * self._pp_micro
         st["pp_pipe_occupancy"] = km / (km + self._pp - 1)
@@ -4982,7 +5001,7 @@ class EngineCore:
             "kv_dtype": self.engine.kv_dtype,
             "kv_dtype_int8": 1 if self.engine.kv_quantized else 0,
             "bytes_per_block": kv_page_bytes(
-                self.cfg.num_layers, self.engine.block_size,
+                self.cfg.num_cache_layers, self.engine.block_size,
                 self.cfg.num_kv_heads, self.cfg.head_dim,
                 self.engine.kv_dtype,
                 np.dtype(self.cfg.jax_dtype).itemsize,

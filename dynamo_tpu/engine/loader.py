@@ -44,6 +44,14 @@ def config_from_hf(path: str | Path) -> ModelConfig:
         # Qwen2-family checkpoints carry qkv biases (the architecture's
         # one delta from llama; qwen3 dropped them again).
         attn_qkv_bias=hf.get("model_type") == "qwen2",
+        # Ouro (looped): the layers run total_ut_steps times a token, with
+        # sandwich norms (modeling_ouro.py: input_layernorm_2,
+        # post_attention_layernorm_2) and an exit gate. ModelConfig
+        # refuses an early_exit_threshold under 1: adaptive exit is not
+        # implemented.
+        ut_steps=hf.get("total_ut_steps", 1),
+        early_exit_threshold=hf.get("early_exit_threshold", 1.0),
+        sandwich_norm=hf.get("model_type") == "ouro",
     )
 
 
@@ -92,7 +100,7 @@ def _quantize_np(w: np.ndarray) -> dict[str, Any]:
 def load_hf_llama(
     path: str | Path, dtype=None, tp: int = 1, quant: str | None = None
 ) -> tuple[ModelConfig, Any]:
-    """Returns (ModelConfig, params pytree) from an HF llama/qwen2
+    """Returns (ModelConfig, params pytree) from an HF llama/qwen2/ouro
     checkpoint.
 
     ``tp`` fixes the shard-blocked layout of the fused wqkv/wgu projections
@@ -157,6 +165,12 @@ def load_hf_llama(
             ],
             tp,
         )
+    if cfg.sandwich_norm:
+        for ours, theirs in (("attn_post_norm", "input_layernorm_2"),
+                             ("mlp_post_norm", "post_attention_layernorm_2")):
+            layers[ours] = np.stack(
+                [t(f"model.layers.{i}.{theirs}.weight") for i in range(L)]
+            )
     np_dt = np.dtype(dt)  # bf16 numpy dtype via jax's ml_dtypes registration
 
     def place(name: str, v: np.ndarray):
@@ -172,6 +186,11 @@ def load_hf_llama(
         # params match the mesh (EngineCore asserts fuse_tp == mesh tp).
         "fuse_tp": np.asarray(tp, np.int32),
     }
+    if cfg.ut_steps > 1:
+        params["exit_gate"] = {  # Linear(h, 1): weight [1, h], bias [1]
+            "w": np.asarray(t("model.early_exit_gate.weight").reshape(-1), np_dt),
+            "b": np.asarray(t("model.early_exit_gate.bias").reshape(()), np_dt),
+        }
     if not cfg.tie_embeddings:
         head = t("lm_head.weight").T
         params["lm_head"] = (

@@ -25,6 +25,14 @@ v5e, round 2):
   ``[q_s | k_s | v_s]`` per shard ``s`` — so a plain ``P(None, None, "tp")``
   sharding gives every shard its own (q, k, v) block and
   :func:`split_qkv` reassembles the natural head order.
+- **Looped stacks roll the passes, not the layers**
+  (``cfg.ut_steps > 1``, Ouro): the same unrolled layer body runs
+  ``ut_steps`` times under ONE ``lax.fori_loop`` (:func:`_run_stack`), so
+  a program holds ``num_layers`` bodies and not ``num_layers x
+  ut_steps``. Each layer's page array holds ``ut_steps`` PLANES of
+  ``num_kv_blocks + 1`` pages; pass ``u`` reads and writes plane ``u`` by
+  adding ``u x pages-per-plane`` to the page ids, so the allocator, the
+  block tables and the attention kernel see nothing new.
 """
 
 from __future__ import annotations
@@ -124,6 +132,7 @@ def init_params_quantized(rng: jax.Array, cfg: ModelConfig, tp: int = 1) -> Para
             "final_norm": jnp.ones((h,), dt),
             "fuse_tp": jnp.asarray(tp, jnp.int32),
         }
+        _init_loop_extras(rng, cfg, params)
         if not cfg.tie_embeddings:
             params["lm_head"] = quantize_weight(
                 dense(jax.random.fold_in(rng, 99), (h, v), h)
@@ -242,9 +251,42 @@ def init_params(rng: jax.Array, cfg: ModelConfig, tp: int = 1) -> Params:
         # pytree so serving can assert params match the mesh.
         "fuse_tp": jnp.asarray(tp, jnp.int32),
     }
+    _init_loop_extras(rng, cfg, params)
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(jax.random.fold_in(rng, 99), (h, v), h)
     return params
+
+
+def _init_loop_extras(rng: jax.Array, cfg: ModelConfig, params: Params) -> None:
+    """The leaves only a looped / sandwich-norm model has, added in place:
+    the two output norms per layer and the exit gate ``Linear(h, 1)``.
+
+    An output norm's weight is the size of its sub-layer's whole
+    contribution to the residual stream, so it is drawn around
+    ``sqrt(2 / L)``: the ``2 L`` branches of a pass then move the state
+    by about twice its own norm, whatever the depth (GPT-2-style inits
+    scale a residual branch by ``1 / sqrt(2 L)`` for the same reason).
+    Drawn around 1, a pass multiplies the state's norm by ``sqrt(2 L)``
+    and, on random weights, what bfloat16 rounds off grows from pass to
+    pass until it parts from float32 whatever the code does (PERF.md, PR
+    27: 0.43 against a tolerance of 0.15 at 48 x 4, 0.05 with this
+    scale), and no comparison with a reference means anything. Each
+    weight varies by 10% around the scale, so that a norm applied with
+    the wrong weight, or not at all, changes the logits."""
+    h, L, dt = cfg.hidden_size, cfg.num_layers, cfg.jax_dtype
+    if cfg.sandwich_norm:
+        for i, name in enumerate(("attn_post_norm", "mlp_post_norm")):
+            key = jax.random.fold_in(rng, 21 + i)
+            params["layers"][name] = (
+                (2.0 / L) ** 0.5
+                * (1.0 + 0.1 * jax.random.normal(key, (L, h), jnp.float32))
+            ).astype(dt)
+    if cfg.ut_steps > 1:
+        key = jax.random.fold_in(rng, 31)
+        params["exit_gate"] = {
+            "w": (jax.random.normal(key, (h,), jnp.float32) * h ** -0.5).astype(dt),
+            "b": jnp.zeros((), dt),
+        }
 
 
 def params_fuse_tp(params: Params) -> int:
@@ -272,10 +314,15 @@ def init_cache(cfg: ModelConfig, engine: EngineConfig, dtype=None) -> tuple:
     ``{"kv": int8 pages, "scale": f32 [n_pages, ps, 2*n_kv]}`` dict —
     symmetric per-slot-per-head quantized storage with the scale pages
     carried alongside (engine/kv_quant.py); the tuple structure and
-    every index in it are unchanged."""
+    every index in it are unchanged.
+
+    A looped model (``cfg.ut_steps > 1``) keeps ``ut_steps`` planes of
+    ``num_kv_blocks + 1`` pages in each layer's array, plane ``u`` for
+    pass ``u``: block ``b`` of pass ``u`` is page ``u * (num_kv_blocks +
+    1) + b``, and every plane ends in a garbage page of its own."""
     dtype = dtype or cfg.jax_dtype
     shape = (
-        engine.num_kv_blocks + 1,
+        cfg.ut_steps * (engine.num_kv_blocks + 1),
         engine.block_size,
         2 * cfg.num_kv_heads,
         cfg.head_dim,
@@ -646,12 +693,15 @@ def dense_layer(
     sm_scale = cfg.head_dim ** -0.5
     if rope_cs is None:
         rope_cs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    # The dtype of what the matmuls read; ``x`` itself is wider in a
+    # looped stack (:func:`_run_stack`), and the same everywhere else.
+    dt = lp["attn_norm"].dtype
     with jax.named_scope("qkv"):
-        y = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        y = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps).astype(dt)
         qkv = _dot(y, lp["wqkv"])
         if "bqkv" in lp:  # Qwen2-family qkv bias (fused column order)
             qkv = qkv + lp["bqkv"]
-        qkv = qkv.astype(x.dtype)
+        qkv = qkv.astype(dt)
         q, k, v = split_qkv(qkv, cfg, tp)
         q = rope_apply(q.reshape(T, cfg.num_heads, cfg.head_dim), *rope_cs)
         k = rope_apply(k.reshape(T, cfg.num_kv_heads, cfg.head_dim), *rope_cs)
@@ -673,11 +723,26 @@ def dense_layer(
                 q, kv_pages, kv_lens, block_tables, cu_q_lens, num_seqs,
                 sm_scale=sm_scale, kv_scales=kv_scales,
             )
+    return _attn_out_and_mlp(x, attn.reshape(T, cfg.q_size), lp, cfg, tp, mesh), cache_l
+
+
+def _attn_out_and_mlp(x, attn, lp, cfg: ModelConfig, tp: int, mesh):
+    """The block after attention: ``x + attn Wo``, then ``x + mlp(norm x)``.
+    With ``cfg.sandwich_norm`` each sub-layer's OUTPUT is normed once more
+    before its residual add (``attn_post_norm`` / ``mlp_post_norm``),
+    inside that sub-layer's scope."""
     with jax.named_scope("o_proj"):
-        x = x + _dot(attn.reshape(T, cfg.q_size), lp["wo"]).astype(x.dtype)
+        a = _dot(attn, lp["wo"]).astype(x.dtype)
+        if cfg.sandwich_norm:
+            a = rms_norm(a, lp["attn_post_norm"], cfg.rms_norm_eps)
+        x = x + a
     with jax.named_scope("mlp"):
-        x = x + _mlp(rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps), lp, cfg, tp, mesh)
-    return x, cache_l
+        y = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps).astype(lp["mlp_norm"].dtype)
+        m = _mlp(y, lp, cfg, tp, mesh)
+        if cfg.sandwich_norm:
+            m = rms_norm(m, lp["mlp_post_norm"], cfg.rms_norm_eps)
+        x = x + m
+    return x
 
 
 # -- the unified forward ----------------------------------------------------
@@ -731,9 +796,12 @@ def forward_hidden(
     mesh=None,
     mm_embeds=None,
     mm_mask=None,
+    want_gates: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """The transformer stack up to the final norm: returns (hidden states
-    [T, h], cache). Shared by the logits path (:func:`forward_tokens`)
+    [T, h], cache), and with ``want_gates`` (looped models) the exit
+    gate's probability after every pass, ``[ut_steps, T]``, as a third.
+    Shared by the logits path (:func:`forward_tokens`)
     and the embeddings path (reference serves /v1/embeddings through its
     engines, http/service/service_v2.rs:277-336).
 
@@ -746,20 +814,105 @@ def forward_hidden(
         if mm_embeds is not None:
             x = jnp.where(mm_mask[:, None], mm_embeds.astype(x.dtype), x)
         rope_cs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-    lp_all = params["layers"]
 
-    layer_caches = list(cache)
-    for l in range(cfg.num_layers):
-        lp = jax.tree.map(lambda a: a[l], lp_all)
-        x, layer_caches[l] = dense_layer(
-            x, lp, layer_caches[l], positions, write_pages, write_offs,
+    def layer(x, lp, cache_l, write_pages, block_tables):
+        return dense_layer(
+            x, lp, cache_l, positions, write_pages, write_offs,
             kv_lens, block_tables, cu_q_lens, num_seqs, cfg,
             tp=tp, mesh=mesh, rope_cs=rope_cs,
         )
 
-    with jax.named_scope("lm_head"):  # the final norm feeds nothing else
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    return x, tuple(layer_caches)
+    return _run_stack(
+        params, cache, x, write_pages, block_tables, layer, cfg,
+        want_gates=want_gates,
+    )
+
+
+def _run_stack(
+    params, cache, x, write_pages, block_tables, layer, cfg: ModelConfig,
+    want_gates: bool = False,
+):
+    """The layer stack and the final norm over ``x`` ``[T, h]``: returns
+    (normed hidden states, cache). ``layer(x, lp, cache_l, write_pages,
+    block_tables) -> (x, cache_l)`` is one block on one layer's pages.
+
+    A single-pass model runs the unrolled layers once, then the final
+    norm (scope ``lm_head``, which it alone feeds). A looped model
+    (``cfg.ut_steps > 1``) runs the SAME unrolled body ``ut_steps`` times
+    under one ``lax.fori_loop`` whose carry is ``(x, caches)`` — the
+    megastep's scan carries the caches the same way — each pass ending in
+    the final norm (scope ``loop_norm``): its output feeds the next pass,
+    and the last pass's the ``lm_head``. Pass ``u`` works on plane ``u``
+    of every layer's pages: the page ids it writes and the block tables
+    it reads are offset by ``u x pages-per-plane``, a garbage page id
+    landing on that plane's own garbage page.
+
+    The body holds no test on the loop's index. Norming at the START of
+    every pass but the first (``lax.cond(u > 0, ...)`` or ``jnp.where``)
+    is the same mathematics and gave other logits on the v5e, not on the
+    CPU (PERF.md, PR 27: the predicate on the induction variable is
+    compiled as if always true); norming at the end needs none.
+
+    ``want_gates`` (looped models; tests and offline use, no serving
+    program) also returns the exit gate's probability after every pass,
+    ``[ut_steps, T]``."""
+    lp_all = params["layers"]
+
+    def one_pass(x, caches, write_pages, block_tables):
+        caches = list(caches)
+        for l in range(cfg.num_layers):
+            lp = jax.tree.map(lambda a: a[l], lp_all)
+            x, caches[l] = layer(x, lp, caches[l], write_pages, block_tables)
+        return x, tuple(caches)
+
+    def final_norm(x):
+        return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+
+    if cfg.ut_steps == 1:
+        x, cache = one_pass(x, cache, write_pages, block_tables)
+        with jax.named_scope("lm_head"):
+            return final_norm(x), cache
+
+    plane = cache_pages(cache[0]) // cfg.ut_steps
+
+    def body(u, carry):
+        x, caches, *gates = carry
+        off = u * plane
+        x, caches = one_pass(
+            x, caches, write_pages + off,
+            None if block_tables is None else block_tables + off,
+        )
+        with jax.named_scope("loop_norm"):
+            x = final_norm(x)
+        if want_gates:
+            gates = [gates[0].at[u].set(exit_gate_probs(params, x))]
+        return (x, caches, *gates)
+
+    # The residual stream and the norm between passes stay in float32:
+    # what one pass rounds off, the next ones work on again.
+    dt = x.dtype
+    gates = (jnp.zeros((cfg.ut_steps, x.shape[0]), jnp.float32),) if want_gates else ()
+    x, cache, *gates = jax.lax.fori_loop(
+        0, cfg.ut_steps, body, (x.astype(jnp.float32), tuple(cache), *gates)
+    )
+    return (x.astype(dt), cache, *gates)
+
+
+def cache_pages(cache_l) -> int:
+    """Pages in one layer's array (plain, or int8 {"kv", "scale"})."""
+    return (cache_l["kv"] if isinstance(cache_l, dict) else cache_l).shape[0]
+
+
+def exit_gate_probs(params: Params, hidden: jax.Array) -> jax.Array:
+    """``sigmoid(w . h + b)`` of the looped model's exit gate on normed
+    hidden states ``[..., h]``, float32. The serving programs never
+    evaluate it: at ``early_exit_threshold`` 1 it decides nothing."""
+    g = params["exit_gate"]
+    z = jnp.einsum(
+        "...h,h->...", hidden.astype(jnp.float32), g["w"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    return jax.nn.sigmoid(z + g["b"].astype(jnp.float32))
 
 
 def forward_ring_prefill(
@@ -793,36 +946,31 @@ def forward_ring_prefill(
         positions = jnp.arange(T, dtype=jnp.int32)
         x = params["embed"][tokens]  # [T, h]
         rope_cs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
-    lp_all = params["layers"]
 
-    layer_caches = list(cache)
-    for l in range(cfg.num_layers):
-        lp = jax.tree.map(lambda a: a[l], lp_all)
+    def layer(x, lp, cache_l, write_pages, _):
         with jax.named_scope("qkv"):
-            y = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            dt = lp["attn_norm"].dtype
+            y = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps).astype(dt)
             qkv = _dot(y, lp["wqkv"])
             if "bqkv" in lp:
                 qkv = qkv + lp["bqkv"]
-            qkv = qkv.astype(x.dtype)
+            qkv = qkv.astype(dt)
             q, k, v = split_qkv(qkv, cfg)
             q = rope_apply(q.reshape(T, cfg.num_heads, cfg.head_dim), *rope_cs)
             k = rope_apply(k.reshape(T, cfg.num_kv_heads, cfg.head_dim), *rope_cs)
             v3 = v.reshape(T, cfg.num_kv_heads, cfg.head_dim)
         with jax.named_scope("kv_write"):
             kvn = _interleave_kv(k.reshape(T, cfg.kv_size), v, cfg)
-            layer_caches[l] = write_kv(layer_caches[l], write_pages, write_offs, kvn)
+            cache_l = write_kv(cache_l, write_pages, write_offs, kvn)
         with jax.named_scope("attn"):
             attn = ring_attention(q, k, v3, mesh=sp_mesh, axis_name=axis_name)
             attn = attn.reshape(T, cfg.q_size)
-        with jax.named_scope("o_proj"):
-            x = x + _dot(attn, lp["wo"]).astype(x.dtype)
-        with jax.named_scope("mlp"):
-            x = x + _mlp(rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps), lp, cfg, 1, None)
+        return _attn_out_and_mlp(x, attn, lp, cfg, 1, None), cache_l
 
+    x, cache = _run_stack(params, cache, x, write_pages, None, layer, cfg)
     with jax.named_scope("lm_head"):
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         last = jax.lax.dynamic_slice_in_dim(x, last_row, 1, axis=0)  # [1, h]
-        return _logits(last, params, cfg), tuple(layer_caches)
+        return _logits(last, params, cfg), cache
 
 
 def embed_forward(

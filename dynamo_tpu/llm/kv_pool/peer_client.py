@@ -161,7 +161,7 @@ class PeerKvClient:
         # casts floats — an int8-vs-float mismatch fails the import FAST
         # per the PR 8 contract and the pull degrades to recompute).
         shape = [
-            core.cfg.num_layers, bs, 2 * core.cfg.num_kv_heads, core.cfg.head_dim,
+            core.cfg.num_cache_layers, bs, 2 * core.cfg.num_kv_heads, core.cfg.head_dim,
         ]
         dtype = core.kv_wire_dtype
         imported = 0

@@ -78,6 +78,11 @@ def pp_param_specs(cfg: ModelConfig, pp: int) -> dict[str, Any]:
     replicated)."""
     if cfg.num_layers % pp:
         raise ValueError(f"pp={pp} must divide num_layers={cfg.num_layers}")
+    if cfg.ut_steps > 1:
+        raise ValueError(
+            f"pp={pp} with ut_steps={cfg.ut_steps}: a looped stack would "
+            "go round the stages once per pass; not built"
+        )
     layers = {
         "attn_norm": P("pp"),
         "mlp_norm": P("pp"),

@@ -64,6 +64,9 @@ def param_partition_specs(cfg: ModelConfig, tp: int) -> dict[str, Any]:
     }
     if cfg.attn_qkv_bias:
         layers["bqkv"] = P(None, "tp")  # fused column order, like wqkv
+    if cfg.sandwich_norm:
+        layers["attn_post_norm"] = P(None, None)
+        layers["mlp_post_norm"] = P(None, None)
     if cfg.is_moe:
         # Expert parallelism: the expert axis shards over the model axis;
         # the expert-sum contraction becomes a psum over 'tp'.
@@ -84,6 +87,8 @@ def param_partition_specs(cfg: ModelConfig, tp: int) -> dict[str, Any]:
         "final_norm": P(None),
         "fuse_tp": P(),
     }
+    if cfg.ut_steps > 1:
+        specs["exit_gate"] = {"w": P(None), "b": P()}
     if not cfg.tie_embeddings:
         specs["lm_head"] = P(None, "tp")
     return specs

@@ -147,6 +147,17 @@ SCHEDULER_GAUGES: dict[str, tuple[str, str]] = {
         "scheduler_fair_enabled",
         "1 when per-tenant deficit-round-robin admission is active",
     ),
+    # Looped stacks (ISSUE 27): what the loop costs the cache.
+    "kv_cache_layers": (
+        "engine_kv_cache_layers",
+        "Planes of K/V a token holds: weight layers x passes over them "
+        "(ut_steps); the weight layers of a single-pass model",
+    ),
+    "kv_bytes_per_token": (
+        "engine_kv_bytes_per_token",
+        "Bytes of K/V cache one token holds over all planes, at the "
+        "cache's dtype (int8: scales included)",
+    ),
 }
 
 
@@ -211,6 +222,11 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
     "ragged_bucket_tokens": (
         "engine_ragged_bucket_tokens",
         "Bucket (padded) tokens summed over ragged dispatches",
+    ),
+    "layer_passes": (
+        "engine_layer_passes",
+        "Passes over the layer stack, per live lane and fused iteration "
+        "(a prefill wave: per sequence): dispatched lanes x k x ut_steps",
     ),
 }
 
