@@ -401,14 +401,10 @@ def serve_phase(mode: str, inject: str | None, report: dict) -> None:
         if mode == "pd":
             # Prompts above --max-local-prefill-length must really have
             # been prefilled on the other chip and their KV handed over.
-            with urllib.request.urlopen(
-                workers[-1][1].replace("/health", "/metrics"), timeout=30
-            ) as r:
-                disagg = {
-                    line.split("{")[0].split()[0]: float(line.split()[-1])
-                    for line in r.read().decode().splitlines()
-                    if line.startswith("dynamo_disagg_")
-                }
+            disagg = {
+                line.split("{")[0].split()[0]: float(line.split()[-1])
+                for line in metric_lines(workers[-1][1], "dynamo_disagg_")
+            }
             report["disagg"] = disagg
             if not any(v > 0 for k, v in disagg.items() if "handoffs" in k):
                 raise PhaseFailed(f"no KV handoff happened: {disagg}")
@@ -430,6 +426,8 @@ def serve_phase(mode: str, inject: str | None, report: dict) -> None:
         report["radix_index"] = used
         report["attention"] = check_attention_lowering(
             ir_dir, roles, head["device"]["platform"])
+        report["attention_traced"] = check_attention_traced(
+            workers, head["device"]["platform"])
     finally:
         children.stop()
         shutil.rmtree(ir_dir, ignore_errors=True)
@@ -459,6 +457,33 @@ def check_attention_lowering(ir_dir: Path, roles, platform: str) -> dict:
             )
         if platform != "tpu" and got["with_mosaic_call"]:
             raise PhaseFailed(f"Mosaic call in a {platform} program? {name} {got}")
+    return found
+
+
+def metric_lines(health_url: str, prefix: str) -> list[str]:
+    """The sample lines of a worker's /metrics whose name starts with ``prefix``."""
+    with urllib.request.urlopen(
+            health_url.replace("/health", "/metrics"), timeout=30) as r:
+        return [l for l in r.read().decode().splitlines() if l.startswith(prefix)]
+
+
+def check_attention_traced(workers, platform: str) -> dict:
+    """Each worker's ``dynamo_engine_attention_calls_traced_total``: which
+    shape its programs stated and which implementation they got. A worker
+    that decodes must have traced the decode shape; on a TPU none may
+    have fallen to the jnp reference."""
+    found: dict[str, dict] = {}
+    for role, url, _ in workers:
+        got = {}
+        for line in metric_lines(url, "dynamo_engine_attention_calls_traced_total{"):
+            labels = dict(kv.split("=") for kv in line[line.index("{") + 1:line.index("}")]
+                          .replace('"', "").split(","))
+            got[f"{labels['shape']}/{labels['impl']}"] = float(line.split()[-1])
+        found[role] = got
+        if role != "prefill" and not any(k.startswith("decode/") and v for k, v in got.items()):
+            raise PhaseFailed(f"{role}: no decode-shaped attention call was traced: {got}")
+        if platform == "tpu" and any(k.endswith("/reference") and v for k, v in got.items()):
+            raise PhaseFailed(f"{role}: attention ran the jnp reference on a TPU: {got}")
     return found
 
 
@@ -493,11 +518,12 @@ def kernel_phase(mode: str, inject: str | None, report: dict) -> None:
 
 
 def kernel_check_child(corrupt: bool) -> int:
-    """Compile both Pallas attention kernels WITHOUT interpret mode on a
-    TPU and compare with the repo's references.
+    """Compile the Pallas attention kernels (the library's ragged one at
+    its ragged and its decode-shaped grids, the orphan paged one) WITHOUT
+    interpret mode on a TPU and compare with the repo's references.
 
     Tolerances. Inputs and outputs are bf16 (8 significand bits: one ulp
-    is 2^-8 relative) and both kernels keep bf16 operands on the MXU with
+    is 2^-8 relative) and the kernels keep bf16 operands on the MXU with
     f32 accumulation, so an output of magnitude <= 4 may differ from an
     f32-exact reference by an ulp or two of 2^-6 (0.0156 was the largest
     difference seen on a v5e): atol 2^-5, rtol 2e-2. The
@@ -616,8 +642,53 @@ def kernel_check_child(corrupt: bool) -> int:
         checks.append({"name": "library ragged kernel", "ok": True,
                        "not_run": "cpu: the TPU interpreter cannot execute it"})
 
-    # (ii) first-party decode kernel, bf16 and int8 pages, head_dim 128,
-    # block 32, 7 query heads per KV head.
+    # (i-b) the decode-shaped path (``cu_q_lens=None``: what a megastep's
+    # attention runs, the library kernel at one sequence a query block)
+    # through the serving entry, at the three cells' decode shapes and at
+    # `--block-size` 128 with 8 KV heads (where a KV block counted in
+    # pages outgrew VMEM): lanes, heads, page size, page-table width, one
+    # layer's page array, contexts.
+    from dynamo_tpu.ops.ragged_attention import ragged_paged_attention
+
+    def decode_case(lanes, heads, kv_heads, ps, width, pages, lo, hi):
+        lens = rng.randint(lo, hi, lanes).astype(np.int32)
+        lens[:4] = [1, ps, ps + 1, hi]      # an inactive lane; page edges
+        tables = np.zeros((lanes, width), np.int32)
+        perm, used = rng.permutation(pages - 1), 0
+        for s_, n in enumerate(lens):
+            need = -(-int(n) // ps)
+            tables[s_, :need] = perm[used:used + need]
+            used += need
+        return (jnp.asarray(rng.randn(lanes, heads, d), jnp.bfloat16),
+                jnp.asarray(rng.randn(pages, ps, 2 * kv_heads, d), jnp.bfloat16),
+                jnp.asarray(lens), jnp.asarray(tables))
+
+    def decode_ref(q, kv, lens, tables):
+        one, ps = jnp.asarray([1], jnp.int32), kv.shape[1]
+        return jnp.concatenate([
+            ragged_paged_attention_ref(
+                q[s_:s_ + 1], kv, lens[s_:s_ + 1],
+                tables[s_:s_ + 1, :-(-int(lens[s_]) // ps)],
+                jnp.asarray([0, 1], jnp.int32), one, sm_scale=sm)
+            for s_ in range(q.shape[0])])
+
+    if on_tpu:
+        for label, geometry in [
+            ("7B: 32 lanes, 28/4 heads", (32, 28, 4, 32, 256, 3073, 384, 1536)),
+            ("1.5B: 8 lanes, 12/2 heads", (8, 12, 2, 32, 256, 11265, 160, 2560)),
+            ("1.5B: 32 lanes, 12/2 heads", (32, 12, 2, 32, 256, 11265, 160, 2560)),
+            ("Ouro: 8 lanes, 16/16 heads", (8, 16, 16, 32, 64, 676, 128, 608)),
+            ("32 lanes, 32/8 heads, 128-token pages", (32, 32, 8, 128, 64, 769, 384, 1536)),
+        ]:
+            case = decode_case(*geometry)
+            lanes_ = jnp.asarray([geometry[0]], jnp.int32)
+            check(f"decode-shaped attention, {label}",
+                  lambda: jax.jit(lambda *a: ragged_paged_attention(
+                      *a, None, lanes_, sm_scale=sm))(*case),
+                  lambda: decode_ref(*case))
+
+    # (ii) the orphan paged kernel (other layout), bf16 and int8 pages,
+    # head_dim 128, block 32, 7 query heads per KV head.
     B, bs, max_blocks, blocks = (8, 32, 16, 256) if on_tpu else (2, 32, 4, 16)
     q = jnp.asarray(rng.randn(B, n_q, d), jnp.bfloat16)
     k = jnp.asarray(rng.randn(n_kv, blocks * bs, d), jnp.bfloat16)
@@ -739,6 +810,7 @@ def main() -> int:
     print(f"repeat prompt cached_tokens={report['repeat_cached_tokens']}; router "
           f"index: {report['radix_index']} (built this run)")
     print(f"served attention lowering: {report['attention']}")
+    print(f"served attention traced (shape/impl: calls): {report['attention_traced']}")
     for c in report.get("kernels", {}).get("checks", ()):
         print(f"kernel check: {c}")
     if "disagg" in report:

@@ -54,6 +54,7 @@ from dynamo_tpu.engine.model import (
     init_params,
     verify_tokens,
 )
+from dynamo_tpu.ops.ragged_attention import traced_impl
 from dynamo_tpu.engine.sampler import (
     LOGPROBS_K,
     device_ngram_draft,
@@ -1777,8 +1778,11 @@ class EngineCore:
         it returns). Its profile annotation carries the dispatch's shape:
         kind (prefill / decode / megastep / mixed), live lanes against
         the padded width, fused iterations, the passes each iteration
-        makes over the layer stack, real against padded tokens, and
-        whether a step was in flight when it was enqueued.
+        makes over the layer stack, real against padded tokens,
+        whether a step was in flight when it was enqueued, and the
+        attention implementation this process's programs of that shape
+        were traced with (``attn``: the decode shape's for decode
+        iterations, the ragged one's for a prefill wave).
 
         Also counts ``layer_passes``: a pass over the stack for each live
         lane of each fused iteration (a prefill wave: each sequence,
@@ -1790,6 +1794,7 @@ class EngineCore:
         self._t_dispatch = self.clock.mark(
             "dispatch", kind=kind, lanes=lanes, width=width, k=k, ut_steps=ut,
             real=real, padded=padded, pipelined=self._inflight is not None,
+            attn=traced_impl("ragged" if kind == "prefill" else "decode"),
         )
 
     def _bucket_for(self, n: int) -> int:

@@ -667,7 +667,7 @@ def dense_layer(
     write_offs: jax.Array,
     kv_lens: jax.Array,
     block_tables: jax.Array,
-    cu_q_lens: jax.Array,
+    cu_q_lens: jax.Array | None,  # None: the decode shape (one row a sequence)
     num_seqs: jax.Array,
     cfg: ModelConfig,
     tp: int = 1,
@@ -756,7 +756,7 @@ def forward_tokens(
     write_offs: jax.Array,   # [T] i32 — destination offset within page
     kv_lens: jax.Array,      # [S] i32 — cache tokens per seq incl. this chunk
     block_tables: jax.Array, # [S, pages_per_seq] i32
-    cu_q_lens: jax.Array,    # [S+1] i32
+    cu_q_lens: jax.Array | None,  # [S+1] i32; None: the decode shape
     num_seqs: jax.Array,     # [1] i32
     last_rows: jax.Array,    # [S] i32 — row of each seq's last token (0 pad)
     cfg: ModelConfig,
@@ -769,6 +769,9 @@ def forward_tokens(
     [S, vocab] f32, cache). Prefill chunks, decode tokens, and mixed
     batches are all this function — a decode step is S sequences of
     q_len 1 (reference chunked-prefill semantics, vLLM scheduler shape).
+    ``cu_q_lens=None`` says that shape statically (T == S, row ``s`` is
+    sequence ``s``): attention then runs its decode path
+    (ops/ragged_attention.py), the same result from less work.
     """
     x, cache = forward_hidden(
         params, cache, tokens, positions, write_pages, write_offs,
@@ -1021,7 +1024,9 @@ def decode_tokens(
 ) -> tuple[jax.Array, jax.Array]:
     """Pure-decode step: B sequences, one token each. Thin assembly over
     :func:`forward_tokens` — in-jit slot computation so decode chains can
-    advance positions on-device."""
+    advance positions on-device. It states the decode shape
+    (``cu_q_lens=None``) instead of an ``arange(B + 1)`` whose meaning
+    the attention call could not read back from a traced array."""
     B = tokens.shape[0]
     bs = engine.block_size
     with jax.named_scope("kv_write"):  # where each lane's K/V row lands
@@ -1029,12 +1034,11 @@ def decode_tokens(
         write_pages = jnp.where(active, page, engine.garbage_block)
         write_offs = positions % bs
         kv_lens = jnp.where(active, positions + 1, 1).astype(jnp.int32)
-        cu = jnp.arange(B + 1, dtype=jnp.int32)
         num_seqs = jnp.array([B], jnp.int32)
         rows = jnp.arange(B, dtype=jnp.int32)
     return forward_tokens(
         params, cache, tokens, positions, write_pages, write_offs,
-        kv_lens, block_tables, cu, num_seqs, rows, cfg, engine, mesh,
+        kv_lens, block_tables, None, num_seqs, rows, cfg, engine, mesh,
     )
 
 

@@ -14,6 +14,8 @@ kernels — on TPU it is first-party, SURVEY.md §7 "hard parts"):
 - ``page_indices``: ``[S, pages_per_seq]`` block table per sequence.
 - ``cu_q_lens``: ``[S + 1]`` cumulative query lengths; sequence ``s`` owns
   q rows ``cu[s]:cu[s+1]``. Entries past ``num_seqs`` repeat ``cu[num_seqs]``.
+  ``None`` is the DECODE SHAPE, said statically by the caller: ``T == S``,
+  row ``s`` is sequence ``s`` with one query token (``arange(S + 1)``).
 - ``num_seqs``: ``i32[1]`` — valid sequences (dynamic).
 
 Query token ``i`` of sequence ``s`` sits at absolute position
@@ -24,8 +26,13 @@ special case.
 On TPU dispatches to the Pallas kernel
 (jax.experimental.pallas.ops.tpu.ragged_paged_attention); elsewhere (CPU
 test meshes) runs a vectorized jnp reference with identical semantics.
-Which of the two a program got is logged once at trace time
-(:func:`_announce`) — the reference on a TPU is a warning, never silent.
+A decode-shaped call runs the same kernel under a grid of its own: one
+sequence a query block, so that a sequence's pass computes on that
+sequence's rows alone (:func:`decode_shape_grid`). Which implementation
+a program got is logged once at trace time (:func:`_announce`) — the
+reference on a TPU is a warning, never silent — and counted by shape
+and implementation (:func:`traced_calls`,
+``dynamo_engine_attention_calls_traced_total`` on /metrics).
 Under tensor parallelism wrap with :func:`sharded_ragged_attention` —
 attention is embarrassingly parallel over heads, so the shard_map has no
 collectives.
@@ -33,8 +40,10 @@ collectives.
 
 from __future__ import annotations
 
+import collections
 import functools
 import logging
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -47,17 +56,49 @@ log = logging.getLogger("dynamo_tpu.ops.ragged_attention")
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
-# Explicit Pallas grid for the library kernel: 8-page DMA batches and
-# 8-query blocks for decode-shaped calls, 128-query blocks for prefill
-# waves (the kernel's own tuned table can pick whole-wave q blocks whose
-# scratch exceeds scoped VMEM at T >= 2048). The values predate this
-# installation; PERF.md "Bring-up on v5e" records what was re-checked.
-# Env-overridable for on-chip tuning sweeps; 0 = the kernel's defaults.
-_DECODE_KV_PAGES_PER_BLOCK = _knobs.get_int("DYNAMO_TPU_ATTN_PAGES_PER_BLOCK")
-_DECODE_QUERIES_PER_BLOCK = _knobs.get_int("DYNAMO_TPU_ATTN_QUERIES_PER_BLOCK")
+# Explicit Pallas grid for the library kernel's RAGGED calls: 8-page DMA
+# batches and 8-query blocks for calls of at most 64 rows (verify rows,
+# small chunks and mixed batches), 128-query blocks for prefill waves
+# (the kernel's own tuned table can pick whole-wave q blocks whose
+# scratch exceeds scoped VMEM at T >= 2048). Env-overridable for
+# on-chip tuning sweeps; 0 = the kernel's defaults.
+_SMALL_KV_PAGES_PER_BLOCK = _knobs.get_int("DYNAMO_TPU_ATTN_PAGES_PER_BLOCK")
+_SMALL_QUERIES_PER_BLOCK = _knobs.get_int("DYNAMO_TPU_ATTN_QUERIES_PER_BLOCK")
 _PREFILL_QUERIES_PER_BLOCK = _knobs.get_int(
     "DYNAMO_TPU_ATTN_PREFILL_QUERIES_PER_BLOCK"
 )
+
+# The grid of a DECODE-SHAPED call: one query a block, 512 KV tokens a
+# block (:func:`decode_shape_grid`). The kernel walks a query block's
+# sequences one after another and computes each one's pass on the WHOLE
+# block's rows (queries x group size), keeping that sequence's; at one
+# query a block the rows are the sequence's own. Swept on the v5e with
+# jax 0.9.0 by tools/attn_decode_bench.py at the three cells' decode
+# shapes, all of page size 32 (PERF.md section 5, PR 28; share of the
+# HBM roofline, (8, 8) -> (1, 16 pages)): 28/4 heads x 32 lanes 27.1 ->
+# 71.4%, 12/2 heads x 8 / 16 / 32 lanes 30.8 / 32.0 / 31.7 -> 49.7 /
+# 55.4 / 54.3%, 16/16 heads x 8 lanes 51.9 -> 52.3%. 16 pages are the
+# best or within 2% of it at every shape but 12/2 heads x 8 lanes, where
+# 24 pages read 56.1% (and cost the 16/16 shape a fifth: 43.4%). The KV
+# block is kept in TOKENS, not pages, because its two VMEM buffers are
+# what Mosaic's scoped limit holds it to: 512 tokens x the 16 combined
+# heads the kernel blocks at most x 128 x 2 B = 2 MB a buffer, at any
+# `--block-size` (16 pages of 128 tokens with 8 KV heads or more are
+# refused, RESOURCE_EXHAUSTED in vmem), and in at most the 16 pages
+# that were swept (one DMA a page: smaller pages were not measured).
+# Constants of the shape, not knobs: the sweep tool passes its own.
+_DECODE_QUERIES_PER_BLOCK = 1
+_DECODE_KV_TOKENS_PER_BLOCK = 512
+_DECODE_KV_PAGES_PER_BLOCK_MAX = 16
+
+
+def decode_shape_grid(page_size: int, pages_per_seq: int) -> tuple[int, int]:
+    """(queries per block, KV pages per block) of a decode-shaped call:
+    (1, 16) at page size 32, and never more pages than a table holds
+    (the kernel refuses that at trace time)."""
+    pages = min(_DECODE_KV_TOKENS_PER_BLOCK // page_size,
+                _DECODE_KV_PAGES_PER_BLOCK_MAX, pages_per_seq)
+    return _DECODE_QUERIES_PER_BLOCK, max(1, pages)
 
 
 @functools.cache
@@ -67,18 +108,50 @@ def _announce(level: int, message: str) -> None:
     log.log(level, message)
 
 
+# Attention calls traced since the process started, by the shape the
+# caller stated ("decode" / "ragged") and the implementation chosen
+# ("library" / "reference"). The choice is static per compiled program,
+# so trace time is where it can be counted: a program of L layers adds L
+# (a looped stack's body is traced once).
+_TRACED: collections.Counter = collections.Counter()
+_TRACED_IMPLS: dict[str, str] = {}   # shape -> "+"-joined impls, kept at trace time
+_TRACED_LOCK = threading.Lock()
+
+
+def _count_traced(shape: str, impl: str) -> None:
+    with _TRACED_LOCK:
+        _TRACED[shape, impl] += 1
+        _TRACED_IMPLS[shape] = "+".join(sorted(
+            i for (s, i), n in _TRACED.items() if s == shape and n))
+
+
+def traced_calls() -> dict[tuple[str, str], int]:
+    """``{(shape, impl): calls traced}``."""
+    with _TRACED_LOCK:
+        return dict(_TRACED)
+
+
+def traced_impl(shape: str) -> str:
+    """The implementation(s) this process's programs got for ``shape``
+    (``+``-joined if more than one; empty before any was traced). A
+    dictionary read: the engine asks on every dispatch."""
+    return _TRACED_IMPLS.get(shape, "")
+
+
 def ragged_paged_attention_ref(
     q: jax.Array,             # [T, n_q, d]
     kv_pages: jax.Array,      # [n_pages, page_size, 2*n_kv, d]
     kv_lens: jax.Array,       # [S] i32
     page_indices: jax.Array,  # [S, pages_per_seq] i32
-    cu_q_lens: jax.Array,     # [S+1] i32
+    cu_q_lens: jax.Array | None,  # [S+1] i32; None: the decode shape
     num_seqs: jax.Array,      # [1] i32
     *,
     sm_scale: float,
     kv_scales: jax.Array | None = None,  # [n_pages, page_size, 2*n_kv] f32
 ) -> jax.Array:               # [T, n_q, d]
     T, n_q, d = q.shape
+    if cu_q_lens is None:
+        cu_q_lens = jnp.arange(T + 1, dtype=jnp.int32)
     n_pages, page_size, n_comb, _ = kv_pages.shape
     n_kv = n_comb // 2
     group = n_q // n_kv
@@ -128,24 +201,30 @@ def pallas_ragged_attention(
     q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs, *,
     sm_scale: float,
 ) -> jax.Array:
-    """The library Pallas kernel under this repo's explicit grid (env 0
-    restores the kernel's own defaults): decode-shaped calls use the
-    decode grid; prefill waves cap the query block (see the module-level
-    comment). Real-valued pages only."""
+    """The library Pallas kernel under this repo's explicit grid:
+    ``cu_q_lens=None`` (the decode shape) takes
+    :func:`decode_shape_grid`; ragged calls of at most 64 rows the small
+    grid, prefill waves a capped query block (the module-level comments;
+    env 0 restores the kernel's own defaults for the ragged calls).
+    Real-valued pages only."""
     from jax.experimental.pallas.ops.tpu.ragged_paged_attention import (
         ragged_paged_attention as _kernel,
     )
 
     kw = {}
-    if _DECODE_KV_PAGES_PER_BLOCK > 0:
+    if cu_q_lens is None:
+        cu_q_lens = jnp.arange(q.shape[0] + 1, dtype=jnp.int32)
+        qb, pages = decode_shape_grid(kv_pages.shape[1], page_indices.shape[1])
+        kw = dict(num_kv_pages_per_block=pages, num_queries_per_block=qb)
+    elif _SMALL_KV_PAGES_PER_BLOCK > 0:
         qb = (
-            _DECODE_QUERIES_PER_BLOCK
+            _SMALL_QUERIES_PER_BLOCK
             if q.shape[0] <= 64
             else min(_PREFILL_QUERIES_PER_BLOCK, q.shape[0])
         )
         kw = dict(
             num_kv_pages_per_block=min(
-                _DECODE_KV_PAGES_PER_BLOCK, page_indices.shape[1]
+                _SMALL_KV_PAGES_PER_BLOCK, page_indices.shape[1]
             ),
             num_queries_per_block=qb,
         )
@@ -160,6 +239,9 @@ def ragged_paged_attention(
     sm_scale: float, kv_scales=None,
 ) -> jax.Array:
     """Backend dispatch: Pallas kernel on TPU, jnp reference elsewhere.
+    ``cu_q_lens=None`` states the decode shape (module docstring): the
+    kernel then runs its decode grid, and the reference reads it as
+    ``arange(S + 1)``.
 
     The kernel wants MXU/VPU-aligned shapes (head_dim % 128, page_size %
     8); models outside that (e.g. the byte-sized test presets) run the
@@ -171,9 +253,9 @@ def ragged_paged_attention(
     (halved gather bytes). The TPU library kernel takes real-valued
     pages, so the int8 serving path dequantizes the REFERENCED pages
     before the call when that is smaller than the whole cache, else the
-    whole cache — a capacity win, no traffic win (ROADMAP S4). The
-    first-party kernel's int8-page variant (ops/paged_attention.py)
-    was meant to carry the traffic win and does not compile for TPU."""
+    whole cache — a capacity win, no traffic win (ROADMAP S10). The
+    orphan kernel's int8-page variant (ops/paged_attention.py) was meant
+    to carry the traffic win and does not compile for TPU (ROADMAP D6)."""
     d = q.shape[-1]
     page_size = kv_pages.shape[1]
     backend = jax.default_backend()
@@ -182,9 +264,15 @@ def ragged_paged_attention(
         f"combined_kv_heads={kv_pages.shape[2]}, kv={kv_pages.dtype}"
     )
     use_kernel = backend == "tpu" and d % 128 == 0 and page_size % 8 == 0
+    shape = "decode" if cu_q_lens is None else "ragged"
+    _count_traced(shape, "library" if use_kernel else "reference")
     if use_kernel:
         _announce(
-            logging.INFO, f"ragged attention: Pallas TPU kernel ({geometry})"
+            logging.INFO,
+            f"ragged attention: Pallas TPU kernel ({geometry}); "
+            + ("decode shape, grid "
+               f"{decode_shape_grid(page_size, page_indices.shape[1])}"
+               if cu_q_lens is None else "ragged shape"),
         )
     elif backend == "tpu":
         _announce(

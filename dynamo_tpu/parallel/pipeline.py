@@ -409,12 +409,13 @@ def _pp_decode_round_body(
     write_pages = jnp.where(act & valid, page, engine.garbage_block)
     write_offs = pos % bs
     kv_lens = jnp.where(act, pos + 1, 1).astype(jnp.int32)
-    cu = jnp.arange(Bm + 1, dtype=jnp.int32)
     num_seqs = jnp.asarray([Bm], jnp.int32)
 
+    # cu_q_lens=None: the decode shape, as engine/model.py:decode_tokens
+    # states it (one row a lane), so attention runs its decode grid.
     x, cache = _stage_layers(
         x, params["layers"], cache, pos, write_pages, write_offs,
-        kv_lens, table, cu, num_seqs, cfg,
+        kv_lens, table, None, num_seqs, cfg,
     )
     # Exit: the last stage's final-norm rows, replicated; then this
     # stage's V/pp slice of the logits.

@@ -257,6 +257,20 @@ class _EngineCounters:
             family = CounterMetricFamily(f"dynamo_{name}", doc, labels=["service"])
             family.add_metric(["engine"], float(stats.get(key, 0) or 0))
             yield family
+        from dynamo_tpu.ops.ragged_attention import traced_calls
+
+        traced = CounterMetricFamily(
+            "dynamo_engine_attention_calls_traced",
+            "Attention calls traced into step programs, by the shape the "
+            "caller stated (decode: one query token a sequence, the "
+            "kernel's decode grid; ragged) and the implementation chosen "
+            "(library: the Pallas kernel; reference: jnp); the choice is "
+            "static per compiled program",
+            labels=["service", "shape", "impl"],
+        )
+        for (shape, impl), n in sorted(traced_calls().items()):
+            traced.add_metric(["engine", shape, impl], float(n))
+        yield traced
 
 
 def bind_engine_counters(

@@ -1,0 +1,257 @@
+"""The decode-shaped attention path (PR 28): ``cu_q_lens=None`` through
+the serving entry against an independent dense softmax, that Mosaic takes
+the library kernel at the decode grid and the served shapes (compiled for
+a described v5e, no chip), and how a program's shape routes a call."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.engine import config as cfgmod
+from dynamo_tpu.engine.model import decode_tokens, init_cache, init_params
+from dynamo_tpu.ops import ragged_attention as ra
+from tests.model_harness import prefill_chunk
+
+# As chip_smoke.py's kernel checks: bf16 inputs and outputs against an
+# f64 softmax over the same bf16 values.
+ATOL, RTOL = 2.0 ** -5, 2e-2
+PAGE_SIZE, HEAD_DIM = 32, 128
+SM_SCALE = HEAD_DIM ** -0.5
+# An inactive lane as decode_tokens writes it (1), page and block
+# boundaries (32 / 33; 256 / 257; 512 / 513 with 16-page blocks), partly
+# filled last pages, and contexts of several blocks.
+KV_LENS = [1, 32, 33, 256, 257, 100, 511, 7, 64, 255, 513, 129, 2, 31, 512, 97]
+WIDTH = 17
+
+
+def _case(lanes: int, n_q: int, n_kv: int, seed: int = 0):
+    """Shuffled page tables over a cache that is random everywhere, so a
+    sequence's last page holds stale finite rows past ``kv_len`` (scaled
+    up so that a leak shows)."""
+    rng = np.random.RandomState(seed)
+    lens = np.asarray((KV_LENS * 2)[:lanes], np.int32)
+    n_pages = lanes * WIDTH + 1
+    q = rng.randn(lanes, n_q, HEAD_DIM)
+    kv = rng.randn(n_pages, PAGE_SIZE, 2 * n_kv, HEAD_DIM).astype(np.float32)
+    tables = rng.permutation(n_pages)[: lanes * WIDTH].reshape(lanes, WIDTH)
+    for s, n in enumerate(lens):
+        for j in range(WIDTH):
+            live = int(np.clip(n - j * PAGE_SIZE, 0, PAGE_SIZE))
+            kv[tables[s, j], live:] *= 100.0
+    return (jnp.asarray(q, jnp.bfloat16), jnp.asarray(kv, jnp.bfloat16),
+            jnp.asarray(lens), jnp.asarray(tables, jnp.int32))
+
+
+def _dense_softmax(q, kv, lens, tables):
+    """One query token a sequence, in numpy float64, sharing no code with
+    the repo's reference."""
+    q, kv = np.asarray(q, np.float64), np.asarray(kv, np.float64)
+    lanes, n_q, d = q.shape
+    n_kv = kv.shape[2] // 2
+    out = np.zeros((lanes, n_q, d))
+    for s in range(lanes):
+        n = int(lens[s])
+        rows = kv[np.asarray(tables[s])].reshape(-1, 2 * n_kv, d)[:n]
+        for h in range(n_q):
+            k, v = rows[:, 2 * (h // (n_q // n_kv))], rows[:, 2 * (h // (n_q // n_kv)) + 1]
+            w = np.exp((scores := k @ q[s, h] * SM_SCALE) - scores.max())
+            out[s, h] = (w / w.sum()) @ v
+    return out
+
+
+def _close(got, want):
+    got = np.asarray(got, np.float64)
+    assert np.all(np.isfinite(got))
+    bad = np.abs(got - want) > ATOL + RTOL * np.abs(want)
+    assert not bad.any(), f"max |diff| {np.max(np.abs(got - want))}"
+
+
+@pytest.mark.parametrize("lanes", [8, 16, 32])
+@pytest.mark.parametrize("n_q,n_kv", [(28, 4), (12, 2), (16, 16), (4, 4)])
+def test_the_decode_shape_gives_one_tokens_attention_a_sequence(n_q, n_kv, lanes):
+    """``cu_q_lens=None`` through the serving entry (on the CPU: the jnp
+    reference reading it as ``arange(S + 1)``)."""
+    q, kv, lens, tables = _case(lanes, n_q, n_kv, seed=lanes + n_q)
+    got = ra.ragged_paged_attention(
+        q, kv, lens, tables, None, jnp.asarray([lanes], jnp.int32),
+        sm_scale=SM_SCALE)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    _close(got, _dense_softmax(q, kv, lens, tables))
+
+
+def test_the_decode_shape_is_what_an_arange_said():
+    q, kv, lens, tables = _case(16, 12, 2, seed=3)
+    num_seqs = jnp.asarray([16], jnp.int32)
+    stated = ra.ragged_paged_attention(
+        q, kv, lens, tables, None, num_seqs, sm_scale=SM_SCALE)
+    spelled = ra.ragged_paged_attention(
+        q, kv, lens, tables, jnp.arange(17, dtype=jnp.int32), num_seqs,
+        sm_scale=SM_SCALE)
+    np.testing.assert_array_equal(np.asarray(stated), np.asarray(spelled))
+
+
+# -- Mosaic takes the decode grid at the served shapes (a described v5e) -----------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("lanes,n_q,n_kv,page_size,width,n_pages", [
+    (32, 28, 4, 32, 256, 3073),      # qwen7b-decode-batch
+    (8, 12, 2, 32, 256, 11265),      # qwen1p5b-chat-steady, narrowest
+    (32, 12, 2, 32, 256, 11265),     # ... and widest
+    (8, 16, 16, 32, 64, 676),        # ouro2p6b-reason-decode
+    (8, 4, 4, 32, 2, 9),             # a table narrower than the grid's pages
+    # `--block-size` 64 / 128 / 256 at 8 KV heads or more (the llama-3-8b
+    # preset's 32/8, Ouro's 16/16): 16 pages a block of these are refused
+    # for VMEM, which is why the block is sized in tokens.
+    (32, 32, 8, 64, 128, 2049),
+    (32, 32, 8, 128, 64, 1025),
+    (32, 32, 8, 256, 32, 513),
+    (8, 16, 16, 64, 32, 338),
+    (8, 16, 16, 128, 16, 169),
+    (32, 28, 4, 128, 64, 769),
+    (32, 32, 8, 16, 256, 4097),      # small pages: 16 of them, not 32
+])
+def test_mosaic_compiles_the_decode_grid_for_a_v5e(
+        one_chip, lanes, n_q, n_kv, page_size, width, n_pages):
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = jax.jit(
+        lambda *a: ra.pallas_ragged_attention(*a, sm_scale=SM_SCALE)
+    ).lower(
+        sds((lanes, n_q, HEAD_DIM), jnp.bfloat16),
+        sds((n_pages, page_size, 2 * n_kv, HEAD_DIM), jnp.bfloat16),
+        sds((lanes,), jnp.int32), sds((lanes, width), jnp.int32),
+        None, sds((1,), jnp.int32),
+    ).compile()
+    assert "ragged_paged_attention_kernel" in compiled.as_text()
+
+
+@pytest.mark.parametrize("page_size,width,grid", [
+    (32, 256, (1, 16)),    # the three cells: what the sweep chose
+    (32, 2, (1, 2)),       # never more pages than the table holds
+    (8, 512, (1, 16)), (16, 256, (1, 16)),   # at most the 16 pages swept
+    (64, 128, (1, 8)), (128, 64, (1, 4)), (256, 32, (1, 2)),
+    (512, 16, (1, 1)), (1024, 8, (1, 1)),   # a page larger than the block
+])
+def test_the_decode_grids_kv_block_is_sized_in_tokens(page_size, width, grid):
+    """At most 512 KV tokens a block at any page size, so that the
+    block's VMEM buffers do not grow with ``--block-size``."""
+    assert ra.decode_shape_grid(page_size, width) == grid
+
+
+# -- routing: the program's shape selects the grid ---------------------------------
+
+
+def _pallas_calls(jaxpr) -> list[tuple[str, tuple]]:
+    """(name, grid) of every pallas_call in a jaxpr."""
+    calls = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            calls.append((eqn.params["name"], tuple(eqn.params["grid_mapping"].grid)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            calls += _pallas_calls(sub)
+    return calls
+
+
+def _delta(before: dict) -> dict:
+    return {k: n - before.get(k, 0) for k, n in ra.traced_calls().items()
+            if n != before.get(k, 0)}
+
+
+def test_on_a_tpu_the_decode_shape_gets_one_sequence_a_query_block(monkeypatch, caplog):
+    """Traced with the backend reading "tpu": ``cu_q_lens=None`` gives the
+    library kernel a query block per sequence, a ragged ``cu_q_lens`` of
+    the same rows the small-call grid; both device ops carry the name
+    the benchmark's readers look for, and the choice is said once."""
+    monkeypatch.setattr(ra.jax, "default_backend", lambda: "tpu")
+    ra._announce.cache_clear()
+    q, kv, lens, tables = _case(32, 28, 4)
+    num_seqs = jnp.asarray([32], jnp.int32)
+
+    before = ra.traced_calls()
+    with caplog.at_level("INFO", logger="dynamo_tpu.ops.ragged_attention"):
+        decode = jax.make_jaxpr(lambda *a: ra.ragged_paged_attention(
+            *a, None, num_seqs, sm_scale=SM_SCALE))(q, kv, lens, tables)
+    assert _delta(before) == {("decode", "library"): 1}
+    assert _pallas_calls(decode.jaxpr) == [("ragged_paged_attention_kernel", (1, 32))]
+    assert any("decode shape, grid (1, 16)" in r.message for r in caplog.records)
+
+    before = ra.traced_calls()
+    ragged = jax.make_jaxpr(lambda *a: ra.ragged_paged_attention(
+        *a, jnp.arange(33, dtype=jnp.int32), num_seqs, sm_scale=SM_SCALE))(
+        q, kv, lens, tables)
+    assert _delta(before) == {("ragged", "library"): 1}
+    assert _pallas_calls(ragged.jaxpr) == [("ragged_paged_attention_kernel", (1, 4))]
+
+
+def test_the_decode_grid_never_asks_for_more_pages_than_the_table_has(monkeypatch):
+    """The library refuses, at trace time, a block of more pages than a
+    sequence's table holds: a table of 2 gets 2, not the grid's 16."""
+    monkeypatch.setattr(ra.jax, "default_backend", lambda: "tpu")
+    q = jnp.zeros((8, 4, 128), jnp.bfloat16)
+    kv = jnp.zeros((9, 32, 8, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda *a: ra.ragged_paged_attention(
+        *a, None, jnp.asarray([8], jnp.int32), sm_scale=1.0))(
+        q, kv, jnp.ones((8,), jnp.int32), jnp.zeros((8, 2), jnp.int32))
+    assert _pallas_calls(jaxpr.jaxpr) == [("ragged_paged_attention_kernel", (1, 8))]
+
+
+def test_decode_tokens_states_the_decode_shape_and_prefill_does_not():
+    """``decode_tokens`` traces every layer's attention as the decode
+    shape; ``forward_tokens`` under a ragged ``cu_q_lens`` (a prefill
+    chunk) as ragged. Read from the counter behind
+    ``dynamo_engine_attention_calls_traced_total``; on the CPU both run
+    the reference, and give what they gave before (test_engine_model)."""
+    cfg, eng = cfgmod.tiny_model(), cfgmod.tiny_engine()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    B = eng.max_num_seqs
+    tables = jnp.zeros((B, eng.max_blocks_per_seq), jnp.int32)
+
+    before = ra.traced_calls()
+    jax.eval_shape(
+        lambda p, c: decode_tokens(
+            p, c, jnp.zeros((B,), jnp.int32), tables, jnp.zeros((B,), jnp.int32),
+            jnp.ones((B,), bool), cfg, eng),
+        params, init_cache(cfg, eng))
+    assert _delta(before) == {("decode", "reference"): cfg.num_layers}
+    # What the dispatch annotation's ``attn`` carries (other tests of this
+    # process may have traced other implementations too).
+    assert "reference" in ra.traced_impl("decode").split("+")
+
+    before = ra.traced_calls()
+    prefill_chunk(params, init_cache(cfg, eng), [1, 2, 3], 0, [0], cfg, eng, 32)
+    assert _delta(before) == {("ragged", "reference"): cfg.num_layers}
+
+
+def test_the_traced_counter_is_on_metrics():
+    from prometheus_client import CollectorRegistry, generate_latest
+
+    from dynamo_tpu.runtime.status_server import _EngineCounters
+
+    for _ in range(28):
+        ra._count_traced("decode", "library")
+    try:
+        registry = CollectorRegistry()
+        registry.register(_EngineCounters(lambda: {}, lambda: {}))
+        text = generate_latest(registry).decode()
+    finally:
+        with ra._TRACED_LOCK:
+            ra._TRACED["decode", "library"] -= 28
+    line = next(l for l in text.splitlines() if l.startswith(
+        'dynamo_engine_attention_calls_traced_total{impl="library"')
+        and 'shape="decode"' in l)
+    assert 'service="engine"' in line and float(line.split()[-1]) >= 28

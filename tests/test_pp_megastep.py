@@ -486,6 +486,22 @@ def test_pp_gauges_and_megastep_span():
     assert st1["pp_pipe_occupancy"] == 1.0
 
 
+def test_pp_decode_states_the_decode_shape():
+    """The wavefront's decode rounds are q_len = 1 calls and say so
+    (``cu_q_lens=None``, as ``decode_tokens`` does): counted as the decode
+    shape, so on a TPU they run the kernel's decode grid and the dispatch
+    annotation's ``attn`` is not empty."""
+    from dynamo_tpu.ops import ragged_attention as ra
+
+    before = ra.traced_calls().get(("decode", "reference"), 0)
+    core = make_core(2, megastep_k=8)
+    seq = core.add_request(_req([1, 2, 3], "d", max_tokens=16, ignore_eos=True))
+    drive(core, [seq])
+    assert core.scheduler_stats()["pp_fused_dispatches"] >= 1
+    assert ra.traced_calls().get(("decode", "reference"), 0) > before
+    assert "reference" in ra.traced_impl("decode").split("+")
+
+
 # -- the A/B bar --------------------------------------------------------------
 
 

@@ -1,0 +1,252 @@
+"""Decode attention alone, at the decode shapes the benchmark's cells
+run, timed from a device trace.
+
+For each shape (lanes, heads, page-table width, page array and context
+distribution of a cell) it runs the library ragged kernel over a sweep
+of its grid (``num_queries_per_block`` x ``num_kv_pages_per_block``),
+the serving entry's decode path first (``serving``: whatever
+``ops/ragged_attention.py`` does with ``cu_q_lens=None``), and prints
+for each: kernel microseconds a call (mean device duration of the
+kernel's events in a trace), the share of the HBM roofline (bytes of the
+pages in use / peak bandwidth over that time: at the cells' 32-token
+pages, what the benchmark's ``attn_decode_roofline`` computes), and the largest
+difference from the serving path's output.
+
+Refuses to run without a TPU: a time from the CPU says nothing here.
+
+Usage (through the chip tool, from the repo root):
+    python -m tools.attn_decode_bench [--shapes 7b,1p5b-32,ouro] [--quick]
+                                      [--page-size 32]
+``--page-size`` re-cuts a shape's cache and tables into pages of another
+size (the worker's ``--block-size``), the same tokens in all.
+Writes ``chiprun_out/attn_decode_bench/table.json`` beside the table.
+"""
+
+from __future__ import annotations
+
+import argparse
+import bisect
+import json
+import math
+import re
+import tempfile
+import time
+from pathlib import Path
+
+import numpy as np
+
+# lanes, q heads, kv heads, page-table width, pages in one layer's array,
+# contexts (kind, lo, hi): qwen7b-decode-batch; qwen1p5b-chat-steady at
+# its three decode widths; ouro2p6b-reason-decode (4 planes of 169 pages).
+SHAPES = {
+    "7b": (32, 28, 4, 256, 3073, ("uniform", 384, 1536)),
+    "1p5b-8": (8, 12, 2, 256, 11265, ("loguniform", 160, 2560)),
+    "1p5b-16": (16, 12, 2, 256, 11265, ("loguniform", 160, 2560)),
+    "1p5b-32": (32, 12, 2, 256, 11265, ("loguniform", 160, 2560)),
+    "ouro": (8, 16, 16, 64, 676, ("uniform", 128, 608)),
+}
+HEAD_DIM = 128
+PAGE_SIZE = 32   # of SHAPES' widths and page counts; main() may re-cut it
+CALLS = 8   # timed calls of each variant inside the trace
+
+
+def geometry(shape: str):
+    """``SHAPES[shape]`` with its table width and page count re-cut from
+    32-token pages to ``PAGE_SIZE``."""
+    lanes, n_q, n_kv, width, n_pages, contexts = SHAPES[shape]
+    return (lanes, n_q, n_kv, width * 32 // PAGE_SIZE,
+            (n_pages - 1) * 32 // PAGE_SIZE + 1, contexts)
+
+
+def make_case(shape: str, seed: int):
+    import jax.numpy as jnp
+
+    lanes, n_q, n_kv, width, n_pages, (kind, lo, hi) = geometry(shape)
+    rng = np.random.RandomState(seed)
+    u = (rng.permutation(lanes) + 0.5) / lanes      # mid-point quantiles
+    if kind == "uniform":
+        lens = lo + u * (hi - lo)
+    else:
+        lens = np.exp(math.log(lo) + u * (math.log(hi) - math.log(lo)))
+    lens = lens.astype(np.int32)
+    q = jnp.asarray(rng.randn(lanes, n_q, HEAD_DIM), jnp.bfloat16)
+    kv = jnp.asarray(
+        rng.randn(n_pages, PAGE_SIZE, 2 * n_kv, HEAD_DIM), jnp.bfloat16)
+    tables = np.zeros((lanes, width), np.int32)
+    perm, used = rng.permutation(n_pages - 1), 0
+    for s, n in enumerate(lens):
+        need = -(-int(n) // PAGE_SIZE)
+        tables[s, :need] = perm[used:used + need]
+        used += need
+    blocks = int(sum(-(-int(n) // PAGE_SIZE) for n in lens))
+    need_bytes = blocks * PAGE_SIZE * 2 * n_kv * HEAD_DIM * 2
+    return (q, kv, jnp.asarray(lens), jnp.asarray(tables)), need_bytes, lens
+
+
+def variants(shape: str, quick: bool):
+    """[(tag, fn(q, kv, lens, tables))], the serving decode path first."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu.ragged_paged_attention import (
+        ragged_paged_attention as library,
+    )
+
+    from dynamo_tpu.ops.ragged_attention import (
+        decode_shape_grid,
+        ragged_paged_attention,
+    )
+
+    lanes, _, _, width, _, _ = geometry(shape)
+    serving_grid = decode_shape_grid(PAGE_SIZE, width)
+    sm = HEAD_DIM ** -0.5
+
+    def serving(q, kv, lens, tables):
+        return ragged_paged_attention(
+            q, kv, lens, tables, None, jnp.asarray([lanes], jnp.int32),
+            sm_scale=sm)
+
+    def lib(qb, pages):
+        def fn(q, kv, lens, tables):
+            return library(
+                q, kv, lens, tables, jnp.arange(lanes + 1, dtype=jnp.int32),
+                jnp.asarray([lanes], jnp.int32), sm_scale=sm,
+                num_queries_per_block=qb, num_kv_pages_per_block=pages)
+        return fn
+
+    # The serving path IS one of the grids: the same program compiles to
+    # one executable under the first name, so the sweep leaves that one out.
+    out = [("serving q{}_p{}".format(*serving_grid), serving)]
+    qbs = (1, 8) if quick else (1, 2, 4, 8, 16, 32)
+    # KV blocks of 128 ... 1024 tokens: 4 ... 32 pages of 32.
+    tokens = (256, 512) if quick else (128, 256, 384, 512, 768, 1024)
+    pgs = sorted({t // PAGE_SIZE for t in tokens} - {0})
+    for qb in qbs:
+        for pages in pgs:
+            if (qb <= max(lanes, 8) and pages <= width
+                    and (qb, pages) != serving_grid):
+                out.append((f"lib_q{qb}_p{pages}", lib(qb, pages)))
+    return out
+
+
+def kernel_times(trace_dir: str) -> dict[str, list[float]]:
+    """{program: [device ns of each attention-kernel event]}. A device op
+    carries no program name on this installation; the "XLA Modules" line
+    does ("jit_<name>(<program id>)"), and an op belongs to the module
+    whose interval holds its start."""
+    from jax.profiler import ProfileData
+
+    path = max(Path(trace_dir).rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
+    out: dict[str, list[float]] = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not (plane.name.startswith("/device:") and "TPU" in plane.name):
+            continue
+        lines = {line.name: line for line in plane.lines}
+        if "XLA Ops" not in lines or "XLA Modules" not in lines:
+            continue
+        modules = sorted(
+            (e.start_ns, e.start_ns + e.duration_ns, re.sub(r"\(.*\)$", "", e.name))
+            for e in lines["XLA Modules"].events)
+        starts = [m[0] for m in modules]
+        for e in lines["XLA Ops"].events:
+            # The event's name is the op's HLO text, "%<name> = ...".
+            if "ragged_paged_attention" not in e.name[:120].split(" = ")[0]:
+                continue
+            i = bisect.bisect_right(starts, e.start_ns) - 1
+            if i >= 0 and e.start_ns < modules[i][1]:
+                out.setdefault(modules[i][2], []).append(e.duration_ns)
+    return out
+
+
+def bench_shape(shape: str, seed: int, quick: bool, hbm_bytes_per_s: float):
+    import jax
+
+    args, need_bytes, lens = make_case(shape, seed)
+    floor_us = 1e6 * need_bytes / hbm_bytes_per_s
+    geo = geometry(shape)
+    print(f"## {shape}: lanes {geo[0]}, heads {geo[1]}/{geo[2]}, page size "
+          f"{PAGE_SIZE}, table width {geo[3]}, pages {geo[4]}, contexts "
+          f"{int(lens.min())}-{int(lens.max())} "
+          f"(mean {lens.mean():.0f}); blocks in use {need_bytes / 1e6:.2f} MB "
+          f"= {floor_us:.1f} us at the HBM's peak", flush=True)
+    ready, rows, base = [], [], None
+    for tag, fn in variants(shape, quick):
+        fn.__name__ = f"{shape}_{tag}".replace("-", "_").replace(" ", "_")
+        jitted = jax.jit(fn)
+        t0 = time.perf_counter()
+        try:
+            out = np.asarray(jax.block_until_ready(jitted(*args)), np.float32)
+        except Exception as e:  # noqa: BLE001 — a refused grid is a row of the table
+            rows.append({"shape": shape, "variant": tag,
+                         "error": f"{type(e).__name__}: {str(e)[:300]}"})
+            print(f"{tag:16s} REFUSED {type(e).__name__}: {str(e)[:160]}", flush=True)
+            continue
+        base = out if base is None else base
+        ready.append((tag, jitted, float(np.max(np.abs(out - base))),
+                      time.perf_counter() - t0))
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        for _, jitted, _, _ in ready:
+            for _ in range(CALLS):
+                out = jitted(*args)
+            jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        times = kernel_times(trace_dir)
+    print(f"# trace: kernel events under {len(times)} programs, e.g. "
+          f"{sorted(times)[:2]}", flush=True)
+    for tag, jitted, diff, compile_s in ready:
+        ns = times.get(f"jit_{jitted.__name__}", [])
+        if not ns:
+            rows.append({"shape": shape, "variant": tag, "error": "no kernel event"})
+            print(f"{tag:16s} no kernel event under jit_{jitted.__name__}; the "
+                  f"trace has {sorted(times)[:4]}", flush=True)
+            continue
+        us = sum(ns) / len(ns) / 1e3
+        rows.append({
+            "shape": shape, "variant": tag, "kernel_us": us,
+            "kernel_us_min": min(ns) / 1e3, "calls": len(ns),
+            "roofline_share": 100.0 * floor_us / us,
+            "max_abs_diff_vs_serving": diff, "compile_run_s": compile_s,
+        })
+        print(f"{tag:16s} {us:9.1f} us a call (min {min(ns) / 1e3:8.1f}, "
+              f"{len(ns)} calls)  {100.0 * floor_us / us:5.1f}% of the HBM "
+              f"roofline  max|diff| {diff:.4f}", flush=True)
+    return rows
+
+
+def main() -> int:
+    global PAGE_SIZE
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--seed", type=int, default=2147483659)
+    ap.add_argument("--page-size", type=int, default=PAGE_SIZE,
+                    choices=(8, 16, 32, 64, 128, 256))
+    ap.add_argument("--quick", action="store_true",
+                    help="a few grids only (a smoke run of the tool)")
+    args = ap.parse_args()
+    PAGE_SIZE = args.page_size
+
+    from dynamo_tpu.device import device_info, device_peaks, enable_compile_cache
+
+    enable_compile_cache()
+    info = device_info()
+    if info["platform"] != "tpu":
+        raise SystemExit(
+            f"tools/attn_decode_bench.py: no TPU (platform {info['platform']!r}, "
+            f"device_kind {info['kind']!r}); a kernel time from another "
+            "device is not a measurement of this one")
+    peaks = device_peaks(info["kind"])
+    print(f"# device {info['kind']} x {info['count']}; HBM peak "
+          f"{peaks.hbm_gbps} GB/s ({peaks.source}); seed {args.seed}", flush=True)
+    rows = []
+    for shape in args.shapes.split(","):
+        rows += bench_shape(shape, args.seed, args.quick, peaks.hbm_gbps * 1e9)
+    out = Path("chiprun_out/attn_decode_bench")
+    out.mkdir(parents=True, exist_ok=True)
+    name = "table.json" if PAGE_SIZE == 32 else f"table_page{PAGE_SIZE}.json"
+    (out / name).write_text(json.dumps(
+        {"device": info, "seed": args.seed, "page_size": PAGE_SIZE,
+         "rows": rows}, indent=1))
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())
