@@ -158,7 +158,7 @@ def main() -> None:
             prefill_buckets=(bucket,),
             prefill_batch=min(16, max(batch, 8)),
             decode_buckets=(max(batch, 8),),
-            decode_chain=min(32, osl),
+            megastep_k=min(32, osl),
         )
         return EngineCore(cfg, eng, seed=0)
 

@@ -518,9 +518,9 @@ def kernel_phase(mode: str, inject: str | None, report: dict) -> None:
 
 
 def kernel_check_child(corrupt: bool) -> int:
-    """Compile the Pallas attention kernels (the library's ragged one at
-    its ragged and its decode-shaped grids, the orphan paged one) WITHOUT
-    interpret mode on a TPU and compare with the repo's references.
+    """Compile the library's Pallas attention kernel, at its ragged and
+    its decode-shaped grids, WITHOUT interpret mode on a TPU and compare
+    with the repo's reference.
 
     Tolerances. Inputs and outputs are bf16 (8 significand bits: one ulp
     is 2^-8 relative) and the kernels keep bf16 operands on the MXU with
@@ -529,15 +529,12 @@ def kernel_check_child(corrupt: bool) -> int:
     difference seen on a v5e): atol 2^-5, rtol 2e-2. The
     references (and only they: Mosaic rejects an f32 x bf16 matmul at
     fp32 contract precision) run at ``highest`` matmul precision so that
-    they, not the kernel, are the exact side. The int8 path compares the kernel's
-    in-VMEM dequant with the reference's dequant-on-gather of the SAME
-    int8 pages and scales, so quantisation error cancels and the same
-    bound holds — in interpret mode only: on a TPU the int8-page variant
-    must raise with Mosaic's reason (ops/paged_attention.py).
+    they, not the kernel, are the exact side.
 
-    On the CPU (``--cpu-tiny``) the first-party kernel runs in interpret
-    mode at a small shape; the library kernel is reported as not run (the
-    TPU interpreter cannot execute its reshaped refs)."""
+    On the CPU (``--cpu-tiny``) the library kernel is reported as not run
+    (the TPU interpreter cannot execute its reshaped refs); the serving
+    entry's decode shape runs the jnp reference at a small geometry, so
+    that the comparison itself is rehearsed."""
     sys.path.insert(0, str(HERE))
     from dynamo_tpu.device import device_info, enable_compile_cache
 
@@ -546,11 +543,6 @@ def kernel_check_child(corrupt: bool) -> int:
     import jax.numpy as jnp
     import numpy as np
 
-    from dynamo_tpu.engine.kv_quant import quantize_kv
-    from dynamo_tpu.ops.paged_attention import (
-        paged_attention_pallas,
-        paged_attention_reference,
-    )
     from dynamo_tpu.ops.ragged_attention import (
         pallas_ragged_attention,
         ragged_paged_attention_ref,
@@ -686,40 +678,12 @@ def kernel_check_child(corrupt: bool) -> int:
                   lambda: jax.jit(lambda *a: ragged_paged_attention(
                       *a, None, lanes_, sm_scale=sm))(*case),
                   lambda: decode_ref(*case))
-
-    # (ii) the orphan paged kernel (other layout), bf16 and int8 pages,
-    # head_dim 128, block 32, 7 query heads per KV head.
-    B, bs, max_blocks, blocks = (8, 32, 16, 256) if on_tpu else (2, 32, 4, 16)
-    q = jnp.asarray(rng.randn(B, n_q, d), jnp.bfloat16)
-    k = jnp.asarray(rng.randn(n_kv, blocks * bs, d), jnp.bfloat16)
-    v = jnp.asarray(rng.randn(n_kv, blocks * bs, d), jnp.bfloat16)
-    tables = jnp.asarray(
-        rng.permutation(blocks)[:B * max_blocks].reshape(B, max_blocks), jnp.int32)
-    seq_lens = jnp.asarray(rng.randint(bs, max_blocks * bs, B), jnp.int32)
-    k8, ks = quantize_kv(k)
-    v8, vs = quantize_kv(v)
-    common = dict(block_tables=tables, seq_lens=seq_lens, block_size=bs)
-    bf16 = dict(k_cache=k, v_cache=v)
-    int8 = dict(k_cache=k8, v_cache=v8, k_scale=ks, v_scale=vs)
-    check("first-party paged kernel, bf16 pages",
-          lambda: paged_attention_pallas(q, interpret=not on_tpu, **common, **bf16),
-          lambda: paged_attention_reference(q, **common, **bf16))
-    if on_tpu:
-        # Mosaic refuses the int8-page variant (the reason is recorded in
-        # ops/paged_attention.py); what is checked is that asking for it
-        # on a TPU is an error that says so, not a quiet reference run.
-        try:
-            paged_attention_pallas(q, **common, **int8)
-            refused = None
-        except NotImplementedError as e:
-            refused = str(e)
-        checks.append({"name": "first-party paged kernel, int8 pages",
-                       "ok": bool(refused) and not corrupt,
-                       "raises_on_tpu": refused})
     else:
-        check("first-party paged kernel, int8 pages (interpreted)",
-              lambda: paged_attention_pallas(q, interpret=True, **common, **int8),
-              lambda: paged_attention_reference(q, **common, **int8))
+        case = decode_case(4, 4, 2, 8, 4, 17, 2, 32)
+        check("decode-shaped attention, rehearsal: 4 lanes, 4/2 heads",
+              lambda: ragged_paged_attention(
+                  *case, None, jnp.asarray([4], jnp.int32), sm_scale=sm),
+              lambda: decode_ref(*case))
 
     for c in checks:
         print(f"  {'ok  ' if c['ok'] else 'FAIL'} {c}", file=sys.stderr, flush=True)

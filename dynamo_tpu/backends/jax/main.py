@@ -1379,8 +1379,8 @@ def main() -> None:
              "flags; host drains outputs every k steps). Prefill chunks "
              "ride the fused dispatch and continue as decode rows; spec "
              "verify rows resolve accept/reject on device. 1 = off (one "
-             "dispatch per token); unset = inherit the legacy "
-             "decode-chain default (8). Token stream is bit-identical "
+             "dispatch per token); unset = the preset's (8). Token "
+             "stream is bit-identical "
              "for any k; only a stop watch wider than 8 ids forces a "
              "batch back to single-step",
     )

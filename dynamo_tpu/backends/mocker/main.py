@@ -814,8 +814,7 @@ def main() -> None:
     ap.add_argument("--obs-publish", default="on", choices=["on", "off"],
                     help="publish periodic metric snapshots on the event "
                          "plane for the fleet aggregator (off the sim "
-                         "step; <2%% TPOT overhead asserted by bench "
-                         "run_fleet_obs_ab)")
+                         "step)")
     ap.add_argument("--obs-interval-s", type=float, default=1.0,
                     help="metric-snapshot publish interval")
     ap.add_argument("--chaos-plan", default="",

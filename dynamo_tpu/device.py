@@ -2,8 +2,8 @@
 no-silent-CPU guard, published peak rates, the persistent compile cache,
 and a per-program compile-time log.
 
-Every entry point that compiles for the chip (the JAX worker, bench.py,
-tools/profile_*.py, chip_smoke.py's children) calls
+Every entry point that compiles for the chip (the JAX worker,
+tools/attn_decode_bench.py, chip_smoke.py's children) calls
 :func:`enable_compile_cache` first and names its device with
 :func:`device_info`; measurement paths take their peaks from
 :func:`device_peaks`, which raises on a device nobody has looked up.

@@ -174,13 +174,6 @@ class EngineConfig:
     enable_prefix_caching: bool = True
     # Decode batch buckets: compile decode at these widths only.
     decode_buckets: tuple[int, ...] = (8, 16, 32, 64)
-    # Multi-step decode (LEGACY alias — see megastep_k): chain this many
-    # decode+sample steps in ONE device program (sampled tokens feed back
-    # on-device via lax.scan), amortizing dispatch/host latency. Stop
-    # conditions are applied per token on the host afterwards; near the
-    # context edge the engine falls back to single steps. 1 = classic
-    # per-token stepping.
-    decode_chain: int = 8
     # Decode MEGASTEP (PERF.md r9): fuse this many decode iterations into
     # ONE device dispatch — an on-device scan over the ragged program
     # with device-resident sampling ((seed, counter)-keyed per inner
@@ -188,21 +181,21 @@ class EngineConfig:
     # max-tokens; lanes that stop early run masked no-op iterations),
     # and the host draining outputs every k steps through the
     # double-buffered fetch. Amortizes the fixed per-dispatch overhead
-    # by k× (PERF.md "Bring-up on v5e" has the measured per-dispatch
-    # cost). The token stream is BIT-IDENTICAL
+    # by k× (PERF.md section 5 has the host's measured cost a
+    # dispatch). The token stream is BIT-IDENTICAL
     # for any k (greedy and seeded sampling; host stop-scan stays the
     # authority — host-only stops roll back via num_computed_tokens).
-    # 1 = off (one dispatch per decode token); 0 = inherit the legacy
-    # decode_chain knob. UNIVERSAL (ISSUE 12): every step shape rides
-    # the scanned body — chunked mixed steps fuse their ragged first
-    # iteration (prefill chunks + decode rows + verify rows) with k-1
+    # 1 = off (one dispatch per decode token). UNIVERSAL (ISSUE 12):
+    # every step shape rides the scanned body — chunked mixed steps
+    # fuse their ragged first iteration (prefill chunks + decode rows +
+    # verify rows) with k-1
     # scanned decode iterations, spec verify rows resolve accept/reject
     # ON DEVICE (rejected drafts roll back inside the dispatch via the
     # lane's position cursor), and a prefill chunk that completes its
     # prompt continues as a decode row in the same dispatch. The one
     # forced-k=1 path left is a stop watch wider than the device's
     # MEGASTEP_WATCH_W slots (surfaced as megastep_forced_single).
-    megastep_k: int = 0
+    megastep_k: int = 8
 
     # Sequence-parallel long-context prefill: prompts at least this long
     # (with no cached prefix) run as ONE dense ring-attention pass over
@@ -307,10 +300,9 @@ class EngineConfig:
 
     @property
     def megastep(self) -> int:
-        """Resolved decode-megastep length (inner iterations per device
-        dispatch): ``megastep_k`` when set (>= 1), else the legacy
-        ``decode_chain`` knob it supersedes."""
-        return self.megastep_k if self.megastep_k >= 1 else self.decode_chain
+        """Decode-megastep length (inner iterations per device
+        dispatch)."""
+        return self.megastep_k
 
     @property
     def max_blocks_per_seq(self) -> int:

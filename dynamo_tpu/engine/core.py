@@ -949,10 +949,10 @@ class EngineCore:
             )
         if engine_cfg.spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {engine_cfg.spec_k}")
-        if engine_cfg.megastep_k < 0:
+        if engine_cfg.megastep_k < 1:
             raise ValueError(
-                f"megastep_k must be >= 0 (0 inherits decode_chain, 1 "
-                f"disables fusion), got {engine_cfg.megastep_k}"
+                f"megastep_k must be >= 1 (1 disables fusion), got "
+                f"{engine_cfg.megastep_k}"
             )
         from dynamo_tpu.engine.kv_quant import KV_DTYPES
 
@@ -4304,7 +4304,7 @@ class EngineCore:
 
     def _chain_length(self, seqs: list[Sequence]) -> int:
         """Inner iterations of this megastep: the resolved megastep k
-        (``--megastep-k``, falling back to the legacy decode_chain knob),
+        (``--megastep-k``),
         capped by the context edge (hard limit — no writes past the
         block table) and by the batch's LARGEST remaining generation
         budget (with every lane's budget nearly spent, long megasteps

@@ -9,8 +9,8 @@ active tenant holds a deficit counter; when the rotation pointer visits
 a tenant it earns one quantum of tokens, and its head request is
 admitted only once the deficit covers the request's prompt cost. Light
 tenants therefore admit at most one quantum behind a flood, regardless
-of how deep the heavy tenant's backlog is — the property the fairness
-A/B (bench.py run_overload_ab) measures.
+of how deep the heavy tenant's backlog is — the property
+tests/test_overload.py pins on the mocker.
 
 Design constraints:
 

@@ -301,12 +301,11 @@ def init_cache(cfg: ModelConfig, engine: EngineConfig, dtype=None) -> tuple:
     ``[n_pages, page_size, 2*n_kv, d]`` (the last page is the garbage
     page absorbing padded-position writes).
 
-    Per-layer arrays instead of one stacked ``[L, ...]`` tensor is a
-    measured −1.4 ms/step at 1B decode shapes (tools/profile_decode.py
-    full vs full_split_cache, PERF.md r5): feeding the Pallas attention
-    custom call a ``cache[l]`` slice of the stacked donated buffer made
-    XLA materialize a per-layer copy each step; separate buffers give
-    the kernel aliased views for free. Pipeline parallelism keeps the
+    Per-layer arrays instead of one stacked ``[L, ...]`` tensor:
+    feeding the Pallas attention custom call a ``cache[l]`` slice of
+    the stacked donated buffer made XLA materialize a per-layer copy
+    each step; separate buffers give the kernel aliased views for free.
+    Pipeline parallelism keeps the
     stacked layout (:func:`init_cache_stacked`) — its stage sharding IS
     the layer axis.
 
@@ -618,8 +617,7 @@ def _logits(x: jax.Array, params: Params, cfg: ModelConfig) -> jax.Array:
     if cfg.tie_embeddings:
         # Contract over h with embed kept [V, h]: dot_general reads the
         # embedding matrix in its stored layout. `embed.T` materialized a
-        # 2x-param-size transposed copy EVERY decode step (measured
-        # +1.6 ms/step at 1B scale on v5e — tools/profile_decode.py).
+        # 2x-param-size transposed copy EVERY decode step.
         return jax.lax.dot_general(
             x, params["embed"],
             (((x.ndim - 1,), (1,)), ((), ())),

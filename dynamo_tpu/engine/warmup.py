@@ -8,8 +8,7 @@ stream silent for the whole compile; the frontend's stall deadline
 (``DYN_DATAPLANE_STALL_TIMEOUT_S``) then declares the worker dead and
 replays the stream onto the same, still compiling, worker. So the worker
 drives synthetic requests through the normal ``add_request``/``step``
-path before ``register_llm`` — the same thing bench.py does for itself —
-and the deadline stays what it is.
+path before ``register_llm``, and the deadline stays what it is.
 
 Covered: every prefill bucket one wave can reach and every decode width
 ``max_num_seqs`` can reach at the full megastep length, for the two

@@ -20,10 +20,10 @@ Each worker is a :class:`MockTpuEngine` with its own local virtual clock
 in global time order, and between events every worker steps its
 admit/step loop forward until it catches up. Iteration cost uses the
 mocker's priced cost model (``base_iter_us + p*prefill_us_per_token +
-d*decode_us_per_seq``), identical to bench run_overload_ab. Peer-prefix
-pulls are priced per SOURCE (``pull_ms_per_block`` × blocks moved) so a
-slow peer is measurably slow — and the measurement flows through the
-same ``note_pull`` EWMA the jax worker publishes.
+d*decode_us_per_seq``). Peer-prefix pulls are priced per SOURCE
+(``pull_ms_per_block`` × blocks moved) so a slow peer is measurably
+slow — and the measurement flows through the same ``note_pull`` EWMA
+the jax worker publishes.
 
 Scale-down is a graceful drain, never a kill: a drained worker stops
 receiving new placements, finishes everything it holds (waiting AND

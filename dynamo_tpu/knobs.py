@@ -129,16 +129,6 @@ KNOBS: dict[str, Knob] = _freeze(
          "k*pp + pp-1 hops per dispatch against pp hops per token on the "
          "host-rollback baseline)"),
     # -- TPU kernels ----------------------------------------------------
-    Knob("DYNAMO_TPU_PAGED_ATTN", "xla", "str", "kernels",
-         "paged-attention backend: `xla` or `pallas`"),
-    Knob("DYNAMO_TPU_ATTN_PAGES_PER_BLOCK", 8, "int", "kernels",
-         "ragged-attention kernel: KV pages fetched per grid block "
-         "(ragged calls; a decode-shaped call has a grid of its own)"),
-    Knob("DYNAMO_TPU_ATTN_QUERIES_PER_BLOCK", 8, "int", "kernels",
-         "ragged-attention kernel: queries per grid block of a ragged "
-         "call of at most 64 rows"),
-    Knob("DYNAMO_TPU_ATTN_PREFILL_QUERIES_PER_BLOCK", 128, "int", "kernels",
-         "ragged-attention kernel: prefill queries per grid block"),
     Knob("DYNAMO_TPU_NO_NATIVE", "", "str", "kernels",
          "non-empty disables the C++ radix-trie indexer (pure-Python "
          "fallback)"),

@@ -107,16 +107,15 @@ class MockEngineArgs:
     # kv_read_us_per_block x the dtype's byte ratio (engine/kv_quant.py:
     # 1.0 for bf16, ~0.516 for int8 at head_dim 128, scales included).
     # kv_read_us_per_block=0 (default) keeps every existing timing
-    # bit-identical; bench.py run_kvquant_ab sets it for the A/B. Token
-    # VALUES never change — only the virtual clock and capacity move.
+    # bit-identical. Token VALUES never change — only the virtual clock
+    # and capacity move.
     kv_dtype: str = "bf16"
     kv_read_us_per_block: float = 0.0
     # Cluster KV pool (ISSUE 11): virtual-clock price of pulling ONE
     # bf16-equivalent KV block from a peer over the dataplane, scaled by
     # the kv_dtype's byte ratio (int8 pulls move ~0.52x the bytes — the
     # packed wire buffer IS the transfer format). 0 = pulls are free on
-    # the clock (legacy timing untouched); bench run_peer_pool_ab sets it
-    # for the shared-prefix fleet A/B.
+    # the clock (legacy timing untouched).
     kv_pull_us_per_block: float = 0.0
     # Overload robustness (mirrors EngineConfig, ISSUE 10): per-tenant
     # DRR fair admission (off = exact FIFO; single tenant is FIFO either
@@ -132,9 +131,9 @@ class MockEngineArgs:
     # iterations over pp stages plus the pipe fill/drain bubble. With
     # megastep_k=1 that is the host-rollback pp baseline (one priced
     # dispatch + bubble PER TOKEN); with megastep_k=k the same bubble
-    # amortizes over k tokens under ONE base_iter_us — exactly the fused
-    # pp megastep A/B bench.py run_pp_megastep_ab asserts. Token VALUES
-    # are unchanged — pp streams stay bit-identical to pp=1.
+    # amortizes over k tokens under ONE base_iter_us. Token VALUES are
+    # unchanged — pp streams stay bit-identical to pp=1
+    # (tests/test_pp_megastep.py).
     pp: int = 1
 
 
@@ -268,9 +267,9 @@ class MockTpuEngine:
             cost_fn=lambda s: len(s.prompt),
         )
         self._running: list[_Seq] = []
-        # Deadline clock — injectable so virtual-clock drivers (bench
-        # run_overload_ab, fairness tests) expire queued requests on the
-        # simulated timeline instead of the wall.
+        # Deadline clock — injectable so virtual-clock drivers (the
+        # fairness tests) expire queued requests on the simulated
+        # timeline instead of the wall.
         self.clock = time.time
         self._wakeup = asyncio.Event()
         self._loop_task: asyncio.Task | None = None
@@ -632,8 +631,7 @@ class MockTpuEngine:
 
         ``kv_blocks_read`` prices the DMA-bound decode KV traffic
         (resident blocks read per lane-iteration), scaled by the
-        configured kv_dtype's byte ratio — int8 halves this term, which
-        is exactly the int8-page win bench.py run_kvquant_ab measures."""
+        configured kv_dtype's byte ratio — int8 halves this term."""
         host_s = self.args.base_iter_us / 1e6
         device_s = (
             prefill_tokens * self.args.prefill_us_per_token

@@ -50,8 +50,6 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dynamo_tpu import knobs as _knobs
-
 log = logging.getLogger("dynamo_tpu.ops.ragged_attention")
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -60,13 +58,11 @@ _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 # batches and 8-query blocks for calls of at most 64 rows (verify rows,
 # small chunks and mixed batches), 128-query blocks for prefill waves
 # (the kernel's own tuned table can pick whole-wave q blocks whose
-# scratch exceeds scoped VMEM at T >= 2048). Env-overridable for
-# on-chip tuning sweeps; 0 = the kernel's defaults.
-_SMALL_KV_PAGES_PER_BLOCK = _knobs.get_int("DYNAMO_TPU_ATTN_PAGES_PER_BLOCK")
-_SMALL_QUERIES_PER_BLOCK = _knobs.get_int("DYNAMO_TPU_ATTN_QUERIES_PER_BLOCK")
-_PREFILL_QUERIES_PER_BLOCK = _knobs.get_int(
-    "DYNAMO_TPU_ATTN_PREFILL_QUERIES_PER_BLOCK"
-)
+# scratch exceeds scoped VMEM at T >= 2048). Constants, as the decode
+# grid's are: a sweep passes its grid as an argument.
+_SMALL_KV_PAGES_PER_BLOCK = 8
+_SMALL_QUERIES_PER_BLOCK = 8
+_PREFILL_QUERIES_PER_BLOCK = 128
 
 # The grid of a DECODE-SHAPED call: one query a block, 512 KV tokens a
 # block (:func:`decode_shape_grid`). The kernel walks a query block's
@@ -204,33 +200,26 @@ def pallas_ragged_attention(
     """The library Pallas kernel under this repo's explicit grid:
     ``cu_q_lens=None`` (the decode shape) takes
     :func:`decode_shape_grid`; ragged calls of at most 64 rows the small
-    grid, prefill waves a capped query block (the module-level comments;
-    env 0 restores the kernel's own defaults for the ragged calls).
-    Real-valued pages only."""
+    grid, prefill waves a capped query block (the module-level
+    comments). Real-valued pages only."""
     from jax.experimental.pallas.ops.tpu.ragged_paged_attention import (
         ragged_paged_attention as _kernel,
     )
 
-    kw = {}
     if cu_q_lens is None:
         cu_q_lens = jnp.arange(q.shape[0] + 1, dtype=jnp.int32)
         qb, pages = decode_shape_grid(kv_pages.shape[1], page_indices.shape[1])
-        kw = dict(num_kv_pages_per_block=pages, num_queries_per_block=qb)
-    elif _SMALL_KV_PAGES_PER_BLOCK > 0:
+    else:
         qb = (
             _SMALL_QUERIES_PER_BLOCK
             if q.shape[0] <= 64
             else min(_PREFILL_QUERIES_PER_BLOCK, q.shape[0])
         )
-        kw = dict(
-            num_kv_pages_per_block=min(
-                _SMALL_KV_PAGES_PER_BLOCK, page_indices.shape[1]
-            ),
-            num_queries_per_block=qb,
-        )
+        pages = min(_SMALL_KV_PAGES_PER_BLOCK, page_indices.shape[1])
     return _kernel(
         q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs,
-        sm_scale=sm_scale, **kw,
+        sm_scale=sm_scale,
+        num_kv_pages_per_block=pages, num_queries_per_block=qb,
     )
 
 
@@ -253,9 +242,7 @@ def ragged_paged_attention(
     (halved gather bytes). The TPU library kernel takes real-valued
     pages, so the int8 serving path dequantizes the REFERENCED pages
     before the call when that is smaller than the whole cache, else the
-    whole cache — a capacity win, no traffic win (ROADMAP S10). The
-    orphan kernel's int8-page variant (ops/paged_attention.py) was meant
-    to carry the traffic win and does not compile for TPU (ROADMAP D6)."""
+    whole cache — a capacity win, no traffic win (ROADMAP S10)."""
     d = q.shape[-1]
     page_size = kv_pages.shape[1]
     backend = jax.default_backend()

@@ -11,7 +11,7 @@ import inspect
 import os
 
 # Force CPU even when the ambient environment points at real TPU hardware
-# (tests are deterministic and cluster-free; bench.py uses the real chip).
+# (tests are deterministic and cluster-free; chipbench uses the real chip).
 # jax 0.9.0 with libtpu 0.0.34 honours JAX_PLATFORMS=cpu on a machine that
 # has a chip (checked on a v5e: jax.devices() is [CpuDevice]), so the
 # assignment covers this process and its subprocesses; the config update

@@ -270,14 +270,14 @@ def test_late_stop_rolls_back_optimistic_step():
     step was already dispatched optimistically: the zombie lane's
     in-flight tokens are discarded (its K/V writes sit past the cursor,
     never attended) and the stream matches the synchronous loop."""
-    ref = EngineCore(CFG, tiny_engine(decode_chain=1), seed=0)
+    ref = EngineCore(CFG, tiny_engine(megastep_k=1), seed=0)
     s = ref.add_request(_req([9, 9, 9], "r", max_tokens=12, ignore_eos=True))
     d, _, _ = drive(ref, [s])
     stop_tok = d["r"][5]  # mid-stream stop: 5 tokens then the stop
 
     def run(async_exec):
         core = EngineCore(
-            CFG, tiny_engine(async_exec=async_exec, decode_chain=1), seed=0
+            CFG, tiny_engine(async_exec=async_exec, megastep_k=1), seed=0
         )
         seq = core.add_request(_req(
             [9, 9, 9], "x", max_tokens=12, stop_token_ids=[stop_tok],
@@ -300,7 +300,7 @@ def test_late_stop_rolls_back_optimistic_step():
 
 def test_late_eos_rollback_async():
     """Same rollback through the EOS path (engine-level eos_token_ids)."""
-    probe = EngineCore(CFG, tiny_engine(decode_chain=1), seed=0)
+    probe = EngineCore(CFG, tiny_engine(megastep_k=1), seed=0)
     s = probe.add_request(_req([1, 2, 3], "p", max_tokens=10, ignore_eos=True))
     d, _, _ = drive(probe, [s])
     eos = d["p"][4]
@@ -310,7 +310,7 @@ def test_late_eos_rollback_async():
 
     def run(async_exec):
         core = EngineCore(
-            CFG, tiny_engine(async_exec=async_exec, decode_chain=1),
+            CFG, tiny_engine(async_exec=async_exec, megastep_k=1),
             seed=0, eos_token_ids=(eos,),
         )
         seq = core.add_request(_req([1, 2, 3], "e", max_tokens=10))
@@ -329,7 +329,7 @@ def test_steady_decode_dispatch_precedes_landing():
     empty when the host blocks (asserted via the dispatch/land event
     hook)."""
     # Built with defaults: the engine chooses the pipelined loop itself.
-    core = EngineCore(CFG, tiny_engine(decode_chain=1), seed=0)
+    core = EngineCore(CFG, tiny_engine(megastep_k=1), seed=0)
     assert core.pipelined and core.scheduler_stats()["async_exec"] == 1
     core._exec_log = []
     seqs = [
@@ -357,7 +357,7 @@ def test_steady_decode_dispatch_precedes_landing():
 def test_sync_loop_lands_before_next_dispatch():
     """The synchronous twin of the hook test: async off, every landing
     precedes the next dispatch (plan+commit per call)."""
-    core = EngineCore(CFG, tiny_engine(async_exec=False, decode_chain=1), seed=0)
+    core = EngineCore(CFG, tiny_engine(async_exec=False, megastep_k=1), seed=0)
     core._exec_log = []
     seq = core.add_request(_req([1, 2, 3], "a", max_tokens=8, ignore_eos=True))
     drive(core, [seq])
@@ -374,7 +374,7 @@ def test_block_pressure_drains_pipeline_and_recovers():
             CFG,
             tiny_engine(
                 num_kv_blocks=12, max_model_len=64, async_exec=async_exec,
-                scheduling="chunked", prefill_chunk=16, decode_chain=1,
+                scheduling="chunked", prefill_chunk=16, megastep_k=1,
             ),
             seed=0,
         )
@@ -399,7 +399,7 @@ def test_block_pressure_drains_pipeline_and_recovers():
 
 
 def test_cancel_mid_flight_discards_in_flight_tokens():
-    core = EngineCore(CFG, tiny_engine(async_exec=True, decode_chain=1), seed=0)
+    core = EngineCore(CFG, tiny_engine(async_exec=True, megastep_k=1), seed=0)
     seq = core.add_request(_req([1, 2, 3], "c", max_tokens=50, ignore_eos=True))
     core.step()  # dispatch prefill
     core.step()  # dispatch decode 1, commit prefill
@@ -418,7 +418,7 @@ def test_plan_commit_and_host_gap_spans_recorded():
     tracing.configure(enabled=True, sample=1.0)
     collector = tracing.get_collector()
     collector.clear()
-    core = EngineCore(CFG, tiny_engine(async_exec=True, decode_chain=1), seed=0)
+    core = EngineCore(CFG, tiny_engine(async_exec=True, megastep_k=1), seed=0)
     seq = core.add_request(_req([1, 2, 3], "t", max_tokens=8, ignore_eos=True))
     drive(core, [seq])
     stats = collector.stats()

@@ -164,7 +164,7 @@ def test_chunked_pure_decode_uses_fused_chains():
     """With no prefill pending, chunked scheduling falls back to the
     fused decode chain (multi-token chunks per step), not 1-token steps."""
     core = EngineCore(
-        CFG, tiny_engine(scheduling="chunked", decode_chain=8), seed=0
+        CFG, tiny_engine(scheduling="chunked", megastep_k=8), seed=0
     )
     seq = core.add_request(_req([1, 2, 3], "a", max_tokens=40, ignore_eos=True))
     core.step()  # prefill + first token

@@ -93,6 +93,43 @@ def test_the_decode_shape_is_what_an_arange_said():
     np.testing.assert_array_equal(np.asarray(stated), np.asarray(spelled))
 
 
+def test_int8_pages_give_the_attention_of_their_dequantised_values(monkeypatch):
+    """``kv_scales`` (int8 pages) at the decode shape, 7 query heads a KV
+    head: the reference dequantises as it gathers, the TPU path (its
+    kernel stood in for by the reference) dequantises the pages the
+    tables name and renumbers them. Both give the attention of the
+    dequantised pages, and lie as near the unquantised pages' as int8
+    allows: a value is off by at most amax / 254
+    (``test_quantize_dequantize_error_bound``), |v| <= ~5 here."""
+    from dynamo_tpu.engine.kv_quant import dequantize_kv, quantize_kv
+
+    q, kv, lens, tables = _case(8, 28, 4, seed=8)
+    kv8, scales = quantize_kv(kv)
+    num_seqs = jnp.asarray([8], jnp.int32)
+    want = _dense_softmax(q, dequantize_kv(kv8, scales), lens, tables)
+    exact = _dense_softmax(q, kv, lens, tables)
+
+    on_cpu = ra.ragged_paged_attention(
+        q, kv8, lens, tables, None, num_seqs, sm_scale=SM_SCALE, kv_scales=scales)
+
+    handed = []
+
+    def kernel(q, pages, *args, **kw):
+        handed.append(pages)
+        return ra.ragged_paged_attention_ref(q, pages, *args, **kw)
+
+    monkeypatch.setattr(ra.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ra, "pallas_ragged_attention", kernel)
+    on_tpu = ra.ragged_paged_attention(
+        q, kv8, lens, tables, None, num_seqs, sm_scale=SM_SCALE, kv_scales=scales)
+    assert handed[0].dtype == q.dtype and handed[0].shape[0] == tables.size
+
+    for got in (on_cpu, on_tpu):
+        assert got.dtype == q.dtype
+        _close(got, want)
+        assert np.max(np.abs(np.asarray(got, np.float64) - exact)) < 0.06
+
+
 # -- Mosaic takes the decode grid at the served shapes (a described v5e) -----------
 
 
@@ -196,6 +233,34 @@ def test_on_a_tpu_the_decode_shape_gets_one_sequence_a_query_block(monkeypatch, 
         q, kv, lens, tables)
     assert _delta(before) == {("ragged", "library"): 1}
     assert _pallas_calls(ragged.jaxpr) == [("ragged_paged_attention_kernel", (1, 4))]
+
+
+@pytest.mark.parametrize("rows,width,grid", [
+    (40, WIDTH, (8, 8)),      # verify rows, a small chunk
+    (2048, WIDTH, (128, 8)),  # a prefill wave
+    (40, 4, (8, 4)),          # a table narrower than the KV block
+])
+def test_a_ragged_call_gets_the_grid_the_cells_were_compiled_with(
+        monkeypatch, rows, width, grid):
+    """(queries, KV pages) a block that the library kernel is handed for a
+    ragged ``cu_q_lens``: constants of this module, and the values the
+    benchmark's programs were compiled with."""
+    import jax.experimental.pallas.ops.tpu.ragged_paged_attention as library
+
+    handed = []
+
+    def kernel(q, *args, num_queries_per_block, num_kv_pages_per_block, **kw):
+        handed.append((num_queries_per_block, num_kv_pages_per_block))
+        return q
+
+    monkeypatch.setattr(library, "ragged_paged_attention", kernel)
+    ra.pallas_ragged_attention(
+        jnp.zeros((rows, 4, HEAD_DIM), jnp.bfloat16),
+        jnp.zeros((9, PAGE_SIZE, 8, HEAD_DIM), jnp.bfloat16),
+        jnp.ones((2,), jnp.int32), jnp.zeros((2, width), jnp.int32),
+        jnp.asarray([0, rows // 2, rows], jnp.int32), jnp.asarray([2], jnp.int32),
+        sm_scale=SM_SCALE)
+    assert handed == [grid]
 
 
 def test_the_decode_grid_never_asks_for_more_pages_than_the_table_has(monkeypatch):

@@ -1,5 +1,5 @@
 """Nothing hides the device: compile-cache placement, the worker's
-no-silent-CPU guard, the peaks table, asked-for-and-unavailable kernels,
+no-silent-CPU guard, the peaks table, which attention a program got,
 one process per chip. Cheap by construction — no engine is built here.
 """
 
@@ -90,55 +90,7 @@ def test_peaks_table_raises_on_an_unknown_device_kind():
             device.device_peaks(kind)
 
 
-def test_bench_refuses_to_run_without_a_tpu():
-    sys.path.insert(0, str(REPO))
-    import bench
-
-    with pytest.raises(SystemExit, match="measures a TPU"):
-        bench.main()
-
-
-# -- kernels: asked for and unavailable is an error ------------------------------
-
-
-def test_paged_attn_pallas_knob_raises_when_unavailable(monkeypatch):
-    from dynamo_tpu.ops.paged_attention import paged_attention
-
-    def call(head_dim, block_size):
-        q = jnp.zeros((2, 4, head_dim), jnp.bfloat16)
-        cache = jnp.zeros((2, 4 * block_size, head_dim), jnp.bfloat16)
-        tables = jnp.zeros((2, 2), jnp.int32)
-        return paged_attention(
-            q, cache, cache, tables, jnp.ones((2,), jnp.int32),
-            block_size=block_size,
-        )
-
-    assert call(16, 8).shape == (2, 4, 16)  # default knob: the XLA path
-    monkeypatch.setenv("DYNAMO_TPU_PAGED_ATTN", "pallas")
-    with pytest.raises(ValueError, match="unsupported geometry"):
-        call(16, 8)  # head_dim 16: no lane-aligned page DMA
-    with pytest.raises(RuntimeError, match="needs a TPU backend"):
-        call(128, 32)  # geometry fine, but this suite runs on the CPU
-
-
-def test_int8_page_kernel_raises_outside_interpret_mode():
-    """Mosaic refused the int8-page variant on a v5e; compiling it is an
-    error carrying the compiler's reason, not a crash deep in lowering."""
-    from dynamo_tpu.ops.paged_attention import (
-        INT8_PAGES_ON_TPU,
-        paged_attention_pallas,
-    )
-
-    q = jnp.zeros((2, 4, 128), jnp.bfloat16)
-    cache = jnp.zeros((2, 4 * 32, 128), jnp.int8)
-    scale = jnp.ones((2, 4 * 32), jnp.float32)
-    with pytest.raises(NotImplementedError, match="aligned to tiling"):
-        paged_attention_pallas(
-            q, cache, cache, jnp.zeros((2, 2), jnp.int32),
-            jnp.ones((2,), jnp.int32), block_size=32,
-            k_scale=scale, v_scale=scale,
-        )
-    assert "ROADMAP D6" in INT8_PAGES_ON_TPU
+# -- kernels: the choice is said ---------------------------------------------------
 
 
 def test_ragged_attention_says_which_implementation_it_chose(caplog):
@@ -214,3 +166,27 @@ def test_chip_smoke_parent_has_no_jax_import_at_module_level():
     assert not any(
         (m or "").split(".")[0] in ("jax", "dynamo_tpu", "numpy") for m in names
     ), names
+
+
+# -- the README names files that exist ---------------------------------------------
+
+
+def test_the_readme_names_only_files_of_the_repo():
+    """Every backticked word of README.md that ends in .py, .json, .md or
+    .yml (wildcards and placeholders aside) is the path, or the tail of
+    the path, of a file git lists: the README writes some paths relative
+    to a package. A deleted file still named there fails here."""
+    listed = subprocess.run(
+        ["git", "ls-files"], cwd=REPO, capture_output=True, text=True
+    )
+    files = listed.stdout.split() if listed.returncode == 0 else []
+    if not files:  # not a git checkout: what is on disk
+        files = [str(p.relative_to(REPO)) for p in REPO.rglob("*.*")]
+    missing = set()
+    for span in re.findall(r"`([^`\n]+)`", (REPO / "README.md").read_text()):
+        for word in span.split():
+            word = word.strip(",;:()\"'").rstrip(".")
+            if re.search(r"\.(py|json|md|yml)$", word) and not re.search(r"[*<>{}$]", word):
+                if not any(f == word or f.endswith("/" + word) for f in files):
+                    missing.add(word)
+    assert not missing, f"README.md names files the repo does not have: {sorted(missing)}"

@@ -296,10 +296,10 @@ def test_logprobs_mixed_batch_only_requested_lanes():
 
 def test_chain_length_respects_generation_budgets():
     """Short-budget batches must not run full decode chains (tool-call
-    workloads: max_tokens=2 with decode_chain=32 used to burn 30 wasted
+    workloads: max_tokens=2 with megastep_k=32 used to burn 30 wasted
     fused steps per chain)."""
     # Synchronous loop: the budgets are read between two counted steps.
-    core = make_core(decode_chain=32, max_model_len=256, async_exec=False)
+    core = make_core(megastep_k=32, max_model_len=256, async_exec=False)
     s1 = core.add_request(_req([1, 2, 3], "a", max_tokens=2))
     s2 = core.add_request(_req([4, 5, 6], "b", max_tokens=3))
     core.step()  # prefill: each seq now has 1 generated token
@@ -313,7 +313,7 @@ def test_chain_length_respects_generation_budgets():
 
 
 def test_chain_length_unbounded_budget_keeps_full_chain():
-    core = make_core(decode_chain=8, max_model_len=256)
+    core = make_core(megastep_k=8, max_model_len=256)
     s = core.add_request(_req([1, 2, 3], "a", max_tokens=200, ignore_eos=True))
     core.step()
     assert core._chain_length([s]) == 8

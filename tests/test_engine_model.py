@@ -1,5 +1,5 @@
 """Engine-model numerics over the unified ragged forward: paged-cache
-consistency across prefill/decode splits, pallas parity, sampling."""
+consistency across prefill/decode splits, sampling."""
 
 import jax
 import jax.numpy as jnp
@@ -9,10 +9,6 @@ import pytest
 from dynamo_tpu.engine import config as cfgmod
 from dynamo_tpu.engine.model import decode_tokens, init_cache, init_params
 from dynamo_tpu.engine.sampler import sample
-from dynamo_tpu.ops.paged_attention import (
-    paged_attention_pallas,
-    paged_attention_reference,
-)
 from tests.model_harness import prefill_chunk
 
 CFG = cfgmod.tiny_model()
@@ -126,27 +122,6 @@ def test_mixed_ragged_batch_matches_separate_calls(params):
     )
     np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(want1), rtol=2e-3, atol=2e-3)
     np.testing.assert_allclose(np.asarray(logits[1]), np.asarray(want2), rtol=2e-3, atol=2e-3)
-
-
-def test_paged_attention_pallas_matches_reference():
-    rng = jax.random.PRNGKey(42)
-    B, n_q, n_kv, d, bs, max_blocks = 4, 8, 2, 16, 8, 6
-    total = (max_blocks * B + 1) * bs
-    ks = jax.random.split(rng, 4)
-    q = jax.random.normal(ks[0], (B, n_q, d), jnp.float32)
-    k_cache = jax.random.normal(ks[1], (n_kv, total, d), jnp.float32)
-    v_cache = jax.random.normal(ks[2], (n_kv, total, d), jnp.float32)
-    tables = np.arange(B * max_blocks, dtype=np.int32).reshape(B, max_blocks)
-    seq_lens = np.array([5, 17, 48, 1], np.int32)
-
-    want = paged_attention_reference(
-        q, k_cache, v_cache, jnp.asarray(tables), jnp.asarray(seq_lens), block_size=bs
-    )
-    got = paged_attention_pallas(
-        q, k_cache, v_cache, jnp.asarray(tables), jnp.asarray(seq_lens),
-        block_size=bs, interpret=True,
-    )
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
 def test_sampler_greedy_and_distributions():

@@ -6,12 +6,10 @@ pressure, independent prefill/decode pools — RecordingConnector, no
 sim), netcost unit tests (EWMA folding, cost ratios, selector shifts),
 and fleet-harness e2e (the autoscaling A/B, the NetKV routing A/B, and
 the drain/kill stream-identity audits — the acceptance criteria of the
-issue, at test scale; BENCH_r12.json pins the full-size run).
+issue, at test scale).
 """
 
 import asyncio
-import json
-import pathlib
 
 import pytest
 
@@ -21,6 +19,7 @@ from dynamo_tpu.fleet.harness import (
     FleetSpec,
     default_tenants,
     mocker_profile,
+    run_fleet_ab,
     run_routing_ab,
 )
 from dynamo_tpu.fleet.workload import TenantSpec, generate_arrivals, rate_at
@@ -43,7 +42,6 @@ from dynamo_tpu.planner.planner_core import (
     SlaTargets,
 )
 
-REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 # -- workload generator ------------------------------------------------------
@@ -406,18 +404,10 @@ def _mini_tenants():
 def test_fleet_ab_planner_beats_equal_budget_static():
     """The test-scale autoscaling A/B (one diurnal period): the closed
     loop tracks the swing, the same mean budget frozen in time misses
-    it. BENCH_r12.json pins the full-size claim; this guards the
-    mechanism in tier-1."""
-    def spec(on, static=0):
-        return FleetSpec(
-            tenants=default_tenants(), duration_s=240.0, seed=0,
-            planner_on=on, static_replicas=static, initial_replicas=4,
-            max_replicas=16, keep_streams=True,
-        )
-
-    planner = FleetHarness(spec(True)).run()
-    budget = max(1, round(planner.mean_replicas))
-    static = FleetHarness(spec(False, static=budget)).run()
+    it. This guards the mechanism in tier-1."""
+    ab = run_fleet_ab(duration_s=240.0, keep_streams=True)
+    planner, static = ab["planner"], ab["static"]
+    budget = ab["static_budget_replicas"]
 
     assert planner.broken_streams == 0 and static.broken_streams == 0
     assert planner.requests == static.requests > 5000
@@ -540,31 +530,3 @@ def test_mocker_profile_matches_cost_model():
     assert p.ttft_at(128) == pytest.approx(0.0328)
     # One decode iteration at full batch: 20 ms + 4*5 ms.
     assert d.itl_at(4) == pytest.approx(0.040)
-
-
-def test_bench_r12_recorded_and_holds_the_bar():
-    """The acceptance numbers are pinned IN THE REPO: BENCH_r12.json is
-    the full-size run of bench.run_fleet_ab, re-asserted here so a
-    regression that silently weakens the recorded claim fails tier-1."""
-    path = REPO / "BENCH_r12.json"
-    r = json.loads(path.read_text())
-    assert r["value"] >= 0.95                      # planner attainment
-    rows = {row["config"]: row for row in r["rows"]}
-    planner = next(v for k, v in rows.items() if k.startswith("planner"))
-    static = next(v for k, v in rows.items() if k.startswith("static"))
-    assert planner["attainment_ttft"] >= 0.95
-    assert static["attainment_ttft"] < 0.8
-    assert planner["broken_streams"] == 0 and static["broken_streams"] == 0
-    assert planner["mean_replicas"] <= r["static_budget_replicas"] * 1.15
-    assert planner["goodput_tok_s"] > 0
-    rt = r["routing_ab"]
-    assert rt["streams_bit_identical"] is True
-    assert (
-        rt["slow_peer_placements"]["network_aware"] * 4
-        <= rt["slow_peer_placements"]["overlap_only"]
-    )
-    assert (
-        rt["slow_peer_pull_blocks"]["network_aware"] * 4
-        <= rt["slow_peer_pull_blocks"]["overlap_only"]
-    )
-    assert rt["ttft_p99_ratio"] < 1.0

@@ -112,12 +112,11 @@ def _workload(core):
 
 
 def test_megastep_resolution_and_validation():
-    # 0 inherits the legacy decode_chain knob; >= 1 overrides it.
-    assert tiny_engine(decode_chain=8).megastep == 8
-    assert tiny_engine(decode_chain=8, megastep_k=1).megastep == 1
-    assert tiny_engine(decode_chain=1, megastep_k=16).megastep == 16
+    assert tiny_engine().megastep == 8
+    assert tiny_engine(megastep_k=1).megastep == 1
+    assert tiny_engine(megastep_k=16).megastep == 16
     with pytest.raises(ValueError, match="megastep_k"):
-        EngineCore(CFG, tiny_engine(megastep_k=-1), seed=0)
+        EngineCore(CFG, tiny_engine(megastep_k=0), seed=0)
 
 
 # -- bit-identical parity -----------------------------------------------------
@@ -593,8 +592,15 @@ def test_watch_overflow_forces_single_step_with_spec():
     s = probe.add_request(_req([3, 4, 5] * 3, "p", max_tokens=20,
                                ignore_eos=True))
     d, _, _ = drive(probe, [s])
-    stop_tok = d["p"][5]
-    stop_ids = list(range(300, 300 + MEGASTEP_WATCH_W)) + [stop_tok]
+    # The stop token must first occur a few tokens in: the stream ends at
+    # its FIRST occurrence, and one that ends at once drafts nothing.
+    stream = d["p"]
+    stop_at = next(
+        (i for i in range(2, len(stream)) if stream[i] not in stream[:i]), None
+    )
+    assert stop_at is not None, f"no late first occurrence in {stream}"
+    stop_ids = list(range(300, 300 + MEGASTEP_WATCH_W)) + [stream[stop_at]]
+    assert not set(stop_ids[:-1]) & set(stream)
 
     core = EngineCore(
         CFG,
@@ -606,7 +612,7 @@ def test_watch_overflow_forces_single_step_with_spec():
         ignore_eos=True,
     ))
     done, fins, _ = drive(core, [seq])
-    assert done == {"x": d["p"][:6]}
+    assert done == {"x": stream[:stop_at + 1]}
     assert fins == {"x": "stop"}
     assert core.exec_stats["megastep_dispatches"] == 0
     assert core.exec_stats["fused_mixed_dispatches"] == 0
@@ -771,7 +777,7 @@ def test_mocker_megastep_fuses_spec_lanes():
     s1, st1 = _mock_megastep_sim_spec(1)
     s8, st8 = _mock_megastep_sim_spec(8)
     assert s1 == s8
-    assert st1["megastep_dispatches"] == 0
+    assert st1["megastep_dispatches"] == st1["fused_mixed_dispatches"] == 0
     assert st8["megastep_dispatches"] > 0
     assert st8["fused_mixed_dispatches"] > 0
     assert st8["dispatches"] < st1["dispatches"]

@@ -472,8 +472,7 @@ def _drive_mocker(fair, heavy_n, light_arrivals, max_vt=60.0):
     """Deterministic virtual-clock drive: a heavy tenant floods at t=0
     with short completions (slots turn over fast — admission order, not
     preemption, is what is under test), a light tenant arrives on a
-    schedule; returns per-request first-token virtual times.
-    (bench.py run_overload_ab is the reported twin.)"""
+    schedule; returns per-request first-token virtual times."""
     args = MockEngineArgs(
         num_kv_blocks=4096, block_size=8, max_num_seqs=2,
         max_num_batched_tokens=128, enable_prefix_caching=False,

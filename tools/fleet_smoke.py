@@ -38,7 +38,7 @@ def main() -> int:
 
     # Quiet base load a 3-worker pool holds easily, then one hard burst
     # window (4x) that a frozen pool could also absorb — the point here
-    # is the ACTUATION, not an SLO gap (bench run_fleet_ab proves that).
+    # is the ACTUATION, not an SLO gap (tests/test_fleet.py has that).
     tenants = [
         TenantSpec(
             name="smoke", users=2_000, rps=8.0,

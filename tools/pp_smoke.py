@@ -10,9 +10,9 @@ pipeline stages change WHERE layers live and how iterations wavefront
 through the stage ring — ``k*pp + pp - 1`` ppermute hops amortized over
 one fused dispatch instead of ``pp`` hops per token on the
 host-rollback baseline — never which tokens a request streams. The real
-engine's bit-parity + quantization-composition invariants are pinned by
-tests/test_pp_megastep.py; the A/B latency bar by bench.py
-run_pp_megastep_ab.
+engine's bit-parity + quantization-composition invariants, and the
+mocker's fused-against-forced-single counters, are pinned by
+tests/test_pp_megastep.py.
 
 CI usage (`.github/workflows/ci.yml` pp-smoke step) and local:
 
