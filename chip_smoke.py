@@ -343,6 +343,12 @@ def serve_phase(mode: str, inject: str | None, report: dict) -> None:
         report["device"] = head["device"]
         report["startup"] = {r: h["startup"] for r, h in health.items()}
         say(f"device: {head['device']}")
+        for role, st in report["startup"].items():
+            # Warm-up times a prefill wave per bucket; the waves planner
+            # decides by that table, so a worker without it pads as before.
+            if not st.get("prefill_bucket_ms"):
+                raise PhaseFailed(f"{role}: no prefill_bucket_ms on /health "
+                                  f"startup after warm-up: {sorted(st)}")
 
         chat = f"{base}/v1/chat/completions"
         model = "no-such-model" if inject == "bad-request" else MODEL
@@ -770,6 +776,7 @@ def main() -> int:
               f" (limit {[m['bytes_limit'] for m in st['memory_after_init']]})")
         print(f"[{role}] bytes per device: params {st['param_bytes_per_device']}, "
               f"cache {st['cache_bytes_per_device']}")
+        print(f"[{role}] prefill wave ms by bucket: {st['prefill_bucket_ms']}")
     print(f"smoke observations (not benchmark results): {report['concurrent']}")
     print(f"repeat prompt cached_tokens={report['repeat_cached_tokens']}; router "
           f"index: {report['radix_index']} (built this run)")

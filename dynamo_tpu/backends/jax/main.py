@@ -533,6 +533,10 @@ async def run_jax_worker(
         t0 = time.perf_counter()
         startup["warmup_phases"] = await asyncio.to_thread(_warm_up, core)
         startup["warmup_seconds"] = round(time.perf_counter() - t0, 2)
+        # What the waves planner decides by (JSON keys are strings).
+        startup["prefill_bucket_ms"] = {
+            str(b): ms for b, ms in core.prefill_bucket_ms.items()
+        }
     if runtime.status is not None:
         runtime.status.health_sections.update(
             device=lambda: device_info,

@@ -43,6 +43,7 @@ from dynamo_tpu.tracing.stepclock import PHASES, StepClock
 from dynamo_tpu.engine.block_allocator import DeviceBlockAllocator, OutOfBlocksError
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
 from dynamo_tpu.engine.fair_queue import FairQueue
+from dynamo_tpu.engine.prefill_cover import cheapest_cover
 from dynamo_tpu.runtime.engine import EngineOverloadedError
 from dynamo_tpu.runtime import wire
 from dynamo_tpu.engine.model import (
@@ -1339,6 +1340,17 @@ class EngineCore:
         # one reading of it (counters on /metrics, ``engine/<phase>``
         # annotations in a profile — tracing/stepclock.py).
         self.clock = StepClock(self._tracer)
+        # What one prefill wave of each token bucket took on this model
+        # and device, in ms (``warm_up`` times each compiled program once
+        # and installs the table when it is over). Empty until then, and
+        # in an engine that skipped warm-up: the waves planner then takes
+        # every waiting token and pads to the next bucket. With a table
+        # it picks the cheapest set of waves (``_plan_prefill_wave``).
+        self.prefill_bucket_ms: dict[int, float] = {}
+        self.prefill_waves: dict[int, int] = {}   # bucket -> waves run
+        # (host seconds, dispatches) the host's cost per dispatch is
+        # counted from: warm-up moves it past its compiles.
+        self._host_floor_base = (0.0, 0)
         # Queue-wait stat spans live under their own service so the
         # request-waterfall sched_admit twin (TpuEngine, service
         # "engine") doesn't double-count the histogram series.
@@ -1432,6 +1444,10 @@ class EngineCore:
             "megastep_useful_lane_iters": 0,
             "ragged_real_tokens": 0,
             "ragged_bucket_tokens": 0,
+            # Prefill waves the planner ended before the waiting tokens
+            # did, to ride a smaller bucket than they would have padded
+            # to (ISSUE 30); ``prefill_waves`` counts all, by bucket.
+            "prefill_cut_waves": 0,
             # Looped stacks (ISSUE 27): passes over the layer stack, per
             # live lane and iteration (_mark_dispatch).
             "layer_passes": 0,
@@ -1773,6 +1789,7 @@ class EngineCore:
 
     def _mark_dispatch(
         self, kind: str, lanes: int, width: int, k: int, real: int, padded: int,
+        **attrs: Any,
     ) -> None:
         """Open the step clock's ``dispatch`` phase (the jitted call, until
         it returns). Its profile annotation carries the dispatch's shape:
@@ -1782,7 +1799,8 @@ class EngineCore:
         whether a step was in flight when it was enqueued, and the
         attention implementation this process's programs of that shape
         were traced with (``attn``: the decode shape's for decode
-        iterations, the ragged one's for a prefill wave).
+        iterations, the ragged one's for a prefill wave). ``attrs`` adds
+        what only one kind of dispatch has (a prefill wave's ``cover``).
 
         Also counts ``layer_passes``: a pass over the stack for each live
         lane of each fused iteration (a prefill wave: each sequence,
@@ -1795,14 +1813,65 @@ class EngineCore:
             "dispatch", kind=kind, lanes=lanes, width=width, k=k, ut_steps=ut,
             real=real, padded=padded, pipelined=self._inflight is not None,
             attn=traced_impl("ragged" if kind == "prefill" else "decode"),
+            **attrs,
         )
 
     def _bucket_for(self, n: int) -> int:
-        """Token-budget bucket: total ragged tokens in a prefill wave."""
+        """The token bucket a ragged dispatch of ``n`` tokens pads to:
+        the smallest that holds them. How many tokens a prefill wave
+        takes, and so which bucket it rides, is the planner's choice
+        (:meth:`_prefill_cover`); this only rounds what it took."""
         for b in self.engine.prefill_buckets:
             if b >= n:
                 return b
         raise ValueError(f"{n} exceeds largest prefill bucket")
+
+    def count_host_floor_from_here(self) -> None:
+        """Start the host's cost per dispatch (:meth:`host_floor_ms`) at
+        this moment: warm-up calls it when its compiles are over and its
+        waves are about to be timed, so that the mean is of dispatches as
+        serving makes them."""
+        self._host_floor_base = (
+            self._host_seconds(), self.exec_stats["dispatches"]
+        )
+
+    def _host_seconds(self) -> float:
+        """The step clock's seconds in the phases that block on host
+        work, but for ``dispatch``: the jitted call returns at once unless
+        it traces and compiles first, which a program left to its first
+        use does for seconds (logprobs, masked sampling) and which is no
+        part of what a dispatch costs from then on."""
+        return sum(
+            sec for phase, sec in self.clock.seconds().items()
+            if PHASES[phase] == "host" and phase != "dispatch"
+        )
+
+    def host_floor_ms(self) -> float:
+        """Host ms a dispatch has cost so far: the step clock's host
+        seconds (:meth:`_host_seconds`; /metrics has them as
+        ``step_phase_seconds_total{blocks="host"}``) over the dispatches
+        made, counted from the end of warm-up's compiles. The next wave
+        cannot reach the device sooner, so no wave is predicted to cost
+        less."""
+        host0, n0 = self._host_floor_base
+        n = self.exec_stats["dispatches"] - n0
+        if n <= 0:
+            return 0.0
+        return 1e3 * (self._host_seconds() - host0) / n
+
+    def _prefill_cover(self, tokens: int) -> tuple[int, ...]:
+        """The buckets of the waves that should prefill ``tokens`` waiting
+        tokens, largest first: the cheapest cover by the measured table
+        and the host's floor (``engine/prefill_cover.py``; the floor is
+        taken to a tenth of a ms, so the search is memoised while it
+        holds still). Empty without a table."""
+        if not self.prefill_bucket_ms:
+            return ()
+        return cheapest_cover(
+            tokens,
+            tuple(sorted(self.prefill_bucket_ms.items())),
+            round(self.host_floor_ms(), 1),
+        )
 
     def _decode_width(self, n: int) -> int:
         for b in self.engine.decode_buckets:
@@ -2200,6 +2269,7 @@ class EngineCore:
         n_sample: list[int] | None = None,
         feed_rows: list[int | None] | None = None,
         kind: str = "prefill",
+        **attrs: Any,
     ) -> _PendingFetch:
         """Assemble and run ONE ragged forward + fused sampling over
         arbitrary rows. Each row is ``(seq, tokens, pos_start, kv_len)``:
@@ -2229,7 +2299,9 @@ class EngineCore:
         Returns a :class:`_PendingFetch`; ``land()`` yields the legacy
         shapes — 2-D ([S, R] tokens, [S, R, ...] logprobs) with
         ``n_sample``, 1-D without. ``kind`` names the dispatch on the
-        step clock's annotation (a prefill wave, or a mixed step)."""
+        step clock's annotation (a prefill wave, or a mixed step) and
+        ``attrs`` go on it too (a wave's ``cover``: the buckets its
+        planner chose for the waiting tokens, empty without a table)."""
         self.clock.mark("assemble")
         b = self._assemble_ragged(rows, S, n_sample, feed_rows)
         R = b.R
@@ -2282,7 +2354,9 @@ class EngineCore:
                 jnp.asarray(top_k),
                 jnp.asarray(top_p),
             )
-            self._mark_dispatch(kind, len(rows), S, 1, int(cu[len(rows)]), b.T)
+            self._mark_dispatch(
+                kind, len(rows), S, 1, int(cu[len(rows)]), b.T, **attrs
+            )
             toks, lps, self.cache = self._prefill_pp(
                 self.params,
                 self.cache,
@@ -2322,7 +2396,9 @@ class EngineCore:
                 jnp.asarray(mm_embeds),
                 jnp.asarray(mm_mask),
             )
-            self._mark_dispatch(kind, len(rows), S, 1, int(cu[len(rows)]), b.T)
+            self._mark_dispatch(
+                kind, len(rows), S, 1, int(cu[len(rows)]), b.T, **attrs
+            )
             toks, lps, self.cache = self._prefill(
                 self.params,
                 self.cache,
@@ -2612,34 +2688,61 @@ class EngineCore:
 
     def _plan_prefill_wave(self, seqs: list[Sequence]) -> _PlannedStep | None:
         """Plan one ragged prefill wave: up to ``prefill_batch`` sequences
-        under a shared token budget (largest prefill bucket) — different
-        chunk lengths pack into one token buffer with no per-lane padding,
-        first-token sampling fused into the same program. The commit side
-        lands the sampled tokens and emits for every sequence whose
-        prompt completed this wave. Chunk cursors read through the
-        optimistic overlay, so consecutive waves of one long prompt
-        pipeline under async execution."""
+        under a shared token budget — different chunk lengths pack into
+        one token buffer with no per-lane padding, first-token sampling
+        fused into the same program, and the last prompt is cut where the
+        budget ends. The commit side lands the sampled tokens and emits
+        for every sequence whose prompt completed this wave. Chunk
+        cursors read through the optimistic overlay, so consecutive waves
+        of one long prompt pipeline under async execution.
+
+        The budget. Without a measured table (``prefill_bucket_ms``: an
+        engine that skipped warm-up, and warm-up itself, whose waves have
+        to fill each bucket to compile it) it is the largest bucket: the
+        wave takes every waiting token and pads to the next bucket up.
+        With one it is the largest bucket of the cheapest cover of the
+        waiting tokens (:meth:`_prefill_cover`): 640 tokens run as a full
+        512 wave now and a 128 wave next, on programs that are compiled
+        anyway (per bucket, not per cursor), where the measurements say
+        that beats one 2,048 wave of 69% padding. The next plan sees what
+        is left, and whatever arrived, and covers that again. Prefill
+        keeps its priority over decode either way. It is not an option:
+        the only parameters are what the engine measured on itself, and
+        where a cut does not pay (a small model whose waves the host
+        cannot feed faster) the same search keeps the prompt whole."""
         S = self.engine.prefill_batch
-        budget = self.engine.prefill_buckets[-1]
-        chosen: list[tuple[Sequence, int, int]] = []  # (seq, p0, chunk)
-        total = 0
+        # What this wave may take: the tokens left of the first S prompts.
+        waiting: list[tuple[Sequence, int, int]] = []  # (seq, p0, left)
         for seq in seqs:
-            if len(chosen) == S or total >= budget:
+            if len(waiting) == S:
                 break
             p0 = seq.prefilled + self._adv3(seq)[0]
-            chunk = min(seq.prompt_len - p0, budget - total)
-            if chunk <= 0:
-                continue
+            if seq.prompt_len > p0:
+                waiting.append((seq, p0, seq.prompt_len - p0))
+        if not waiting:
+            return None
+        largest = self.engine.prefill_buckets[-1]
+        tokens = sum(left for _, _, left in waiting)
+        cover = self._prefill_cover(tokens)
+        budget = cover[0] if cover else largest
+        chosen: list[tuple[Sequence, int, int]] = []  # (seq, p0, chunk)
+        total = 0
+        for seq, p0, left in waiting:
+            if total >= budget:
+                break
+            chunk = min(left, budget - total)
             chosen.append((seq, p0, chunk))
             total += chunk
-        if not chosen:
-            return None
+        bucket = self._bucket_for(total)
+        self.prefill_waves[bucket] = self.prefill_waves.get(bucket, 0) + 1
+        if total < tokens and budget < largest:
+            self.exec_stats["prefill_cut_waves"] += 1
         t_disp = time.time()
         rows: list[tuple[Sequence, list[int], int, int]] = []
         for seq, p0, chunk in chosen:
             self._mark_first_sched(seq, t_disp)
             rows.append((seq, seq.prompt[p0 : p0 + chunk], p0, p0 + chunk))
-        pend = self._dispatch_ragged(rows, S)
+        pend = self._dispatch_ragged(rows, S, cover="+".join(map(str, cover)))
         adv: dict[str, tuple[int, int, int]] = {}
         feed_index: dict[str, int] = {}
         feed_series: dict[str, tuple[int, int, int]] = {}
@@ -4956,6 +5059,10 @@ class EngineCore:
         st["queue_limit"] = self._max_waiting
         st["fair_enabled"] = 1 if self.engine.fair_scheduling else 0
         st.update(self.exec_stats)
+        # Prefill waves by the bucket they rode, and the measured ms of a
+        # wave per bucket that their planner decides by (empty: none).
+        st["prefill_waves"] = dict(self.prefill_waves)
+        st["prefill_bucket_ms"] = dict(self.prefill_bucket_ms)
         st["megastep_k"] = self.engine.megastep
         toks = self.exec_stats["committed_tokens"]
         st["dispatches_per_token"] = (

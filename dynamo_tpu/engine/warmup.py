@@ -43,7 +43,8 @@ log = logging.getLogger("dynamo_tpu.engine.warmup")
 
 
 def _run(core: EngineCore, prompts: list[list[int]], max_tokens: int,
-         temperature: float, tag: str) -> None:
+         temperature: float, tag: str) -> float:
+    """Serve ``prompts`` to the end; returns the seconds the step loop took."""
     seqs = [
         core.add_request(PreprocessedRequest(
             model="warmup",
@@ -54,8 +55,10 @@ def _run(core: EngineCore, prompts: list[list[int]], max_tokens: int,
         ))
         for i, p in enumerate(prompts)
     ]
+    t0 = time.perf_counter()
     while any(s.finish is None for s in seqs):
         core.step()
+    return time.perf_counter() - t0
 
 
 def _on_one_stack_chunk(fn, *args):
@@ -92,7 +95,22 @@ def warm_up(core: EngineCore) -> dict[str, float]:
     The allocator's KV-event callbacks are detached for the duration and
     the warm-up blocks are dropped from the prefix cache afterwards, so
     routers never hear of them. Scheduler counters do include the
-    warm-up's dispatches."""
+    warm-up's dispatches.
+
+    When every program is compiled, each prefill bucket's wave runs once
+    more and is timed from its first step to its last token, and the
+    table (``core.prefill_bucket_ms``, ms per bucket) is installed: from
+    then on the waves planner covers the waiting prompt tokens with the
+    cheapest set of compiled waves (``EngineCore._plan_prefill_wave``).
+    It is installed last and whole. While it is empty the planner takes
+    every waiting token, which is what lets a wave here fill its bucket
+    and compile it; a table that grew bucket by bucket could price four
+    512 waves under the 2,048 wave that was about to be compiled, and
+    serving would compile that program on a user's request. One sampling
+    kind is timed: the two differ by the sampler. An engine that skips
+    warm-up (in-process test engines, multi-host workers, whose
+    schedulers run in lockstep and so may read no local clock) keeps an
+    empty table and plans as before; that is why there is no option."""
     return _on_one_stack_chunk(_warm_up, core)
 
 
@@ -122,6 +140,7 @@ def _warm_up(core: EngineCore) -> dict[str, float]:
     try:
         for temperature, name in ((1.0, "sampled"), (0.0, "greedy")):
             prev = 0
+            waves = []  # (bucket, prompts, tokens each) that filled a bucket
             for bucket in eng.prefill_buckets:
                 n = min(eng.prefill_batch, lanes)
                 length = min(bucket // n, max_prompt)
@@ -131,6 +150,7 @@ def _warm_up(core: EngineCore) -> dict[str, float]:
                 _run(core, prompts(n, length), 1, temperature,
                      f"{name}-prefill{bucket}")
                 phases[f"prefill T={bucket} {name}"] = time.perf_counter() - t0
+                waves.append((bucket, n, length))
                 prev = bucket
             prev = 0
             for width in eng.decode_buckets:
@@ -145,9 +165,24 @@ def _warm_up(core: EngineCore) -> dict[str, float]:
                     time.perf_counter() - t0
                 )
                 prev = width
+        # Everything is compiled: the host's cost per dispatch counts from
+        # here, and each bucket's wave is timed on a fresh set of prompts.
+        core.count_host_floor_from_here()
+        t0 = time.perf_counter()
+        table = {}
+        for bucket, n, length in waves:
+            seconds = _run(core, prompts(n, length), 1, 1.0,
+                           f"timed-prefill{bucket}")
+            table[bucket] = round(1e3 * seconds, 3)
+        phases["prefill waves timed"] = time.perf_counter() - t0
     finally:
         core.clear_kv_cache()
         alloc.on_stored, alloc.on_removed = saved
+    core.prefill_bucket_ms = table
     for phase, seconds in phases.items():
         log.info("warm-up %-28s %6.1f s", phase, seconds)
+    log.info(
+        "prefill wave ms by bucket: %s (host floor so far %.1f ms a dispatch)",
+        table, core.host_floor_ms(),
+    )
     return {p: round(s, 2) for p, s in phases.items()}

@@ -223,6 +223,12 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
         "engine_ragged_bucket_tokens",
         "Bucket (padded) tokens summed over ragged dispatches",
     ),
+    "prefill_cut_waves": (
+        "engine_prefill_cut_waves",
+        "Prefill waves the planner ended before the waiting prompt tokens "
+        "did, other than at the largest bucket: the rest rides a later, "
+        "smaller wave instead of padding this one to the next bucket",
+    ),
     "layer_passes": (
         "engine_layer_passes",
         "Passes over the layer stack, per live lane and fused iteration "
@@ -233,14 +239,16 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
 
 class _EngineCounters:
     """Scrape-time collector for the engine's cumulative counters: the
-    step clock's seconds per phase and :data:`ENGINE_COUNTERS`."""
+    step clock's seconds per phase, :data:`ENGINE_COUNTERS`, and the
+    labelled series (prefill waves and their measured ms by bucket,
+    attention calls traced by shape and implementation)."""
 
     def __init__(self, phase_seconds: Callable[[], dict], stats: Callable[[], dict]):
         self._phase_seconds = phase_seconds
         self._stats = stats
 
     def collect(self):
-        from prometheus_client.core import CounterMetricFamily
+        from prometheus_client.core import CounterMetricFamily, GaugeMetricFamily
 
         phases = CounterMetricFamily(
             "dynamo_engine_step_phase_seconds",
@@ -257,6 +265,25 @@ class _EngineCounters:
             family = CounterMetricFamily(f"dynamo_{name}", doc, labels=["service"])
             family.add_metric(["engine"], float(stats.get(key, 0) or 0))
             yield family
+        waves = CounterMetricFamily(
+            "dynamo_engine_prefill_waves",
+            "Prefill waves dispatched, by the token bucket they rode",
+            labels=["service", "bucket"],
+        )
+        for bucket, n in sorted(stats.get("prefill_waves", {}).items()):
+            waves.add_metric(["engine", str(bucket)], float(n))
+        yield waves
+        bucket_ms = GaugeMetricFamily(
+            "dynamo_engine_prefill_bucket_ms",
+            "Measured ms of one prefill wave per token bucket (warm-up "
+            "times each compiled program once); the waves planner covers "
+            "the waiting prompt tokens with the cheapest set of these. No "
+            "series: not measured, and every wave pads to the next bucket",
+            labels=["service", "bucket"],
+        )
+        for bucket, ms in sorted(stats.get("prefill_bucket_ms", {}).items()):
+            bucket_ms.add_metric(["engine", str(bucket)], float(ms))
+        yield bucket_ms
         from dynamo_tpu.ops.ragged_attention import traced_calls
 
         traced = CounterMetricFamily(
