@@ -80,10 +80,7 @@ async def _serve_kv_fetch(runtime, namespace: str, component: str, core) -> None
         # own (cross-precision fleets).
         yield {
             wire.KV_VERSION: 2,
-            wire.KV_SHAPE: [
-                core.cfg.num_cache_layers, core.engine.block_size,
-                2 * core.cfg.num_kv_heads, core.cfg.head_dim,
-            ],
+            wire.KV_SHAPE: list(core.kv_page_shape),
             # "int8" pages ship as the canonical packed buffer (int8 kv
             # bytes + f32 scales, engine/kv_quant.py); a mixed-dtype
             # consumer fails fast at import_blocks.
@@ -272,7 +269,7 @@ def build_engine(
             raise ValueError(f"--moe-dispatch set but preset {preset!r} is dense")
         model_cfg = dataclasses.replace(model_cfg, moe_dispatch=moe_dispatch)
     overrides = dict(engine_overrides or {})
-    if preset in ("tiny", "tiny-moe", "tiny-loop") and model_path is None:
+    if preset in ("tiny", "tiny-moe", "tiny-loop", "tiny-axk1") and model_path is None:
         engine_cfg = tiny_engine(**overrides)
     else:
         # Checkpoint serving uses the full-size engine defaults (the
@@ -527,6 +524,17 @@ async def run_jax_worker(
     )
     startup["engine_loop"] = "pipelined" if core.pipelined else "synchronous"
     log.info("engine loop: %s", startup["engine_loop"])
+    startup["attention"] = core.cfg.attention
+    startup["kv_bytes_per_token"] = core.kv_bytes_per_token
+    if core.cfg.shared_sparse:
+        startup["experts_held"] = list(core.cfg.experts_held_range)
+    log.info(
+        "attention %s, %d B of cache a token%s", core.cfg.attention,
+        core.kv_bytes_per_token,
+        (", routed experts held [%d, %d) of %d"
+         % (*core.cfg.experts_held_range, core.cfg.num_experts))
+        if core.cfg.shared_sparse else "",
+    )
     if warm_up:
         from dynamo_tpu.engine.warmup import warm_up as _warm_up
 

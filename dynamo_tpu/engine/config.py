@@ -20,6 +20,16 @@ _DTYPES = {
 }
 
 
+class UnsupportedModelOption(NotImplementedError):
+    """An engine option that this model's cache page or layers do not
+    carry, refused at start-up. ``option`` names it (``kv_dtype``, ``tp``,
+    ``pp``, ``ring_prefill``, ``spec_decode``)."""
+
+    def __init__(self, option: str, model: str, why: str):
+        super().__init__(f"{option} is not carried for model {model!r}: {why}")
+        self.option = option
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     """Llama-family decoder-only transformer hyperparameters."""
@@ -46,7 +56,8 @@ class ModelConfig:
     num_experts_per_tok: int = 2
     # Per-expert capacity headroom for sparse dispatch: capacity =
     # ceil(N * top_k / E * factor); tokens past it drop for that expert
-    # (Switch/GShard semantics).
+    # (Switch/GShard semantics). The mixtral path's (router_scoring
+    # "softmax") ONLY: the sigmoid-routed layer drops nothing.
     moe_capacity_factor: float = 2.0
     # EP dispatch mode under a mesh: "replicated" computes every token on
     # every expert shard and psums (the right trade at serving batch —
@@ -68,8 +79,57 @@ class ModelConfig:
     # passes this; 1.0 = never (every token takes every pass). Adaptive
     # exit makes compute per token vary and is not implemented.
     early_exit_threshold: float = 1.0
+    # -- latent attention and a shared-expert sparse MLP (A.X-K1) -----------
+    # "gqa": full K and V per kv head. "mla": multi-head latent attention —
+    # a low-rank q (q_lora_rank, with its norm), ONE compressed K/V vector
+    # a token (kv_lora_rank, with its norm) beside a rope part shared by
+    # all heads (qk_rope_head_dim); the cached unit is [ckv | kr],
+    # kv_lora_rank + qk_rope_head_dim values a token a layer. num_kv_heads
+    # and head_dim have no say there (head_dim is kept at the q/k head's
+    # whole width, qk_nope_head_dim + qk_rope_head_dim, for whoever asks).
+    attention: str = "gqa"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN, static: {"type": "yarn", "factor", "original_max_position_
+    # embeddings", "beta_fast", "beta_slow", "mscale", "mscale_all_dim"}
+    # (a dict is frozen to sorted items so that the config stays hashable);
+    # None = plain rope. Only the latent attention reads it.
+    rope_scaling: tuple | dict | None = None
+    # Leading layers that keep the dense SwiGLU (width intermediate_size)
+    # in a sparse model; the others' experts have width
+    # moe_intermediate_size.
+    first_dense_layers: int = 0
+    moe_intermediate_size: int = 0
+    # How the router scores: "softmax" over the chosen logits (the mixtral
+    # path, capacity-bounded) or "sigmoid" per expert with the choice
+    # limited to the topk_group best of n_group groups (a group's score:
+    # the sum of its two highest), weights normalised over the chosen
+    # (norm_topk_prob) and scaled by routed_scaling_factor.
+    router_scoring: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # Shared experts of width moe_intermediate_size each, added to every
+    # token's routed result.
+    num_shared_experts: int = 0
+    # (rank, of): this chip's share of every sparse layer's routed
+    # experts, [rank * E/of, (rank + 1) * E/of) of num_experts. The router
+    # stays num_experts wide; only the held experts' terms are added, and
+    # none is dropped (moe_capacity_factor has no say). None: all held.
+    experts_held: tuple[int, int] | None = None
 
     def __post_init__(self):
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(
+                self, "rope_scaling", tuple(sorted(self.rope_scaling.items()))
+            )
+        if self.experts_held is not None:
+            object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        self._check_latent_sparse()
         if self.ut_steps < 1:
             raise ValueError(f"ut_steps={self.ut_steps} must be >= 1")
         if self.ut_steps > 1 and self.early_exit_threshold < 1.0:
@@ -80,9 +140,132 @@ class ModelConfig:
                 "only at threshold 1"
             )
 
+    def _check_latent_sparse(self) -> None:
+        """A field of the latent / sigmoid-routed model that does not apply
+        raises; none is silently ignored."""
+        latent = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                  "qk_rope_head_dim", "v_head_dim")
+        if self.attention not in ("gqa", "mla"):
+            raise ValueError(f"unknown attention {self.attention!r} (gqa or mla)")
+        if self.attention == "mla":
+            missing = [f for f in latent if getattr(self, f) <= 0]
+            if missing:
+                raise ValueError(f"attention='mla' needs {missing} > 0")
+            if self.attn_qkv_bias or self.sandwich_norm or self.ut_steps > 1:
+                raise NotImplementedError(
+                    "attention='mla' with attn_qkv_bias, sandwich_norm or "
+                    "ut_steps > 1 is not implemented"
+                )
+            scaling = dict(self.rope_scaling or ())
+            if scaling and scaling.get("type") != "yarn":
+                raise NotImplementedError(
+                    f"rope_scaling type {scaling.get('type')!r}: only 'yarn'"
+                )
+        else:
+            stray = [f for f in latent if getattr(self, f)]
+            if stray or self.rope_scaling is not None:
+                raise ValueError(
+                    f"{stray or ['rope_scaling']} set with attention='gqa': "
+                    "only the latent attention reads them"
+                )
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router_scoring {self.router_scoring!r}")
+        sparse_only = {
+            "first_dense_layers": 0, "moe_intermediate_size": 0, "n_group": 1,
+            "topk_group": 1, "routed_scaling_factor": 1.0,
+            "num_shared_experts": 0, "experts_held": None,
+        }
+        if not self.shared_sparse:
+            stray = [f for f, d in sparse_only.items() if getattr(self, f) != d]
+            if stray:
+                raise ValueError(
+                    f"{stray} set with router_scoring='softmax' or no experts:"
+                    " only the sigmoid-routed sparse MLP reads them"
+                )
+            return
+        E, k = self.num_experts, self.num_experts_per_tok
+        if self.moe_intermediate_size <= 0:
+            raise ValueError("router_scoring='sigmoid' needs moe_intermediate_size > 0")
+        if not 0 <= self.first_dense_layers < self.num_layers:
+            raise ValueError(
+                f"first_dense_layers={self.first_dense_layers} of "
+                f"{self.num_layers} layers leaves no sparse layer"
+            )
+        if E % self.n_group or not 1 <= self.topk_group <= self.n_group:
+            raise ValueError(
+                f"n_group={self.n_group} must divide num_experts={E}, and "
+                f"topk_group={self.topk_group} lie in 1..n_group"
+            )
+        if self.n_group > 1 and E // self.n_group < 2:
+            raise ValueError("a group's score is the sum of its two best experts")
+        if k > self.topk_group * (E // self.n_group):
+            raise ValueError(
+                f"num_experts_per_tok={k} exceeds the "
+                f"{self.topk_group * (E // self.n_group)} experts of the kept groups"
+            )
+        if not self.norm_topk_prob:
+            raise NotImplementedError("norm_topk_prob=False is not implemented")
+        if self.moe_dispatch != "replicated":
+            raise NotImplementedError(
+                "moe_dispatch is the mixtral path's; the sigmoid-routed layer "
+                "runs one chip's share without an exchange"
+            )
+        if self.experts_held is not None:
+            rank, of = self.experts_held
+            if of < 1 or E % of or not 0 <= rank < of:
+                raise ValueError(
+                    f"experts_held={self.experts_held}: 'of' must divide "
+                    f"num_experts={E} and rank lie in 0..of-1"
+                )
+
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def shared_sparse(self) -> bool:
+        """The sigmoid-routed sparse MLP with shared experts and a chip's
+        share of the routed ones (dropless), not the mixtral path."""
+        return self.is_moe and self.router_scoring == "sigmoid"
+
+    @property
+    def latent(self) -> bool:
+        return self.attention == "mla"
+
+    @property
+    def experts_held_range(self) -> tuple[int, int]:
+        """[lo, hi) of the routed experts this chip holds."""
+        if self.experts_held is None:
+            return 0, self.num_experts
+        rank, of = self.experts_held
+        n = self.num_experts // of
+        return rank * n, (rank + 1) * n
+
+    @property
+    def num_experts_held(self) -> int:
+        lo, hi = self.experts_held_range
+        return hi - lo
+
+    def kv_page_tail(self, block_size: int) -> tuple[int, ...]:
+        """Trailing shape of one page of ``block_size`` tokens in one
+        layer's plane: ``(block_size, 2 * num_kv_heads, head_dim)`` (K
+        even, V odd) or, latent, the ``(rows, lanes)`` that hold
+        ``block_size x (kv_lora_rank + qk_rope_head_dim)`` values
+        (ops/latent_attention.py, "The page"). THE one place the page
+        shape comes from (model.init_cache, the engine's page movers,
+        descriptors and /health)."""
+        if self.latent:
+            from dynamo_tpu.ops.latent_attention import latent_page_shape
+
+            return latent_page_shape(block_size, self.kv_lora_rank, self.qk_rope_head_dim)
+        return (block_size, 2 * self.num_kv_heads, self.head_dim)
+
+    @property
+    def kv_unit_values(self) -> int:
+        """Values one token caches in one layer's plane."""
+        if self.latent:
+            return self.kv_lora_rank + self.qk_rope_head_dim
+        return 2 * self.num_kv_heads * self.head_dim
 
     @property
     def num_cache_layers(self) -> int:
@@ -103,16 +286,44 @@ class ModelConfig:
     def kv_size(self) -> int:
         return self.num_kv_heads * self.head_dim
 
+    def _attn_params(self) -> int:
+        """One layer's attention projections (and, latent, the two norms
+        inside them); the qkv bias is left out as before."""
+        h = self.hidden_size
+        if self.latent:
+            H, dn, dr, dv = (self.num_heads, self.qk_nope_head_dim,
+                             self.qk_rope_head_dim, self.v_head_dim)
+            rq, rkv = self.q_lora_rank, self.kv_lora_rank
+            return (h * rq + rq + rq * H * (dn + dr)       # wq_a, q_norm, wq_b
+                    + h * (rkv + dr) + rkv                 # wkv_a, kv_norm
+                    + rkv * H * (dn + dv) + H * dv * h)    # wkv_b, wo
+        return h * (self.q_size + 2 * self.kv_size) + self.q_size * h
+
+    def _mlp_params(self) -> int:
+        """All layers' MLP weights as HELD here: a dense SwiGLU, the
+        mixtral path's router and experts, or the sigmoid-routed layer's
+        router (full width), held experts and shared experts behind
+        ``first_dense_layers`` dense ones."""
+        h, i, L = self.hidden_size, self.intermediate_size, self.num_layers
+        if self.shared_sparse:
+            im = self.moe_intermediate_size
+            sparse = (h * self.num_experts
+                      + (self.num_experts_held + self.num_shared_experts) * 3 * h * im)
+            return (self.first_dense_layers * 3 * h * i
+                    + (L - self.first_dense_layers) * sparse)
+        if self.is_moe:
+            return L * (h * self.num_experts + self.num_experts * 3 * h * i)
+        return L * 3 * h * i
+
     def param_bytes(self) -> int:
-        """Approximate parameter footprint at the configured dtype."""
-        h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
-        per_layer = (
-            h * (self.q_size + 2 * self.kv_size)  # wq, wk, wv
-            + self.q_size * h                     # wo
-            + 3 * h * i                           # gate, up, down
-            + (4 if self.sandwich_norm else 2) * h  # norms
+        """Parameter footprint at the configured dtype, of what this
+        chip holds (``experts_held``)."""
+        h, v = self.hidden_size, self.vocab_size
+        norms = (4 if self.sandwich_norm else 2) * h
+        total = (
+            v * h + self.num_layers * (self._attn_params() + norms)
+            + self._mlp_params() + h + (0 if self.tie_embeddings else h * v)
         )
-        total = v * h + self.num_layers * per_layer + h + (0 if self.tie_embeddings else h * v)
         if self.ut_steps > 1:
             total += h + 1                        # exit gate: w [h], b []
         bytes_per = jnp.dtype(self.jax_dtype).itemsize
@@ -121,7 +332,14 @@ class ModelConfig:
     def quantized_param_bytes(self) -> int:
         """Footprint with int8 weight-only quantization
         (model.quantize_params: projections + lm_head at 1 byte,
-        embeddings/norms at the model dtype)."""
+        embeddings/norms at the model dtype). Sparse and latent models are
+        served unquantised (model.init_params_quantized raises for them),
+        so there is nothing to count."""
+        if self.is_moe or self.latent:
+            raise NotImplementedError(
+                f"int8 weights for {self.name!r}: experts and latent "
+                "projections are served unquantised"
+            )
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         proj_per_layer = (
             h * (self.q_size + 2 * self.kv_size) + self.q_size * h + 3 * h * i
@@ -449,6 +667,87 @@ def tiny_moe(vocab_size: int = 384) -> ModelConfig:
     )
 
 
+def axk1_ep16() -> ModelConfig:
+    """A.X-K1 (SKT, model_type "axk1") as ONE chip of sixteen holds it:
+    latent attention whole, 12 of each sparse layer's 192 routed experts
+    (rank 0) beside the shared one, an eighth of the vocabulary, one
+    dense and six sparse layers of the 61 (the rest lie on further
+    chips as pipeline stages). 9.68 GB in bf16."""
+    return ModelConfig(
+        name="a.x-k1-ep16",
+        vocab_size=20480,
+        hidden_size=7168,
+        intermediate_size=18432,
+        num_layers=7,
+        num_heads=64,
+        num_kv_heads=64,
+        head_dim=192,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-6,
+        attention="mla",
+        q_lora_rank=1536,
+        kv_lora_rank=512,
+        qk_nope_head_dim=128,
+        qk_rope_head_dim=64,
+        v_head_dim=128,
+        rope_scaling={
+            "type": "yarn", "factor": 32, "beta_fast": 32, "beta_slow": 1,
+            "mscale": 1, "mscale_all_dim": 1,
+            "original_max_position_embeddings": 4096,
+        },
+        first_dense_layers=1,
+        moe_intermediate_size=2048,
+        num_experts=192,
+        num_experts_per_tok=8,
+        router_scoring="sigmoid",
+        n_group=8,
+        topk_group=4,
+        routed_scaling_factor=2.5,
+        num_shared_experts=1,
+        experts_held=(0, 16),
+    )
+
+
+def tiny_axk1(vocab_size: int = 384, experts_held=(0, 4)) -> ModelConfig:
+    """A.X-K1's shape at test size: latent attention with YaRN, one dense
+    layer then two sigmoid-routed ones (16 experts in 4 groups of which
+    2, 4 a token, one shared), this chip holding a quarter of them."""
+    return ModelConfig(
+        name="tiny-axk1",
+        vocab_size=vocab_size,
+        hidden_size=64,
+        intermediate_size=160,
+        num_layers=3,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=24,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-6,
+        dtype="float32",
+        attention="mla",
+        q_lora_rank=48,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        rope_scaling={
+            "type": "yarn", "factor": 32, "beta_fast": 32, "beta_slow": 1,
+            "mscale": 1, "mscale_all_dim": 1,
+            "original_max_position_embeddings": 64,
+        },
+        first_dense_layers=1,
+        moe_intermediate_size=32,
+        num_experts=16,
+        num_experts_per_tok=4,
+        router_scoring="sigmoid",
+        n_group=4,
+        topk_group=2,
+        routed_scaling_factor=2.5,
+        num_shared_experts=1,
+        experts_held=experts_held,
+    )
+
+
 def tiny_loop(vocab_size: int = 384) -> ModelConfig:
     """The looped stack (Ouro's shape) at test size: 3 layers x 3 passes."""
     return ModelConfig(
@@ -505,7 +804,9 @@ PRESETS = {
     "qwen2-7b": qwen2_7b,
     "mixtral-8x7b": mixtral_8x7b,
     "ouro-2.6b": ouro_2_6b,
+    "a.x-k1-ep16": axk1_ep16,
     "tiny": tiny_model,
     "tiny-moe": tiny_moe,
     "tiny-loop": tiny_loop,
+    "tiny-axk1": tiny_axk1,
 }

@@ -55,6 +55,7 @@ from dynamo_tpu.engine.model import (
     init_params,
     verify_tokens,
 )
+from dynamo_tpu.engine.config import UnsupportedModelOption
 from dynamo_tpu.ops.ragged_attention import traced_impl
 from dynamo_tpu.engine.sampler import (
     LOGPROBS_K,
@@ -158,6 +159,34 @@ class Sequence:
         return self.processed
 
 
+def _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh) -> None:
+    """A model with latent attention or the sigmoid-routed sparse MLP
+    runs on ONE chip's programs with a plain latent page. Every option
+    that page, or that layer, does not carry is refused here, at start-up
+    and by name (:class:`UnsupportedModelOption`), not at the first
+    request that meets it. Carried: the prefix cache, preemption and
+    recompute, the host and disk tiers, the disagg payload, peer pulls,
+    embeddings, both schedulers, the megastep."""
+    if not (model_cfg.latent or model_cfg.shared_sparse):
+        return
+    refused = {
+        "kv_dtype": engine_cfg.kv_quantized and model_cfg.latent
+        and "int8 pages keep a scale per slot and KV head; a latent page has no heads",
+        "tp": mesh is not None
+        and "no sharding rule for the latent projections or the held experts "
+            "(a share is stated with experts_held, not with a mesh)",
+        "pp": pp_mesh is not None
+        and "the pipeline's stage body is the dense layer's",
+        "ring_prefill": (sp_mesh is not None or engine_cfg.ring_prefill_threshold > 0)
+        and "ring attention reads expanded K and V per head",
+        "spec_decode": engine_cfg.spec_decode != "off"
+        and "verify rows were not compared with the reference for this model",
+    }
+    for option, why in refused.items():
+        if why:
+            raise UnsupportedModelOption(option, model_cfg.name, why)
+
+
 def _check_fuse_tp(params, tp: int) -> None:
     """The fused wqkv/wgu column layout is tp-dependent; serving params
     fused for a different tp would produce silently wrong logits
@@ -189,14 +218,22 @@ class _PendingFetch:
     host while the next step computes. ``sr`` carries the (S, R) reshape
     for sample-width dispatches (the legacy 2-D return shape)."""
 
-    def __init__(self, core: "EngineCore", toks, lps, sr=None, aux=None):
+    def __init__(self, core: "EngineCore", toks, lps, sr=None, aux=None,
+                 expert_stats=None):
         self.core = core
         self.toks = toks
         self.lps = lps
         self.sr = sr
         self.aux = aux
+        # (phase, int32 [4] on device); None where the program returned no
+        # counts (a model without the sparse layer, the pipeline's programs)
+        if expert_stats is not None and expert_stats[1] is None:
+            expert_stats = None
+        self.expert_stats = expert_stats
         self.no = core._note_dispatch()
         start_host_copy(toks)
+        if expert_stats is not None:
+            start_host_copy(expert_stats[1])
         if aux is not None:
             start_host_copy(aux)
         if lps is not None:
@@ -219,6 +256,9 @@ class _PendingFetch:
         # commit wrapper opened it; a merged plan's later parts reopen it).
         core.clock.mark("land")
         toks = fetch_replicated(self.toks)  # dynalint: sync-ok — double-buffered landing point
+        if self.expert_stats is not None:
+            phase, counts = self.expert_stats
+            core.expert_stats[phase] += fetch_replicated(counts)  # dynalint: sync-ok — lands with the tokens
         lps = self.lps
         if lps is not None:
             lps = tuple(fetch_replicated_many(lps))  # dynalint: sync-ok — batched logprob landing
@@ -352,6 +392,18 @@ class _RaggedBatch:
 MEGASTEP_WATCH_W = 8
 
 
+def _expert_stats_list(cfg) -> list | None:
+    """Where a program's sparse layers leave their counts at trace time
+    (model._shared_sparse_mlp); None for a model that keeps none."""
+    return [] if cfg.shared_sparse else None
+
+
+def _expert_stats_sum(stats: list | None):
+    """int32 [4] over a step's sparse layers: held experts touched, layer
+    steps, (token, expert) pairs on held experts, pairs routed."""
+    return sum(stats[1:], stats[0]) if stats else None
+
+
 def _megastep_body(
     params, cache, tokens, block_tables, positions, active,
     seeds, counters, temperature, top_k, top_p,
@@ -383,8 +435,10 @@ def _megastep_body(
     def body(carry, i):
         toks, cache, alive, pos = carry
         act = active & alive
+        stats = _expert_stats_list(cfg)
         logits, cache = decode_tokens(
             params, cache, toks, block_tables, pos, act, cfg, engine, mesh,
+            expert_stats=stats,
         )
         with jax.named_scope("sample"):
             nxt = sample_seeded(
@@ -398,14 +452,16 @@ def _megastep_body(
             lp = token_logprobs(logits, out_tok) if want_logprobs else None
             alive = alive & ~stop_flags(nxt, watch, budgets, min_left, i)
             pos = pos + act.astype(jnp.int32)
-        return (out_tok, cache, alive, pos), (out_tok, lp)
+        return (out_tok, cache, alive, pos), (out_tok, lp, _expert_stats_sum(stats))
 
-    (_, cache, _, _), (sampled, lps) = jax.lax.scan(
+    (_, cache, _, _), (sampled, lps, stats) = jax.lax.scan(
         body,
         (tokens, cache, jnp.ones_like(active), positions),
         jnp.arange(n_steps),
     )
-    return _replicate_out(sampled, mesh), _replicate_out(lps, mesh), cache
+    if stats is not None:
+        stats = jnp.sum(stats, axis=0)
+    return _replicate_out(sampled, mesh), _replicate_out(lps, mesh), cache, stats
 
 
 def _megastep_fused_body(
@@ -704,12 +760,14 @@ def _prefill_and_sample(
     only rows whose prompt completed this wave. ``want_mm`` (a separate
     compiled variant) splices multimodal embedding rows over placeholder
     positions (llm/multimodal.py)."""
+    stats = _expert_stats_list(cfg)
     logits, cache = forward_tokens(
         params, cache, tokens, positions, write_pages, write_offs,
         kv_lens, block_tables, cu_q_lens, num_seqs, last_rows,
         cfg, engine, mesh,
         mm_embeds=mm_embeds if want_mm else None,
         mm_mask=mm_mask if want_mm else None,
+        expert_stats=stats,
     )
     with jax.named_scope("sample"):
         toks = sample_seeded(
@@ -717,7 +775,8 @@ def _prefill_and_sample(
             need_mask=need_mask, all_greedy=all_greedy,
         )
         lps = token_logprobs(logits, toks) if want_logprobs else None
-    return _replicate_out(toks, mesh), _replicate_out(lps, mesh), cache
+    return (_replicate_out(toks, mesh), _replicate_out(lps, mesh), cache,
+            _expert_stats_sum(stats))
 
 
 def _pp_prefill_and_sample(
@@ -902,6 +961,7 @@ class EngineCore:
         pipeline parallelism instead: layer-staged GPipe prefill waves and
         wavefront decode chains (parallel/pipeline.py)."""
         bs = engine_cfg.block_size
+        _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh)
         for b in engine_cfg.prefill_buckets:
             if b % bs:
                 raise ValueError(f"prefill bucket {b} not a multiple of block_size {bs}")
@@ -1048,6 +1108,12 @@ class EngineCore:
         )
         self._ring_H = engine_cfg.spec_window + engine_cfg.spec_ngram_max
         self.spec_stats = SpecStats()
+        # What the sparse layers counted, by the program that ran them:
+        # int64 [4] each (model._shared_sparse_mlp), landed with the
+        # tokens of every dispatch.
+        self.expert_stats = {
+            phase: np.zeros(4, np.int64) for phase in ("decode", "prefill")
+        }
         self.cfg = model_cfg
         self.engine = engine_cfg
         self.eos_token_ids = set(eos_token_ids)
@@ -1812,7 +1878,11 @@ class EngineCore:
         self._t_dispatch = self.clock.mark(
             "dispatch", kind=kind, lanes=lanes, width=width, k=k, ut_steps=ut,
             real=real, padded=padded, pipelined=self._inflight is not None,
-            attn=traced_impl("ragged" if kind == "prefill" else "decode"),
+            attn=traced_impl(
+                ("latent-" if self.cfg.latent else "")
+                + ("ragged" if kind == "prefill" else "decode")
+            ),
+            attention=self.cfg.attention,
             **attrs,
         )
 
@@ -2022,6 +2092,32 @@ class EngineCore:
         if self.engine.kv_quantized:
             return "int8"
         return np.dtype(self.cfg.jax_dtype).name
+
+    @property
+    def kv_page_shape(self) -> tuple[int, ...]:
+        """One block as it leaves the device (host and disk tiers, the
+        disagg payload, peer pulls): ``[planes, *page]`` with the page of
+        ``ModelConfig.kv_page_tail``: ``(block_size, 2 kv, d)``, or the
+        latent page's ``(rows, lanes)``."""
+        return (
+            self.cfg.num_cache_layers,
+            *self.cfg.kv_page_tail(self.engine.block_size),
+        )
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        """Bytes of cache one token holds over all planes, scales of an
+        int8 cache included."""
+        from dynamo_tpu.engine.kv_quant import kv_page_bytes
+
+        if self.cfg.latent:
+            return (self.cfg.num_cache_layers * self.cfg.kv_unit_values
+                    * np.dtype(self.cfg.jax_dtype).itemsize)
+        return kv_page_bytes(
+            self.cfg.num_cache_layers, 1, self.cfg.num_kv_heads,
+            self.cfg.head_dim, self.engine.kv_dtype,
+            np.dtype(self.cfg.jax_dtype).itemsize,
+        )
 
     def _page_geometry(self) -> tuple[int, int, int, int]:
         return (
@@ -2357,6 +2453,7 @@ class EngineCore:
             self._mark_dispatch(
                 kind, len(rows), S, 1, int(cu[len(rows)]), b.T, **attrs
             )
+            stats = None
             toks, lps, self.cache = self._prefill_pp(
                 self.params,
                 self.cache,
@@ -2399,7 +2496,7 @@ class EngineCore:
             self._mark_dispatch(
                 kind, len(rows), S, 1, int(cu[len(rows)]), b.T, **attrs
             )
-            toks, lps, self.cache = self._prefill(
+            toks, lps, self.cache, stats = self._prefill(
                 self.params,
                 self.cache,
                 *args,
@@ -2411,7 +2508,8 @@ class EngineCore:
         self.clock.mark("plan")
         self.exec_stats["single_step_dispatches"] += 1
         return _PendingFetch(
-            self, toks, lps, sr=(S, R) if n_sample is not None else None
+            self, toks, lps, sr=(S, R) if n_sample is not None else None,
+            expert_stats=("prefill", stats),
         )
 
     def _dispatch_fused(
@@ -3102,7 +3200,7 @@ class EngineCore:
         # — stage hops, sampling, stop flags — is one dispatch, armed
         # with the same per-lane stop inputs as the single-chip body.
         program = self._decode_pp if self.pp_mesh is not None else self._decode
-        out, lps, self.cache = program(
+        out, lps, self.cache, *stats = program(
             self.params,
             self.cache,
             *args,
@@ -3119,7 +3217,9 @@ class EngineCore:
         self.exec_stats[
             "megastep_dispatches" if n_steps > 1 else "single_step_dispatches"
         ] += 1
-        return _PendingFetch(self, out, lps)  # [n_steps, B] on land()
+        return _PendingFetch(  # [n_steps, B] on land()
+            self, out, lps, expert_stats=("decode", stats[0] if stats else None)
+        )
 
     # -- the iteration -----------------------------------------------------
 
@@ -4612,12 +4712,7 @@ class EngineCore:
             if seq is None:
                 raise KeyError(f"no held blocks for request {request_id}")
             self._touch_hold(request_id)
-            shape = [
-                self.cfg.num_cache_layers,
-                self.engine.block_size,
-                2 * self.cfg.num_kv_heads,
-                self.cfg.head_dim,
-            ]
+            shape = list(self.kv_page_shape)
             dtype = self.kv_wire_dtype
             # Producer layout version: staged pages are always the FULL
             # combined [L, bs, 2kv, d] page regardless of the producer's
@@ -4630,7 +4725,7 @@ class EngineCore:
             # chained block hashes are computed over block_size-token
             # groups, so the hash domains are disjoint (import validates).
             layout = {
-                "kind": "combined_kv_page",
+                "kind": "latent_kv_page" if self.cfg.latent else "combined_kv_page",
                 "block_size": self.engine.block_size,
                 "tp": int(self.mesh.shape["tp"]) if self.mesh is not None else 1,
                 # int8 pages travel as the canonical packed buffer: int8
@@ -4821,12 +4916,7 @@ class EngineCore:
         existing host-side cast."""
         import ml_dtypes  # noqa: F401  (registers bfloat16 with numpy)
 
-        expected = (
-            self.cfg.num_cache_layers,
-            self.engine.block_size,
-            2 * self.cfg.num_kv_heads,
-            self.cfg.head_dim,
-        )
+        expected = self.kv_page_shape
         local_dtype = np.dtype(self.cfg.jax_dtype)
         staged: list[tuple[int, int | None, Any]] = []
         for blk in blocks:
@@ -4835,11 +4925,12 @@ class EngineCore:
                 kind = (blk.get(wire.IMP_LAYOUT) or {}).get(
                     "kind", "combined_kv_page"
                 )
-                if kind != "combined_kv_page":
+                if kind not in ("combined_kv_page", "latent_kv_page"):
                     raise ValueError(
                         f"unknown producer KV layout {kind!r}; cannot relayout"
                     )
-                if shape[1] != expected[1]:
+                mine = "latent_kv_page" if self.cfg.latent else "combined_kv_page"
+                if kind == mine == "combined_kv_page" and shape[1] != expected[1]:
                     # Resegmenting is pointless, not just hard: the chained
                     # block hashes are per-block_size, so relayouted pages
                     # could never prefix-match a local request.
@@ -5006,9 +5097,7 @@ class EngineCore:
             pages = -(-self.engine.prefill_buckets[-1] // bs) + 1
             shape = (
                 self.cfg.ut_steps * pages,  # a plane per pass (model.init_cache)
-                bs,
-                2 * self.cfg.num_kv_heads,
-                self.cfg.head_dim,
+                *self.cfg.kv_page_tail(bs),
             )
             self._embed_scratch = tuple(
                 jnp.zeros(shape, self.cfg.jax_dtype)
@@ -5075,14 +5164,18 @@ class EngineCore:
         st["pp_stages"] = self._pp
         # What the loop costs the cache: planes of K/V per token (layers
         # x passes) and their bytes at the cache's dtype.
-        from dynamo_tpu.engine.kv_quant import kv_page_bytes
-
         st["kv_cache_layers"] = self.cfg.num_cache_layers
-        st["kv_bytes_per_token"] = kv_page_bytes(
-            self.cfg.num_cache_layers, 1, self.cfg.num_kv_heads,
-            self.cfg.head_dim, self.engine.kv_dtype,
-            np.dtype(self.cfg.jax_dtype).itemsize,
-        )
+        st["kv_bytes_per_token"] = self.kv_bytes_per_token
+        st["attention"] = self.cfg.attention
+        # A sparse model's share and what its router sent it, by the
+        # program that counted (decode megasteps, prefill waves): held
+        # experts touched and layer steps, pairs on held experts and
+        # pairs routed (model._shared_sparse_mlp).
+        st["experts_held"] = self.cfg.num_experts_held if self.cfg.shared_sparse else 0
+        st["expert_stats"] = {
+            phase: [int(n) for n in counts]
+            for phase, counts in self.expert_stats.items()
+        }
         k = max(1, self.engine.megastep)
         km = k * self._pp_micro
         st["pp_pipe_occupancy"] = km / (km + self._pp - 1)
@@ -5112,12 +5205,7 @@ class EngineCore:
             # must be visible on /metrics, not just asserted in tests.
             "kv_dtype": self.engine.kv_dtype,
             "kv_dtype_int8": 1 if self.engine.kv_quantized else 0,
-            "bytes_per_block": kv_page_bytes(
-                self.cfg.num_cache_layers, self.engine.block_size,
-                self.cfg.num_kv_heads, self.cfg.head_dim,
-                self.engine.kv_dtype,
-                np.dtype(self.cfg.jax_dtype).itemsize,
-            ),
+            "bytes_per_block": self.kv_bytes_per_token * self.engine.block_size,
             "capacity_blocks": a.capacity,
             "resident_blocks": a.used_blocks,
             "prefix_queries": a.prefix_queries,

@@ -25,9 +25,62 @@ from dynamo_tpu.engine.config import ModelConfig
 log = logging.getLogger("dynamo_tpu.loader")
 
 
-def config_from_hf(path: str | Path) -> ModelConfig:
+# Published model types whose layer is the latent-attention, sigmoid-routed
+# one (ModelConfig.attention "mla", router_scoring "sigmoid").
+LATENT_SPARSE_TYPES = ("axk1",)
+
+
+def _latent_sparse_config(hf: dict, experts_held) -> ModelConfig:
+    if hf.get("topk_method", "none") != "none":
+        raise NotImplementedError(
+            f"topk_method={hf['topk_method']!r}: a bias on the router's "
+            "choice is not implemented (only 'none')"
+        )
+    return ModelConfig(
+        name=hf["model_type"],
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
+        head_dim=hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"],
+        rope_theta=hf.get("rope_theta", 10000.0),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        tie_embeddings=hf.get("tie_word_embeddings", False),
+        attention="mla",
+        q_lora_rank=hf["q_lora_rank"],
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        rope_scaling=hf.get("rope_scaling"),
+        first_dense_layers=hf.get("first_k_dense_replace", 0),
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_experts=hf["n_routed_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        router_scoring=hf.get("scoring_func", "sigmoid"),
+        n_group=hf.get("n_group", 1),
+        topk_group=hf.get("topk_group", 1),
+        norm_topk_prob=hf.get("norm_topk_prob", True),
+        routed_scaling_factor=hf.get("routed_scaling_factor", 1.0),
+        num_shared_experts=hf.get("n_shared_experts", 0),
+        experts_held=experts_held,
+    )
+
+
+def config_from_hf(path: str | Path, experts_held=None) -> ModelConfig:
+    """``experts_held`` ``(rank, of)``: the share of a sparse model's routed
+    experts to load (a model without a stated share refuses it)."""
     with open(Path(path) / "config.json") as f:
         hf = json.load(f)
+    if hf.get("model_type") in LATENT_SPARSE_TYPES:
+        return _latent_sparse_config(hf, experts_held)
+    if experts_held is not None:
+        raise ValueError(
+            f"experts_held={experts_held} for model_type "
+            f"{hf.get('model_type')!r}: only {LATENT_SPARSE_TYPES} state a share"
+        )
     head_dim = hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"]
     return ModelConfig(
         name=hf.get("model_type", "llama"),
@@ -97,11 +150,96 @@ def _quantize_np(w: np.ndarray) -> dict[str, Any]:
     return {"w": q, "scale": scale.astype(np.float32)}
 
 
+def _half_split(w: np.ndarray, dr: int) -> np.ndarray:
+    """The last ``dr`` output columns of ``w`` ``[..., in, out]`` from the
+    checkpoint's interleaved rope pairs ``(2i, 2i + 1)`` to the half-split
+    ones ``(i, i + dr/2)`` that ``model.rope_apply`` turns (the published
+    code makes the same permutation of q and k at run time)."""
+    order = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
+    return np.concatenate([w[..., :-dr], w[..., -dr:][..., order]], axis=-1)
+
+
+def _load_latent_sparse(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]:
+    """The latent-attention, sigmoid-routed model's tree
+    (``model._init_latent_attention`` / ``_init_shared_sparse_mlp``) from
+    the checkpoint's names: ``self_attn.{q_a_proj, q_a_layernorm, q_b_proj,
+    kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj, o_proj}``, a dense
+    layer's ``mlp.{gate,up,down}_proj``, a sparse layer's ``mlp.gate``,
+    ``mlp.experts.<e>.*`` (the HELD experts only) and
+    ``mlp.shared_experts.*``."""
+    np_dt = np.dtype(dt)
+    L, Ld = cfg.num_layers, cfg.first_dense_layers
+    H, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    dv = cfg.v_head_dim
+    lo, hi = cfg.experts_held_range
+
+    def t(key: str) -> np.ndarray:
+        return np.asarray(sd[key], np.float32)
+
+    def proj(l: int, name: str) -> np.ndarray:
+        return t(f"model.layers.{l}.{name}.weight").T  # [in, out]
+
+    def stack(name: str, layers=range(L), fix=lambda w: w) -> np.ndarray:
+        return np.asarray(np.stack([fix(proj(l, name)) for l in layers]), np_dt)
+
+    def norms(name: str) -> np.ndarray:
+        return np.asarray(
+            np.stack([t(f"model.layers.{l}.{name}.weight") for l in range(L)]), np_dt)
+
+    def q_b(w):  # per head [nope | rope]: the rope part of every head
+        w = w.reshape(w.shape[0], H, dn + dr)
+        return _half_split(w, dr).reshape(w.shape[0], H * (dn + dr))
+
+    layers = {
+        "attn_norm": norms("input_layernorm"),
+        "mlp_norm": norms("post_attention_layernorm"),
+        "wq_a": stack("self_attn.q_a_proj"),
+        "q_norm": norms("self_attn.q_a_layernorm"),
+        "wq_b": stack("self_attn.q_b_proj", fix=q_b),
+        "wkv_a": stack("self_attn.kv_a_proj_with_mqa", fix=lambda w: _half_split(w, dr)),
+        "kv_norm": norms("self_attn.kv_a_layernorm"),
+        "wk_b": stack("self_attn.kv_b_proj", fix=lambda w: np.transpose(
+            w.reshape(w.shape[0], H, dn + dv)[..., :dn], (1, 2, 0))),
+        "wv_b": stack("self_attn.kv_b_proj", fix=lambda w: np.transpose(
+            w.reshape(w.shape[0], H, dn + dv)[..., dn:], (1, 0, 2))),
+        "wo": stack("self_attn.o_proj"),
+    }
+    sparse = range(Ld, L)
+
+    def gate_up(prefix: str, layers_):
+        return np.asarray(np.concatenate(
+            [stack(f"{prefix}.gate_proj", layers_), stack(f"{prefix}.up_proj", layers_)],
+            axis=-1), np_dt)
+
+    moe = {
+        "w_router": stack("mlp.gate", sparse),
+        # one array a sparse layer (model._init_shared_sparse_mlp)
+        "w_gu": tuple(np.stack(
+            [gate_up(f"mlp.experts.{e}", [l])[0] for e in range(lo, hi)]) for l in sparse),
+        "w_down": tuple(np.stack(
+            [stack(f"mlp.experts.{e}.down_proj", [l])[0] for e in range(lo, hi)])
+            for l in sparse),
+    }
+    if cfg.num_shared_experts:
+        moe["shared_wgu"] = gate_up("mlp.shared_experts", sparse)
+        moe["shared_down"] = stack("mlp.shared_experts.down_proj", sparse)
+    params: dict[str, Any] = {"layers": layers, "moe": moe}
+    if Ld:
+        params["dense_mlp"] = {
+            "wgu": np.asarray(_fuse_np(
+                [stack("mlp.gate_proj", range(Ld)), stack("mlp.up_proj", range(Ld))], tp),
+                np_dt),
+            "w_down": stack("mlp.down_proj", range(Ld)),
+        }
+    return params
+
+
 def load_hf_llama(
-    path: str | Path, dtype=None, tp: int = 1, quant: str | None = None
+    path: str | Path, dtype=None, tp: int = 1, quant: str | None = None,
+    experts_held: tuple[int, int] | None = None,
 ) -> tuple[ModelConfig, Any]:
-    """Returns (ModelConfig, params pytree) from an HF llama/qwen2/ouro
-    checkpoint.
+    """Returns (ModelConfig, params pytree) from an HF llama/qwen2/ouro/
+    axk1 checkpoint (``experts_held``: see :func:`config_from_hf`).
 
     ``tp`` fixes the shard-blocked layout of the fused wqkv/wgu projections
     (model.fuse_qkv/fuse_gu) and must match the serving mesh's tp axis.
@@ -116,12 +254,32 @@ def load_hf_llama(
     if quant not in (None, "int8"):
         raise ValueError(f"unknown quantization {quant!r}")
     path = Path(path)
-    cfg = config_from_hf(path)
+    cfg = config_from_hf(path, experts_held)
     dt = dtype or cfg.jax_dtype
     sd = _read_state_dict(path)
 
     def t(key: str) -> np.ndarray:
         return np.asarray(sd[key], np.float32)
+
+    if cfg.latent:
+        if quant is not None or tp != 1:
+            raise NotImplementedError(
+                f"quant={quant!r} / tp={tp} for {cfg.name!r}: experts and "
+                "latent projections load unquantised, in the tp=1 layout"
+            )
+        np_dt = np.dtype(dt)
+        params = _load_latent_sparse(cfg, sd, dt, tp)
+        params.update({
+            "embed": np.asarray(t("model.embed_tokens.weight"), np_dt),
+            "final_norm": np.asarray(t("model.norm.weight"), np_dt),
+            "fuse_tp": np.asarray(tp, np.int32),
+        })
+        if not cfg.tie_embeddings:
+            params["lm_head"] = np.asarray(t("lm_head.weight").T, np_dt)
+        log.info("loaded %s: %d layers, vocab %d, routed experts [%d, %d) of %d",
+                 path, cfg.num_layers, cfg.vocab_size, *cfg.experts_held_range,
+                 cfg.num_experts)
+        return cfg, params
 
     def proj(i: int, name: str) -> np.ndarray:
         return t(f"model.layers.{i}.{name}.weight").T  # [in, out]

@@ -38,6 +38,7 @@ v5e, round 2):
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any
 
 import jax
@@ -45,6 +46,11 @@ import jax.numpy as jnp
 from jax import shard_map
 
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+from dynamo_tpu.ops.latent_attention import (
+    latent_decode_attention,
+    latent_ragged_attention,
+    write_latent_rows,
+)
 from dynamo_tpu.ops.ragged_attention import (
     ragged_paged_attention,
     sharded_ragged_attention,
@@ -91,8 +97,11 @@ def init_params_quantized(rng: jax.Array, cfg: ModelConfig, tp: int = 1) -> Para
     copies (unrolled, this init took 135 s to compile for 28 layers on
     a v5e — longer than any serving program).
     """
-    if cfg.is_moe:
-        raise NotImplementedError("int8 init for MoE presets not yet supported")
+    if cfg.is_moe or cfg.latent:
+        raise NotImplementedError(
+            f"int8 weights for {cfg.name!r}: experts and latent projections "
+            "are served unquantised (no int8 init for them)"
+        )
     h, i, v, L = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_layers
     dt = cfg.jax_dtype
 
@@ -216,15 +225,19 @@ def init_params(rng: jax.Array, cfg: ModelConfig, tp: int = 1) -> Params:
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5).astype(dt)
 
-    wq = dense(keys[1], (L, h, cfg.q_size), h)
-    wk = dense(keys[2], (L, h, cfg.kv_size), h)
-    wv = dense(keys[3], (L, h, cfg.kv_size), h)
     layers: dict[str, Any] = {
         "attn_norm": jnp.ones((L, h), dt),
         "mlp_norm": jnp.ones((L, h), dt),
-        "wqkv": fuse_qkv(wq, wk, wv, tp),
-        "wo": dense(keys[4], (L, cfg.q_size, h), cfg.q_size),
     }
+    extra: dict[str, Any] = {}
+    if cfg.latent:
+        layers.update(_init_latent_attention(rng, cfg, dense))
+    else:
+        wq = dense(keys[1], (L, h, cfg.q_size), h)
+        wk = dense(keys[2], (L, h, cfg.kv_size), h)
+        wv = dense(keys[3], (L, h, cfg.kv_size), h)
+        layers["wqkv"] = fuse_qkv(wq, wk, wv, tp)
+        layers["wo"] = dense(keys[4], (L, cfg.q_size, h), cfg.q_size)
     if cfg.attn_qkv_bias:
         # Qwen2-family qkv bias, in the same shard-blocked fused column
         # order as wqkv (random fused == fused random for init; the
@@ -232,7 +245,9 @@ def init_params(rng: jax.Array, cfg: ModelConfig, tp: int = 1) -> Params:
         layers["bqkv"] = dense(
             jax.random.fold_in(rng, 11), (L, cfg.q_size + 2 * cfg.kv_size), 1
         )
-    if cfg.is_moe:
+    if cfg.shared_sparse:
+        extra = _init_shared_sparse_mlp(rng, cfg, dense, tp)
+    elif cfg.is_moe:
         E = cfg.num_experts
         layers["w_router"] = dense(jax.random.fold_in(rng, 7), (L, h, E), h)
         layers["w_gate"] = dense(keys[5], (L, E, h, i), h)
@@ -250,11 +265,115 @@ def init_params(rng: jax.Array, cfg: ModelConfig, tp: int = 1) -> Params:
         # The fused wqkv/wgu column layout depends on tp; carried in the
         # pytree so serving can assert params match the mesh.
         "fuse_tp": jnp.asarray(tp, jnp.int32),
+        **extra,
     }
     _init_loop_extras(rng, cfg, params)
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(jax.random.fold_in(rng, 99), (h, v), h)
     return params
+
+
+def _init_latent_attention(rng: jax.Array, cfg: ModelConfig, dense) -> dict:
+    """The latent attention's leaves, ``[L, ...]`` each: ``wq_a [h, rq]``
+    with ``q_norm [rq]``, ``wq_b [rq, H (dn + dr)]`` (per head nope then
+    rope), ``wkv_a [h, rkv + dr]`` (compressed K/V then the shared rope
+    key) with ``kv_norm [rkv]``, the K/V up-projection (the published
+    ``kv_b_proj [rkv, H (dn + dv)]``) in its two parts and in the order
+    the absorbed decode contracts them, ``wk_b [H, dn, rkv]`` (``q_nope
+    -> q'``) and ``wv_b [H, rkv, dv]`` (``o' -> o``), so that no decode
+    step slices or transposes a weight, and ``wo [H dv, h]``."""
+    h, L, H = cfg.hidden_size, cfg.num_layers, cfg.num_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    key = lambda n: jax.random.fold_in(rng, 40 + n)  # noqa: E731
+    return {
+        "wq_a": dense(key(0), (L, h, rq), h),
+        "q_norm": jnp.ones((L, rq), cfg.jax_dtype),
+        "wq_b": dense(key(1), (L, rq, H * (dn + dr)), rq),
+        "wkv_a": dense(key(2), (L, h, rkv + dr), h),
+        "kv_norm": jnp.ones((L, rkv), cfg.jax_dtype),
+        "wk_b": dense(key(3), (L, H, dn, rkv), rkv),
+        "wv_b": dense(key(5), (L, H, rkv, dv), rkv),
+        "wo": dense(key(4), (L, H * dv, h), H * dv),
+    }
+
+
+def _init_shared_sparse_mlp(rng: jax.Array, cfg: ModelConfig, dense, tp: int) -> dict:
+    """The MLPs of a model whose leading layers are dense and whose others
+    are sigmoid-routed: ``dense_mlp`` (``[first_dense_layers, ...]``: wgu,
+    w_down) and ``moe`` (``[num_layers - first_dense_layers, ...]``:
+    ``w_router [h, E]`` at the router's full width, the shared experts as
+    one SwiGLU of width ``ns x im``, ``shared_wgu [h, 2 ns im]`` and
+    ``shared_down [ns im, h]``, and the HELD experts' ``w_gu [Eh, h, 2
+    im]`` (gate then up) and ``w_down [Eh, im, h]``). The experts' two
+    leaves are TUPLES of one array a sparse layer, not stacked: a
+    ``[l]`` slice of a stacked array that feeds a loop over the experts
+    is copied out before the loop, 1 GB a layer at the published widths
+    (the v5e's compiler, PR 32), as :func:`init_cache` found for the
+    pages. Expert ``e`` of the model is drawn from key ``e`` whatever
+    share is held, so that the shares of one seed are shares of one
+    model.
+
+    A routed expert's down-projection is drawn at ``1 /
+    num_experts_per_tok`` of the fan-in scale. The choice of the ``k``
+    highest scores is not continuous: on random weights the ``k``-th and
+    the next score of a token lie ~0.07 of their spread apart, what
+    bfloat16 has rounded off the residual stream by then (~1%) moves
+    them past each other in one token of six a layer, and a whole term
+    ``w_e SwiGLU_e(x)`` then enters or leaves on one side of a
+    comparison with float32 and not on the other. At the fan-in scale
+    that term is 10-20% of the stream (a pre-norm branch on random
+    weights is as large as the stream it adds to; in a trained model of
+    this depth the stream is tens of times a branch), and engine and
+    reference part by 0.3-0.5 in a log-probability whatever the code
+    does; at ``1 / k`` a term that flips moves the stream by 1-2%
+    (the ``k`` chosen terms together are then about a tenth of the
+    shared expert's; PERF.md, PR 32: 0.29 on the v5e at the fan-in scale
+    against a tolerance of 0.15). The router, the shared experts and every other
+    leaf keep the fan-in scale."""
+    h, i, im = cfg.hidden_size, cfg.intermediate_size, cfg.moe_intermediate_size
+    Ld = cfg.first_dense_layers
+    Ls = cfg.num_layers - Ld
+    lo, hi = cfg.experts_held_range
+    ns = cfg.num_shared_experts
+    key = lambda n: jax.random.fold_in(rng, 60 + n)  # noqa: E731
+
+    def experts(k, shape, fan_in):  # Ls x [Eh, ...], expert e from key e
+        return tuple(
+            jnp.stack([
+                dense(jax.random.fold_in(jax.random.fold_in(k, e), s), shape, fan_in)
+                for e in range(lo, hi)
+            ])
+            for s in range(Ls)
+        )
+
+    out = {"moe": {
+        "w_router": dense(key(0), (Ls, h, cfg.num_experts), h),
+        "w_gu": experts(key(1), (h, 2 * im), h),
+        "w_down": experts(key(2), (im, h), im * cfg.num_experts_per_tok ** 2),
+    }}
+    if ns:
+        out["moe"]["shared_wgu"] = dense(key(3), (Ls, h, 2 * ns * im), h)
+        out["moe"]["shared_down"] = dense(key(4), (Ls, ns * im, h), ns * im)
+    if Ld:
+        out["dense_mlp"] = {
+            "wgu": fuse_gu(dense(key(5), (Ld, h, i), h), dense(key(6), (Ld, h, i), h), tp),
+            "w_down": dense(key(7), (Ld, i, h), i),
+        }
+    return out
+
+
+def layer_params(params: Params, l: int, cfg: ModelConfig) -> dict:
+    """Layer ``l``'s leaves: ``params["layers"]`` at ``l`` and, where the
+    MLPs are kept apart (``cfg.shared_sparse``), the dense MLP of a
+    leading layer or the sparse one of the others."""
+    lp = jax.tree.map(lambda a: a[l], params["layers"])
+    if cfg.shared_sparse:
+        Ld = cfg.first_dense_layers
+        group, at = ("dense_mlp", l) if l < Ld else ("moe", l - Ld)
+        # ``v[at]``: a stacked leaf's slice, or the experts' own array
+        lp.update({k: v[at] for k, v in params[group].items()})
+    return lp
 
 
 def _init_loop_extras(rng: jax.Array, cfg: ModelConfig, params: Params) -> None:
@@ -322,11 +441,10 @@ def init_cache(cfg: ModelConfig, engine: EngineConfig, dtype=None) -> tuple:
     dtype = dtype or cfg.jax_dtype
     shape = (
         cfg.ut_steps * (engine.num_kv_blocks + 1),
-        engine.block_size,
-        2 * cfg.num_kv_heads,
-        cfg.head_dim,
+        *cfg.kv_page_tail(engine.block_size),
     )
     if engine.kv_quantized:
+        _refuse_int8_latent(cfg)
         return tuple(
             {
                 "kv": jnp.zeros(shape, jnp.int8),
@@ -353,16 +471,23 @@ def init_cache_stacked(
     shape = (
         cfg.num_layers,
         engine.num_kv_blocks + 1,
-        engine.block_size,
-        2 * cfg.num_kv_heads,
-        cfg.head_dim,
+        *cfg.kv_page_tail(engine.block_size),
     )
     if engine.kv_quantized:
+        _refuse_int8_latent(cfg)
         return {
             "kv": jnp.zeros(shape, jnp.int8),
             "scale": jnp.zeros(shape[:-1], jnp.float32),
         }
     return jnp.zeros(shape, dtype)
+
+
+def _refuse_int8_latent(cfg: ModelConfig) -> None:
+    if cfg.latent:
+        raise NotImplementedError(
+            "kv_dtype='int8' with attention='mla': the int8 pages keep a "
+            "scale per slot and KV HEAD, and a latent page has no heads"
+        )
 
 
 # -- building blocks -------------------------------------------------------
@@ -385,6 +510,58 @@ def rope_tables(
     return jnp.cos(angles), jnp.sin(angles)
 
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(d: int, theta: float, scaling: dict) -> jax.Array:
+    """YaRN's static frequencies ``[d/2]``: pair ``i``'s ``theta^(-2i/d)``
+    blended with itself over ``factor`` by the linear ramp between the
+    correction dims of ``beta_fast`` and ``beta_slow`` (the family's
+    ``yarn_find_correction_range``): pairs that turn more than
+    ``beta_fast`` times over the original context keep their frequency,
+    those that turn less than ``beta_slow`` times are interpolated."""
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def correction_dim(rotations: float) -> float:
+        return d * math.log(orig / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(float(scaling.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(correction_dim(float(scaling.get("beta_slow", 1)))), d - 1)
+    if low == high:
+        high += 0.001
+    extra = theta ** (-jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2))
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return extra / factor * ramp + extra * (1 - ramp)
+
+
+def latent_rope_tables(positions: jax.Array, cfg: ModelConfig):
+    """(cos, sin) ``[T, dr/2]`` of the latent attention's rope part:
+    plain rope over ``qk_rope_head_dim``, or YaRN's frequencies with cos
+    and sin scaled by ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)``."""
+    d = cfg.qk_rope_head_dim
+    scaling = dict(cfg.rope_scaling or ())
+    if not scaling:
+        return rope_tables(positions, d, cfg.rope_theta)
+    freqs = yarn_inv_freq(d, cfg.rope_theta, scaling)
+    angles = positions[..., None].astype(jnp.float32) * freqs
+    m = (_yarn_mscale(scaling["factor"], scaling.get("mscale", 1))
+         / _yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0)))
+    return jnp.cos(angles) * m, jnp.sin(angles) * m
+
+
+def latent_sm_scale(cfg: ModelConfig) -> float:
+    """``(dn + dr)^-0.5``, times YaRN's ``mscale(factor,
+    mscale_all_dim)^2`` where the rope is scaled."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    scaling = dict(cfg.rope_scaling or ())
+    if scaling and scaling.get("mscale_all_dim", 0):
+        scale *= _yarn_mscale(scaling["factor"], scaling["mscale_all_dim"]) ** 2
+    return scale
+
+
 def rope_apply(
     x: jax.Array, cos: jax.Array, sin: jax.Array
 ) -> jax.Array:
@@ -402,8 +579,11 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return rope_apply(x, cos, sin)
 
 
-def _mlp(x, lp, cfg: ModelConfig, tp: int, mesh=None):
-    if cfg.is_moe:
+def _mlp(x, lp, cfg: ModelConfig, tp: int, mesh=None, row_valid=None,
+         expert_stats: list | None = None):
+    if "w_router" in lp and cfg.shared_sparse:
+        return _shared_sparse_mlp(x, lp, cfg, row_valid, expert_stats)
+    if cfg.is_moe and not cfg.shared_sparse:
         return _moe_mlp(x, lp, cfg, mesh)
     gu = _dot(x, lp["wgu"])
     g, u = split_gu(gu, tp)
@@ -411,8 +591,153 @@ def _mlp(x, lp, cfg: ModelConfig, tp: int, mesh=None):
     return _dot(act, lp["w_down"]).astype(x.dtype)
 
 
+# -- the sigmoid-routed sparse MLP: a chip's share, dropless ------------------
+
+# At or under this many rows every held expert runs on EVERY row and the
+# rows not routed to it are weighted zero: a decode step's bytes and
+# operations are then the same whatever the router favours (PERF.md, PR
+# 32). A bf16 weight costs 2 B to read and 2 FLOP a row, so under ~240
+# rows (the v5e's FLOP per byte) the product hides behind the weight
+# stream it needs anyway. Above it the work follows the pairs held.
+_EXPERTS_ALL_ROWS_MAX = 256
+# Above it: per-expert capacities, as shares of the rows, of which each
+# expert takes the smallest that holds its tokens. The last is all rows,
+# so nothing is ever dropped.
+_EXPERT_TIERS = (1 / 16, 1 / 8, 1 / 4, 1 / 2, 1)
+
+
+def route_sigmoid(xf: jax.Array, w_router: jax.Array, cfg: ModelConfig):
+    """(weights ``[N, E]`` float32, zero where not chosen; chosen ``[N,
+    E]`` bool). ``sc = sigmoid(x Wg)`` in float32; the experts in
+    ``n_group`` groups, a group's score the sum of its two highest
+    ``sc``; the ``topk_group`` best groups kept; the ``k`` highest ``sc``
+    among their experts chosen; weights ``sc_e / sum(sc_chosen) x
+    routed_scaling_factor``. No bias on the choice."""
+    N, E, G, k = xf.shape[0], cfg.num_experts, cfg.n_group, cfg.num_experts_per_tok
+    logits = jnp.dot(
+        xf.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    sc = jax.nn.sigmoid(logits)
+    rows = jnp.arange(N)[:, None]
+    pick = sc
+    if G > 1:
+        grp = sc.reshape(N, G, E // G)
+        g_score = jnp.sum(jax.lax.top_k(grp, 2)[0], axis=-1)            # [N, G]
+        _, g_idx = jax.lax.top_k(g_score, cfg.topk_group)
+        kept = jnp.zeros((N, G), bool).at[rows, g_idx].set(True)
+        pick = jnp.where(kept[:, :, None], grp, -1.0).reshape(N, E)     # sc > 0
+    _, idx = jax.lax.top_k(pick, k)
+    w = jnp.take_along_axis(sc, idx, axis=1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
+    weights = jnp.zeros((N, E), jnp.float32).at[rows, idx].set(w)
+    chosen = jnp.zeros((N, E), bool).at[rows, idx].set(True)
+    return weights, chosen
+
+
+def _swiglu(x, w_gu, w_down):
+    """``(silu(x Wg) * (x Wu)) Wd`` with ``w_gu = [Wg | Wu]``; float32 out."""
+    gu = jnp.dot(x, w_gu, preferred_element_type=jnp.float32)
+    g, u = jnp.split(gu, 2, axis=-1)
+    act = (jax.nn.silu(g) * u).astype(x.dtype)
+    return jnp.dot(act, w_down, preferred_element_type=jnp.float32)
+
+
+def _experts_all_rows(xf, w_held, w_gu, w_down):
+    """Every held expert on every row, the rows not routed to it weighted
+    zero: ``[N, h]`` float32. The same bytes and operations whatever the
+    routing. One plain ``x @ W`` pair an expert, as a dense layer's: the
+    batched form (``einsum("nh,ehi->eni")``) made the v5e's compiler
+    re-lay the experts out, and keep the copy (672 MB a layer at the
+    published widths) beside the weights for the whole megastep."""
+    out = jnp.zeros((xf.shape[0], w_down.shape[-1]), jnp.float32)
+    for e in range(w_gu.shape[0]):
+        out = out + w_held[:, e, None] * _swiglu(xf, w_gu[e], w_down[e])
+    return out
+
+
+def _experts_by_load(xf, w_held, chosen_held, w_gu, w_down):
+    """Each held expert on the rows routed to it and no others: its rows
+    are brought to the front (a stable sort of its column of
+    ``chosen_held``), and it runs on the smallest of the static
+    capacities ``_EXPERT_TIERS x N`` that holds them, the last being all
+    ``N``: work follows the pairs held (under twice them), and no pair
+    is dropped at any skew. ``[N, h]`` float32."""
+    N, h = xf.shape
+    Eh = w_gu.shape[0]
+    caps = sorted({max(1, min(N, math.ceil(N * f))) for f in _EXPERT_TIERS})
+    order = jnp.argsort(~chosen_held, axis=0, stable=True).astype(jnp.int32)  # [N, Eh]
+    counts = jnp.sum(chosen_held, axis=0).astype(jnp.int32)                   # [Eh]
+    tier = jnp.searchsorted(jnp.asarray(caps, jnp.int32), counts).astype(jnp.int32)
+
+    def run(cap: int, e, out):
+        rows = jax.lax.dynamic_slice_in_dim(order, e, 1, axis=1)[:cap, 0]
+        wt = jnp.where(
+            jnp.arange(cap) < counts[e],
+            jax.lax.dynamic_slice_in_dim(w_held, e, 1, axis=1)[rows, 0], 0.0,
+        )
+        y = _swiglu(
+            xf[rows],
+            jax.lax.dynamic_index_in_dim(w_gu, e, keepdims=False),
+            jax.lax.dynamic_index_in_dim(w_down, e, keepdims=False),
+        )
+        return out.at[rows].add(wt[:, None] * y)
+
+    def body(e, out):
+        return jax.lax.switch(
+            tier[e], [functools.partial(run, c) for c in caps], e, out
+        )
+
+    return jax.lax.fori_loop(0, Eh, body, jnp.zeros((N, h), jnp.float32))
+
+
+def _shared_sparse_mlp(x, lp, cfg: ModelConfig, row_valid=None,
+                       expert_stats: list | None = None):
+    """``sum_e w_e SwiGLU_e(x) + SwiGLU_shared(x)`` over THIS chip's share
+    of the routed experts (``cfg.experts_held``): the router runs at its
+    full width, only the held experts' terms are added (what the absent
+    ones would add is left out, and that partial result goes on), the
+    shared experts are computed whole. Dropless: every (row, held expert)
+    pair the router chose is computed. ``row_valid`` ``[N]`` marks the
+    rows that are tokens (padding routes nowhere and is not counted).
+    With ``expert_stats`` one int32 ``[4]`` is appended: held experts
+    touched, 1 (this layer's step), pairs on held experts, pairs routed."""
+    shape = x.shape
+    xf = x.reshape(-1, shape[-1])
+    N = xf.shape[0]
+    lo, hi = cfg.experts_held_range
+    with jax.named_scope("router"):
+        weights, chosen = route_sigmoid(xf, lp["w_router"], cfg)
+        if row_valid is None:
+            row_valid = jnp.ones((N,), bool)
+        chosen_held = chosen[:, lo:hi] & row_valid[:, None]
+        w_held = jnp.where(chosen_held, weights[:, lo:hi], 0.0)
+        if expert_stats is not None:
+            expert_stats.append(jnp.stack([
+                jnp.sum(jnp.any(chosen_held, axis=0)), jnp.int32(1),
+                jnp.sum(chosen_held),
+                jnp.sum(row_valid) * cfg.num_experts_per_tok,
+            ]).astype(jnp.int32))
+    with jax.named_scope("experts"):
+        if N <= _EXPERTS_ALL_ROWS_MAX:
+            out = _experts_all_rows(xf, w_held, lp["w_gu"], lp["w_down"])
+        else:
+            out = _experts_by_load(xf, w_held, chosen_held, lp["w_gu"], lp["w_down"])
+    if "shared_wgu" in lp:
+        with jax.named_scope("shared_expert"):
+            out = out + _swiglu(xf, lp["shared_wgu"], lp["shared_down"])
+    return out.astype(x.dtype).reshape(shape)
+
+
+# -- the mixtral path: softmax over the chosen, capacity-bounded ------------
+
 def _moe_capacity(N: int, cfg: ModelConfig) -> int:
-    """Per-expert token capacity for a dispatch of N tokens (static)."""
+    """Per-expert token capacity for a dispatch of N tokens (static).
+    The MIXTRAL path's only (``router_scoring="softmax"``): a token past
+    an expert's capacity is dropped for that expert, which happens once
+    ``num_experts_per_tok x moe_capacity_factor < num_experts``. The
+    sigmoid-routed layer (:func:`_shared_sparse_mlp`) has no capacity and
+    drops nothing."""
     k, E = cfg.num_experts_per_tok, cfg.num_experts
     return max(1, min(N, int(-(-N * k * cfg.moe_capacity_factor // E))))
 
@@ -671,6 +996,8 @@ def dense_layer(
     tp: int = 1,
     mesh=None,
     rope_cs: tuple[jax.Array, jax.Array] | None = None,
+    row_valid: jax.Array | None = None,
+    expert_stats: list | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """One transformer block over a ragged token batch: attn-norm → fused
     qkv → rope → in-place page scatter → ragged paged attention → wo →
@@ -721,14 +1048,90 @@ def dense_layer(
                 q, kv_pages, kv_lens, block_tables, cu_q_lens, num_seqs,
                 sm_scale=sm_scale, kv_scales=kv_scales,
             )
-    return _attn_out_and_mlp(x, attn.reshape(T, cfg.q_size), lp, cfg, tp, mesh), cache_l
+    x = _attn_out_and_mlp(
+        x, attn.reshape(T, cfg.q_size), lp, cfg, tp, mesh,
+        row_valid=row_valid, expert_stats=expert_stats,
+    )
+    return x, cache_l
 
 
-def _attn_out_and_mlp(x, attn, lp, cfg: ModelConfig, tp: int, mesh):
+def latent_layer(
+    x: jax.Array,            # [T, h]
+    lp: dict,                # ONE layer's params (:func:`layer_params`)
+    cache_l: jax.Array,      # ONE layer's latent pages [n_pages, rows, lanes]
+    write_pages: jax.Array,
+    write_offs: jax.Array,
+    kv_lens: jax.Array,
+    block_tables: jax.Array,
+    cu_q_lens: jax.Array | None,  # None: the decode shape (one row a sequence)
+    num_seqs: jax.Array,
+    cfg: ModelConfig,
+    rope_cs: tuple[jax.Array, jax.Array],
+    row_valid: jax.Array | None = None,
+    expert_stats: list | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """One block with latent (MLA) attention over a ragged token batch,
+    :func:`dense_layer`'s counterpart: low-rank q with its norm, one
+    compressed K/V vector a token with its norm beside a rope key all
+    heads share, the page scatter of ``[ckv | kr]``, attention (absorbed
+    in the decode shape, expanded heads for a ragged batch, both over
+    the pages: ops/latent_attention.py), ``wo``, and the layer's MLP (dense, or the
+    sigmoid-routed share). Scopes: ``qkv/q_proj`` (with the absorbed
+    query's ``Wkvb_k``), ``qkv/kv_down``, ``kv_write``, ``attn``, ``o_proj`` (with
+    ``Wkvb_v`` in the decode shape), then the MLP's."""
+    T = x.shape[0]
+    H, dn, dr, dv = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    r, eps = cfg.kv_lora_rank, cfg.rms_norm_eps
+    sm_scale = latent_sm_scale(cfg)
+    dt = lp["attn_norm"].dtype
+    wk, wv = lp["wk_b"], lp["wv_b"]          # [H, dn, r], [H, r, dv]
+    decode = cu_q_lens is None
+    # "qkv": the section a trace's reader knows the projections before
+    # attention by (the dense layer's fused one); the two parts inside it.
+    with jax.named_scope("qkv"), jax.named_scope("q_proj"):
+        y = rms_norm(x, lp["attn_norm"], eps).astype(dt)
+        cq = rms_norm(_dot(y, lp["wq_a"]).astype(dt), lp["q_norm"], eps)
+        q = _dot(cq, lp["wq_b"]).astype(dt).reshape(T, H, dn + dr)
+        q_nope, q_rope = q[..., :dn], rope_apply(q[..., dn:], *rope_cs)
+        if decode:
+            q_lat = jnp.einsum(
+                "bhd,hdr->bhr", q_nope, wk, preferred_element_type=jnp.float32
+            ).astype(dt)
+    with jax.named_scope("qkv"), jax.named_scope("kv_down"):
+        kva = _dot(y, lp["wkv_a"]).astype(dt)
+        ckv = rms_norm(kva[:, :r], lp["kv_norm"], eps)
+        kr = rope_apply(kva[:, None, r:], *rope_cs)[:, 0]
+    with jax.named_scope("kv_write"):
+        cache_l = write_latent_rows(cache_l, write_pages, write_offs, ckv, kr)
+    if decode:
+        with jax.named_scope("attn"):
+            o_lat = latent_decode_attention(
+                q_lat, q_rope, cache_l, kv_lens, block_tables, sm_scale=sm_scale
+            )
+        with jax.named_scope("o_proj"):
+            attn = jnp.einsum(
+                "bhr,hrd->bhd", o_lat, wv, preferred_element_type=jnp.float32
+            ).astype(dt)
+    else:
+        with jax.named_scope("attn"):
+            attn = latent_ragged_attention(
+                q_nope, q_rope, wk, wv, cache_l, kv_lens, block_tables,
+                cu_q_lens, num_seqs, sm_scale=sm_scale,
+            )
+    x = _attn_out_and_mlp(
+        x, attn.reshape(T, H * dv), lp, cfg, 1, None,
+        row_valid=row_valid, expert_stats=expert_stats,
+    )
+    return x, cache_l
+
+
+def _attn_out_and_mlp(x, attn, lp, cfg: ModelConfig, tp: int, mesh,
+                      row_valid=None, expert_stats: list | None = None):
     """The block after attention: ``x + attn Wo``, then ``x + mlp(norm x)``.
     With ``cfg.sandwich_norm`` each sub-layer's OUTPUT is normed once more
     before its residual add (``attn_post_norm`` / ``mlp_post_norm``),
-    inside that sub-layer's scope."""
+    inside that sub-layer's scope. ``row_valid`` and ``expert_stats`` are
+    the sigmoid-routed MLP's (:func:`_shared_sparse_mlp`)."""
     with jax.named_scope("o_proj"):
         a = _dot(attn, lp["wo"]).astype(x.dtype)
         if cfg.sandwich_norm:
@@ -736,7 +1139,7 @@ def _attn_out_and_mlp(x, attn, lp, cfg: ModelConfig, tp: int, mesh):
         x = x + a
     with jax.named_scope("mlp"):
         y = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps).astype(lp["mlp_norm"].dtype)
-        m = _mlp(y, lp, cfg, tp, mesh)
+        m = _mlp(y, lp, cfg, tp, mesh, row_valid, expert_stats)
         if cfg.sandwich_norm:
             m = rms_norm(m, lp["mlp_post_norm"], cfg.rms_norm_eps)
         x = x + m
@@ -762,6 +1165,7 @@ def forward_tokens(
     mesh=None,
     mm_embeds=None,          # [T, h] — multimodal rows (override where mask)
     mm_mask=None,            # [T] bool
+    expert_stats: list | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """One step over every scheduled token. Returns (last-token logits
     [S, vocab] f32, cache). Prefill chunks, decode tokens, and mixed
@@ -774,7 +1178,7 @@ def forward_tokens(
     x, cache = forward_hidden(
         params, cache, tokens, positions, write_pages, write_offs,
         kv_lens, block_tables, cu_q_lens, num_seqs, cfg, engine, mesh,
-        mm_embeds=mm_embeds, mm_mask=mm_mask,
+        mm_embeds=mm_embeds, mm_mask=mm_mask, expert_stats=expert_stats,
     )
     with jax.named_scope("lm_head"):
         last = x[last_rows]  # [S, h]
@@ -798,6 +1202,7 @@ def forward_hidden(
     mm_embeds=None,
     mm_mask=None,
     want_gates: bool = False,
+    expert_stats: list | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """The transformer stack up to the final norm: returns (hidden states
     [T, h], cache), and with ``want_gates`` (looped models) the exit
@@ -808,19 +1213,36 @@ def forward_hidden(
 
     ``mm_embeds``/``mm_mask`` (a separately-compiled prefill variant)
     override the token-embedding rows at multimodal placeholder
-    positions with encoder output (llm/multimodal.py)."""
+    positions with encoder output (llm/multimodal.py).
+
+    ``expert_stats`` (a list, the sigmoid-routed MLP's): every sparse
+    layer appends its int32 ``[4]`` count (:func:`_shared_sparse_mlp`) at
+    trace time, for the caller to sum inside the same trace."""
     tp = int(mesh.shape["tp"]) if mesh is not None else 1
     with jax.named_scope("embed"):
         x = params["embed"][tokens]  # [T, h]
         if mm_embeds is not None:
             x = jnp.where(mm_mask[:, None], mm_embeds.astype(x.dtype), x)
-        rope_cs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+        if cfg.latent:
+            rope_cs = latent_rope_tables(positions, cfg)
+        else:
+            rope_cs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+        # Padding rows (and a megastep's dead lanes) write the garbage
+        # page: they route to no expert and are not counted.
+        row_valid = write_pages != engine.garbage_block if cfg.shared_sparse else None
 
     def layer(x, lp, cache_l, write_pages, block_tables):
+        if cfg.latent:
+            return latent_layer(
+                x, lp, cache_l, write_pages, write_offs, kv_lens,
+                block_tables, cu_q_lens, num_seqs, cfg, rope_cs,
+                row_valid=row_valid, expert_stats=expert_stats,
+            )
         return dense_layer(
             x, lp, cache_l, positions, write_pages, write_offs,
             kv_lens, block_tables, cu_q_lens, num_seqs, cfg,
             tp=tp, mesh=mesh, rope_cs=rope_cs,
+            row_valid=row_valid, expert_stats=expert_stats,
         )
 
     return _run_stack(
@@ -857,13 +1279,12 @@ def _run_stack(
     ``want_gates`` (looped models; tests and offline use, no serving
     program) also returns the exit gate's probability after every pass,
     ``[ut_steps, T]``."""
-    lp_all = params["layers"]
-
     def one_pass(x, caches, write_pages, block_tables):
         caches = list(caches)
         for l in range(cfg.num_layers):
-            lp = jax.tree.map(lambda a: a[l], lp_all)
-            x, caches[l] = layer(x, lp, caches[l], write_pages, block_tables)
+            x, caches[l] = layer(
+                x, layer_params(params, l, cfg), caches[l], write_pages, block_tables
+            )
         return x, tuple(caches)
 
     def final_norm(x):
@@ -1019,6 +1440,7 @@ def decode_tokens(
     cfg: ModelConfig,
     engine: EngineConfig,
     mesh=None,
+    expert_stats: list | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Pure-decode step: B sequences, one token each. Thin assembly over
     :func:`forward_tokens` — in-jit slot computation so decode chains can
@@ -1037,6 +1459,7 @@ def decode_tokens(
     return forward_tokens(
         params, cache, tokens, positions, write_pages, write_offs,
         kv_lens, block_tables, None, num_seqs, rows, cfg, engine, mesh,
+        expert_stats=expert_stats,
     )
 
 

@@ -160,9 +160,7 @@ class PeerKvClient:
         # different float precision reports its own dtype; import_blocks
         # casts floats — an int8-vs-float mismatch fails the import FAST
         # per the PR 8 contract and the pull degrades to recompute).
-        shape = [
-            core.cfg.num_cache_layers, bs, 2 * core.cfg.num_kv_heads, core.cfg.head_dim,
-        ]
+        shape = list(core.kv_page_shape)
         dtype = core.kv_wire_dtype
         imported = 0
         ok = False

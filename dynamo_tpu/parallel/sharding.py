@@ -47,7 +47,21 @@ def make_mesh(dp: int = 1, tp: int = 1, devices=None) -> Mesh:
 def param_partition_specs(cfg: ModelConfig, tp: int) -> dict[str, Any]:
     """PartitionSpec pytree matching `model.init_params` structure
     (mesh-free: also used for memory planning of pods larger than the
-    local machine, parallel/placement.py)."""
+    local machine, parallel/placement.py).
+
+    A model with latent attention or the sigmoid-routed sparse MLP has no
+    rule here: its share of a layer is stated WITHOUT a mesh, as
+    ``ModelConfig.experts_held = (rank, of)`` (the chips that share each
+    layer's routed experts; attention, router, shared experts and norms
+    whole on every one), and one chip runs it without the exchange."""
+    if cfg.latent or cfg.shared_sparse:
+        from dynamo_tpu.engine.config import UnsupportedModelOption
+
+        raise UnsupportedModelOption(
+            "tp", cfg.name,
+            "no sharding rule for the latent projections or the held experts "
+            "(a share is stated with experts_held, not with a mesh)",
+        )
     for what, n in (
         ("num_kv_heads", cfg.num_kv_heads),
         ("num_heads", cfg.num_heads),

@@ -156,7 +156,13 @@ SCHEDULER_GAUGES: dict[str, tuple[str, str]] = {
     "kv_bytes_per_token": (
         "engine_kv_bytes_per_token",
         "Bytes of K/V cache one token holds over all planes, at the "
-        "cache's dtype (int8: scales included)",
+        "cache's dtype (int8: scales included; a latent cache: its "
+        "[ckv | kr] rows)",
+    ),
+    "experts_held": (
+        "engine_experts_held",
+        "Routed experts of each sparse layer this worker holds (its share "
+        "of the router's width); 0 for a model without a stated share",
     ),
 }
 
@@ -237,6 +243,26 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
 }
 
 
+# What a sparse model's layers counted of their router's choices, in the
+# order EngineCore.scheduler_stats()["expert_stats"][phase] holds them
+# (model._shared_sparse_mlp); phase: the program that counted ("decode": a
+# megastep's iterations, "prefill": a wave). All zero for a dense model.
+EXPERT_COUNTERS: tuple[tuple[str, str], ...] = (
+    ("engine_experts_touched",
+     "Held experts that at least one live token was routed to, summed "
+     "over sparse layers and steps"),
+    ("engine_expert_steps",
+     "Sparse layers x steps counted: experts_touched / expert_steps is "
+     "the held experts touched a layer a step"),
+    ("engine_expert_pairs_held",
+     "(token, expert) pairs the router chose that fell on held experts "
+     "(all computed: the layer drops none)"),
+    ("engine_expert_pairs_routed",
+     "(token, expert) pairs the router chose over its whole width: live "
+     "tokens x experts per token x sparse layers"),
+)
+
+
 class _EngineCounters:
     """Scrape-time collector for the engine's cumulative counters: the
     step clock's seconds per phase, :data:`ENGINE_COUNTERS`, and the
@@ -298,6 +324,13 @@ class _EngineCounters:
         for (shape, impl), n in sorted(traced_calls().items()):
             traced.add_metric(["engine", shape, impl], float(n))
         yield traced
+        by_phase = stats.get("expert_stats", {})
+        for i, (name, doc) in enumerate(EXPERT_COUNTERS):
+            family = CounterMetricFamily(
+                f"dynamo_{name}", doc, labels=["service", "phase"])
+            for phase, counts in sorted(by_phase.items()):
+                family.add_metric(["engine", phase], float(counts[i]))
+            yield family
 
 
 def bind_engine_counters(

@@ -61,11 +61,12 @@ PRAGMA_ALLOWLIST: dict[tuple[str, str, str], int] = {
     ("dynamo_tpu/engine/core.py", "holds-lock", "_step_lock"): 16,
     # Intentional syncs inside blocking-host-sync hot paths: the
     # double-buffered landing point (_PendingFetch.land — tokens +
-    # batched logprobs, and land_aux for the on-device draft round
-    # counters, ISSUE 18), np.asarray over host block-id lists (dispatch
+    # batched logprobs, land_aux for the on-device draft round
+    # counters, ISSUE 18, and a sparse model's expert counts, ISSUE 32,
+    # which land with the tokens), np.asarray over host block-id lists (dispatch
     # assembly + ring prefill), and the host-tier page staging in
     # _stage_page (host buffer, not a device array).
-    ("dynamo_tpu/engine/core.py", "sync-ok", ""): 6,
+    ("dynamo_tpu/engine/core.py", "sync-ok", ""): 7,
     # Host-buffer asarray sites cleared by the dynacheck transitive-
     # blocking sweep: packed-page unpacking and pp microbatch planning
     # operate on host arrays only.

@@ -82,10 +82,14 @@ def test_serving_compiles_no_feedback_program_after_warm_up():
     )
 
     # Widths no other test's engine has: the jit caches of one function
-    # are shared by every engine in the process.
+    # are shared by every engine in the process. The feedback width is
+    # megastep x max(widest decode bucket, prefill_batch): at the default
+    # prefill_batch of 8 it is the 32 of every other engine with k = 4, and
+    # a worker that ran one of those first had the wave's padding compiled.
     core = EngineCore(
         tiny_model(),
-        tiny_engine(megastep_k=4, decode_buckets=(3, 7), max_num_seqs=7),
+        tiny_engine(megastep_k=4, decode_buckets=(3, 7), max_num_seqs=7,
+                    prefill_batch=5),
         seed=0,
     )
     assert core.pipelined
