@@ -476,8 +476,9 @@ def metric_lines(health_url: str, prefix: str) -> list[str]:
 def check_attention_traced(workers, platform: str) -> dict:
     """Each worker's ``dynamo_engine_attention_calls_traced_total``: which
     shape its programs stated and which implementation they got. A worker
-    that decodes must have traced the decode shape; on a TPU none may
-    have fallen to the jnp reference."""
+    that decodes must have traced the decode shape (``latent-decode`` for
+    a latent model); on a TPU none may have fallen to the jnp reference,
+    and a latent model's decode none to its ``jnp`` path."""
     found: dict[str, dict] = {}
     for role, url, _ in workers:
         got = {}
@@ -486,11 +487,22 @@ def check_attention_traced(workers, platform: str) -> dict:
                           .replace('"', "").split(","))
             got[f"{labels['shape']}/{labels['impl']}"] = float(line.split()[-1])
         found[role] = got
-        if role != "prefill" and not any(k.startswith("decode/") and v for k, v in got.items()):
-            raise PhaseFailed(f"{role}: no decode-shaped attention call was traced: {got}")
-        if platform == "tpu" and any(k.endswith("/reference") and v for k, v in got.items()):
-            raise PhaseFailed(f"{role}: attention ran the jnp reference on a TPU: {got}")
+        judge_attention_traced(role, got, platform)
     return found
+
+
+def judge_attention_traced(role: str, got: dict[str, float], platform: str) -> None:
+    """``got``: ``{"<shape>/<impl>": calls traced}`` of one worker."""
+    traced = {k for k, v in got.items() if v}
+    if role != "prefill" and not any(
+            k.split("/")[0] in ("decode", "latent-decode") for k in traced):
+        raise PhaseFailed(f"{role}: no decode-shaped attention call was traced: {got}")
+    if platform == "tpu" and any(k.endswith("/reference") for k in traced):
+        raise PhaseFailed(f"{role}: attention ran the jnp reference on a TPU: {got}")
+    if platform == "tpu" and "latent-decode/jnp" in traced:
+        raise PhaseFailed(
+            f"{role}: latent decode attention ran its jnp path on a TPU, not the "
+            f"paged kernel: {got}")
 
 
 def kernel_phase(mode: str, inject: str | None, report: dict) -> None:

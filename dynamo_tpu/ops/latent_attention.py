@@ -39,23 +39,36 @@ Two shapes, as :mod:`dynamo_tpu.ops.ragged_attention` has them:
   as the paged kernel does for the dense layer. No work is spent on
   another sequence's keys.
 
-Both are plain ``jax.numpy`` (no Pallas kernel yet: ROADMAP). The decode
-call sorts its lanes by context and walks ``_DECODE_PAGES_PER_CHUNK``
-pages a turn of ``_DECODE_LANES_PER_GROUP`` lanes at a time, each group up
-to its own longest context and no further, so its work follows the
+The ragged call is plain ``jax.numpy``. The decode call is one algorithm
+with two implementations, chosen from what the call can observe
+(:func:`decode_impl`, as ops/ragged_attention.py chooses the library
+kernel): on a TPU, for a page whose slices are whole tiles, a first-party
+Pallas kernel (:func:`latent_decode_pallas`, PR 34): the block table and
+the lengths by scalar prefetch, the pages left in HBM and fetched a page a
+DMA into a ring of VMEM blocks, each lane walking its own pages and no
+further, every cached byte read once and used there for the scores AND the
+values, all 64 heads against the one key (M = 64 on the MXU, no head
+loop). Anywhere else (the CPU, the tiny rehearsal's ``[20, 16]`` page)
+:func:`latent_decode_jnp`, which stays the definition the tests hold the
+kernel to: it sorts its lanes by context and walks
+``_DECODE_PAGES_PER_CHUNK`` gathered pages a turn of
+``_DECODE_LANES_PER_GROUP`` lanes at a time, each group up to its own
+longest context and no further. Either way the call's work follows the
 contexts in flight and neither the block table's width nor the one
-longest stream. Which path a program
-traced is counted like the paged kernel's
-(``dynamo_engine_attention_calls_traced_total`` with ``shape``
-``latent-decode`` / ``latent-ragged``, ``impl`` ``jnp``).
+longest stream. Which path a program traced is counted like the paged
+kernel's (``dynamo_engine_attention_calls_traced_total`` with ``shape``
+``latent-decode`` / ``latent-ragged``, ``impl`` ``pallas`` / ``jnp``).
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from dynamo_tpu.ops.ragged_attention import _NEG_INF, _count_traced
 
@@ -160,6 +173,20 @@ def _chunk_tables(block_tables: jax.Array, pages: int) -> jax.Array:
     return jnp.pad(block_tables, ((0, 0), (0, pad)))
 
 
+def decode_impl(backend: str, pages: jax.Array, r: int) -> str:
+    """Which implementation a decode call over ``pages`` gets on
+    ``backend``: ``"pallas"`` on a TPU where the page's slices are whole
+    tiles for the kernel (128 lanes a row, hence ``r`` a multiple of 128,
+    and half a page's tokens a whole number of the dtype's sublane tiles:
+    16 rows of bf16, 8 of f32), else ``"jnp"`` (the CPU; the tiny
+    rehearsal's ``[20, 16]`` page). The label of the call's counter."""
+    ps, _, w = _geometry(pages, r)
+    sublanes = 32 // pages.dtype.itemsize
+    fits = (w == 128 and jnp.issubdtype(pages.dtype, jnp.floating)
+            and (ps // 2) % sublanes == 0)
+    return "pallas" if backend == "tpu" and fits else "jnp"
+
+
 def latent_decode_attention(
     q_lat: jax.Array,         # [B, H, r] — q_nope through Wkvb_k (absorbed)
     q_rope: jax.Array,        # [B, H, dr]
@@ -169,61 +196,281 @@ def latent_decode_attention(
     *,
     sm_scale: float,
 ) -> jax.Array:               # [B, H, r] — P ckv, before Wkvb_v
+    """One algorithm, the implementation chosen from what the call can
+    observe (:func:`decode_impl`), as ops/ragged_attention.py chooses the
+    library kernel; :func:`latent_decode_jnp` is the definition."""
     with jax.named_scope("latent_paged_attention"):
-        _count_traced("latent-decode", "jnp")
-        B, H, r = q_lat.shape
-        ps, tiles, w = _geometry(pages, r)
-        C = min(_DECODE_PAGES_PER_CHUNK, block_tables.shape[1])
-        offs = jnp.arange(C * ps, dtype=jnp.int32).reshape(C, ps)
-        # Lanes in order of context: a group's loop then ends at ITS longest.
-        order = jnp.argsort(kv_lens)
-        tables = _chunk_tables(block_tables, C)[order]
-        lens = kv_lens[order]
-        q_tiles = q_lat[order].reshape(B, H, tiles, w)
-        q_rope = q_rope[order]
+        impl = decode_impl(jax.default_backend(), pages, q_lat.shape[-1])
+        _count_traced("latent-decode", impl)
+        fn = latent_decode_pallas if impl == "pallas" else latent_decode_jnp
+        return fn(q_lat, q_rope, pages, kv_lens, block_tables, sm_scale=sm_scale)
 
-        def group(a: int, b: int):
-            """Lanes ``a:b`` of the sorted batch: (sum ``[n, H]``,
-            numerator ``[n, H, tiles, w]``), chunk by chunk of ``C`` pages
-            up to the group's longest context."""
-            def body(c, carry):
-                m, l, acc = carry
-                ids = jax.lax.dynamic_slice_in_dim(tables[a:b], c * C, C, axis=1)
-                ckv, kr = _split(pages[ids], r)     # [n, C, tiles, ps, w], [n, C, ps, dr]
-                s = jnp.einsum("bhd,bptd->bhpt", q_rope[a:b], kr,
-                               preferred_element_type=jnp.float32)
-                for j in range(tiles):              # one K = w product a lane tile
-                    s = s + jnp.einsum("bhc,bptc->bhpt", q_tiles[a:b, :, j], ckv[:, :, j],
-                                       preferred_element_type=jnp.float32)
-                s = s * sm_scale
-                live = ((c * C * ps + offs)[None] < lens[a:b, None, None])[:, None]
-                s = jnp.where(live, s, _NEG_INF)
-                m_new = jnp.maximum(m, jnp.max(s, axis=(-2, -1)))
-                p = jnp.where(live, jnp.exp(s - m_new[..., None, None]), 0.0)
-                alpha = jnp.exp(m - m_new)
-                l = l * alpha + jnp.sum(p, axis=(-2, -1))
-                pv = p.astype(pages.dtype)
-                acc = acc * alpha[..., None, None] + jnp.stack(
-                    [jnp.einsum("bhpt,bptc->bhc", pv, ckv[:, :, j],
-                                preferred_element_type=jnp.float32)
-                     for j in range(tiles)], axis=2)
-                return m_new, l, acc
 
-            n_chunks = (lens[b - 1] + C * ps - 1) // (C * ps)
-            _, l, acc = jax.lax.fori_loop(
-                0, n_chunks, body,
-                (jnp.full((b - a, H), _NEG_INF, jnp.float32),
-                 jnp.zeros((b - a, H), jnp.float32),
-                 jnp.zeros((b - a, H, tiles, w), jnp.float32)),
-            )
-            return l, acc
+def latent_decode_jnp(q_lat, q_rope, pages, kv_lens, block_tables, *, sm_scale: float):
+    """Plain ``jax.numpy``: the lanes sorted by context and walked
+    ``_DECODE_PAGES_PER_CHUNK`` gathered pages a turn,
+    ``_DECODE_LANES_PER_GROUP`` lanes at a time."""
+    B, H, r = q_lat.shape
+    ps, tiles, w = _geometry(pages, r)
+    C = min(_DECODE_PAGES_PER_CHUNK, block_tables.shape[1])
+    offs = jnp.arange(C * ps, dtype=jnp.int32).reshape(C, ps)
+    # Lanes in order of context: a group's loop then ends at ITS longest.
+    order = jnp.argsort(kv_lens)
+    tables = _chunk_tables(block_tables, C)[order]
+    lens = kv_lens[order]
+    q_tiles = q_lat[order].reshape(B, H, tiles, w)
+    q_rope = q_rope[order]
 
-        parts = [group(a, min(B, a + _DECODE_LANES_PER_GROUP))
-                 for a in range(0, B, _DECODE_LANES_PER_GROUP)]
-        l = jnp.concatenate([p[0] for p in parts])
-        acc = jnp.concatenate([p[1] for p in parts])
-        out = (acc / jnp.maximum(l, 1e-30)[..., None, None]).reshape(B, H, r)
-        return out[jnp.argsort(order)].astype(q_lat.dtype)
+    def group(a: int, b: int):
+        """Lanes ``a:b`` of the sorted batch: (sum ``[n, H]``,
+        numerator ``[n, H, tiles, w]``), chunk by chunk of ``C`` pages
+        up to the group's longest context."""
+        def body(c, carry):
+            m, l, acc = carry
+            ids = jax.lax.dynamic_slice_in_dim(tables[a:b], c * C, C, axis=1)
+            ckv, kr = _split(pages[ids], r)     # [n, C, tiles, ps, w], [n, C, ps, dr]
+            s = jnp.einsum("bhd,bptd->bhpt", q_rope[a:b], kr,
+                           preferred_element_type=jnp.float32)
+            for j in range(tiles):              # one K = w product a lane tile
+                s = s + jnp.einsum("bhc,bptc->bhpt", q_tiles[a:b, :, j], ckv[:, :, j],
+                                   preferred_element_type=jnp.float32)
+            s = s * sm_scale
+            live = ((c * C * ps + offs)[None] < lens[a:b, None, None])[:, None]
+            s = jnp.where(live, s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=(-2, -1)))
+            p = jnp.where(live, jnp.exp(s - m_new[..., None, None]), 0.0)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=(-2, -1))
+            pv = p.astype(pages.dtype)
+            acc = acc * alpha[..., None, None] + jnp.stack(
+                [jnp.einsum("bhpt,bptc->bhc", pv, ckv[:, :, j],
+                            preferred_element_type=jnp.float32)
+                 for j in range(tiles)], axis=2)
+            return m_new, l, acc
+
+        n_chunks = (lens[b - 1] + C * ps - 1) // (C * ps)
+        _, l, acc = jax.lax.fori_loop(
+            0, n_chunks, body,
+            (jnp.full((b - a, H), _NEG_INF, jnp.float32),
+             jnp.zeros((b - a, H), jnp.float32),
+             jnp.zeros((b - a, H, tiles, w), jnp.float32)),
+        )
+        return l, acc
+
+    parts = [group(a, min(B, a + _DECODE_LANES_PER_GROUP))
+             for a in range(0, B, _DECODE_LANES_PER_GROUP)]
+    l = jnp.concatenate([p[0] for p in parts])
+    acc = jnp.concatenate([p[1] for p in parts])
+    out = (acc / jnp.maximum(l, 1e-30)[..., None, None]).reshape(B, H, r)
+    return out[jnp.argsort(order)].astype(q_lat.dtype)
+
+
+def _decode_kernel(
+    lens_ref,      # SMEM [B] i32 (scalar prefetch)
+    tables_ref,    # SMEM [B * width] i32 (scalar prefetch), lane after lane
+    q_ref,         # VMEM [1, H, r] — this grid step's lane
+    qr_ref,        # VMEM [1, H, 2 w] — [q_rope | 0 | 0 | q_rope]
+    pages_ref,     # HBM  [n_pages, rows, w]
+    out_ref,       # VMEM [1, H, r]
+    buf,           # VMEM [K, N, rows, w] — a ring of K KV blocks of N pages
+    sems,          # DMA semaphores [K], one a buffer
+    ring_ref,      # SMEM [4] — the ring's state from one grid step to the next
+    m_ref, l_ref,  # VMEM [H, 128] f32, every lane of a row the same
+    acc_ref,       # VMEM [H, r] f32
+    *, sm_scale: float, width: int,
+):
+    """A grid step is one lane, which walks its own ``ceil(kv_len / (N
+    ps))`` KV blocks. Every (lane, block) of the call is one link of a
+    chain through the ring of buffers: a link's pages are asked for ``K -
+    1`` links before it is computed on, across lanes and grid steps alike,
+    so that ``(K - 1) N`` pages are in flight whatever one block's
+    arithmetic takes (a v5e streams at 83% of its HBM rate from ~24 up).
+
+    A block is computed on as two HALVES, the tokens of each page's slots
+    ``0 .. ps/2`` and ``ps/2 .. ps``: a ``kr`` row holds one token of each
+    (left and right 64 lanes), so half ``h``'s keys are ``[N ps/2, r + w]``
+    = the half's rows of the four ``ckv`` tiles beside the ``kr`` rows AS
+    STORED, against ``[q_lat | q_rope | 0]`` or ``[q_lat | 0 | q_rope]``:
+    one K = 640 product a half with no slice inside a row. The values are
+    the same rows' first ``r`` lanes. The order of the keys inside a block
+    is then (half, page, slot), which a softmax does not see but the mask
+    of a lane's last block must."""
+    K, N = buf.shape[:2]
+    ps, tiles, w = _geometry(buf, q_ref.shape[-1])
+    half, span = ps // 2, N * ps
+    quarters = sorted({max(1, N * k // 4) for k in (1, 2, 3, 4)})
+    lane, B = pl.program_id(0), pl.num_programs(0)
+
+    def each_page(lane, blk, slot, wait: bool):
+        """Start, or await, the copy of every page of block ``blk`` that
+        ``lane`` has. Where the block is whole no page is tested, and one
+        wait (for as many bytes as the buffer holds) awaits them all."""
+        first = blk * N
+        have = pl.cdiv(lens_ref[lane], ps) - first
+        entry = lane * width + first
+
+        def copy(p, page):
+            return pltpu.make_async_copy(pages_ref.at[page], buf.at[slot, p], sems.at[slot])
+
+        @pl.when(have >= N)
+        def _():
+            if wait:
+                # (a wait reads its descriptor's size alone)
+                pltpu.make_async_copy(buf.at[slot], buf.at[slot], sems.at[slot]).wait()
+            else:
+                # every entry read before the first copy starts: a start is a
+                # fence to the scheduler, the reads and their sums are not
+                ids = [tables_ref[entry + p] for p in range(N)]
+                for p in range(N):
+                    copy(p, ids[p]).start()
+
+        @pl.when(have < N)
+        def _():
+            for p in range(N):
+                @pl.when(p < have)
+                def _(p=p):
+                    c = copy(p, 0 if wait else tables_ref[entry + p])
+                    c.wait() if wait else c.start()
+
+    def fetch(ahead):
+        """Ask for the link the fetch cursor ``ahead`` = (lane, block,
+        slot) stands on, if there is one, and move it on a link."""
+        f_lane, f_blk, f_slot = ahead
+        pl.when(f_lane < B)(lambda: each_page(f_lane, f_blk, f_slot, False))
+        more = f_blk + 1 < pl.cdiv(lens_ref[jnp.minimum(f_lane, B - 1)], span)
+        return (jnp.where(more, f_lane, jnp.minimum(f_lane + 1, B)),
+                jnp.where(more, f_blk + 1, 0),
+                jnp.where(f_slot + 1 == K, 0, f_slot + 1))
+
+    @pl.when(lane == 0)
+    def _():
+        # A page that was never asked for is multiplied by a weight of 0:
+        # it has to hold numbers.
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+        ahead = (0, 0, 0)
+        for _ in range(K - 1):
+            ahead = fetch(ahead)
+        ring_ref[0] = 0
+        for i in range(3):
+            ring_ref[1 + i] = ahead[i]
+
+    n_tok = lens_ref[lane]
+    n_blk = pl.cdiv(n_tok, span)
+    m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def attend(i, slot, n: int, masked: bool):
+        """Block ``i`` of this lane, the first ``n`` pages of buffer
+        ``slot``, into the running max, sum and accumulator."""
+        s, v, live = [], [], []
+        for h in range(2):
+            rows = [buf[slot, pl.ds(0, n), pl.ds(j * ps + h * half, half), :].reshape(n * half, w)
+                    for j in range(tiles)]
+            kr = buf[slot, pl.ds(0, n), pl.ds(tiles * ps, half), :].reshape(n * half, w)
+            q = jnp.concatenate([q_ref[0], qr_ref[0, :, pl.ds(h * w, w)]], axis=1)
+            s_h = jax.lax.dot_general(
+                q, jnp.concatenate(rows + [kr], axis=1), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            if masked:
+                col = jax.lax.broadcasted_iota(jnp.int32, s_h.shape, 1)
+                pos = i * span + (col // half) * ps + h * half + col % half
+                live.append(pos < n_tok)
+                s_h = jnp.where(live[h], s_h, _NEG_INF)
+            s.append(s_h)
+            v.append(jnp.concatenate(rows, axis=1))
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.maximum(
+            jnp.max(s[0], axis=1, keepdims=True), jnp.max(s[1], axis=1, keepdims=True)))
+        p = [jnp.exp(s_h - m_new[:, :1]) for s_h in s]
+        if masked:
+            p = [jnp.where(live_h, p_h, 0.0) for live_h, p_h in zip(live, p)]
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + (
+            jnp.sum(p[0], axis=1, keepdims=True) + jnp.sum(p[1], axis=1, keepdims=True))
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * alpha[:, :1] + (
+            jnp.dot(p[0].astype(buf.dtype), v[0], preferred_element_type=jnp.float32)
+            + jnp.dot(p[1].astype(buf.dtype), v[1], preferred_element_type=jnp.float32))
+
+    def block(i, ring):
+        slot, *ahead = ring
+        ahead = fetch(ahead)          # into the buffer the link before this one left
+        each_page(lane, i, slot, True)
+        # A lane's last block alone is masked, and computed on as many
+        # quarters of the buffer as hold its pages.
+        have = pl.cdiv(n_tok, ps) - i * N
+        pl.when(have > N)(lambda: attend(i, slot, N, False))
+        for lo, n in zip([0, *quarters], quarters):
+            pl.when((have > lo) & (have <= n))(lambda n=n: attend(i, slot, n, True))
+        return (jnp.where(slot + 1 == K, 0, slot + 1), *ahead)
+
+    ring = jax.lax.fori_loop(0, n_blk, block, tuple(ring_ref[i] for i in range(4)))
+    for i in range(4):
+        ring_ref[i] = ring[i]
+    out_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(out_ref.dtype)
+
+
+# Pages a KV block and blocks in the ring of the kernel: constants of the
+# shape, swept on the v5e by tools/attn_decode_bench.py (PERF.md section 5,
+# PR 34); the sweep passes its own.
+_KERNEL_PAGES_PER_BLOCK = 32
+_KERNEL_BLOCKS_IN_RING = 3
+
+
+# jitted so that a program's layers, and every program of a width, share ONE
+# trace of the kernel (0.7 s each otherwise, paid in every warm start-up)
+@functools.partial(jax.jit, static_argnames=("sm_scale", "pages_per_block", "blocks_in_ring"))
+def latent_decode_pallas(
+    q_lat, q_rope, pages, kv_lens, block_tables, *, sm_scale: float,
+    pages_per_block: int = _KERNEL_PAGES_PER_BLOCK,
+    blocks_in_ring: int = _KERNEL_BLOCKS_IN_RING,
+):
+    """The absorbed decode attention as one Pallas TPU kernel over the paged
+    latent cache (:func:`_decode_kernel`): ``block_tables`` and ``kv_lens``
+    by scalar prefetch, ``pages`` left in HBM and fetched a page a DMA
+    through the block table, each cached byte read once and used in VMEM
+    for scores and values. f32 scores, running max and sum, and
+    accumulator; the weights cast to the page's dtype for the value
+    products, as :func:`latent_decode_jnp` does. No sort and no groups: a
+    lane walks its own pages, and its digits depend on them and on its
+    length alone. Needs a geometry :func:`decode_impl` accepts."""
+    B, H, r = q_lat.shape
+    w = pages.shape[-1]
+    N = max(1, min(pages_per_block, block_tables.shape[1]))
+    zeros = jnp.zeros_like(q_rope)
+    q_rope = jnp.concatenate([q_rope, zeros, zeros, q_rope], axis=-1)
+    # A table names pages of this array or the DMA engine faults.
+    block_tables = jnp.clip(block_tables, 0, pages.shape[0] - 1)
+    by_lane = lambda b, *_: (b, 0, 0)
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, sm_scale=sm_scale, width=block_tables.shape[1]),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, H, r), by_lane),
+                pl.BlockSpec((1, H, 2 * w), by_lane),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, H, r), by_lane),
+            scratch_shapes=[
+                pltpu.VMEM((blocks_in_ring, N, *pages.shape[1:]), pages.dtype),
+                pltpu.SemaphoreType.DMA((blocks_in_ring,)),
+                pltpu.SMEM((4,), jnp.int32),
+                pltpu.VMEM((H, 128), jnp.float32),
+                pltpu.VMEM((H, 128), jnp.float32),
+                pltpu.VMEM((H, r), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q_lat.shape, q_lat.dtype),
+        # The chain of copies runs from one grid step into the next. The
+        # tables were clipped above: a check of each copy's bounds is 12 of
+        # the ~20 scalar bundles a page costs.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), disable_bounds_checks=True),
+        name="latent_decode_attention_kernel",
+    )(kv_lens.astype(jnp.int32), block_tables.astype(jnp.int32).reshape(-1), q_lat, q_rope, pages)
 
 
 def latent_ragged_attention(

@@ -1,7 +1,8 @@
 """The decode-shaped attention path (PR 28): ``cu_q_lens=None`` through
 the serving entry against an independent dense softmax, that Mosaic takes
 the library kernel at the decode grid and the served shapes (compiled for
-a described v5e, no chip), and how a program's shape routes a call."""
+a described v5e, no chip; the latent model's first-party kernel with them),
+and how a program's shape routes a call."""
 
 import os
 
@@ -175,6 +176,25 @@ def test_mosaic_compiles_the_decode_grid_for_a_v5e(
         None, sds((1,), jnp.int32),
     ).compile()
     assert "ragged_paged_attention_kernel" in compiled.as_text()
+
+
+@pytest.mark.parametrize("lanes", [128, 32])
+def test_mosaic_compiles_the_latent_decode_kernel_for_a_v5e(one_chip, lanes):
+    """A.X-K1's absorbed decode call at the cell's two decode widths (64
+    heads, pages of ``[144, 128]``, a table of 128 pages): the first-party
+    kernel of ops/latent_attention.py (PR 34) at the module's constants."""
+    from dynamo_tpu.ops import latent_attention as la
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pages = sds((12289, *la.latent_page_shape(32, 512, 64)), jnp.bfloat16)
+    assert la.decode_impl("tpu", pages, 512) == "pallas"
+    compiled = jax.jit(
+        lambda *a: la.latent_decode_pallas(*a, sm_scale=192 ** -0.5)
+    ).lower(
+        sds((lanes, 64, 512), jnp.bfloat16), sds((lanes, 64, 64), jnp.bfloat16), pages,
+        sds((lanes,), jnp.int32), sds((lanes, 128), jnp.int32),
+    ).compile()
+    assert "latent_decode_attention_kernel" in compiled.as_text()
 
 
 @pytest.mark.parametrize("page_size,width,grid", [

@@ -12,13 +12,23 @@ pages in use / peak bandwidth over that time: at the cells' 32-token
 pages, what the benchmark's ``attn_decode_roofline`` computes), and the largest
 difference from the serving path's output.
 
+A LATENT shape (``axk1-128``, ``axk1-32``: A.X-K1's absorbed decode
+call, 64 heads against one 576-wide key a token, pages of ``[144, 128]``)
+runs ``ops/latent_attention.py`` instead: the serving entry first, then
+the ``jnp`` path and the first-party kernel side by side over
+``--latent-pages`` pages a KV block (and 2 / 3 / 4 blocks in its ring). Its
+microseconds are the whole call's (every device op of the program, as
+the benchmark's ``latent_attn_roofline.axk1`` counts the scope), the
+kernel's own beside them.
+
 Refuses to run without a TPU: a time from the CPU says nothing here.
 
 Usage (through the chip tool, from the repo root):
-    python -m tools.attn_decode_bench [--shapes 7b,1p5b-32,ouro] [--quick]
-                                      [--page-size 32]
-``--page-size`` re-cuts a shape's cache and tables into pages of another
-size (the worker's ``--block-size``), the same tokens in all.
+    python -m tools.attn_decode_bench [--shapes 7b,1p5b-32,ouro,axk1-128]
+                                      [--quick] [--page-size 32]
+                                      [--latent-pages 8,16,32,64]
+``--page-size`` re-cuts a dense shape's cache and tables into pages of
+another size (the worker's ``--block-size``), the same tokens in all.
 Writes ``chiprun_out/attn_decode_bench/table.json`` beside the table.
 """
 
@@ -26,6 +36,7 @@ from __future__ import annotations
 
 import argparse
 import bisect
+import functools
 import json
 import math
 import re
@@ -45,6 +56,13 @@ SHAPES = {
     "1p5b-32": (32, 12, 2, 256, 11265, ("loguniform", 160, 2560)),
     "ouro": (8, 16, 16, 64, 676, ("uniform", 128, 608)),
 }
+# lanes, heads, r, dr, page-table width, pages in one layer's array,
+# contexts: axk1-ep16-decode at its two decode widths.
+LATENT_SHAPES = {
+    "axk1-128": (128, 64, 512, 64, 128, 12289, ("uniform", 300, 2080)),
+    "axk1-32": (32, 64, 512, 64, 128, 12289, ("uniform", 300, 2080)),
+}
+LATENT_PAGES = (8, 16, 32, 64)   # pages a KV block of the kernel; main() may set it
 HEAD_DIM = 128
 PAGE_SIZE = 32   # of SHAPES' widths and page counts; main() may re-cut it
 CALLS = 8   # timed calls of each variant inside the trace
@@ -58,27 +76,45 @@ def geometry(shape: str):
             (n_pages - 1) * 32 // PAGE_SIZE + 1, contexts)
 
 
-def make_case(shape: str, seed: int):
-    import jax.numpy as jnp
-
-    lanes, n_q, n_kv, width, n_pages, (kind, lo, hi) = geometry(shape)
-    rng = np.random.RandomState(seed)
+def _contexts_and_tables(rng, lanes, width, n_pages, contexts, page_size):
+    """(context of each lane, its block table over distinct shuffled pages,
+    pages in use)."""
+    kind, lo, hi = contexts
     u = (rng.permutation(lanes) + 0.5) / lanes      # mid-point quantiles
     if kind == "uniform":
         lens = lo + u * (hi - lo)
     else:
         lens = np.exp(math.log(lo) + u * (math.log(hi) - math.log(lo)))
     lens = lens.astype(np.int32)
-    q = jnp.asarray(rng.randn(lanes, n_q, HEAD_DIM), jnp.bfloat16)
-    kv = jnp.asarray(
-        rng.randn(n_pages, PAGE_SIZE, 2 * n_kv, HEAD_DIM), jnp.bfloat16)
     tables = np.zeros((lanes, width), np.int32)
     perm, used = rng.permutation(n_pages - 1), 0
     for s, n in enumerate(lens):
-        need = -(-int(n) // PAGE_SIZE)
+        need = -(-int(n) // page_size)
         tables[s, :need] = perm[used:used + need]
         used += need
-    blocks = int(sum(-(-int(n) // PAGE_SIZE) for n in lens))
+    return lens, tables, used
+
+
+def make_case(shape: str, seed: int):
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(seed)
+    if shape in LATENT_SHAPES:
+        from dynamo_tpu.ops.latent_attention import latent_page_shape
+
+        lanes, heads, r, dr, width, n_pages, contexts = LATENT_SHAPES[shape]
+        lens, tables, blocks = _contexts_and_tables(rng, lanes, width, n_pages, contexts, 32)
+        rows, w = latent_page_shape(32, r, dr)
+        args = (jnp.asarray(rng.randn(lanes, heads, r), jnp.bfloat16),
+                jnp.asarray(rng.randn(lanes, heads, dr), jnp.bfloat16),
+                jnp.asarray(rng.randn(n_pages, rows, w), jnp.bfloat16),
+                jnp.asarray(lens), jnp.asarray(tables))
+        return args, blocks * rows * w * 2, lens
+    lanes, n_q, n_kv, width, n_pages, contexts = geometry(shape)
+    lens, tables, blocks = _contexts_and_tables(rng, lanes, width, n_pages, contexts, PAGE_SIZE)
+    q = jnp.asarray(rng.randn(lanes, n_q, HEAD_DIM), jnp.bfloat16)
+    kv = jnp.asarray(
+        rng.randn(n_pages, PAGE_SIZE, 2 * n_kv, HEAD_DIM), jnp.bfloat16)
     need_bytes = blocks * PAGE_SIZE * 2 * n_kv * HEAD_DIM * 2
     return (q, kv, jnp.asarray(lens), jnp.asarray(tables)), need_bytes, lens
 
@@ -127,8 +163,31 @@ def variants(shape: str, quick: bool):
     return out
 
 
-def kernel_times(trace_dir: str) -> dict[str, list[float]]:
-    """{program: [device ns of each attention-kernel event]}. A device op
+def latent_variants(shape: str, quick: bool):
+    """[(tag, fn(q_lat, q_rope, pages, lens, tables))]: the serving entry
+    (the kernel at the module's constants, on a TPU), the ``jnp`` path, the
+    kernel over pages a block x blocks in the ring (the serving entry's pair
+    left out: one program compiles to one executable under the first name)."""
+    from dynamo_tpu.ops import latent_attention as la
+
+    sm = (128 + LATENT_SHAPES[shape][3]) ** -0.5      # qk_nope_head_dim + qk_rope_head_dim
+    out = [("serving", functools.partial(la.latent_decode_attention, sm_scale=sm)),
+           ("jnp", functools.partial(la.latent_decode_jnp, sm_scale=sm))]
+    for pages in LATENT_PAGES:
+        for ring in (3,) if quick else (2, 3, 4):
+            if (pages, ring) != (la._KERNEL_PAGES_PER_BLOCK, la._KERNEL_BLOCKS_IN_RING):
+                out.append((f"kernel_p{pages}_r{ring}", functools.partial(
+                    la.latent_decode_pallas, sm_scale=sm, pages_per_block=pages,
+                    blocks_in_ring=ring)))
+    return out
+
+
+KERNELS = ("ragged_paged_attention", "latent_decode_attention_kernel")
+
+
+def kernel_times(trace_dir: str) -> tuple[dict[str, list[float]], dict[str, list[float]]]:
+    """({program: [device ns of each attention-kernel event]}, {program:
+    [device ns of each execution of the whole program]}). A device op
     carries no program name on this installation; the "XLA Modules" line
     does ("jit_<name>(<program id>)"), and an op belongs to the module
     whose interval holds its start."""
@@ -136,6 +195,7 @@ def kernel_times(trace_dir: str) -> dict[str, list[float]]:
 
     path = max(Path(trace_dir).rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
     out: dict[str, list[float]] = {}
+    whole: dict[str, list[float]] = {}
     for plane in ProfileData.from_file(str(path)).planes:
         if not (plane.name.startswith("/device:") and "TPU" in plane.name):
             continue
@@ -146,14 +206,16 @@ def kernel_times(trace_dir: str) -> dict[str, list[float]]:
             (e.start_ns, e.start_ns + e.duration_ns, re.sub(r"\(.*\)$", "", e.name))
             for e in lines["XLA Modules"].events)
         starts = [m[0] for m in modules]
+        for a, b, name in modules:
+            whole.setdefault(name, []).append(b - a)
         for e in lines["XLA Ops"].events:
             # The event's name is the op's HLO text, "%<name> = ...".
-            if "ragged_paged_attention" not in e.name[:120].split(" = ")[0]:
+            if not any(k in e.name[:120].split(" = ")[0] for k in KERNELS):
                 continue
             i = bisect.bisect_right(starts, e.start_ns) - 1
             if i >= 0 and e.start_ns < modules[i][1]:
                 out.setdefault(modules[i][2], []).append(e.duration_ns)
-    return out
+    return out, whole
 
 
 def bench_shape(shape: str, seed: int, quick: bool, hbm_bytes_per_s: float):
@@ -161,16 +223,25 @@ def bench_shape(shape: str, seed: int, quick: bool, hbm_bytes_per_s: float):
 
     args, need_bytes, lens = make_case(shape, seed)
     floor_us = 1e6 * need_bytes / hbm_bytes_per_s
-    geo = geometry(shape)
-    print(f"## {shape}: lanes {geo[0]}, heads {geo[1]}/{geo[2]}, page size "
-          f"{PAGE_SIZE}, table width {geo[3]}, pages {geo[4]}, contexts "
+    latent = shape in LATENT_SHAPES
+    if latent:
+        lanes, heads, r, dr, width, n_pages, _ = LATENT_SHAPES[shape]
+        said = (f"lanes {lanes}, heads {heads} against one key of {r} + {dr}, "
+                f"page size 32, table width {width}, pages {n_pages}")
+    else:
+        geo = geometry(shape)
+        said = (f"lanes {geo[0]}, heads {geo[1]}/{geo[2]}, page size "
+                f"{PAGE_SIZE}, table width {geo[3]}, pages {geo[4]}")
+    print(f"## {shape}: {said}, contexts "
           f"{int(lens.min())}-{int(lens.max())} "
           f"(mean {lens.mean():.0f}); blocks in use {need_bytes / 1e6:.2f} MB "
           f"= {floor_us:.1f} us at the HBM's peak", flush=True)
     ready, rows, base = [], [], None
-    for tag, fn in variants(shape, quick):
-        fn.__name__ = f"{shape}_{tag}".replace("-", "_").replace(" ", "_")
-        jitted = jax.jit(fn)
+    for tag, fn in (latent_variants if latent else variants)(shape, quick):
+        def program(*a, _fn=fn):
+            return _fn(*a)
+        program.__name__ = f"{shape}_{tag}".replace("-", "_").replace(" ", "_")
+        jitted = jax.jit(program)
         t0 = time.perf_counter()
         try:
             out = np.asarray(jax.block_until_ready(jitted(*args)), np.float32)
@@ -189,11 +260,34 @@ def bench_shape(shape: str, seed: int, quick: bool, hbm_bytes_per_s: float):
                 out = jitted(*args)
             jax.block_until_ready(out)
         jax.profiler.stop_trace()
-        times = kernel_times(trace_dir)
+        times, whole = kernel_times(trace_dir)
     print(f"# trace: kernel events under {len(times)} programs, e.g. "
           f"{sorted(times)[:2]}", flush=True)
     for tag, jitted, diff, compile_s in ready:
         ns = times.get(f"jit_{jitted.__name__}", [])
+        if latent:
+            # The whole call is the measure (the jnp path IS many ops); the
+            # kernel's own events beside it where there is a kernel.
+            call = whole.get(f"jit_{jitted.__name__}", [])
+            if not call:
+                rows.append({"shape": shape, "variant": tag, "error": "no program event"})
+                print(f"{tag:16s} no execution of jit_{jitted.__name__} in the trace",
+                      flush=True)
+                continue
+            us = sum(call) / len(call) / 1e3
+            kernel_us = sum(ns) / len(ns) / 1e3 if ns else None
+            rows.append({
+                "shape": shape, "variant": tag, "call_us": us,
+                "call_us_min": min(call) / 1e3, "calls": len(call),
+                "kernel_us": kernel_us, "roofline_share": 100.0 * floor_us / us,
+                "max_abs_diff_vs_serving": diff, "compile_run_s": compile_s,
+            })
+            print(f"{tag:16s} {us:9.1f} us a call (min {min(call) / 1e3:8.1f}, "
+                  f"{len(call)} calls; the kernel alone "
+                  + (f"{kernel_us:8.1f}" if ns else "     none")
+                  + f")  {100.0 * floor_us / us:5.1f}% of the HBM roofline  "
+                  f"max|diff| {diff:.4f}", flush=True)
+            continue
         if not ns:
             rows.append({"shape": shape, "variant": tag, "error": "no kernel event"})
             print(f"{tag:16s} no kernel event under jit_{jitted.__name__}; the "
@@ -213,16 +307,19 @@ def bench_shape(shape: str, seed: int, quick: bool, hbm_bytes_per_s: float):
 
 
 def main() -> int:
-    global PAGE_SIZE
+    global PAGE_SIZE, LATENT_PAGES
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--shapes", default=",".join([*SHAPES, *LATENT_SHAPES]))
     ap.add_argument("--seed", type=int, default=2147483659)
     ap.add_argument("--page-size", type=int, default=PAGE_SIZE,
                     choices=(8, 16, 32, 64, 128, 256))
     ap.add_argument("--quick", action="store_true",
                     help="a few grids only (a smoke run of the tool)")
+    ap.add_argument("--latent-pages", default=",".join(map(str, LATENT_PAGES)),
+                    help="pages a KV block of the latent kernel, swept")
     args = ap.parse_args()
     PAGE_SIZE = args.page_size
+    LATENT_PAGES = tuple(int(n) for n in args.latent_pages.split(","))
 
     from dynamo_tpu.device import device_info, device_peaks, enable_compile_cache
 
