@@ -2,6 +2,7 @@
 
     python chip_smoke.py              # one TPU chip: qwen2-7b, int8 weights
     python chip_smoke.py --cpu-tiny   # rehearsal on the CPU, `tiny` preset
+    python chip_smoke.py --cpu-tiny --cpu-preset tiny-lfm2   # the same, a hybrid model
 
 Starts the three processes a user starts (README "Run it"): the control-
 plane store, the JAX worker and the OpenAI frontend with the KV router.
@@ -299,6 +300,13 @@ def serve_phase(mode: str, inject: str | None, report: dict) -> None:
             env,
         )
         worker_flags = list(MODES[mode])
+        if mode == "cpu-tiny":   # `tiny`, or a hybrid model (conv state pages, paired heads)
+            worker_flags = ["--preset", report.get("cpu_preset", "tiny")]
+            if worker_flags[1] != "tiny":
+                # 128-wide paired heads: the CPU's reference attention gathers
+                # [T, span, heads, 128] in float32, 137 GB at the default 8,192
+                worker_flags += ["--max-model-len", "512", "--num-kv-blocks", "256",
+                                 "--max-num-seqs", "8"]
         if inject == "worker-start":
             worker_flags += ["--preset", "no-such-preset"]
         workers = []
@@ -703,6 +711,37 @@ def kernel_check_child(corrupt: bool) -> int:
                   *case, None, jnp.asarray([4], jnp.int32), sm_scale=sm),
               lambda: decode_ref(*case))
 
+    # (i-c) 64-wide heads cached in pairs (a model of head width 64 and an
+    # even number of KV heads: LFM2's 32/8 heads): the serving entry for
+    # them, against the per-head reference on pages of 64-wide heads that
+    # hold the same K and V. On a TPU the pair gets the library kernel.
+    from dynamo_tpu.ops.ragged_attention import paired_heads_attention
+
+    def paired_case(lanes, heads, kv_heads, ps, width, pages, lo, hi):
+        q, kv, lens, tables = decode_case(lanes, heads, kv_heads // 2, ps, width, pages, lo, hi)
+        half = d // 2        # kv [pages, ps, 2 x kv_heads / 2, 128] = pairs, K even V odd
+        plain = kv.reshape(pages, ps, kv_heads // 2, 2, 2, half).transpose(
+            0, 1, 2, 4, 3, 5).reshape(pages, ps, 2 * kv_heads, half)
+        return q[..., :half], kv, lens, tables, plain
+
+    def paired_ref(q, _, lens, tables, plain):
+        one, ps = jnp.asarray([1], jnp.int32), plain.shape[1]
+        return jnp.concatenate([
+            ragged_paged_attention_ref(
+                q[s_:s_ + 1], plain, lens[s_:s_ + 1],
+                tables[s_:s_ + 1, :-(-int(lens[s_]) // ps)],
+                jnp.asarray([0, 1], jnp.int32), one, sm_scale=(d // 2) ** -0.5)
+            for s_ in range(q.shape[0])])
+
+    geometry = (128, 32, 8, 32, 128, 4097, 300, 2800) if on_tpu else (4, 4, 2, 8, 4, 17, 2, 32)
+    case = paired_case(*geometry)
+    lanes_ = jnp.asarray([geometry[0]], jnp.int32)
+    check(f"paired 64-wide heads, decode shape: {geometry[0]} lanes, "
+          f"{geometry[1]}/{geometry[2]} heads",
+          lambda: jax.jit(lambda q, kv, lens, tables: paired_heads_attention(
+              q, kv, lens, tables, None, lanes_, sm_scale=(d // 2) ** -0.5))(*case[:4]),
+          lambda: paired_ref(*case))
+
     for c in checks:
         print(f"  {'ok  ' if c['ok'] else 'FAIL'} {c}", file=sys.stderr, flush=True)
     print(json.dumps({"device": info, "atol": ATOL, "rtol": RTOL, "checks": checks}))
@@ -723,6 +762,9 @@ def main() -> int:
                        help="two one-chip workers: --role prefill and --role decode")
     which.add_argument("--kernel-check-child", action="store_true",
                        help=argparse.SUPPRESS)
+    ap.add_argument("--cpu-preset", choices=["tiny", "tiny-lfm2"], default="tiny",
+                    help="what --cpu-tiny serves: the dense tiny preset, or the "
+                         "hybrid one (conv layers beside paired 64-wide heads)")
     ap.add_argument("--corrupt", action="store_true", help=argparse.SUPPRESS)
     ap.add_argument("--inject", choices=["worker-start", "bad-request",
                                          "kernel-mismatch"], default=None,
@@ -742,6 +784,8 @@ def main() -> int:
     OUT.mkdir(exist_ok=True)
 
     report: dict = {"mode": mode}
+    if mode == "cpu-tiny":
+        report["cpu_preset"] = args.cpu_preset
     failures: list[str] = []
     phases = [("serve", serve_phase)]
     if mode in ("chip", "cpu-tiny"):  # the kernels are the same on four chips

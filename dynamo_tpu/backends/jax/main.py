@@ -346,6 +346,13 @@ def build_engine(
                 buckets = (dp * max(1, engine_cfg.decode_buckets[-1] // dp),)
             engine_cfg = dataclasses.replace(engine_cfg, decode_buckets=buckets)
     params = loaded_params
+    if quant and model_cfg.hybrid:
+        from dynamo_tpu.engine.config import UnsupportedModelOption
+
+        raise UnsupportedModelOption(
+            "quant", model_cfg.name,
+            "conv operators and experts are served unquantised (no int8 init for them)",
+        )
     if quant == "int8":
         import jax
 
@@ -524,8 +531,20 @@ async def run_jax_worker(
     )
     startup["engine_loop"] = "pipelined" if core.pipelined else "synchronous"
     log.info("engine loop: %s", startup["engine_loop"])
+    if core.cfg.hybrid and role != "aggregated":
+        # Refused at start-up, by name: a hybrid cache's blocks do not
+        # leave the device (EngineCore.kv_page_shape).
+        from dynamo_tpu.engine.config import UnsupportedModelOption
+
+        raise UnsupportedModelOption(
+            "disagg", core.cfg.name,
+            f"role={role!r} hands blocks to a peer; one block holds pages of "
+            "two shapes and [planes, *page] carries one",
+        )
     startup["attention"] = core.cfg.attention
     startup["kv_bytes_per_token"] = core.kv_bytes_per_token
+    startup["cache_layers"] = core.cfg.cache_layer_counts
+    startup["state_bytes_per_block"] = core.cfg.state_bytes_per_block()
     if core.cfg.shared_sparse:
         startup["experts_held"] = list(core.cfg.experts_held_range)
     log.info(
@@ -927,18 +946,28 @@ async def run_jax_worker(
                 yield out
 
     else:
-        await _serve_kv_fetch(runtime, namespace, component, core)
-        fetch_client = await (
-            runtime.namespace(namespace).component(component).endpoint("kv_fetch").client()
-        )
-        peer_kv = PeerKvClient(core, fetch_client)
-        _peer_clients.append(peer_kv)
+        peer_kv = None
+        if core.cfg.hybrid:
+            # No kv_fetch endpoint and no peer client (option "peer_kv"):
+            # a hybrid cache's blocks do not leave the device, so a
+            # router's peer hint is left unanswered and the prefix is
+            # computed here.
+            log.info("peer KV pulls are not carried for %r: kv_fetch not "
+                     "served, peer_prefix hints ignored", core.cfg.name)
+        else:
+            await _serve_kv_fetch(runtime, namespace, component, core)
+            fetch_client = await (
+                runtime.namespace(namespace).component(component).endpoint("kv_fetch").client()
+            )
+            peer_kv = PeerKvClient(core, fetch_client)
+            _peer_clients.append(peer_kv)
 
         async def handler(request: Any, context: Context) -> AsyncIterator[Any]:
             await _resolve_mm(core, encode_client, embed_fetch_client, request)
             hint = (request.get("kv_transfer_params") or {}).get("peer_prefix")
             if (
                 hint
+                and peer_kv is not None
                 and hint.get("worker_id") != worker_id
                 and request.get("token_ids")
             ):
@@ -1325,11 +1354,9 @@ def _first_token_finish(core, stop: StopConditions, token: int) -> str | None:
 def main() -> None:
     ap = argparse.ArgumentParser(description="dynamo-tpu JAX engine worker")
     ap.add_argument("--model-name", default="tiny")
-    ap.add_argument(
-        "--preset", default="tiny",
-        choices=["tiny", "tiny-moe", "tiny-loop", "llama3-1b", "llama3-8b",
-                 "llama3-70b", "qwen2-7b", "mixtral-8x7b", "ouro-2.6b"],
-    )
+    from dynamo_tpu.engine.config import PRESETS
+
+    ap.add_argument("--preset", default="tiny", choices=sorted(PRESETS))
     ap.add_argument("--namespace", default="dynamo")
     ap.add_argument("--component", default=None, help="defaults by role")
     ap.add_argument("--tokenizer", default=None,

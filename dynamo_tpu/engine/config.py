@@ -13,6 +13,9 @@ from dataclasses import dataclass, field
 
 import jax.numpy as jnp
 
+# What ModelConfig.layer_types may name, as published.
+LAYER_KINDS = ("conv", "full_attention")
+
 _DTYPES = {
     "bfloat16": jnp.bfloat16,
     "float32": jnp.float32,
@@ -23,7 +26,8 @@ _DTYPES = {
 class UnsupportedModelOption(NotImplementedError):
     """An engine option that this model's cache page or layers do not
     carry, refused at start-up. ``option`` names it (``kv_dtype``, ``tp``,
-    ``pp``, ``ring_prefill``, ``spec_decode``)."""
+    ``pp``, ``ring_prefill``, ``spec_decode``, ``host_kv_blocks``,
+    ``disk_kv_dir``, ``disagg``, ``peer_kv``, ``quant``)."""
 
     def __init__(self, option: str, model: str, why: str):
         super().__init__(f"{option} is not carried for model {model!r}: {why}")
@@ -121,6 +125,29 @@ class ModelConfig:
     # stays num_experts wide; only the held experts' terms are added, and
     # none is dropped (moe_capacity_factor has no say). None: all held.
     experts_held: tuple[int, int] | None = None
+    # A learned bias per expert ADDED TO THE SCORES THE CHOICE IS MADE BY
+    # and to nothing else: the chosen experts' weights are their plain
+    # sigmoid scores (``moe.expert_bias`` [sparse layers, E], float32).
+    router_bias: bool = False
+    # What the chosen scores' sum is raised by before the division.
+    router_norm_eps: float = 1e-20
+    # -- layers of two kinds (LFM2): gated short convolutions among GQA ------
+    # One entry a layer, "conv" or "full_attention" (the published
+    # names); None: every layer is attention. A conv layer keeps NO keys
+    # or values: per sequence it needs the conv_L_cache - 1 newest rows of
+    # its gated input ``u``, whatever the context (:meth:`kv_page_tail`).
+    layer_types: tuple[str, ...] | None = None
+    conv_L_cache: int = 0
+    conv_bias: bool = False
+    # RMSNorm over each head's values of q and of k (weights
+    # ``q_layernorm`` / ``k_layernorm`` [head_dim]) BEFORE rope.
+    qk_norm: bool = False
+    # Cache 64-wide KV heads two to a 128-wide row where the geometry
+    # allows (:attr:`kv_head_pairs`). The engine clears it for a dense
+    # model served with an option the pair does not carry (a mesh, int8
+    # pages), which then keeps the page and the options it had
+    # (core._unpaired_where_not_carried).
+    kv_pairing: bool = True
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
@@ -129,7 +156,10 @@ class ModelConfig:
             )
         if self.experts_held is not None:
             object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
         self._check_latent_sparse()
+        self._check_hybrid()
         if self.ut_steps < 1:
             raise ValueError(f"ut_steps={self.ut_steps} must be >= 1")
         if self.ut_steps > 1 and self.early_exit_threshold < 1.0:
@@ -174,6 +204,7 @@ class ModelConfig:
             "first_dense_layers": 0, "moe_intermediate_size": 0, "n_group": 1,
             "topk_group": 1, "routed_scaling_factor": 1.0,
             "num_shared_experts": 0, "experts_held": None,
+            "router_bias": False, "router_norm_eps": 1e-20,
         }
         if not self.shared_sparse:
             stray = [f for f, d in sparse_only.items() if getattr(self, f) != d]
@@ -218,9 +249,69 @@ class ModelConfig:
                     f"num_experts={E} and rank lie in 0..of-1"
                 )
 
+    def _check_hybrid(self) -> None:
+        """``layer_types`` and what only a conv layer reads: a field that
+        does not apply, or a combination no program was compared for,
+        raises by name."""
+        if self.qk_norm and self.latent:
+            raise ValueError("qk_norm set with attention='mla': the latent "
+                             "attention has norms of its own")
+        if self.layer_types is None:
+            stray = [f for f, d in (("conv_L_cache", 0), ("conv_bias", False))
+                     if getattr(self, f) != d]
+            if stray:
+                raise ValueError(f"{stray} set without layer_types: only a conv layer reads them")
+            return
+        kinds = set(self.layer_types)
+        if len(self.layer_types) != self.num_layers or not kinds <= set(LAYER_KINDS):
+            raise ValueError(
+                f"layer_types must name each of the {self.num_layers} layers "
+                f"one of {LAYER_KINDS}; got {self.layer_types}"
+            )
+        if "conv" not in kinds:
+            return
+        if self.conv_L_cache < 2:
+            raise ValueError(f"conv_L_cache={self.conv_L_cache}: a conv layer needs >= 2 taps")
+        if self.conv_bias:
+            raise NotImplementedError("conv_bias=True is not implemented")
+        if self.latent or self.ut_steps > 1 or self.sandwich_norm or self.attn_qkv_bias:
+            raise NotImplementedError(
+                "conv layers with attention='mla', ut_steps > 1, sandwich_norm "
+                "or attn_qkv_bias are not implemented"
+            )
+        if self.is_moe and not self.shared_sparse:
+            raise NotImplementedError(
+                "conv layers beside the softmax-routed (mixtral) MLP are not implemented"
+            )
+
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def hybrid(self) -> bool:
+        """Some layers keep a rolling convolution state and no K/V."""
+        return self.layer_types is not None and "conv" in self.layer_types
+
+    def layer_kind(self, l: int) -> str:
+        """What layer ``l`` caches: "attention" (pages of K/V, or latent
+        rows) or "conv" (state pages)."""
+        if self.layer_types is None or self.layer_types[l] != "conv":
+            return "attention"
+        return "conv"
+
+    def layers_of(self, kind: str) -> tuple[int, ...]:
+        return tuple(l for l in range(self.num_layers) if self.layer_kind(l) == kind)
+
+    @property
+    def kv_head_pairs(self) -> bool:
+        """KV heads cached two to a 128-wide row (``[k_2j | k_2j+1]``,
+        ``[v_2j | v_2j+1]``), so that 64-wide heads keep their published
+        cache bytes AND run the attention kernel, whose lanes are 128
+        (ops/ragged_attention.py, :func:`paired_heads_attention`). From the
+        geometry alone, unless :attr:`kv_pairing` was cleared."""
+        return (self.kv_pairing and not self.latent and self.head_dim == 64
+                and self.num_kv_heads % 2 == 0)
 
     @property
     def shared_sparse(self) -> bool:
@@ -246,19 +337,54 @@ class ModelConfig:
         lo, hi = self.experts_held_range
         return hi - lo
 
-    def kv_page_tail(self, block_size: int) -> tuple[int, ...]:
+    def kv_page_tail(self, block_size: int, kind: str = "attention") -> tuple[int, ...]:
         """Trailing shape of one page of ``block_size`` tokens in one
-        layer's plane: ``(block_size, 2 * num_kv_heads, head_dim)`` (K
-        even, V odd) or, latent, the ``(rows, lanes)`` that hold
-        ``block_size x (kv_lora_rank + qk_rope_head_dim)`` values
-        (ops/latent_attention.py, "The page"). THE one place the page
-        shape comes from (model.init_cache, the engine's page movers,
-        descriptors and /health)."""
+        layer's plane, by the layer's kind (:meth:`layer_kind`).
+        "attention": ``(block_size, 2 * num_kv_heads, head_dim)`` (K even,
+        V odd); with :attr:`kv_head_pairs` ``(block_size, num_kv_heads, 2
+        * head_dim)``, the same bytes with two heads a row; latent, the
+        ``(rows, lanes)`` that hold ``block_size x (kv_lora_rank +
+        qk_rope_head_dim)`` values (ops/latent_attention.py, "The page").
+        "conv": ``(conv_L_cache - 1, h / 128, 128)``, the newest rows of
+        ``u`` written in the block, each in whole 128-lane rows (a ``[2,
+        h]`` tail would pad its 2 sublanes to a tile's 16); whatever
+        ``block_size``, which only has to be a multiple of the slots. THE
+        one place a page shape comes from (model.init_cache, the engine's
+        page movers, descriptors and /health)."""
+        if kind == "conv":
+            slots, h = self.conv_L_cache - 1, self.hidden_size
+            if block_size % slots:
+                raise ValueError(
+                    f"block_size={block_size} must be a multiple of "
+                    f"conv_L_cache - 1 = {slots}: a position's slot is "
+                    "position % slots in every block"
+                )
+            return (slots, h // 128, 128) if h % 128 == 0 else (slots, 1, h)
         if self.latent:
             from dynamo_tpu.ops.latent_attention import latent_page_shape
 
             return latent_page_shape(block_size, self.kv_lora_rank, self.qk_rope_head_dim)
+        if self.kv_head_pairs:
+            return (block_size, self.num_kv_heads, 2 * self.head_dim)
         return (block_size, 2 * self.num_kv_heads, self.head_dim)
+
+    def cache_layers(self, kind: str) -> int:
+        """Page arrays of one kind (``"attention"`` planes count a looped
+        model's passes): THE count beside :meth:`kv_page_tail`."""
+        return len(self.layers_of(kind)) * self.ut_steps
+
+    @property
+    def cache_layer_counts(self) -> dict[str, int]:
+        """``{"attention": n, "conv": n}``, as /health and /metrics give it."""
+        return {kind: self.cache_layers(kind) for kind in ("attention", "conv")}
+
+    def state_bytes_per_block(self) -> int:
+        """Bytes of convolution state one block holds over all conv
+        layers (0 for a model without them)."""
+        if not self.hybrid:
+            return 0
+        return (self.cache_layers("conv") * (self.conv_L_cache - 1)
+                * self.hidden_size * jnp.dtype(self.jax_dtype).itemsize)
 
     @property
     def kv_unit_values(self) -> int:
@@ -269,10 +395,12 @@ class ModelConfig:
 
     @property
     def num_cache_layers(self) -> int:
-        """Planes of K/V a token holds: one per (pass, weight layer), in
+        """Planes of K/V a token holds: one per (pass, attention layer), in
         slot order ``u * num_layers + l`` wherever a block leaves the
-        device. Equals num_layers for every single-pass model."""
-        return self.num_layers * self.ut_steps
+        device. Equals num_layers for every single-pass model whose
+        layers are all attention; a conv layer holds none
+        (:meth:`cache_layers`)."""
+        return self.cache_layers("attention")
 
     @property
     def jax_dtype(self):
@@ -297,7 +425,14 @@ class ModelConfig:
             return (h * rq + rq + rq * H * (dn + dr)       # wq_a, q_norm, wq_b
                     + h * (rkv + dr) + rkv                 # wkv_a, kv_norm
                     + rkv * H * (dn + dv) + H * dv * h)    # wkv_b, wo
-        return h * (self.q_size + 2 * self.kv_size) + self.q_size * h
+        qk_norms = 2 * self.head_dim if self.qk_norm else 0
+        return h * (self.q_size + 2 * self.kv_size) + self.q_size * h + qk_norms
+
+    def _conv_params(self) -> int:
+        """One conv layer's operator: ``in_proj [h, 3h]``, the depthwise
+        taps ``[conv_L_cache, h]`` and ``out_proj [h, h]``."""
+        h = self.hidden_size
+        return 3 * h * h + self.conv_L_cache * h + h * h
 
     def _mlp_params(self) -> int:
         """All layers' MLP weights as HELD here: a dense SwiGLU, the
@@ -308,6 +443,7 @@ class ModelConfig:
         if self.shared_sparse:
             im = self.moe_intermediate_size
             sparse = (h * self.num_experts
+                      + (self.num_experts if self.router_bias else 0)
                       + (self.num_experts_held + self.num_shared_experts) * 3 * h * im)
             return (self.first_dense_layers * 3 * h * i
                     + (L - self.first_dense_layers) * sparse)
@@ -320,8 +456,10 @@ class ModelConfig:
         chip holds (``experts_held``)."""
         h, v = self.hidden_size, self.vocab_size
         norms = (4 if self.sandwich_norm else 2) * h
+        n_conv = len(self.layers_of("conv"))
         total = (
-            v * h + self.num_layers * (self._attn_params() + norms)
+            v * h + (self.num_layers - n_conv) * self._attn_params()
+            + n_conv * self._conv_params() + self.num_layers * norms
             + self._mlp_params() + h + (0 if self.tie_embeddings else h * v)
         )
         if self.ut_steps > 1:
@@ -335,10 +473,10 @@ class ModelConfig:
         embeddings/norms at the model dtype). Sparse and latent models are
         served unquantised (model.init_params_quantized raises for them),
         so there is nothing to count."""
-        if self.is_moe or self.latent:
+        if self.is_moe or self.latent or self.hybrid:
             raise NotImplementedError(
-                f"int8 weights for {self.name!r}: experts and latent "
-                "projections are served unquantised"
+                f"int8 weights for {self.name!r}: experts, latent projections "
+                "and conv operators are served unquantised"
             )
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         proj_per_layer = (
@@ -748,6 +886,72 @@ def tiny_axk1(vocab_size: int = 384, experts_held=(0, 4)) -> ModelConfig:
     )
 
 
+_LFM2_PERIOD = ("full_attention", "conv", "conv", "conv")
+
+
+def lfm2_24b_a2b_10l() -> ModelConfig:
+    """LFM2-24B-A2B (LiquidAI, model_type "lfm2_moe") as stage 0 of a
+    four-stage pipeline holds it: layers 0-9 of the 40 whole (two dense
+    conv layers, then two periods of attention-conv-conv-conv with all
+    64 bias-chosen experts of width 1536, 4 a token), the whole
+    vocabulary, tied embeddings. 32 query heads on 8 KV heads of width
+    64, QK-norm, three-tap gated convolutions. 10.53 GB in bf16."""
+    return ModelConfig(
+        name="lfm2-24b-a2b-10l",
+        vocab_size=65536,
+        hidden_size=2048,
+        intermediate_size=11776,
+        num_layers=10,
+        num_heads=32,
+        num_kv_heads=8,
+        head_dim=64,
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-5,
+        tie_embeddings=True,
+        layer_types=("conv", "conv") + 2 * _LFM2_PERIOD,
+        conv_L_cache=3,
+        qk_norm=True,
+        first_dense_layers=2,
+        moe_intermediate_size=1536,
+        num_experts=64,
+        num_experts_per_tok=4,
+        router_scoring="sigmoid",
+        router_bias=True,
+        router_norm_eps=1e-6,
+    )
+
+
+def tiny_lfm2(vocab_size: int = 384) -> ModelConfig:
+    """LFM2's shape at test size: two dense conv layers, then
+    attention-conv-conv-conv with 8 bias-chosen experts, 2 a token; 4
+    query heads on 2 KV heads of width 64 (so that the heads are cached
+    in pairs, as at the published width)."""
+    return ModelConfig(
+        name="tiny-lfm2",
+        vocab_size=vocab_size,
+        hidden_size=256,
+        intermediate_size=320,
+        num_layers=6,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=64,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-5,
+        dtype="float32",
+        tie_embeddings=True,
+        layer_types=("conv", "conv") + _LFM2_PERIOD,
+        conv_L_cache=3,
+        qk_norm=True,
+        first_dense_layers=2,
+        moe_intermediate_size=64,
+        num_experts=8,
+        num_experts_per_tok=2,
+        router_scoring="sigmoid",
+        router_bias=True,
+        router_norm_eps=1e-6,
+    )
+
+
 def tiny_loop(vocab_size: int = 384) -> ModelConfig:
     """The looped stack (Ouro's shape) at test size: 3 layers x 3 passes."""
     return ModelConfig(
@@ -805,8 +1009,10 @@ PRESETS = {
     "mixtral-8x7b": mixtral_8x7b,
     "ouro-2.6b": ouro_2_6b,
     "a.x-k1-ep16": axk1_ep16,
+    "lfm2-24b-a2b-10l": lfm2_24b_a2b_10l,
     "tiny": tiny_model,
     "tiny-moe": tiny_moe,
     "tiny-loop": tiny_loop,
     "tiny-axk1": tiny_axk1,
+    "tiny-lfm2": tiny_lfm2,
 }

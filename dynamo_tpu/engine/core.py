@@ -26,6 +26,7 @@ Design notes:
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 import threading
 import time
@@ -159,28 +160,63 @@ class Sequence:
         return self.processed
 
 
+_TWO_SHAPES = "one block holds pages of two shapes; [planes, *page] carries one"
+
+
+def _unpaired_where_not_carried(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh):
+    """``model_cfg``, with ``kv_pairing`` cleared where a DENSE model of
+    64-wide KV heads is served with an option the paired page does not
+    carry: a mesh of any kind (tp shards KV heads one by one; a stage's
+    and a ring's programs were never compared with pairs) or int8 pages
+    (a scale per slot and KV head). Such a model keeps those options on
+    the page it had before there were pairs, ``(block_size, 2 n_kv, 64)``,
+    and the attention a 64-wide head gets there. A hybrid model has no
+    unpaired path that was ever compared: it refuses them by name
+    (:func:`_refuse_uncarried_options`)."""
+    engaged = (mesh is not None or sp_mesh is not None or pp_mesh is not None
+               or engine_cfg.ring_prefill_threshold > 0 or engine_cfg.kv_quantized)
+    if model_cfg.kv_head_pairs and not model_cfg.hybrid and engaged:
+        return dataclasses.replace(model_cfg, kv_pairing=False)
+    return model_cfg
+
+
 def _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh) -> None:
     """A model with latent attention or the sigmoid-routed sparse MLP
-    runs on ONE chip's programs with a plain latent page. Every option
-    that page, or that layer, does not carry is refused here, at start-up
-    and by name (:class:`UnsupportedModelOption`), not at the first
-    request that meets it. Carried: the prefix cache, preemption and
-    recompute, the host and disk tiers, the disagg payload, peer pulls,
-    embeddings, both schedulers, the megastep."""
-    if not (model_cfg.latent or model_cfg.shared_sparse):
+    runs on ONE chip's programs with a plain latent page; one with conv
+    layers (``model_cfg.hybrid``) keeps pages of two shapes, its 64-wide
+    KV heads in pairs, and a rolling state that does not forgive a write
+    past the cursor (model.conv_layer, "The invariant"). Every option
+    that page, or that layer, does not carry is refused here, at
+    start-up and by name (:class:`UnsupportedModelOption`), not at the
+    first request that meets it. Carried by all: the prefix cache,
+    preemption and recompute, embeddings, both schedulers, the megastep;
+    by the latent page also the host and disk tiers, the disagg payload
+    and peer pulls, which a hybrid cache refuses (a block that leaves the
+    device is ``[planes, *page]`` of ONE shape:
+    ``EngineCore.kv_page_shape``)."""
+    hybrid = model_cfg.hybrid
+    # one chip's programs: the layers no mesh rule, stage body or verify row knows
+    if not (model_cfg.latent or model_cfg.shared_sparse or hybrid):
         return
     refused = {
-        "kv_dtype": engine_cfg.kv_quantized and model_cfg.latent
-        and "int8 pages keep a scale per slot and KV head; a latent page has no heads",
+        "kv_dtype": engine_cfg.kv_quantized and (model_cfg.latent or hybrid) and (
+            "int8 pages keep a scale per slot and KV head; "
+            + ("a latent page has no heads" if model_cfg.latent else
+               "conv state pages and paired heads have no such scale")),
+        "host_kv_blocks": hybrid and engine_cfg.host_kv_blocks > 0 and _TWO_SHAPES,
+        "disk_kv_dir": hybrid and bool(engine_cfg.disk_kv_dir) and _TWO_SHAPES,
         "tp": mesh is not None
-        and "no sharding rule for the latent projections or the held experts "
-            "(a share is stated with experts_held, not with a mesh)",
+        and "no sharding rule for the latent projections, the held experts (a "
+            "share is stated with experts_held, not with a mesh), conv "
+            "operators or paired KV heads",
         "pp": pp_mesh is not None
         and "the pipeline's stage body is the dense layer's",
         "ring_prefill": (sp_mesh is not None or engine_cfg.ring_prefill_threshold > 0)
         and "ring attention reads expanded K and V per head",
-        "spec_decode": engine_cfg.spec_decode != "off"
-        and "verify rows were not compared with the reference for this model",
+        "spec_decode": engine_cfg.spec_decode != "off" and (
+            "a rejected draft has already overwritten the convolution's rolling "
+            "state past the cursor the lane goes on from" if hybrid else
+            "verify rows were not compared with the reference for this model"),
     }
     for option, why in refused.items():
         if why:
@@ -961,6 +997,7 @@ class EngineCore:
         pipeline parallelism instead: layer-staged GPipe prefill waves and
         wavefront decode chains (parallel/pipeline.py)."""
         bs = engine_cfg.block_size
+        model_cfg = _unpaired_where_not_carried(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh)
         _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh)
         for b in engine_cfg.prefill_buckets:
             if b % bs:
@@ -1113,6 +1150,14 @@ class EngineCore:
         # tokens of every dispatch.
         self.expert_stats = {
             phase: np.zeros(4, np.int64) for phase in ("decode", "prefill")
+        }
+        # Where the rows of a model with conv layers found the state they
+        # read (model.conv_layer), counted at dispatch on the host: the
+        # pages written by an earlier iteration of the same megastep, by
+        # the sequence's own previous dispatch, or by whoever filled a
+        # shared block (a prefix hit; a resume after preemption).
+        self.conv_state_reads = {
+            source: 0 for source in ("same_step", "earlier_dispatch", "prefix_hit")
         }
         self.cfg = model_cfg
         self.engine = engine_cfg
@@ -2093,12 +2138,20 @@ class EngineCore:
             return "int8"
         return np.dtype(self.cfg.jax_dtype).name
 
+    def _refuse_leaving_the_device(self, option: str) -> None:
+        """A block of a hybrid cache (K/V pages beside conv state pages)
+        does not leave the device: every way out names its option."""
+        if self.cfg.hybrid:
+            raise UnsupportedModelOption(option, self.cfg.name, _TWO_SHAPES)
+
     @property
     def kv_page_shape(self) -> tuple[int, ...]:
         """One block as it leaves the device (host and disk tiers, the
         disagg payload, peer pulls): ``[planes, *page]`` with the page of
         ``ModelConfig.kv_page_tail``: ``(block_size, 2 kv, d)``, or the
-        latent page's ``(rows, lanes)``."""
+        latent page's ``(rows, lanes)``. A hybrid cache has no such shape
+        and refuses."""
+        self._refuse_leaving_the_device("disagg")
         return (
             self.cfg.num_cache_layers,
             *self.cfg.kv_page_tail(self.engine.block_size),
@@ -2110,7 +2163,7 @@ class EngineCore:
         int8 cache included."""
         from dynamo_tpu.engine.kv_quant import kv_page_bytes
 
-        if self.cfg.latent:
+        if self.cfg.latent or self.cfg.hybrid:
             return (self.cfg.num_cache_layers * self.cfg.kv_unit_values
                     * np.dtype(self.cfg.jax_dtype).itemsize)
         return kv_page_bytes(
@@ -2286,6 +2339,11 @@ class EngineCore:
             feed_idx = np.full(T, -1, np.int32)
         for i, (seq, toks_list, pos0, kv_len) in enumerate(rows):
             chunk = len(toks_list)
+            if self.cfg.hybrid and pos0 > 0:
+                # a (re)admitted sequence's first rows start at its hit
+                self.conv_state_reads[
+                    "prefix_hit" if pos0 == seq.num_cached_tokens else "earlier_dispatch"
+                ] += 1
             pos = np.arange(pos0, pos0 + chunk, dtype=np.int32)
             tokens[t : t + chunk] = toks_list
             positions[t : t + chunk] = pos
@@ -2552,6 +2610,8 @@ class EngineCore:
         b = self._assemble_ragged(rows, S, n_sample, feed_rows, force_R=use_dd)
         self.exec_stats["decode_live_lanes"] += len(rows)
         self.exec_stats["decode_padded_lanes"] += S
+        if self.cfg.hybrid:
+            self.conv_state_reads["same_step"] += int(sum(cont)) * (n_steps - 1)
         R = b.R
         W = MEGASTEP_WATCH_W
         draft = np.full((S, R - 1), -1, np.int32)
@@ -3136,6 +3196,9 @@ class EngineCore:
         # a token, both when the chain lands, so a window sees whole pairs).
         self.exec_stats["decode_live_lanes"] += len(seqs)
         self.exec_stats["decode_padded_lanes"] += B
+        if self.cfg.hybrid:
+            self.conv_state_reads["earlier_dispatch"] += len(seqs)
+            self.conv_state_reads["same_step"] += len(seqs) * (n_steps - 1)
         W = MEGASTEP_WATCH_W
         tokens = np.zeros(B, np.int32)
         positions = np.zeros(B, np.int32)
@@ -4707,6 +4770,7 @@ class EngineCore:
         still running — the sequence serves from ``running`` before it
         ever reaches ``_held``). Defaults describe the whole committed
         prefix, the legacy pull-after-prefill shape."""
+        self._refuse_leaving_the_device("disagg")
         with self._step_lock:
             seq = self._held.get(request_id) or self._streaming_seq(request_id)
             if seq is None:
@@ -4795,6 +4859,7 @@ class EngineCore:
         acquisition and gathered in ONE program (the kv_transfer path's
         batching); offload-tier blocks read from host RAM / disk with no
         device involvement. Stops at the first hash held nowhere."""
+        self._refuse_leaving_the_device("peer_kv")
         where: list[tuple[str, int]] = []  # ("dev", block_idx) | ("off", hash)
         dev_hashes: list[int] = []
         pages_dev = None
@@ -5024,6 +5089,7 @@ class EngineCore:
         Both step locks are held for the dispatch (each cache handle is
         donated by that core's concurrent steps); a global id()-ordered
         acquisition makes mutual pulls deadlock-free."""
+        self._refuse_leaving_the_device("disagg")
         if src is self:
             raise ValueError("cannot direct-import from self")
         if isinstance(src.cache, tuple) != isinstance(self.cache, tuple):
@@ -5094,14 +5160,12 @@ class EngineCore:
         bs = self.engine.block_size
         n_pages = -(-bucket // bs)
         if getattr(self, "_embed_scratch", None) is None:
-            pages = -(-self.engine.prefill_buckets[-1] // bs) + 1
-            shape = (
-                self.cfg.ut_steps * pages,  # a plane per pass (model.init_cache)
-                *self.cfg.kv_page_tail(bs),
-            )
-            self._embed_scratch = tuple(
-                jnp.zeros(shape, self.cfg.jax_dtype)
-                for _ in range(self.cfg.num_layers)
+            from dynamo_tpu.engine.model import cache_for_blocks
+
+            # a plane per pass, a page shape per layer kind (model.init_cache)
+            self._embed_scratch = cache_for_blocks(
+                self.cfg, dataclasses.replace(self.engine, kv_dtype="bf16"),
+                -(-self.engine.prefill_buckets[-1] // bs),
             )
             self._embed_fn = jax.jit(
                 _program(embed_forward, cfg=self.cfg, engine=self.engine, mesh=self.mesh),
@@ -5166,6 +5230,9 @@ class EngineCore:
         # x passes) and their bytes at the cache's dtype.
         st["kv_cache_layers"] = self.cfg.num_cache_layers
         st["kv_bytes_per_token"] = self.kv_bytes_per_token
+        st["cache_layers"] = self.cfg.cache_layer_counts
+        st["state_bytes_per_block"] = self.cfg.state_bytes_per_block()
+        st["conv_state_reads"] = dict(self.conv_state_reads)
         st["attention"] = self.cfg.attention
         # A sparse model's share and what its router sent it, by the
         # program that counted (decode megasteps, prefill waves): held

@@ -69,6 +69,41 @@ def _latent_sparse_config(hf: dict, experts_held) -> ModelConfig:
     )
 
 
+# Published model types whose layers are of two kinds by ``layer_types``:
+# gated short convolutions among grouped-query attention (LFM2).
+HYBRID_CONV_TYPES = ("lfm2_moe",)
+
+
+def _hybrid_conv_config(hf: dict) -> ModelConfig:
+    rope = hf.get("rope_parameters") or {}
+    return ModelConfig(
+        name=hf["model_type"],
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["hidden_size"] // hf["num_attention_heads"],
+        rope_theta=float(rope.get("rope_theta", hf.get("rope_theta", 1000000.0))),
+        rms_norm_eps=hf.get("norm_eps", 1e-5),
+        tie_embeddings=hf.get("tie_word_embeddings", True),
+        layer_types=tuple(hf["layer_types"]),
+        conv_L_cache=hf["conv_L_cache"],
+        conv_bias=hf.get("conv_bias", False),
+        qk_norm=True,
+        first_dense_layers=hf.get("num_dense_layers", 0),
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        router_scoring="sigmoid",
+        norm_topk_prob=hf.get("norm_topk_prob", True),
+        routed_scaling_factor=hf.get("routed_scaling_factor", 1.0),
+        router_bias=hf.get("use_expert_bias", False),
+        router_norm_eps=1e-6,
+    )
+
+
 def config_from_hf(path: str | Path, experts_held=None) -> ModelConfig:
     """``experts_held`` ``(rank, of)``: the share of a sparse model's routed
     experts to load (a model without a stated share refuses it)."""
@@ -76,6 +111,8 @@ def config_from_hf(path: str | Path, experts_held=None) -> ModelConfig:
         hf = json.load(f)
     if hf.get("model_type") in LATENT_SPARSE_TYPES:
         return _latent_sparse_config(hf, experts_held)
+    if hf.get("model_type") in HYBRID_CONV_TYPES and experts_held is None:
+        return _hybrid_conv_config(hf)
     if experts_held is not None:
         raise ValueError(
             f"experts_held={experts_held} for model_type "
@@ -234,12 +271,87 @@ def _load_latent_sparse(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, An
     return params
 
 
+def _load_hybrid_conv(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]:
+    """The tree of a model with conv and attention layers
+    (``model.init_params``: ``layers`` the two norms of every layer,
+    ``conv`` / ``attn`` one entry a layer of that kind, ``dense_mlp``,
+    ``moe``) from the checkpoint's names: ``operator_norm``, ``ffn_norm``;
+    a conv layer's ``conv.{in_proj, conv, out_proj}`` (``conv.conv.weight
+    [h, 1, L]`` becomes the taps ``[L, h]``; ``in_proj``'s columns are ``[B |
+    C | x]`` as published); an attention layer's ``self_attn.{q_proj,
+    k_proj, v_proj, out_proj, q_layernorm, k_layernorm}`` (rotate-half
+    rope: no permutation); a dense layer's ``feed_forward.{w1, w3, w2}``
+    (gate, up, down); a sparse layer's ``feed_forward.gate``,
+    ``feed_forward.expert_bias`` and ``feed_forward.experts.<e>.{w1, w3,
+    w2}``; the final norm is ``model.embedding_norm``."""
+    np_dt = np.dtype(dt)
+    L, Ld, E = cfg.num_layers, cfg.first_dense_layers, cfg.num_experts
+
+    def t(key: str) -> np.ndarray:
+        return np.asarray(sd[key], np.float32)
+
+    def proj(l: int, name: str) -> np.ndarray:
+        return t(f"model.layers.{l}.{name}.weight").T  # [in, out]
+
+    def stack(name: str, layers, fix=lambda w: w) -> np.ndarray:
+        return np.asarray(np.stack([fix(proj(l, name)) for l in layers]), np_dt)
+
+    def norms(name: str, layers=range(L)) -> np.ndarray:
+        return np.asarray(
+            np.stack([t(f"model.layers.{l}.{name}.weight") for l in layers]), np_dt)
+
+    conv, attn, sparse = cfg.layers_of("conv"), cfg.layers_of("attention"), range(Ld, L)
+
+    def gate_up(prefix: str, l: int) -> np.ndarray:
+        return np.concatenate([proj(l, f"{prefix}.w1"), proj(l, f"{prefix}.w3")], axis=-1)
+
+    params: dict[str, Any] = {
+        "layers": {"attn_norm": norms("operator_norm"), "mlp_norm": norms("ffn_norm")},
+        "conv": {
+            "in_proj": stack("conv.in_proj", conv),
+            # published [h, 1, L] -> [L, h]: tap j, L - 1 - j positions back
+            "conv_w": np.asarray(np.stack(
+                [t(f"model.layers.{l}.conv.conv.weight")[:, 0, :].T for l in conv]), np_dt),
+            "out_proj": stack("conv.out_proj", conv),
+        },
+        "attn": {
+            "wqkv": np.asarray(_fuse_np(
+                [stack(f"self_attn.{n}", attn) for n in ("q_proj", "k_proj", "v_proj")],
+                tp), np_dt),
+            "wo": stack("self_attn.out_proj", attn),
+            "q_layernorm": norms("self_attn.q_layernorm", attn),
+            "k_layernorm": norms("self_attn.k_layernorm", attn),
+        },
+        "moe": {
+            "w_router": stack("feed_forward.gate", sparse),
+            # one array a sparse layer (model._init_shared_sparse_mlp)
+            "w_gu": tuple(np.asarray(np.stack(
+                [gate_up(f"feed_forward.experts.{e}", l) for e in range(E)]), np_dt)
+                for l in sparse),
+            "w_down": tuple(np.asarray(np.stack(
+                [proj(l, f"feed_forward.experts.{e}.w2") for e in range(E)]), np_dt)
+                for l in sparse),
+        },
+    }
+    if cfg.router_bias:
+        params["moe"]["expert_bias"] = np.stack(
+            [t(f"model.layers.{l}.feed_forward.expert_bias") for l in sparse])
+    if Ld:
+        params["dense_mlp"] = {
+            "wgu": np.asarray(_fuse_np(
+                [stack("feed_forward.w1", range(Ld)), stack("feed_forward.w3", range(Ld))],
+                tp), np_dt),
+            "w_down": stack("feed_forward.w2", range(Ld)),
+        }
+    return params
+
+
 def load_hf_llama(
     path: str | Path, dtype=None, tp: int = 1, quant: str | None = None,
     experts_held: tuple[int, int] | None = None,
 ) -> tuple[ModelConfig, Any]:
     """Returns (ModelConfig, params pytree) from an HF llama/qwen2/ouro/
-    axk1 checkpoint (``experts_held``: see :func:`config_from_hf`).
+    axk1/lfm2_moe checkpoint (``experts_held``: see :func:`config_from_hf`).
 
     ``tp`` fixes the shard-blocked layout of the fused wqkv/wgu projections
     (model.fuse_qkv/fuse_gu) and must match the serving mesh's tp axis.
@@ -261,17 +373,19 @@ def load_hf_llama(
     def t(key: str) -> np.ndarray:
         return np.asarray(sd[key], np.float32)
 
-    if cfg.latent:
+    if cfg.latent or cfg.hybrid:
         if quant is not None or tp != 1:
             raise NotImplementedError(
-                f"quant={quant!r} / tp={tp} for {cfg.name!r}: experts and "
-                "latent projections load unquantised, in the tp=1 layout"
+                f"quant={quant!r} / tp={tp} for {cfg.name!r}: experts, latent "
+                "projections and conv operators load unquantised, in the tp=1 layout"
             )
         np_dt = np.dtype(dt)
-        params = _load_latent_sparse(cfg, sd, dt, tp)
+        load = _load_hybrid_conv if cfg.hybrid else _load_latent_sparse
+        params = load(cfg, sd, dt, tp)
+        final_norm = "model.embedding_norm.weight" if cfg.hybrid else "model.norm.weight"
         params.update({
             "embed": np.asarray(t("model.embed_tokens.weight"), np_dt),
-            "final_norm": np.asarray(t("model.norm.weight"), np_dt),
+            "final_norm": np.asarray(t(final_norm), np_dt),
             "fuse_tp": np.asarray(tp, np.int32),
         })
         if not cfg.tie_embeddings:

@@ -33,6 +33,8 @@ a program got is logged once at trace time (:func:`_announce`) — the
 reference on a TPU is a warning, never silent — and counted by shape
 and implementation (:func:`traced_calls`,
 ``dynamo_engine_attention_calls_traced_total`` on /metrics).
+Heads HALF a lane row wide come in through :func:`paired_heads_attention`
+(two KV heads a 128-wide row, the same cache bytes, the same kernel).
 Under tensor parallelism wrap with :func:`sharded_ragged_attention` —
 attention is embarrassingly parallel over heads, so the shard_map has no
 collectives.
@@ -304,6 +306,34 @@ def ragged_paged_attention(
         q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs,
         sm_scale=sm_scale, kv_scales=kv_scales,
     )
+
+
+def paired_heads_attention(
+    q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs, *, sm_scale: float,
+) -> jax.Array:
+    """Attention for heads HALF a lane row wide over pages that keep two
+    KV heads a row, at the bytes the heads have: ``kv_pages [n_pages,
+    page_size, n_kv, 2 d]`` with ``[k_2j | k_2j+1]`` at combined head ``2
+    j`` and ``[v_2j | v_2j+1]`` at ``2 j + 1`` (K even, V odd, as ever: ``n_kv
+    / 2`` KV heads of width ``2 d`` to the call below). A query head of
+    KV head ``2 j`` goes in as ``[q | 0]`` and one of ``2 j + 1`` as ``[0 |
+    q]``: its scores against the paired key are its own head's, the
+    zeros meeting the other's; of the output ``[o_2j | o_2j+1]`` its own
+    half is kept. ``sm_scale`` is the published head's (``d^-0.5``).
+    Twice the attention FLOPs, the same cache bytes, and the library
+    kernel on a TPU where a 64-wide head alone would get the ``jnp``
+    reference (:func:`ragged_paged_attention` counts the call by shape
+    and implementation). ``q [T, n_q, d]`` -> ``[T, n_q, d]``."""
+    T, n_q, d = q.shape
+    n_kv = kv_pages.shape[2]               # 2 x (n_kv / 2) combined rows
+    second = ((jnp.arange(n_q) // (n_q // n_kv)) % 2 == 1)[None, :, None]
+    zeros = jnp.zeros_like(q)
+    q2 = jnp.where(second, jnp.concatenate([zeros, q], -1),
+                   jnp.concatenate([q, zeros], -1))
+    out = ragged_paged_attention(
+        q2, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs, sm_scale=sm_scale,
+    )
+    return jnp.where(second, out[..., d:], out[..., :d])
 
 
 def sharded_ragged_attention(

@@ -159,6 +159,12 @@ SCHEDULER_GAUGES: dict[str, tuple[str, str]] = {
         "cache's dtype (int8: scales included; a latent cache: its "
         "[ckv | kr] rows)",
     ),
+    "state_bytes_per_block": (
+        "engine_state_bytes_per_block",
+        "Bytes of convolution state one block holds over all conv layers "
+        "(their newest conv_L_cache - 1 rows, whatever the block size); 0 "
+        "for a model without conv layers",
+    ),
     "experts_held": (
         "engine_experts_held",
         "Routed experts of each sparse layer this worker holds (its share "
@@ -324,6 +330,28 @@ class _EngineCounters:
         for (shape, impl), n in sorted(traced_calls().items()):
             traced.add_metric(["engine", shape, impl], float(n))
         yield traced
+        kinds = GaugeMetricFamily(
+            "dynamo_engine_cache_layers",
+            "Page arrays the cache holds, by what a layer of that kind "
+            "caches: attention (planes of K/V, or latent rows) or conv (the "
+            "short convolution's state pages)",
+            labels=["service", "kind"],
+        )
+        for kind, n in sorted(stats.get("cache_layers", {}).items()):
+            kinds.add_metric(["engine", kind], float(n))
+        yield kinds
+        reads = CounterMetricFamily(
+            "dynamo_engine_conv_state_reads",
+            "Times a sequence's rows read convolution state from the pages, "
+            "by where it was written: same_step (an earlier iteration of the "
+            "same megastep), earlier_dispatch (the sequence's own previous "
+            "chunk or step), prefix_hit (a shared block another request "
+            "filled). The state is found, never rebuilt",
+            labels=["service", "from"],
+        )
+        for source, n in sorted(stats.get("conv_state_reads", {}).items()):
+            reads.add_metric(["engine", source], float(n))
+        yield reads
         by_phase = stats.get("expert_stats", {})
         for i, (name, doc) in enumerate(EXPERT_COUNTERS):
             family = CounterMetricFamily(

@@ -36,6 +36,19 @@ def test_cpu_tiny_rehearsal_passes_and_names_its_device():
     assert report["startup"]["aggregated"]["warmup_phases"]
 
 
+def test_cpu_rehearsal_serves_a_hybrid_model():
+    """Conv state pages beside paired 64-wide heads through the same three
+    processes; the kernel check's paired-heads case runs too."""
+    out = _smoke("--cpu-tiny", "--cpu-preset", "tiny-lfm2")
+    assert out.returncode == 0, out.stderr[-3000:]
+    report = json.loads((REPO / "chip_smoke_out" / "report.json").read_text())
+    assert report["cpu_preset"] == "tiny-lfm2" and report["repeat_cached_tokens"] > 0
+    assert report["startup"]["aggregated"]["cache_layers"] == {"attention": 1, "conv": 5}
+    assert any(k.startswith("decode/") for k in report["attention_traced"]["aggregated"])
+    assert any("paired 64-wide heads" in c["name"] and c["ok"]
+               for c in report["kernels"]["checks"])
+
+
 def test_without_the_cpu_argument_a_missing_tpu_is_a_failure():
     out = subprocess.run(
         [sys.executable, "chip_smoke.py"], cwd=REPO, capture_output=True,
