@@ -440,8 +440,12 @@ def serve_phase(mode: str, inject: str | None, report: dict) -> None:
         report["radix_index"] = used
         report["attention"] = check_attention_lowering(
             ir_dir, roles, head["device"]["platform"])
-        report["attention_traced"] = check_attention_traced(
-            workers, head["device"]["platform"])
+        report["attention_traced"] = check_calls_traced(
+            workers, head["device"]["platform"],
+            "dynamo_engine_attention_calls_traced_total", judge_attention_traced)
+        report["experts_traced"] = check_calls_traced(
+            workers, head["device"]["platform"],
+            "dynamo_engine_expert_calls_traced_total", judge_experts_traced)
     finally:
         children.stop()
         shutil.rmtree(ir_dir, ignore_errors=True)
@@ -481,22 +485,31 @@ def metric_lines(health_url: str, prefix: str) -> list[str]:
         return [l for l in r.read().decode().splitlines() if l.startswith(prefix)]
 
 
-def check_attention_traced(workers, platform: str) -> dict:
-    """Each worker's ``dynamo_engine_attention_calls_traced_total``: which
-    shape its programs stated and which implementation they got. A worker
-    that decodes must have traced the decode shape (``latent-decode`` for
-    a latent model); on a TPU none may have fallen to the jnp reference,
-    and a latent model's decode none to its ``jnp`` path."""
+def check_calls_traced(workers, platform: str, metric: str, judge) -> dict:
+    """Each worker's ``metric`` (a ``..._calls_traced_total{shape, impl}``
+    counter) as ``{"<shape>/<impl>": calls traced}``, held to ``judge``:
+    :func:`judge_attention_traced` (a worker that decodes must have traced
+    the decode shape, and on a TPU none may have fallen to a jnp path) or
+    :func:`judge_experts_traced` (no series for a dense model; on a TPU a
+    sparse wave may not have run every expert on every row)."""
     found: dict[str, dict] = {}
     for role, url, _ in workers:
         got = {}
-        for line in metric_lines(url, "dynamo_engine_attention_calls_traced_total{"):
+        for line in metric_lines(url, metric + "{"):
             labels = dict(kv.split("=") for kv in line[line.index("{") + 1:line.index("}")]
                           .replace('"', "").split(","))
             got[f"{labels['shape']}/{labels['impl']}"] = float(line.split()[-1])
         found[role] = got
-        judge_attention_traced(role, got, platform)
+        judge(role, got, platform)
     return found
+
+
+def judge_experts_traced(role: str, got: dict[str, float], platform: str) -> None:
+    """``got``: ``{"<shape>/<impl>": calls traced}`` of one worker."""
+    if platform == "tpu" and got.get("wave/all_rows"):
+        raise PhaseFailed(
+            f"{role}: a sparse prefill wave ran every held expert on every row on a "
+            f"TPU, not the grouped product over the chosen pairs: {got}")
 
 
 def judge_attention_traced(role: str, got: dict[str, float], platform: str) -> None:
@@ -838,6 +851,7 @@ def main() -> int:
           f"index: {report['radix_index']} (built this run)")
     print(f"served attention lowering: {report['attention']}")
     print(f"served attention traced (shape/impl: calls): {report['attention_traced']}")
+    print(f"served experts traced (shape/impl: calls): {report['experts_traced']}")
     for c in report.get("kernels", {}).get("checks", ()):
         print(f"kernel check: {c}")
     if "disagg" in report:

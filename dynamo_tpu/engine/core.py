@@ -50,6 +50,7 @@ from dynamo_tpu.runtime import wire
 from dynamo_tpu.engine.model import (
     decode_tokens,
     embed_forward,
+    expert_call_shape,
     forward_ring_prefill,
     forward_tokens,
     init_cache,
@@ -57,6 +58,7 @@ from dynamo_tpu.engine.model import (
     verify_tokens,
 )
 from dynamo_tpu.engine.config import UnsupportedModelOption
+from dynamo_tpu.ops import grouped_matmul
 from dynamo_tpu.ops.ragged_attention import traced_impl
 from dynamo_tpu.engine.sampler import (
     LOGPROBS_K,
@@ -435,8 +437,9 @@ def _expert_stats_list(cfg) -> list | None:
 
 
 def _expert_stats_sum(stats: list | None):
-    """int32 [4] over a step's sparse layers: held experts touched, layer
-    steps, (token, expert) pairs on held experts, pairs routed."""
+    """int32 [5] over a step's sparse layers: held experts touched, layer
+    steps, (token, expert) pairs on held experts, pairs routed, rows the
+    expert products ran on."""
     return sum(stats[1:], stats[0]) if stats else None
 
 
@@ -1146,10 +1149,10 @@ class EngineCore:
         self._ring_H = engine_cfg.spec_window + engine_cfg.spec_ngram_max
         self.spec_stats = SpecStats()
         # What the sparse layers counted, by the program that ran them:
-        # int64 [4] each (model._shared_sparse_mlp), landed with the
+        # int64 [5] each (model._shared_sparse_mlp), landed with the
         # tokens of every dispatch.
         self.expert_stats = {
-            phase: np.zeros(4, np.int64) for phase in ("decode", "prefill")
+            phase: np.zeros(5, np.int64) for phase in ("decode", "prefill")
         }
         # Where the rows of a model with conv layers found the state they
         # read (model.conv_layer), counted at dispatch on the host: the
@@ -1910,7 +1913,9 @@ class EngineCore:
         whether a step was in flight when it was enqueued, and the
         attention implementation this process's programs of that shape
         were traced with (``attn``: the decode shape's for decode
-        iterations, the ragged one's for a prefill wave). ``attrs`` adds
+        iterations, the ragged one's for a prefill wave) and, for a
+        sparse model, the path its expert products got (``experts``: a
+        wave's for a prefill dispatch, a step's otherwise). ``attrs`` adds
         what only one kind of dispatch has (a prefill wave's ``cover``).
 
         Also counts ``layer_passes``: a pass over the stack for each live
@@ -1928,8 +1933,19 @@ class EngineCore:
                 + ("ragged" if kind == "prefill" else "decode")
             ),
             attention=self.cfg.attention,
+            **self._experts_traced(kind, padded),
             **attrs,
         )
+
+    def _experts_traced(self, kind: str, tokens: int) -> dict[str, str]:
+        """``{"experts": path}`` of a sparse model's dispatch, for its
+        annotation: what this process's sparse layers of that shape were
+        traced with (a prefill dispatch of ``tokens`` rows: a wave's where
+        it is wide enough for one; every other dispatch a step's)."""
+        if not self.cfg.shared_sparse:
+            return {}
+        rows = tokens if kind == "prefill" else 1
+        return {"experts": grouped_matmul.traced_impl(expert_call_shape(rows))}
 
     def _bucket_for(self, n: int) -> int:
         """The token bucket a ragged dispatch of ``n`` tokens pads to:
@@ -5236,8 +5252,9 @@ class EngineCore:
         st["attention"] = self.cfg.attention
         # A sparse model's share and what its router sent it, by the
         # program that counted (decode megasteps, prefill waves): held
-        # experts touched and layer steps, pairs on held experts and
-        # pairs routed (model._shared_sparse_mlp).
+        # experts touched and layer steps, pairs on held experts, pairs
+        # routed and rows the expert products ran on
+        # (model._shared_sparse_mlp).
         st["experts_held"] = self.cfg.num_experts_held if self.cfg.shared_sparse else 0
         st["expert_stats"] = {
             phase: [int(n) for n in counts]

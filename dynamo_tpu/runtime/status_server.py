@@ -266,6 +266,11 @@ EXPERT_COUNTERS: tuple[tuple[str, str], ...] = (
     ("engine_expert_pairs_routed",
      "(token, expert) pairs the router chose over its whole width: live "
      "tokens x experts per token x sparse layers"),
+    ("engine_expert_rows_computed",
+     "Rows the expert products ran on: every held expert on every row of "
+     "a decode step; in a prefill wave the rows of the tiles that hold a "
+     "chosen pair. Over expert_pairs_held: how far the work follows the "
+     "load (1 = only the chosen pairs)"),
 )
 
 
@@ -330,6 +335,21 @@ class _EngineCounters:
         for (shape, impl), n in sorted(traced_calls().items()):
             traced.add_metric(["engine", shape, impl], float(n))
         yield traced
+        from dynamo_tpu.ops import grouped_matmul
+
+        experts = CounterMetricFamily(
+            "dynamo_engine_expert_calls_traced",
+            "Sparse layers' expert calls traced into step programs, by "
+            "shape (wave: more rows than every expert on every row serves, "
+            "a prefill wave; step: a decode step's rows) and the path "
+            "chosen (grouped/pallas, grouped/ragged_dot: one grouped "
+            "product over the chosen pairs sorted by expert; all_rows: "
+            "every held expert on every row)",
+            labels=["service", "shape", "impl"],
+        )
+        for (shape, impl), n in sorted(grouped_matmul.traced_calls().items()):
+            experts.add_metric(["engine", shape, impl], float(n))
+        yield experts
         kinds = GaugeMetricFamily(
             "dynamo_engine_cache_layers",
             "Page arrays the cache holds, by what a layer of that kind "

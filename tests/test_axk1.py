@@ -355,6 +355,24 @@ def test_chip_smoke_fails_a_latent_worker_that_decoded_with_jnp_on_a_tpu(got, pl
             chip_smoke.judge_attention_traced("aggregated", got, platform)
 
 
+@pytest.mark.parametrize("got,platform,fails", [
+    ({"wave/grouped/pallas": 6.0, "step/all_rows": 12.0}, "tpu", None),
+    ({"wave/grouped/ragged_dot": 6.0, "step/all_rows": 12.0}, "tpu", None),   # widths no tile divides
+    ({"wave/all_rows": 6.0, "step/all_rows": 12.0}, "tpu", "every held expert on every row"),
+    ({"wave/all_rows": 6.0, "wave/grouped/pallas": 6.0}, "tpu", "every held expert on every row"),
+    ({"wave/all_rows": 6.0}, "cpu", None),
+    ({}, "tpu", None),                                                         # a dense model
+], ids=["kernel", "xla", "all-rows-on-a-tpu", "both-on-a-tpu", "cpu", "dense"])
+def test_chip_smoke_fails_a_sparse_worker_whose_waves_ran_every_row_on_a_tpu(got, platform, fails):
+    import chip_smoke
+
+    if fails is None:
+        chip_smoke.judge_experts_traced("aggregated", got, platform)
+    else:
+        with pytest.raises(chip_smoke.PhaseFailed, match=fails):
+            chip_smoke.judge_experts_traced("aggregated", got, platform)
+
+
 @pytest.mark.parametrize("rows_per_block,pages_per_chunk", [(128, 8), (4, 2), (3, 1)],
                          ids=["one-block", "blocks-of-4", "blocks-of-3"])
 def test_the_ragged_call_is_the_textbooks_over_rows_prefixes_blocks_and_chunks(
@@ -449,7 +467,7 @@ def _reference_mlp(y, lp_all, cfg, held):
     return out, shared
 
 
-@pytest.mark.parametrize("rows", [24, 300], ids=["all-rows", "by-load"])
+@pytest.mark.parametrize("rows", [24, 300], ids=["all-rows", "grouped"])
 def test_the_shares_routed_parts_and_the_shared_expert_once_add_up(rows):
     """Four chips hold four experts each of the sixteen: the routed parts
     of all four shares, with the shared expert counted once, are the
@@ -475,7 +493,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up(rows):
     assert float(np.abs(np.asarray(routed)).mean()) > 0.05      # the routed part is no rounding
 
 
-@pytest.mark.parametrize("rows", [40, 300], ids=["all-rows", "by-load"])
+@pytest.mark.parametrize("rows", [40, 300], ids=["all-rows", "grouped"])
 def test_nothing_is_dropped_when_every_token_goes_to_one_held_expert(rows):
     """A router rigged to score expert 2 (held) highest for every token
     (and expert 3 beside it, so that their group is always kept): every
@@ -488,8 +506,10 @@ def test_nothing_is_dropped_when_every_token_goes_to_one_held_expert(rows):
     lp["w_router"] = jnp.asarray(rig)
     stats: list = []
     got = model_mod._shared_sparse_mlp(y, lp, CFG, expert_stats=stats)
-    touched, steps, pairs_held, pairs_routed = (int(n) for n in stats[0])
+    touched, steps, pairs_held, pairs_routed, rows_computed = (int(n) for n in stats[0])
     assert steps == 1 and pairs_routed == rows * 4 and touched >= 1
+    # 4 held experts on every row, or (a wave) the rows of the pairs held and no others
+    assert rows_computed == (4 * rows if rows <= model_mod._EXPERTS_ALL_ROWS_MAX else pairs_held)
     _, chosen = model_mod.route_sigmoid(y, lp["w_router"], CFG)
     assert bool(chosen[:, 2].all()) and pairs_held == int(chosen[:, :4].sum()) >= rows
     assert model_mod._moe_capacity(rows, dataclasses.replace(
@@ -531,9 +551,44 @@ def test_a_decode_step_runs_every_held_expert_whatever_the_router_favours():
     text = str(jax.make_jaxpr(lambda a: model_mod._shared_sparse_mlp(a, lp, CFG))(y))
     # one loop of a fixed number of turns over the held experts (PR 35), no test of a value
     assert "cond[" not in text and "while[" not in text and text.count("scan[") == 1
-    by_load = str(jax.make_jaxpr(lambda a: model_mod._shared_sparse_mlp(a, lp, CFG))(
+    # a wave follows the load through ONE grouped product (PR 36), and says so
+    from dynamo_tpu.ops import grouped_matmul
+
+    before = grouped_matmul.traced_calls()
+    wave = str(jax.make_jaxpr(lambda a: model_mod._shared_sparse_mlp(a, lp, CFG))(
         jnp.zeros((512, 64), jnp.float32)))
-    assert "cond[" in by_load
+    after = grouped_matmul.traced_calls()
+    assert {k: n - before.get(k, 0) for k, n in after.items() if n != before.get(k, 0)} == {
+        ("wave", "grouped/ragged_dot"): 1}
+    # its one loop: the combine's passes over a row's places, as many as the fullest row holds
+    assert "cond[" not in wave and "scan[" not in wave and wave.count("while[") == 1
+    assert wave.count("ragged_dot_general[") == 2
+    assert grouped_matmul.traced_impl("wave") == "grouped/ragged_dot"
+    assert grouped_matmul.traced_impl("step") == "all_rows"
+
+
+@pytest.mark.parametrize("rows", [300, 2048])
+def test_a_wave_of_the_cells_share_computes_the_few_pairs_it_holds(rows):
+    """12 of 192 experts held, 8 a token in 4 of 8 groups (the cell's
+    counts at a toy width): most chosen pairs fall on experts this chip does
+    not hold and belong to no group; the grouped product over the rest is
+    every held expert on every row."""
+    cfg = dataclasses.replace(
+        CFG, num_experts=192, num_experts_per_tok=8, n_group=8, topk_group=4,
+        experts_held=(0, 16), hidden_size=32, moe_intermediate_size=16)
+    rs = np.random.RandomState(rows)
+    lp = {"w_router": jnp.asarray(rs.randn(32, 192), jnp.float32),
+          "w_gu": jnp.asarray(rs.randn(12, 32, 32) * 0.2, jnp.float32),
+          "w_down": jnp.asarray(rs.randn(12, 16, 32) * 0.2, jnp.float32)}
+    y = jnp.asarray(rs.randn(rows, 32), jnp.float32)
+    stats: list = []
+    got = model_mod._shared_sparse_mlp(y, lp, cfg, expert_stats=stats)
+    weights, chosen = model_mod.route_sigmoid(y, lp["w_router"], cfg)
+    want = model_mod._experts_all_rows(y, weights[:, :12], lp["w_gu"], lp["w_down"])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    _, _, held, routed, computed = (int(n) for n in stats[0])
+    assert routed == rows * 8 and 0 < held == computed == int(chosen[:, :12].sum()) < routed // 4
+    assert int((~chosen[:, :12].any(axis=1)).sum()) > rows // 4    # rows with no pair here
 
 
 # -- faults -------------------------------------------------------------------
@@ -790,7 +845,10 @@ def test_counters_and_gauges_of_the_share(served):
 
     assert [n for n, _ in EXPERT_COUNTERS] == [
         "engine_experts_touched", "engine_expert_steps", "engine_expert_pairs_held",
-        "engine_expert_pairs_routed"]
+        "engine_expert_pairs_routed", "engine_expert_rows_computed"]
+    # every one of the 4 held experts on every row (padding too) of a step and of these short waves
+    assert decode[4] % (4 * decode[1]) == 0 and decode[4] >= 4 * decode[3] // 4
+    assert prefill[4] % (4 * prefill[1]) == 0 and prefill[4] >= 4 * prefill[3] // 4
     assert "experts_held" in SCHEDULER_GAUGES
     assert axk1_ep16().kv_unit_values * 2 * 7 == 8064
 

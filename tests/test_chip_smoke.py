@@ -45,6 +45,8 @@ def test_cpu_rehearsal_serves_a_hybrid_model():
     assert report["cpu_preset"] == "tiny-lfm2" and report["repeat_cached_tokens"] > 0
     assert report["startup"]["aggregated"]["cache_layers"] == {"attention": 1, "conv": 5}
     assert any(k.startswith("decode/") for k in report["attention_traced"]["aggregated"])
+    # its short prompts never make a wave: every sparse layer ran a step's path, and said so
+    assert report["experts_traced"]["aggregated"].get("step/all_rows", 0) >= 4
     assert any("paired 64-wide heads" in c["name"] and c["ok"]
                for c in report["kernels"]["checks"])
 

@@ -197,6 +197,35 @@ def test_mosaic_compiles_the_latent_decode_kernel_for_a_v5e(one_chip, lanes):
     assert "latent_decode_attention_kernel" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows,k,held,h,im", [
+    (2048, 4, 64, 2048, 1536),      # lfm2-24b-hybrid-decode, its widest wave
+    (512, 4, 64, 2048, 1536),       # ... and a narrow one
+    (2048, 8, 12, 7168, 2048),      # axk1-ep16-decode
+], ids=["lfm2-2048", "lfm2-512", "axk1-2048"])
+def test_mosaic_compiles_the_grouped_expert_layer_for_a_v5e(one_chip, rows, k, held, h, im):
+    """A sparse prefill wave's expert layer (``model._experts_grouped``, PR
+    36) at the two sparse cells' shapes: the library's grouped matmul at the
+    blocks ``ops/grouped_matmul.py:tiling`` chooses (K whole, under the
+    scoped VMEM), once for gate/up and once for down, and no branch."""
+    from dynamo_tpu.engine import model
+    from dynamo_tpu.ops import grouped_matmul as gm
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    w_gu, w_down = sds((held, h, 2 * im), jnp.bfloat16), sds((held, im, h), jnp.bfloat16)
+    assert gm.impl("tpu", jnp.bfloat16, w_gu, w_down) == "pallas"
+    compiled = jax.jit(
+        lambda *a: model._experts_grouped(*a, k=k, impl="pallas", all_held=held == 64)
+    ).lower(
+        sds((rows, h), jnp.bfloat16), sds((rows, held), jnp.float32), sds((rows, held), jnp.bool_),
+        w_gu, w_down,
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "conditional(" not in text
+    # the sorted rows, both products' results and the activation: the wave's temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * k * (2 * h + 12 * im + 6 * h)
+
+
 @pytest.mark.parametrize("page_size,width,grid", [
     (32, 256, (1, 16)),    # the three cells: what the sweep chose
     (32, 2, (1, 2)),       # never more pages than the table holds

@@ -11,6 +11,7 @@ sequence that goes on) is pinned; every option the two-shaped cache does
 not carry is refused by name."""
 
 import dataclasses
+import functools
 import json
 import math
 
@@ -558,32 +559,227 @@ def test_the_experts_run_under_one_loop_however_many_are_held():
         assert held % math.gcd(model_mod._EXPERTS_LOOP_UNROLL, held) == 0
 
 
-@pytest.mark.parametrize("held,follows", [(12, True), (16, True), (17, False), (64, False)])
-def test_wide_batches_follow_the_load_only_up_to_the_count_that_serves_on_the_chip(held, follows):
-    """``_EXPERTS_BY_LOAD_MAX_HELD``, a limit of its own (not the loop's
-    unroll): up to it a batch wider than ``_EXPERTS_ALL_ROWS_MAX`` rows runs
-    each expert on the rows routed to it; a model that holds more (LFM2's
-    64) runs every expert on every row, because ``_experts_by_load`` halts
-    the v5e there (PERF.md section 6, PR 35). The same sums either way."""
+def _routed_layer(rs, held, h=32, im=16, total=None, dtype=jnp.float32):
+    """(cfg, lp) of one sparse layer that holds the first ``held`` of
+    ``total`` experts, 4 a token, bias on the choice, no shared expert."""
+    total = total or held
+    cfg = dataclasses.replace(CFG, hidden_size=h, moe_intermediate_size=im, num_experts=total,
+                              num_experts_per_tok=4, num_heads=1, num_kv_heads=1, head_dim=h,
+                              experts_held=None if held == total else (0, total // held))
+    lp = {"w_router": jnp.asarray(rs.randn(h, total), jnp.float32),
+          "expert_bias": jnp.asarray(rs.randn(total) * 0.05, jnp.float32),
+          "w_gu": jnp.asarray(rs.randn(held, h, 2 * im) * 0.2, dtype),
+          "w_down": jnp.asarray(rs.randn(held, im, h) * 0.2, dtype)}
+    return cfg, lp
+
+
+@pytest.mark.parametrize("held", [12, 16, 17, 64])
+def test_wide_batches_follow_the_load_whatever_the_count_of_held_experts(held):
+    """A batch wider than ``_EXPERTS_ALL_ROWS_MAX`` rows runs ONE grouped
+    product over the chosen pairs sorted by expert (PR 36), at 12 held
+    experts and at 64 alike: no ``switch`` over capacity tiers (PR 35's
+    ``_experts_by_load`` halted the v5e at 64 and is gone), no limit on the
+    count, the same sums as every expert on every row."""
     rs = np.random.RandomState(held)
-    N, h, im = 512, 32, 16
-    cfg = dataclasses.replace(CFG, hidden_size=h, moe_intermediate_size=im, num_experts=held,
-                              num_experts_per_tok=4, num_heads=1, num_kv_heads=1, head_dim=h)
-    lp = {"w_router": jnp.asarray(rs.randn(h, held), jnp.float32),
-          "expert_bias": jnp.asarray(rs.randn(held) * 0.05, jnp.float32),
-          "w_gu": jnp.asarray(rs.randn(held, h, 2 * im) * 0.2, jnp.float32),
-          "w_down": jnp.asarray(rs.randn(held, im, h) * 0.2, jnp.float32)}
-    y = jnp.asarray(rs.randn(N, h), jnp.float32)
+    cfg, lp = _routed_layer(rs, held)
+    y = jnp.asarray(rs.randn(512, 32), jnp.float32)
     got = model_mod._shared_sparse_mlp(y, lp, cfg)
     text = str(jax.make_jaxpr(lambda a: model_mod._shared_sparse_mlp(a, lp, cfg))(y))
-    assert ("cond[" in text) == follows == (held <= model_mod._EXPERTS_BY_LOAD_MAX_HELD)
+    # no branch and no loop over the experts; a chip that holds every expert unrolls the
+    # combine's passes over a row's places too
+    assert "cond[" not in text and "scan[" not in text and "while[" not in text
+    assert text.count("ragged_dot_general[") == 2 and "_experts_grouped" in text
     weights, chosen = model_mod.route_sigmoid(y, lp["w_router"], cfg, bias=lp["expert_bias"])
     want = model_mod._experts_all_rows(y, weights, lp["w_gu"], lp["w_down"])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     # the decode widths never follow the load, whatever is held
     narrow = str(jax.make_jaxpr(lambda a: model_mod._shared_sparse_mlp(a, lp, cfg))(y[:128]))
-    assert "cond[" not in narrow
-    assert lfm2_24b_a2b_10l().num_experts_held == 64 > model_mod._EXPERTS_BY_LOAD_MAX_HELD >= 12
+    assert "cond[" not in narrow and "ragged_dot" not in narrow and narrow.count("scan[") == 1
+    for gone in ("_experts_by_load", "_EXPERT_TIERS", "_EXPERTS_BY_LOAD_MAX_HELD"):
+        assert not hasattr(model_mod, gone)
+    assert lfm2_24b_a2b_10l().num_experts_held == 64
+
+
+def _rigged(case: str, N: int, Eh: int, k: int, rs):
+    """(chosen_held ``[N, Eh]``, w_held) of a routing no router would draw."""
+    chosen = np.zeros((N, Eh), bool)
+    if case == "one-expert":            # every row to expert 5 alone
+        chosen[:, 5] = True
+    else:
+        idx = np.argsort(rs.rand(N, Eh), axis=1)[:, :k]
+        chosen[np.arange(N)[:, None], idx] = True
+    if case == "an-expert-with-no-row":
+        chosen[:, 3] = False
+        chosen[:, 0] = False
+    if case == "a-padded-tail":         # ``row_valid`` false past row 300
+        chosen[300:] = False
+    if case == "rows-with-no-held-expert":
+        chosen[::3] = False
+    w = np.where(chosen, rs.rand(N, Eh) + 0.1, 0.0)
+    return jnp.asarray(chosen), jnp.asarray(w, jnp.float32)
+
+
+@pytest.mark.parametrize("case", [
+    "even", "one-expert", "an-expert-with-no-row", "a-padded-tail", "rows-with-no-held-expert"])
+def test_the_grouped_product_computes_every_chosen_pair_at_any_skew(case, monkeypatch):
+    """Dropless: every row on ONE expert (a capacity would drop most of
+    them), experts that get no row (empty groups, the first one among
+    them), padding rows and rows whose experts this chip does not hold (they
+    belong to no group): the sums of every expert on every row, with the
+    sorted places in one slab and in many."""
+    rs = np.random.RandomState(7)
+    N, Eh, k, h, im = 384, 16, 4, 32, 16
+    chosen, w_held = _rigged(case, N, Eh, k, rs)
+    w_gu = jnp.asarray(rs.randn(Eh, h, 2 * im) * 0.2, jnp.float32)
+    w_down = jnp.asarray(rs.randn(Eh, im, h) * 0.2, jnp.float32)
+    y = jnp.asarray(rs.randn(N, h), jnp.float32)
+    got = model_mod._experts_grouped(y, w_held, chosen, w_gu, w_down, k=k, impl="ragged_dot",
+                                     all_held=case == "even")
+    want = model_mod._experts_all_rows(y, w_held, w_gu, w_down)
+    assert float(jnp.abs(want).max()) > 0.1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    if case == "a-padded-tail":
+        assert not np.asarray(got[300:]).any()
+    # the same a slab of the sorted places at a time: 1,536 places of 448 B in slabs of 40 kB
+    monkeypatch.setattr(model_mod, "_GROUPED_SLAB_BYTES", 40_000)
+    assert model_mod._slab_places(N * k, h, im, 4, 1) == 86 and -(-N * k // 86) == 18
+    for all_held in (False, True):
+        slabbed = jax.jit(functools.partial(
+            model_mod._experts_grouped.__wrapped__, k=k, impl="ragged_dot", all_held=all_held))
+        np.testing.assert_allclose(np.asarray(slabbed(y, w_held, chosen, w_gu, w_down)),
+                                   np.asarray(want), atol=2e-5)
+    text = str(jax.make_jaxpr(functools.partial(
+        model_mod._experts_grouped.__wrapped__, k=k, impl="ragged_dot", all_held=True))(
+        y, w_held, chosen, w_gu, w_down))
+    assert "while[" in text and "cond[" not in text      # as many slabs as hold a pair
+    monkeypatch.undo()
+    # places padded to whole tiles, as the Pallas kernel needs them: the same sums
+    rows, counts, place, weight = model_mod._sorted_pairs(chosen, w_held, k, 128)
+    assert rows.shape == (N * k,) and N * k % 128 == 0
+    assert model_mod._sorted_pairs(chosen, w_held, k, 1000)[0].shape == (2000,)
+    pairs = int(chosen.sum())
+    assert int(counts.sum()) == pairs
+    # the permutation: a sorted place reads its pair's row, expert by expert
+    experts = np.repeat(np.arange(Eh), np.asarray(counts))
+    assert np.asarray(chosen)[np.asarray(rows[:pairs]), experts].all()
+    live = np.asarray(place) < pairs
+    assert live.sum() == pairs and (np.asarray(weight)[~live] == 0).all()
+    assert (np.diff(np.where(live, np.asarray(place), 10 ** 6), axis=1) >= 0).all()
+
+
+def test_the_sorted_places_go_a_slab_at_a_time_where_they_would_hold_a_gigabyte():
+    """LFM2's widest wave is one slab (226 MB of sorted rows, results and
+    activation); A.X-K1's 16,384 places of 62 KB go 4,096 at a time, as
+    many slabs as hold a pair (a tenth of its places are live)."""
+    assert model_mod._slab_places(2048 * 4, 2048, 1536, 2, 128) == 8192
+    assert model_mod._slab_places(2048 * 8, 7168, 2048, 2, 128) == 4096
+    assert model_mod._slab_places(1024 * 8, 7168, 2048, 2, 128) == 4096
+    assert model_mod._slab_places(512 * 8, 7168, 2048, 2, 128) == 4096
+    assert model_mod._slab_places(300 * 4, 256, 128, 4, 1) == 1200
+    for places, h, im in ((8192, 2048, 1536), (4096, 7168, 2048)):
+        assert places * (h * 2 + 2 * im * 4 + im * 2 + h * 4) <= model_mod._GROUPED_SLAB_BYTES
+
+
+def test_the_combine_adds_a_tokens_terms_in_ascending_expert_order():
+    """Given the products every expert on every row gives, the grouped
+    layer's combine is BIT-equal to ``_experts_all_rows``: the same terms
+    in the same order (its other terms are exact zeros), so a token's sum
+    has one order in a wave and in a decode step."""
+    from dynamo_tpu.ops import grouped_matmul
+
+    rs = np.random.RandomState(11)
+    N, Eh, k, h, im = 64, 8, 4, 16, 8
+    chosen, w_held = _rigged("rows-with-no-held-expert", N, Eh, k, rs)
+    w_gu = jnp.asarray(rs.randn(Eh, h, 2 * im), jnp.float32)
+    w_down = jnp.asarray(rs.randn(Eh, im, h), jnp.float32)
+    y = jnp.asarray(rs.randn(N, h), jnp.float32)
+    rows, counts, place, weight = model_mod._sorted_pairs(chosen, w_held, k, 1)
+    # the products in sorted order, each by the call every-expert-on-every-row makes
+    per_expert = [model_mod._swiglu(y, w_gu[e], w_down[e]) for e in range(Eh)]
+    experts = np.repeat(np.arange(Eh), np.asarray(counts))
+    sorted_y = jnp.stack([per_expert[e][r] for e, r in zip(experts, np.asarray(rows))])
+    combine = lambda full: jax.jit(functools.partial(
+        grouped_matmul.combine, jnp.zeros((N, h), jnp.float32), full=full))
+    got = combine(False)(sorted_y, place, weight)
+    want = jax.jit(model_mod._experts_all_rows)(y, w_held, w_gu, w_down)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # and another order is another sum: the order is what is pinned
+    np.testing.assert_array_equal(np.asarray(combine(True)(sorted_y, place, weight)), np.asarray(want))
+    flipped = combine(True)(sorted_y, place[:, ::-1], weight[:, ::-1])
+    # a chip that holds few experts: the passes no row needs are skipped, the digits stay
+    few = jnp.where(jnp.arange(N)[:, None] % 7 == 0, place, N * k)
+    skipped = combine(False)(sorted_y, few.at[:, 1:].set(N * k), weight)
+    first = jnp.where(few[:, :1] < sorted_y.shape[0],
+                      weight[:, :1] * sorted_y[jnp.minimum(few[:, 0], sorted_y.shape[0] - 1)], 0.0)
+    np.testing.assert_array_equal(np.asarray(skipped), np.asarray(0.0 + first))
+    loop = str(jax.make_jaxpr(combine(False))(sorted_y, place, weight))
+    unrolled = str(jax.make_jaxpr(combine(True))(sorted_y, place, weight))
+    assert "while[" in loop and "while[" not in unrolled and "cond[" not in loop + unrolled
+
+
+def test_the_fifth_count_is_the_rows_the_expert_products_ran_on():
+    """Every held expert on every row: ``held / k`` = 16 x the pairs held
+    for LFM2's 64 and 4. Grouped: the rows of the tiles that hold a pair,
+    ``pairs <= rows < pairs + held x tile`` (the CPU's tile is one row)."""
+    from dynamo_tpu.ops import grouped_matmul
+
+    rs = np.random.RandomState(3)
+    cfg, lp = _routed_layer(rs, 64)
+    for rows in (128, 512):
+        stats: list = []
+        model_mod._shared_sparse_mlp(jnp.asarray(rs.randn(rows, 32), jnp.float32), lp, cfg,
+                                     expert_stats=stats)
+        touched, steps, pairs, routed, computed = (int(n) for n in stats[0])
+        assert pairs == routed == rows * 4 and steps == 1 and touched <= 64
+        assert computed == (16 * pairs if rows == 128 else pairs)
+    counts = jnp.asarray(rs.randint(0, 300, size=64), jnp.int32).at[5].set(0)
+    for tile in (1, 8, 128):
+        visited = int(grouped_matmul.rows_visited(counts, tile))
+        assert int(counts.sum()) <= visited < int(counts.sum()) + 64 * tile and visited % tile == 0
+    # one group over three tiles' boundaries, one inside a tile, one empty
+    assert int(grouped_matmul.rows_visited(jnp.asarray([130, 20, 0, 1]), 128)) == (2 + 1 + 0 + 1) * 128
+
+
+def test_the_traced_calls_counter_and_the_annotation_name_the_path(served):
+    """``dynamo_engine_expert_calls_traced_total{shape, impl}``, counted at
+    trace time where the path is chosen, and ``experts`` on the
+    ``engine/dispatch`` annotation: a wave (more rows than every expert on
+    every row serves) the grouped product, a step every row."""
+    from dynamo_tpu.ops import grouped_matmul
+    from dynamo_tpu.runtime.status_server import _EngineCounters
+
+    core, _ = served
+    before = grouped_matmul.traced_calls()
+    assert before[("step", "all_rows")] >= 4          # the probe's programs: 4 sparse layers each
+    lp = _sparse_layer()
+    for rows, key in ((300, ("wave", "grouped/ragged_dot")), (256, ("step", "all_rows"))):
+        jax.make_jaxpr(lambda a: model_mod._shared_sparse_mlp(a, lp, CFG))(
+            jnp.zeros((rows, 256), jnp.float32))
+        after = grouped_matmul.traced_calls()
+        assert {k: n - before.get(k, 0) for k, n in after.items() if n != before.get(k, 0)} == {key: 1}
+        before = after
+    assert model_mod.expert_call_shape(256) == "step" and model_mod.expert_call_shape(257) == "wave"
+    assert core._experts_traced("prefill", 512) == {"experts": "grouped/ragged_dot"}
+    assert core._experts_traced("prefill", 64) == {"experts": "all_rows"}
+    assert core._experts_traced("megastep", 1024) == {"experts": "all_rows"}
+    assert make_core(tiny_model())._experts_traced("prefill", 512) == {}
+    marks = []
+    core.clock.mark, mark = (lambda *a, **kw: marks.append(kw)), core.clock.mark
+    try:
+        core._mark_dispatch("prefill", 1, 1, 1, 300, 512)
+    finally:
+        core.clock.mark = mark
+    assert marks[0]["experts"] == "grouped/ragged_dot" and marks[0]["padded"] == 512
+    families = {f.name: f for f in _EngineCounters(lambda: {}, core.scheduler_stats).collect()}
+    traced = {(s.labels["shape"], s.labels["impl"]): s.value
+              for s in families["dynamo_engine_expert_calls_traced"].samples}
+    assert traced[("wave", "grouped/ragged_dot")] >= 1 and traced[("step", "all_rows")] >= 4
+    computed = {s.labels["phase"]: s.value
+                for s in families["dynamo_engine_expert_rows_computed"].samples}
+    held = {s.labels["phase"]: s.value
+            for s in families["dynamo_engine_expert_pairs_held"].samples}
+    # 8 of 8 experts held, 2 a token: every row runs 4 x the pairs it holds
+    assert computed["decode"] >= 4 * held["decode"] > 0 and computed["prefill"] >= held["prefill"] > 0
 
 
 # -- faults --------------------------------------------------------------------

@@ -1,0 +1,265 @@
+"""One sparse layer's expert products alone, at the shapes the
+benchmark's sparse cells run, timed on the host's clock around calls that
+end in ``block_until_ready``.
+
+For each shape (``lfm2``: 64 of 64 experts of 2048 x 1536 held, 4 a
+token; ``axk1``: 12 of 192 experts of 7168 x 2048 held, 8 a token) and
+each row count it runs, on the same routed rows:
+
+- ``serving``: what ``model._shared_sparse_mlp`` would run at that width
+  (every expert on every row at or under ``_EXPERTS_ALL_ROWS_MAX`` rows,
+  ``model._experts_grouped`` above it, its implementation chosen by
+  ``ops/grouped_matmul.py:impl``);
+- ``all_rows``: ``model._experts_all_rows``, the definition the others
+  are compared with;
+- ``grouped/ragged_dot`` and ``grouped/pallas`` over ``--tilings``
+  (``rows x columns`` of a block of the Pallas kernel, ``K`` whole; ``auto``
+  = ``grouped_matmul.tiling``), and with ``--pieces`` the parts of the
+  grouped layer one by one: the permutation, the gather of the sorted
+  rows, the two grouped products, the combine.
+
+It prints ms a call against the time the held experts' bytes take at the
+chip's HBM rate, the rows the products ran on over the pairs held, and
+the largest difference from ``all_rows``.
+
+Refuses to run without a TPU (a time from the CPU says nothing here)
+unless ``--toy`` cuts the shapes to a size the CPU runs in seconds: that
+is the rehearsal of its control flow, and its times mean nothing.
+
+Usage (through the chip tool, from the repo root):
+    python -m tools.experts_bench [--shapes lfm2,axk1] [--rows 128,512,1024,2048]
+                                  [--routing random,one] [--tilings auto,128x512]
+                                  [--pieces] [--toy]
+Writes ``chiprun_out/experts_bench/table.json`` beside the table.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import time
+from pathlib import Path
+
+import numpy as np
+
+# hidden, expert width, experts the router chooses among, experts held
+# (the first ones), experts a token: lfm2-24b-hybrid-decode, axk1-ep16-decode.
+SHAPES = {
+    "lfm2": (2048, 1536, 64, 64, 4),
+    "axk1": (7168, 2048, 192, 12, 8),
+}
+TOY = {
+    "lfm2": (256, 128, 16, 16, 4),
+    "axk1": (256, 128, 48, 3, 8),
+}
+HBM_BYTES_PER_S = 819e9     # TPU v5e (chipbench/peaks.py)
+CALLS = 20
+
+
+def make_case(shape: tuple, rows: int, routing: str, seed: int):
+    """(xf, w_held, chosen_held, w_gu, w_down) of one layer: bf16 rows
+    and weights; ``routing`` ``random`` draws each row's experts evenly
+    among all, ``one`` sends every row to held expert 0 first (the
+    dropless worst case)."""
+    import jax
+    import jax.numpy as jnp
+
+    h, im, experts, held, k = shape
+    rs = np.random.RandomState(seed)
+    score = rs.rand(rows, experts)
+    if routing == "one":
+        score[:, 0] = 2.0
+    idx = np.argsort(-score, axis=1)[:, :k]
+    chosen = np.zeros((rows, experts), bool)
+    chosen[np.arange(rows)[:, None], idx] = True
+    chosen_held = jnp.asarray(chosen[:, :held])
+    w_held = jnp.where(chosen_held, jnp.asarray(rs.rand(rows, held), jnp.float32), 0.0)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    normal = lambda key, dims, scale: (
+        jax.random.normal(key, dims, jnp.float32) * scale).astype(jnp.bfloat16)
+    return (normal(keys[0], (rows, h), 1.0), w_held, chosen_held,
+            normal(keys[1], (held, h, 2 * im), h ** -0.5),
+            normal(keys[2], (held, im, h), im ** -0.5))
+
+
+def _grouped(impl: str, k: int, all_held: bool, tm: int | None, tn: int | None):
+    """``model._experts_grouped`` traced anew, with the Pallas kernel's
+    blocks stated (``tm`` rows, ``K`` whole, ``tn`` columns; None: the
+    module's own choice)."""
+    import contextlib
+    from unittest import mock
+
+    from dynamo_tpu.engine import model
+    from dynamo_tpu.ops import grouped_matmul as gm
+
+    def run(xf, w_held, chosen_held, w_gu, w_down):
+        with contextlib.ExitStack() as stack:
+            if tm is not None:      # read at trace time, which is inside this call
+                stack.enter_context(mock.patch.object(gm, "_TILE_ROWS", tm))
+                stack.enter_context(mock.patch.object(
+                    gm, "tiling", lambda kk, n, itemsize: (tm, kk, min(tn, n))))
+            return model._experts_grouped.__wrapped__(
+                xf, w_held, chosen_held, w_gu, w_down, k=k, impl=impl, all_held=all_held)
+
+    return run
+
+
+def variants(shape: tuple, rows: int, tilings: list[str], on_tpu: bool):
+    """[(tag, fn(xf, w_held, chosen_held, w_gu, w_down), rows a tile of
+    its grouped products or None)], serving first. XLA's own tile on a
+    TPU is not the module's to know: its rows read as the pairs held."""
+    from dynamo_tpu.engine import model
+    from dynamo_tpu.ops import grouped_matmul as gm
+
+    k, all_held = min(shape[4], shape[3]), shape[3] == shape[2]
+
+    def serving(xf, w_held, chosen_held, w_gu, w_down):
+        import jax
+
+        if model.expert_call_shape(rows) == "step":
+            return model._experts_all_rows(xf, w_held, w_gu, w_down)
+        impl = gm.impl(jax.default_backend(), xf.dtype, w_gu, w_down)
+        return model._experts_grouped(xf, w_held, chosen_held, w_gu, w_down, k=k, impl=impl,
+                                      all_held=all_held)
+
+    def all_rows(xf, w_held, chosen_held, w_gu, w_down):
+        return model._experts_all_rows(xf, w_held, w_gu, w_down)
+
+    auto = gm.tile_rows("pallas" if on_tpu else "ragged_dot")
+    out = [("serving", serving, None if model.expert_call_shape(rows) == "step" else auto),
+           ("all_rows", all_rows, None),
+           ("grouped/ragged_dot", _grouped("ragged_dot", k, all_held, None, None), 1)]
+    if on_tpu:
+        for t in tilings:
+            tm, tn = (None, None) if t == "auto" else (int(n) for n in t.split("x"))
+            out.append((f"grouped/pallas {t}", _grouped("pallas", k, all_held, tm, tn), tm or auto))
+    return out
+
+
+def pieces(shape: tuple, impl: str):
+    """[(tag, fn, None)] of the grouped layer's parts over ALL the sorted places
+    at once (the layer goes a slab at a time where they are many), each a
+    program of its own (what the whole fuses is not seen here)."""
+    import jax.numpy as jnp
+
+    from dynamo_tpu.engine import model
+    from dynamo_tpu.ops import grouped_matmul as gm
+
+    k = min(shape[4], shape[3])
+    tile = gm.tile_rows(impl)
+
+    def perm(xf, w_held, chosen_held, w_gu, w_down):
+        return model._sorted_pairs(chosen_held, w_held, k, tile)
+
+    def gather(xf, w_held, chosen_held, w_gu, w_down):
+        return xf[model._sorted_pairs(chosen_held, w_held, k, tile)[0]]
+
+    def product(which):
+        def run(xf, w_held, chosen_held, w_gu, w_down):
+            counts = jnp.sum(chosen_held, axis=0, dtype=jnp.int32)
+            P = -(-xf.shape[0] * k // tile) * tile
+            w = w_gu if which == "gate_up" else w_down
+            lhs = jnp.zeros((P, w.shape[1]), xf.dtype) + xf[0, 0]
+            return gm.grouped_matmul(lhs, w, counts, impl=impl)
+        return run
+
+    def combine(xf, w_held, chosen_held, w_gu, w_down):
+        rows, counts, place, weight = model._sorted_pairs(chosen_held, w_held, k, tile)
+        y = jnp.zeros((rows.shape[0], xf.shape[1]), jnp.float32) + w_held[0, 0]
+        return gm.combine(jnp.zeros(xf.shape, jnp.float32), y,
+                          jnp.where(place < jnp.sum(counts), place, y.shape[0]), weight,
+                          full=shape[3] == shape[2])
+
+    return [("  permutation", perm, None), ("  permutation + gather", gather, None),
+            ("  product gate/up", product("gate_up"), None),
+            ("  product down", product("down"), None),
+            ("  permutation + combine", combine, None)]
+
+
+def time_call(fn, args) -> tuple[float, object]:
+    import jax
+
+    run = jax.jit(fn)
+    out = jax.block_until_ready(run(*args))
+    t0 = time.perf_counter()
+    for _ in range(CALLS):
+        out = run(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / CALLS * 1e3, out
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--shapes", default="lfm2,axk1")
+    ap.add_argument("--rows", default="128,512,1024,2048")
+    ap.add_argument("--routing", default="random")
+    ap.add_argument("--tilings", default="auto")
+    ap.add_argument("--pieces", action="store_true")
+    ap.add_argument("--toy", action="store_true")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--out", default="chiprun_out/experts_bench")
+    args = ap.parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops import grouped_matmul as gm
+
+    device = jax.devices()[0]
+    on_tpu = device.platform == "tpu"
+    if not on_tpu and not args.toy:
+        raise SystemExit("experts_bench: no TPU here (a time from the CPU says nothing); "
+                         "--toy rehearses the control flow at toy shapes")
+    print(f"device: {device.platform} / {device.device_kind}"
+          + (" (toy shapes: the times mean nothing)" if args.toy else ""), flush=True)
+    table = []
+    for name in args.shapes.split(","):
+        shape = (TOY if args.toy else SHAPES)[name]
+        h, im, experts, held, k = shape
+        floor_ms = held * 3 * h * im * 2 / HBM_BYTES_PER_S * 1e3
+        for routing in args.routing.split(","):
+            for rows in (int(n) for n in args.rows.split(",")):
+                case = make_case(shape, rows, routing, args.seed)
+                counts = jnp.sum(case[2], axis=0, dtype=jnp.int32)
+                pairs = int(jnp.sum(counts))
+                print(f"\n== {name}: {held} of {experts} experts of {h} x {im} held, {rows} rows, "
+                      f"{routing} routing, {pairs} pairs held; the bytes take {floor_ms:.3f} ms",
+                      flush=True)
+                todo = variants(shape, rows, args.tilings.split(","), on_tpu)
+                if args.pieces:
+                    todo += pieces(shape, gm.impl(device.platform, case[0].dtype, case[3], case[4]))
+                want = None
+                for tag, fn, tile in todo:
+                    line = {"shape": name, "rows": rows, "routing": routing, "variant": tag.strip(),
+                            "pairs_held": pairs, "bytes_ms": floor_ms}
+                    try:
+                        ms, out = time_call(fn, case)
+                    except Exception as e:   # a tiling the compiler refuses: say so, go on
+                        print(f"{tag:<32} FAILED: {str(e).splitlines()[0][:160]}", flush=True)
+                        table.append({**line, "error": str(e)[:400]})
+                        continue
+                    line["ms"] = ms
+                    note = ""
+                    if tag == "all_rows":
+                        want = out
+                        line["rows_computed"] = held * rows
+                    elif tile:
+                        line["rows_computed"] = int(gm.rows_visited(counts, tile))
+                    if want is not None and tag.startswith("grouped"):
+                        line["max_abs_diff"] = float(jnp.max(jnp.abs(out - want)))
+                        note = (f", max |diff| {line['max_abs_diff']:.2e} of "
+                                f"{float(jnp.max(jnp.abs(want))):.2e}")
+                    if "rows_computed" in line:
+                        note = f", rows / pair {line['rows_computed'] / max(pairs, 1):.2f}" + note
+                    print(f"{tag:<32} {ms:8.3f} ms = {ms / floor_ms:5.2f} x the bytes{note}",
+                          flush=True)
+                    table.append(line)
+    out_dir = Path(args.out)
+    out_dir.mkdir(parents=True, exist_ok=True)
+    (out_dir / "table.json").write_text(json.dumps(
+        {"device": device.device_kind, "toy": args.toy, "lines": table}, indent=1))
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())
