@@ -446,6 +446,7 @@ def serve_phase(mode: str, inject: str | None, report: dict) -> None:
         report["experts_traced"] = check_calls_traced(
             workers, head["device"]["platform"],
             "dynamo_engine_expert_calls_traced_total", judge_experts_traced)
+        report["token_account"] = token_accounts(workers)
     finally:
         children.stop()
         shutil.rmtree(ir_dir, ignore_errors=True)
@@ -483,6 +484,20 @@ def metric_lines(health_url: str, prefix: str) -> list[str]:
     with urllib.request.urlopen(
             health_url.replace("/health", "/metrics"), timeout=30) as r:
         return [l for l in r.read().decode().splitlines() if l.startswith(prefix)]
+
+
+def token_accounts(workers) -> dict:
+    """Each worker's account of a token since its start (the step clock's
+    lane-seconds over the tokens decode iterations committed, ms:
+    ``tools/token_account.py``). Observed, never judged."""
+    from tools.token_account import account
+
+    found = {}
+    for role, url, _ in workers:
+        acc = account("", "\n".join(metric_lines(url, "dynamo_engine_")))
+        found[role] = {k: acc[k] for k in (
+            "decode_ms_per_token", "prefill_stall_ms_per_token", "host_stall_ms_per_token")}
+    return found
 
 
 def check_calls_traced(workers, platform: str, metric: str, judge) -> dict:
@@ -852,6 +867,8 @@ def main() -> int:
     print(f"served attention lowering: {report['attention']}")
     print(f"served attention traced (shape/impl: calls): {report['attention_traced']}")
     print(f"served experts traced (shape/impl: calls): {report['experts_traced']}")
+    print("a token's account since start, ms (decode / behind a wave / behind "
+          f"the host; warm-up and compiles included): {report['token_account']}")
     for c in report.get("kernels", {}).get("checks", ()):
         print(f"kernel check: {c}")
     if "disagg" in report:

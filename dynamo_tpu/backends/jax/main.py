@@ -615,7 +615,8 @@ async def run_jax_worker(
     bind_store_gauges(runtime.status, runtime.store)
     bind_scheduler_gauges(runtime.status, core.scheduler_stats)
     bind_engine_counters(
-        runtime.status, core.step_phase_seconds, core.scheduler_stats
+        runtime.status, core.step_phase_seconds, core.scheduler_stats,
+        core.device_account,
     )
     bind_spec_gauges(runtime.status, core.spec_decode_stats)
     bind_kv_cache_gauges(runtime.status, core.kv_cache_stats)

@@ -257,7 +257,7 @@ class _PendingFetch:
     for sample-width dispatches (the legacy 2-D return shape)."""
 
     def __init__(self, core: "EngineCore", toks, lps, sr=None, aux=None,
-                 expert_stats=None):
+                 expert_stats=None, finishing: int = 0):
         self.core = core
         self.toks = toks
         self.lps = lps
@@ -268,7 +268,11 @@ class _PendingFetch:
         if expert_stats is not None and expert_stats[1] is None:
             expert_stats = None
         self.expert_stats = expert_stats
+        # lanes a decode megastep runs to the end of their budget
+        # (_PlannedStep.finishing)
+        self.finishing = finishing
         self.no = core._note_dispatch()
+        core.clock.in_flight(self.no, toks)
         start_host_copy(toks)
         if expert_stats is not None:
             start_host_copy(expert_stats[1])
@@ -300,7 +304,8 @@ class _PendingFetch:
         lps = self.lps
         if lps is not None:
             lps = tuple(fetch_replicated_many(lps))  # dynalint: sync-ok — batched logprob landing
-        core.clock.mark("commit")
+        # ``land`` ends, and with it the dispatch's record on the clock.
+        core.clock.landed(self.no)
         if self.sr is not None:
             # fetch_replicated already landed host np arrays; reshape to
             # the legacy 2-D ([S, R], [S, R, ...]) sample-width views.
@@ -350,6 +355,9 @@ class _PlannedStep:
     # live drafts): the next plan must commit this step first.
     deterministic: bool = True
     committed: bool = False
+    # Lanes this step's decode megastep runs to the end of their budget:
+    # still in ``running`` while it is in flight, and decode-ready no more.
+    finishing: int = 0
 
     def commit(self) -> list:
         if self.committed:
@@ -1525,7 +1533,6 @@ class EngineCore:
             "pipelined_dispatches": 0,
             "commits": 0,
             "drains": 0,
-            "last_host_gap_ms": 0.0,
             # Megastep observability: dispatches that fused k > 1 decode
             # iterations vs everything else (prefill waves, mixed steps,
             # verify rows, k == 1 decode), plus committed (client-
@@ -1534,6 +1541,9 @@ class EngineCore:
             "megastep_dispatches": 0,
             "single_step_dispatches": 0,
             "committed_tokens": 0,
+            # Of those, the tokens decode iterations gave: all but each
+            # stream's first (the denominator of the clock's lane-seconds).
+            "decode_tokens_committed": 0,
             # Universal megastep (ISSUE 12): dispatches that fused a
             # ragged mixed/verify first iteration with scanned decode
             # continuation, and batches forced back to k=1 because a
@@ -1580,10 +1590,9 @@ class EngineCore:
         # the landing of step n's outputs in steady-state decode.
         self._exec_log: list[tuple[str, int]] | None = None
         self._dispatch_no = 0
-        # Step-clock readings (ns) of the newest and the previous
-        # ``dispatch`` mark; 0 breaks the host_gap chain.
-        self._t_dispatch = 0
-        self._t_prev_dispatch = 0
+        # Decode-ready lanes (running, prefill done) as the step's planner
+        # counted them: what a wave leaves waiting (_mark_dispatch).
+        self._decode_ready = 0
         # Admission-time prefix-cache accounting (kv_prefix_cache_admitted_*
         # gauges). Separate from the allocator's match_prefix counters:
         # those count router/disagg probes, these count admitted sequences
@@ -1869,34 +1878,13 @@ class EngineCore:
 
     def _note_dispatch(self) -> int:
         """Dispatch-side bookkeeping for the pipelining invariants: the
-        sequence number feeds the test hook (the async contract is that
-        dispatch N+1 precedes the landing of step N's outputs), and the
-        host-side WALL-CLOCK gap between consecutive dispatch enqueues is
-        recorded as the ``host_gap`` stat — an upper bound on device
-        idle when the pipeline is empty, fully covered by the in-flight
-        step when it is not (``overlapped`` attr). The mocker records the
-        same stat name from its cost model's exact device-idle term; the
-        two track the same bottleneck but are not numerically comparable."""
+        sequence number (the one :meth:`_mark_dispatch` gave the step
+        clock's record) feeds the test hook: the async contract is that
+        dispatch N+1 precedes the landing of step N's outputs."""
         self._dispatch_no += 1
         self.exec_stats["dispatches"] += 1
         if self._inflight is not None:
             self.exec_stats["pipelined_dispatches"] += 1
-        # Both ends are the step clock's readings at ``dispatch`` marks.
-        now = self._t_dispatch
-        if self._t_prev_dispatch:
-            self.exec_stats["last_host_gap_ms"] = (
-                (now - self._t_prev_dispatch) * 1e-6
-            )
-            self._tracer.record(
-                "host_gap", self.clock.wall_s(self._t_prev_dispatch),
-                self.clock.wall_s(now),
-                attrs={
-                    "dispatch": self._dispatch_no,
-                    "overlapped": self._inflight is not None,
-                },
-                stat=True,
-            )
-        self._t_prev_dispatch = now
         if self._exec_log is not None:
             self._exec_log.append(("dispatch", self._dispatch_no))
         return self._dispatch_no
@@ -1917,6 +1905,14 @@ class EngineCore:
         sparse model, the path its expert products got (``experts``: a
         wave's for a prefill dispatch, a step's otherwise). ``attrs`` adds
         what only one kind of dispatch has (a prefill wave's ``cover``).
+        ``no`` is the number :meth:`_note_dispatch` is about to give it.
+
+        The clock opens the dispatch's record with the decode-ready lanes
+        it carries and those it leaves waiting (counted by the planner,
+        not walked): a wave carries none and every decode-ready lane waits
+        behind it; a decode step carries its lanes and leaves none (a
+        lane it does not carry is one its predecessor finishes); a mixed
+        step carries the decode-ready lanes among its rows.
 
         Also counts ``layer_passes``: a pass over the stack for each live
         lane of each fused iteration (a prefill wave: each sequence,
@@ -1925,8 +1921,14 @@ class EngineCore:
         read less."""
         ut = self.cfg.ut_steps
         self.exec_stats["layer_passes"] += lanes * k * ut
-        self._t_dispatch = self.clock.mark(
-            "dispatch", kind=kind, lanes=lanes, width=width, k=k, ut_steps=ut,
+        if kind in ("megastep", "decode"):
+            carried, waiting = lanes, 0
+        else:
+            carried = 0 if kind == "prefill" else min(lanes, self._decode_ready)
+            waiting = self._decode_ready - carried
+        self.clock.dispatch_begin(
+            self._dispatch_no + 1, kind, carried, waiting,
+            lanes=lanes, width=width, k=k, ut_steps=ut,
             real=real, padded=padded, pipelined=self._inflight is not None,
             attn=traced_impl(
                 ("latent-" if self.cfg.latent else "")
@@ -2272,9 +2274,16 @@ class EngineCore:
 
     # -- device-step assembly ---------------------------------------------
 
+    def _to_device(self, arr: np.ndarray) -> jax.Array:
+        """``jnp.asarray``, after a poll of the step clock: a dozen
+        transfers make ``h2d`` the phase a wave most often ends under."""
+        self.clock.poll()
+        return jnp.asarray(arr)
+
     def _put_batch(self, arr: np.ndarray) -> jax.Array:
         """Place a host batch array: leading axis split over dp when the
         mesh is on and the width divides (decode buckets always do)."""
+        self.clock.poll()
         if self.mesh is None or arr.shape[0] % self._dp:
             return jnp.asarray(arr)
         from jax.sharding import NamedSharding, PartitionSpec
@@ -2543,7 +2552,8 @@ class EngineCore:
             # R == 1 these are bit-for-bit the legacy shapes, so the
             # no-speculation program cache is untouched).
             self.clock.mark("h2d")
-            tok_in = jnp.asarray(tokens)
+            put = self._to_device
+            tok_in = put(tokens)
             if feed_idx is not None:
                 # Device-resident feedback: override the placeholder slots
                 # with just-sampled ids straight from the in-flight step's
@@ -2551,21 +2561,21 @@ class EngineCore:
                 tok_in = self._fed(tok_in, feed_idx)
             args = (
                 tok_in,
-                jnp.asarray(positions),
-                jnp.asarray(write_pages),
-                jnp.asarray(write_offs),
-                jnp.asarray(kv_lens),
-                jnp.asarray(tables),
-                jnp.asarray(cu),
-                jnp.asarray(np.array([len(rows)], np.int32)),
-                jnp.asarray(gather.reshape(-1)),
-                jnp.asarray(np.repeat(seeds, R)),
-                jnp.asarray(counters.reshape(-1)),
-                jnp.asarray(np.repeat(temp, R)),
-                jnp.asarray(np.repeat(top_k, R)),
-                jnp.asarray(np.repeat(top_p, R)),
-                jnp.asarray(mm_embeds),
-                jnp.asarray(mm_mask),
+                put(positions),
+                put(write_pages),
+                put(write_offs),
+                put(kv_lens),
+                put(tables),
+                put(cu),
+                put(np.array([len(rows)], np.int32)),
+                put(gather.reshape(-1)),
+                put(np.repeat(seeds, R)),
+                put(counters.reshape(-1)),
+                put(np.repeat(temp, R)),
+                put(np.repeat(top_k, R)),
+                put(np.repeat(top_p, R)),
+                put(mm_embeds),
+                put(mm_mask),
             )
             self._mark_dispatch(
                 kind, len(rows), S, 1, int(cu[len(rows)]), b.T, **attrs
@@ -3042,6 +3052,7 @@ class EngineCore:
             jnp.asarray([seq.sampling.top_p], np.float32),
         )
         self._mark_dispatch("prefill", 1, 1, 1, P_len, T)
+        self._dispatch_no += 1
         toks, lps, self.cache = self._ring(
             self.params,
             self.cache,
@@ -3050,6 +3061,7 @@ class EngineCore:
             all_greedy=all_greedy,
             want_logprobs=want_lp,
         )
+        self.clock.in_flight(self._dispatch_no, toks)
         self.clock.mark("land")
         self._ring_prefills += 1
         if self._ring_prefills == 1:
@@ -3059,7 +3071,7 @@ class EngineCore:
             )
         # dynacheck: allow-transitive-blocking(ring prefill is deliberately synchronous — sp engines keep the classic loop, and the single long prompt IS the step)
         tok = int(fetch_replicated(toks)[0])
-        self.clock.mark("commit")
+        self.clock.landed(self._dispatch_no)
         completed = seq.hashed.extend(seq.prompt)
         self._commit_completed(seq, completed)
         seq.prefilled = seq.processed = P_len
@@ -3085,6 +3097,7 @@ class EngineCore:
         selection can never diverge."""
         ready: list[Sequence] = []
         for seq in decoding:
+            self.clock.poll()
             if seq not in self.running:
                 continue  # preempted by an earlier lane in this loop
             if self._grow_blocks(seq, n_tokens):
@@ -3235,6 +3248,7 @@ class EngineCore:
         if feed_lanes is not None and any(f is not None for f in feed_lanes):
             feed_idx = np.full(B, -1, np.int32)
         for i, seq in enumerate(seqs):
+            self.clock.poll()
             if feed_idx is not None and i < len(feed_lanes) and feed_lanes[i] is not None:
                 feed_idx[i] = feed_lanes[i]
             else:
@@ -3248,6 +3262,7 @@ class EngineCore:
             seeds[i] = seq.seed
             counters[i] = self._eff_generated(seq)
             self._arm_stop_inputs(seq, i, watch, budgets, min_left)
+        finishing = int(np.count_nonzero(budgets <= n_steps))
         need_mask = any(
             s.sampling.top_k > 0 or s.sampling.top_p < 1.0 for s in seqs
         )
@@ -3297,7 +3312,8 @@ class EngineCore:
             "megastep_dispatches" if n_steps > 1 else "single_step_dispatches"
         ] += 1
         return _PendingFetch(  # [n_steps, B] on land()
-            self, out, lps, expert_stats=("decode", stats[0] if stats else None)
+            self, out, lps, expert_stats=("decode", stats[0] if stats else None),
+            finishing=finishing,
         )
 
     # -- the iteration -----------------------------------------------------
@@ -3335,13 +3351,6 @@ class EngineCore:
                 # turns them into the wire-typed DeadlineExceededError).
                 outputs = self._shed_outputs + outputs
                 self._shed_outputs = []
-            if self._inflight is None and not (
-                self.running or self.waiting or self._inbox
-            ):
-                # Engine going idle: break the host_gap chain so the next
-                # burst's first dispatch doesn't record request inter-arrival
-                # time as per-dispatch host overhead.
-                self._t_prev_dispatch = 0
             if self.flight.capacity and outputs:
                 # Flight-recorder step record (counts + cursors only; the
                 # dump is redacted by contract): one dict append per
@@ -3426,6 +3435,7 @@ class EngineCore:
             prefills = [
                 s for s in self.running if not self._eff_prefill_done(s)
             ]
+            self._count_decode_ready(prefills)
             plan = None
             if prefills and self.engine.megastep > 1 and self.pp_mesh is None:
                 # Universal megastep (ISSUE 12): prefill chunks, decode
@@ -3452,11 +3462,20 @@ class EngineCore:
             )
         return plan
 
+    def _count_decode_ready(self, prefills: list[Sequence]) -> None:
+        """Decode-ready lanes, for the step clock's lane-seconds: running,
+        prefill done (under the overlay), and not run to the end of their
+        budget by the step in flight. Counted from the planner's own
+        lists, no lane walked."""
+        ending = self._inflight.finishing if self._inflight is not None else 0
+        self._decode_ready = max(0, len(self.running) - len(prefills) - ending)
+
     # dynalint: holds-lock(_step_lock) — called from _plan_step
     def _plan_waves(self) -> _PlannedStep | None:
         """Prefill-priority scheduling: one monolithic prefill wave
         strictly before any decode (the classic vLLM-default shape)."""
         prefills = [s for s in self.running if not self._eff_prefill_done(s)]
+        self._count_decode_ready(prefills)
         if prefills:
             ring_out = self._maybe_ring_prefill(prefills)
             if ring_out is not None:
@@ -3474,6 +3493,7 @@ class EngineCore:
         both waste a slot and write past the block table."""
         out: list[Sequence] = []
         for s in self.running:
+            self.clock.poll()  # a lane: a 128-lane plan is long (stepclock)
             dpre, dproc, dgen = self._adv3(s)
             if s.pending is None and dgen == 0:
                 continue  # no sampled token yet (still prefilling)
@@ -3533,8 +3553,14 @@ class EngineCore:
             )
             if vplan is not None:
                 parts.append(vplan)
-        # A verify preemption may have evicted a chain candidate.
-        chain_ready = [s for s in chain_ready if s in self.running]
+        # A verify preemption may have evicted a chain candidate (a loop
+        # for the step clock's poll: ms of compares at 128 lanes).
+        kept: list[Sequence] = []
+        for s in chain_ready:
+            self.clock.poll()
+            if s in self.running:
+                kept.append(s)
+        chain_ready = kept
         if chain_ready:
             cplan = self._plan_megastep(chain_ready, n_steps)
             if cplan is not None:
@@ -3662,7 +3688,7 @@ class EngineCore:
         return _PlannedStep(
             core=self, commit_fn=commit, adv=adv,
             feed_tokens=pend.toks, feed_index=feed_index,
-            feed_series=feed_series,
+            feed_series=feed_series, finishing=pend.finishing,
         )
 
     # -- speculative decoding (draft + batched ragged verify) ---------------
@@ -4660,6 +4686,9 @@ class EngineCore:
         the client gets)."""
         seq.out_tokens.extend(tokens)
         self.exec_stats["committed_tokens"] += len(tokens)
+        self.exec_stats["decode_tokens_committed"] += (
+            len(tokens) - (not seq.emitted_first)
+        )
         out = LLMEngineOutput(token_ids=tokens)
         if lp_entries:
             out.logprobs = lp_entries
@@ -4686,6 +4715,7 @@ class EngineCore:
         it, on both the prefill and decode paths."""
         seq.out_tokens.append(token)
         self.exec_stats["committed_tokens"] += 1
+        self.exec_stats["decode_tokens_committed"] += int(seq.emitted_first)
         finish = self._check_stop(seq, token)
         out = LLMEngineOutput(token_ids=[token])
         if lp is not None:
@@ -5273,6 +5303,12 @@ class EngineCore:
             (phase, PHASES[phase]): seconds
             for phase, seconds in self.clock.seconds().items()
         }
+
+    def device_account(self) -> dict[str, dict]:
+        """The step clock's account of the device (device seconds and late
+        landings by kind, starved seconds, lane-seconds), as /metrics
+        exports it beside the phases (:meth:`StepClock.account`)."""
+        return self.clock.account()
 
     def kv_cache_stats(self) -> dict:
         """Point-in-time prefix-cache gauges (status-server /metrics

@@ -211,6 +211,11 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
         "engine_committed_tokens",
         "Tokens committed to client streams since start",
     ),
+    "decode_tokens_committed": (
+        "engine_decode_tokens_committed",
+        "Tokens decode iterations committed to client streams: all but "
+        "each stream's first. The denominator of engine_lane_seconds",
+    ),
     "decode_live_lanes": (
         "engine_decode_live_lanes",
         "Live lanes summed over decode dispatches",
@@ -249,6 +254,46 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
 }
 
 
+# The step clock's account of the device: key of ``StepClock.account()`` ->
+# (name, doc, labels after ``service``). Counters, from the same readings as
+# the phases.
+DEVICE_ACCOUNT_COUNTERS: dict[str, tuple[str, str, tuple[str, ...]]] = {
+    "device_seconds": (
+        "engine_device_seconds",
+        "Estimated device-busy seconds by the kind of dispatch (prefill, "
+        "megastep, decode, mixed): from the later of its enqueue and its "
+        "predecessor's finish to its landing, where the blocking fetch "
+        "waited; a late landing (the output was ready before the host came "
+        "for it) counts up to the upper end of its bracketed finish",
+        ("kind",),
+    ),
+    "late_landings": (
+        "engine_late_landings",
+        "Dispatches whose output was ready before the host fetched it, by "
+        "kind: the device may have had nothing queued after them",
+        ("kind",),
+    ),
+    "starved_seconds": (
+        "engine_device_starved_seconds",
+        "Seconds between a dispatch's finish and the next enqueue while the "
+        "engine had work (no_work is not starvation). bound: lower / upper, "
+        "equal where the landing waited (a late landing's finish is "
+        "bracketed by two polls); phase: the step-clock phase they lay "
+        "under; after: the kind of the dispatch whose end began them",
+        ("bound", "phase", "after"),
+    ),
+    "lane_seconds": (
+        "engine_lane_seconds",
+        "Seconds of decode-ready lanes (running, prefill done) by state: "
+        "decode (inside a dispatch that carried the lane), behind_prefill (a "
+        "wave that did not carry it held the device), behind_host (the "
+        "device starved, upper bound, while it was runnable). Over "
+        "engine_decode_tokens_committed: what a token cost, by cause",
+        ("state",),
+    ),
+}
+
+
 # What a sparse model's layers counted of their router's choices, in the
 # order EngineCore.scheduler_stats()["expert_stats"][phase] holds them
 # (model._shared_sparse_mlp); phase: the program that counted ("decode": a
@@ -278,11 +323,15 @@ class _EngineCounters:
     """Scrape-time collector for the engine's cumulative counters: the
     step clock's seconds per phase, :data:`ENGINE_COUNTERS`, and the
     labelled series (prefill waves and their measured ms by bucket,
-    attention calls traced by shape and implementation)."""
+    attention calls traced by shape and implementation) and, where the
+    engine offers it, the step clock's account of the device
+    (``account``: ``EngineCore.device_account``)."""
 
-    def __init__(self, phase_seconds: Callable[[], dict], stats: Callable[[], dict]):
+    def __init__(self, phase_seconds: Callable[[], dict], stats: Callable[[], dict],
+                 account: Callable[[], dict] | None = None):
         self._phase_seconds = phase_seconds
         self._stats = stats
+        self._account = account
 
     def collect(self):
         from prometheus_client.core import CounterMetricFamily, GaugeMetricFamily
@@ -297,6 +346,8 @@ class _EngineCounters:
         for (phase, blocks), seconds in self._phase_seconds().items():
             phases.add_metric(["engine", phase, blocks], seconds)
         yield phases
+        if self._account is not None:
+            yield from self._device_account(self._account())
         stats = self._stats()
         for key, (name, doc) in ENGINE_COUNTERS.items():
             family = CounterMetricFamily(f"dynamo_{name}", doc, labels=["service"])
@@ -381,18 +432,40 @@ class _EngineCounters:
             yield family
 
 
+    @staticmethod
+    def _device_account(account: dict):
+        """The step clock's account (``StepClock.account``), from the same
+        readings as the phases above."""
+        from prometheus_client.core import CounterMetricFamily
+
+        for key, (name, doc, labels) in DEVICE_ACCOUNT_COUNTERS.items():
+            family = CounterMetricFamily(
+                f"dynamo_{name}", doc, labels=["service", *labels])
+            for label, value in account[key].items():
+                values = label if isinstance(label, tuple) else (label,)
+                family.add_metric(["engine", *values], float(value))
+            yield family
+
+
 def bind_engine_counters(
     status: "SystemStatusServer | None",
     phase_seconds: Callable[[], dict],
     scheduler_stats: Callable[[], dict],
+    device_account: Callable[[], dict] | None = None,
 ) -> None:
     """Export ``dynamo_engine_step_phase_seconds_total{phase, blocks}``
-    (``phase_seconds`` returns ``{(phase, blocks): seconds}``) and the
-    :data:`ENGINE_COUNTERS` on a worker's /metrics. No-op when the
-    status server is disabled."""
+    (``phase_seconds`` returns ``{(phase, blocks): seconds}``), the
+    :data:`ENGINE_COUNTERS` and, beside the phases, the step clock's
+    account of the device (``device_account``:
+    ``dynamo_engine_device_seconds_total{kind}``,
+    ``..._late_landings_total{kind}``,
+    ``..._device_starved_seconds_total{bound, phase, after}``,
+    ``..._lane_seconds_total{state}``) on a worker's /metrics. No-op when
+    the status server is disabled."""
     if status is None:
         return
-    status.metrics.registry.register(_EngineCounters(phase_seconds, scheduler_stats))
+    status.metrics.registry.register(
+        _EngineCounters(phase_seconds, scheduler_stats, device_account))
 
 
 # Speculative-decoding gauge export: stats-dict key -> (name, doc). Keys

@@ -414,27 +414,35 @@ def test_cancel_mid_flight_discards_in_flight_tokens():
 # -- observability ------------------------------------------------------------
 
 
-def test_plan_commit_and_host_gap_spans_recorded():
+def test_plan_commit_spans_and_the_device_account_recorded():
     tracing.configure(enabled=True, sample=1.0)
     collector = tracing.get_collector()
     collector.clear()
     core = EngineCore(CFG, tiny_engine(async_exec=True, megastep_k=1), seed=0)
     seq = core.add_request(_req([1, 2, 3], "t", max_tokens=8, ignore_eos=True))
     drive(core, [seq])
-    stats = collector.stats()
-    names = {s.name for s in stats}
+    names = {s.name for s in collector.stats()}
     assert "engine_plan" in names
     assert "engine_commit" in names
-    gaps = [s for s in stats if s.name == "host_gap"]
-    assert gaps, "host_gap stat missing"
-    # Steady-state decode gaps are overlapped (a step was in flight when
-    # the next dispatch was enqueued).
-    assert any(g.attrs.get("overlapped") for g in gaps)
-    assert core.exec_stats["last_host_gap_ms"] >= 0.0
-    # Idle reset: with all work drained, the gap chain is broken so the
-    # next burst's first dispatch won't record inter-arrival time as
-    # per-dispatch host overhead.
-    assert core._t_prev_dispatch == 0.0
+    # What the engine's ``host_gap`` stat bounded is measured now: the step
+    # clock keeps a record per dispatch and counts the device's seconds,
+    # the seconds it had nothing queued and the lanes' waiting.
+    assert "host_gap" not in names and "last_host_gap_ms" not in core.exec_stats
+    acc = core.device_account()
+    n = core.exec_stats["dispatches"]
+    assert n == 8 and not core.clock._open          # every record closed
+    assert acc["device_seconds"]["prefill"] > 0 and acc["device_seconds"]["decode"] > 0
+    assert sum(acc["late_landings"].values()) <= n
+    starved = {"lower": 0.0, "upper": 0.0}
+    for (bound, phase, after), sec in acc["starved_seconds"].items():
+        assert phase != "no_work" and sec >= 0
+        starved[bound] += sec
+    assert starved["lower"] <= starved["upper"]
+    # One lane, carried by every decode step: its decode seconds are the
+    # decode steps' device seconds, and nothing waited behind a wave.
+    assert acc["lane_seconds"]["decode"] == pytest.approx(acc["device_seconds"]["decode"])
+    assert acc["lane_seconds"]["behind_prefill"] == 0
+    assert core.exec_stats["decode_tokens_committed"] == 7   # of 8: the first is the wave's
     st = core.scheduler_stats()
     assert st["async_exec"] == 1
     assert st["dispatches"] == core.exec_stats["dispatches"]

@@ -258,9 +258,16 @@ def test_plan_and_commit_spans_come_from_the_clocks_readings():
     by_name = {}
     for s in collector.stats():
         by_name.setdefault(s.name, []).append(s)
-    assert by_name["engine_plan"] and by_name["engine_commit"] and by_name["host_gap"]
-    for s in by_name["engine_plan"] + by_name["engine_commit"] + by_name["host_gap"]:
+    assert by_name["engine_plan"] and by_name["engine_commit"]
+    for s in by_name["engine_plan"] + by_name["engine_commit"]:
         assert t0 - 0.01 <= s.start_s <= s.end_s <= t1 + 0.01   # on the wall clock
+    # The per-dispatch ``host_gap`` span is gone: the device's account is
+    # kept from the same readings, and lies inside the same wall time.
+    assert "host_gap" not in by_name
+    acc = core.device_account()
+    busy = sum(acc["device_seconds"].values())
+    lower = sum(v for (b, _, _), v in acc["starved_seconds"].items() if b == "lower")
+    assert 0 < busy and busy + lower <= (t1 - t0) * 1.001
     # Each lies inside the step clock's own account of those phases.
     seconds = core.clock.seconds()
     planning = sum(seconds[p] for p in ("plan", "assemble", "h2d", "dispatch"))

@@ -1,7 +1,11 @@
 """Async-execution smoke: a mocker-backed frontend with ``--async-exec on``
 streams BIT-IDENTICAL output to a twin deployment with it off, and the
-worker's trace collector carries the ``host_gap`` stat the pipelined loop
-reports per dispatch.
+worker's trace collector carries the ``host_gap`` stat the mocker's
+pipelined loop reports per dispatch (its cost model's exact idle term).
+The JAX engine files no such span: its step clock measures what the span
+bounded, and a tiny engine on the pipelined loop must show the counters
+(``dynamo_engine_late_landings_total``,
+``dynamo_engine_device_starved_seconds_total``) on its ``/metrics`` text.
 
 This is the user-visible contract of the async pipelined execution loop
 (ISSUE 5): one-step-ahead scheduling and device-resident token feedback
@@ -124,6 +128,43 @@ async def run_one(async_exec: bool) -> tuple[str, int]:
     return text, len(gaps)
 
 
+def engine_account() -> tuple[float, float, float]:
+    """A tiny JAX engine on the one-step-ahead loop, one request: (late
+    landings, starved seconds lower, upper) as its worker's /metrics
+    would show them."""
+    from chipbench.readers import prometheus
+    from dynamo_tpu.engine import EngineCore, tiny_engine, tiny_model
+    from dynamo_tpu.llm.protocols.common import (
+        PreprocessedRequest,
+        SamplingOptions,
+        StopConditions,
+    )
+    from dynamo_tpu.runtime.metrics import MetricsRegistry
+    from dynamo_tpu.runtime.status_server import _EngineCounters
+
+    core = EngineCore(tiny_model(), tiny_engine(async_exec=True, megastep_k=1), seed=0)
+    seq = core.add_request(PreprocessedRequest(
+        model="tiny", token_ids=[1, 2, 3], request_id="smoke",
+        sampling=SamplingOptions(temperature=0.0),
+        stop=StopConditions(max_tokens=16, ignore_eos=True),
+    ))
+    while seq.finish is None or core.has_work():
+        core.step()
+    registry = MetricsRegistry()
+    registry.registry.register(_EngineCounters(
+        core.step_phase_seconds, core.scheduler_stats, core.device_account))
+    text = [registry.render().decode()]
+    busy = prometheus.total(text, "dynamo_engine_device_seconds_total")
+    late = prometheus.total(text, "dynamo_engine_late_landings_total")
+    lower, upper = (
+        prometheus.total(text, "dynamo_engine_device_starved_seconds_total", {"bound": b})
+        for b in ("lower", "upper")
+    )
+    assert busy and busy > 0, "the engine's step clock counted no device seconds"
+    assert late is not None and lower is not None and lower <= upper, (late, lower, upper)
+    return late, lower, upper
+
+
 async def run() -> None:
     text_on, gaps_on = await run_one(True)
     text_off, _ = await run_one(False)
@@ -132,9 +173,13 @@ async def run() -> None:
         f"async-on stream diverged from async-off:\n  on : {text_on!r}\n"
         f"  off: {text_off!r}"
     )
+    late, lower, upper = engine_account()
     print(
         f"async-smoke OK: {len(text_on)} chars bit-identical async-on vs "
-        f"off; {gaps_on} host_gap stats recorded", flush=True,
+        f"off; {gaps_on} host_gap stats recorded by the mocker; a tiny JAX "
+        f"engine: {late:.0f} late landings, device starved "
+        f"{lower * 1e3:.1f}..{upper * 1e3:.1f} ms (compiles included)",
+        flush=True,
     )
 
 
