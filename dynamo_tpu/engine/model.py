@@ -54,7 +54,7 @@ import jax.numpy as jnp
 from jax import shard_map
 
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
-from dynamo_tpu.ops import grouped_matmul
+from dynamo_tpu.ops import expert_stream, grouped_matmul
 from dynamo_tpu.ops.latent_attention import (
     latent_decode_attention,
     latent_ragged_attention,
@@ -687,6 +687,12 @@ def _mlp(x, lp, cfg: ModelConfig, tp: int, mesh=None, row_valid=None,
 # 32). A bf16 weight costs 2 B to read and 2 FLOP a row, so under ~240
 # rows (the v5e's FLOP per byte) the product hides behind the weight
 # stream it needs anyway. Above it the work follows the pairs held.
+# Which path such a step takes is ``ops/expert_stream.py:impl``'s to say
+# from the backend, the dtype and the shapes: on a TPU, bf16 or f32 at
+# whole-lane widths, ONE Pallas kernel that streams each held expert's
+# weights once (``"stream/pallas"``, PR 38); everywhere else (the CPU
+# rehearsals, int8, odd widths) :func:`_experts_all_rows`, the loop of XLA
+# products that is the definition of both (``"all_rows"``).
 _EXPERTS_ALL_ROWS_MAX = 256
 # Above it: the chosen pairs sorted by expert and one grouped product over
 # them (:func:`_experts_grouped`), whatever the count of held experts.
@@ -744,7 +750,9 @@ def _swiglu(x, w_gu, w_down):
 
 
 # Experts an iteration of :func:`_experts_all_rows`'s loop, so that one
-# expert's weights stream while the one before computes. One layer at 128
+# expert's weights stream while the one before computes: the CPU's and the
+# fallback path's knob since PR 38 (a TPU step at whole-lane widths runs
+# ``ops/expert_stream.py``, which has no loop of products). One layer at 128
 # rows on the v5e, ms a call (PERF.md section 6, PR 35): 64 experts of 2048
 # x 1536 at unroll 1 / 2 / 4 / 8 / 16: 2.137 / 2.102 / 2.087 / 2.075 / 2.120,
 # as 64 unrolled bodies 2.191 (and 18 s to compile where the loop takes
@@ -755,7 +763,11 @@ _EXPERTS_LOOP_UNROLL = 4
 def _experts_all_rows(xf, w_held, w_gu, w_down):
     """Every held expert on every row, the rows not routed to it weighted
     zero: ``[N, h]`` float32. The same bytes and operations whatever the
-    routing. One plain ``x @ W`` pair an expert, as a dense layer's: the
+    routing. The DEFINITION of a step's expert layer, and its path on every
+    backend but a TPU (and on a TPU for int8 or odd widths): there
+    ``ops/expert_stream.py:expert_stream`` computes the same sums, in the
+    same order of experts, from one stream of the weights, and is tested
+    against this. One plain ``x @ W`` pair an expert, as a dense layer's: the
     batched form (``einsum("nh,ehi->eni")``) made the v5e's compiler
     re-lay the experts out, and keep the copy (672 MB a layer at the
     published widths) beside the weights for the whole megastep. ONE
@@ -892,11 +904,13 @@ def _shared_sparse_mlp(x, lp, cfg: ModelConfig, row_valid=None,
         w_held = jnp.where(chosen_held, weights[:, lo:hi], 0.0)
         Eh = hi - lo
         shape_name = expert_call_shape(N)
+        backend = jax.default_backend()
         grouped = grouped_matmul.impl(
-            jax.default_backend(), xf.dtype, lp["w_gu"], lp["w_down"],
+            backend, xf.dtype, lp["w_gu"], lp["w_down"],
         ) if shape_name == "wave" else None
-        grouped_matmul.count_traced(
-            shape_name, f"grouped/{grouped}" if grouped else "all_rows")
+        path = f"grouped/{grouped}" if grouped else expert_stream.impl(
+            backend, xf.dtype, N, lp["w_gu"], lp["w_down"])
+        grouped_matmul.count_traced(shape_name, path)
         if expert_stats is not None:
             expert_stats.append(jnp.stack([
                 jnp.sum(jnp.any(chosen_held, axis=0)), jnp.int32(1),
@@ -913,6 +927,8 @@ def _shared_sparse_mlp(x, lp, cfg: ModelConfig, row_valid=None,
                 xf, w_held, chosen_held, lp["w_gu"], lp["w_down"],
                 k=min(cfg.num_experts_per_tok, Eh), impl=grouped,
                 all_held=Eh == cfg.num_experts)
+        elif path == "stream/pallas":
+            out = expert_stream.expert_stream(xf, w_held, lp["w_gu"], lp["w_down"])
         else:
             out = _experts_all_rows(xf, w_held, lp["w_gu"], lp["w_down"])
     if "shared_wgu" in lp:

@@ -143,8 +143,10 @@ def combine(out: jax.Array, y: jax.Array, place: jax.Array, weight: jax.Array, *
 # Sparse layers' expert calls traced since the process started, by the
 # shape of the call ("wave": more rows than every-expert-on-every-row
 # serves, a prefill wave; "step": a decode step's rows) and the path it
-# got ("grouped/pallas", "grouped/ragged_dot", "all_rows"). Static per
-# compiled program, so counted at trace time, as
+# got ("grouped/pallas", "grouped/ragged_dot" for a wave; for a step
+# "stream/pallas", ``ops/expert_stream.py``'s kernel, or "all_rows", the
+# loop of XLA products: every held expert on every row either way). Static
+# per compiled program, so counted at trace time, as
 # ``ops/ragged_attention.py`` counts the attention calls.
 _TRACED: collections.Counter = collections.Counter()
 _TRACED_LOCK = threading.Lock()

@@ -394,8 +394,10 @@ class _EngineCounters:
             "shape (wave: more rows than every expert on every row serves, "
             "a prefill wave; step: a decode step's rows) and the path "
             "chosen (grouped/pallas, grouped/ragged_dot: one grouped "
-            "product over the chosen pairs sorted by expert; all_rows: "
-            "every held expert on every row)",
+            "product over the chosen pairs sorted by expert; "
+            "stream/pallas, all_rows: every held expert on every row, as "
+            "one Pallas kernel that streams each expert's weights once or "
+            "as a loop of XLA products)",
             labels=["service", "shape", "impl"],
         )
         for (shape, impl), n in sorted(grouped_matmul.traced_calls().items()):

@@ -226,6 +226,28 @@ def test_mosaic_compiles_the_grouped_expert_layer_for_a_v5e(one_chip, rows, k, h
     assert compiled.memory_analysis().temp_size_in_bytes < rows * k * (2 * h + 12 * im + 6 * h)
 
 
+@pytest.mark.parametrize("rows", [32, 128, 256])
+@pytest.mark.parametrize("held,h,im", [(64, 2048, 1536), (12, 7168, 2048)], ids=["lfm2", "axk1"])
+def test_mosaic_compiles_the_expert_stream_kernel_for_a_v5e(one_chip, held, h, im, rows):
+    """A decode step's expert layer (``ops/expert_stream.py``, PR 38) at the
+    two sparse cells' shapes and the three widths a step has, at the blocks
+    the module chooses: one Mosaic call, its rings and sums inside the VMEM
+    limit it states, no copy of the experts beside them."""
+    from dynamo_tpu.ops import expert_stream as es
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    w_gu, w_down = sds((held, h, 2 * im), jnp.bfloat16), sds((held, im, h), jnp.bfloat16)
+    assert es.impl("tpu", jnp.bfloat16, rows, w_gu, w_down) == "stream/pallas"
+    compiled = jax.jit(es.expert_stream).lower(
+        sds((rows, h), jnp.bfloat16), sds((rows, held), jnp.float32), w_gu, w_down,
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "expert_stream_kernel" in text
+    # the rows re-cut by K slab and the padded weights' columns: nothing a weight's size
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * rows * h * 4
+
+
 @pytest.mark.parametrize("page_size,width,grid", [
     (32, 256, (1, 16)),    # the three cells: what the sweep chose
     (32, 2, (1, 2)),       # never more pages than the table holds

@@ -97,7 +97,12 @@ def test_the_experts_bench_runs_at_toy_shapes_and_refuses_the_cpu_otherwise(tmp_
     table = json.loads((tmp_path / "table.json").read_text())
     assert table["toy"] and not any("error" in line for line in table["lines"])
     by = {(l["shape"], l["rows"], l["routing"], l["variant"]): l for l in table["lines"]}
-    assert len(by) == 2 * 2 * 2 * 8
+    # a step's width gets the stream kernel (interpreted here) beside the eight, a wave's does not
+    assert len(by) == 2 * 2 * (9 + 8)
+    for shape in ("lfm2", "axk1"):
+        for routing in ("random", "one"):
+            assert by[shape, 128, routing, "stream/pallas auto"]["max_abs_diff"] < 5e-2
+            assert (shape, 512, routing, "stream/pallas auto") not in by
     for shape, held in (("lfm2", 16), ("axk1", 3)):
         for routing in ("random", "one"):
             wide = by[shape, 512, routing, "grouped/ragged_dot"]
@@ -108,6 +113,14 @@ def test_the_experts_bench_runs_at_toy_shapes_and_refuses_the_cpu_otherwise(tmp_
     # every row on held expert 0 first: a share then holds more pairs than an even router sends it
     assert by["axk1", 512, "one", "all_rows"]["pairs_held"] > by["axk1", 512, "random", "all_rows"]["pairs_held"]
     assert by["lfm2", 512, "one", "all_rows"]["pairs_held"] == 512 * 4      # every expert held: every pair
+    # as a megastep holds the layer: two dependent calls in one loop, the kernel at stated blocks
+    rc = experts_bench.main(["--toy", "--shapes", "lfm2", "--rows", "32", "--in-loop", "2",
+                             "--blocks", "auto,128x128x2", "--out", str(tmp_path / "loop")])
+    looped = {l["variant"]: l for l in
+              json.loads((tmp_path / "loop" / "table.json").read_text())["lines"]}
+    assert rc == 0 and all(l["in_loop"] == 2 and "error" not in l for l in looped.values())
+    assert looped["stream/pallas auto"]["max_abs_diff"] < 5e-2
+    assert looped["stream/pallas 128x128x2"]["max_abs_diff"] < 5e-2
     assert set(experts_bench.SHAPES) == set(experts_bench.TOY) == {"lfm2", "axk1"}
     assert experts_bench.SHAPES["lfm2"] == (2048, 1536, 64, 64, 4)
     assert experts_bench.SHAPES["axk1"] == (7168, 2048, 192, 12, 8)

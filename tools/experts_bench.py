@@ -8,10 +8,14 @@ each row count it runs, on the same routed rows:
 
 - ``serving``: what ``model._shared_sparse_mlp`` would run at that width
   (every expert on every row at or under ``_EXPERTS_ALL_ROWS_MAX`` rows,
+  by the path ``ops/expert_stream.py:impl`` chooses;
   ``model._experts_grouped`` above it, its implementation chosen by
   ``ops/grouped_matmul.py:impl``);
 - ``all_rows``: ``model._experts_all_rows``, the definition the others
   are compared with;
+- ``stream/pallas`` over ``--blocks`` at a step's widths (``rows of w_gu x
+  rows of w_down x blocks in the ring`` of ``ops/expert_stream.py``'s
+  kernel; ``auto`` = the module's own; interpreted where there is no TPU);
 - ``grouped/ragged_dot`` and ``grouped/pallas`` over ``--tilings``
   (``rows x columns`` of a block of the Pallas kernel, ``K`` whole; ``auto``
   = ``grouped_matmul.tiling``), and with ``--pieces`` the parts of the
@@ -26,9 +30,13 @@ Refuses to run without a TPU (a time from the CPU says nothing here)
 unless ``--toy`` cuts the shapes to a size the CPU runs in seconds: that
 is the rehearsal of its control flow, and its times mean nothing.
 
+``--in-loop K`` times every variant as ``K`` dependent calls inside one
+``fori_loop``, as a megastep holds a sparse layer, and prints ms a call.
+
 Usage (through the chip tool, from the repo root):
-    python -m tools.experts_bench [--shapes lfm2,axk1] [--rows 128,512,1024,2048]
+    python -m tools.experts_bench [--shapes lfm2,axk1] [--rows 32,128,256,512,1024,2048]
                                   [--routing random,one] [--tilings auto,128x512]
+                                  [--blocks auto,1024x1536x3] [--in-loop 8]
                                   [--pieces] [--toy]
 Writes ``chiprun_out/experts_bench/table.json`` beside the table.
 """
@@ -104,19 +112,38 @@ def _grouped(impl: str, k: int, all_held: bool, tm: int | None, tn: int | None):
     return run
 
 
-def variants(shape: tuple, rows: int, tilings: list[str], on_tpu: bool):
+def _stream(blocks: str, interpret: bool):
+    """``expert_stream`` at the blocks stated (``tk x ti x ring``; ``auto``:
+    the module's own), interpreted where there is no TPU."""
+    from dynamo_tpu.ops import expert_stream as es
+
+    kw = {}
+    if blocks != "auto":
+        kw = dict(zip(("tk", "ti", "ring"), (int(n) for n in blocks.split("x"))))
+
+    def run(xf, w_held, chosen_held, w_gu, w_down):
+        return es.expert_stream(xf, w_held, w_gu, w_down, interpret=interpret, **kw)
+
+    return run
+
+
+def variants(shape: tuple, rows: int, tilings: list[str], blocks: list[str], on_tpu: bool):
     """[(tag, fn(xf, w_held, chosen_held, w_gu, w_down), rows a tile of
     its grouped products or None)], serving first. XLA's own tile on a
     TPU is not the module's to know: its rows read as the pairs held."""
     from dynamo_tpu.engine import model
+    from dynamo_tpu.ops import expert_stream as es
     from dynamo_tpu.ops import grouped_matmul as gm
 
     k, all_held = min(shape[4], shape[3]), shape[3] == shape[2]
+    step = model.expert_call_shape(rows) == "step"
 
     def serving(xf, w_held, chosen_held, w_gu, w_down):
         import jax
 
-        if model.expert_call_shape(rows) == "step":
+        if step:
+            if es.impl(jax.default_backend(), xf.dtype, rows, w_gu, w_down) == "stream/pallas":
+                return es.expert_stream(xf, w_held, w_gu, w_down)
             return model._experts_all_rows(xf, w_held, w_gu, w_down)
         impl = gm.impl(jax.default_backend(), xf.dtype, w_gu, w_down)
         return model._experts_grouped(xf, w_held, chosen_held, w_gu, w_down, k=k, impl=impl,
@@ -126,9 +153,11 @@ def variants(shape: tuple, rows: int, tilings: list[str], on_tpu: bool):
         return model._experts_all_rows(xf, w_held, w_gu, w_down)
 
     auto = gm.tile_rows("pallas" if on_tpu else "ragged_dot")
-    out = [("serving", serving, None if model.expert_call_shape(rows) == "step" else auto),
-           ("all_rows", all_rows, None),
-           ("grouped/ragged_dot", _grouped("ragged_dot", k, all_held, None, None), 1)]
+    out = [("serving", serving, None if step else auto),
+           ("all_rows", all_rows, None)]
+    if step:
+        out += [(f"stream/pallas {b}", _stream(b, not on_tpu), None) for b in blocks]
+    out.append(("grouped/ragged_dot", _grouped("ragged_dot", k, all_held, None, None), 1))
     if on_tpu:
         for t in tilings:
             tm, tn = (None, None) if t == "auto" else (int(n) for n in t.split("x"))
@@ -176,24 +205,51 @@ def pieces(shape: tuple, impl: str):
             ("  permutation + combine", combine, None)]
 
 
-def time_call(fn, args) -> tuple[float, object]:
+def in_loop(fn, turns: int):
+    """``fn`` as ``turns`` calls inside one ``fori_loop``, each on rows the
+    one before it moved (by a part in 2^20 of its result, so that no call
+    can be hoisted out or run beside another): a megastep's hold on a
+    sparse layer. Returns the last call's result."""
+    import jax
+    import jax.numpy as jnp
+
+    def run(xf, *rest):
+        def turn(_, carry):
+            out = fn(carry[0], *rest)
+            return (xf + (out * 2.0 ** -20).astype(xf.dtype)), out
+
+        return jax.lax.fori_loop(0, turns, turn, (xf, jnp.zeros(xf.shape, jnp.float32)))[1]
+
+    return run
+
+
+def time_call(fn, args, turns: int = 1) -> tuple[float, object]:
+    """(ms a call, the result): ``CALLS`` dispatches, of ``turns`` calls
+    each where ``fn`` returns ``[N, h]`` (a piece that does not is timed a
+    call a dispatch)."""
     import jax
 
+    if turns > 1 and getattr(jax.eval_shape(fn, *args), "shape", None) == args[0].shape:
+        fn = in_loop(fn, turns)
+    else:
+        turns = 1
     run = jax.jit(fn)
     out = jax.block_until_ready(run(*args))
     t0 = time.perf_counter()
     for _ in range(CALLS):
         out = run(*args)
     jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / CALLS * 1e3, out
+    return (time.perf_counter() - t0) / CALLS * 1e3 / turns, out
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--shapes", default="lfm2,axk1")
-    ap.add_argument("--rows", default="128,512,1024,2048")
+    ap.add_argument("--rows", default="32,128,256,512,1024,2048")
     ap.add_argument("--routing", default="random")
     ap.add_argument("--tilings", default="auto")
+    ap.add_argument("--blocks", default="auto")
+    ap.add_argument("--in-loop", type=int, default=1)
     ap.add_argument("--pieces", action="store_true")
     ap.add_argument("--toy", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
@@ -225,15 +281,16 @@ def main(argv=None) -> int:
                 print(f"\n== {name}: {held} of {experts} experts of {h} x {im} held, {rows} rows, "
                       f"{routing} routing, {pairs} pairs held; the bytes take {floor_ms:.3f} ms",
                       flush=True)
-                todo = variants(shape, rows, args.tilings.split(","), on_tpu)
+                todo = variants(shape, rows, args.tilings.split(","), args.blocks.split(","),
+                                on_tpu)
                 if args.pieces:
                     todo += pieces(shape, gm.impl(device.platform, case[0].dtype, case[3], case[4]))
                 want = None
                 for tag, fn, tile in todo:
                     line = {"shape": name, "rows": rows, "routing": routing, "variant": tag.strip(),
-                            "pairs_held": pairs, "bytes_ms": floor_ms}
+                            "pairs_held": pairs, "bytes_ms": floor_ms, "in_loop": args.in_loop}
                     try:
-                        ms, out = time_call(fn, case)
+                        ms, out = time_call(fn, case, args.in_loop)
                     except Exception as e:   # a tiling the compiler refuses: say so, go on
                         print(f"{tag:<32} FAILED: {str(e).splitlines()[0][:160]}", flush=True)
                         table.append({**line, "error": str(e)[:400]})
@@ -245,7 +302,7 @@ def main(argv=None) -> int:
                         line["rows_computed"] = held * rows
                     elif tile:
                         line["rows_computed"] = int(gm.rows_visited(counts, tile))
-                    if want is not None and tag.startswith("grouped"):
+                    if want is not None and tag.startswith(("grouped", "stream")):
                         line["max_abs_diff"] = float(jnp.max(jnp.abs(out - want)))
                         note = (f", max |diff| {line['max_abs_diff']:.2e} of "
                                 f"{float(jnp.max(jnp.abs(want))):.2e}")
