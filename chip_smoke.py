@@ -3,6 +3,7 @@
     python chip_smoke.py              # one TPU chip: qwen2-7b, int8 weights
     python chip_smoke.py --cpu-tiny   # rehearsal on the CPU, `tiny` preset
     python chip_smoke.py --cpu-tiny --cpu-preset tiny-lfm2   # the same, a hybrid model
+    python chip_smoke.py --cpu-tiny --cpu-preset tiny-laguna # the same, window + full layers
 
 Starts the three processes a user starts (README "Run it"): the control-
 plane store, the JAX worker and the OpenAI frontend with the KV router.
@@ -403,8 +404,14 @@ def serve_phase(mode: str, inject: str | None, report: dict) -> None:
         usage = check_completion(*http_json(chat, repeat),
                                  "repeat prompt, second send")
         cached = (usage.get("prompt_tokens_details") or {}).get("cached_tokens", 0)
-        if not cached > 0:
+        # A window model's blocks are found again by no one: its workers say
+        # so on /health, and a hit there would be an unsound one.
+        caching = all(st.get("prefix_caching", True) for st in report["startup"].values())
+        if caching and not cached > 0:
             raise PhaseFailed(f"no cached_tokens on the repeated prompt: {usage}")
+        if not caching and cached:
+            raise PhaseFailed(f"{cached} cached_tokens from workers whose prefix "
+                              f"caching is off: {usage}")
         report["repeat_cached_tokens"] = cached
         say(f"repeat prompt served {cached} cached tokens")
 
@@ -446,6 +453,8 @@ def serve_phase(mode: str, inject: str | None, report: dict) -> None:
         report["experts_traced"] = check_calls_traced(
             workers, head["device"]["platform"],
             "dynamo_engine_expert_calls_traced_total", judge_experts_traced)
+        for role, st in report["startup"].items():
+            judge_window(role, st, report["attention_traced"][role])
         report["token_account"] = token_accounts(workers)
     finally:
         children.stop()
@@ -539,6 +548,29 @@ def judge_attention_traced(role: str, got: dict[str, float], platform: str) -> N
         raise PhaseFailed(
             f"{role}: latent decode attention ran its jnp path on a TPU, not the "
             f"paged kernel: {got}")
+
+
+def judge_window(role: str, startup: dict, traced: dict[str, float]) -> None:
+    """A worker of a model with window layers (``startup.window_blocks``):
+    its decode steps must have traced a window call (on a TPU never the jnp
+    reference: :func:`judge_attention_traced` has refused that), and its
+    window table may be no wider than one dispatch's span: the window's
+    blocks, one more where the window starts inside a block, and the blocks
+    of the most queries a sequence has in one dispatch (a prefill chunk of
+    the largest bucket that goes on through a megastep). A wider table
+    means the window layers keep, or walk, more than their window."""
+    if not startup.get("window_blocks"):
+        return
+    if role != "prefill" and not any(k.startswith("window-decode/") and v
+                                     for k, v in traced.items()):
+        raise PhaseFailed(f"{role}: a window model traced no window-decode call: {traced}")
+    bs, window = startup["block_size"], startup["sliding_window"]
+    chunk = max(int(b) for b in startup["prefill_bucket_ms"]) + startup["megastep_k"]
+    limit = window // bs + 1 + -(-chunk // bs)
+    if startup["window_table_blocks"] > limit:
+        raise PhaseFailed(
+            f"{role}: window table of {startup['window_table_blocks']} columns for "
+            f"window {window}, blocks of {bs} and chunks of {chunk}: at most {limit}")
 
 
 def kernel_phase(mode: str, inject: str | None, report: dict) -> None:
@@ -790,9 +822,11 @@ def main() -> int:
                        help="two one-chip workers: --role prefill and --role decode")
     which.add_argument("--kernel-check-child", action="store_true",
                        help=argparse.SUPPRESS)
-    ap.add_argument("--cpu-preset", choices=["tiny", "tiny-lfm2"], default="tiny",
-                    help="what --cpu-tiny serves: the dense tiny preset, or the "
-                         "hybrid one (conv layers beside paired 64-wide heads)")
+    ap.add_argument("--cpu-preset", choices=["tiny", "tiny-lfm2", "tiny-laguna"],
+                    default="tiny",
+                    help="what --cpu-tiny serves: the dense tiny preset, the "
+                         "hybrid one (conv layers beside paired 64-wide heads), or "
+                         "the one of window and full attention layers (two pools)")
     ap.add_argument("--corrupt", action="store_true", help=argparse.SUPPRESS)
     ap.add_argument("--inject", choices=["worker-start", "bad-request",
                                          "kernel-mismatch"], default=None,

@@ -346,12 +346,13 @@ def build_engine(
                 buckets = (dp * max(1, engine_cfg.decode_buckets[-1] // dp),)
             engine_cfg = dataclasses.replace(engine_cfg, decode_buckets=buckets)
     params = loaded_params
-    if quant and model_cfg.hybrid:
+    if quant and model_cfg.layer_groups:
         from dynamo_tpu.engine.config import UnsupportedModelOption
 
         raise UnsupportedModelOption(
             "quant", model_cfg.name,
-            "conv operators and experts are served unquantised (no int8 init for them)",
+            "conv operators, layers of more than one kind and experts are "
+            "served unquantised (no int8 init for them)",
         )
     if quant == "int8":
         import jax
@@ -531,20 +532,40 @@ async def run_jax_worker(
     )
     startup["engine_loop"] = "pipelined" if core.pipelined else "synchronous"
     log.info("engine loop: %s", startup["engine_loop"])
-    if core.cfg.hybrid and role != "aggregated":
-        # Refused at start-up, by name: a hybrid cache's blocks do not
-        # leave the device (EngineCore.kv_page_shape).
+    if core.cfg.layer_groups and role != "aggregated":
+        # Refused at start-up, by name: the blocks of a hybrid cache, or
+        # of one with a window pool, do not leave the device
+        # (EngineCore.kv_page_shape).
         from dynamo_tpu.engine.config import UnsupportedModelOption
 
         raise UnsupportedModelOption(
             "disagg", core.cfg.name,
-            f"role={role!r} hands blocks to a peer; one block holds pages of "
-            "two shapes and [planes, *page] carries one",
+            f"role={role!r} hands blocks to a peer; "
+            + ("one block holds pages of two shapes and [planes, *page] carries one"
+               if core.cfg.hybrid else
+               "the window layers' pages lie in a pool of their own and do not "
+               "leave the device"),
         )
     startup["attention"] = core.cfg.attention
     startup["kv_bytes_per_token"] = core.kv_bytes_per_token
     startup["cache_layers"] = core.cfg.cache_layer_counts
     startup["state_bytes_per_block"] = core.cfg.state_bytes_per_block()
+    startup["prefix_caching"] = bool(core.engine.enable_prefix_caching)
+    if core.cfg.windowed:
+        startup["window_blocks"] = core.engine.num_window_blocks
+        startup["sliding_window"] = core.cfg.sliding_window
+        startup["block_size"] = core.engine.block_size
+        startup["megastep_k"] = core.engine.megastep
+        startup["window_table_blocks"] = core.engine.window_table_blocks(
+            core.cfg.sliding_window)
+        startup["window_bytes_per_sequence"] = core.cfg.window_bytes_per_sequence(
+            core.engine.block_size)
+        log.info(
+            "window %d: %d window layers in a pool of %d blocks (%d B a decoding "
+            "sequence), tables of %d columns; prefix caching off",
+            core.cfg.sliding_window, core.cfg.cache_layers("window"),
+            core.engine.num_window_blocks, startup["window_bytes_per_sequence"],
+            startup["window_table_blocks"])
     if core.cfg.shared_sparse:
         startup["experts_held"] = list(core.cfg.experts_held_range)
     log.info(
@@ -948,9 +969,9 @@ async def run_jax_worker(
 
     else:
         peer_kv = None
-        if core.cfg.hybrid:
+        if core.cfg.layer_groups:
             # No kv_fetch endpoint and no peer client (option "peer_kv"):
-            # a hybrid cache's blocks do not leave the device, so a
+            # a hybrid or two-pool cache's blocks do not leave the device, so a
             # router's peer hint is left unanswered and the prefix is
             # computed here.
             log.info("peer KV pulls are not carried for %r: kv_fetch not "

@@ -14,7 +14,9 @@ from dataclasses import dataclass, field
 import jax.numpy as jnp
 
 # What ModelConfig.layer_types may name, as published.
-LAYER_KINDS = ("conv", "full_attention")
+LAYER_KINDS = ("conv", "full_attention", "sliding_attention")
+# What a layer caches (ModelConfig.layer_kind), by its published kind.
+_CACHE_KIND = {"conv": "conv", "full_attention": "attention", "sliding_attention": "window"}
 
 _DTYPES = {
     "bfloat16": jnp.bfloat16,
@@ -27,7 +29,8 @@ class UnsupportedModelOption(NotImplementedError):
     """An engine option that this model's cache page or layers do not
     carry, refused at start-up. ``option`` names it (``kv_dtype``, ``tp``,
     ``pp``, ``ring_prefill``, ``spec_decode``, ``host_kv_blocks``,
-    ``disk_kv_dir``, ``disagg``, ``peer_kv``, ``quant``)."""
+    ``disk_kv_dir``, ``disagg``, ``peer_kv``, ``quant``,
+    ``prefix_caching``)."""
 
     def __init__(self, option: str, model: str, why: str):
         super().__init__(f"{option} is not carried for model {model!r}: {why}")
@@ -148,18 +151,47 @@ class ModelConfig:
     # pages), which then keeps the page and the options it had
     # (core._unpaired_where_not_carried).
     kv_pairing: bool = True
+    # -- window and full attention layers mixed (Laguna) ----------------------
+    # A "sliding_attention" layer's query at position p sees the keys at p -
+    # sliding_window + 1 .. p (its own position counted) and no others, so
+    # a sequence needs that layer's K/V of its newest sliding_window tokens
+    # only: such layers keep their pages in a POOL of their own
+    # (:meth:`layer_kind` "window"; EngineConfig.num_window_blocks), whose
+    # blocks a sequence gives back as they slide out.
+    sliding_window: int = 0
+    # Query heads of each layer (``num_attention_heads_per_layer``); None:
+    # num_heads everywhere. KV heads and head_dim are one for all layers.
+    heads_per_layer: tuple[int, ...] | None = None
+    # Rope parameters by published layer kind (``rope_parameters``):
+    # {"full_attention": {...}, "sliding_attention": {...}}, each with
+    # rope_theta, partial_rotary_factor (the share of a head's values that
+    # is rotated, from the front) and rope_type "default" or "yarn" (then
+    # factor, original_max_position_embeddings, beta_fast, beta_slow and
+    # attention_factor, which multiplies cos and sin). Frozen to sorted
+    # items like rope_scaling. None: rope_theta over the whole head.
+    rope_by_kind: tuple | dict | None = None
+    # A gate on each head's attention output, sigmoid(norm(x) Wg) with Wg
+    # [h, heads] (``gating`` "per-head"), before the output projection.
+    attn_gate: bool = False
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
             object.__setattr__(
                 self, "rope_scaling", tuple(sorted(self.rope_scaling.items()))
             )
+        if isinstance(self.rope_by_kind, dict):
+            object.__setattr__(self, "rope_by_kind", tuple(sorted(
+                (kind, tuple(sorted(dict(rp).items())))
+                for kind, rp in self.rope_by_kind.items())))
+        if self.heads_per_layer is not None:
+            object.__setattr__(self, "heads_per_layer", tuple(self.heads_per_layer))
         if self.experts_held is not None:
             object.__setattr__(self, "experts_held", tuple(self.experts_held))
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
         self._check_latent_sparse()
         self._check_hybrid()
+        self._check_windowed()
         if self.ut_steps < 1:
             raise ValueError(f"ut_steps={self.ut_steps} must be >= 1")
         if self.ut_steps > 1 and self.early_exit_threshold < 1.0:
@@ -284,9 +316,94 @@ class ModelConfig:
                 "conv layers beside the softmax-routed (mixtral) MLP are not implemented"
             )
 
+    def _check_windowed(self) -> None:
+        """``sliding_window``, ``heads_per_layer``, ``rope_by_kind`` and
+        ``attn_gate``: a field that does not apply, or a combination no
+        program was compared for, raises by name."""
+        windowed = self.windowed
+        if windowed != (self.sliding_window > 0):
+            raise ValueError(
+                f"sliding_window={self.sliding_window} with layer_types="
+                f"{self.layer_types}: a 'sliding_attention' layer needs a window, "
+                "and only such a layer reads one")
+        per_layer = self.heads_per_layer is not None or self.rope_by_kind is not None
+        if not (windowed or per_layer or self.attn_gate):
+            return
+        if not windowed:
+            raise ValueError(
+                "heads_per_layer, rope_by_kind or attn_gate set without a "
+                "'sliding_attention' layer: only a model with such layers reads them")
+        if self.latent or self.hybrid or self.ut_steps > 1 or self.sandwich_norm \
+                or self.attn_qkv_bias or self.qk_norm or self.kv_head_pairs:
+            raise NotImplementedError(
+                "sliding_attention layers, heads_per_layer, rope_by_kind or "
+                "attn_gate with attention='mla', conv layers, ut_steps > 1, "
+                "sandwich_norm, attn_qkv_bias, qk_norm or paired 64-wide heads "
+                "are not implemented")
+        if self.is_moe and not self.shared_sparse:
+            raise NotImplementedError(
+                "these layers beside the softmax-routed (mixtral) MLP are not implemented")
+        if self.heads_per_layer is not None and (
+                len(self.heads_per_layer) != self.num_layers
+                or any(n <= 0 or n % self.num_kv_heads for n in self.heads_per_layer)):
+            raise ValueError(
+                f"heads_per_layer={self.heads_per_layer} must give each of the "
+                f"{self.num_layers} layers a multiple of num_kv_heads={self.num_kv_heads}")
+        for kind, rp in self.rope_by_kind or ():
+            rp = dict(rp)
+            if kind not in ("full_attention", "sliding_attention") \
+                    or rp.get("rope_type", "default") not in ("default", "yarn"):
+                raise ValueError(f"rope_by_kind[{kind!r}]={rp}: a layer kind that "
+                                 "attends, rope_type 'default' or 'yarn'")
+            if int(self.head_dim * rp.get("partial_rotary_factor", 1)) % 2:
+                raise ValueError(f"rope_by_kind[{kind!r}]: an odd count of rotated values")
+
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def windowed(self) -> bool:
+        """Some layers attend a sliding window and keep their pages in the
+        window pool."""
+        return self.layer_types is not None and "sliding_attention" in self.layer_types
+
+    @property
+    def wave_query_chunk(self) -> int:
+        """Queries a piece, where a wave of a windowed model goes to the
+        attention kernel in pieces (ops/ragged_attention.py,
+        ``split_query_chunks``): the kernel walks every key a piece's table
+        holds for every query of it, so a piece of a quarter window walks
+        at most 1.25 windows and a page where the whole chunk of a wave
+        walked window + chunk."""
+        return max(1, self.sliding_window // 4)
+
+    @property
+    def layer_groups(self) -> bool:
+        """The layers' operators are kept apart by kind in the parameter
+        tree (``attn``, ``attn_window``, ``conv``), one entry a layer of the
+        kind: their shapes differ."""
+        return self.hybrid or self.windowed
+
+    def heads_of(self, l: int) -> int:
+        """Query heads of layer ``l``."""
+        return self.num_heads if self.heads_per_layer is None else self.heads_per_layer[l]
+
+    def rope_of(self, kind: str) -> dict:
+        """Rope parameters of the layers of published ``kind``
+        (``rope_by_kind``); the model's one ``rope_theta`` over the whole
+        head where none are given."""
+        for k, rp in self.rope_by_kind or ():
+            if k == kind:
+                return dict(rp)
+        return {"rope_theta": self.rope_theta, "rope_type": "default",
+                "partial_rotary_factor": 1}
+
+    @property
+    def dense_mlp_layers(self) -> tuple[int, ...]:
+        """The layers whose MLP is the dense SwiGLU in a sparse model
+        (``mlp_only_layers``): the leading ``first_dense_layers``."""
+        return tuple(range(self.first_dense_layers))
 
     @property
     def hybrid(self) -> bool:
@@ -295,10 +412,10 @@ class ModelConfig:
 
     def layer_kind(self, l: int) -> str:
         """What layer ``l`` caches: "attention" (pages of K/V, or latent
-        rows) or "conv" (state pages)."""
-        if self.layer_types is None or self.layer_types[l] != "conv":
-            return "attention"
-        return "conv"
+        rows, for as long as the sequence lives), "conv" (state pages) or
+        "window" (pages of K/V in the window pool, held while a later query
+        may still see them)."""
+        return "attention" if self.layer_types is None else _CACHE_KIND[self.layer_types[l]]
 
     def layers_of(self, kind: str) -> tuple[int, ...]:
         return tuple(l for l in range(self.num_layers) if self.layer_kind(l) == kind)
@@ -345,6 +462,7 @@ class ModelConfig:
         * head_dim)``, the same bytes with two heads a row; latent, the
         ``(rows, lanes)`` that hold ``block_size x (kv_lora_rank +
         qk_rope_head_dim)`` values (ops/latent_attention.py, "The page").
+        "window": the same page as "attention", in a pool of its own.
         "conv": ``(conv_L_cache - 1, h / 128, 128)``, the newest rows of
         ``u`` written in the block, each in whole 128-lane rows (a ``[2,
         h]`` tail would pad its 2 sublanes to a tile's 16); whatever
@@ -375,8 +493,20 @@ class ModelConfig:
 
     @property
     def cache_layer_counts(self) -> dict[str, int]:
-        """``{"attention": n, "conv": n}``, as /health and /metrics give it."""
-        return {kind: self.cache_layers(kind) for kind in ("attention", "conv")}
+        """``{"attention": n, "conv": n}``, as /health and /metrics give
+        it; with ``"window"`` for a model that has such layers."""
+        kinds = ("attention", "conv") + (("window",) if self.windowed else ())
+        return {kind: self.cache_layers(kind) for kind in kinds}
+
+    def window_bytes_per_sequence(self, block_size: int) -> int:
+        """Bytes of K/V the window layers hold for one decoding sequence,
+        whatever its context: ``sliding_window / block_size + 1`` blocks
+        (the window's tokens, cut at block edges) in each."""
+        if not self.windowed:
+            return 0
+        blocks = self.sliding_window // block_size + 1
+        return (self.cache_layers("window") * blocks * block_size * self.kv_unit_values
+                * jnp.dtype(self.jax_dtype).itemsize)
 
     def state_bytes_per_block(self) -> int:
         """Bytes of convolution state one block holds over all conv
@@ -410,13 +540,17 @@ class ModelConfig:
     def q_size(self) -> int:
         return self.num_heads * self.head_dim
 
+    def q_size_of(self, l: int) -> int:
+        return self.heads_of(l) * self.head_dim
+
     @property
     def kv_size(self) -> int:
         return self.num_kv_heads * self.head_dim
 
-    def _attn_params(self) -> int:
-        """One layer's attention projections (and, latent, the two norms
-        inside them); the qkv bias is left out as before."""
+    def _attn_params(self, l: int = 0) -> int:
+        """Layer ``l``'s attention projections (and, latent, the two norms
+        inside them; with ``attn_gate`` the gate's); the qkv bias is left
+        out as before."""
         h = self.hidden_size
         if self.latent:
             H, dn, dr, dv = (self.num_heads, self.qk_nope_head_dim,
@@ -426,7 +560,9 @@ class ModelConfig:
                     + h * (rkv + dr) + rkv                 # wkv_a, kv_norm
                     + rkv * H * (dn + dv) + H * dv * h)    # wkv_b, wo
         qk_norms = 2 * self.head_dim if self.qk_norm else 0
-        return h * (self.q_size + 2 * self.kv_size) + self.q_size * h + qk_norms
+        q_size = self.q_size_of(l)
+        gate = h * self.heads_of(l) if self.attn_gate else 0
+        return h * (q_size + 2 * self.kv_size) + q_size * h + qk_norms + gate
 
     def _conv_params(self) -> int:
         """One conv layer's operator: ``in_proj [h, 3h]``, the depthwise
@@ -457,8 +593,10 @@ class ModelConfig:
         h, v = self.hidden_size, self.vocab_size
         norms = (4 if self.sandwich_norm else 2) * h
         n_conv = len(self.layers_of("conv"))
+        attn = sum(self._attn_params(l) for l in range(self.num_layers)
+                   if self.layer_kind(l) != "conv")
         total = (
-            v * h + (self.num_layers - n_conv) * self._attn_params()
+            v * h + attn
             + n_conv * self._conv_params() + self.num_layers * norms
             + self._mlp_params() + h + (0 if self.tie_embeddings else h * v)
         )
@@ -473,10 +611,11 @@ class ModelConfig:
         embeddings/norms at the model dtype). Sparse and latent models are
         served unquantised (model.init_params_quantized raises for them),
         so there is nothing to count."""
-        if self.is_moe or self.latent or self.hybrid:
+        if self.is_moe or self.latent or self.layer_groups:
             raise NotImplementedError(
-                f"int8 weights for {self.name!r}: experts, latent projections "
-                "and conv operators are served unquantised"
+                f"int8 weights for {self.name!r}: experts, latent projections, "
+                "conv operators and layers of more than one kind are served "
+                "unquantised"
             )
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         proj_per_layer = (
@@ -504,6 +643,11 @@ class EngineConfig:
     """
 
     num_kv_blocks: int = 2048
+    # Blocks of the WINDOW pool (a model with sliding_attention layers
+    # only: ModelConfig.windowed), which holds those layers' pages. 0 = as
+    # many as every lane decoding plus one widest prefill wave can hold
+    # (:meth:`window_blocks_auto`).
+    num_window_blocks: int = 0
     block_size: int = 32
     # Paged KV cache storage dtype (ISSUE 8): "bf16" keeps the classic
     # model-dtype pages (byte-for-byte the pre-quantization layout);
@@ -527,7 +671,10 @@ class EngineConfig:
     # disk-tier eviction truly forgets a block. None = off.
     disk_kv_dir: str | None = None
     disk_kv_blocks: int = 4096
-    enable_prefix_caching: bool = True
+    # None: on, unless the model's cache cannot find a block again by its
+    # hash (a model with window layers: off, and True is refused by name;
+    # core._resolve_window_pool). The engine holds the resolved bool.
+    enable_prefix_caching: bool | None = None
     # Decode batch buckets: compile decode at these widths only.
     decode_buckets: tuple[int, ...] = (8, 16, 32, 64)
     # Decode MEGASTEP (PERF.md r9): fuse this many decode iterations into
@@ -663,6 +810,28 @@ class EngineConfig:
     @property
     def max_blocks_per_seq(self) -> int:
         return (self.max_model_len + self.block_size - 1) // self.block_size
+
+    def window_span_blocks(self, window: int, tokens: int) -> int:
+        """Blocks that hold what ``tokens`` consecutive queries of one
+        sequence see through a window of ``window``, and write: positions
+        ``p - window + 1 .. p + tokens - 1`` for a first query at ``p``,
+        wherever ``p`` lies in its block."""
+        return (window + tokens - 2) // self.block_size + 2
+
+    def window_table_blocks(self, window: int) -> int:
+        """Columns of a sequence's window table, one width for every
+        program: the span of the most queries a sequence has in ONE
+        dispatch, a prefill chunk of the largest bucket that goes on as a
+        decode row for the rest of a megastep."""
+        return self.window_span_blocks(
+            window, self.prefill_buckets[-1] + self.megastep_k - 1)
+
+    def window_blocks_auto(self, window: int) -> int:
+        """``num_window_blocks`` where it is left 0: every lane's decode
+        span, and one widest wave's tokens behind ``prefill_batch``
+        cursors."""
+        return (self.max_num_seqs * self.window_span_blocks(window, self.megastep_k)
+                + self.prefill_buckets[-1] // self.block_size + 2 * self.prefill_batch)
 
     @property
     def token_budget(self) -> int:
@@ -952,6 +1121,88 @@ def tiny_lfm2(vocab_size: int = 384) -> ModelConfig:
     )
 
 
+_LAGUNA_PERIOD = ("full_attention",) + 3 * ("sliding_attention",)
+_LAGUNA_ROPE = {
+    "full_attention": {
+        "rope_theta": 500000, "rope_type": "yarn", "factor": 128,
+        "original_max_position_embeddings": 8192, "beta_slow": 1, "beta_fast": 32,
+        "attention_factor": 1.4852030263919618, "partial_rotary_factor": 0.5,
+    },
+    "sliding_attention": {
+        "rope_type": "default", "rope_theta": 10000, "partial_rotary_factor": 1,
+    },
+}
+
+
+def laguna_s21_ep8_9l() -> ModelConfig:
+    """Laguna-S-2.1 (poolside, model_type "laguna") as ONE chip of eight
+    holds its first stage: layers 0-8 of the 48 (full attention at 0, 4, 8
+    with 48 query heads and YaRN over half of each head; window-512
+    attention at 1-3 and 5-7 with 72; 8 KV heads of 128 on all; a per-head
+    output gate), the leading dense layer, then eight sparse layers with
+    32 of each one's 256 sigmoid-routed experts (rank 0, 10 a token)
+    beside the shared one, an eighth of the vocabulary. 6.40 GB in bf16."""
+    return ModelConfig(
+        name="laguna-s-2.1-ep8-9l",
+        vocab_size=12544,
+        hidden_size=3072,
+        intermediate_size=12288,
+        num_layers=9,
+        num_heads=48,
+        num_kv_heads=8,
+        head_dim=128,
+        rms_norm_eps=1e-6,
+        layer_types=(2 * _LAGUNA_PERIOD + ("full_attention",)),
+        heads_per_layer=(48, 72, 72, 72, 48, 72, 72, 72, 48),
+        sliding_window=512,
+        rope_by_kind=_LAGUNA_ROPE,
+        attn_gate=True,
+        first_dense_layers=1,
+        moe_intermediate_size=1024,
+        num_experts=256,
+        num_experts_per_tok=10,
+        router_scoring="sigmoid",
+        routed_scaling_factor=2.5,
+        num_shared_experts=1,
+        experts_held=(0, 8),
+    )
+
+
+def tiny_laguna(vocab_size: int = 384, experts_held=(0, 2)) -> ModelConfig:
+    """Laguna's shape at test size: one dense layer then four sparse ones,
+    one whole period (full attention with 4 query heads and YaRN over half
+    a head, then three window-8 layers with 6; 2 KV heads of 16; the
+    per-head gate), 8 sigmoid-routed experts of which 3 a token beside a
+    shared one, this chip holding half of them."""
+    rope = {k: dict(v) for k, v in _LAGUNA_ROPE.items()}
+    rope["full_attention"].update(factor=8, original_max_position_embeddings=32)
+    return ModelConfig(
+        name="tiny-laguna",
+        vocab_size=vocab_size,
+        hidden_size=64,
+        intermediate_size=160,
+        num_layers=5,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        rms_norm_eps=1e-6,
+        dtype="float32",
+        layer_types=_LAGUNA_PERIOD + ("full_attention",),
+        heads_per_layer=(4, 6, 6, 6, 4),
+        sliding_window=8,
+        rope_by_kind=rope,
+        attn_gate=True,
+        first_dense_layers=1,
+        moe_intermediate_size=32,
+        num_experts=8,
+        num_experts_per_tok=3,
+        router_scoring="sigmoid",
+        routed_scaling_factor=2.5,
+        num_shared_experts=1,
+        experts_held=experts_held,
+    )
+
+
 def tiny_loop(vocab_size: int = 384) -> ModelConfig:
     """The looped stack (Ouro's shape) at test size: 3 layers x 3 passes."""
     return ModelConfig(
@@ -1010,9 +1261,11 @@ PRESETS = {
     "ouro-2.6b": ouro_2_6b,
     "a.x-k1-ep16": axk1_ep16,
     "lfm2-24b-a2b-10l": lfm2_24b_a2b_10l,
+    "laguna-s-2.1-ep8-9l": laguna_s21_ep8_9l,
     "tiny": tiny_model,
     "tiny-moe": tiny_moe,
     "tiny-loop": tiny_loop,
     "tiny-axk1": tiny_axk1,
     "tiny-lfm2": tiny_lfm2,
+    "tiny-laguna": tiny_laguna,
 }

@@ -107,6 +107,17 @@ class Sequence:
     pinned_hashes: list[int] = field(default_factory=list)
     committed_blocks: int = 0                  # prefix of block_ids committed
     num_cached_tokens: int = 0
+    # The WINDOW pool's blocks (a model with sliding_attention layers):
+    # block ``win_first + j`` of the sequence lies in that pool's block
+    # ``win_ids[j]``; blocks before ``win_first`` slid out of every later
+    # query's window and were given back (EngineCore._hold_window). Left
+    # out of ``==``: the planner asks ``seq in ready`` for every lane, the
+    # generated ``__eq__`` builds a tuple of the compared fields, and past
+    # 30 of them CPython builds it as a list first: 1.1 -> 2.9 us a
+    # comparison, +12 ms of ``plan`` a dispatch at 128 lanes (PERF.md
+    # section 6, PR 39).
+    win_first: int = field(default=0, compare=False)
+    win_ids: list[int] = field(default_factory=list, compare=False)
     # -- progress --
     prefilled: int = 0      # prompt tokens with K/V written
     processed: int = 0      # all tokens with K/V written
@@ -163,6 +174,46 @@ class Sequence:
 
 
 _TWO_SHAPES = "one block holds pages of two shapes; [planes, *page] carries one"
+_TWO_POOLS = ("the window layers' pages lie in a pool of their own, whose blocks "
+              "are given back as they slide out; a block that leaves the device, "
+              "or is found again by its hash, carries the full layers' pages only")
+
+
+def _resolve_window_pool(model_cfg, engine_cfg):
+    """``engine_cfg`` with what a model's cache decides filled in.
+    ``enable_prefix_caching`` None becomes True, and False for a model with
+    window layers: a window block is not content-addressed, so a prefix
+    hit would find the full layers' pages and not the window layers' newest
+    ``sliding_window`` tokens (asked for by name, it is refused:
+    :func:`_refuse_uncarried_options`). ``num_window_blocks`` 0 becomes
+    what every lane decoding and one widest wave hold
+    (``EngineConfig.window_blocks_auto``); one dispatch's span for one
+    sequence has to fit with room to spare."""
+    windowed = model_cfg.windowed
+    prefix = engine_cfg.enable_prefix_caching
+    if not windowed:
+        if engine_cfg.num_window_blocks:
+            raise ValueError(
+                f"num_window_blocks={engine_cfg.num_window_blocks} for model "
+                f"{model_cfg.name!r}: only a model with sliding_attention layers "
+                "has a window pool")
+        if prefix is None:
+            return dataclasses.replace(engine_cfg, enable_prefix_caching=True)
+        return engine_cfg
+    w = model_cfg.sliding_window
+    blocks = engine_cfg.num_window_blocks or engine_cfg.window_blocks_auto(w)
+    least = engine_cfg.window_table_blocks(w) + engine_cfg.window_span_blocks(
+        w, engine_cfg.megastep_k)
+    if blocks < least:
+        raise ValueError(
+            f"num_window_blocks={blocks} cannot hold one sequence's widest "
+            f"dispatch beside one decoding lane ({least} blocks of "
+            f"{engine_cfg.block_size} tokens for window {w})")
+    if prefix is None:
+        log.info("model %s has sliding_attention layers: prefix caching is off "
+                 "(window blocks are not content-addressed)", model_cfg.name)
+    return dataclasses.replace(
+        engine_cfg, num_window_blocks=blocks, enable_prefix_caching=False)
 
 
 def _unpaired_where_not_carried(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh):
@@ -196,21 +247,25 @@ def _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh) -> 
     and peer pulls, which a hybrid cache refuses (a block that leaves the
     device is ``[planes, *page]`` of ONE shape:
     ``EngineCore.kv_page_shape``)."""
-    hybrid = model_cfg.hybrid
+    hybrid, windowed = model_cfg.hybrid, model_cfg.windowed
     # one chip's programs: the layers no mesh rule, stage body or verify row knows
-    if not (model_cfg.latent or model_cfg.shared_sparse or hybrid):
+    if not (model_cfg.latent or model_cfg.shared_sparse or hybrid or windowed):
         return
+    stays = _TWO_SHAPES if hybrid else _TWO_POOLS
     refused = {
-        "kv_dtype": engine_cfg.kv_quantized and (model_cfg.latent or hybrid) and (
+        "prefix_caching": windowed and engine_cfg.enable_prefix_caching is True
+        and _TWO_POOLS,
+        "kv_dtype": engine_cfg.kv_quantized and (model_cfg.latent or hybrid or windowed) and (
             "int8 pages keep a scale per slot and KV head; "
             + ("a latent page has no heads" if model_cfg.latent else
+               "the window pool's pages were not compared as int8" if windowed else
                "conv state pages and paired heads have no such scale")),
-        "host_kv_blocks": hybrid and engine_cfg.host_kv_blocks > 0 and _TWO_SHAPES,
-        "disk_kv_dir": hybrid and bool(engine_cfg.disk_kv_dir) and _TWO_SHAPES,
+        "host_kv_blocks": (hybrid or windowed) and engine_cfg.host_kv_blocks > 0 and stays,
+        "disk_kv_dir": (hybrid or windowed) and bool(engine_cfg.disk_kv_dir) and stays,
         "tp": mesh is not None
         and "no sharding rule for the latent projections, the held experts (a "
             "share is stated with experts_held, not with a mesh), conv "
-            "operators or paired KV heads",
+            "operators, paired KV heads or layers of unequal head counts",
         "pp": pp_mesh is not None
         and "the pipeline's stage body is the dense layer's",
         "ring_prefill": (sp_mesh is not None or engine_cfg.ring_prefill_threshold > 0)
@@ -218,6 +273,8 @@ def _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh) -> 
         "spec_decode": engine_cfg.spec_decode != "off" and (
             "a rejected draft has already overwritten the convolution's rolling "
             "state past the cursor the lane goes on from" if hybrid else
+            "a window block is given back by the cursor a verify row may fall "
+            "behind" if windowed else
             "verify rows were not compared with the reference for this model"),
     }
     for option, why in refused.items():
@@ -1010,6 +1067,7 @@ class EngineCore:
         bs = engine_cfg.block_size
         model_cfg = _unpaired_where_not_carried(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh)
         _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh)
+        engine_cfg = _resolve_window_pool(model_cfg, engine_cfg)
         for b in engine_cfg.prefill_buckets:
             if b % bs:
                 raise ValueError(f"prefill bucket {b} not a multiple of block_size {bs}")
@@ -1301,13 +1359,20 @@ class EngineCore:
                 jax.random.PRNGKey(seed), model_cfg
             )
             self.cache = init_cache(model_cfg, engine_cfg)
+        # A window model's blocks are found again by no one (prefix caching
+        # is off for it): their KV events are not published.
         self.allocator = DeviceBlockAllocator(
             engine_cfg.num_kv_blocks,
             bs,
             enable_prefix_caching=engine_cfg.enable_prefix_caching,
-            on_stored=on_stored,
-            on_removed=on_removed,
+            on_stored=None if model_cfg.windowed else on_stored,
+            on_removed=None if model_cfg.windowed else on_removed,
         )
+        # The window pool: block ids of its own over the window layers'
+        # page arrays (model.cache_for_blocks), never hashed, never cached.
+        self.window_allocator = DeviceBlockAllocator(
+            engine_cfg.num_window_blocks, bs, enable_prefix_caching=False,
+        ) if model_cfg.windowed else None
         self.host_pool = None
         self.disk_pool = None
         self.offload = None
@@ -1575,6 +1640,9 @@ class EngineCore:
             # Looped stacks (ISSUE 27): passes over the layer stack, per
             # live lane and iteration (_mark_dispatch).
             "layer_passes": 0,
+            # Window-pool blocks given back while their sequence went on
+            # (_release_window_behind); 0 for a model without such a pool.
+            "window_blocks_released": 0,
         }
         # Crash/stall flight recorder (ISSUE 13): one record per step
         # with outputs — step shape, lane cursors, cumulative dispatch
@@ -1935,9 +2003,25 @@ class EngineCore:
                 + ("ragged" if kind == "prefill" else "decode")
             ),
             attention=self.cfg.attention,
+            **self._window_traced(kind),
             **self._experts_traced(kind, padded),
             **attrs,
         )
+
+    def _window_traced(self, kind: str) -> dict[str, Any]:
+        """What only a window model's dispatch annotation has: the window,
+        the query heads of its full / window layers, and what its window
+        calls of that shape were traced with."""
+        if not self.cfg.windowed:
+            return {}
+        heads = {k: self.cfg.heads_of(self.cfg.layers_of(k)[0])
+                 for k in ("attention", "window") if self.cfg.layers_of(k)}
+        return {
+            "window": self.cfg.sliding_window,
+            "heads": "/".join(str(n) for n in heads.values()),
+            "attn_window": traced_impl(
+                "window-ragged" if kind == "prefill" else "window-decode"),
+        }
 
     def _experts_traced(self, kind: str, tokens: int) -> dict[str, str]:
         """``{"experts": path}`` of a sparse model's dispatch, for its
@@ -2158,9 +2242,12 @@ class EngineCore:
 
     def _refuse_leaving_the_device(self, option: str) -> None:
         """A block of a hybrid cache (K/V pages beside conv state pages)
-        does not leave the device: every way out names its option."""
+        or of a two-pool cache (full layers beside window layers) does not
+        leave the device: every way out names its option."""
         if self.cfg.hybrid:
             raise UnsupportedModelOption(option, self.cfg.name, _TWO_SHAPES)
+        if self.cfg.windowed:
+            raise UnsupportedModelOption(option, self.cfg.name, _TWO_POOLS)
 
     @property
     def kv_page_shape(self) -> tuple[int, ...]:
@@ -2181,7 +2268,7 @@ class EngineCore:
         int8 cache included."""
         from dynamo_tpu.engine.kv_quant import kv_page_bytes
 
-        if self.cfg.latent or self.cfg.hybrid:
+        if self.cfg.latent or self.cfg.layer_groups:
             return (self.cfg.num_cache_layers * self.cfg.kv_unit_values
                     * np.dtype(self.cfg.jax_dtype).itemsize)
         return kv_page_bytes(
@@ -2291,10 +2378,70 @@ class EngineCore:
         spec = PartitionSpec("dp", *([None] * (arr.ndim - 1)))
         return jax.device_put(arr, NamedSharding(self.mesh, spec))
 
-    def _table_array(self, block_ids: list[int]) -> np.ndarray:
-        t = np.full(self.engine.max_blocks_per_seq, self.engine.garbage_block, np.int32)
-        t[: len(block_ids)] = block_ids
+    def _blank_tables(self, rows: int) -> np.ndarray:
+        """``rows`` block tables that point nowhere: every column the
+        garbage page. A window model's row is three parts sent as one
+        (model.split_tables): the full pool's table, the index of the
+        window table's first block, the window pool's table."""
+        P = self.engine.max_blocks_per_seq
+        if self.window_allocator is None:
+            return np.full((rows, P), self.engine.garbage_block, np.int32)
+        W = self.engine.window_table_blocks(self.cfg.sliding_window)
+        t = np.full((rows, P + 1 + W), self.engine.num_window_blocks, np.int32)
+        t[:, :P] = self.engine.garbage_block
+        t[:, P] = 0
         return t
+
+    def _table_row(self, row: np.ndarray, seq: Sequence) -> None:
+        """Fill ``row`` of :meth:`_blank_tables` with ``seq``'s blocks, as
+        the plan that is being dispatched grew and slid them
+        (:meth:`_grow_blocks`, :meth:`_hold_window`)."""
+        row[: len(seq.block_ids)] = seq.block_ids
+        if self.window_allocator is not None:
+            P = self.engine.max_blocks_per_seq
+            row[P] = seq.win_first
+            row[P + 1 : P + 1 + len(seq.win_ids)] = seq.win_ids
+
+    def _hold_window(self, seq: Sequence, p0: int, n_tokens: int) -> bool:
+        """Make ``seq``'s window blocks those that its next ``n_tokens``
+        queries, the first at position ``p0``, see and write: positions
+        ``p0 - window + 1 .. p0 + n_tokens - 1``. Blocks wholly before them
+        slid out of every later query's window (a cursor never goes back
+        for a sequence that goes on) and return to the pool NOW: the device
+        runs dispatches in order, so whoever is handed them next writes them
+        after every dispatch already enqueued has read them. False, with
+        nothing new held, where the pool cannot give the blocks ahead: the
+        caller preempts, drains or cuts its wave as for the full pool.
+        True at once for a model without window layers."""
+        pool = self.window_allocator
+        if pool is None:
+            return True
+        self._release_window_behind(seq, p0)
+        need = ((p0 + n_tokens - 1) // self.engine.block_size + 1
+                - (seq.win_first + len(seq.win_ids)))
+        if need > pool.free_blocks:
+            return False
+        seq.win_ids.extend(pool.alloc() for _ in range(max(0, need)))
+        return True
+
+    def _release_window_behind(self, seq: Sequence, p: int) -> None:
+        """Give back ``seq``'s window blocks that lie wholly before what a
+        query at position ``p`` sees (:meth:`_hold_window`'s first half). A
+        wave calls it for ``p`` = the position AFTER its chunk as soon as
+        the chunk is dispatched: a prompt that waits for its next chunk, or
+        for its first decode step behind other prompts' waves, then holds
+        its window and not its last chunk too (48 prompts that arrive
+        together otherwise fill the pool with chunks no one will read, and
+        the lanes that decode are preempted for it)."""
+        first = max(0, p - self.cfg.sliding_window + 1) // self.engine.block_size
+        behind = min(first - seq.win_first, len(seq.win_ids))
+        if behind > 0:
+            for b in seq.win_ids[:behind]:
+                self.window_allocator.free_partial(b)
+            del seq.win_ids[:behind]
+            self.exec_stats["window_blocks_released"] += behind
+        if first > seq.win_first:
+            seq.win_first = first
 
     def _commit_completed(self, seq: Sequence, completed) -> None:
         for blk in completed:
@@ -2325,7 +2472,6 @@ class EngineCore:
         ``force_R`` keeps the verify sample width even when every row is
         q_len=1 — a device-drafting dispatch needs the R-wide slots for
         its inner rounds although iteration 0 carries no host draft."""
-        P = self.engine.max_blocks_per_seq
         bs = self.engine.block_size
         total = sum(len(tl) for _, tl, _, _ in rows)
         T = self._bucket_for(total)
@@ -2344,7 +2490,7 @@ class EngineCore:
         write_pages = np.full(T, self.engine.garbage_block, np.int32)
         write_offs = np.zeros(T, np.int32)
         kv_lens = np.zeros(S, np.int32)
-        tables = np.full((S, P), self.engine.garbage_block, np.int32)
+        tables = self._blank_tables(S)
         cu = np.zeros(S + 1, np.int32)
         last_rows = np.zeros(S, np.int32)
         # Sample gather + per-slot rng counters [S, R]: slot (i, j) of a
@@ -2376,7 +2522,7 @@ class EngineCore:
             write_pages[t : t + chunk] = ids[pos // bs]
             write_offs[t : t + chunk] = pos % bs
             kv_lens[i] = kv_len
-            tables[i, : len(ids)] = ids
+            self._table_row(tables[i], seq)
             last_rows[i] = t + chunk - 1
             # Counters read through the optimistic overlay: with a step in
             # flight the lane's generated count lags by exactly the tokens
@@ -2915,8 +3061,12 @@ class EngineCore:
             if total >= budget:
                 break
             chunk = min(left, budget - total)
+            if not self._hold_window(seq, p0, chunk):
+                break  # the window pool is short: the wave ends here
             chosen.append((seq, p0, chunk))
             total += chunk
+        if not chosen:
+            return None  # not even the first prompt's chunk (_plan_waves goes on)
         bucket = self._bucket_for(total)
         self.prefill_waves[bucket] = self.prefill_waves.get(bucket, 0) + 1
         if total < tokens and budget < largest:
@@ -2927,6 +3077,9 @@ class EngineCore:
             self._mark_first_sched(seq, t_disp)
             rows.append((seq, seq.prompt[p0 : p0 + chunk], p0, p0 + chunk))
         pend = self._dispatch_ragged(rows, S, cover="+".join(map(str, cover)))
+        if self.window_allocator is not None:
+            for seq, p0, chunk in chosen:   # the tables are assembled: see the method
+                self._release_window_behind(seq, p0 + chunk)
         adv: dict[str, tuple[int, int, int]] = {}
         feed_index: dict[str, int] = {}
         feed_series: dict[str, tuple[int, int, int]] = {}
@@ -3124,6 +3277,8 @@ class EngineCore:
         covered)."""
         bs = self.engine.block_size
         base = self._eff_processed(seq)
+        if not self._hold_window(seq, base, n_tokens):
+            return False
         need = (base + n_tokens - 1) // bs + 1 - len(seq.block_ids)
         grabbed: list[int] = []
         for _ in range(max(0, need)):
@@ -3176,6 +3331,9 @@ class EngineCore:
         self.allocator.release(seq.pinned_hashes)
         seq.block_ids = seq.block_ids[: seq.committed_blocks]
         seq.pinned_hashes = []
+        for bid in seq.win_ids:   # the window pool's: held by no one else
+            self.window_allocator.free_partial(bid)
+        seq.win_ids, seq.win_first = [], 0
 
     def _arm_stop_inputs(
         self, seq: Sequence, i: int, watch: np.ndarray,
@@ -3231,9 +3389,7 @@ class EngineCore:
         W = MEGASTEP_WATCH_W
         tokens = np.zeros(B, np.int32)
         positions = np.zeros(B, np.int32)
-        tables = np.full(
-            (B, self.engine.max_blocks_per_seq), self.engine.garbage_block, np.int32
-        )
+        tables = self._blank_tables(B)
         active = np.zeros(B, bool)
         temp = np.ones(B, np.float32)
         top_k = np.zeros(B, np.int32)
@@ -3254,7 +3410,7 @@ class EngineCore:
             else:
                 tokens[i] = seq.pending
             positions[i] = self._eff_processed(seq)
-            tables[i, : len(seq.block_ids)] = seq.block_ids
+            self._table_row(tables[i], seq)
             active[i] = True
             temp[i] = seq.sampling.temperature
             top_k[i] = seq.sampling.top_k
@@ -3482,7 +3638,23 @@ class EngineCore:
                 # The ring path runs synchronously (sp engines keep the
                 # classic loop); wrap its already-committed outputs.
                 return _PlannedStep(core=self, commit_fn=lambda: ring_out)
-            return self._plan_prefill_wave(prefills)
+            plan = self._plan_prefill_wave(prefills)
+            if plan is not None:
+                return plan
+            # The window pool could not hold even the first prompt's next
+            # chunk. The lanes that decode go on: they end, and give their
+            # blocks back. With none to run, settle what is in flight, then
+            # take the blocks of the youngest sequence that holds any, as
+            # for a decode lane that cannot grow.
+            plan = self._plan_decode()
+            if plan is None:
+                if self._inflight is not None:
+                    raise _NeedDrain(prefills[0].request_id)
+                victim = next((s for s in reversed(self.running)
+                               if s is not prefills[0] and s.win_ids), None)
+                if victim is not None:
+                    self._preempt(victim)
+            return plan
         return self._plan_decode()
 
     def _decode_candidates(self) -> list[Sequence]:
@@ -3987,6 +4159,8 @@ class EngineCore:
                 chunk -= chunk % bs
                 if chunk <= 0:
                     continue
+            if not self._hold_window(seq, p0, chunk):
+                continue  # the window pool is short: this prompt waits
             self._mark_first_sched(seq, t_step)
             rows.append((seq, seq.prompt[p0 : p0 + chunk], p0, p0 + chunk))
             kinds.append("p")
@@ -4260,6 +4434,8 @@ class EngineCore:
                 chunk -= chunk % bs
                 if chunk <= 0:
                     continue
+            if not self._hold_window(seq, p0, chunk):
+                continue  # the window pool is short: this prompt waits
             self._mark_first_sched(seq, t_step)
             # A chunk that completes its prompt continues as a decode
             # row — when its watch fits the device flags, the context
@@ -5209,12 +5385,18 @@ class EngineCore:
             from dynamo_tpu.engine.model import cache_for_blocks
 
             # a plane per pass, a page shape per layer kind (model.init_cache)
-            self._embed_scratch = cache_for_blocks(
-                self.cfg, dataclasses.replace(self.engine, kv_dtype="bf16"),
-                -(-self.engine.prefill_buckets[-1] // bs),
-            )
+            blocks = -(-self.engine.prefill_buckets[-1] // bs)
+            scratch_engine = dataclasses.replace(self.engine, kv_dtype="bf16")
+            embed_engine = self.engine
+            if self.cfg.windowed:
+                # both of the scratch's pools hold the one prompt whole, and
+                # its table is as wide: the window table starts at block 0
+                scratch_engine = embed_engine = dataclasses.replace(
+                    scratch_engine, num_kv_blocks=blocks, num_window_blocks=blocks,
+                    max_model_len=blocks * bs)
+            self._embed_scratch = cache_for_blocks(self.cfg, scratch_engine, blocks)
             self._embed_fn = jax.jit(
-                _program(embed_forward, cfg=self.cfg, engine=self.engine, mesh=self.mesh),
+                _program(embed_forward, cfg=self.cfg, engine=embed_engine, mesh=self.mesh),
                 donate_argnums=(1,),
             )
         garbage = self._embed_scratch[0].shape[0] // self.cfg.ut_steps - 1
@@ -5226,6 +5408,9 @@ class EngineCore:
         write_pages[:T] = np.arange(T) // bs
         tables = np.full((1, garbage), garbage, np.int32)
         tables[0, :n_pages] = np.arange(n_pages)
+        if self.cfg.windowed:   # [full | first = 0 | window], the same blocks
+            tables = np.concatenate(
+                [tables, np.zeros((1, 1), np.int32), tables], axis=1)
         pooled, self._embed_scratch = self._embed_fn(
             self.params,
             self._embed_scratch,
@@ -5279,6 +5464,16 @@ class EngineCore:
         st["cache_layers"] = self.cfg.cache_layer_counts
         st["state_bytes_per_block"] = self.cfg.state_bytes_per_block()
         st["conv_state_reads"] = dict(self.conv_state_reads)
+        st["prefix_caching"] = bool(self.engine.enable_prefix_caching)
+        if self.window_allocator is not None:
+            # The window pool: its size, what is held now and what was given
+            # back, a decoding sequence's bytes there, the table's columns.
+            st["window_blocks"] = self.window_allocator.capacity
+            st["window_blocks_in_use"] = self.window_allocator.used_blocks
+            st["window_bytes_per_sequence"] = self.cfg.window_bytes_per_sequence(
+                self.engine.block_size)
+            st["window_table_blocks"] = self.engine.window_table_blocks(
+                self.cfg.sliding_window)
         st["attention"] = self.cfg.attention
         # A sparse model's share and what its router sent it, by the
         # program that counted (decode megasteps, prefill waves): held

@@ -104,6 +104,51 @@ def _hybrid_conv_config(hf: dict) -> ModelConfig:
     )
 
 
+# Published model types whose attention layers are of two kinds by
+# ``layer_types``, full and sliding-window, with query heads per layer, rope
+# parameters per kind and a per-head output gate (Laguna).
+WINDOWED_TYPES = ("laguna",)
+
+
+def _windowed_config(hf: dict, experts_held) -> ModelConfig:
+    """The published keys as ``chipbench/architectures/laguna.py`` reads
+    them; what the file has no key for (the gate's and the router's
+    nonlinearity) is the family's convention, listed under ``assumed`` in
+    the benchmark's configuration of it."""
+    kinds = list(hf.get("mlp_layer_types") or ["sparse"] * hf["num_hidden_layers"])
+    dense = kinds.index("sparse") if "sparse" in kinds else len(kinds)
+    if any(k != "sparse" for k in kinds[dense:]):
+        raise NotImplementedError("a dense MLP layer after a sparse one is not implemented")
+    if hf.get("gating", "per-head") != "per-head" or hf.get("moe_router_logit_softcapping"):
+        raise NotImplementedError("only per-head gating and an uncapped router are implemented")
+    return ModelConfig(
+        name=hf["model_type"],
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["head_dim"],
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        tie_embeddings=hf.get("tie_word_embeddings", False),
+        layer_types=tuple(hf["layer_types"]),
+        heads_per_layer=tuple(hf["num_attention_heads_per_layer"]),
+        sliding_window=hf["sliding_window"],
+        rope_by_kind=hf["rope_parameters"],
+        attn_gate=True,
+        first_dense_layers=dense,
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        router_scoring="sigmoid",
+        norm_topk_prob=hf.get("norm_topk_prob", True),
+        routed_scaling_factor=hf.get("moe_routed_scaling_factor", 1.0),
+        num_shared_experts=1,
+        experts_held=experts_held,
+    )
+
+
 def config_from_hf(path: str | Path, experts_held=None) -> ModelConfig:
     """``experts_held`` ``(rank, of)``: the share of a sparse model's routed
     experts to load (a model without a stated share refuses it)."""
@@ -111,12 +156,14 @@ def config_from_hf(path: str | Path, experts_held=None) -> ModelConfig:
         hf = json.load(f)
     if hf.get("model_type") in LATENT_SPARSE_TYPES:
         return _latent_sparse_config(hf, experts_held)
+    if hf.get("model_type") in WINDOWED_TYPES:
+        return _windowed_config(hf, experts_held)
     if hf.get("model_type") in HYBRID_CONV_TYPES and experts_held is None:
         return _hybrid_conv_config(hf)
     if experts_held is not None:
         raise ValueError(
-            f"experts_held={experts_held} for model_type "
-            f"{hf.get('model_type')!r}: only {LATENT_SPARSE_TYPES} state a share"
+            f"experts_held={experts_held} for model_type {hf.get('model_type')!r}: "
+            f"only {LATENT_SPARSE_TYPES + WINDOWED_TYPES} state a share"
         )
     head_dim = hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"]
     return ModelConfig(
@@ -346,12 +393,81 @@ def _load_hybrid_conv(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]
     return params
 
 
+def _load_windowed(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]:
+    """The tree of a model with full and window attention layers
+    (``model._init_attention_by_kind``: ``layers`` the two norms of every
+    layer, ``attn`` / ``attn_window`` one entry a layer of that kind, each
+    with its own query heads; ``dense_mlp``, ``moe`` as the latent sparse
+    model's) from the checkpoint's names: ``input_layernorm``,
+    ``post_attention_layernorm``; ``self_attn.{q_proj, k_proj, v_proj,
+    o_proj}`` and the gate ``self_attn.g_proj [heads, h]`` (rotate-half
+    rope: no permutation); a dense layer's ``mlp.{gate,up,down}_proj``; a
+    sparse layer's ``mlp.gate``, ``mlp.experts.<e>.*`` (the HELD experts
+    only) and ``mlp.shared_expert.*``."""
+    np_dt = np.dtype(dt)
+    L, Ld = cfg.num_layers, cfg.first_dense_layers
+    lo, hi = cfg.experts_held_range
+
+    def t(key: str) -> np.ndarray:
+        return np.asarray(sd[key], np.float32)
+
+    def proj(l: int, name: str) -> np.ndarray:
+        return t(f"model.layers.{l}.{name}.weight").T  # [in, out]
+
+    def stack(name: str, layers) -> np.ndarray:
+        return np.asarray(np.stack([proj(l, name) for l in layers]), np_dt)
+
+    def norms(name: str) -> np.ndarray:
+        return np.asarray(
+            np.stack([t(f"model.layers.{l}.{name}.weight") for l in range(L)]), np_dt)
+
+    def gate_up(prefix: str, layers) -> np.ndarray:
+        return np.concatenate(
+            [stack(f"{prefix}.gate_proj", layers), stack(f"{prefix}.up_proj", layers)], axis=-1)
+
+    def attention(layers) -> dict[str, Any]:
+        return {
+            "wqkv": np.asarray(_fuse_np(
+                [stack(f"self_attn.{n}", layers) for n in ("q_proj", "k_proj", "v_proj")],
+                tp), np_dt),
+            "wo": stack("self_attn.o_proj", layers),
+            "wg": stack("self_attn.g_proj", layers),
+        }
+
+    sparse = range(Ld, L)
+    params: dict[str, Any] = {
+        "layers": {"attn_norm": norms("input_layernorm"),
+                   "mlp_norm": norms("post_attention_layernorm")},
+        "attn": attention(cfg.layers_of("attention")),
+        "attn_window": attention(cfg.layers_of("window")),
+        "moe": {
+            "w_router": stack("mlp.gate", sparse),
+            # one array a sparse layer (model._init_shared_sparse_mlp)
+            "w_gu": tuple(np.stack(
+                [gate_up(f"mlp.experts.{e}", [l])[0] for e in range(lo, hi)]) for l in sparse),
+            "w_down": tuple(np.stack(
+                [stack(f"mlp.experts.{e}.down_proj", [l])[0] for e in range(lo, hi)])
+                for l in sparse),
+            "shared_wgu": gate_up("mlp.shared_expert", sparse),
+            "shared_down": stack("mlp.shared_expert.down_proj", sparse),
+        },
+    }
+    if Ld:
+        params["dense_mlp"] = {
+            "wgu": np.asarray(_fuse_np(
+                [stack("mlp.gate_proj", range(Ld)), stack("mlp.up_proj", range(Ld))], tp),
+                np_dt),
+            "w_down": stack("mlp.down_proj", range(Ld)),
+        }
+    return params
+
+
 def load_hf_llama(
     path: str | Path, dtype=None, tp: int = 1, quant: str | None = None,
     experts_held: tuple[int, int] | None = None,
 ) -> tuple[ModelConfig, Any]:
     """Returns (ModelConfig, params pytree) from an HF llama/qwen2/ouro/
-    axk1/lfm2_moe checkpoint (``experts_held``: see :func:`config_from_hf`).
+    axk1/lfm2_moe/laguna checkpoint (``experts_held``: see :func:`config_from_hf`).
 
     ``tp`` fixes the shard-blocked layout of the fused wqkv/wgu projections
     (model.fuse_qkv/fuse_gu) and must match the serving mesh's tp axis.
@@ -373,14 +489,16 @@ def load_hf_llama(
     def t(key: str) -> np.ndarray:
         return np.asarray(sd[key], np.float32)
 
-    if cfg.latent or cfg.hybrid:
+    if cfg.latent or cfg.layer_groups:
         if quant is not None or tp != 1:
             raise NotImplementedError(
                 f"quant={quant!r} / tp={tp} for {cfg.name!r}: experts, latent "
-                "projections and conv operators load unquantised, in the tp=1 layout"
+                "projections, conv operators and layers of more than one kind load "
+                "unquantised, in the tp=1 layout"
             )
         np_dt = np.dtype(dt)
-        load = _load_hybrid_conv if cfg.hybrid else _load_latent_sparse
+        load = (_load_hybrid_conv if cfg.hybrid else
+                _load_windowed if cfg.windowed else _load_latent_sparse)
         params = load(cfg, sd, dt, tp)
         final_norm = "model.embedding_norm.weight" if cfg.hybrid else "model.norm.weight"
         params.update({

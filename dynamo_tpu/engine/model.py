@@ -40,6 +40,13 @@ v5e, round 2):
   allocator, the prefix index and preemption see nothing new either.
   Heads 64 wide are cached two to a 128-wide row
   (``cfg.kv_head_pairs``) and attend through the same kernel.
+- **A pool per layer kind** (``sliding_attention`` layers, Laguna): a
+  layer whose queries see a window keeps its pages in a SECOND pool with
+  block ids, and a block table, of its own (:func:`split_tables`); a
+  sequence holds there the blocks a later query may still see and gives
+  the others back. Query heads (``cfg.heads_of``), rope
+  (:func:`kind_rope_tables`) and the table are the layer kind's; the
+  layer body is the one :func:`dense_layer`.
 """
 
 from __future__ import annotations
@@ -107,10 +114,11 @@ def init_params_quantized(rng: jax.Array, cfg: ModelConfig, tp: int = 1) -> Para
     copies (unrolled, this init took 135 s to compile for 28 layers on
     a v5e — longer than any serving program).
     """
-    if cfg.is_moe or cfg.latent or cfg.hybrid:
+    if cfg.is_moe or cfg.latent or cfg.layer_groups:
         raise NotImplementedError(
-            f"int8 weights for {cfg.name!r}: experts, latent projections and "
-            "conv operators are served unquantised (no int8 init for them)"
+            f"int8 weights for {cfg.name!r}: experts, latent projections, conv "
+            "operators and layers of more than one kind are served unquantised "
+            "(no int8 init for them)"
         )
     h, i, v, L = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_layers
     dt = cfg.jax_dtype
@@ -200,11 +208,14 @@ def fuse_gu(wg: jax.Array, wu: jax.Array, tp: int = 1) -> jax.Array:
 
 def split_qkv(qkv: jax.Array, cfg: ModelConfig, tp: int = 1):
     """Inverse of :func:`fuse_qkv` on activations ``[T, q+2kv]``: returns
-    (q [T, q_size], k [T, kv_size], v [T, kv_size]) in natural head order."""
+    (q [T, q_size], k [T, kv_size], v [T, kv_size]) in natural head order;
+    ``q_size`` is what the columns leave for it (the layer's own heads:
+    ``cfg.heads_of``)."""
     T = qkv.shape[0]
-    qs, kvs = cfg.q_size // tp, cfg.kv_size // tp
+    q_size = qkv.shape[1] - 2 * cfg.kv_size
+    qs, kvs = q_size // tp, cfg.kv_size // tp
     blocks = qkv.reshape(T, tp, qs + 2 * kvs)
-    q = blocks[:, :, :qs].reshape(T, cfg.q_size)
+    q = blocks[:, :, :qs].reshape(T, q_size)
     k = blocks[:, :, qs : qs + kvs].reshape(T, cfg.kv_size)
     v = blocks[:, :, qs + kvs :].reshape(T, cfg.kv_size)
     return q, k, v
@@ -242,6 +253,8 @@ def init_params(rng: jax.Array, cfg: ModelConfig, tp: int = 1) -> Params:
     extra: dict[str, Any] = {}
     if cfg.latent:
         layers.update(_init_latent_attention(rng, cfg, dense))
+    elif cfg.windowed:
+        extra.update(_init_attention_by_kind(rng, cfg, dense, tp))
     else:
         # A model with conv layers keeps its attention leaves apart, one
         # entry an ATTENTION layer (``attn``), beside ``conv``.
@@ -298,6 +311,43 @@ def _varied_ones(key, shape, dt):
     the wrong weight, in the wrong place or not at all changes the
     logits (as :func:`_init_loop_extras` draws its own)."""
     return (1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)).astype(dt)
+
+
+# The parameter group of each cache kind's operators (``cfg.layer_groups``).
+_GROUP_OF_KIND = {"attention": "attn", "window": "attn_window", "conv": "conv"}
+# A gate's logits are drawn this many times the fan-in scale: on a normed
+# input they are then ~N(0, 1.4^2) and the gates sigmoid of them, spread
+# over (0.2, 0.8) and not all near 0.5, so that a gate dropped, or taken
+# from the wrong head, moves the logits (tests/test_laguna.py).
+_GATE_LOGIT_SCALE = 1.4
+
+
+def _init_attention_by_kind(rng: jax.Array, cfg: ModelConfig, dense, tp: int) -> dict:
+    """The attention leaves of a model whose full and window layers differ
+    in their query heads: ``attn`` (one entry a full layer) and
+    ``attn_window`` (one a window layer), each ``wqkv [h, (n + 2 n_kv)
+    d]``, ``wo [n d, h]`` and, with ``cfg.attn_gate``, ``wg [h, n]`` for
+    ITS ``n`` query heads. Layer ``l``'s leaves are drawn from key ``l``
+    whatever its kind."""
+    h, d = cfg.hidden_size, cfg.head_dim
+    key = lambda l, n: jax.random.fold_in(jax.random.fold_in(rng, 70 + n), l)  # noqa: E731
+    out: dict[str, dict] = {}
+    for kind in ("attention", "window"):
+        layers = cfg.layers_of(kind)
+        if not layers:
+            continue
+        group: dict[str, list] = {"wqkv": [], "wo": []}
+        for l in layers:
+            q = cfg.q_size_of(l)
+            group["wqkv"].append(fuse_qkv(
+                dense(key(l, 0), (h, q), h), dense(key(l, 1), (h, cfg.kv_size), h),
+                dense(key(l, 2), (h, cfg.kv_size), h), tp))
+            group["wo"].append(dense(key(l, 3), (q, h), q))
+            if cfg.attn_gate:
+                group.setdefault("wg", []).append(dense(
+                    key(l, 4), (h, q // d), h / _GATE_LOGIT_SCALE ** 2))
+        out[_GROUP_OF_KIND[kind]] = {k: jnp.stack(v) for k, v in group.items()}
+    return out
 
 
 def _init_conv_operators(rng: jax.Array, cfg: ModelConfig, dense) -> dict:
@@ -432,16 +482,15 @@ def _init_shared_sparse_mlp(rng: jax.Array, cfg: ModelConfig, dense, tp: int) ->
 
 def layer_params(params: Params, l: int, cfg: ModelConfig) -> dict:
     """Layer ``l``'s leaves: ``params["layers"]`` at ``l``; where the
-    layers are of two kinds (``cfg.hybrid``), its operator's from
-    ``attn`` or ``conv`` at its index among its kind; and, where the
+    layers are of two kinds (``cfg.layer_groups``), its operator's from
+    ``attn``, ``attn_window`` or ``conv`` at its index among its kind; and, where the
     MLPs are kept apart (``cfg.shared_sparse``), the dense MLP of a
     leading layer or the sparse one of the others."""
     lp = jax.tree.map(lambda a: a[l], params["layers"])
-    if cfg.hybrid:
+    if cfg.layer_groups:
         kind = cfg.layer_kind(l)
         at = cfg.layers_of(kind).index(l)
-        group = "conv" if kind == "conv" else "attn"
-        lp.update({k: v[at] for k, v in params[group].items()})
+        lp.update({k: v[at] for k, v in params[_GROUP_OF_KIND[kind]].items()})
     if cfg.shared_sparse:
         Ld = cfg.first_dense_layers
         group, at = ("dense_mlp", l) if l < Ld else ("moe", l - Ld)
@@ -512,20 +561,26 @@ def init_cache(cfg: ModelConfig, engine: EngineConfig, dtype=None) -> tuple:
     ``num_kv_blocks + 1`` pages in each layer's array, plane ``u`` for
     pass ``u``: block ``b`` of pass ``u`` is page ``u * (num_kv_blocks +
     1) + b``, and every plane ends in a garbage page of its own."""
-    return cache_for_blocks(cfg, engine, engine.num_kv_blocks, dtype)
+    return cache_for_blocks(cfg, engine, engine.num_kv_blocks, dtype,
+                            window_blocks=engine.num_window_blocks)
 
 
-def cache_for_blocks(cfg: ModelConfig, engine: EngineConfig, blocks: int, dtype=None) -> tuple:
+def cache_for_blocks(cfg: ModelConfig, engine: EngineConfig, blocks: int, dtype=None,
+                     window_blocks: int | None = None) -> tuple:
     """:func:`init_cache` for ``blocks`` blocks and a garbage page: every
     layer's array ``[pages, *cfg.kv_page_tail(block_size, kind)]`` of ITS
     kind, indexed by the same block ids. A conv layer's pages hold its
-    state (:func:`conv_layer`), an attention layer's its K/V."""
+    state (:func:`conv_layer`), an attention layer's its K/V. A WINDOW
+    layer's array is the window pool's: ``window_blocks`` blocks (as many
+    as ``blocks`` where not given) and a garbage page, under block ids of
+    that pool's own."""
     dtype = dtype or cfg.jax_dtype
-    pages = cfg.ut_steps * (blocks + 1)
-    shapes = [
-        (pages, *cfg.kv_page_tail(engine.block_size, cfg.layer_kind(l)))
-        for l in range(cfg.num_layers)
-    ]
+    window_blocks = blocks if window_blocks is None else window_blocks
+    shapes = []
+    for l in range(cfg.num_layers):
+        kind = cfg.layer_kind(l)
+        pages = cfg.ut_steps * ((window_blocks if kind == "window" else blocks) + 1)
+        shapes.append((pages, *cfg.kv_page_tail(engine.block_size, kind)))
     if engine.kv_quantized:
         _refuse_int8_latent(cfg)
         return tuple(
@@ -566,6 +621,10 @@ def init_cache_stacked(
 
 
 def _refuse_int8_latent(cfg: ModelConfig) -> None:
+    if cfg.windowed:
+        raise NotImplementedError(
+            "kv_dtype='int8' with sliding_attention layers: the window pool's "
+            "pages were not compared as int8")
     if cfg.hybrid or cfg.kv_head_pairs:
         raise NotImplementedError(
             "kv_dtype='int8' with conv state pages or paired KV heads: the "
@@ -640,6 +699,23 @@ def latent_rope_tables(positions: jax.Array, cfg: ModelConfig):
     return jnp.cos(angles) * m, jnp.sin(angles) * m
 
 
+def kind_rope_tables(positions: jax.Array, cfg: ModelConfig, kind: str):
+    """(cos, sin) ``[T, r/2]`` of the layers of published ``kind``
+    (``cfg.rope_of``), ``r = head_dim x partial_rotary_factor`` the values
+    of a head that are rotated, from the front (:func:`rope_apply` passes
+    the others through). rope_type "yarn": :func:`yarn_inv_freq` over those
+    ``r`` values, cos and sin both multiplied by ``attention_factor``."""
+    rp = cfg.rope_of(kind)
+    r = int(cfg.head_dim * rp.get("partial_rotary_factor", 1))
+    theta = float(rp["rope_theta"])
+    if rp.get("rope_type", "default") != "yarn":
+        return rope_tables(positions, r, theta)
+    with jax.named_scope("rope_yarn"):
+        angles = positions[..., None].astype(jnp.float32) * yarn_inv_freq(r, theta, rp)
+        m = float(rp.get("attention_factor", 1.0))
+        return jnp.cos(angles) * m, jnp.sin(angles) * m
+
+
 def latent_sm_scale(cfg: ModelConfig) -> float:
     """``(dn + dr)^-0.5``, times YaRN's ``mscale(factor,
     mscale_all_dim)^2`` where the rope is scaled."""
@@ -653,7 +729,13 @@ def latent_sm_scale(cfg: ModelConfig) -> float:
 def rope_apply(
     x: jax.Array, cos: jax.Array, sin: jax.Array
 ) -> jax.Array:
-    """Rotate ``x`` ``[..., T, n, d]`` by precomputed tables ``[..., T, d/2]``."""
+    """Rotate ``x`` ``[..., T, n, d]`` by precomputed tables ``[..., T,
+    r/2]``: the first ``r`` values of a head in half-split pairs, the
+    other ``d - r`` passed through (``r = d`` for every model but one with
+    a ``partial_rotary_factor``)."""
+    r = 2 * cos.shape[-1]
+    if r < x.shape[-1]:
+        return jnp.concatenate([rope_apply(x[..., :r], cos, sin), x[..., r:]], axis=-1)
     cos = cos[..., None, :]
     sin = sin[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -1206,6 +1288,7 @@ def dense_layer(
     rope_cs: tuple[jax.Array, jax.Array] | None = None,
     row_valid: jax.Array | None = None,
     expert_stats: list | None = None,
+    window: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """One transformer block over a ragged token batch: attn-norm → fused
     qkv (→ per-head RMSNorm of q and k where the layer has
@@ -1217,9 +1300,21 @@ def dense_layer(
     on ONE layer's page array is also the perf contract: the Pallas
     attention call must see its own buffer, not a slice of a stacked
     tensor (see :func:`init_cache`). ``rope_cs`` carries the per-pass
-    precomputed rotary tables (:func:`rope_tables`).
+    precomputed rotary tables (:func:`rope_tables`; the layer kind's own
+    where kinds differ, :func:`kind_rope_tables`). The layer's query heads
+    are what its ``wqkv`` holds beside the KV heads.
 
-    The ``jax.named_scope`` sections (``qkv`` holding ``qk_norm``, ``kv_write``, ``attn``,
+    ``window`` (a ``sliding_attention`` layer): ``cache_l`` is the window
+    pool's array, and ``write_pages``, ``kv_lens`` and ``block_tables`` are
+    the WINDOW's (:func:`split_tables`): the table's first column the page
+    that holds the oldest key a query of this call may see, ``kv_lens``
+    counted from that page's first token. The call then reads the window's
+    pages alone (ops/ragged_attention.py, "A window"). With ``wg`` in the
+    layer's leaves each head's output is gated by ``sigmoid(y wg)`` of the
+    SAME normed input, inside ``o_proj`` (scope ``attn_gate``).
+
+    The ``jax.named_scope`` sections (``qkv`` holding ``qk_norm``, ``kv_write``, ``attn``
+    (holding ``full`` or ``window`` where a model has both),
     ``o_proj``, ``mlp``; ``embed`` and ``lm_head`` around the stack) put
     the model's own names on the device ops of a profile. They change op
     metadata only: the lowered program and its compile-cache key stay
@@ -1238,7 +1333,7 @@ def dense_layer(
             qkv = qkv + lp["bqkv"]
         qkv = qkv.astype(dt)
         q, k, v = split_qkv(qkv, cfg, tp)
-        q = q.reshape(T, cfg.num_heads, cfg.head_dim)
+        q = q.reshape(T, -1, cfg.head_dim)
         k = k.reshape(T, cfg.num_kv_heads, cfg.head_dim)
         if "q_layernorm" in lp:  # per head, BEFORE rope
             with jax.named_scope("qk_norm"):
@@ -1254,7 +1349,14 @@ def dense_layer(
     else:
         kv_pages, kv_scales = cache_l, None
     with jax.named_scope("attn"):
-        if mesh is not None:
+        if cfg.windowed:
+            with jax.named_scope("window" if window else "full"):
+                attn = ragged_paged_attention(
+                    q, kv_pages, kv_lens, block_tables, cu_q_lens, num_seqs,
+                    sm_scale=sm_scale, window=window,
+                    query_chunk=cfg.wave_query_chunk,
+                )
+        elif mesh is not None:
             attn = sharded_ragged_attention(
                 mesh, q, kv_pages, kv_lens, block_tables, cu_q_lens,
                 num_seqs, sm_scale=sm_scale, kv_scales=kv_scales,
@@ -1269,11 +1371,31 @@ def dense_layer(
                 q, kv_pages, kv_lens, block_tables, cu_q_lens, num_seqs,
                 sm_scale=sm_scale, kv_scales=kv_scales,
             )
+    if "wg" in lp:
+        with jax.named_scope("o_proj"), jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(_dot(y, lp["wg"]))             # [T, heads] f32
+            attn = (attn.astype(jnp.float32) * gate[:, :, None]).astype(dt)
     x = _attn_out_and_mlp(
-        x, attn.reshape(T, cfg.q_size), lp, cfg, tp, mesh,
+        x, attn.reshape(T, -1), lp, cfg, tp, mesh,
         row_valid=row_valid, expert_stats=expert_stats,
     )
     return x, cache_l
+
+
+def split_tables(block_tables: jax.Array, cfg: ModelConfig, engine: EngineConfig):
+    """A window model's block table ``[S, P + 1 + W]`` (``P =
+    engine.max_blocks_per_seq``) in its three parts: the FULL pool's table
+    ``[S, P]``, block ``j`` of the sequence in column ``j`` as ever; one
+    column ``first`` ``[S]``, the index among the sequence's blocks of the
+    one in the window table's column 0; and the WINDOW pool's table ``[S,
+    W]``, block ``first + j`` of the sequence in column ``j`` (the host
+    assembles the three and sends them as one array: ``EngineCore.
+    _table_row``). Every other model's table is the full one: ``(tables,
+    None, None)``."""
+    if not cfg.windowed:
+        return block_tables, None, None
+    P = engine.max_blocks_per_seq
+    return block_tables[:, :P], block_tables[:, P], block_tables[:, P + 1:]
 
 
 def latent_layer(
@@ -1583,13 +1705,46 @@ def forward_hidden(
             x = jnp.where(mm_mask[:, None], mm_embeds.astype(x.dtype), x)
         if cfg.latent:
             rope_cs = latent_rope_tables(positions, cfg)
+        elif cfg.windowed:
+            rope_cs = None   # a pair of tables a layer kind, below
         else:
             rope_cs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
         # Padding rows (and a megastep's dead lanes) write the garbage
         # page: they route to no expert and are not counted.
         row_valid = write_pages != engine.garbage_block if cfg.shared_sparse else None
+    block_tables, win_first, win_tables = split_tables(block_tables, cfg, engine)
+    if win_tables is not None:
+        with jax.named_scope("qkv"):
+            rope_cs = {kind: kind_rope_tables(positions, cfg, kind)
+                       for kind in dict.fromkeys(cfg.layer_types)}
+        with jax.named_scope("kv_write"):
+            # Where each row's K/V lands in the WINDOW pool: the page of its
+            # position's block, counted from the window table's first; a
+            # row that writes the full pool's garbage page writes this
+            # pool's (its last page).
+            T, bs = tokens.shape[0], engine.block_size
+            if cu_q_lens is None:
+                seq_of = jnp.arange(T, dtype=jnp.int32)
+            else:
+                seq_of = jnp.minimum(
+                    jnp.sum(jnp.arange(T, dtype=jnp.int32)[:, None] >= cu_q_lens[None, 1:],
+                            axis=1), win_tables.shape[0] - 1).astype(jnp.int32)
+            column = jnp.clip(positions // bs - win_first[seq_of], 0, win_tables.shape[1] - 1)
+            win_write = jnp.where(write_pages == engine.garbage_block,
+                                  engine.num_window_blocks, win_tables[seq_of, column])
+            win_kv_lens = kv_lens - bs * win_first
 
-    def layer(x, lp, cache_l, write_pages, block_tables):
+    def layer(x, lp, cache_l, write_pages, block_tables, l):
+        if cfg.windowed:  # full and window attention layers, a pool each
+            window = cfg.sliding_window if cfg.layer_kind(l) == "window" else None
+            return dense_layer(
+                x, lp, cache_l, positions,
+                win_write if window else write_pages, write_offs,
+                win_kv_lens if window else kv_lens,
+                win_tables if window else block_tables, cu_q_lens, num_seqs, cfg,
+                tp=tp, mesh=mesh, rope_cs=rope_cs[cfg.layer_types[l]],
+                row_valid=row_valid, expert_stats=expert_stats, window=window,
+            )
         if "in_proj" in lp:  # a conv layer's leaves (cfg.layer_types)
             return conv_layer(
                 x, lp, cache_l, positions, write_pages, block_tables,
@@ -1621,7 +1776,7 @@ def _run_stack(
 ):
     """The layer stack and the final norm over ``x`` ``[T, h]``: returns
     (normed hidden states, cache). ``layer(x, lp, cache_l, write_pages,
-    block_tables) -> (x, cache_l)`` is one block on one layer's pages.
+    block_tables, l) -> (x, cache_l)`` is block ``l`` on its layer's pages.
 
     A single-pass model runs the unrolled layers once, then the final
     norm (scope ``lm_head``, which it alone feeds). A looped model
@@ -1647,7 +1802,7 @@ def _run_stack(
         caches = list(caches)
         for l in range(cfg.num_layers):
             x, caches[l] = layer(
-                x, layer_params(params, l, cfg), caches[l], write_pages, block_tables
+                x, layer_params(params, l, cfg), caches[l], write_pages, block_tables, l
             )
         return x, tuple(caches)
 
@@ -1733,7 +1888,7 @@ def forward_ring_prefill(
         x = params["embed"][tokens]  # [T, h]
         rope_cs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
 
-    def layer(x, lp, cache_l, write_pages, _):
+    def layer(x, lp, cache_l, write_pages, _tables, _l):
         with jax.named_scope("qkv"):
             dt = lp["attn_norm"].dtype
             y = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps).astype(dt)

@@ -35,6 +35,9 @@ and implementation (:func:`traced_calls`,
 ``dynamo_engine_attention_calls_traced_total`` on /metrics).
 Heads HALF a lane row wide come in through :func:`paired_heads_attention`
 (two KV heads a 128-wide row, the same cache bytes, the same kernel).
+A layer that attends a sliding WINDOW says ``window=``: the caller hands it
+the table of the window's pages alone and ``kv_lens`` shortened by the
+tokens before them (:func:`ragged_paged_attention`, "A window").
 Under tensor parallelism wrap with :func:`sharded_ragged_attention` —
 attention is embarrassingly parallel over heads, so the shard_map has no
 collectives.
@@ -65,6 +68,32 @@ _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 _SMALL_KV_PAGES_PER_BLOCK = 8
 _SMALL_QUERIES_PER_BLOCK = 8
 _PREFILL_QUERIES_PER_BLOCK = 128
+# A wave's query block holds its queries x ALL the call's query heads (the
+# kernel blocks 16 combined KV heads, or all there are), and its body is
+# unrolled over the KV heads, each pass on queries x group rows. Up to 32
+# heads, 128 queries fit Mosaic's default scoped VMEM (16 MB) and compile in
+# seconds. 48 and 72 heads (Laguna's full and window layers, groups of 6 and
+# 9 on 8 KV heads) at 128 queries are refused at compile time
+# (RESOURCE_EXHAUSTED in vmem: 21 / 32 MB), and under a limit of their own
+# they compile for 28 / 146 s a kernel (measured for the v5e, PR 39: a
+# program's set-up went from seconds to minutes, a worker's past the
+# benchmark's limit for one). At 32 queries they fit the default and compile
+# in 2 / 7 s; what it costs is a re-read of the context's K/V for every
+# block more: ~2.6 ms a full layer for a 2,048-token chunk at a context of
+# 8,192, beside ~2.1 ms of attention FLOPs (PERF.md section 5).
+_WIDE_HEADS_MIN = 33
+_WIDE_HEADS_QUERIES_PER_BLOCK = 32
+# Their KV block: 1,024 tokens, not the 8 pages of the other ragged calls.
+# A pass of the kernel's body (one KV head of one KV block for one query
+# block) costs about the same whether the block holds 256 or 1,024 keys, so
+# a wave's call is its PASSES. The calls alone on the v5e, a 2,048-query
+# chunk behind a context of 4,096 at page size 32 (PR 39, calls w1 / w2;
+# pages a block 8 / 16 / 32, the queries in pieces where it says so): 48
+# heads 9.48 / 5.39 / - ms whole, 8.32 / 4.93 / 3.82 in pieces of 256;
+# 72 heads on a window of 512, 10.97 whole at 8, 3.37 / 2.37 / 1.42 in
+# pieces of 128 (a piece's table is 21 pages: ONE block). 32 pages x 2
+# buffers are 8 MB of Mosaic's 16; 64 were not tried in a served program.
+_WIDE_HEADS_KV_TOKENS_PER_BLOCK = 1024
 
 # The grid of a DECODE-SHAPED call: one query a block, 512 KV tokens a
 # block (:func:`decode_shape_grid`). The kernel walks a query block's
@@ -107,7 +136,8 @@ def _announce(level: int, message: str) -> None:
 
 
 # Attention calls traced since the process started, by the shape the
-# caller stated ("decode" / "ragged") and the implementation chosen
+# caller stated ("decode" / "ragged"; "window-decode" / "window-ragged" for
+# a call with ``window=``) and the implementation chosen
 # ("library" / "reference"). The choice is static per compiled program,
 # so trace time is where it can be counted: a program of L layers adds L
 # (a looped stack's body is traced once).
@@ -146,6 +176,7 @@ def ragged_paged_attention_ref(
     *,
     sm_scale: float,
     kv_scales: jax.Array | None = None,  # [n_pages, page_size, 2*n_kv] f32
+    window: int | None = None,  # a query sees its own position and window - 1 before
 ) -> jax.Array:               # [T, n_q, d]
     T, n_q, d = q.shape
     if cu_q_lens is None:
@@ -187,6 +218,8 @@ def ragged_paged_attention_ref(
     s = jnp.einsum("thgd,tshd->thgs", qg, k) * sm_scale  # [T, n_kv, group, span]
     pos = jnp.arange(span, dtype=jnp.int32)
     mask = (pos[None, :] <= abs_pos[:, None]) & (pos[None, :] < kv_lens[seq_id][:, None])
+    if window is not None:
+        mask = mask & (pos[None, :] > abs_pos[:, None] - window)
     mask = mask & valid_row[:, None]
     s = jnp.where(mask[:, None, None, :], s, _NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
@@ -197,13 +230,14 @@ def ragged_paged_attention_ref(
 
 def pallas_ragged_attention(
     q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs, *,
-    sm_scale: float,
+    sm_scale: float, window: int | None = None,
 ) -> jax.Array:
     """The library Pallas kernel under this repo's explicit grid:
     ``cu_q_lens=None`` (the decode shape) takes
     :func:`decode_shape_grid`; ragged calls of at most 64 rows the small
     grid, prefill waves a capped query block (the module-level
-    comments). Real-valued pages only."""
+    comments). Real-valued pages only. ``window`` is the kernel's
+    ``sliding_window``: a MASK, it walks every page up to ``kv_lens``."""
     from jax.experimental.pallas.ops.tpu.ragged_paged_attention import (
         ragged_paged_attention as _kernel,
     )
@@ -212,27 +246,89 @@ def pallas_ragged_attention(
         cu_q_lens = jnp.arange(q.shape[0] + 1, dtype=jnp.int32)
         qb, pages = decode_shape_grid(kv_pages.shape[1], page_indices.shape[1])
     else:
-        qb = (
-            _SMALL_QUERIES_PER_BLOCK
-            if q.shape[0] <= 64
-            else min(_PREFILL_QUERIES_PER_BLOCK, q.shape[0])
-        )
-        pages = min(_SMALL_KV_PAGES_PER_BLOCK, page_indices.shape[1])
+        rows, heads = q.shape[:2]
+        qb, pages = _SMALL_QUERIES_PER_BLOCK, _SMALL_KV_PAGES_PER_BLOCK
+        if rows > 64 and heads < _WIDE_HEADS_MIN:
+            qb = min(_PREFILL_QUERIES_PER_BLOCK, rows)
+        elif rows > 64:
+            qb = min(_WIDE_HEADS_QUERIES_PER_BLOCK, rows)
+            pages = max(1, _WIDE_HEADS_KV_TOKENS_PER_BLOCK // kv_pages.shape[1])
+        pages = min(pages, page_indices.shape[1])
     return _kernel(
         q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs,
-        sm_scale=sm_scale,
+        sm_scale=sm_scale, sliding_window=window,
         num_kv_pages_per_block=pages, num_queries_per_block=qb,
+    )
+
+
+def split_query_chunks(
+    rows: int, kv_lens, page_indices, cu_q_lens, num_seqs, *,
+    chunk: int, page_size: int, window: int | None = None,
+):
+    """A ragged call's sequences cut into pieces of at most ``chunk``
+    queries, each a sequence of its own to the kernel: ``(kv_lens,
+    page_indices, cu_q_lens, num_seqs)`` of ``rows // chunk + S`` pieces,
+    the live ones first and in the order of their queries, so ``q`` and the
+    output are untouched.
+
+    Why: the library kernel walks EVERY KV block of a sequence up to
+    ``kv_lens`` for every block of its queries; causality and the window are
+    masks, not bounds. A piece's ``kv_lens`` ends at its own last query, so
+    the keys after it are not walked; and with ``window`` its table starts
+    at the page of the oldest key its FIRST query sees, ``(window - 1 +
+    chunk) / page_size + 2`` columns at most, so the keys before the
+    window are not walked either. Positions are relative in kernel and
+    reference alike (a query's is ``kv_lens - q_len + i``), so the masks
+    are the same masks."""
+    S, width = page_indices.shape
+    pieces = -(-rows // chunk) + S
+    live = jnp.arange(S, dtype=jnp.int32) < num_seqs[0]
+    q_lens = jnp.where(live, cu_q_lens[1:] - cu_q_lens[:-1], 0)       # [S]
+    parts = -(-q_lens // chunk)                                       # pieces of sequence s
+    ends = jnp.cumsum(parts)                                          # ... up to and with s
+    i = jnp.arange(pieces, dtype=jnp.int32)
+    s = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1), S - 1).astype(jnp.int32)
+    lo = jnp.minimum((i - (ends[s] - parts[s])) * chunk, q_lens[s])
+    hi = jnp.where(i < ends[-1], jnp.minimum(lo + chunk, q_lens[s]), lo)
+    before = kv_lens[s] - q_lens[s]            # keys before the sequence's first query
+    first_col = jnp.zeros_like(s)
+    if window is not None:
+        first_col = jnp.maximum(before + lo - (window - 1), 0) // page_size
+        width = min(width, -(-(page_size - 1 + window - 1 + chunk) // page_size))
+    cols = jnp.minimum(first_col[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :],
+                       page_indices.shape[1] - 1)
+    return (
+        (before + hi - first_col * page_size).astype(jnp.int32),
+        page_indices[s[:, None], cols],
+        cu_q_lens[0] + jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(hi - lo)]),
+        ends[-1:].astype(jnp.int32),
     )
 
 
 def ragged_paged_attention(
     q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs, *,
-    sm_scale: float, kv_scales=None,
+    sm_scale: float, kv_scales=None, window: int | None = None,
+    query_chunk: int | None = None,
 ) -> jax.Array:
     """Backend dispatch: Pallas kernel on TPU, jnp reference elsewhere.
     ``cu_q_lens=None`` states the decode shape (module docstring): the
     kernel then runs its decode grid, and the reference reads it as
     ``arange(S + 1)``.
+
+    **A window** (``window=w``): a query at position ``p`` sees the keys at
+    ``p - w + 1 .. p``. The library kernel's ``sliding_window`` only masks
+    (``row - w >= col``): it still reads every page from the table's first
+    to ``kv_lens``. So the caller hands in the table of the pages a query
+    of this call may see, the first of them the one that holds its
+    sequence's OLDEST visible key, and ``kv_lens`` less the tokens before
+    that page (model.dense_layer). Positions are relative in kernel and
+    reference alike (a query's is ``kv_lens - q_len + i``), so the mask is
+    the same mask and the call reads the window's pages and no others.
+    Counted under ``window-decode`` / ``window-ragged``.
+
+    ``query_chunk`` (a ragged call of more rows than that): the sequences
+    go to kernel or reference in pieces of that many queries
+    (:func:`split_query_chunks`), the same numbers for fewer keys walked.
 
     The kernel wants MXU/VPU-aligned shapes (head_dim % 128, page_size %
     8); models outside that (e.g. the byte-sized test presets) run the
@@ -254,6 +350,12 @@ def ragged_paged_attention(
     )
     use_kernel = backend == "tpu" and d % 128 == 0 and page_size % 8 == 0
     shape = "decode" if cu_q_lens is None else "ragged"
+    if cu_q_lens is not None and query_chunk and q.shape[0] > query_chunk:
+        kv_lens, page_indices, cu_q_lens, num_seqs = split_query_chunks(
+            q.shape[0], kv_lens, page_indices, cu_q_lens, num_seqs,
+            chunk=query_chunk, page_size=page_size, window=window)
+    if window is not None:
+        shape, geometry = f"window-{shape}", f"{geometry}, window={window}"
     _count_traced(shape, "library" if use_kernel else "reference")
     if use_kernel:
         _announce(
@@ -300,11 +402,11 @@ def ragged_paged_attention(
             kv_scales = None
         return pallas_ragged_attention(
             q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs,
-            sm_scale=sm_scale,
+            sm_scale=sm_scale, window=window,
         )
     return ragged_paged_attention_ref(
         q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs,
-        sm_scale=sm_scale, kv_scales=kv_scales,
+        sm_scale=sm_scale, kv_scales=kv_scales, window=window,
     )
 
 

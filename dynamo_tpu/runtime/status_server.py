@@ -170,6 +170,22 @@ SCHEDULER_GAUGES: dict[str, tuple[str, str]] = {
         "Routed experts of each sparse layer this worker holds (its share "
         "of the router's width); 0 for a model without a stated share",
     ),
+    # Window and full attention layers mixed (ISSUE 39): the window pool.
+    "window_bytes_per_sequence": (
+        "engine_window_bytes_per_sequence",
+        "Bytes of K/V the window layers hold for one decoding sequence, "
+        "whatever its context (sliding_window / block_size + 1 blocks in "
+        "each); 0 for a model without window layers",
+    ),
+    "window_blocks_in_use": (
+        "engine_window_blocks_in_use",
+        "Blocks of the window pool that sequences hold now: those a later "
+        "query of theirs may still see",
+    ),
+    "window_blocks": (
+        "engine_window_blocks",
+        "Blocks of the window pool (0: the model has no window layers)",
+    ),
 }
 
 
@@ -250,6 +266,11 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
         "engine_layer_passes",
         "Passes over the layer stack, per live lane and fused iteration "
         "(a prefill wave: per sequence): dispatched lanes x k x ut_steps",
+    ),
+    "window_blocks_released": (
+        "engine_window_blocks_released",
+        "Window-pool blocks that slid wholly out of every later query's "
+        "window and were given back while their sequence went on",
     ),
 }
 
@@ -406,8 +427,9 @@ class _EngineCounters:
         kinds = GaugeMetricFamily(
             "dynamo_engine_cache_layers",
             "Page arrays the cache holds, by what a layer of that kind "
-            "caches: attention (planes of K/V, or latent rows) or conv (the "
-            "short convolution's state pages)",
+            "caches: attention (planes of K/V, or latent rows), conv (the "
+            "short convolution's state pages) or window (K/V of a sliding "
+            "window, in a pool of its own)",
             labels=["service", "kind"],
         )
         for kind, n in sorted(stats.get("cache_layers", {}).items()):

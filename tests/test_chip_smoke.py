@@ -68,3 +68,23 @@ def test_a_broken_phase_fails_the_run(phase):
     assert out.returncode != 0
     assert '"ok"' not in out.stdout
     assert "FAILED" in out.stderr
+
+
+def test_a_window_models_worker_is_held_to_its_window():
+    """``judge_window``: a worker that says it has a window pool must have
+    traced a window-decode call, and its table may be no wider than one
+    dispatch's span."""
+    import chip_smoke
+
+    startup = {"window_blocks": 1024, "block_size": 32, "sliding_window": 512, "megastep_k": 8,
+               "prefill_bucket_ms": {"256": 1.0, "2048": 9.0}, "window_table_blocks": 82}
+    traced = {"decode/library": 3.0, "window-decode/library": 6.0}
+    chip_smoke.judge_window("aggregated", startup, traced)
+    chip_smoke.judge_window("aggregated", {"cache_layers": {"attention": 2}}, {})   # no pool
+    with pytest.raises(chip_smoke.PhaseFailed, match="no window-decode"):
+        chip_smoke.judge_window("aggregated", startup, {"decode/library": 3.0})
+    with pytest.raises(chip_smoke.PhaseFailed, match="338 columns"):
+        chip_smoke.judge_window("aggregated", {**startup, "window_table_blocks": 338}, traced)
+    with pytest.raises(chip_smoke.PhaseFailed, match="reference on a TPU"):
+        chip_smoke.judge_attention_traced(
+            "aggregated", {"decode/library": 3.0, "window-decode/reference": 6.0}, "tpu")

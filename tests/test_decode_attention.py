@@ -178,6 +178,132 @@ def test_mosaic_compiles_the_decode_grid_for_a_v5e(
     assert "ragged_paged_attention_kernel" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows,decode,n_q,width,n_pages,window", [
+    (48, True, 48, 338, 16385, None),     # laguna-s21-longctx-agents: a full layer's decode call
+    (48, True, 72, 82, 1025, 512),        # ... a window layer's: groups of 9 heads a KV head
+    (2048, False, 48, 338, 16385, None),  # ... its widest wave through a full layer
+    (2048, False, 72, 82, 1025, 512),     # ... and through a window layer
+    (256, False, 72, 82, 1025, 512),      # ... its narrowest
+], ids=["full-decode", "window-decode", "full-wave", "window-wave", "window-wave-256"])
+def test_mosaic_compiles_lagunas_attention_calls_for_a_v5e(
+        one_chip, rows, decode, n_q, width, n_pages, window):
+    """48 and 72 query heads on 8 KV heads (groups of 6 and of 9) at the
+    cell's tables: the decode grid, and a wave's query block, which holds all
+    the heads at once and is 32 queries for them (ops/ragged_attention.py,
+    ``_WIDE_HEADS_QUERIES_PER_BLOCK``: 128 are refused for VMEM) beside a KV
+    block of 1,024 tokens; ``sliding_window`` handed to the kernel for a
+    window layer."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    lanes = rows if decode else 8
+
+    def call(q, kv, lens, tables, cu, ns):
+        if not decode:   # a wave goes in pieces of a quarter window (model.dense_layer)
+            lens, tables, cu, ns = ra.split_query_chunks(
+                rows, lens, tables, cu, ns, chunk=128, page_size=32, window=window)
+        return ra.pallas_ragged_attention(
+            q, kv, lens, tables, None if decode else cu, ns, sm_scale=SM_SCALE, window=window)
+
+    compiled = jax.jit(call).lower(
+        sds((rows, n_q, HEAD_DIM), jnp.bfloat16),
+        sds((n_pages, 32, 16, HEAD_DIM), jnp.bfloat16),
+        sds((lanes,), jnp.int32), sds((lanes, width), jnp.int32),
+        sds((lanes + 1,), jnp.int32), sds((1,), jnp.int32),
+    ).compile()
+    assert "ragged_paged_attention_kernel" in compiled.as_text()
+
+
+def _ragged_case(q_lens, befores, *, window, page_size=4, width=13, rows=48, n_q=6, n_kv=2,
+                 d=8, seed=0):
+    """A ragged call as the engine states it: ``befores[s]`` keys of
+    sequence ``s`` lie in the table before its first query (for a window
+    call: from the table's first page on)."""
+    rng = np.random.RandomState(seed)
+    S = len(q_lens) + 1   # a dead sequence behind the live ones
+    n_pages = S * width + 1
+    tables = rng.permutation(n_pages)[: S * width].reshape(S, width)
+    cu = np.zeros(S + 1, np.int32)
+    cu[1:len(q_lens) + 1] = np.cumsum(q_lens)
+    cu[len(q_lens) + 1:] = cu[len(q_lens)]
+    lens = np.zeros(S, np.int32)
+    lens[:len(q_lens)] = np.asarray(q_lens) + np.asarray(befores)
+    assert lens.max() <= width * page_size and cu[-1] <= rows
+    return (jnp.asarray(rng.randn(rows, n_q, d), jnp.float32),
+            jnp.asarray(rng.randn(n_pages, page_size, 2 * n_kv, d), jnp.float32),
+            jnp.asarray(lens), jnp.asarray(tables, jnp.int32), jnp.asarray(cu),
+            jnp.asarray([len(q_lens)], jnp.int32)), int(cu[-1])
+
+
+@pytest.mark.parametrize("chunk", [1, 2, 5, 8, 16])
+@pytest.mark.parametrize("window,q_lens,befores", [
+    (8, [13, 1, 20], [5, 8, 7]),     # a window call: at most window - 1 + page_size - 1 keys before
+    (8, [40], [10]),                 # one sequence, many pieces
+    (None, [13, 1, 20], [27, 8, 13]),
+    (None, [13, 0, 20, 7], [27, 9, 13, 0]),   # a live sequence with no query
+], ids=["window", "window-one", "full", "full-empty"])
+def test_a_call_in_pieces_gives_the_whole_calls_attention(window, q_lens, befores, chunk):
+    """``query_chunk``: every sequence goes on as pieces of at most that
+    many queries, each with ``kv_lens`` up to its own last query and, for a
+    window, a table from the page of its first query's oldest key: the
+    same masks over fewer keys, so the same numbers (the kernel walks what
+    the table holds: ops/ragged_attention.py, ``split_query_chunks``)."""
+    args, live = _ragged_case(q_lens, befores, window=window)
+    whole = ra.ragged_paged_attention(*args, sm_scale=0.3, window=window)
+    pieces = jax.jit(lambda *a: ra.ragged_paged_attention(
+        *a, sm_scale=0.3, window=window, query_chunk=chunk))(*args)
+    np.testing.assert_allclose(pieces[:live], whole[:live], atol=2e-6)
+    assert not np.any(np.asarray(pieces[live:]))
+
+
+def test_a_windows_pieces_walk_the_window_and_not_the_chunk():
+    """What the split is for: a piece's table is ``(window - 1 + chunk) /
+    page_size + 2`` columns at most whatever the call's, and its
+    ``kv_lens`` end at its own last query."""
+    q, kv, lens, tables, cu, ns = _ragged_case([40], [10], window=8)[0]
+    sub_lens, sub_tables, sub_cu, sub_ns = ra.split_query_chunks(
+        q.shape[0], lens, tables, cu, ns, chunk=4, page_size=4, window=8)
+    assert sub_tables.shape == (48 // 4 + 2, (3 + 7 + 4 + 3) // 4)
+    assert int(sub_ns[0]) == 10 and sub_cu[:11].tolist() == list(range(0, 44, 4))
+    # piece j's first query is at 10 + 4 j: its oldest key at 3 + 4 j, page (3 + 4 j) // 4 = j
+    assert sub_lens[:10].tolist() == [10 + 4 * (j + 1) - 4 * j for j in range(10)]
+    assert sub_tables[:10, 0].tolist() == tables[0, :10].tolist()
+
+
+def test_the_wave_bench_refuses_the_cpu(monkeypatch):
+    """``tools/attn_wave_bench.py`` times the TPU kernel whole against in
+    pieces; on the CPU it would time the reference path under the kernel's
+    name."""
+    from tools import attn_wave_bench
+
+    monkeypatch.setattr("sys.argv", ["attn_wave_bench"])
+    with pytest.raises(SystemExit, match="chiprun"):
+        attn_wave_bench.main()
+
+
+@pytest.mark.parametrize("rows", [48, 2048])
+def test_mosaic_compiles_lagunas_expert_layer_for_a_v5e(one_chip, rows):
+    """The sparse layer A.X-K1 and LFM2 run, at Laguna's shape (32 held
+    experts of 3072 x 1024, 10 a token of 256): a decode step's stream
+    kernel and a wave's grouped product."""
+    from dynamo_tpu.engine import model
+    from dynamo_tpu.ops import expert_stream as es
+    from dynamo_tpu.ops import grouped_matmul as gm
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    w_gu, w_down = sds((32, 3072, 2048), jnp.bfloat16), sds((32, 1024, 3072), jnp.bfloat16)
+    x = sds((rows, 3072), jnp.bfloat16)
+    if model.expert_call_shape(rows) == "step":
+        assert es.impl("tpu", jnp.bfloat16, rows, w_gu, w_down) == "stream/pallas"
+        compiled = jax.jit(es.expert_stream).lower(
+            x, sds((rows, 32), jnp.float32), w_gu, w_down).compile()
+        assert "expert_stream_kernel" in compiled.as_text()
+    else:
+        assert gm.impl("tpu", jnp.bfloat16, w_gu, w_down) == "pallas"
+        compiled = jax.jit(
+            lambda *a: model._experts_grouped(*a, k=10, impl="pallas", all_held=False)
+        ).lower(x, sds((rows, 32), jnp.float32), sds((rows, 32), jnp.bool_), w_gu, w_down).compile()
+        assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+
+
 @pytest.mark.parametrize("lanes", [128, 32])
 def test_mosaic_compiles_the_latent_decode_kernel_for_a_v5e(one_chip, lanes):
     """A.X-K1's absorbed decode call at the cell's two decode widths (64

@@ -21,6 +21,16 @@ microseconds are the whole call's (every device op of the program, as
 the benchmark's ``latent_attn_roofline.axk1`` counts the scope), the
 kernel's own beside them.
 
+A WINDOW shape (``laguna-window-48``: the decode call of Laguna's
+sliding_attention layers, 72 query heads on 8 KV heads, window 512) hands
+the kernel what the engine hands it: each lane's table starts at the page
+that holds the oldest key its query sees, ``kv_lens`` counts from that
+page's first token, and ``sliding_window`` masks the rest; the bytes are
+those pages'. Its ``reference`` row is the ``jnp`` path on the same
+arguments: no kernel to time, but the largest difference says whether the
+kernel's groups of 9 heads are the reference's. ``laguna-full-48`` is the
+same cell's full layers (48 heads on 8, the whole context).
+
 Refuses to run without a TPU: a time from the CPU says nothing here.
 
 Usage (through the chip tool, from the repo root):
@@ -55,7 +65,12 @@ SHAPES = {
     "1p5b-16": (16, 12, 2, 256, 11265, ("loguniform", 160, 2560)),
     "1p5b-32": (32, 12, 2, 256, 11265, ("loguniform", 160, 2560)),
     "ouro": (8, 16, 16, 64, 676, ("uniform", 128, 608)),
+    # laguna-s21-longctx-agents: the full layers' pool, and the window
+    # layers' (tables of EngineConfig.window_table_blocks columns)
+    "laguna-full-48": (48, 48, 8, 338, 16385, ("uniform", 4096, 10752)),
+    "laguna-window-48": (48, 72, 8, 82, 1025, ("uniform", 4096, 10752)),
 }
+WINDOWS = {"laguna-window-48": 512}   # shape -> its layers' sliding window
 # lanes, heads, r, dr, page-table width, pages in one layer's array,
 # contexts: axk1-ep16-decode at its two decode widths.
 LATENT_SHAPES = {
@@ -111,6 +126,12 @@ def make_case(shape: str, seed: int):
                 jnp.asarray(lens), jnp.asarray(tables))
         return args, blocks * rows * w * 2, lens
     lanes, n_q, n_kv, width, n_pages, contexts = geometry(shape)
+    if shape in WINDOWS:
+        # what a lane holds of its context: from the page of the oldest key
+        # its newest query sees (position context - window) to the end
+        kind, lo, hi = contexts
+        span = WINDOWS[shape] + PAGE_SIZE - 1
+        contexts = (kind, min(lo, span), min(hi, span))
     lens, tables, blocks = _contexts_and_tables(rng, lanes, width, n_pages, contexts, PAGE_SIZE)
     q = jnp.asarray(rng.randn(lanes, n_q, HEAD_DIM), jnp.bfloat16)
     kv = jnp.asarray(
@@ -129,28 +150,37 @@ def variants(shape: str, quick: bool):
     from dynamo_tpu.ops.ragged_attention import (
         decode_shape_grid,
         ragged_paged_attention,
+        ragged_paged_attention_ref,
     )
 
     lanes, _, _, width, _, _ = geometry(shape)
     serving_grid = decode_shape_grid(PAGE_SIZE, width)
     sm = HEAD_DIM ** -0.5
+    window = WINDOWS.get(shape)
 
     def serving(q, kv, lens, tables):
         return ragged_paged_attention(
             q, kv, lens, tables, None, jnp.asarray([lanes], jnp.int32),
-            sm_scale=sm)
+            sm_scale=sm, window=window)
+
+    def reference(q, kv, lens, tables):
+        return ragged_paged_attention_ref(
+            q, kv, lens, tables, None, jnp.asarray([lanes], jnp.int32),
+            sm_scale=sm, window=window)
 
     def lib(qb, pages):
         def fn(q, kv, lens, tables):
             return library(
                 q, kv, lens, tables, jnp.arange(lanes + 1, dtype=jnp.int32),
-                jnp.asarray([lanes], jnp.int32), sm_scale=sm,
+                jnp.asarray([lanes], jnp.int32), sm_scale=sm, sliding_window=window,
                 num_queries_per_block=qb, num_kv_pages_per_block=pages)
         return fn
 
     # The serving path IS one of the grids: the same program compiles to
     # one executable under the first name, so the sweep leaves that one out.
     out = [("serving q{}_p{}".format(*serving_grid), serving)]
+    if window:   # a table narrow enough for the jnp path's gather
+        out.append(("reference", reference))
     qbs = (1, 8) if quick else (1, 2, 4, 8, 16, 32)
     # KV blocks of 128 ... 1024 tokens: 4 ... 32 pages of 32.
     tokens = (256, 512) if quick else (128, 256, 384, 512, 768, 1024)
@@ -289,9 +319,10 @@ def bench_shape(shape: str, seed: int, quick: bool, hbm_bytes_per_s: float):
                   f"max|diff| {diff:.4f}", flush=True)
             continue
         if not ns:
-            rows.append({"shape": shape, "variant": tag, "error": "no kernel event"})
-            print(f"{tag:16s} no kernel event under jit_{jitted.__name__}; the "
-                  f"trace has {sorted(times)[:4]}", flush=True)
+            rows.append({"shape": shape, "variant": tag, "error": "no kernel event",
+                         "max_abs_diff_vs_serving": diff})
+            print(f"{tag:16s} no kernel event under jit_{jitted.__name__} "
+                  f"(max|diff| {diff:.4f}); the trace has {sorted(times)[:4]}", flush=True)
             continue
         us = sum(ns) / len(ns) / 1e3
         rows.append({
