@@ -28,9 +28,11 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import contextlib
 import dataclasses
 import logging
 import math
+import threading
 import time
 import uuid
 
@@ -154,6 +156,56 @@ async def _resolve_mm(core, encode_client, embed_fetch_client, request: dict) ->
     request["mm"] = dict(
         mm, embeds=allemb.tobytes(), embeds_shape=list(allemb.shape)
     )
+
+
+class StepKvEvents:
+    """The allocator's KV events on their way from the engine thread to
+    the loop, where the publisher's bounded buffer lives.
+
+    One raised inside an engine step (``step``: the core's
+    ``step_scope``, entered on the engine thread under the step lock) is
+    kept, in the order raised, and the step's events cross together when
+    it returns: one write to the loop's wake-up socket and one hand-over
+    of the GIL a step, where a hop per committed block made dozens while
+    the engine thread was between a landing and the next enqueue (PERF.md
+    section 6, PR 40). Raised by any other thread, or outside a step (an
+    import, a cache clear: they hold the same lock, so never beside a
+    step), an event crosses at once as before. ``stored`` and ``removed``
+    keep their order; the publisher sees what it saw."""
+
+    def __init__(self, loop, publisher: KvEventPublisher):
+        self._loop = loop
+        self._pub = publisher
+        self._thread: int | None = None   # the engine thread, inside a step
+        self._events: list[tuple] = []
+
+    def stored(self, hashes: list[int], parent: int | None) -> None:
+        self._send(self._pub.stored_nowait, list(hashes), parent)
+
+    def removed(self, hashes: list[int]) -> None:
+        self._send(self._pub.removed_nowait, list(hashes))
+
+    def _send(self, fn, *args) -> None:
+        if threading.get_ident() == self._thread:
+            self._events.append((fn, args))
+        else:
+            self._loop.call_soon_threadsafe(fn, *args)
+
+    @contextlib.contextmanager
+    def step(self):
+        self._thread = threading.get_ident()
+        try:
+            yield
+        finally:
+            self._thread = None
+            if self._events:
+                events, self._events = self._events, []
+                self._loop.call_soon_threadsafe(self._deliver, events)
+
+    @staticmethod
+    def _deliver(events: list[tuple]) -> None:
+        for fn, args in events:
+            fn(*args)
 
 
 def _eos_for(tokenizer: str) -> tuple[int, ...]:
@@ -453,14 +505,12 @@ async def run_jax_worker(
     # KV events fire from the engine thread (core.step under to_thread)
     # and the offload worker thread (tier demotions); hop them onto the
     # loop where the publisher's bounded buffer lives. Device-tier events
-    # come from the allocator callbacks, host/disk-tier events from the
-    # offload engine — the router's global index composes them back to
+    # come from the allocator callbacks (a step's in one hop when it
+    # returns: StepKvEvents), host/disk-tier events from the offload
+    # engine — the router's global index composes them back to
     # worker-level residency.
-    def on_stored(hashes: list[int], parent: int | None) -> None:
-        loop.call_soon_threadsafe(kv_pub.stored_nowait, list(hashes), parent)
-
-    def on_removed(hashes: list[int]) -> None:
-        loop.call_soon_threadsafe(kv_pub.removed_nowait, list(hashes))
+    kv_events = StepKvEvents(loop, kv_pub)
+    on_stored, on_removed = kv_events.stored, kv_events.removed
 
     def on_tier_stored(hashes: list[int], parent: int | None, tier: str) -> None:
         loop.call_soon_threadsafe(
@@ -521,6 +571,7 @@ async def run_jax_worker(
         return info, built
 
     device_info, (core, engine) = await asyncio.to_thread(_build)
+    core.step_scope = kv_events.step
     log.info(
         "jax worker device: platform=%s device_kind=%r devices=%d "
         "(engine built in %.1f s; peak bytes after init %s; bytes per "

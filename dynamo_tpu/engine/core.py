@@ -26,6 +26,7 @@ Design notes:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import threading
@@ -91,8 +92,15 @@ from dynamo_tpu.tokens import TokenBlockSequence, compute_seq_hashes
 log = logging.getLogger("dynamo_tpu.engine")
 
 
-@dataclass
+@dataclass(eq=False)
 class Sequence:
+    """One request's lane. A lane is itself: two sequences are equal only
+    where they are the same object, so the planners' ``seq in
+    self.running``, ``victim in ready`` and ``self.running.remove(seq)``
+    compare identities and build no tuple of 29 fields for every lane
+    they pass (1.1 us a comparison, thousands a plan at 128 lanes: PERF.md
+    section 6, PRs 39 and 40)."""
+
     request_id: str
     prompt: list[int]
     sampling: SamplingOptions
@@ -110,14 +118,9 @@ class Sequence:
     # The WINDOW pool's blocks (a model with sliding_attention layers):
     # block ``win_first + j`` of the sequence lies in that pool's block
     # ``win_ids[j]``; blocks before ``win_first`` slid out of every later
-    # query's window and were given back (EngineCore._hold_window). Left
-    # out of ``==``: the planner asks ``seq in ready`` for every lane, the
-    # generated ``__eq__`` builds a tuple of the compared fields, and past
-    # 30 of them CPython builds it as a list first: 1.1 -> 2.9 us a
-    # comparison, +12 ms of ``plan`` a dispatch at 128 lanes (PERF.md
-    # section 6, PR 39).
-    win_first: int = field(default=0, compare=False)
-    win_ids: list[int] = field(default_factory=list, compare=False)
+    # query's window and were given back (EngineCore._hold_window).
+    win_first: int = 0
+    win_ids: list[int] = field(default_factory=list)
     # -- progress --
     prefilled: int = 0      # prompt tokens with K/V written
     processed: int = 0      # all tokens with K/V written
@@ -494,6 +497,59 @@ class _RaggedBatch:
 # the host stop-scan stays the authority either way.
 MEGASTEP_WATCH_W = 8
 
+# A megastep's per-lane inputs cross to the device as ONE int32 array
+# ``[B, LANE_COLS]``, a column a quantity, floats by their bits, the watch
+# list last: a transfer costs the runtime's Python once (~0.45 ms on the
+# serving host) where twelve arrays cost it twelve times, on the leg
+# between a landing and the next enqueue (PERF.md section 6, PR 40).
+(_L_TOKEN, _L_FEED, _L_POSITION, _L_ACTIVE, _L_SEED, _L_COUNTER, _L_TEMPERATURE,
+ _L_TOP_K, _L_TOP_P, _L_BUDGET, _L_MIN_LEFT, _L_WATCH) = range(12)
+LANE_COLS = _L_WATCH + MEGASTEP_WATCH_W
+
+
+def pack_lanes(
+    tokens, feed_idx, positions, active, seeds, counters, temperature,
+    top_k, top_p, watch, budgets, min_left,
+) -> np.ndarray:
+    """int32 ``[B, LANE_COLS]`` of a megastep's twelve per-lane host
+    arrays (``feed_idx`` None: no lane is fed), which
+    :func:`unpack_lanes` takes apart again on the device, bit for bit."""
+    lanes = np.empty((tokens.shape[0], LANE_COLS), np.int32)
+    lanes[:, _L_TOKEN] = tokens
+    lanes[:, _L_FEED] = -1 if feed_idx is None else feed_idx
+    lanes[:, _L_POSITION] = positions
+    lanes[:, _L_ACTIVE] = active
+    lanes[:, _L_SEED] = seeds
+    lanes[:, _L_COUNTER] = counters
+    lanes[:, _L_TOP_K] = top_k
+    lanes[:, _L_BUDGET] = budgets
+    lanes[:, _L_MIN_LEFT] = min_left
+    lanes[:, _L_WATCH:] = watch
+    bits = lanes.view(np.float32)   # the same memory: a float's bits as they are
+    bits[:, _L_TEMPERATURE] = temperature
+    bits[:, _L_TOP_P] = top_p
+    return lanes
+
+
+def unpack_lanes(lanes: jax.Array, feed: jax.Array):
+    """A megastep's per-lane inputs from their packed array, in the order
+    the step programs name them: ``tokens`` (a lane whose feed column is
+    >= 0 reads ``feed`` there: the step in flight's sampled tokens,
+    sampler.gather_feedback), ``positions``, ``active``, ``seeds``,
+    ``counters``, ``temperature``, ``top_k``, ``top_p``, ``watch``,
+    ``budgets``, ``min_left``. Slices and bitcasts of a ``[128, 19]``
+    array, once a dispatch."""
+    def f32(col):
+        return jax.lax.bitcast_convert_type(lanes[:, col], jnp.float32)
+
+    tokens = gather_feedback(feed, lanes[:, _L_TOKEN], lanes[:, _L_FEED])
+    return (
+        tokens, lanes[:, _L_POSITION], lanes[:, _L_ACTIVE] != 0,
+        lanes[:, _L_SEED], lanes[:, _L_COUNTER], f32(_L_TEMPERATURE),
+        lanes[:, _L_TOP_K], f32(_L_TOP_P), lanes[:, _L_WATCH:],
+        lanes[:, _L_BUDGET], lanes[:, _L_MIN_LEFT],
+    )
+
 
 def _expert_stats_list(cfg) -> list | None:
     """Where a program's sparse layers leave their counts at trace time
@@ -509,9 +565,7 @@ def _expert_stats_sum(stats: list | None):
 
 
 def _megastep_body(
-    params, cache, tokens, block_tables, positions, active,
-    seeds, counters, temperature, top_k, top_p,
-    watch, budgets, min_left,
+    params, cache, lanes, block_tables, feed,
     *, n_steps, need_mask, all_greedy=False, want_logprobs=False,
     cfg, engine, mesh=None,
 ):
@@ -534,7 +588,12 @@ def _megastep_body(
     is emitted — stops only the host can see (stop strings, truncated
     watch lists) roll back via the ``num_computed_tokens`` cursor, whose
     un-advanced tail is never attended and is rewritten by the next
-    dispatch."""
+    dispatch.
+
+    The per-lane inputs arrive packed (:func:`pack_lanes`) beside the
+    block tables and the feedback source."""
+    (tokens, positions, active, seeds, counters, temperature, top_k, top_p,
+     watch, budgets, min_left) = unpack_lanes(lanes, feed)
 
     def body(carry, i):
         toks, cache, alive, pos = carry
@@ -912,9 +971,7 @@ def _pp_prefill_and_sample(
 
 
 def _pp_decode_chain(
-    params, cache, tokens, block_tables, positions, active,
-    seeds, counters, temperature, top_k, top_p,
-    watch, budgets, min_left,
+    params, cache, lanes, block_tables, feed,
     *, n_steps, need_mask, all_greedy=False, want_logprobs=False,
     cfg, engine, pp_mesh, n_micro,
 ):
@@ -951,6 +1008,8 @@ def _pp_decode_chain(
     engines per-microbatch with host-driven queues instead)."""
     from dynamo_tpu.parallel.pipeline import pp_decode_round
 
+    (tokens, positions, active, seeds, counters, temperature, top_k, top_p,
+     watch, budgets, min_left) = unpack_lanes(lanes, feed)
     pp = int(pp_mesh.shape["pp"])
     M = n_micro
     B = tokens.shape[0]
@@ -1547,6 +1606,11 @@ class EngineCore:
         # Serializes step() against cross-thread cache surgery
         # (import/export of disaggregated KV blocks).
         self._step_lock = threading.Lock()
+        # A context every step() runs in, entered and left on the engine
+        # thread under the step lock: the worker gathers a step's KV
+        # events in it and hands them to its loop in one hop
+        # (backends/jax/main.py:StepKvEvents).
+        self.step_scope: Callable[[], Any] = contextlib.nullcontext
         self._embed_lock = threading.Lock()
         self._held: dict[str, Sequence] = {}
         # Chunk-commit notification hook: called as
@@ -1680,13 +1744,23 @@ class EngineCore:
         # flat width (_feed_pad: a program per output shape), so the
         # gather compiles per token-buffer width and not per (previous
         # width, next width) pair: serving crosses widths that warm-up's
-        # phases, one width at a time, never pair up.
+        # phases, one width at a time, never pair up. A megastep gathers
+        # inside its own program (unpack_lanes), from the same source.
         self._feed = jax.jit(gather_feedback)
         self._feed_width = (
             engine_cfg.megastep * self._spec_R
             * max(engine_cfg.decode_buckets[-1], engine_cfg.prefill_batch)
         )
         self._feed_pad = jax.jit(pad_feedback, static_argnames=("width",))
+        # What a megastep is handed where no lane is fed: zeros of the
+        # padded source's shape, placed as a step's sampled tokens are
+        # (_replicate_out), so that the megastep compiles once for both.
+        self._no_feed = jnp.zeros(self._feed_width, jnp.int32)
+        if mesh is not None or pp_mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self._no_feed = jax.device_put(
+                self._no_feed, NamedSharding(mesh or pp_mesh, PartitionSpec()))
         self.sp_mesh = sp_mesh
         self._ring = None
         if sp_mesh is not None:
@@ -1930,19 +2004,25 @@ class EngineCore:
             return None
         return self._inflight.feed_series.get(seq.request_id)
 
-    def _fed(self, host_tokens: jax.Array, src_idx: np.ndarray) -> jax.Array:
-        """``host_tokens`` with the slots ``src_idx`` names (>= 0: a flat
-        index into the in-flight step's sampled output) overridden on
-        device by those just-sampled ids — enqueued on the device
-        stream, never blocking. The in-flight output is padded once, on
-        first use, to the engine's one feedback width."""
+    def _feed_source(self) -> jax.Array:
+        """The in-flight step's sampled output as a feed index names it:
+        flat, and padded once, on first use, to the engine's one feedback
+        width."""
         plan = self._inflight
         if plan.feed_padded is None:
             plan.feed_padded = self._feed_pad(
                 plan.feed_tokens,
                 width=max(self._feed_width, plan.feed_tokens.size),
             )
-        return self._feed(plan.feed_padded, host_tokens, jnp.asarray(src_idx))
+        return plan.feed_padded
+
+    def _fed(self, host_tokens: jax.Array, src_idx: np.ndarray) -> jax.Array:
+        """``host_tokens`` with the slots ``src_idx`` names (>= 0: a flat
+        index into the in-flight step's sampled output) overridden on
+        device by those just-sampled ids — enqueued on the device
+        stream, never blocking. A ragged dispatch's token buffer; a
+        megastep gathers inside its own program (:func:`unpack_lanes`)."""
+        return self._feed(self._feed_source(), host_tokens, jnp.asarray(src_idx))
 
     def _note_dispatch(self) -> int:
         """Dispatch-side bookkeeping for the pipelining invariants: the
@@ -3249,9 +3329,10 @@ class EngineCore:
         mixed chunked step (n_tokens = 1) so the two schedulers' victim
         selection can never diverge."""
         ready: list[Sequence] = []
+        live = {id(s) for s in self.running}   # no list is walked to ask
         for seq in decoding:
             self.clock.poll()
-            if seq not in self.running:
+            if id(seq) not in live:
                 continue  # preempted by an earlier lane in this loop
             if self._grow_blocks(seq, n_tokens):
                 ready.append(seq)
@@ -3264,6 +3345,7 @@ class EngineCore:
             victim = next((s for s in reversed(self.running) if s is not seq), None)
             if victim is not None:
                 self._preempt(victim)
+                live.discard(id(victim))
                 if victim in ready:
                     ready.remove(victim)
                 if self._grow_blocks(seq, n_tokens):
@@ -3373,8 +3455,11 @@ class EngineCore:
         optimistic overlay. Per-lane stop inputs (watch ids, remaining
         generation budget, min-tokens floor) arm the on-device stop
         flags so lanes that finish early run masked no-ops instead of
-        writing K/V past their stop. Returns a pending fetch whose
-        ``land()`` yields ([n_steps, B] tokens, lp arrays or None)."""
+        writing K/V past their stop. The per-lane inputs cross as ONE
+        packed array (:func:`pack_lanes`) beside the block tables: two
+        transfers a megastep, and the gather of the fed tokens inside the
+        program. Returns a pending fetch whose ``land()`` yields
+        ([n_steps, B] tokens, lp arrays or None)."""
         self.clock.mark("assemble")
         B = self._decode_width(len(seqs))
         seqs = seqs[:B]
@@ -3424,23 +3509,17 @@ class EngineCore:
         )
         want_lp = any(s.logprobs is not None for s in seqs)
         all_greedy = all(s.sampling.temperature == 0.0 for s in seqs)
+        lanes = pack_lanes(
+            tokens, feed_idx, positions, active, seeds, counters, temp,
+            top_k, top_p, watch, budgets, min_left,
+        )
         self.clock.mark("h2d")
-        tok_in = self._put_batch(tokens)
-        if feed_idx is not None:
-            tok_in = self._fed(tok_in, feed_idx)
+        # Two transfers, and the step in flight's output where a lane
+        # reads its token from it (zeros of that shape where none does).
         args = (
-            tok_in,
+            self._put_batch(lanes),
             self._put_batch(tables),
-            self._put_batch(positions),
-            self._put_batch(active),
-            self._put_batch(seeds),
-            self._put_batch(counters),
-            self._put_batch(temp),
-            self._put_batch(top_k),
-            self._put_batch(top_p),
-            self._put_batch(watch),
-            self._put_batch(budgets),
-            self._put_batch(min_left),
+            self._no_feed if feed_idx is None else self._feed_source(),
         )
         self._mark_dispatch(
             "megastep" if n_steps > 1 else "decode",
@@ -3485,7 +3564,7 @@ class EngineCore:
         exactly one call. Otherwise it plans, dispatches, and commits in
         place — the classic synchronous loop; the token stream is
         bit-identical either way."""
-        with self._step_lock:
+        with self._step_lock, self.step_scope():
             return self._step_locked()
 
     # dynalint: holds-lock(_step_lock) — step() locks before dispatching here
@@ -3725,14 +3804,9 @@ class EngineCore:
             )
             if vplan is not None:
                 parts.append(vplan)
-        # A verify preemption may have evicted a chain candidate (a loop
-        # for the step clock's poll: ms of compares at 128 lanes).
-        kept: list[Sequence] = []
-        for s in chain_ready:
-            self.clock.poll()
-            if s in self.running:
-                kept.append(s)
-        chain_ready = kept
+            # A verify preemption may have evicted a chain candidate.
+            live = {id(s) for s in self.running}
+            chain_ready = [s for s in chain_ready if id(s) in live]
         if chain_ready:
             cplan = self._plan_megastep(chain_ready, n_steps)
             if cplan is not None:

@@ -15,10 +15,12 @@ Covered: every prefill bucket one wave can reach and every decode width
 sampling programs ordinary requests select (sampled without a top-k/top-p
 mask — the OpenAI default — and greedy). On the pipelined loop a decode
 phase runs two megasteps, so that both kinds of output (a prefill
-wave's, a megastep's) have fed a token buffer of that width: the
-feedback gather compiles per width and its padding per output shape
-(``EngineCore._fed``), so serving may then cross widths freely. Left to
-compile on first use,
+wave's, a megastep's) have fed a token buffer of that width: a megastep
+takes its lanes' inputs as one packed array and gathers the fed tokens
+inside its own program, from a source padded per output shape to one
+width (``EngineCore._feed_source``; a megastep that nothing feeds is
+handed zeros of that shape and runs the same program), so serving may
+then cross widths freely. Left to compile on first use,
 one short program at a time: shortened megasteps at the end of a
 generation budget, masked sampling, logprobs, speculative verify rows and
 multimodal prefill. Chunked scheduling runs the same traffic, which

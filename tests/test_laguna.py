@@ -314,16 +314,36 @@ def test_a_prompt_between_its_waves_holds_its_window_and_not_its_chunk():
     assert got["s3"] == done["s3"] and len(done["s3"]) == 12
 
 
-def test_a_sequences_equality_still_compares_a_tuple_it_can_build_at_once():
-    """The planner's ``seq in ready`` runs the dataclass's ``__eq__`` for
-    every lane against every lane: a tuple of the compared fields, which
-    CPython builds through a list once there are more than 30 (2.6 x the
-    time: +12 ms of ``plan`` a dispatch at 128 lanes, every model's). The
-    window's two fields are not compared."""
+def test_a_window_lane_is_itself_and_a_plan_compares_none():
+    """``Sequence`` compares by identity (PR 40): two lanes whose fields
+    are equal, window blocks and all, are two lanes, and planning,
+    dispatching and committing 128 decoding lanes calls no ``__eq__`` of
+    it (the generated one built a tuple of 29 fields for every lane a
+    ``seq in ready`` passed: +11 ms of ``plan`` a dispatch when the
+    window's two fields joined it, PR 39)."""
     from dynamo_tpu.engine.core import Sequence
+    from dynamo_tpu.llm.protocols.common import SamplingOptions, StopConditions
 
-    compared = [f.name for f in dataclasses.fields(Sequence) if f.compare]
-    assert len(compared) <= 30 and not {"win_ids", "win_first"} & set(compared)
+    fields = dict(request_id="a", prompt=[1, 2], sampling=SamplingOptions(),
+                  stop=StopConditions(max_tokens=2), seed=1, win_first=2, win_ids=[5, 6])
+    a, b = Sequence(**fields), Sequence(**fields)
+    assert a != b and a == a and [a, b].index(b) == 1
+    assert "__eq__" not in vars(Sequence)
+    core = make_core(max_num_seqs=128, decode_buckets=(128,), max_model_len=64,
+                     num_kv_blocks=128 * 16 + 8, num_window_blocks=128 * 6 + 32)
+    seqs = [core.add_request(_req(PROMPT[i % 9: i % 9 + 10], f"s{i}", max_tokens=40,
+                                  ignore_eos=True)) for i in range(128)]
+    while core.exec_stats["megastep_dispatches"] < 2:
+        core.step()
+    calls = []
+    Sequence.__eq__ = lambda x, y: calls.append(1) or x is y
+    try:
+        for _ in range(2):
+            core.step()
+    finally:
+        del Sequence.__eq__
+    assert len(core.running) == 128 and all(s.finish is None for s in seqs)
+    assert not calls
 
 
 def test_embeddings_run_both_kinds_of_layer():

@@ -69,10 +69,11 @@ def test_the_call_goes_through_unchanged():
 
 def test_serving_compiles_no_feedback_program_after_warm_up():
     """The one-step-ahead loop feeds a step's token buffer from the step
-    before it on the device: a gather per buffer width, over a source
-    padded per output shape. Warm-up meets every width and both kinds of
-    source, so serving that crosses widths (4 <-> 8 lanes) and follows a
-    prefill wave compiles neither again."""
+    before it on the device: a megastep gathers inside its own program
+    (since PR 40: no gather program of its own) over a source padded per
+    output shape to ONE width. Warm-up meets every width and both kinds of
+    source, so serving that crosses widths (3 <-> 7 lanes) and follows a
+    prefill wave compiles no padding and no megastep again."""
     from dynamo_tpu.engine import EngineCore, tiny_engine, tiny_model
     from dynamo_tpu.engine.warmup import warm_up
     from dynamo_tpu.llm.protocols.common import (
@@ -95,15 +96,17 @@ def test_serving_compiles_no_feedback_program_after_warm_up():
     assert core.pipelined
 
     def programs():
-        return core._feed._cache_size(), core._feed_pad._cache_size()
+        return (core._feed._cache_size(), core._feed_pad._cache_size(),
+                core._decode._cache_size())
 
     cold = programs()
     phases = warm_up(core)
     assert any(p.startswith("decode B=7") for p in phases)
     warm = programs()
-    # a gather per decode width; a padding per output shape (a prefill
-    # wave's, and a megastep's per width)
-    assert (warm[0] - cold[0], warm[1] - cold[1]) == (2, 3)
+    # no gather of a megastep's own; a padding per output shape (a prefill
+    # wave's, and a megastep's per width); a megastep per width and
+    # sampling kind
+    assert tuple(w - c for w, c in zip(warm, cold)) == (0, 3, 4)
     fed = core.exec_stats["pipelined_dispatches"]
 
     def req(i, n_prompt, max_tokens):
