@@ -18,8 +18,42 @@ any of it):
   ``attn_decode_bytes_per_layer(context_tokens, mf, block_size, kv_bytes)``,
   ``forward_flops_per_token(mf, context)``.
 
-The tolerance of the comparison (``reference.check.LOGPROB_ATOL``) is not
-part of it: an architecture may not bring its own.
+One member is OPTIONAL (``OPTIONAL``; the six modules here have none):
+
+- ``score_probe(cfg, params, prompt, probe, **options) -> dict``: the
+  reference's side of one probe, for an architecture whose step yields
+  something other than the next token of each sequence, so that "generated
+  token j is predicted from row ``len(prompt) + j - 1`` of one causal
+  forward" (``reference.check.score_probe``, the default) does not describe
+  it: a block-diffusion model reads a token from its OWN row of a forward
+  whose input holds mask tokens at the block's places still hidden when that
+  token was chosen. ``probe`` is what ``reference.check.run_probe`` returned:
+  ``tokens``, ``top_ids``, ``top_lps`` and, for such a module alone,
+  ``extra``: a list with one dict a generated token that carries every key
+  of the program's log-probability entry other than ``top`` (the denoising
+  step's index, say). It returns what the default returns, one item a
+  generated token: ``top_lps`` (the reference's log-probability of every id
+  in ``top_ids``), ``argmax``, ``argmax_lp`` and ``finite``.
+  ``reference.check`` calls it in place of its own wherever a module has one.
+  **``extra`` is the program's CLAIM of its schedule, not an input of the
+  reference.** A module that only rebuilt each step's input from it would
+  replay a wrong unmasking order and pass it. So the module owes two
+  checks, both inside the return's shape (it still brings no tolerance):
+  the claim is LEGAL (every place of a block chosen at one step, steps
+  counted from the block's first; otherwise ``finite`` is False and
+  ``compare`` fails), and it is the schedule the REFERENCE would have kept:
+  where the reference's own forward over a step's input would have chosen
+  another place than the claimed one, the token's ``argmax`` is no token
+  (-1) and its ``argmax_lp`` the confidence of the reference's place, so
+  that ``compare`` holds the two to its near-tie rule as it holds two top
+  tokens. ``tests/chipbench/data/toy_block_arch.py`` does both, and its
+  tests show a legal but wrong order caught that a replay passes. The
+  engine's entry format for such a step does not exist yet: the first
+  module that reads one fixes the keys it trusts, and says so here.
+
+The tolerance of the comparison (``reference.check.LOGPROB_ATOL``) and the
+comparison itself (``reference.check.compare``) are not part of either: an
+architecture may not bring its own.
 """
 
 from __future__ import annotations
@@ -32,6 +66,7 @@ from dataclasses import dataclass
 SURFACE = ("KEYS", "derived", "reference_logits", "decode_weight_bytes",
            "kv_bytes_per_token", "attn_decode_bytes_per_layer",
            "forward_flops_per_token")
+OPTIONAL = ("score_probe",)
 
 
 @dataclass(frozen=True)

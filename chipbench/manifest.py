@@ -37,9 +37,10 @@ def metrics_of(manifest: dict, kind: str, cell_name: str) -> list[dict]:
 
 
 def metric_file(kind: str, name: str) -> Path:
-    """The metric's reader file. A name split by suffix for its cells
-    (``queue_wait_ms_mean.chat``) reads with its base name's file unless
-    it has one of its own."""
+    """The metric's reader file. A name split by suffix
+    (``queue_wait_ms_mean.chat``, so that it can name another end-to-end
+    metric under ``moves``) reads with its base name's file unless it has
+    one of its own."""
     folder = HERE / ("end_to_end" if kind == "end_to_end" else "layer_metrics")
     own = folder / f"{name}.json"
     return own if own.exists() or "." not in name else folder / f"{name.rsplit('.', 1)[0]}.json"
@@ -141,4 +142,13 @@ def problems(manifest: dict, root: Path = ROOT) -> list[str]:
                     if "workloads" in moved and wname not in moved["workloads"]:
                         bad.append(f"metric {m['name']} moves {m['moves']}, which "
                                    f"cell {wname} does not report")
+    # One entry a metric: entries that read with the same file and move the
+    # same end-to-end metric are ONE entry with a list of cells.
+    seen: dict[tuple[str, str], str] = {}
+    for m in manifest["per_layer"]:
+        key = (metric_file("per_layer", m["name"]).stem, m.get("moves"))
+        if key in seen:
+            bad.append(f"metrics {seen[key]} and {m['name']} read with {key[0]}.json and "
+                       f"move {key[1]}: one entry with a list of cells")
+        seen.setdefault(key, m["name"])
     return bad

@@ -16,10 +16,17 @@ the traced slice of the window. ``stat`` selects:
 - ``attn_roofline``: the least one decode-attention call could take,
   reading the K/V blocks in use once at the HBM rate, as % of the
   kernel's measured time per call (it is bandwidth-bound: one query token
-  per sequence).
+  per sequence);
+- ``step_mfu``: the operations one decode step NEEDS (the architecture's
+  ``forward_flops_per_token`` at the mean context of the streams in flight,
+  times the live lanes a decode dispatch carried over the window) over the
+  measured step, as % of the chip's bf16 peak (int8 weight-only products
+  run in bf16 too). What the program computes beyond that (held experts on
+  rows that did not choose them, padded lanes) does not count.
 
-The bytes of the last two are counted by the configuration's architecture
-(``chipbench/architectures``), the rates are ``chipbench/peaks.py``'s.
+The bytes and operations of the last three are counted by the
+configuration's architecture (``chipbench/architectures``), the rates are
+``chipbench/peaks.py``'s.
 """
 
 from __future__ import annotations
@@ -69,6 +76,29 @@ def _observed(ctx) -> architectures.Observed:
         counter=counter)
 
 
+def _live_contexts(ctx) -> list[int]:
+    """Context of each stream in flight at the slice's middle: its prompt
+    plus the tokens it had been sent by then."""
+    mid = sum(_slice(ctx)) / 2
+    pace = stats.percentile(
+        [v for r in ctx.records if r.ok
+         for v in [stats.tpot_ms(r.first, r.finished, r.completion_tokens)] if v], 50)
+    live = []
+    for r in ctx.records:
+        if r.first is None or r.first > mid or (r.finished or mid + 1) < mid:
+            continue
+        n = r.completion_tokens or r.req.max_tokens
+        # tokens sent by the slice's middle, evenly spread as in
+        # stats.tokens_in_window; a stream still running is taken to
+        # run at the median pace of those that ended
+        if r.finished:
+            sent = 1 + (n - 1) * (mid - r.first) / max(r.finished - r.first, 1e-9)
+        else:
+            sent = min(n, 1 + (mid - r.first) * 1000.0 / pace) if pace else 1
+        live.append(int((r.prompt_tokens or len(r.req.prompt)) + sent))
+    return live
+
+
 def read(ctx, stat: str, module: str | None = None, op: str | None = None,
          per: str | None = None):
     tr = ctx.trace
@@ -96,30 +126,19 @@ def read(ctx, stat: str, module: str | None = None, op: str | None = None,
         floor_ms = 1000.0 * arch.decode_weight_bytes(
             mf, ctx.config["serve"].get("quant"), _observed(ctx)) / pk.hbm_bytes_per_s
         return 100.0 * floor_ms / step if step else None
+    if stat == "step_mfu":
+        step = _module_ms(ctx, module, per_step=True)
+        lanes = _observed(ctx).decode_lanes_mean
+        live = _live_contexts(ctx)
+        if not step or not lanes or not live:
+            return None
+        flops = arch.forward_flops_per_token(mf, int(sum(live) / len(live))) * lanes
+        return 100.0 * flops / (step / 1000.0) / pk.bf16_flops
     if stat == "attn_roofline":
-        a, b = _slice(ctx)
-        mid = (a + b) / 2
         kernel = [(s, c) for k, s, c in tr["ops"]
                   if k.startswith(module + "/") and op in k]
         seconds, calls = sum(s for s, _ in kernel), sum(c for _, c in kernel)
-        pace = stats.percentile(
-            [v for r in ctx.records if r.ok
-             for v in [stats.tpot_ms(r.first, r.finished, r.completion_tokens)] if v], 50)
-        # Context of each stream in flight at the slice's middle: its prompt
-        # plus the tokens it had been sent by then.
-        live = []
-        for r in ctx.records:
-            if r.first is None or r.first > mid or (r.finished or mid + 1) < mid:
-                continue
-            n = r.completion_tokens or r.req.max_tokens
-            # tokens sent by the slice's middle, evenly spread as in
-            # stats.tokens_in_window; a stream still running is taken to
-            # run at the median pace of those that ended
-            if r.finished:
-                sent = 1 + (n - 1) * (mid - r.first) / max(r.finished - r.first, 1e-9)
-            else:
-                sent = min(n, 1 + (mid - r.first) * 1000.0 / pace) if pace else 1
-            live.append(int((r.prompt_tokens or len(r.req.prompt)) + sent))
+        live = _live_contexts(ctx)
         if not calls or not live:
             return None
         need = arch.attn_decode_bytes_per_layer(

@@ -11,7 +11,12 @@ architecture's plain reference (``chipbench/architectures``, by the
 configuration's ``model_type``) over prompt + generated tokens on its own
 weights (:func:`score_request`), and the parent holds the two together
 with :func:`compare`. Sent a second time, the probe's prompt is a
-prefix-cache hit, so that path is compared as well. The HTTP path is not
+prefix-cache hit, so that path is compared as well. How long the probe is,
+is the configuration's to say (``run.probe_of``); how the reference scores
+it is the architecture's, where its module brings a ``score_probe`` of its
+own (a step that yields other than the next token of a sequence), and
+:func:`score_probe` here otherwise. The comparison and its tolerance are
+neither's. The HTTP path is not
 part of this check: every measured response is checked on it
 (``run.check_record``).
 
@@ -52,11 +57,14 @@ def reference_logprobs(cfg: dict, params, ids: list[int], rows: list[int], **opt
     return np.asarray(jax.nn.log_softmax(logits, axis=-1), np.float32)
 
 
-def run_probe(core, prompt_ids: list[int], max_tokens: int, top: int, tag: str) -> dict:
+def run_probe(core, prompt_ids: list[int], max_tokens: int, top: int, tag: str,
+              extra: bool = False) -> dict:
     """One greedy request with log-probabilities through the worker's own
     engine core, stepped the way its warm-up steps it: the same programs,
     scheduler and paged cache a served request runs on. Only called while
-    the worker serves nothing (the parent sends no traffic meanwhile)."""
+    the worker serves nothing (the parent sends no traffic meanwhile).
+    ``extra``: carry what else the program said of each token, for an
+    architecture that scores the probe itself."""
     from dynamo_tpu.llm.protocols.common import (
         OutputOptions,
         PreprocessedRequest,
@@ -78,18 +86,29 @@ def run_probe(core, prompt_ids: list[int], max_tokens: int, top: int, tag: str) 
             if s is seq:
                 tokens += list(out.token_ids)
                 entries += list(out.logprobs or [])
-    return {
+    probe = {
         "tokens": tokens,
         "top_ids": [[t for t, _ in e["top"]] for e in entries],
         "top_lps": [[lp for _, lp in e["top"]] for e in entries],
         "cached_tokens": int(seq.num_cached_tokens),
     }
+    if extra:
+        # what else the program said of each token (the denoising step that
+        # chose it, say): a CLAIM, which the architecture's score_probe checks
+        # against what its own forward would have done; it never only replays it
+        probe["extra"] = [{k: v for k, v in e.items() if k != "top"} for e in entries]
+    return probe
 
 
 def score_probe(cfg: dict, params, prompt: list[int], probe: dict, **options) -> dict:
     """The reference's side of one probe as :func:`run_probe` gave it: its
     log-probability of every id the engine listed, its own arg-max and
-    that arg-max's log-probability, per generated position."""
+    that arg-max's log-probability, per generated position. For a model
+    whose step yields the NEXT token: one causal forward over prompt +
+    generated tokens. An architecture whose tokens are chosen otherwise
+    brings a ``score_probe`` of its own with this signature and this return
+    (``chipbench/architectures``, which says what it owes), built on
+    :func:`reference_logprobs`."""
     import numpy as np
 
     ids = prompt + probe["tokens"]
@@ -140,15 +159,16 @@ def score_request(core, cfg: dict, body: dict) -> dict:
 
 def _score_request(core, cfg: dict, body: dict) -> dict:
     prompt = list(body["prompt_ids"])
-    served = [run_probe(core, prompt, body["max_tokens"], body["top"], tag)
+    own = getattr(architectures.of(cfg), "score_probe", None)
+    served = [run_probe(core, prompt, body["max_tokens"], body["top"], tag, extra=bool(own))
               for tag in ("first", "repeat")]
+    asked = ("tokens", "top_ids") + (("extra",) if own else ())
     scored = []
     for probe in served:
-        if scored and probe["tokens"] == served[0]["tokens"] and (
-                probe["top_ids"] == served[0]["top_ids"]):
+        if scored and all(probe[k] == served[0][k] for k in asked):
             scored.append(scored[0])   # the same sequence and the same ids asked
         else:
-            scored.append(score_probe(cfg, core.params, prompt, probe))
+            scored.append((own or score_probe)(cfg, core.params, prompt, probe))
     return {"served": served, "scored": {"sequences": scored},
             "megastep_k": int(core.engine.megastep)}
 

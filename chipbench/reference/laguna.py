@@ -42,7 +42,13 @@ configuration's ``assumed`` reads it (the gate's nonlinearity and input, the
 router's sigmoid, half-split rope pairs: each in ONE place here); weights
 are random, from the seed. ``faults`` names mechanisms to leave out, for the
 comparisons that must then FAIL: "window" (a window layer attends the whole
-context), "gate" (no output gate).
+context), "gate" (no output gate). "fp8" leaves nothing out: it is the
+CONTROL of a bfloat16 configuration (``reference/control.py``), the same
+mathematics in the nearest precision below the one the configuration
+states: every activation that enters a weight product (the two normed
+inputs of a layer, the heads' output into ``Wo``, the final normed row) and
+K and V as a cache would hold them, rounded to ``float8_e4m3fn``; weights,
+the router's input, softmax and the sums stay float32.
 
 Weights arrive a piece at a time as float32 arrays in the published
 (unfused) layout from ``chipbench/architectures/laguna.py``.
@@ -81,16 +87,22 @@ def gate_of(a, w_gate):
     return jax.nn.sigmoid(a @ w_gate)
 
 
-def attention(x, w, *, n_kv, head_dim, rp, window, eps, gated=True):
+def fp8(t):
+    """``t`` as an e4m3 value would hold it, in float32 again."""
+    return t.astype(jnp.float8_e4m3fn).astype(jnp.float32)
+
+
+def attention(x, w, *, n_kv, head_dim, rp, window, eps, gated=True, low=lambda t: t):
     """x + attention(rmsnorm(x)) over a whole sequence x [T, h] (float32);
-    ``window`` None for a full layer."""
+    ``window`` None for a full layer; ``low`` rounds what the control keeps
+    in a lower precision."""
     T = x.shape[0]
-    a = rms_norm(x, w["attn_norm"], eps)
+    a = low(rms_norm(x, w["attn_norm"], eps))
     q = (a @ w["wq"]).reshape(T, -1, head_dim)
     k = (a @ w["wk"]).reshape(T, n_kv, head_dim)
-    v = (a @ w["wv"]).reshape(T, n_kv, head_dim)
+    v = low((a @ w["wv"]).reshape(T, n_kv, head_dim))
     pos = jnp.arange(T)
-    q, k = rope(q, pos, rp), rope(k, pos, rp)
+    q, k = rope(q, pos, rp), low(rope(k, pos, rp))
     seen = pos[:, None] >= pos[None, :]
     if window is not None:
         seen = seen & (pos[None, :] > pos[:, None] - window)
@@ -109,7 +121,7 @@ def attention(x, w, *, n_kv, head_dim, rp, window, eps, gated=True):
     o = o.transpose(1, 0, 2, 3).reshape(T, -1, head_dim)     # [T, n_l, d], head h = g * group + j
     if gated:
         o = o * gate_of(a, w["wg"])[:, :, None]
-    return x + o.reshape(T, -1) @ w["wo"]
+    return x + low(o.reshape(T, -1)) @ w["wo"]
 
 
 def routing_weights(b, w_router, *, top_k, scale):
@@ -131,11 +143,13 @@ def forward(ids, embed, layers, final_norm, lm_head_chunks, *, n_kv, head_dim, r
     ``mlp`` either ``("dense", blocks)`` or ``("sparse", w_router [h, E],
     experts, shared_blocks)`` as in ``reference.axk1.forward``. A piece at a
     time, as in ``reference.qwen2.forward``."""
+    low = fp8 if "fp8" in faults else (lambda t: t)
+
     def attn_of(kind):
         win = window if kind == "sliding_attention" and "window" not in faults else None
         return jax.jit(lambda x, w: attention(
             x, w, n_kv=n_kv, head_dim=head_dim, rp=rope_by_kind[kind], window=win,
-            eps=eps, gated="gate" not in faults))
+            eps=eps, gated="gate" not in faults, low=low))
 
     attn = {kind: attn_of(kind) for kind in rope_by_kind}
     route = jax.jit(lambda b, w_router: routing_weights(b, w_router, top_k=top_k, scale=scale))
@@ -149,15 +163,16 @@ def forward(ids, embed, layers, final_norm, lm_head_chunks, *, n_kv, head_dim, r
             b = rms_norm(x, mlp_norm, eps)
             if mlp[0] == "dense":
                 for w_gate, w_up, w_down in mlp[1]:
-                    x = x + block(b, w_gate, w_up, w_down)
+                    x = x + block(low(b), w_gate, w_up, w_down)
                 continue
             _, w_router, experts, shared_blocks = mlp
             weights = route(b, w_router)
+            b = low(b)
             for e, w_gate, w_up, w_down in experts:
                 if lo <= e < hi:
                     x = x + expert(b, weights[:, e], w_gate, w_up, w_down)
             for w_gate, w_up, w_down in shared_blocks:
                 if shared:
                     x = x + block(b, w_gate, w_up, w_down)
-        x = rms_norm(x[jnp.asarray(rows)], final_norm, eps)
+        x = low(rms_norm(x[jnp.asarray(rows)], final_norm, eps))
         return jnp.concatenate([x @ chunk for chunk in lm_head_chunks], axis=-1)

@@ -40,6 +40,8 @@ from chipbench.reference import check as ref_check  # noqa: E402
 
 ROOT = Path(__file__).resolve().parent.parent
 OUT = ROOT / "chipbench_out"          # git-ignored: logs, traces, run records
+# The probe of ``correct`` where the configuration's file sizes none; ``top``
+# is the yardstick's alone (``probe_of``).
 PROBE = {"prompt_tokens": 96, "max_tokens": 17, "top": 5}
 
 
@@ -62,6 +64,19 @@ def side_call(worker, kind: str, body: dict, children, timeout: float) -> dict:
     return result
 
 
+def probe_of(config: dict) -> dict:
+    """The probe's sizes: ``PROBE``, under the configuration file's optional
+    top-level ``probe`` object ``{"prompt_tokens", "max_tokens"}``. A
+    configuration sizes it where the defaults cannot reach what its cells
+    name (a window of 512 positions is never left by 96 + 17 tokens)."""
+    own = config.get("probe", {})
+    if not set(own) <= {"prompt_tokens", "max_tokens"} or not all(
+            isinstance(v, int) and v > 0 for v in own.values()):
+        raise HarnessFault(f"configuration {config.get('name')!r}: probe {own!r} is not "
+                           "{prompt_tokens, max_tokens} of positive whole numbers")
+    return {**PROBE, **own}
+
+
 def reference_check(cl, config: dict, seed: int) -> dict:
     """(a) of ``correct``: the engine's prefill-then-decode through its
     cache against the plain reference, on a seeded probe; see
@@ -70,10 +85,11 @@ def reference_check(cl, config: dict, seed: int) -> dict:
     to random weights."""
     import random
 
+    probe = probe_of(config)
     rng = random.Random(seed ^ 0x5EED)
     hi = min(config["vocab_size"], 32000)
-    ids = [rng.randrange(1, hi) for _ in range(PROBE["prompt_tokens"])]
-    got = side_call(cl.workers[0], "ref", {"prompt_ids": ids, **PROBE}, cl.children, 900)
+    ids = [rng.randrange(1, hi) for _ in range(probe["prompt_tokens"])]
+    got = side_call(cl.workers[0], "ref", {"prompt_ids": ids, **probe}, cl.children, 900)
     verdict = ref_check.compare(got["served"], got["scored"])
     verdict["repeat_identical"] = got["served"][0]["tokens"] == got["served"][1]["tokens"]
     verdict["second_send_cached_tokens"] = got["served"][1]["cached_tokens"]

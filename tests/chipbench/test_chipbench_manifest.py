@@ -8,6 +8,7 @@ import pytest
 
 from chipbench import manifest
 from chipbench.configs import load_config, model_fields
+from chipbench_entries import layer_entry
 
 ROOT = Path(__file__).resolve().parents[2]
 TINY = ROOT / "tests" / "chipbench" / "data" / "tiny_manifest.json"
@@ -29,6 +30,32 @@ def test_file_is_small_and_command_stays_inside_paths(man):
     assert all(not w.startswith("/") and ".." not in w for w in man["command"])
 
 
+def test_the_whole_file_keeps_the_drivers_rules_of_form(man):
+    """What the driver refuses before any run and ``manifest.problems`` does
+    not measure; one test for the whole file, whatever cells it holds."""
+    assert len(json.dumps(man)) < 64 * 1024
+    assert 1 <= len(man["per_layer"]) <= 128 and 1 <= len(man["end_to_end"]) <= 16
+    assert 1 <= len(man["configs"]) <= 24 and 1 <= len(man["workloads"]) <= 24
+    one_line = lambda s: 1 <= len(s) <= 200 and s.isprintable()   # noqa: E731
+    for entry in man["configs"] + man["workloads"]:
+        assert all(one_line(entry[key]) for key in ("why", "source") if key in entry), entry
+    for c in man["configs"]:
+        assert len(c["reduced"]) <= 16 and all(manifest.NAME.match(k) for k in c["reduced"])
+    assert len({c["file"] for c in man["configs"]}) == len(man["configs"])
+    assert all(one_line(m["layer"]) for m in man["per_layer"])
+    assert all(one_line(word) for word in man["command"]) and len(man["command"]) <= 32
+    # a roofline share is a percentage, and the whole step's share of the peak
+    # stands beside the kernels' (the contract's `mfu`)
+    for m in man["per_layer"]:
+        if "roofline" in m["name"] or "mfu" in m["name"]:
+            assert m["unit"] == "%" and m["better"] == "higher", m["name"]
+    # (the six cells of PR 41 each list it; a later cell brings the share that
+    # fits its step, under a name with `mfu` in it)
+    for cell in ("qwen7b-decode-batch", "qwen1p5b-chat-steady", "ouro2p6b-reason-decode",
+                 "axk1-ep16-decode", "lfm2-24b-hybrid-decode", "laguna-s21-longctx-agents"):
+        assert layer_entry(man, "decode_step_mfu", cell) is not None, cell
+
+
 @pytest.mark.parametrize("kind", ["end_to_end", "per_layer"])
 def test_every_metric_has_a_reader_module(man, kind):
     import importlib
@@ -48,8 +75,10 @@ def test_layer_metric_moves_a_metric_its_cells_report(man):
 
 
 def test_layers_are_spelled_one_way(man):
+    # PERF.md section 3's layers, letter for letter; a later PR may name a
+    # layer of its own beside them
     layers = {m["layer"] for m in man["per_layer"]}
-    assert layers == {"load generator", "frontend", "scheduler",
+    assert layers >= {"load generator", "frontend", "scheduler",
                       "device programs", "kernels", "device", "set-up",
                       "demoted end-to-end"}
 
@@ -57,7 +86,7 @@ def test_layers_are_spelled_one_way(man):
 def test_a_suffixed_metric_reads_with_its_base_names_file():
     base = manifest.metric_file("per_layer", "queue_wait_ms_mean")
     assert manifest.metric_file("per_layer", "queue_wait_ms_mean.chat") == base
-    assert manifest.metric_file("per_layer", "device_idle_share.batch").name == (
+    assert manifest.metric_file("per_layer", "device_idle_share.chat").name == (
         "device_idle_share.json")
     own = manifest.metric_file("per_layer", "open_loop_ttft_ms_p90")
     assert own.name == "open_loop_ttft_ms_p90.json" and own.exists()

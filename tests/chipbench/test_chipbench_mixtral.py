@@ -133,7 +133,7 @@ def test_whole_command_on_the_cpu_on_the_sparse_configuration():
     assert proc.returncode == 0, proc.stderr[-3000:]
     result = json.loads(proc.stdout.strip().splitlines()[-1])
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 5
-    assert {"tokens_per_dispatch", "device_idle_share.batch", "warmup_s", "correct_check_s",
+    assert {"tokens_per_dispatch", "device_idle_share", "warmup_s", "correct_check_s",
             "closed_loop_ttft_ms_p50"} <= set(result["metrics"]), result["metrics"]
     assert result["device"]["busy_s"] > 0 and result["breakdown"]["device_ops"]
     record = json.loads((ROOT / "chipbench_out" / "tiny-moe-closed-1" / "run.json").read_text())

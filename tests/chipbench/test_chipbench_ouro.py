@@ -18,6 +18,7 @@ from chipbench.architectures import ouro
 from chipbench.configs import engine_overrides, load_config, model_fields
 from chipbench.readers import scope_share
 from chipbench.reference import check
+from chipbench_entries import layer_entry
 
 ROOT = Path(__file__).resolve().parents[2]
 TINY = "tests/chipbench/data/tiny_manifest_ouro.json"
@@ -105,15 +106,19 @@ def test_the_cell_is_the_one_the_issue_sizes():
     assert manifest.topology_of(cell) == "one-worker"
     e2e = {m["name"] for m in manifest.metrics_of(man, "end_to_end", CELL)}
     assert e2e == {"setup_s", "tpot_ms_p50", "output_tokens_per_s"}
-    layer = {m["name"] for m in manifest.metrics_of(man, "per_layer", CELL)}
-    assert {n for n in layer if n.endswith(".loop")} == {n + ".loop" for n in (
-        "decode_step_device_ms", "decode_weight_floor_share", "attn_kernel_time_share",
-        "attn_decode_roofline", "lm_head_time_share", "unscoped_time_share",
-        "device_idle_share", "hbm_peak_share", "tokens_per_dispatch", "host_ms_per_dispatch",
-        "decode_lane_occupancy", "preemptions_per_kdispatch", "closed_loop_ttft_ms_p50",
-        "layer_passes_per_token", "loop_norm_time_share")}
-    assert layer - {n for n in layer if n.endswith(".loop")} == {
-        "warmup_s", "compile_s", "trace_lower_s", "correct_check_s"}
+    # at least these, under whatever name and wherever they stand (PR 41: one
+    # entry a metric, with a list of cells)
+    for reader in (
+            "decode_step_device_ms", "decode_weight_floor_share", "attn_kernel_time_share",
+            "attn_decode_roofline", "lm_head_time_share", "unscoped_time_share",
+            "device_idle_share", "hbm_peak_share", "tokens_per_dispatch", "host_ms_per_dispatch",
+            "decode_lane_occupancy", "preemptions_per_kdispatch", "closed_loop_ttft_ms_p50",
+            "layer_passes_per_token", "loop_norm_time_share", "warmup_s", "compile_s",
+            "trace_lower_s", "correct_check_s"):
+        assert layer_entry(man, reader, CELL) is not None, reader
+    # the loop's own two list no cell that runs its stack once
+    for reader in ("layer_passes_per_token", "loop_norm_time_share"):
+        assert "qwen7b-decode-batch" not in layer_entry(man, reader, CELL)["workloads"]
     # every stream at its longest fits the cache with room: no preemption
     traffic = generators.load_traffic(cell["traffic"])
     engine = load_config(NAME)["serve"]["engine"]
@@ -213,7 +218,7 @@ def test_whole_command_on_the_cpu_on_the_looped_configuration():
     assert proc.returncode == 0, proc.stderr[-3000:]
     result = json.loads(proc.stdout.strip().splitlines()[-1])
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 5
-    assert {"tokens_per_dispatch", "device_idle_share.batch", "warmup_s", "correct_check_s",
+    assert {"tokens_per_dispatch", "device_idle_share", "warmup_s", "correct_check_s",
             "closed_loop_ttft_ms_p50", "layer_passes_per_token.loop"} <= set(
                 result["metrics"]), result["metrics"]
     # three passes a token: a little more for the iterations a stream's end wastes, a

@@ -16,6 +16,7 @@ import pytest
 from chipbench import manifest
 from chipbench.readers import phase_summary
 from chipbench.trace import phases
+from chipbench_entries import layer_entry
 
 ROOT = Path(__file__).resolve().parents[2]
 DATA = ROOT / "tests" / "chipbench" / "data"
@@ -24,10 +25,14 @@ MS = 1e6   # ns
 
 NEW = ["host_ms_per_dispatch", "between_steps_ms_per_dispatch", "land_wait_ms_per_dispatch",
        "decode_lane_occupancy", "megastep_useful_share", "prefill_bucket_fill",
-       "preemptions_per_kdispatch", "idle_in_step_share", "idle_between_steps_share",
-       "idle_land_share", "idle_no_work_share", "idle_unattributed_share",
-       "lm_head_time_share", "unscoped_time_share"]
-COUNTERS, IDLE = NEW[:7], NEW[7:12]
+       "preemptions_per_kdispatch", "lm_head_time_share", "unscoped_time_share"]
+COUNTERS = NEW[:7]
+# The five idle_*_share metrics that split a slice's idle seconds by these
+# phases went in PR 41 (device_starved_share says it of the whole window); the
+# reader keeps the stat, and the tests below keep it to its arithmetic.
+IDLE = {"in_step": ["admit", "plan", "assemble", "h2d", "dispatch", "commit"],
+        "between_steps": ["between_steps"], "land": ["land"], "no_work": ["no_work"],
+        "unattributed": ["unattributed"]}
 
 
 def hand_made(shift_ms: float = 0.0) -> dict:
@@ -147,15 +152,12 @@ def test_the_reader_on_the_hand_made_summary(tmp_path, monkeypatch):
     out.parent.mkdir(parents=True)
     out.write_text(json.dumps(phases.summarize(hand_made())))
     ctx = SimpleNamespace(cell={"name": "cell"}, trace={"devices": 1})
-    shares = {}
-    for name in IDLE:
-        spec = json.loads(manifest.metric_file("per_layer", name).read_text())
-        assert spec["reader"] == "phase_summary"
-        shares[name] = phase_summary.read(ctx, **spec["args"])
+    shares = {name: phase_summary.read(ctx, "idle_share", phases=phases_of)
+              for name, phases_of in IDLE.items()}
     assert sum(shares.values()) == pytest.approx(100.0)
-    assert shares["idle_in_step_share"] == pytest.approx(100 * 23 / 42)
-    assert shares["idle_land_share"] == pytest.approx(100 * 6 / 42)
-    assert shares["idle_unattributed_share"] == pytest.approx(100 * 4 / 42)
+    assert shares["in_step"] == pytest.approx(100 * 23 / 42)
+    assert shares["land"] == pytest.approx(100 * 6 / 42)
+    assert shares["unattributed"] == pytest.approx(100 * 4 / 42)
     lm = json.loads(manifest.metric_file("per_layer", "lm_head_time_share").read_text())
     assert phase_summary.read(ctx, **lm["args"]) == pytest.approx(100 * 28 / 58)
     un = json.loads(manifest.metric_file("per_layer", "unscoped_time_share").read_text())
@@ -187,17 +189,19 @@ def test_recorded_v5e_slice():
 
 def test_manifest_has_the_new_metrics_for_both_cells():
     man = manifest.load()
-    by_name = {m["name"]: m for m in man["per_layer"]}
     for base in NEW:
-        for suffix, cell, moves in (("batch", "qwen7b-decode-batch", "tpot_ms_p50"),
-                                    ("chat", "qwen1p5b-chat-steady", "tpot_ms_mean")):
-            if base == "prefill_bucket_fill" and suffix == "batch":
-                assert f"{base}.batch" not in by_name
+        for cell, moves in (("qwen7b-decode-batch", "tpot_ms_p50"),
+                            ("qwen1p5b-chat-steady", "tpot_ms_mean")):
+            m = layer_entry(man, base, cell)
+            if base == "prefill_bucket_fill" and moves == "tpot_ms_p50":
+                # batch's fill reads with prefill_wave_fill.json (PERF.md section 7)
+                assert m is None and layer_entry(man, "prefill_wave_fill", cell)
                 continue
-            m = by_name[f"{base}.{suffix}"]
-            assert m["workloads"] == [cell] and m["moves"] == moves
+            assert cell in m["workloads"] and m["moves"] == moves
             assert m["source"] == ("program_counter" if base in COUNTERS else "device_trace")
-            assert manifest.metric_file("per_layer", m["name"]).name == f"{base}.json"
+    # the retired five are gone, and nothing reads with their files
+    assert not [m["name"] for m in man["per_layer"] if m["name"].startswith("idle_")]
+    assert not list((ROOT / "chipbench" / "layer_metrics").glob("idle_*"))
     assert manifest.problems(manifest.load(ROOT / TINY)) == []
 
 
@@ -211,16 +215,20 @@ def test_new_metrics_in_the_cpu_rehearsal(workload, suffix):
     assert proc.returncode == 0, proc.stderr[-3000:]
     result = json.loads(proc.stdout.strip().splitlines()[-1])
     assert result["correct"] is True
-    got = {k: v["value"] for k, v in result["metrics"].items()}
+    # by reader file: a rehearsal cell's names carry a suffix or not as the
+    # benchmark's own do (PR 41)
+    got = {manifest.metric_file("per_layer", k).stem: v["value"]
+           for k, v in result["metrics"].items()}
     for base in COUNTERS:
         if base == "prefill_bucket_fill" and suffix == "batch":
             continue
-        assert isinstance(got[f"{base}.{suffix}"], float), base
-    assert 0 < got[f"decode_lane_occupancy.{suffix}"] <= 100
-    assert 0 < got[f"megastep_useful_share.{suffix}"] <= 100
-    assert got[f"host_ms_per_dispatch.{suffix}"] > got[f"between_steps_ms_per_dispatch.{suffix}"] > 0
-    assert sum(got[f"{base}.{suffix}"] for base in IDLE) == pytest.approx(100.0)
+        assert isinstance(got[base], float), base
+    assert 0 < got["decode_lane_occupancy"] <= 100
+    assert 0 < got["megastep_useful_share"] <= 100
+    assert got["host_ms_per_dispatch"] > got["between_steps_ms_per_dispatch"] > 0
     summary = json.loads((ROOT / "chipbench_out" / workload / "phase_summary.json").read_text())
+    # the slice's idle seconds still add up by phase in the summary
+    assert sum(summary["idle_s"].values()) == pytest.approx(summary["idle_total_s"])
     counters = summary["counters"]
     # between the two scrapes, which a busy CPU does not take exactly at the
     # window's open and close (on the chip, 45 s: 44.99 s)

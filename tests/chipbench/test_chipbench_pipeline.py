@@ -54,7 +54,7 @@ def test_manifest_has_them_for_both_cells():
         for suffix, cell, moves in (("batch", "qwen7b-decode-batch", "tpot_ms_p50"),
                                     ("chat", "qwen1p5b-chat-steady", "tpot_ms_mean")):
             m = by_name[f"{base}.{suffix}"]
-            assert m["workloads"] == [cell] and m["moves"] == moves
+            assert cell in m["workloads"] and m["moves"] == moves
             assert (m["unit"], m["source"], m["layer"]) == (unit, "program_counter", "scheduler")
             spec = json.loads(manifest.metric_file("per_layer", m["name"]).read_text())
             assert spec["reader"] == "prometheus_ratio" and spec["doc"]
@@ -76,6 +76,6 @@ def test_the_rehearsals_served_loop_is_pipelined():
     # pipeline (the first, and one after a lull) is not pipelined.
     assert 50 < got["pipelined_dispatch_share.batch"] <= 100
     assert got["pipeline_drains_per_kdispatch.batch"] == 0
-    assert got["preemptions_per_kdispatch.batch"] == 0
+    assert got["preemptions_per_kdispatch"] == 0
     record = json.loads((ROOT / "chipbench_out" / "tiny-closed-p" / "run.json").read_text())
     assert record["compiled_in_window"] == []

@@ -1,9 +1,11 @@
 """Readers over scrapes, /health and the reduced trace."""
 
+import json
 from types import SimpleNamespace
 
 import pytest
 
+from chipbench import manifest
 from chipbench.readers import (
     health_field,
     prometheus,
@@ -120,6 +122,37 @@ def test_trace_stats():
     with pytest.raises(KeyError):
         trace_reduce.read(_trace_ctx(), "module_ms", module="_megastep_body",
                           per="megastep_k")
+
+
+def test_the_whole_steps_share_of_the_chips_peak():
+    """``decode_step_mfu``: the operations a step needs (the architecture's
+    count at the mean context in flight x the live lanes a decode dispatch
+    carried) over the measured step, against the bf16 peak."""
+    from chipbench import peaks
+    from chipbench.configs import model_fields
+
+    ctx = _trace_ctx(megastep_k=8, trace_started_unix=100.0, trace_seconds=4.0)
+    req = SimpleNamespace(max_tokens=1025, prompt="x" * 300)
+    ctx.records = [SimpleNamespace(ok=True, first=90.0, finished=110.0, completion_tokens=1025,
+                                   prompt_tokens=300 + 100 * i, cached_tokens=0, req=req)
+                   for i in range(4)]
+    lines = lambda lanes, n: {"worker": [  # noqa: E731
+        f'dynamo_engine_decode_live_lanes_total{{service="engine"}} {lanes}\n'
+        f'dynamo_scheduler_megastep_dispatches_total{{service="engine"}} {n}\n'], "frontend": []}
+    ctx.scrape_open, ctx.scrape_close = lines(0, 0), lines(3000, 100)
+    got = trace_reduce.read(ctx, "step_mfu", module="_megastep_body")
+    # the slice's middle is 12 s into streams of 1,024 steps in 20 s: 1 + 614.4 sent
+    context = int(sum(300 + 100 * i + 1 + 1024 * 12 / 20 for i in range(4)) / 4)
+    flops = peaks.forward_flops_per_token(model_fields(ctx.config), context) * 30
+    assert got == pytest.approx(100 * flops / 0.014 / 197e12)
+    assert 5 < got < 20            # decode is bandwidth-bound: far from 100, never 0
+    spec = json.loads(manifest.metric_file("per_layer", "decode_step_mfu.chat").read_text())
+    assert spec["reader"] == "trace_reduce" and spec["args"]["stat"] == "step_mfu"
+    # nothing to read: no counters (an untraced run scrapes none), no streams, no program
+    ctx.scrape_open, ctx.scrape_close = {}, {}
+    assert trace_reduce.read(ctx, "step_mfu", module="_megastep_body") is None
+    ctx.scrape_open, ctx.scrape_close, ctx.records = lines(0, 0), lines(3000, 100), []
+    assert trace_reduce.read(ctx, "step_mfu", module="_megastep_body") is None
 
 
 def test_no_trace_no_number():

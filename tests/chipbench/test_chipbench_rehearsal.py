@@ -30,7 +30,7 @@ def run_cell(workload: str, trace: int, *extra: str, timeout: float = 240):
 
 
 @pytest.mark.parametrize("workload,trace,workers,must_have", [
-    ("tiny-closed-1", 1, 1, {"tokens_per_dispatch", "device_idle_share.batch",
+    ("tiny-closed-1", 1, 1, {"tokens_per_dispatch", "device_idle_share",
                              "warmup_s", "correct_check_s", "closed_loop_ttft_ms_p50"}),
     ("tiny-sessions-4x1", 0, 4, {"setup_s", "ttft_ms_p90"}),
 ])
