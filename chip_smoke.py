@@ -4,6 +4,7 @@
     python chip_smoke.py --cpu-tiny   # rehearsal on the CPU, `tiny` preset
     python chip_smoke.py --cpu-tiny --cpu-preset tiny-lfm2   # the same, a hybrid model
     python chip_smoke.py --cpu-tiny --cpu-preset tiny-laguna # the same, window + full layers
+    python chip_smoke.py --cpu-tiny --cpu-preset tiny-sdar   # the same, generation by blocks
 
 Starts the three processes a user starts (README "Run it"): the control-
 plane store, the JAX worker and the OpenAI frontend with the KV router.
@@ -455,6 +456,7 @@ def serve_phase(mode: str, inject: str | None, report: dict) -> None:
             "dynamo_engine_expert_calls_traced_total", judge_experts_traced)
         for role, st in report["startup"].items():
             judge_window(role, st, report["attention_traced"][role])
+            judge_blocks(role, st, report["attention_traced"][role])
         report["token_account"] = token_accounts(workers)
     finally:
         children.stop()
@@ -540,7 +542,7 @@ def judge_attention_traced(role: str, got: dict[str, float], platform: str) -> N
     """``got``: ``{"<shape>/<impl>": calls traced}`` of one worker."""
     traced = {k for k, v in got.items() if v}
     if role != "prefill" and not any(
-            k.split("/")[0] in ("decode", "latent-decode") for k in traced):
+            k.split("/")[0] in ("decode", "latent-decode", "block-decode") for k in traced):
         raise PhaseFailed(f"{role}: no decode-shaped attention call was traced: {got}")
     if platform == "tpu" and any(k.endswith("/reference") for k in traced):
         raise PhaseFailed(f"{role}: attention ran the jnp reference on a TPU: {got}")
@@ -571,6 +573,23 @@ def judge_window(role: str, startup: dict, traced: dict[str, float]) -> None:
         raise PhaseFailed(
             f"{role}: window table of {startup['window_table_blocks']} columns for "
             f"window {window}, blocks of {bs} and chunks of {chunk}: at most {limit}")
+
+
+def judge_blocks(role: str, startup: dict, traced: dict[str, float]) -> None:
+    """A worker of a block-diffusion model (``startup.block_length``): its
+    steps must have traced a ``block-decode`` call, the block's rows folded
+    into one decode-shaped call of the kernel (on a TPU never the jnp
+    reference: :func:`judge_attention_traced` has refused that), and none of
+    the plain ``decode`` shape, whose mask is causal inside a block."""
+    if not startup.get("block_length"):
+        return
+    live = {k for k, v in traced.items() if v}
+    if role != "prefill" and not any(k.startswith("block-decode/") for k in live):
+        raise PhaseFailed(f"{role}: a block model traced no block-decode call: {traced}")
+    if any(k.split("/")[0] in ("decode", "ragged") for k in live):
+        raise PhaseFailed(
+            f"{role}: a block model traced a causal attention call, which cannot see a "
+            f"block both ways: {traced}")
 
 
 def kernel_phase(mode: str, inject: str | None, report: dict) -> None:
@@ -822,11 +841,12 @@ def main() -> int:
                        help="two one-chip workers: --role prefill and --role decode")
     which.add_argument("--kernel-check-child", action="store_true",
                        help=argparse.SUPPRESS)
-    ap.add_argument("--cpu-preset", choices=["tiny", "tiny-lfm2", "tiny-laguna"],
+    ap.add_argument("--cpu-preset", choices=["tiny", "tiny-lfm2", "tiny-laguna", "tiny-sdar"],
                     default="tiny",
                     help="what --cpu-tiny serves: the dense tiny preset, the "
-                         "hybrid one (conv layers beside paired 64-wide heads), or "
-                         "the one of window and full attention layers (two pools)")
+                         "hybrid one (conv layers beside paired 64-wide heads), "
+                         "the one of window and full attention layers (two pools), or "
+                         "the one that generates by diffusion over blocks")
     ap.add_argument("--corrupt", action="store_true", help=argparse.SUPPRESS)
     ap.add_argument("--inject", choices=["worker-start", "bad-request",
                                          "kernel-mismatch"], default=None,

@@ -617,6 +617,15 @@ async def run_jax_worker(
             core.cfg.sliding_window, core.cfg.cache_layers("window"),
             core.engine.num_window_blocks, startup["window_bytes_per_sequence"],
             startup["window_table_blocks"])
+    if core.cfg.block_length:
+        startup["block_length"] = core.cfg.block_length
+        startup["denoising_steps"] = core.cfg.denoising_steps
+        startup["megastep_k"] = core.engine.megastep
+        log.info(
+            "blocks of %d places, %d denoising passes and a clean one; %d forwards "
+            "(%d blocks) a dispatch", core.cfg.block_length, core.cfg.denoising_steps,
+            core.engine.megastep,
+            core.engine.megastep // (core.cfg.denoising_steps + 1))
     if core.cfg.shared_sparse:
         startup["experts_held"] = list(core.cfg.experts_held_range)
     log.info(

@@ -173,6 +173,24 @@ class ModelConfig:
     # A gate on each head's attention output, sigmoid(norm(x) Wg) with Wg
     # [h, heads] (``gating`` "per-head"), before the output projection.
     attn_gate: bool = False
+    # -- generation by diffusion over blocks (SDAR) ---------------------------
+    # ``block_length`` B > 0: the model generates B places at a time. A
+    # query at position p sees key j iff ``j // B <= p // B``: every
+    # earlier block and, BOTH ways, its own. A block starts as
+    # ``mask_token_id`` at its hidden places; a denoising pass runs the
+    # stack over the block's B rows, samples each hidden place from its OWN
+    # row and reveals those whose confidence passes
+    # ``confidence_threshold`` or, if fewer than the step's quota
+    # (``B / denoising_steps``, the remainder to the first steps), the
+    # quota's most confident (sampler.unmask_block); one more pass over the
+    # clean block writes its K/V. 0: one next token a step, as ever.
+    # ``denoising_steps`` is what a block is SERVED with (the one place
+    # that says so): a deployment that serves fewer than the released
+    # usage states its own in the model's configuration.
+    block_length: int = 0
+    denoising_steps: int = 0
+    confidence_threshold: float = 1.0
+    mask_token_id: int = 0
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
@@ -192,6 +210,7 @@ class ModelConfig:
         self._check_latent_sparse()
         self._check_hybrid()
         self._check_windowed()
+        self._check_block()
         if self.ut_steps < 1:
             raise ValueError(f"ut_steps={self.ut_steps} must be >= 1")
         if self.ut_steps > 1 and self.early_exit_threshold < 1.0:
@@ -242,13 +261,18 @@ class ModelConfig:
             stray = [f for f, d in sparse_only.items() if getattr(self, f) != d]
             if stray:
                 raise ValueError(
-                    f"{stray} set with router_scoring='softmax' or no experts:"
-                    " only the sigmoid-routed sparse MLP reads them"
+                    f"{stray} set without moe_intermediate_size > 0 or with no "
+                    "experts: only the dropless sparse MLP reads them"
                 )
+            if self.is_moe and self.router_scoring != "softmax":
+                raise ValueError("router_scoring='sigmoid' needs moe_intermediate_size > 0")
             return
         E, k = self.num_experts, self.num_experts_per_tok
-        if self.moe_intermediate_size <= 0:
-            raise ValueError("router_scoring='sigmoid' needs moe_intermediate_size > 0")
+        if self.router_scoring == "softmax" and (
+                self.n_group > 1 or self.router_bias or self.routed_scaling_factor != 1.0):
+            raise NotImplementedError(
+                "router_scoring='softmax' on the dropless layer with groups, a "
+                "choice bias or a scaling factor is not implemented")
         if not 0 <= self.first_dense_layers < self.num_layers:
             raise ValueError(
                 f"first_dense_layers={self.first_dense_layers} of "
@@ -280,6 +304,30 @@ class ModelConfig:
                     f"experts_held={self.experts_held}: 'of' must divide "
                     f"num_experts={E} and rank lie in 0..of-1"
                 )
+
+    def _check_block(self) -> None:
+        """What only a block-diffusion model reads, and the layers its
+        in-block two-way mask was written for: plain GQA pages."""
+        B = self.block_length
+        if not B:
+            stray = [f for f, d in (("denoising_steps", 0), ("confidence_threshold", 1.0),
+                                    ("mask_token_id", 0)) if getattr(self, f) != d]
+            if stray:
+                raise ValueError(f"{stray} set with block_length=0: only a model that "
+                                 "generates by blocks reads them")
+            return
+        if B < 1 or not 1 <= self.denoising_steps <= B:
+            raise ValueError(f"block_length={B} needs 1 <= denoising_steps="
+                             f"{self.denoising_steps} <= block_length")
+        if not 0 <= self.mask_token_id < self.vocab_size:
+            raise ValueError(f"mask_token_id={self.mask_token_id} outside the vocabulary")
+        if (self.latent or self.layer_types is not None or self.ut_steps > 1
+                or self.kv_head_pairs or (self.is_moe and not self.shared_sparse)):
+            raise NotImplementedError(
+                "block_length > 0 with latent attention, layers of more than one "
+                "kind, a looped stack, paired KV heads or the capacity-bounded "
+                "(mixtral) MLP is not implemented: the in-block mask is the GQA "
+                "layer's")
 
     def _check_hybrid(self) -> None:
         """``layer_types`` and what only a conv layer reads: a field that
@@ -432,9 +480,13 @@ class ModelConfig:
 
     @property
     def shared_sparse(self) -> bool:
-        """The sigmoid-routed sparse MLP with shared experts and a chip's
-        share of the routed ones (dropless), not the mixtral path."""
-        return self.is_moe and self.router_scoring == "sigmoid"
+        """The DROPLESS sparse MLP (a chip's share of the routed experts of
+        width ``moe_intermediate_size``, shared experts beside them, scored
+        by a sigmoid or a softmax over all: ``router_scoring``), not the
+        mixtral path (experts of width ``intermediate_size``,
+        capacity-bounded). Told apart by the layout the tree already has,
+        not by the scoring."""
+        return self.is_moe and self.moe_intermediate_size > 0
 
     @property
     def latent(self) -> bool:
@@ -699,6 +751,12 @@ class EngineConfig:
     # forced-k=1 path left is a stop watch wider than the device's
     # MEGASTEP_WATCH_W slots (surfaced as megastep_forced_single).
     megastep_k: int = 8
+    # A block-diffusion model (ModelConfig.block_length > 0) runs WHOLE
+    # blocks a megastep, ``ModelConfig.denoising_steps + 1`` forwards each
+    # (the last writes the clean block's K/V): the engine holds
+    # ``megastep_k`` resolved to the largest multiple of that it holds, at
+    # least one block (core._resolve_block_megastep), so ``megastep`` is
+    # the forwards a dispatch fuses for every model.
 
     # Sequence-parallel long-context prefill: prompts at least this long
     # (with no cached prefix) run as ONE dense ring-attention pass over
@@ -1121,6 +1179,68 @@ def tiny_lfm2(vocab_size: int = 384) -> ModelConfig:
     )
 
 
+def sdar_30b_a3b_6l() -> ModelConfig:
+    """SDAR-30B-A3B-Chat (JetLM, model_type "sdar_moe") as stage 0 of an
+    eight-stage pipeline holds it: layers 0-5 of the 48 whole, all 128
+    softmax-routed experts of width 768 (8 a token, the chosen scores
+    normalised), the whole vocabulary, untied; 32 query heads on 4 KV heads
+    of width 128, QK-norm. Generation by diffusion over blocks of 4 places,
+    the surest first, every place over a confidence of 0.9 at once; served
+    with 2 denoising steps where the released usage has 4 (the threshold
+    of trained weights reveals more than a step's quota; the benchmark's
+    random weights never reach it). 8.72 GB in bf16."""
+    return ModelConfig(
+        name="sdar-30b-a3b-6l",
+        vocab_size=151936,
+        hidden_size=2048,
+        intermediate_size=6144,
+        num_layers=6,
+        num_heads=32,
+        num_kv_heads=4,
+        head_dim=128,
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-6,
+        qk_norm=True,
+        moe_intermediate_size=768,
+        num_experts=128,
+        num_experts_per_tok=8,
+        router_scoring="softmax",
+        block_length=4,
+        denoising_steps=2,
+        confidence_threshold=0.9,
+        mask_token_id=151669,
+    )
+
+
+def tiny_sdar(vocab_size: int = 384, **changes) -> ModelConfig:
+    """SDAR's shape at test size: GQA with QK-norm, 8 softmax-routed
+    experts, 2 a token, blocks of 4 places."""
+    fields = dict(
+        name="tiny-sdar",
+        vocab_size=vocab_size,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=2,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-6,
+        dtype="float32",
+        qk_norm=True,
+        moe_intermediate_size=32,
+        num_experts=8,
+        num_experts_per_tok=2,
+        router_scoring="softmax",
+        block_length=4,
+        denoising_steps=2,
+        confidence_threshold=0.9,
+        mask_token_id=vocab_size - 1,
+    )
+    fields.update(changes)
+    return ModelConfig(**fields)
+
+
 _LAGUNA_PERIOD = ("full_attention",) + 3 * ("sliding_attention",)
 _LAGUNA_ROPE = {
     "full_attention": {
@@ -1262,10 +1382,12 @@ PRESETS = {
     "a.x-k1-ep16": axk1_ep16,
     "lfm2-24b-a2b-10l": lfm2_24b_a2b_10l,
     "laguna-s-2.1-ep8-9l": laguna_s21_ep8_9l,
+    "sdar-30b-a3b-6l": sdar_30b_a3b_6l,
     "tiny": tiny_model,
     "tiny-moe": tiny_moe,
     "tiny-loop": tiny_loop,
     "tiny-axk1": tiny_axk1,
     "tiny-lfm2": tiny_lfm2,
     "tiny-laguna": tiny_laguna,
+    "tiny-sdar": tiny_sdar,
 }

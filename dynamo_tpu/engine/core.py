@@ -49,6 +49,7 @@ from dynamo_tpu.engine.prefill_cover import cheapest_cover
 from dynamo_tpu.runtime.engine import EngineOverloadedError
 from dynamo_tpu.runtime import wire
 from dynamo_tpu.engine.model import (
+    block_tokens,
     decode_tokens,
     embed_forward,
     expert_call_shape,
@@ -72,6 +73,7 @@ from dynamo_tpu.engine.sampler import (
     stop_flags,
     stop_flags_prefix,
     token_logprobs,
+    unmask_block,
 )
 from dynamo_tpu.llm.kv_router.protocols import ForwardPassMetrics, KvStats, WorkerStats
 from dynamo_tpu.spec import SpecConfig, SpecStats, propose_ngram, resolve_spec_config
@@ -157,14 +159,23 @@ class Sequence:
     # frame; admitted sequences always run to completion (expiring a
     # partially-streamed request would break the stream).
     deadline_epoch: float | None = None
+    # A block-diffusion model: the prompt's last ``prompt_len % B`` tokens
+    # are held back from the prefill wave and open the first block as known
+    # places (EngineCore._plan_blocks). 0 for every other model.
+    tail: int = 0
 
     @property
     def prompt_len(self) -> int:
         return len(self.prompt)
 
     @property
+    def wave_len(self) -> int:
+        """Prompt tokens a prefill wave runs: all but the held-back tail."""
+        return len(self.prompt) - self.tail
+
+    @property
     def prefill_done(self) -> bool:
-        return self.prefilled >= self.prompt_len
+        return self.prefilled >= self.wave_len
 
     @property
     def num_computed_tokens(self) -> int:
@@ -236,6 +247,29 @@ def _unpaired_where_not_carried(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh):
     return model_cfg
 
 
+def _resolve_block_megastep(model_cfg, engine_cfg):
+    """The engine's configuration with a block-diffusion model's
+    ``megastep_k`` resolved to the forwards a dispatch fuses: whole blocks
+    of ``model_cfg.denoising_steps + 1`` passes (as many as ``megastep_k``
+    holds, at least one). A page holds whole blocks, so that a block never
+    straddles two and a page's K/V is a function of the tokens up to its
+    end."""
+    B = model_cfg.block_length
+    if not B:
+        return engine_cfg
+    misfit = [n for n in (engine_cfg.block_size, *engine_cfg.prefill_buckets) if n % B]
+    if misfit:
+        raise ValueError(
+            f"block_size and prefill_buckets must hold whole blocks of {B}; {misfit} do not")
+    passes = model_cfg.denoising_steps + 1
+    return dataclasses.replace(
+        engine_cfg, megastep_k=max(1, engine_cfg.megastep_k // passes) * passes)
+
+
+_BLOCK_STEP = ("a step of this model is a block of places a lane, denoised over "
+               "several forwards; ")
+
+
 def _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh) -> None:
     """A model with latent attention or the sigmoid-routed sparse MLP
     runs on ONE chip's programs with a plain latent page; one with conv
@@ -251,16 +285,23 @@ def _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh) -> 
     device is ``[planes, *page]`` of ONE shape:
     ``EngineCore.kv_page_shape``)."""
     hybrid, windowed = model_cfg.hybrid, model_cfg.windowed
+    blocks = model_cfg.block_length > 0
     # one chip's programs: the layers no mesh rule, stage body or verify row knows
-    if not (model_cfg.latent or model_cfg.shared_sparse or hybrid or windowed):
+    if not (model_cfg.latent or model_cfg.shared_sparse or hybrid or windowed or blocks):
         return
     stays = _TWO_SHAPES if hybrid else _TWO_POOLS
     refused = {
+        "scheduling": blocks and engine_cfg.scheduling == "chunked" and (
+            _BLOCK_STEP + "a mixed step's decode rows are one token a lane, and its "
+            "chunks are not held to whole blocks"),
         "prefix_caching": windowed and engine_cfg.enable_prefix_caching is True
         and _TWO_POOLS,
-        "kv_dtype": engine_cfg.kv_quantized and (model_cfg.latent or hybrid or windowed) and (
+        "kv_dtype": engine_cfg.kv_quantized and (
+            model_cfg.latent or hybrid or windowed or blocks) and (
             "int8 pages keep a scale per slot and KV head; "
             + ("a latent page has no heads" if model_cfg.latent else
+               "a block in flight is quantised anew every pass, which was not compared"
+               if blocks else
                "the window pool's pages were not compared as int8" if windowed else
                "conv state pages and paired heads have no such scale")),
         "host_kv_blocks": (hybrid or windowed) and engine_cfg.host_kv_blocks > 0 and stays,
@@ -274,6 +315,7 @@ def _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh) -> 
         "ring_prefill": (sp_mesh is not None or engine_cfg.ring_prefill_threshold > 0)
         and "ring attention reads expanded K and V per head",
         "spec_decode": engine_cfg.spec_decode != "off" and (
+            _BLOCK_STEP + "there is no next token to draft" if blocks else
             "a rejected draft has already overwritten the convolution's rolling "
             "state past the cursor the lane goes on from" if hybrid else
             "a window block is given back by the cursor a verify row may fall "
@@ -592,6 +634,11 @@ def _megastep_body(
 
     The per-lane inputs arrive packed (:func:`pack_lanes`) beside the
     block tables and the feedback source."""
+    if cfg.block_length:
+        return _megastep_blocks(
+            params, cache, lanes, block_tables, feed, n_steps=n_steps,
+            need_mask=need_mask, all_greedy=all_greedy,
+            want_logprobs=want_logprobs, cfg=cfg, engine=engine, mesh=mesh)
     (tokens, positions, active, seeds, counters, temperature, top_k, top_p,
      watch, budgets, min_left) = unpack_lanes(lanes, feed)
 
@@ -625,6 +672,120 @@ def _megastep_body(
     if stats is not None:
         stats = jnp.sum(stats, axis=0)
     return _replicate_out(sampled, mesh), _replicate_out(lps, mesh), cache, stats
+
+
+def _megastep_blocks(
+    params, cache, lanes, block_tables, known,
+    *, n_steps, need_mask, all_greedy, want_logprobs, cfg, engine, mesh=None,
+):
+    """:func:`_megastep_body` of a block-diffusion model
+    (``cfg.block_length = B > 0``): a scanned iteration is ONE forward over
+    ``B`` rows a lane (model.block_tokens), and a dispatch runs ``n_steps /
+    (steps + 1)`` whole blocks, ``steps = cfg.denoising_steps`` denoising
+    passes and a clean pass each. A block starts as ``cfg.mask_token_id``
+    at its hidden places (``known`` ``[S, B]``: a prompt's tail opens a
+    lane's FIRST block of the dispatch as known places, -1 where hidden;
+    every later block is all hidden). A denoising pass samples each hidden
+    place from its OWN row with the request's sampler (key ``(seed,
+    position x steps + step)``: a place's draw is the same whatever
+    neighbours, preemption or blocks a dispatch it meets), takes the
+    sample's probability under the raw row as its confidence, and reveals
+    by ``sampler.unmask_block``. Every pass writes the block's K/V at the
+    block's own positions; the clean pass's stay, and the lane's cursor
+    moves ``B`` on. Hidden places are a MASK OF PLACES, never ``id ==
+    mask_token_id``: a prompt token with that id is a known token.
+
+    A lane goes dead for the dispatch's later blocks once its budget of
+    places to generate is spent or a revealed place holds a watched id (past
+    the min-tokens floor); the host's stop scan stays the authority over
+    what is kept. Returns ``(tokens [n_blocks, S, B], logprob arrays or
+    None, cache, expert counts, aux)`` with ``aux`` one flat int32 array: the
+    step that revealed each place ``[n_blocks, S, B]`` (-1: known), the lanes
+    alive at each block's start ``[n_blocks, S]``, the places revealed by
+    threshold / by quota ``[2]``."""
+    B, steps = cfg.block_length, cfg.denoising_steps
+    n_blocks = n_steps // (steps + 1)
+    S = lanes.shape[0]
+    f32 = lambda col: jax.lax.bitcast_convert_type(lanes[:, col], jnp.float32)  # noqa: E731
+    position, active = lanes[:, _L_POSITION], lanes[:, _L_ACTIVE] != 0
+    seeds = jnp.repeat(lanes[:, _L_SEED], B)
+    temperature, top_k, top_p = (
+        jnp.repeat(f32(_L_TEMPERATURE), B), jnp.repeat(lanes[:, _L_TOP_K], B),
+        jnp.repeat(f32(_L_TOP_P), B))
+    watch, min_left = lanes[:, _L_WATCH:], lanes[:, _L_MIN_LEFT]
+    place = jnp.arange(B, dtype=jnp.int32)
+    K = LOGPROBS_K
+
+    def one_pass(carry, p):
+        toks, hidden, step_of, lp, cache, pos, act, counts = carry
+        stats = _expert_stats_list(cfg)
+        logits, cache = block_tokens(
+            params, cache, jnp.where(hidden, cfg.mask_token_id, toks),
+            block_tables, pos, act, cfg, engine, mesh, expert_stats=stats)
+        with jax.named_scope("unmask"):
+            counters = ((pos[:, None] + place[None, :]) * steps + p).reshape(-1)
+            x0 = sample_seeded(
+                logits, seeds, counters, temperature, top_k, top_p,
+                need_mask=need_mask, all_greedy=all_greedy)
+            lse = jax.scipy.special.logsumexp(logits, axis=-1)
+            chosen = jnp.take_along_axis(logits, x0[:, None], axis=-1)[:, 0] - lse
+            reveal, by_threshold = unmask_block(
+                jnp.exp(chosen).reshape(S, B), hidden, p,
+                steps=steps, threshold=cfg.confidence_threshold)
+            # the clean pass (p == steps) finds no place hidden and reveals none
+            reveal = reveal & act[:, None] & (p < steps)
+            toks = jnp.where(reveal, x0.reshape(S, B), toks)
+            step_of = jnp.where(reveal, p, step_of)
+            hidden = hidden & ~reveal
+            n = jnp.sum(reveal, axis=1)
+            counts = counts + jnp.stack([
+                jnp.sum(jnp.where(by_threshold, n, 0)),
+                jnp.sum(jnp.where(by_threshold, 0, n))]).astype(jnp.int32)
+            if want_logprobs:
+                top_lps, top_ids = jax.lax.top_k(logits, K)
+                new = (chosen.reshape(S, B), top_ids.astype(jnp.int32).reshape(S, B, K),
+                       (top_lps - lse[:, None]).reshape(S, B, K))
+                lp = tuple(jnp.where(reveal if a.ndim == 2 else reveal[..., None], a, old)
+                           for a, old in zip(new, lp))
+        return (toks, hidden, step_of, lp, cache, pos, act, counts), _expert_stats_sum(stats)
+
+    def one_block(carry, b):
+        cache, pos, alive, budget, floor, counts = carry
+        act = active & alive
+        # the dispatch's first block may open with known places (a prompt's tail)
+        opens = (b == 0) & (known >= 0)
+        toks = jnp.where(opens, known, 0)
+        hidden = ~opens
+        lp = (jnp.zeros((S, B), jnp.float32), jnp.zeros((S, B, K), jnp.int32),
+              jnp.zeros((S, B, K), jnp.float32)) if want_logprobs else None
+        (toks, _, step_of, lp, cache, _, _, counts), stats = jax.lax.scan(
+            one_pass,
+            (toks, hidden, jnp.full((S, B), -1, jnp.int32), lp, cache, pos, act, counts),
+            jnp.arange(steps + 1))
+        with jax.named_scope("unmask"):
+            # the places this block generated, in order: 1, 2, ... at its hidden places
+            ordinal = jnp.cumsum(hidden, axis=1) * hidden
+            hit = (toks[:, :, None] == watch[:, None, :]).any(axis=2) & hidden & (
+                ordinal >= floor[:, None])
+            made = jnp.sum(hidden, axis=1)
+            budget, floor = budget - made, floor - made
+            alive = alive & ~hit.any(axis=1) & (budget > 0)
+            pos = pos + B * act.astype(jnp.int32)
+        if stats is not None:
+            stats = jnp.sum(stats, axis=0)
+        return (cache, pos, alive, budget, floor, counts), (toks, step_of, lp, act, stats)
+
+    (cache, _, _, _, _, counts), (tokens, step_of, lps, ran, stats) = jax.lax.scan(
+        one_block,
+        (cache, position, jnp.ones_like(active), lanes[:, _L_BUDGET], min_left,
+         jnp.zeros(2, jnp.int32)),
+        jnp.arange(n_blocks))
+    if stats is not None:
+        stats = jnp.sum(stats, axis=0)
+    aux = jnp.concatenate([
+        step_of.reshape(-1), ran.astype(jnp.int32).reshape(-1), counts])
+    return (_replicate_out(tokens, mesh), _replicate_out(lps, mesh), cache, stats,
+            _replicate_out(aux, mesh))
 
 
 def _megastep_fused_body(
@@ -1127,6 +1288,7 @@ class EngineCore:
         model_cfg = _unpaired_where_not_carried(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh)
         _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh)
         engine_cfg = _resolve_window_pool(model_cfg, engine_cfg)
+        engine_cfg = _resolve_block_megastep(model_cfg, engine_cfg)
         for b in engine_cfg.prefill_buckets:
             if b % bs:
                 raise ValueError(f"prefill bucket {b} not a multiple of block_size {bs}")
@@ -1707,6 +1869,17 @@ class EngineCore:
             # Window-pool blocks given back while their sequence went on
             # (_release_window_behind); 0 for a model without such a pool.
             "window_blocks_released": 0,
+            # A block-diffusion model (_plan_blocks): forwards counted once
+            # a live lane a pass, by the pass's kind; blocks whose clean
+            # pass moved a lane's cursor on; places revealed on the device,
+            # by the rule that revealed them; places generated and not kept
+            # (after a cut, or in a block run past a stop only the host saw).
+            "denoise_forwards": 0,
+            "commit_forwards": 0,
+            "blocks_committed": 0,
+            "places_revealed_threshold": 0,
+            "places_revealed_quota": 0,
+            "block_places_discarded": 0,
         }
         # Crash/stall flight recorder (ISSUE 13): one record per step
         # with outputs — step shape, lane cursors, cumulative dispatch
@@ -1870,6 +2043,9 @@ class EngineCore:
         )
         if not seq.prompt:
             raise ValueError("empty prompt")
+        if self.cfg.block_length:
+            self._refuse_block_request(pre)
+            seq.tail = seq.prompt_len % self.cfg.block_length
         limit = self.engine.max_model_len
         if seq.prompt_len >= limit:
             raise ValueError(
@@ -1939,6 +2115,25 @@ class EngineCore:
         self._enqueue(seq)
         return seq
 
+    def _refuse_block_request(self, pre: PreprocessedRequest) -> None:
+        """What a request may not ask of a block-diffusion model, by name:
+        the places of a block are revealed surest first, not left to right,
+        so a penalty over "the tokens so far" has no order to follow, and
+        ``n`` samples of one prompt are ``n`` requests."""
+        sampling = pre.sampling
+        asked = {
+            "frequency_penalty": sampling.frequency_penalty != 0.0,
+            "presence_penalty": sampling.presence_penalty != 0.0,
+            "repetition_penalty": sampling.repetition_penalty != 1.0,
+            "n": sampling.n != 1,
+            "spec_decode": (pre.spec_decode or {}).get("method", "off") != "off",
+        }
+        for option, was in asked.items():
+            if was:
+                raise ValueError(
+                    f"{option} is not carried by {self.cfg.name!r}: " + _BLOCK_STEP
+                    + "its places are revealed surest first, not left to right")
+
     def _enqueue(self, seq: Sequence) -> None:
         """Hand a validated sequence to the scheduler (overridden by the
         multihost LeaderCore to stage intake until it is journaled)."""
@@ -1973,7 +2168,7 @@ class EngineCore:
         return self._inflight.adv.get(seq.request_id, (0, 0, 0))
 
     def _eff_prefill_done(self, seq: Sequence) -> bool:
-        return seq.prefilled + self._adv3(seq)[0] >= seq.prompt_len
+        return seq.prefilled + self._adv3(seq)[0] >= seq.wave_len
 
     def _eff_processed(self, seq: Sequence) -> int:
         return seq.processed + self._adv3(seq)[1]
@@ -2079,12 +2274,12 @@ class EngineCore:
             lanes=lanes, width=width, k=k, ut_steps=ut,
             real=real, padded=padded, pipelined=self._inflight is not None,
             attn=traced_impl(
-                ("latent-" if self.cfg.latent else "")
+                ("latent-" if self.cfg.latent else "block-" if self.cfg.block_length else "")
                 + ("ragged" if kind == "prefill" else "decode")
             ),
             attention=self.cfg.attention,
             **self._window_traced(kind),
-            **self._experts_traced(kind, padded),
+            **self._experts_traced(kind, padded, width),
             **attrs,
         )
 
@@ -2103,14 +2298,17 @@ class EngineCore:
                 "window-ragged" if kind == "prefill" else "window-decode"),
         }
 
-    def _experts_traced(self, kind: str, tokens: int) -> dict[str, str]:
+    def _experts_traced(self, kind: str, tokens: int, width: int = 1) -> dict[str, str]:
         """``{"experts": path}`` of a sparse model's dispatch, for its
         annotation: what this process's sparse layers of that shape were
         traced with (a prefill dispatch of ``tokens`` rows: a wave's where
-        it is wide enough for one; every other dispatch a step's)."""
+        it is wide enough for one; every other dispatch a step's, but a
+        block-diffusion model's, whose ``width`` lanes bring a block of rows
+        each and may be as wide as a wave)."""
         if not self.cfg.shared_sparse:
             return {}
-        rows = tokens if kind == "prefill" else 1
+        rows = tokens if kind == "prefill" else (
+            width * self.cfg.block_length if self.cfg.block_length else 1)
         return {"experts": grouped_matmul.traced_impl(expert_call_shape(rows))}
 
     def _bucket_for(self, n: int) -> int:
@@ -2261,7 +2459,9 @@ class EngineCore:
             seq.prompt_hashes = compute_seq_hashes(seq.prompt, bs)
             # Cap the reusable prefix so at least one token is prefilled
             # (the engine needs last-token logits to start decoding).
-            cap = (P - 1) // bs
+            # A block-diffusion model needs no such logits: its wave ends
+            # where the prompt's whole blocks do, and may be all cached.
+            cap = seq.wave_len // bs if self.cfg.block_length else (P - 1) // bs
             cached_ids = self.allocator.acquire_cached(seq.prompt_hashes[:cap])
             ncached = len(cached_ids)
             if self.host_pool is not None:
@@ -3127,8 +3327,8 @@ class EngineCore:
             if len(waiting) == S:
                 break
             p0 = seq.prefilled + self._adv3(seq)[0]
-            if seq.prompt_len > p0:
-                waiting.append((seq, p0, seq.prompt_len - p0))
+            if seq.wave_len > p0:
+                waiting.append((seq, p0, seq.wave_len - p0))
         if not waiting:
             return None
         largest = self.engine.prefill_buckets[-1]
@@ -3163,8 +3363,9 @@ class EngineCore:
         adv: dict[str, tuple[int, int, int]] = {}
         feed_index: dict[str, int] = {}
         feed_series: dict[str, tuple[int, int, int]] = {}
+        blocks = self.cfg.block_length > 0   # its wave samples nothing that is kept
         for i, (seq, p0, chunk) in enumerate(chosen):
-            done = p0 + chunk >= seq.prompt_len
+            done = p0 + chunk >= seq.wave_len and not blocks
             adv[seq.request_id] = (chunk, chunk, 1 if done else 0)
             if done:
                 feed_index[seq.request_id] = i
@@ -3182,7 +3383,7 @@ class EngineCore:
                 tok, lp = self._advance_prefill_chunk(
                     seq, chunk, toks, lps, i, t_disp, now
                 )
-                if tok is None:
+                if tok is None or blocks:
                     continue  # prompt not finished this wave
                 seq.pending = tok
                 seq.generated += 1
@@ -3361,7 +3562,10 @@ class EngineCore:
         base = self._eff_processed(seq)
         if not self._hold_window(seq, base, n_tokens):
             return False
-        need = (base + n_tokens - 1) // bs + 1 - len(seq.block_ids)
+        # never more than a table holds (a block-diffusion lane's last
+        # dispatch may plan blocks that its budget ends before)
+        need = min((base + n_tokens - 1) // bs + 1,
+                   self.engine.max_blocks_per_seq) - len(seq.block_ids)
         grabbed: list[int] = []
         for _ in range(max(0, need)):
             try:
@@ -3388,7 +3592,12 @@ class EngineCore:
             new_prompt = seq.hashed.all_tokens()
             if seq.pending is not None:
                 new_prompt.append(seq.pending)
-            seq.prompt = new_prompt
+            # a block-diffusion lane that has not run its first block yet
+            # keeps the tail that block opens with
+            if len(new_prompt) >= seq.prompt_len:
+                seq.prompt = new_prompt
+            if self.cfg.block_length:
+                seq.tail = seq.prompt_len % self.cfg.block_length
         seq.pending = None
         # The rebuilt prompt absorbs every emitted token; keeping
         # out_tokens too would double-count them in the drafter's lookup
@@ -3444,6 +3653,7 @@ class EngineCore:
     def _dispatch_megastep(
         self, seqs: list[Sequence], n_steps: int,
         feed_lanes: list[int | None] | None = None,
+        opens: list[list[int]] | None = None,
     ) -> _PendingFetch:
         """Assemble and enqueue one decode megastep: ``n_steps`` fused
         decode+sample iterations over these lanes in ONE device dispatch
@@ -3459,10 +3669,25 @@ class EngineCore:
         packed array (:func:`pack_lanes`) beside the block tables: two
         transfers a megastep, and the gather of the fed tokens inside the
         program. Returns a pending fetch whose ``land()`` yields
-        ([n_steps, B] tokens, lp arrays or None)."""
+        ([n_steps, B] tokens, lp arrays or None).
+
+        A block-diffusion model's megastep (:func:`_megastep_blocks`) comes
+        with ``opens`` (aligned with seqs): the tokens each lane's first
+        block opens with. A lane's position is then its next block's first
+        place and its budget the places it may still generate, and in the
+        feedback source's place goes ``[B, block_length]`` of ``opens``, -1
+        where a place is hidden: a block starts from mask tokens, so
+        nothing of the step in flight is fed to it, only its K/V, which the
+        device orders. ``land()`` then yields ``[n_blocks, B, block_length]``
+        tokens and ``land_aux()`` the steps, live lanes and reveal counts,
+        flat."""
         self.clock.mark("assemble")
         B = self._decode_width(len(seqs))
         seqs = seqs[:B]
+        blk = self.cfg.block_length if opens is not None else 0
+        # what a lane can yield in this dispatch: a token an iteration, or
+        # the places of its whole blocks
+        yields = n_steps // (self.cfg.denoising_steps + 1) * blk if blk else n_steps
         # Occupancy: live lanes against the padded width (the commit side
         # counts the lane-iterations issued and those that gave a client
         # a token, both when the chain lands, so a window sees whole pairs).
@@ -3482,8 +3707,8 @@ class EngineCore:
         seeds = np.zeros(B, np.int32)
         counters = np.zeros(B, np.int32)
         watch = np.full((B, W), -1, np.int32)
-        # Padded lanes never hit their budget (gen <= n_steps < n_steps+1).
-        budgets = np.full(B, n_steps + 1, np.int32)
+        # Padded lanes never hit their budget (gen <= yields < yields+1).
+        budgets = np.full(B, yields + 1, np.int32)
         min_left = np.zeros(B, np.int32)
         feed_idx = None
         if feed_lanes is not None and any(f is not None for f in feed_lanes):
@@ -3492,7 +3717,7 @@ class EngineCore:
             self.clock.poll()
             if feed_idx is not None and i < len(feed_lanes) and feed_lanes[i] is not None:
                 feed_idx[i] = feed_lanes[i]
-            else:
+            elif not blk:
                 tokens[i] = seq.pending
             positions[i] = self._eff_processed(seq)
             self._table_row(tables[i], seq)
@@ -3503,7 +3728,13 @@ class EngineCore:
             seeds[i] = seq.seed
             counters[i] = self._eff_generated(seq)
             self._arm_stop_inputs(seq, i, watch, budgets, min_left)
-        finishing = int(np.count_nonzero(budgets <= n_steps))
+        made = np.full(B, yields, np.int32)
+        if blk:
+            known = np.full((B, blk), -1, np.int32)
+            for i, k in enumerate(opens[:B]):
+                known[i, : len(k)] = k
+                made[i] -= len(k)
+        finishing = int(np.count_nonzero(budgets <= made))
         need_mask = any(
             s.sampling.top_k > 0 or s.sampling.top_p < 1.0 for s in seqs
         )
@@ -3515,21 +3746,25 @@ class EngineCore:
         )
         self.clock.mark("h2d")
         # Two transfers, and the step in flight's output where a lane
-        # reads its token from it (zeros of that shape where none does).
-        args = (
-            self._put_batch(lanes),
-            self._put_batch(tables),
-            self._no_feed if feed_idx is None else self._feed_source(),
-        )
+        # reads its token from it (zeros of that shape where none does);
+        # a block model's third is its known places.
+        if blk:
+            third = self._put_batch(known)
+        else:
+            third = self._no_feed if feed_idx is None else self._feed_source()
+        args = (self._put_batch(lanes), self._put_batch(tables), third)
+        steps = self.cfg.denoising_steps
         self._mark_dispatch(
             "megastep" if n_steps > 1 else "decode",
-            len(seqs), B, n_steps, len(seqs) * n_steps, B * n_steps,
+            len(seqs), B, n_steps, len(seqs) * yields, B * yields,
+            **({"block": blk, "steps": steps, "passes": steps + 1} if blk else {}),
         )
         # On a pp engine the FUSED pp megastep: the whole wavefront chain
         # — stage hops, sampling, stop flags — is one dispatch, armed
         # with the same per-lane stop inputs as the single-chip body.
         program = self._decode_pp if self.pp_mesh is not None else self._decode
-        out, lps, self.cache, *stats = program(
+        # a block model's program returns its side channel last
+        out, lps, self.cache, *rest = program(
             self.params,
             self.cache,
             *args,
@@ -3547,8 +3782,8 @@ class EngineCore:
             "megastep_dispatches" if n_steps > 1 else "single_step_dispatches"
         ] += 1
         return _PendingFetch(  # [n_steps, B] on land()
-            self, out, lps, expert_stats=("decode", stats[0] if stats else None),
-            finishing=finishing,
+            self, out, lps, expert_stats=("decode", rest[0] if rest else None),
+            aux=rest[1] if blk else None, finishing=finishing,
         )
 
     # -- the iteration -----------------------------------------------------
@@ -3776,6 +4011,8 @@ class EngineCore:
         dispatched device step — and so a megastep can never exhaust
         blocks MID-dispatch: every lane's k tokens of block headroom are
         reserved here, at plan time, by construction."""
+        if self.cfg.block_length:
+            return self._plan_blocks()
         decoding = self._decode_candidates()
         if not decoding:
             return None
@@ -3936,6 +4173,133 @@ class EngineCore:
             feed_tokens=pend.toks, feed_index=feed_index,
             feed_series=feed_series, finishing=pend.finishing,
         )
+
+    # -- generation by blocks (ModelConfig.block_length > 0) ----------------
+
+    def _block_candidates(self) -> list[Sequence]:
+        """Lanes whose next block may run, under the optimistic overlay:
+        the wave has run the prompt's whole blocks, and the step in flight
+        does not spend what is left of the generation budget."""
+        out: list[Sequence] = []
+        for s in self.running:
+            self.clock.poll()
+            if not self._eff_prefill_done(s):
+                continue
+            if (s.stop.max_tokens is not None
+                    and self._eff_generated(s) >= s.stop.max_tokens):
+                continue  # finishes (length) in flight
+            out.append(s)
+        return out
+
+    def _plan_blocks(self) -> _PlannedStep | None:
+        """Plan one megastep of a block-diffusion model: ``megastep / (steps +
+        1)`` whole blocks a lane in ONE dispatch (:func:`_megastep_blocks`).
+        Every lane's headroom for those blocks is grown before the dispatch,
+        as :meth:`_plan_decode` grows a chain's. A lane's cursor
+        (``num_computed_tokens``) moves ``B`` on at a block's clean pass and
+        by nothing before it: the passes of a block in flight overwrite
+        its K/V in place, past the cursor, where no sequence that goes on
+        reads (PR 35's invariant). The commit side is the authority on what
+        is kept: it scans each block's generated places in order for EOS,
+        stop ids and the budget, emits what is kept as one chunk, ends the
+        request at a cut and counts the places after it as discarded."""
+        ready = self._block_candidates()
+        if not ready:
+            return None
+        B, steps = self.cfg.block_length, self.cfg.denoising_steps
+        n_steps = self.engine.megastep
+        n_blocks = n_steps // (steps + 1)
+        ready = self._grow_or_preempt(ready, n_blocks * B)
+        if not ready:
+            return None
+        ready = ready[: self._decode_width(len(ready))]
+        t_decode = time.time()
+        # the places a lane's first block of this dispatch opens with: a
+        # prompt's tail, once, before the lane's first block
+        known = [s.prompt[s.wave_len:] if self._eff_processed(s) < s.prompt_len else []
+                 for s in ready]
+        now = time.time()
+        for s in ready:
+            self._mark_first_sched(s, now)   # a prompt of no whole block rode no wave
+        pend = self._dispatch_megastep(ready, n_steps, opens=known)
+        adv = {s.request_id: (0, n_blocks * B, n_blocks * B - len(k))
+               for s, k in zip(ready, known)}
+
+        # dynalint: holds-lock(_step_lock) — commits run inside the step
+        def commit() -> list[tuple[Sequence, LLMEngineOutput]]:
+            outputs: list[tuple[Sequence, LLMEngineOutput]] = []
+            toks, lps = pend.land()                 # [n_blocks, S, B]
+            aux = pend.land_aux()
+            S = toks.shape[1]
+            step_of = aux[: n_blocks * S * B].reshape(n_blocks, S, B)
+            ran = aux[n_blocks * S * B: -2].reshape(n_blocks, S)
+            st = self.exec_stats
+            st["places_revealed_threshold"] += int(aux[-2])
+            st["places_revealed_quota"] += int(aux[-1])
+            lane_blocks = int(ran[:, : len(ready)].sum())
+            st["denoise_forwards"] += lane_blocks * steps
+            st["commit_forwards"] += lane_blocks
+            live = {id(s) for s in self.running}
+            emitted_total = kept_blocks = 0
+            made = 0
+            for i, seq in enumerate(ready):
+                t = len(known[i])
+                made += int(ran[:, i].sum()) * B - (t if ran[0, i] else 0)
+                if seq.finish is not None or seq.cancelled or id(seq) not in live:
+                    continue  # late finish/preempt: the blocks are discarded
+                emitted: list[int] = []
+                entries: list[dict] | None = (
+                    [] if lps is not None and seq.logprobs is not None else None)
+                finish = None
+                for b in range(n_blocks):
+                    if not ran[b, i]:
+                        break   # the device saw the lane end; so will the scan
+                    first = t if b == 0 else 0
+                    k, finish = self._scan_stop(seq, toks[b, i, first:])
+                    block = [int(x) for x in toks[b, i, : first + k]]
+                    self._commit_completed(seq, seq.hashed.extend(block))
+                    seq.processed += first + k
+                    if entries is not None:
+                        at = seq.processed - first - k
+                        entries += [
+                            dict(_lp_entry(block[j], lps[0][b, i, j], lps[1][b, i, j],
+                                           lps[2][b, i, j], seq.logprobs),
+                                 block=(at + j) // B, step=int(step_of[b, i, j]), place=j)
+                            for j in range(first, first + k)]
+                    seq.generated += k
+                    emitted += block[first:]
+                    kept_blocks += 1
+                    if finish is not None:
+                        if entries and first + k < B:
+                            # the places after the cut: revealed, sent to no one,
+                            # and part of what a later step of the block saw
+                            entries[-1]["cut"] = [
+                                [j, int(step_of[b, i, j]), int(toks[b, i, j])]
+                                for j in range(first + k, B)]
+                        break
+                outputs.append((seq, self._emit_chunk(seq, emitted, entries, finish)))
+                emitted_total += len(emitted)
+                if finish is not None:
+                    seq.finish = finish
+                    self._finish(seq)
+            st["blocks_committed"] += kept_blocks
+            st["block_places_discarded"] += made - emitted_total
+            st["megastep_issued_lane_iters"] += len(ready) * n_steps
+            st["megastep_useful_lane_iters"] += kept_blocks * (steps + 1)
+            self._tracer.record(
+                "engine_megastep", t_decode, time.time(),
+                attrs={
+                    "seqs": len(ready), "inner_steps": n_steps,
+                    "tokens": emitted_total, "pp_stages": self._pp,
+                    "blocks": n_blocks, "block": B, "steps": steps,
+                    "fused_shapes": {"decode": len(ready), "chunk": 0, "verify": 0},
+                },
+                stat=True,
+            )
+            return outputs
+
+        return _PlannedStep(
+            core=self, commit_fn=commit, adv=adv, finishing=pend.finishing)
 
     # -- speculative decoding (draft + batched ragged verify) ---------------
 
@@ -5522,6 +5886,8 @@ class EngineCore:
         st["prefill_waves"] = dict(self.prefill_waves)
         st["prefill_bucket_ms"] = dict(self.prefill_bucket_ms)
         st["megastep_k"] = self.engine.megastep
+        st["block_length"] = self.cfg.block_length
+        st["denoising_steps"] = self.cfg.denoising_steps
         toks = self.exec_stats["committed_tokens"]
         st["dispatches_per_token"] = (
             self.exec_stats["dispatches"] / toks if toks else 0.0

@@ -149,6 +149,46 @@ def _windowed_config(hf: dict, experts_held) -> ModelConfig:
     )
 
 
+# Published model types that generate by diffusion over blocks on a
+# Qwen3-MoE body (SDAR): QK-normed GQA, softmax-routed whole experts.
+BLOCK_SPARSE_TYPES = ("sdar_moe",)
+
+
+def _block_sparse_config(hf: dict) -> ModelConfig:
+    """The published keys as ``chipbench/architectures/sdar_moe.py`` reads
+    them. ``config.json`` gives neither block length nor schedule: the
+    released usage's are taken unless the file brings keys of those names
+    (the benchmark's configuration lists them under ``assumed``)."""
+    if hf.get("mlp_only_layers") or hf.get("decoder_sparse_step", 1) != 1 \
+            or hf.get("use_sliding_window") or hf.get("rope_scaling"):
+        raise NotImplementedError(
+            "sdar_moe with dense layers among the sparse ones, a sliding window or "
+            "scaled rope is not implemented")
+    return ModelConfig(
+        name=hf["model_type"],
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf.get("head_dim", hf["hidden_size"] // hf["num_attention_heads"]),
+        rope_theta=float(hf.get("rope_theta", 1000000.0)),
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        tie_embeddings=hf.get("tie_word_embeddings", False),
+        qk_norm=True,
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_experts=hf["num_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        router_scoring="softmax",
+        norm_topk_prob=hf.get("norm_topk_prob", True),
+        block_length=hf.get("block_length", 4),
+        denoising_steps=hf.get("denoising_steps", 4),
+        confidence_threshold=hf.get("confidence_threshold", 0.9),
+        mask_token_id=hf.get("mask_token_id", 151669),
+    )
+
+
 def config_from_hf(path: str | Path, experts_held=None) -> ModelConfig:
     """``experts_held`` ``(rank, of)``: the share of a sparse model's routed
     experts to load (a model without a stated share refuses it)."""
@@ -158,6 +198,8 @@ def config_from_hf(path: str | Path, experts_held=None) -> ModelConfig:
         return _latent_sparse_config(hf, experts_held)
     if hf.get("model_type") in WINDOWED_TYPES:
         return _windowed_config(hf, experts_held)
+    if hf.get("model_type") in BLOCK_SPARSE_TYPES and experts_held is None:
+        return _block_sparse_config(hf)
     if hf.get("model_type") in HYBRID_CONV_TYPES and experts_held is None:
         return _hybrid_conv_config(hf)
     if experts_held is not None:
@@ -462,6 +504,48 @@ def _load_windowed(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]:
     return params
 
 
+def _load_block_sparse(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]:
+    """An sdar_moe checkpoint into the tree ``model.init_params`` builds for
+    it (``layers``: norms, ``wqkv``, ``wo``, the head norms; ``moe``: router
+    and every expert), under the base family's names: ``self_attn.{q,k,v,
+    o}_proj``, ``q_norm`` / ``k_norm``, ``mlp.gate``, ``mlp.experts.E.{gate,
+    up,down}_proj``."""
+    L, E = cfg.num_layers, cfg.num_experts
+    np_dt = np.dtype(dt)
+
+    def w(l: int, name: str) -> np.ndarray:
+        return np.asarray(sd[f"model.layers.{l}.{name}.weight"], np.float32)
+
+    def stack(name: str, transpose: bool = True) -> np.ndarray:
+        return np.asarray(np.stack(
+            [w(l, name).T if transpose else w(l, name) for l in range(L)]), np_dt)
+
+    def gate_up(l: int, e: int) -> np.ndarray:
+        return np.concatenate([w(l, f"mlp.experts.{e}.gate_proj").T,
+                               w(l, f"mlp.experts.{e}.up_proj").T], axis=1)
+
+    return {
+        "layers": {
+            "attn_norm": stack("input_layernorm", False),
+            "mlp_norm": stack("post_attention_layernorm", False),
+            "wqkv": np.asarray(_fuse_np(
+                [stack(f"self_attn.{n}") for n in ("q_proj", "k_proj", "v_proj")], tp), np_dt),
+            "wo": stack("self_attn.o_proj"),
+            "q_layernorm": stack("self_attn.q_norm", False),
+            "k_layernorm": stack("self_attn.k_norm", False),
+        },
+        "moe": {
+            "w_router": stack("mlp.gate"),
+            # one array a sparse layer (model._init_shared_sparse_mlp)
+            "w_gu": tuple(np.asarray(np.stack([gate_up(l, e) for e in range(E)]), np_dt)
+                          for l in range(L)),
+            "w_down": tuple(np.asarray(np.stack(
+                [w(l, f"mlp.experts.{e}.down_proj").T for e in range(E)]), np_dt)
+                for l in range(L)),
+        },
+    }
+
+
 def load_hf_llama(
     path: str | Path, dtype=None, tp: int = 1, quant: str | None = None,
     experts_held: tuple[int, int] | None = None,
@@ -489,7 +573,7 @@ def load_hf_llama(
     def t(key: str) -> np.ndarray:
         return np.asarray(sd[key], np.float32)
 
-    if cfg.latent or cfg.layer_groups:
+    if cfg.latent or cfg.layer_groups or cfg.block_length:
         if quant is not None or tp != 1:
             raise NotImplementedError(
                 f"quant={quant!r} / tp={tp} for {cfg.name!r}: experts, latent "
@@ -498,7 +582,8 @@ def load_hf_llama(
             )
         np_dt = np.dtype(dt)
         load = (_load_hybrid_conv if cfg.hybrid else
-                _load_windowed if cfg.windowed else _load_latent_sparse)
+                _load_windowed if cfg.windowed else
+                _load_block_sparse if cfg.block_length else _load_latent_sparse)
         params = load(cfg, sd, dt, tp)
         final_norm = "model.embedding_norm.weight" if cfg.hybrid else "model.norm.weight"
         params.update({

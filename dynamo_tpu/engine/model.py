@@ -47,6 +47,12 @@ v5e, round 2):
   the others back. Query heads (``cfg.heads_of``), rope
   (:func:`kind_rope_tables`) and the table are the layer kind's; the
   layer body is the one :func:`dense_layer`.
+- **A block of places a lane** (``cfg.block_length``, SDAR): a query sees
+  every earlier block and, both ways, its own. The rows of a batch are
+  whole blocks (:func:`block_rows`), a block's rows fold into the GQA
+  group of ONE decode-shaped attention call (``ops/ragged_attention.py``,
+  ``block_attention``), in a step's pass (:func:`block_tokens`) and in a
+  prefill wave alike; pages, allocator and prefix index see nothing new.
 """
 
 from __future__ import annotations
@@ -68,6 +74,7 @@ from dynamo_tpu.ops.latent_attention import (
     write_latent_rows,
 )
 from dynamo_tpu.ops.ragged_attention import (
+    block_attention,
     paired_heads_attention,
     ragged_paged_attention,
     sharded_ragged_attention,
@@ -268,7 +275,8 @@ def init_params(rng: jax.Array, cfg: ModelConfig, tp: int = 1) -> Params:
         if cfg.qk_norm:
             for i, name in enumerate(("q_layernorm", "k_layernorm")):
                 attn[name] = _varied_ones(
-                    jax.random.fold_in(rng, 80 + i), (La, cfg.head_dim), dt)
+                    jax.random.fold_in(rng, 80 + i), (La, cfg.head_dim), dt,
+                    _qk_norm_gain(cfg))
     if cfg.hybrid:
         extra["conv"] = _init_conv_operators(rng, cfg, dense)
     if cfg.attn_qkv_bias:
@@ -306,11 +314,48 @@ def init_params(rng: jax.Array, cfg: ModelConfig, tp: int = 1) -> Params:
     return params
 
 
-def _varied_ones(key, shape, dt):
-    """A norm's weight drawn 10% around 1, so that a norm applied with
-    the wrong weight, in the wrong place or not at all changes the
-    logits (as :func:`_init_loop_extras` draws its own)."""
-    return (1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)).astype(dt)
+def _varied_ones(key, shape, dt, mean: float = 1.0):
+    """A norm's weight drawn 10% around ``mean`` (1), so that a norm
+    applied with the wrong weight, in the wrong place or not at all
+    changes the logits (as :func:`_init_loop_extras` draws its own)."""
+    return (mean * (1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32))).astype(dt)
+
+
+def _qk_norm_gain(cfg: ModelConfig) -> float:
+    """What the head norms of ``q`` and ``k`` are drawn around: 1, but
+    ``_QK_NORM_GAIN_BLOCKS`` for a model that generates by blocks
+    (``block_length > 0``).
+
+    With both at 1 a random model's scores are ~N(0, 1), a query's
+    softmax over a context of 1,400 keys is all but flat (~500 keys
+    weigh in), and every place reads the MEAN of its context. A block's
+    hidden places all embed the mask token, so attention is the only
+    thing that tells them, or two lanes, apart: reading a mean, the
+    places of a block come out alike, a token that repeats adds up in
+    that mean where distinct ones cancel, and greedy generation falls
+    into one or two tokens a lane within a hundred. Three things then
+    measure nothing a trained model shows: a pass's rows route alike
+    (35-41 of 128 experts touched), the confidences of a block's places
+    lie within the comparison's tolerance of each other, so that no
+    unmasking order can be told from another, and the lower precision
+    (fp8) reads under that tolerance. Drawn around g the scores are
+    ~N(0, g^4), a query reads a few keys (as a trained head does), and
+    its place and its lane show. The upper end is bfloat16 itself: the
+    sharper the softmax, the further the cache's rounding of k moves it.
+
+    The value is the one that leaves the comparison's tolerance (0.15)
+    room on both sides, between the sound readings and fp8's (the v5e,
+    PERF.md section 6, PR 42; max |diff| over the seeds of each sweep,
+    sound / fp8, and the seeds on which leaving the order out was
+    caught): 1: 0.011-0.022 / 0.10-0.165, 0 of 9; 1.25: 0.015-0.017 /
+    0.11-0.20, 1 of 3; 1.5: 0.031-0.052 / 0.23-0.44, 10 of 11; **1.6:
+    0.034-0.093 / 0.42-0.63, 10 of 10**; 1.75: 0.085-0.107 / 0.78-1.00,
+    2 of 3 (the sound error itself is then inside the near-tie rule's
+    reach)."""
+    return _QK_NORM_GAIN_BLOCKS if cfg.block_length > 0 else 1.0
+
+
+_QK_NORM_GAIN_BLOCKS = 1.6
 
 
 # The parameter group of each cache kind's operators (``cfg.layer_groups``).
@@ -823,6 +868,31 @@ def route_sigmoid(xf: jax.Array, w_router: jax.Array, cfg: ModelConfig,
     return weights, chosen
 
 
+def route_softmax(xf: jax.Array, w_router: jax.Array, cfg: ModelConfig,
+                  bias: jax.Array | None = None):
+    """(weights ``[N, E]`` float32, zero where not chosen; chosen ``[N,
+    E]`` bool), as :func:`route_sigmoid` returns them. ``s = softmax(x
+    Wr)`` in float32 over ALL ``E`` experts; the ``k`` highest chosen;
+    weights ``s_e / sum(s_chosen)`` (``norm_topk_prob``). No groups, no
+    bias on the choice (ModelConfig refuses them with this scoring)."""
+    N, k = xf.shape[0], cfg.num_experts_per_tok
+    logits = jnp.dot(
+        xf.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    sc = jax.nn.softmax(logits, axis=-1)
+    rows = jnp.arange(N)[:, None]
+    w, idx = jax.lax.top_k(sc, k)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + cfg.router_norm_eps)
+    weights = jnp.zeros(sc.shape, jnp.float32).at[rows, idx].set(w)
+    chosen = jnp.zeros(sc.shape, bool).at[rows, idx].set(True)
+    return weights, chosen
+
+
+# The dropless layer's router, by ``ModelConfig.router_scoring``.
+_ROUTERS = {"sigmoid": route_sigmoid, "softmax": route_softmax}
+
+
 def _swiglu(x, w_gu, w_down):
     """``(silu(x Wg) * (x Wu)) Wd`` with ``w_gu = [Wg | Wu]``; float32 out."""
     gu = jnp.dot(x, w_gu, preferred_element_type=jnp.float32)
@@ -978,7 +1048,7 @@ def _shared_sparse_mlp(x, lp, cfg: ModelConfig, row_valid=None,
     N = xf.shape[0]
     lo, hi = cfg.experts_held_range
     with jax.named_scope("router"):
-        weights, chosen = route_sigmoid(
+        weights, chosen = _ROUTERS[cfg.router_scoring](
             xf, lp["w_router"], cfg, bias=lp.get("expert_bias"))
         if row_valid is None:
             row_valid = jnp.ones((N,), bool)
@@ -1289,6 +1359,7 @@ def dense_layer(
     row_valid: jax.Array | None = None,
     expert_stats: list | None = None,
     window: int | None = None,
+    blocks: tuple | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """One transformer block over a ragged token batch: attn-norm → fused
     qkv (→ per-head RMSNorm of q and k where the layer has
@@ -1312,6 +1383,12 @@ def dense_layer(
     pages alone (ops/ragged_attention.py, "A window"). With ``wg`` in the
     layer's leaves each head's output is gated by ``sigmoid(y wg)`` of the
     SAME normed input, inside ``o_proj`` (scope ``attn_gate``).
+
+    ``blocks`` (a block-diffusion model, :func:`block_rows`): the rows are
+    whole diffusion blocks and each sees its block both ways beside the
+    causal past, one decode-shaped call with a block's rows folded into
+    the GQA group (ops/ragged_attention.py, :func:`block_attention`; scope
+    ``attn/block``).
 
     The ``jax.named_scope`` sections (``qkv`` holding ``qk_norm``, ``kv_write``, ``attn``
     (holding ``full`` or ``window`` where a model has both),
@@ -1349,7 +1426,14 @@ def dense_layer(
     else:
         kv_pages, kv_scales = cache_l, None
     with jax.named_scope("attn"):
-        if cfg.windowed:
+        if blocks is not None:
+            block_ends, block_pages, num_blocks, shape = blocks
+            with jax.named_scope("block"):
+                attn = block_attention(
+                    q, kv_pages, block_ends, block_pages, num_blocks,
+                    block_length=cfg.block_length, sm_scale=sm_scale, shape=shape,
+                )
+        elif cfg.windowed:
             with jax.named_scope("window" if window else "full"):
                 attn = ragged_paged_attention(
                     q, kv_pages, kv_lens, block_tables, cu_q_lens, num_seqs,
@@ -1646,6 +1730,7 @@ def forward_tokens(
     mm_embeds=None,          # [T, h] — multimodal rows (override where mask)
     mm_mask=None,            # [T] bool
     expert_stats: list | None = None,
+    block_shape: str = "block-ragged",
 ) -> tuple[jax.Array, jax.Array]:
     """One step over every scheduled token. Returns (last-token logits
     [S, vocab] f32, cache). Prefill chunks, decode tokens, and mixed
@@ -1659,6 +1744,7 @@ def forward_tokens(
         params, cache, tokens, positions, write_pages, write_offs,
         kv_lens, block_tables, cu_q_lens, num_seqs, cfg, engine, mesh,
         mm_embeds=mm_embeds, mm_mask=mm_mask, expert_stats=expert_stats,
+        block_shape=block_shape,
     )
     with jax.named_scope("lm_head"):
         last = x[last_rows]  # [S, h]
@@ -1683,6 +1769,7 @@ def forward_hidden(
     mm_mask=None,
     want_gates: bool = False,
     expert_stats: list | None = None,
+    block_shape: str = "block-ragged",
 ) -> tuple[jax.Array, jax.Array]:
     """The transformer stack up to the final norm: returns (hidden states
     [T, h], cache), and with ``want_gates`` (looped models) the exit
@@ -1712,6 +1799,11 @@ def forward_hidden(
         # Padding rows (and a megastep's dead lanes) write the garbage
         # page: they route to no expert and are not counted.
         row_valid = write_pages != engine.garbage_block if cfg.shared_sparse else None
+    blocks = None
+    if cfg.block_length:
+        with jax.named_scope("attn"), jax.named_scope("block"):
+            blocks = (*block_rows(positions, block_tables, cu_q_lens, num_seqs,
+                                  cfg.block_length), block_shape)
     block_tables, win_first, win_tables = split_tables(block_tables, cfg, engine)
     if win_tables is not None:
         with jax.named_scope("qkv"):
@@ -1761,7 +1853,7 @@ def forward_hidden(
             x, lp, cache_l, positions, write_pages, write_offs,
             kv_lens, block_tables, cu_q_lens, num_seqs, cfg,
             tp=tp, mesh=mesh, rope_cs=rope_cs,
-            row_valid=row_valid, expert_stats=expert_stats,
+            row_valid=row_valid, expert_stats=expert_stats, blocks=blocks,
         )
 
     return _run_stack(
@@ -1947,6 +2039,62 @@ def embed_forward(
         jnp.sum(w), 1.0
     )
     return pooled, scratch
+
+
+def block_rows(positions, block_tables, cu_q_lens, num_seqs, B: int):
+    """What a block-diffusion model's attention call needs of a ragged batch
+    whose every sequence brings WHOLE diffusion blocks (``B`` consecutive
+    rows from a position that ``B`` divides; the host cuts its waves so:
+    ``EngineCore._plan_prefill_wave``): ``(block_ends [T / B], the block
+    table's row of each block's sequence [T / B, pages], live blocks
+    i32[1])``. A block ends ``B`` past its first row's position whatever
+    the sequence's ``kv_lens`` say: that is the mask."""
+    T = positions.shape[0]
+    first = jnp.arange(0, T, B, dtype=jnp.int32)
+    seq_of = jnp.minimum(
+        jnp.sum(first[:, None] >= cu_q_lens[None, 1:], axis=1),
+        block_tables.shape[0] - 1).astype(jnp.int32)
+    return (positions[first] + B, block_tables[seq_of],
+            (cu_q_lens[num_seqs[0]] // B).reshape(1).astype(jnp.int32))
+
+
+def block_tokens(
+    params: Params,
+    cache: jax.Array,
+    tokens: jax.Array,        # [S, B] i32 — a block a lane, mask tokens where hidden
+    block_tables: jax.Array,  # [S, pages_per_seq] i32
+    positions: jax.Array,     # [S] i32 — position of each block's first place
+    active: jax.Array,        # [S] bool
+    cfg: ModelConfig,
+    engine: EngineConfig,
+    mesh=None,
+    expert_stats: list | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """One pass of a block-diffusion step: every lane's block of ``B =
+    cfg.block_length`` places through the stack, each row seeing its block
+    both ways beside the lane's causal past. Returns (``[S x B, vocab]``
+    logits, a row a place; cache). The block's K/V are WRITTEN, at its own
+    positions, every pass: a denoising pass's are overwritten by the next
+    and the clean pass's stay (nothing reads them in between but the pass
+    that wrote them). Dead lanes write the garbage page. Thin assembly
+    over :func:`forward_tokens`, as :func:`verify_tokens`."""
+    S, B = tokens.shape
+    bs = engine.block_size
+    with jax.named_scope("kv_write"):
+        positions = jnp.where(active, positions, 0)
+        pos = positions[:, None] + jnp.arange(B, dtype=jnp.int32)[None, :]   # [S, B]
+        page = jnp.take_along_axis(block_tables, pos // bs, axis=1)
+        write_pages = jnp.where(active[:, None], page, engine.garbage_block).reshape(-1)
+        write_offs = (pos % bs).reshape(-1)
+        kv_lens = (positions + B).astype(jnp.int32)
+        cu = B * jnp.arange(S + 1, dtype=jnp.int32)
+        num_seqs = jnp.array([S], jnp.int32)
+        rows = jnp.arange(S * B, dtype=jnp.int32)
+    return forward_tokens(
+        params, cache, tokens.reshape(-1), pos.reshape(-1), write_pages,
+        write_offs, kv_lens, block_tables, cu, num_seqs, rows, cfg,
+        engine, mesh, expert_stats=expert_stats, block_shape="block-decode",
+    )
 
 
 def decode_tokens(

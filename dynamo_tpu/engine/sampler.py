@@ -269,6 +269,39 @@ def token_logprobs(
     return chosen, top_ids.astype(jnp.int32), top_lps
 
 
+def unmask_block(
+    conf: jax.Array,        # [S, B] float32: confidence of each place's sample
+    hidden: jax.Array,      # [S, B] bool: places not yet revealed
+    step: jax.Array,        # scalar int32: 0-based denoising step
+    *,
+    steps: int,
+    threshold: float,
+) -> tuple[jax.Array, jax.Array]:   # (reveal [S, B] bool, by_threshold [S] bool)
+    """Which hidden places of each lane's block a denoising step reveals
+    (a block-diffusion model's confidence-ordered unmasking,
+    ``low_confidence_dynamic``): every hidden place whose confidence is
+    OVER ``threshold``; or, where those are fewer than the step's quota,
+    the quota's most confident hidden places, ties to the lower place.
+    The quota is ``B // steps``, one more in the first ``B % steps``
+    steps, so that ``steps`` steps reveal a block whatever the threshold
+    does; a block with fewer hidden places than the quota (known places
+    of a prompt's tail) reveals them all. ``by_threshold`` says which of
+    the two rules a lane's reveal came by."""
+    B = conf.shape[1]
+    quota = B // steps + (step < B % steps).astype(jnp.int32)
+    over = hidden & (conf > threshold)
+    c = jnp.where(hidden, conf, -jnp.inf)
+    place = jnp.arange(B, dtype=jnp.int32)
+    # a place's rank among its lane's hidden places: those surer, or as
+    # sure and lower
+    ahead = (c[:, None, :] > c[:, :, None]) | (
+        (c[:, None, :] == c[:, :, None]) & (place[None, None, :] < place[None, :, None]))
+    rank = jnp.sum(ahead & hidden[:, None, :], axis=2)
+    by_threshold = jnp.sum(over, axis=1) >= quota
+    reveal = jnp.where(by_threshold[:, None], over, hidden & (rank < quota))
+    return reveal, by_threshold
+
+
 def sample(
     logits: jax.Array,        # [B, V] float32
     rng: jax.Array,           # single key, or per-lane keys [B, 2]

@@ -410,6 +410,44 @@ def ragged_paged_attention(
     )
 
 
+def block_attention(
+    q, kv_pages, block_ends, page_indices, num_blocks, *,
+    block_length: int, sm_scale: float, shape: str,
+) -> jax.Array:
+    """Attention of a block-diffusion model (``ModelConfig.block_length``):
+    ``q [T, n_q, d]`` is ``T / B`` diffusion blocks of ``B`` consecutive
+    rows, and every row of block ``b`` sees the keys ``0 .. block_ends[b] -
+    1`` of the sequence whose pages ``page_indices[b]`` names: each earlier
+    block and, BOTH ways, its own (whose K/V the caller has written).
+
+    One decode-shaped call: a block's ``B`` rows are folded into the GQA
+    group, ``[T / B, n_kv x (B x group), d]`` with ONE query a block at
+    ``kv_lens = block_ends``, so that the causal mask the library kernel
+    builds (a query's position is ``kv_lens - 1``) is the block's own and a
+    block's K/V are read once a call. ``page_indices`` ``[T / B, pages]``
+    has a row a BLOCK (a wave repeats a sequence's row for each of its
+    blocks), ``num_blocks`` ``i32[1]`` the live ones. Counted under
+    ``shape``: ``"block-decode"`` (a denoising or clean pass of a step) or
+    ``"block-ragged"`` (a prefill wave's whole blocks)."""
+    T, n_q, d = q.shape
+    B, n_kv = block_length, kv_pages.shape[2] // 2
+    group = n_q // n_kv
+    folded = q.reshape(T // B, B, n_kv, group, d).transpose(0, 2, 1, 3, 4).reshape(
+        T // B, n_kv * B * group, d)
+    page_size = kv_pages.shape[1]
+    use_kernel = jax.default_backend() == "tpu" and d % 128 == 0 and page_size % 8 == 0
+    _count_traced(shape, "library" if use_kernel else "reference")
+    _announce(
+        logging.INFO if use_kernel or jax.default_backend() != "tpu" else logging.WARNING,
+        f"block attention ({shape}): {'Pallas TPU kernel' if use_kernel else 'jnp reference'}"
+        f", {B} rows a block folded into {folded.shape[1]} heads (head_dim={d}, "
+        f"page_size={page_size})")
+    call = pallas_ragged_attention if use_kernel else ragged_paged_attention_ref
+    out = call(folded, kv_pages, block_ends, page_indices, None, num_blocks,
+               sm_scale=sm_scale)
+    return out.reshape(T // B, n_kv, B, group, d).transpose(0, 2, 1, 3, 4).reshape(T, n_q, d)
+
+
 def paired_heads_attention(
     q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs, *, sm_scale: float,
 ) -> jax.Array:

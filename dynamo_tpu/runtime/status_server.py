@@ -186,6 +186,17 @@ SCHEDULER_GAUGES: dict[str, tuple[str, str]] = {
         "engine_window_blocks",
         "Blocks of the window pool (0: the model has no window layers)",
     ),
+    # Generation by diffusion over blocks (ISSUE 42).
+    "block_length": (
+        "engine_block_length",
+        "Places a block-diffusion model generates at a time, each seeing "
+        "its block both ways (0: one next token a step)",
+    ),
+    "denoising_steps": (
+        "engine_denoising_steps",
+        "Denoising passes a block is served with; one more, over the clean "
+        "block, writes its K/V (0: not a block-diffusion model)",
+    ),
 }
 
 
@@ -272,7 +283,31 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
         "Window-pool blocks that slid wholly out of every later query's "
         "window and were given back while their sequence went on",
     ),
+    "blocks_committed": (
+        "engine_blocks_committed",
+        "Blocks of a block-diffusion model of which a client was sent at "
+        "least one place (a block cut by a stop counts)",
+    ),
+    "block_places_discarded": (
+        "engine_block_places_discarded",
+        "Places a block-diffusion model generated and no client was sent: "
+        "those after a cut, and those of blocks run past a stop that only "
+        "the host's scan saw",
+    ),
 }
+
+# Counters of a block-diffusion model with ONE label: stats key -> (name,
+# doc, label, {label value: stats key}).
+BLOCK_COUNTERS: tuple[tuple[str, str, str, dict[str, str]], ...] = (
+    ("engine_denoise_forwards",
+     "Forwards over a block's rows, counted once a live lane a pass: the "
+     "denoising passes, and the clean pass that writes the block's K/V",
+     "pass", {"denoise": "denoise_forwards", "commit": "commit_forwards"}),
+    ("engine_places_revealed",
+     "Places a denoising pass revealed, by the rule: every hidden place "
+     "over the confidence threshold, or the step's quota of the surest",
+     "by", {"threshold": "places_revealed_threshold", "quota": "places_revealed_quota"}),
+)
 
 
 # The step clock's account of the device: key of ``StepClock.account()`` ->
@@ -373,6 +408,11 @@ class _EngineCounters:
         for key, (name, doc) in ENGINE_COUNTERS.items():
             family = CounterMetricFamily(f"dynamo_{name}", doc, labels=["service"])
             family.add_metric(["engine"], float(stats.get(key, 0) or 0))
+            yield family
+        for name, doc, label, keys in BLOCK_COUNTERS:
+            family = CounterMetricFamily(f"dynamo_{name}", doc, labels=["service", label])
+            for value, key in keys.items():
+                family.add_metric(["engine", value], float(stats.get(key, 0) or 0))
             yield family
         waves = CounterMetricFamily(
             "dynamo_engine_prefill_waves",

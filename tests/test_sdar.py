@@ -1,0 +1,559 @@
+"""SDAR's step (``ModelConfig.block_length > 0``): a megastep that denoises a
+block of places a lane and commits 0..B tokens. The engine is held to the
+plain reference (``chipbench/reference/sdar_moe.py``) by the architecture's
+own ``score_probe`` at ``TIGHT`` (float32 on both sides) over schedules,
+thresholds, prompt tails, cuts, loops, preemption and prefix hits; the
+dropless layer's shares add up under either scoring; what the model does
+not carry is refused by name.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import architectures
+from chipbench.architectures import sdar_moe as arch
+from chipbench.configs import engine_overrides, load_config, model_fields
+from chipbench.reference import check
+from chipbench.reference import sdar_moe as reference
+from dynamo_tpu.engine import PRESETS, EngineConfig, ModelConfig
+from dynamo_tpu.engine import model as model_mod
+from dynamo_tpu.engine.config import (
+    UnsupportedModelOption,
+    mixtral_8x7b,
+    sdar_30b_a3b_6l,
+    tiny_engine,
+    tiny_lfm2,
+    tiny_moe,
+    tiny_sdar,
+)
+from dynamo_tpu.engine.core import EngineCore, _resolve_block_megastep
+from dynamo_tpu.engine.model import init_params
+from dynamo_tpu.engine.sampler import unmask_block
+from dynamo_tpu.llm.protocols.common import (
+    FinishReason,
+    OutputOptions,
+    PreprocessedRequest,
+    SamplingOptions,
+    StopConditions,
+)
+from dynamo_tpu.ops.ragged_attention import block_attention, ragged_paged_attention_ref
+
+CFG = tiny_sdar()
+FILE = load_config("tiny-sdar-rehearsal")
+PROMPT = [int(t) for t in np.random.RandomState(0).randint(1, 380, size=120)]
+TIGHT = 1e-4   # float32 on both sides: the readings are 1e-6
+
+
+def file_with(steps: int = 2, threshold: float = 0.9) -> dict:
+    cfg = json.loads(json.dumps(FILE))
+    cfg["denoising_steps"] = steps
+    cfg["confidence_threshold"] = threshold
+    return cfg
+
+
+def make_core(cfg: dict = FILE, seed: int = 5, **engine) -> EngineCore:
+    fields = dict(engine_overrides(cfg), **engine)
+    return EngineCore(ModelConfig(**model_fields(cfg)), EngineConfig(**fields), seed=seed)
+
+
+def _req(prompt, rid, max_tokens, logprobs=None, **stop):
+    return PreprocessedRequest(
+        model="m", token_ids=list(prompt), request_id=rid,
+        sampling=SamplingOptions(temperature=0.0),
+        stop=StopConditions(max_tokens=max_tokens, **stop),
+        output=OutputOptions(logprobs=logprobs))
+
+
+def run_to_completion(core, seqs):
+    out = {s.request_id: [] for s in seqs}
+    chunks = {s.request_id: [] for s in seqs}
+    while any(s.finish is None for s in seqs):
+        for s, o in core.step():
+            out[s.request_id] += list(o.token_ids)
+            chunks[s.request_id].append(len(o.token_ids))
+    while core.has_work():
+        core.step()
+    return out, chunks
+
+
+def _streams(prompts, max_tokens, cfg=FILE, **engine):
+    core = make_core(cfg, **engine)
+    seqs = [core.add_request(_req(p, f"s{i}", n, ignore_eos=True))
+            for i, (p, n) in enumerate(zip(prompts, max_tokens))]
+    return run_to_completion(core, seqs)[0], core
+
+
+def held_to_reference(core, cfg, body):
+    got = check.score_request(core, cfg, body)
+    return check.compare(got["served"], got["scored"], atol=TIGHT), got
+
+
+def test_the_preset_is_the_file_and_the_published_size_its_sums():
+    mf = model_fields(FILE)
+    assert dataclasses.replace(ModelConfig(**mf), name="tiny-sdar") == CFG
+    assert CFG.shared_sparse and CFG.block_length == 4 and not CFG.layer_groups
+    assert "tiny-sdar" in PRESETS and "sdar-30b-a3b-6l" in PRESETS
+    big = sdar_30b_a3b_6l()
+    layer = 2 * 2048 * 4096 + 2 * 2048 * 512 + 2 * 128 + 2 * 2048 + 2048 * 128 + 128 * 3 * 2048 * 768
+    assert big.param_bytes() == 2 * (6 * layer + 2 * 2048 * 151936 + 2048) == 8_722_111_488
+    assert model_mod._routed_down_divisor(big) == 16
+    # the head norms of a block model are drawn around a gain over 1 (a query reads a
+    # few keys, not its context's mean); every other model's around 1, as they were
+    gain = model_mod._QK_NORM_GAIN_BLOCKS
+    assert 1.25 < gain < 1.75       # the chip's sweep: under it fp8 passes, over it bf16 fails
+    assert model_mod._qk_norm_gain(big) == model_mod._qk_norm_gain(CFG) == gain
+    assert model_mod._qk_norm_gain(tiny_lfm2()) == 1.0
+    drawn = init_params(jax.random.PRNGKey(0), CFG)["layers"]
+    assert abs(float(jnp.mean(drawn["q_layernorm"])) - gain) < 0.1
+    assert abs(float(jnp.mean(drawn["k_layernorm"])) - gain) < 0.1
+    # what tells the dropless layer from the mixtral path is the layout, not the scoring
+    assert big.shared_sparse and big.router_scoring == "softmax"
+    assert not mixtral_8x7b().shared_sparse and not tiny_moe().shared_sparse
+
+
+# -- the engine against the reference ------------------------------------------
+
+@pytest.mark.parametrize("steps,threshold", [(1, 0.9), (2, 0.9), (4, 0.9), (2, 0.03), (4, 0.03)],
+                         ids=["1-step", "2-steps", "4-steps", "2-steps-fires", "4-steps-fires"])
+def test_the_engine_keeps_the_references_schedule(steps, threshold):
+    cfg = file_with(steps, threshold)
+    core = make_core(cfg)
+    assert core.cfg.denoising_steps == steps
+    assert core.engine.megastep == max(1, 8 // (steps + 1)) * (steps + 1)
+    verdict, got = held_to_reference(core, cfg, {"prompt_ids": PROMPT[:98], "max_tokens": 17,
+                                                 "top": 5})
+    assert verdict["ok"] and verdict["compared"] == 170 and verdict["max_abs_diff"] < TIGHT
+    first, repeat = got["served"]
+    assert first["tokens"] == repeat["tokens"] and repeat["cached_tokens"] == 96
+    fired = core.exec_stats["places_revealed_threshold"]
+    assert (fired > 0) == (threshold < 0.5)
+    # every entry says where its token lies and which step revealed it; the
+    # last block was cut at 3 of 4 and says what came after
+    extra = first["extra"]
+    assert [(e["block"], e["place"]) for e in extra] == [
+        ((98 + j) // 4, (98 + j) % 4) for j in range(17)]
+    assert all(0 <= e["step"] < steps for e in extra)
+    assert [len(e.get("cut", ())) for e in extra] == [0] * 16 + [1]
+
+
+@pytest.mark.parametrize("whole,tail", [(0, 1), (0, 2), (0, 3), (96, 0), (96, 1), (96, 2),
+                                        (96, 3)])
+def test_a_prompts_tail_opens_the_first_block(whole, tail):
+    core = make_core()
+    verdict, got = held_to_reference(
+        core, FILE, {"prompt_ids": PROMPT[:whole + tail], "max_tokens": 9, "top": 5})
+    assert verdict["ok"] and verdict["max_abs_diff"] < TIGHT
+    assert got["served"][1]["cached_tokens"] == whole
+    # the first block generated B - tail places: its forwards are counted once a lane
+    assert core.exec_stats["denoise_forwards"] == 2 * core.exec_stats["commit_forwards"]
+
+
+@pytest.mark.parametrize("fault", ["fp8", "causal", "order"])
+def test_each_fault_fails_the_comparison(fault):
+    cfg = file_with(4)
+    failed = 0
+    for seed in (1, 3):
+        core = make_core(cfg, seed=seed)
+        body = {"prompt_ids": PROMPT[:98], "max_tokens": 17, "top": 5}
+        got = check.score_request(core, cfg, body)
+        assert check.compare(got["served"], got["scored"])["ok"]
+        scored = arch.score_probe(cfg, core.params, body["prompt_ids"], got["served"][0],
+                                  faults=(fault,))
+        failed += not check.compare(got["served"][:1], {"sequences": [scored]})["ok"]
+    assert failed == 2
+
+
+@pytest.mark.parametrize("lie", ["step", "place", "cut"])
+def test_a_claim_that_is_no_schedule_is_not_scored(lie):
+    core = make_core()
+    body = {"prompt_ids": PROMPT[:98], "max_tokens": 17, "top": 5}
+    probe = check.run_probe(core, body["prompt_ids"], 17, 5, "lie", extra=True)
+    if lie == "step":      # every place of a block revealed by the last step
+        probe["extra"] = [dict(e, step=1) for e in probe["extra"]]
+    elif lie == "place":
+        probe["extra"][3] = dict(probe["extra"][3], place=0)
+    else:                  # the cut block's last place is not told
+        probe["extra"][-1] = {k: v for k, v in probe["extra"][-1].items() if k != "cut"}
+    scored = arch.score_probe(FILE, core.params, body["prompt_ids"], probe)
+    assert scored["finite"] is False
+    assert not check.compare([probe], {"sequences": [scored]})["ok"]
+
+
+def test_the_reference_forward_is_block_masked():
+    """Row p sees key j iff j // B <= p // B: a token AFTER p in p's block
+    moves p's logits, one in the next block does not."""
+    core = make_core()
+    mf = model_fields(FILE)
+    base = arch.reference_logits(core.params, mf, PROMPT[:12], [5])
+    same_block = list(PROMPT[:12])
+    same_block[7] = (same_block[7] + 1) % 380
+    next_block = list(PROMPT[:12])
+    next_block[8] = (next_block[8] + 1) % 380
+    assert float(jnp.abs(arch.reference_logits(core.params, mf, same_block, [5]) - base).max()) > 1e-3
+    assert float(jnp.abs(arch.reference_logits(core.params, mf, next_block, [5]) - base).max()) == 0
+
+
+# -- cuts ------------------------------------------------------------------------
+
+def test_max_tokens_cuts_a_block_and_only_whole_pages_are_hashed():
+    core = make_core()
+    seq = core.add_request(_req(PROMPT[:30], "cut", 11, ignore_eos=True))
+    out, chunks = run_to_completion(core, [seq])
+    assert len(out["cut"]) == 11 and seq.finish == "length" and seq.generated == 11
+    # 30 + 11 = 41 tokens: blocks of 4 end at 44, 3 places discarded; two blocks
+    # a dispatch and one chunk a dispatch: 2 + 4, then 4 + 1
+    assert chunks["cut"] == [6, 5]
+    assert core.exec_stats["block_places_discarded"] >= 3
+    assert core.exec_stats["committed_tokens"] == 11
+    # five whole pages of 8 tokens were kept (40 of 41 tokens): those are hashed
+    assert core.cached_prefix_tokens(PROMPT[:30] + out["cut"]) == 40
+
+
+def test_eos_inside_a_block_ends_the_request_there():
+    free = make_core()
+    seq = free.add_request(_req(PROMPT[:30], "free", 24, ignore_eos=True))
+    want = run_to_completion(free, [seq])[0]["free"]
+    at = 9                                # inside the third generated block
+    eos = want[at]
+    first = want.index(eos)
+    core = EngineCore(CFG, EngineConfig(**engine_overrides(FILE)), seed=5,
+                      eos_token_ids=(eos,))
+    seq = core.add_request(_req(PROMPT[:30], "eos", 24))
+    got = run_to_completion(core, [seq])[0]["eos"]
+    assert got == want[:first + 1] and seq.finish == FinishReason.EOS.value
+    assert seq.generated == first + 1 and first <= at
+    # a stop id stops it the same way
+    core = make_core()
+    seq = core.add_request(_req(PROMPT[:30], "stop-id", 24, ignore_eos=True,
+                                stop_token_ids=[eos]))
+    assert run_to_completion(core, [seq])[0]["stop-id"] == want[:first + 1]
+    assert seq.finish == "stop" and not core.running
+
+
+def test_a_stop_string_cuts_a_block_from_the_host():
+    """Stop strings are text: the detokenizer finds one and cancels the
+    request, wherever in a block its last token lies; the engine discards
+    the places after it and gives the blocks back."""
+    from dynamo_tpu.llm.detokenizer import StopStringChecker
+
+    core = make_core(async_exec=True)
+    seq = core.add_request(_req(PROMPT[:30], "text", 60, ignore_eos=True))
+    text = lambda toks: "".join(chr(65 + t % 26) for t in toks)  # noqa: E731
+    free = make_core()
+    ref = free.add_request(_req(PROMPT[:30], "w", 60, ignore_eos=True))
+    want = text(run_to_completion(free, [ref])[0]["w"])
+    stop = want[13:16]                    # ends at place 3 of 4 of a block
+    checker = StopStringChecker([stop])
+    shown = ""
+    while not checker.stopped and core.has_work():
+        for s, o in core.step():
+            piece, stopped = checker.step(text(o.token_ids))
+            shown += piece
+            if stopped:
+                core.cancel_request(s)
+    while core.has_work():
+        core.step()
+    assert shown == want[: want.index(stop)] and not core.running
+    assert seq.cancelled and core.exec_stats["block_places_discarded"] > 0
+
+
+# -- one stream whatever the loop ------------------------------------------------
+
+@pytest.mark.parametrize("async_exec", [False, True], ids=["sync", "async"])
+@pytest.mark.parametrize("blocks", [1, 2])
+def test_one_stream_whatever_the_loop_and_the_blocks_a_dispatch(async_exec, blocks):
+    prompts = [PROMPT[:n] for n in (30, 17, 64, 3)]
+    lengths = [21, 33, 9, 14]
+    want, _ = _streams(prompts, lengths, async_exec=False, megastep_k=3)
+    got, core = _streams(prompts, lengths, async_exec=async_exec, megastep_k=3 * blocks)
+    assert core.engine.megastep == 3 * blocks and core.pipelined == async_exec
+    assert got == want and [len(got[f"s{i}"]) for i in range(4)] == lengths
+    if async_exec:
+        assert core.exec_stats["pipelined_dispatches"] > 0
+
+
+def test_seeded_sampling_is_one_stream_too():
+    def run(**engine):
+        core = make_core(**engine)
+        seq = core.add_request(PreprocessedRequest(
+            model="m", token_ids=PROMPT[:30], request_id="t",
+            sampling=SamplingOptions(temperature=0.8, top_k=20, seed=7),
+            stop=StopConditions(max_tokens=21, ignore_eos=True), output=OutputOptions()))
+        return run_to_completion(core, [seq])[0]["t"]
+    assert run(async_exec=False, megastep_k=3) == run(async_exec=True, megastep_k=6)
+
+
+# -- preemption and the prefix cache ----------------------------------------------
+
+def _pages(core, seq, n_pages):
+    return [np.asarray(layer[np.asarray(seq.block_ids[:n_pages])]) for layer in core.cache]
+
+
+def test_preempt_and_recompute_gives_the_same_pages_and_stream():
+    want, _ = _streams([PROMPT[:21]], [30], async_exec=False)
+    core = make_core(async_exec=False)
+    seq = core.add_request(_req(PROMPT[:21], "s0", 30, ignore_eos=True))
+    got = []
+    while seq.generated < 15:
+        for _, out in core.step():
+            got += list(out.token_ids)
+    # 21 + 15 = 36 tokens = 4 whole pages of 8, written by clean passes
+    before = _pages(core, seq, 4)
+    with core._step_lock:
+        core._preempt(seq)
+    core.clear_kv_cache()                 # nothing to find: the wave recomputes them all
+    assert seq.prompt_len == 36 and seq.tail == 0 and seq.generated == 15
+    while seq.prefilled < 36:
+        core.step()
+    after = _pages(core, seq, 4)
+    for a, b in zip(before, after):
+        np.testing.assert_allclose(a, b, atol=1e-5)    # a wave's rows against a pass's
+    done, _ = run_to_completion(core, [seq])
+    assert got + done["s0"] == want["s0"] and core.sched_stats["preemptions"] == 1
+
+
+def test_block_pressure_preempts_and_the_streams_are_the_unpressed_ones():
+    prompts = [list(range(1 + 20 * i, 17 + 20 * i)) for i in range(3)]
+    roomy, _ = _streams(prompts, [33] * 3, num_kv_blocks=64, max_model_len=64)
+    tight, core = _streams(prompts, [33] * 3, num_kv_blocks=14, max_model_len=64)
+    assert core.sched_stats["preemptions"] >= 1
+    assert tight == roomy and all(len(v) == 33 for v in tight.values())
+
+
+def test_a_prefix_hit_on_a_page_of_generated_blocks():
+    core = make_core()
+    seq = core.add_request(_req(PROMPT[:16], "a", 17, ignore_eos=True))
+    first = run_to_completion(core, [seq])[0]["a"]
+    # pages 2 and 3 (tokens 16..31) hold generated blocks only
+    longer = PROMPT[:16] + first[:16] + PROMPT[40:47]
+    verdict, got = held_to_reference(core, FILE, {"prompt_ids": longer, "max_tokens": 9, "top": 5})
+    assert verdict["ok"] and verdict["max_abs_diff"] < TIGHT
+    assert got["served"][0]["cached_tokens"] == 32
+
+
+# -- the pieces -------------------------------------------------------------------
+
+def test_unmask_block_reveals_by_threshold_or_by_quota():
+    conf = jnp.asarray([[0.2, 0.95, 0.93, 0.1],     # two over: by threshold (quota 1)
+                        [0.3, 0.3, 0.2, 0.91],      # one over, quota 1: that one
+                        [0.3, 0.5, 0.5, 0.1],       # none over: the surest, ties to the lower
+                        [0.99, 0.2, 0.1, 0.3]])     # the surest is not hidden
+    hidden = jnp.asarray([[True] * 4, [True] * 4, [True] * 4, [False, True, True, True]])
+    reveal, by_threshold = unmask_block(conf, hidden, jnp.int32(0), steps=4, threshold=0.9)
+    assert reveal.tolist() == [[False, True, True, False], [False, False, False, True],
+                               [False, True, False, False], [False, False, False, True]]
+    assert by_threshold.tolist() == [True, True, False, False]
+    # 3 steps of 4 places: quotas 2, 1, 1
+    for step, quota in ((0, 2), (1, 1), (2, 1)):
+        reveal, _ = unmask_block(conf[2:3], hidden[2:3], jnp.int32(step), steps=3, threshold=2.0)
+        assert int(reveal.sum()) == quota
+
+
+def test_the_fold_is_the_block_mask():
+    """block_attention's one decode-shaped call against the plain reference
+    call a ROW, each row told its block's end: the same numbers."""
+    rs = np.random.RandomState(3)
+    B, n_q, n_kv, d, page = 4, 4, 2, 16, 8
+    kv_pages = jnp.asarray(rs.randn(6, page, 2 * n_kv, d), jnp.float32)
+    q = jnp.asarray(rs.randn(3 * B, n_q, d), jnp.float32)
+    ends = jnp.asarray([8, 12, 4], jnp.int32)        # three blocks of two sequences
+    tables = jnp.asarray([[0, 1, 5], [0, 1, 5], [2, 3, 5]], jnp.int32)
+    got = block_attention(q, kv_pages, ends, tables, jnp.asarray([3], jnp.int32),
+                          block_length=B, sm_scale=0.25, shape="block-ragged")
+    rows = ragged_paged_attention_ref(
+        q, kv_pages, jnp.repeat(ends, B), jnp.repeat(tables, B, axis=0), None,
+        jnp.asarray([3 * B], jnp.int32), sm_scale=0.25)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(rows), atol=1e-5)
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_the_shares_of_the_dropless_layer_add_up_under_either_scoring(scoring):
+    """Four chips' shares of 8 experts (each computed with the share's own
+    parameters) add up to the uncut layer, and that to the plain sum over the
+    chosen experts: the softmax shares too."""
+    whole = tiny_sdar(router_scoring=scoring, block_length=0, denoising_steps=0,
+                      confidence_threshold=1.0, mask_token_id=0)
+    params = init_params(jax.random.PRNGKey(5), whole)
+    lp = model_mod.layer_params(params, 1, whole)
+    y = jnp.asarray(np.random.RandomState(1).randn(21, 64), jnp.float32)
+    im = whole.moe_intermediate_size
+    with jax.default_matmul_precision("highest"):
+        if scoring == "softmax":
+            w = reference.routing_weights(y, lp["w_router"], top_k=2)
+        else:
+            from chipbench.reference import lfm2_moe
+
+            w = lfm2_moe.routing_weights(y, lp["w_router"], jnp.zeros(8), top_k=2, scale=1.0,
+                                         norm_eps=whole.router_norm_eps)
+        assert int((w > 0).sum()) == 21 * 2
+        np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, atol=1e-5)
+        want = sum(w[:, e, None] * reference.mlp_block(
+            y, lp["w_gu"][e][:, :im], lp["w_gu"][e][:, im:], lp["w_down"][e]) for e in range(8))
+        uncut = model_mod._shared_sparse_mlp(y, lp, whole)
+        assert float(jnp.abs(uncut - want).max()) < TIGHT
+        total = 0
+        for rank in range(4):
+            cfg = dataclasses.replace(whole, experts_held=(rank, 4))
+            lp_r = model_mod.layer_params(init_params(jax.random.PRNGKey(5), cfg), 1, cfg)
+            assert lp_r["w_gu"].shape[0] == 2
+            part = model_mod._shared_sparse_mlp(y, lp_r, cfg)
+            assert float(jnp.abs(part).max()) > 1e-4      # the share adds something
+            total = total + part
+        assert float(jnp.abs(total - want).max()) < TIGHT
+
+
+def test_mixtral_keeps_its_path_and_its_numbers():
+    """The capacity-bounded layer is chosen by the layout the tree has, as
+    before: a softmax-scored model WITHOUT moe_intermediate_size goes through
+    _moe_mlp, token for token what its own reference gives."""
+    from chipbench.reference import mixtral as mixtral_reference  # noqa: F401
+
+    cfg = tiny_moe()
+    params = init_params(jax.random.PRNGKey(2), cfg)
+    lp = model_mod.layer_params(params, 0, cfg)
+    assert "w_gate" in lp and "w_gu" not in lp
+    y = jnp.asarray(np.random.RandomState(2).randn(5, 64), jnp.float32)
+    np.testing.assert_array_equal(np.asarray(model_mod._mlp(y, lp, cfg, 1)),
+                                  np.asarray(model_mod._moe_mlp(y, lp, cfg, None)))
+    with pytest.raises(ValueError, match="moe_intermediate_size"):
+        dataclasses.replace(cfg, router_scoring="sigmoid")
+
+
+# -- refused by name ----------------------------------------------------------------
+
+@pytest.mark.parametrize("option,engine", [
+    ("spec_decode", {"spec_decode": "ngram"}),
+    ("scheduling", {"scheduling": "chunked"}),
+    ("kv_dtype", {"kv_dtype": "int8"}),
+])
+def test_an_option_the_block_step_does_not_carry_is_refused_at_start_up(option, engine):
+    with pytest.raises(UnsupportedModelOption) as e:
+        EngineCore(CFG, tiny_engine(**engine), seed=0)
+    assert e.value.option == option and "tiny-sdar" in str(e.value)
+
+
+def test_meshes_quantised_weights_and_misfit_sizes_are_refused():
+    from dynamo_tpu.backends.jax.main import build_engine
+
+    with pytest.raises(UnsupportedModelOption, match="tp"):
+        EngineCore(CFG, tiny_engine(), seed=0, mesh=object())
+    with pytest.raises(UnsupportedModelOption, match="pp"):
+        EngineCore(CFG, tiny_engine(), seed=0, pp_mesh=object())
+    with pytest.raises(NotImplementedError, match="tiny-sdar"):
+        build_engine("tiny-sdar", {"num_kv_blocks": 16, "block_size": 8}, quant="int8")
+    with pytest.raises(ValueError, match="whole blocks"):
+        EngineCore(CFG, tiny_engine(block_size=6, prefill_buckets=(24,)), seed=0)
+    with pytest.raises(ValueError, match="denoising_steps"):
+        tiny_sdar(denoising_steps=5)
+    with pytest.raises(ValueError, match="only a model that generates by blocks"):
+        dataclasses.replace(tiny_lfm2(), denoising_steps=2)
+    with pytest.raises(ValueError, match="block_length"):
+        dataclasses.replace(CFG, block_length=0)
+    with pytest.raises(NotImplementedError):
+        dataclasses.replace(tiny_lfm2(), block_length=4, denoising_steps=2)
+
+
+@pytest.mark.parametrize("option,sampling", [
+    ("frequency_penalty", {"frequency_penalty": 0.5}),
+    ("presence_penalty", {"presence_penalty": 0.5}),
+    ("repetition_penalty", {"repetition_penalty": 1.2}),
+    ("n", {"n": 2}),
+])
+def test_a_request_for_left_to_right_sampling_is_refused_by_name(option, sampling):
+    core = make_core()
+    with pytest.raises(ValueError, match=option):
+        core.add_request(PreprocessedRequest(
+            model="m", token_ids=PROMPT[:8], request_id="r",
+            sampling=SamplingOptions(temperature=0.0, **sampling),
+            stop=StopConditions(max_tokens=4), output=OutputOptions()))
+    assert not core.has_work()
+
+
+def test_resolved_schedule_and_what_the_worker_reports():
+    eng = _resolve_block_megastep(tiny_sdar(denoising_steps=4), tiny_engine(block_size=8))
+    assert eng.megastep == 5                                   # one block of 5 passes
+    eng = _resolve_block_megastep(CFG, tiny_engine(block_size=8))
+    assert CFG.denoising_steps == 2 and eng.megastep == 6      # two blocks of 3 passes
+    core = make_core()
+    stats = core.scheduler_stats()
+    assert stats["block_length"] == 4 and stats["denoising_steps"] == 2
+    assert stats["megastep_k"] == 6
+
+
+def test_counters_of_a_run():
+    from dynamo_tpu.ops.ragged_attention import traced_calls
+
+    core = make_core()
+    seq = core.add_request(_req(PROMPT[:34], "c", 25, ignore_eos=True))
+    run_to_completion(core, [seq])
+    st = core.exec_stats
+    # 34 = 8 whole blocks + a tail of 2; 25 tokens: 2 + 5 whole blocks + 3 of 4
+    assert st["blocks_committed"] == 7
+    assert st["places_revealed_quota"] + st["places_revealed_threshold"] >= 26
+    assert st["denoise_forwards"] == 2 * st["commit_forwards"] >= 14
+    assert st["committed_tokens"] == 25 and st["block_places_discarded"] >= 1
+    traced = traced_calls()
+    assert traced.get(("block-decode", "reference"), 0) > 0
+    assert traced.get(("block-ragged", "reference"), 0) > 0
+    assert not core.running and core.allocator.free_blocks > 0
+
+
+# -- a checkpoint under the base family's names ---------------------------------------
+
+def test_loads_a_checkpoint_under_the_base_familys_names(tmp_path):
+    from safetensors.numpy import save_file
+
+    from dynamo_tpu.engine.loader import load_hf_llama
+
+    rng = np.random.RandomState(0)
+    h, d, E, im, v = 64, 16, 8, 32, 384
+    mat = lambda o, i: (rng.randn(o, i) * i ** -0.5).astype(np.float32)  # noqa: E731
+    sd = {"model.embed_tokens.weight": mat(v, h), "model.norm.weight": np.ones(h, np.float32),
+          "lm_head.weight": mat(v, h)}
+    for l in range(2):
+        p = f"model.layers.{l}."
+        sd[p + "input_layernorm.weight"] = (1 + 0.1 * rng.randn(h)).astype(np.float32)
+        sd[p + "post_attention_layernorm.weight"] = (1 + 0.1 * rng.randn(h)).astype(np.float32)
+        for name, out in (("q_proj", 4 * d), ("k_proj", 2 * d), ("v_proj", 2 * d)):
+            sd[p + f"self_attn.{name}.weight"] = mat(out, h)
+        sd[p + "self_attn.o_proj.weight"] = mat(h, 4 * d)
+        sd[p + "self_attn.q_norm.weight"] = (1 + 0.1 * rng.randn(d)).astype(np.float32)
+        sd[p + "self_attn.k_norm.weight"] = (1 + 0.1 * rng.randn(d)).astype(np.float32)
+        sd[p + "mlp.gate.weight"] = mat(E, h)
+        for e in range(E):
+            sd[p + f"mlp.experts.{e}.gate_proj.weight"] = mat(im, h)
+            sd[p + f"mlp.experts.{e}.up_proj.weight"] = mat(im, h)
+            sd[p + f"mlp.experts.{e}.down_proj.weight"] = mat(h, im) / 4
+    save_file(sd, str(tmp_path / "model.safetensors"))
+    hf = {k: val for k, val in FILE.items()
+          if k not in ("name", "torch_dtype", "serve", "source", "deployment", "reduced",
+                       "assumed", "probe")}
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    cfg, loaded = load_hf_llama(tmp_path, dtype=jnp.float32)
+    assert cfg == dataclasses.replace(CFG, name="sdar_moe", dtype="bfloat16")
+    assert [a.shape for a in loaded["moe"]["w_gu"]] == [(E, h, 2 * im)] * 2
+    np.testing.assert_array_equal(loaded["moe"]["w_gu"][1][5, :, im:],
+                                  sd["model.layers.1.mlp.experts.5.up_proj.weight"].T)
+    np.testing.assert_array_equal(loaded["layers"]["k_layernorm"][1],
+                                  sd["model.layers.1.self_attn.k_norm.weight"])
+    np.testing.assert_array_equal(loaded["layers"]["wqkv"][0][:, 4 * d: 6 * d],
+                                  sd["model.layers.0.self_attn.k_proj.weight"].T)
+    # the loaded tree serves, and the reference reads it as it reads a drawn one
+    served = EngineCore(dataclasses.replace(cfg, dtype="float32"),
+                        EngineConfig(**engine_overrides(FILE)),
+                        params=jax.tree.map(jnp.asarray, loaded))
+    verdict, _ = held_to_reference(served, FILE, {"prompt_ids": PROMPT[:30], "max_tokens": 9,
+                                                  "top": 5})
+    assert verdict["ok"] and verdict["max_abs_diff"] < TIGHT
+
+
+def test_architectures_knows_the_module_and_its_optional_member():
+    assert "sdar_moe" in architectures.known()
+    assert architectures.of(FILE) is arch and callable(arch.score_probe)

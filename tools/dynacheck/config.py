@@ -174,6 +174,9 @@ AUDITED_CURSOR_WRITERS: dict[str, set[str]] = {
         # replay, chunk advance, scanned-continuation rollback.
         "EngineCore._plan_fused.commit",
         "EngineCore._apply_verify_row",
+        # Block-diffusion megastep (ISSUE 42): the cursor moves a whole
+        # block on at its clean pass, and by the kept places at a cut.
+        "EngineCore._plan_blocks.commit",
     },
     # The allocator owns its bookkeeping wholesale: every public method is
     # an audited entry point; the rule guards against OTHER files reaching
