@@ -456,7 +456,8 @@ def serve_phase(mode: str, inject: str | None, report: dict) -> None:
             "dynamo_engine_expert_calls_traced_total", judge_experts_traced)
         for role, st in report["startup"].items():
             judge_window(role, st, report["attention_traced"][role])
-            judge_blocks(role, st, report["attention_traced"][role])
+            judge_blocks(role, st, report["attention_traced"][role],
+                         next(url for r, url, _ in workers if r == role))
         report["token_account"] = token_accounts(workers)
     finally:
         children.stop()
@@ -575,12 +576,15 @@ def judge_window(role: str, startup: dict, traced: dict[str, float]) -> None:
             f"window {window}, blocks of {bs} and chunks of {chunk}: at most {limit}")
 
 
-def judge_blocks(role: str, startup: dict, traced: dict[str, float]) -> None:
+def judge_blocks(role: str, startup: dict, traced: dict[str, float],
+                 health_url: str | None = None) -> None:
     """A worker of a block-diffusion model (``startup.block_length``): its
     steps must have traced a ``block-decode`` call, the block's rows folded
     into one decode-shaped call of the kernel (on a TPU never the jnp
     reference: :func:`judge_attention_traced` has refused that), and none of
-    the plain ``decode`` shape, whose mask is causal inside a block."""
+    the plain ``decode`` shape, whose mask is causal inside a block. With the
+    worker's ``health_url``: of the rows its block passes ran, fewer went
+    through the head (the clean pass, at the least, has none)."""
     if not startup.get("block_length"):
         return
     live = {k for k, v in traced.items() if v}
@@ -590,6 +594,11 @@ def judge_blocks(role: str, startup: dict, traced: dict[str, float]) -> None:
         raise PhaseFailed(
             f"{role}: a block model traced a causal attention call, which cannot see a "
             f"block both ways: {traced}")
+    if health_url and role != "prefill":
+        head, rows = (float(metric_lines(health_url, f"dynamo_engine_block_{n}_total{{")[0]
+                            .split()[-1]) for n in ("head_rows", "rows"))
+        if not 0 < head < rows:
+            raise PhaseFailed(f"{role}: the head ran on {head:.0f} of {rows:.0f} block rows")
 
 
 def kernel_phase(mode: str, inject: str | None, report: dict) -> None:

@@ -49,7 +49,8 @@ from dynamo_tpu.engine.prefill_cover import cheapest_cover
 from dynamo_tpu.runtime.engine import EngineOverloadedError
 from dynamo_tpu.runtime import wire
 from dynamo_tpu.engine.model import (
-    block_tokens,
+    block_hidden,
+    block_logits,
     decode_tokens,
     embed_forward,
     expert_call_shape,
@@ -66,6 +67,7 @@ from dynamo_tpu.engine.sampler import (
     LOGPROBS_K,
     device_ngram_draft,
     gather_feedback,
+    hidden_at_most,
     pad_feedback,
     resolve_verify,
     ring_append,
@@ -680,7 +682,7 @@ def _megastep_blocks(
 ):
     """:func:`_megastep_body` of a block-diffusion model
     (``cfg.block_length = B > 0``): a scanned iteration is ONE forward over
-    ``B`` rows a lane (model.block_tokens), and a dispatch runs ``n_steps /
+    ``B`` rows a lane (model.block_hidden), and a dispatch runs ``n_steps /
     (steps + 1)`` whole blocks, ``steps = cfg.denoising_steps`` denoising
     passes and a clean pass each. A block starts as ``cfg.mask_token_id``
     at its hidden places (``known`` ``[S, B]``: a prompt's tail opens a
@@ -695,6 +697,22 @@ def _megastep_blocks(
     moves ``B`` on. Hidden places are a MASK OF PLACES, never ``id ==
     mask_token_id``: a prompt token with that id is a known token.
 
+    **Which pass runs the head on which rows.** Only a hidden place's logits,
+    draw and confidence are ever read, and after ``p`` steps a live lane
+    holds at most ``H_p`` hidden places (``sampler.hidden_at_most``: 4, 2, 0
+    at ``B`` 4 and 2 steps). So pass ``p`` hands the head and the sampler
+    ``S x H_p`` rows: each lane's hidden places in ascending order (spare
+    slots, where a prompt's tail or the threshold left fewer, point at the
+    lane's place 0 and are read by no one; a dead lane reveals nothing, so
+    any ``H_p`` of its places do), and spreads the draws back over ``[S,
+    B]``. Pass 0 (``H_0 = B``) takes every row as it lies; the clean pass
+    (``H = 0``) stops at the final norm: it writes the K/V, counts its
+    experts, and has no head, no draw, no confidence. The passes stay ONE
+    scanned body, the stack once a program, and ``lax.switch`` on the
+    pass's number picks the head of that pass's shape: written out, the
+    three passes cost the v5e a head computed three times a pass (XLA
+    rematerialised the logits) and 15 s of set-up (PERF.md section 6, PR 43).
+
     A lane goes dead for the dispatch's later blocks once its budget of
     places to generate is spent or a revealed place holds a watched id (past
     the min-tokens floor); the host's stop scan stays the authority over
@@ -708,33 +726,69 @@ def _megastep_blocks(
     S = lanes.shape[0]
     f32 = lambda col: jax.lax.bitcast_convert_type(lanes[:, col], jnp.float32)  # noqa: E731
     position, active = lanes[:, _L_POSITION], lanes[:, _L_ACTIVE] != 0
-    seeds = jnp.repeat(lanes[:, _L_SEED], B)
-    temperature, top_k, top_p = (
-        jnp.repeat(f32(_L_TEMPERATURE), B), jnp.repeat(lanes[:, _L_TOP_K], B),
-        jnp.repeat(f32(_L_TOP_P), B))
+    lane_sampling = (lanes[:, _L_SEED], f32(_L_TEMPERATURE), lanes[:, _L_TOP_K], f32(_L_TOP_P))
     watch, min_left = lanes[:, _L_WATCH:], lanes[:, _L_MIN_LEFT]
-    place = jnp.arange(B, dtype=jnp.int32)
+    lane, place = jnp.arange(S, dtype=jnp.int32), jnp.arange(B, dtype=jnp.int32)
     K = LOGPROBS_K
 
-    def one_pass(carry, p):
-        toks, hidden, step_of, lp, cache, pos, act, counts = carry
-        stats = _expert_stats_list(cfg)
-        logits, cache = block_tokens(
-            params, cache, jnp.where(hidden, cfg.mask_token_id, toks),
-            block_tables, pos, act, cfg, engine, mesh, expert_stats=stats)
+    def blank_lp():   # chosen, top ids, top log-probabilities of a block's places
+        return (jnp.zeros((S, B), jnp.float32), jnp.zeros((S, B, K), jnp.int32),
+                jnp.zeros((S, B, K), jnp.float32)) if want_logprobs else None
+
+    def head(H, x, hidden, pos, p):
+        """A branch of ``one_pass``'s switch: pass ``p``'s head and draws on
+        ``H`` rows a lane, spread back over ``[S, B]``: (the draws, their
+        confidences, the log-probability arrays or None). ``H`` 0, the clean
+        pass: zeros, which no one reads."""
+        if not H:
+            return jnp.zeros((S, B), jnp.int32), jnp.zeros((S, B), jnp.float32), blank_lp()
         with jax.named_scope("unmask"):
-            counters = ((pos[:, None] + place[None, :]) * steps + p).reshape(-1)
+            if H < B:
+                # slot j of a lane: its j-th hidden place (place 0 where it has fewer)
+                nth = jnp.cumsum(hidden, axis=1) - 1
+                at = hidden[:, None, :] & (nth[:, None, :] == place[None, :H, None])
+                slots = jnp.argmax(at, axis=2).astype(jnp.int32)               # [S, H]
+                rows = (lane[:, None] * B + slots).reshape(-1)
+                slot_of = jnp.clip(nth, 0, H - 1)
+
+                def spread(a):     # [S x H, ...] by slot -> [S, B, ...] by place
+                    a = a.reshape(S, H, *a.shape[1:])
+                    return jnp.take_along_axis(
+                        a, slot_of.reshape(S, B, *(1,) * (a.ndim - 2)), axis=1)
+            else:
+                slots, rows = jnp.broadcast_to(place, (S, B)), None
+                spread = lambda a: a.reshape(S, B, *a.shape[1:])  # noqa: E731
+        logits = block_logits(params, x, rows, cfg)                            # [S x H, V]
+        with jax.named_scope("unmask"):
+            counters = ((pos[:, None] + slots) * steps + p).reshape(-1)
+            seeds, temperature, top_k, top_p = (jnp.repeat(a, H) for a in lane_sampling)
             x0 = sample_seeded(
                 logits, seeds, counters, temperature, top_k, top_p,
                 need_mask=need_mask, all_greedy=all_greedy)
             lse = jax.scipy.special.logsumexp(logits, axis=-1)
             chosen = jnp.take_along_axis(logits, x0[:, None], axis=-1)[:, 0] - lse
+            lp = None
+            if want_logprobs:
+                top_lps, top_ids = jax.lax.top_k(logits, K)
+                lp = (spread(chosen), spread(top_ids.astype(jnp.int32)),
+                      spread(top_lps - lse[:, None]))
+            return spread(x0), spread(jnp.exp(chosen)), lp
+
+    heads = [partial(head, H) for H in hidden_at_most(B, steps)]
+
+    def one_pass(carry, p):
+        toks, hidden, step_of, lp, cache, pos, act, counts = carry
+        stats = _expert_stats_list(cfg)
+        x, cache = block_hidden(
+            params, cache, jnp.where(hidden, cfg.mask_token_id, toks),
+            block_tables, pos, act, cfg, engine, mesh, expert_stats=stats)
+        x0, conf, new = jax.lax.switch(p, heads, x, hidden, pos, p)
+        with jax.named_scope("unmask"):
+            # the clean pass finds no place of a live lane hidden and reveals none
             reveal, by_threshold = unmask_block(
-                jnp.exp(chosen).reshape(S, B), hidden, p,
-                steps=steps, threshold=cfg.confidence_threshold)
-            # the clean pass (p == steps) finds no place hidden and reveals none
-            reveal = reveal & act[:, None] & (p < steps)
-            toks = jnp.where(reveal, x0.reshape(S, B), toks)
+                conf, hidden, p, steps=steps, threshold=cfg.confidence_threshold)
+            reveal = reveal & act[:, None]
+            toks = jnp.where(reveal, x0, toks)
             step_of = jnp.where(reveal, p, step_of)
             hidden = hidden & ~reveal
             n = jnp.sum(reveal, axis=1)
@@ -742,9 +796,6 @@ def _megastep_blocks(
                 jnp.sum(jnp.where(by_threshold, n, 0)),
                 jnp.sum(jnp.where(by_threshold, 0, n))]).astype(jnp.int32)
             if want_logprobs:
-                top_lps, top_ids = jax.lax.top_k(logits, K)
-                new = (chosen.reshape(S, B), top_ids.astype(jnp.int32).reshape(S, B, K),
-                       (top_lps - lse[:, None]).reshape(S, B, K))
                 lp = tuple(jnp.where(reveal if a.ndim == 2 else reveal[..., None], a, old)
                            for a, old in zip(new, lp))
         return (toks, hidden, step_of, lp, cache, pos, act, counts), _expert_stats_sum(stats)
@@ -756,11 +807,10 @@ def _megastep_blocks(
         opens = (b == 0) & (known >= 0)
         toks = jnp.where(opens, known, 0)
         hidden = ~opens
-        lp = (jnp.zeros((S, B), jnp.float32), jnp.zeros((S, B, K), jnp.int32),
-              jnp.zeros((S, B, K), jnp.float32)) if want_logprobs else None
         (toks, _, step_of, lp, cache, _, _, counts), stats = jax.lax.scan(
             one_pass,
-            (toks, hidden, jnp.full((S, B), -1, jnp.int32), lp, cache, pos, act, counts),
+            (toks, hidden, jnp.full((S, B), -1, jnp.int32), blank_lp(), cache, pos, act,
+             counts),
             jnp.arange(steps + 1))
         with jax.named_scope("unmask"):
             # the places this block generated, in order: 1, 2, ... at its hidden places
@@ -1873,9 +1923,13 @@ class EngineCore:
             # a live lane a pass, by the pass's kind; blocks whose clean
             # pass moved a lane's cursor on; places revealed on the device,
             # by the rule that revealed them; places generated and not kept
-            # (after a cut, or in a block run past a stop only the host saw).
+            # (after a cut, or in a block run past a stop only the host saw);
+            # rows of those forwards, and the rows of them that went through
+            # the head and the sampler (sampler.hidden_at_most a lane a pass).
             "denoise_forwards": 0,
             "commit_forwards": 0,
+            "block_rows": 0,
+            "head_rows": 0,
             "blocks_committed": 0,
             "places_revealed_threshold": 0,
             "places_revealed_quota": 0,
@@ -4239,6 +4293,8 @@ class EngineCore:
             lane_blocks = int(ran[:, : len(ready)].sum())
             st["denoise_forwards"] += lane_blocks * steps
             st["commit_forwards"] += lane_blocks
+            st["block_rows"] += lane_blocks * (steps + 1) * B
+            st["head_rows"] += lane_blocks * sum(hidden_at_most(B, steps))
             live = {id(s) for s in self.running}
             emitted_total = kept_blocks = 0
             made = 0

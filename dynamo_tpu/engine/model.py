@@ -51,8 +51,9 @@ v5e, round 2):
   every earlier block and, both ways, its own. The rows of a batch are
   whole blocks (:func:`block_rows`), a block's rows fold into the GQA
   group of ONE decode-shaped attention call (``ops/ragged_attention.py``,
-  ``block_attention``), in a step's pass (:func:`block_tokens`) and in a
-  prefill wave alike; pages, allocator and prefix index see nothing new.
+  ``block_attention``), in a step's pass (:func:`block_hidden`, and
+  :func:`block_logits` on the rows whose places can still be hidden) and in
+  a prefill wave alike; pages, allocator and prefix index see nothing new.
 """
 
 from __future__ import annotations
@@ -2058,7 +2059,7 @@ def block_rows(positions, block_tables, cu_q_lens, num_seqs, B: int):
             (cu_q_lens[num_seqs[0]] // B).reshape(1).astype(jnp.int32))
 
 
-def block_tokens(
+def block_hidden(
     params: Params,
     cache: jax.Array,
     tokens: jax.Array,        # [S, B] i32 — a block a lane, mask tokens where hidden
@@ -2070,14 +2071,16 @@ def block_tokens(
     mesh=None,
     expert_stats: list | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """One pass of a block-diffusion step: every lane's block of ``B =
-    cfg.block_length`` places through the stack, each row seeing its block
-    both ways beside the lane's causal past. Returns (``[S x B, vocab]``
-    logits, a row a place; cache). The block's K/V are WRITTEN, at its own
-    positions, every pass: a denoising pass's are overwritten by the next
-    and the clean pass's stay (nothing reads them in between but the pass
-    that wrote them). Dead lanes write the garbage page. Thin assembly
-    over :func:`forward_tokens`, as :func:`verify_tokens`."""
+    """One pass of a block-diffusion step up to the final norm: every lane's
+    block of ``B = cfg.block_length`` places through the stack, each row
+    seeing its block both ways beside the lane's causal past. Returns
+    (``[S x B, h]`` normed hidden states, a row a place; cache) and NO
+    logits: the clean pass of a block is this and nothing more. The block's
+    K/V are WRITTEN, at its own positions, every pass: a denoising pass's
+    are overwritten by the next and the clean pass's stay (nothing reads
+    them in between but the pass that wrote them). Dead lanes write the
+    garbage page. Thin assembly over :func:`forward_hidden`, as
+    :func:`verify_tokens` is over :func:`forward_tokens`."""
     S, B = tokens.shape
     bs = engine.block_size
     with jax.named_scope("kv_write"):
@@ -2089,12 +2092,22 @@ def block_tokens(
         kv_lens = (positions + B).astype(jnp.int32)
         cu = B * jnp.arange(S + 1, dtype=jnp.int32)
         num_seqs = jnp.array([S], jnp.int32)
-        rows = jnp.arange(S * B, dtype=jnp.int32)
-    return forward_tokens(
+    return forward_hidden(
         params, cache, tokens.reshape(-1), pos.reshape(-1), write_pages,
-        write_offs, kv_lens, block_tables, cu, num_seqs, rows, cfg,
-        engine, mesh, expert_stats=expert_stats, block_shape="block-decode",
+        write_offs, kv_lens, block_tables, cu, num_seqs, cfg, engine, mesh,
+        expert_stats=expert_stats, block_shape="block-decode",
     )
+
+
+def block_logits(params: Params, hidden: jax.Array, rows: jax.Array | None,
+                 cfg: ModelConfig) -> jax.Array:
+    """The head of a block pass on the rows asked for: ``[len(rows), vocab]``
+    float32 logits of ``hidden[rows]`` (:func:`block_hidden`'s ``[S x B,
+    h]``), or of every row, a row a place, with ``rows`` None. A denoising
+    pass asks for the places that can still be hidden, the clean pass for
+    none (it does not come here): ``core._megastep_blocks``."""
+    with jax.named_scope("lm_head"):
+        return _logits(hidden if rows is None else hidden[rows], params, cfg)
 
 
 def decode_tokens(

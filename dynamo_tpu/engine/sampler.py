@@ -269,6 +269,27 @@ def token_logprobs(
     return chosen, top_ids.astype(jnp.int32), top_lps
 
 
+def step_quota(B: int, steps: int, step):
+    """The places of a block of ``B`` a denoising step reveals at the least:
+    ``B // steps``, one more in the first ``B % steps`` steps (``step``
+    0-based, a Python int or a traced scalar)."""
+    return B // steps + (step < B % steps)
+
+
+def hidden_at_most(B: int, steps: int) -> tuple[int, ...]:
+    """``H_p`` for ``p = 0 .. steps``: the most places of a block of ``B``
+    that can still be hidden when pass ``p`` starts, ``B`` less the quotas
+    of the steps before it (:func:`unmask_block` reveals a step's quota, or
+    every hidden place where they are fewer): 4, 2, 0 at ``B`` 4 and 2
+    steps; 4, 3, 2, 1, 0 at 4. The last, 0, is the clean pass's. A pass
+    needs the head and the sampler on no more rows a lane than that
+    (``core._megastep_blocks``)."""
+    out = [B]
+    for step in range(steps):
+        out.append(out[-1] - step_quota(B, steps, step))
+    return tuple(out)
+
+
 def unmask_block(
     conf: jax.Array,        # [S, B] float32: confidence of each place's sample
     hidden: jax.Array,      # [S, B] bool: places not yet revealed
@@ -288,7 +309,7 @@ def unmask_block(
     of a prompt's tail) reveals them all. ``by_threshold`` says which of
     the two rules a lane's reveal came by."""
     B = conf.shape[1]
-    quota = B // steps + (step < B % steps).astype(jnp.int32)
+    quota = step_quota(B, steps, step)
     over = hidden & (conf > threshold)
     c = jnp.where(hidden, conf, -jnp.inf)
     place = jnp.arange(B, dtype=jnp.int32)

@@ -288,6 +288,17 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
         "Blocks of a block-diffusion model of which a client was sent at "
         "least one place (a block cut by a stop counts)",
     ),
+    "block_rows": (
+        "engine_block_rows",
+        "Rows of a block-diffusion model's forwards: block_length a live "
+        "lane a pass, the clean pass included",
+    ),
+    "head_rows": (
+        "engine_block_head_rows",
+        "Of those rows, the ones that went through the head and the "
+        "sampler: a pass's places that could still be hidden, none in the "
+        "clean pass",
+    ),
     "block_places_discarded": (
         "engine_block_places_discarded",
         "Places a block-diffusion model generated and no client was sent: "

@@ -90,10 +90,10 @@ def test_a_window_models_worker_is_held_to_its_window():
             "aggregated", {"decode/library": 3.0, "window-decode/reference": 6.0}, "tpu")
 
 
-def test_a_block_models_worker_is_held_to_its_block_calls():
+def test_a_block_models_worker_is_held_to_its_block_calls(monkeypatch):
     """``judge_blocks``: a worker that says it generates by blocks must have
     traced a block-decode call and no causal one; on a TPU never the jnp
-    reference."""
+    reference; and its head ran on fewer rows than its block passes did."""
     import chip_smoke
 
     startup = {"block_length": 4, "denoising_steps": 2, "megastep_k": 6}
@@ -108,3 +108,10 @@ def test_a_block_models_worker_is_held_to_its_block_calls():
     with pytest.raises(chip_smoke.PhaseFailed, match="reference on a TPU"):
         chip_smoke.judge_attention_traced(
             "aggregated", {"block-decode/reference": 24.0}, "tpu")
+    rows = {"dynamo_engine_block_head_rows_total{": 600.0, "dynamo_engine_block_rows_total{": 1200.0}
+    monkeypatch.setattr(chip_smoke, "metric_lines",
+                        lambda url, prefix: [f'{prefix}service="engine"}} {rows[prefix]}'])
+    chip_smoke.judge_blocks("aggregated", startup, traced, "http://worker/health")
+    rows["dynamo_engine_block_head_rows_total{"] = 1200.0
+    with pytest.raises(chip_smoke.PhaseFailed, match="1200 of 1200 block rows"):
+        chip_smoke.judge_blocks("aggregated", startup, traced, "http://worker/health")

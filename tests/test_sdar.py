@@ -33,9 +33,15 @@ from dynamo_tpu.engine.config import (
     tiny_moe,
     tiny_sdar,
 )
-from dynamo_tpu.engine.core import EngineCore, _resolve_block_megastep
-from dynamo_tpu.engine.model import init_params
-from dynamo_tpu.engine.sampler import unmask_block
+from dynamo_tpu.engine import core as core_mod
+from dynamo_tpu.engine.core import EngineCore, _megastep_blocks, _resolve_block_megastep
+from dynamo_tpu.engine.model import block_hidden, block_logits, init_cache, init_params
+from dynamo_tpu.engine.sampler import (
+    LOGPROBS_K,
+    hidden_at_most,
+    sample_seeded,
+    unmask_block,
+)
 from dynamo_tpu.llm.protocols.common import (
     FinishReason,
     OutputOptions,
@@ -336,6 +342,256 @@ def test_a_prefix_hit_on_a_page_of_generated_blocks():
     verdict, got = held_to_reference(core, FILE, {"prompt_ids": longer, "max_tokens": 9, "top": 5})
     assert verdict["ok"] and verdict["max_abs_diff"] < TIGHT
     assert got["served"][0]["cached_tokens"] == 32
+
+
+# -- the head and the sampler only where a place can still be hidden -----------------
+
+def _plain_megastep_blocks(params, cache, lanes, block_tables, known, *, n_steps, need_mask,
+                           all_greedy, want_logprobs, cfg, engine):
+    """The block megastep in its plain form, kept here as what
+    ``core._megastep_blocks`` is held to: ONE scanned body for every pass,
+    the clean pass too, with the head and the sampler on every row of it."""
+    B, steps = cfg.block_length, cfg.denoising_steps
+    n_blocks, S, K = n_steps // (steps + 1), lanes.shape[0], LOGPROBS_K
+    f32 = lambda col: jax.lax.bitcast_convert_type(lanes[:, col], jnp.float32)  # noqa: E731
+    position, active = lanes[:, core_mod._L_POSITION], lanes[:, core_mod._L_ACTIVE] != 0
+    seeds = jnp.repeat(lanes[:, core_mod._L_SEED], B)
+    temperature, top_k, top_p = (
+        jnp.repeat(f32(core_mod._L_TEMPERATURE), B), jnp.repeat(lanes[:, core_mod._L_TOP_K], B),
+        jnp.repeat(f32(core_mod._L_TOP_P), B))
+    watch, min_left = lanes[:, core_mod._L_WATCH:], lanes[:, core_mod._L_MIN_LEFT]
+    place = jnp.arange(B, dtype=jnp.int32)
+
+    def one_pass(carry, p):
+        toks, hidden, step_of, lp, cache, pos, act, counts = carry
+        x, cache = block_hidden(params, cache, jnp.where(hidden, cfg.mask_token_id, toks),
+                                block_tables, pos, act, cfg, engine)
+        logits = block_logits(params, x, None, cfg)
+        counters = ((pos[:, None] + place[None, :]) * steps + p).reshape(-1)
+        x0 = sample_seeded(logits, seeds, counters, temperature, top_k, top_p,
+                           need_mask=need_mask, all_greedy=all_greedy)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        chosen = jnp.take_along_axis(logits, x0[:, None], axis=-1)[:, 0] - lse
+        reveal, by_threshold = unmask_block(
+            jnp.exp(chosen).reshape(S, B), hidden, p,
+            steps=steps, threshold=cfg.confidence_threshold)
+        reveal = reveal & act[:, None] & (p < steps)
+        toks = jnp.where(reveal, x0.reshape(S, B), toks)
+        step_of = jnp.where(reveal, p, step_of)
+        hidden = hidden & ~reveal
+        n = jnp.sum(reveal, axis=1)
+        counts = counts + jnp.stack([
+            jnp.sum(jnp.where(by_threshold, n, 0)),
+            jnp.sum(jnp.where(by_threshold, 0, n))]).astype(jnp.int32)
+        if want_logprobs:
+            top_lps, top_ids = jax.lax.top_k(logits, K)
+            new = (chosen.reshape(S, B), top_ids.astype(jnp.int32).reshape(S, B, K),
+                   (top_lps - lse[:, None]).reshape(S, B, K))
+            lp = tuple(jnp.where(reveal if a.ndim == 2 else reveal[..., None], a, old)
+                       for a, old in zip(new, lp))
+        return (toks, hidden, step_of, lp, cache, pos, act, counts), None
+
+    def one_block(carry, b):
+        cache, pos, alive, budget, floor, counts = carry
+        act = active & alive
+        opens = (b == 0) & (known >= 0)
+        toks = jnp.where(opens, known, 0)
+        hidden = ~opens
+        lp = (jnp.zeros((S, B), jnp.float32), jnp.zeros((S, B, K), jnp.int32),
+              jnp.zeros((S, B, K), jnp.float32)) if want_logprobs else None
+        (toks, _, step_of, lp, cache, _, _, counts), _ = jax.lax.scan(
+            one_pass,
+            (toks, hidden, jnp.full((S, B), -1, jnp.int32), lp, cache, pos, act, counts),
+            jnp.arange(steps + 1))
+        ordinal = jnp.cumsum(hidden, axis=1) * hidden
+        hit = (toks[:, :, None] == watch[:, None, :]).any(axis=2) & hidden & (
+            ordinal >= floor[:, None])
+        made = jnp.sum(hidden, axis=1)
+        budget, floor = budget - made, floor - made
+        alive = alive & ~hit.any(axis=1) & (budget > 0)
+        pos = pos + B * act.astype(jnp.int32)
+        return (cache, pos, alive, budget, floor, counts), (toks, step_of, lp, act)
+
+    (cache, _, _, _, _, counts), (tokens, step_of, lps, ran) = jax.lax.scan(
+        one_block,
+        (cache, position, jnp.ones_like(active), lanes[:, core_mod._L_BUDGET], min_left,
+         jnp.zeros(2, jnp.int32)),
+        jnp.arange(n_blocks))
+    aux = jnp.concatenate([step_of.reshape(-1), ran.astype(jnp.int32).reshape(-1), counts])
+    return tokens, lps, cache, None, aux
+
+
+def _megastep_inputs(cfg, engine, temperature, masked, seed=0):
+    """Eight lanes at three blocks a dispatch: a plain lane, three whose first
+    block a prompt's tail opens with 1-3 known places, one that is not live,
+    one whose budget ends inside the second block and one that stops on a
+    watched id (both dead for the blocks after), one more plain; a cache of
+    noise, so that a block's rows read a past."""
+    S, B, pages = 8, cfg.block_length, 6
+    rs = np.random.RandomState(seed)
+    params = init_params(jax.random.PRNGKey(seed), cfg)
+    cache = tuple(jnp.asarray(rs.randn(*c.shape), c.dtype) for c in init_cache(cfg, engine))
+    tables = jnp.asarray(np.arange(S * pages).reshape(S, pages), jnp.int32)
+    watch = np.full((S, core_mod.MEGASTEP_WATCH_W), -1, np.int32)
+    watch[6, :] = np.arange(40, 40 + core_mod.MEGASTEP_WATCH_W * 40, 40)   # some id will come
+    lanes = core_mod.pack_lanes(
+        tokens=np.zeros(S, np.int32), feed_idx=None,
+        positions=np.asarray([8, 16, 12, 8, 8, 20, 8, 24], np.int32),
+        active=np.asarray([1, 1, 1, 1, 0, 1, 1, 1], np.int32),
+        seeds=np.arange(11, 11 + S, dtype=np.int32), counters=np.zeros(S, np.int32),
+        temperature=np.full(S, temperature, np.float32),
+        top_k=np.full(S, 20 if masked else 0, np.int32),
+        top_p=np.full(S, 0.9 if masked else 1.0, np.float32), watch=watch,
+        budgets=np.asarray([99, 99, 99, 99, 99, 6, 99, 99], np.int32),
+        min_left=np.zeros(S, np.int32))
+    known = np.full((S, B), -1, np.int32)
+    for lane, tail in ((1, 1), (2, 2), (3, 3)):
+        known[lane, :tail] = rs.randint(1, 380, size=tail)
+    return params, cache, jnp.asarray(lanes), tables, jnp.asarray(known)
+
+
+@pytest.mark.parametrize("steps,temperature,threshold,want_lp", [
+    (steps, temperature, threshold, True)
+    for steps in (1, 2, 4) for temperature in (0.0, 0.7) for threshold in (0.9, 0.004)
+] + [(2, 0.7, 0.9, False), (4, 0.0, 0.004, False)])
+def test_the_megastep_is_bit_equal_to_its_plain_form(steps, temperature, threshold, want_lp):
+    """Tokens, the step that revealed each place, the lanes that ran, the two
+    reveal counts, the log-probabilities and every page of the cache: the
+    same bits as with the head and the sampler on every row of every pass.
+    A threshold of 0.004 reveals MORE than the quota (a pass then finds
+    fewer hidden places than its slots); seeded lanes ask for top-k / top-p
+    where the threshold fires, so both samplers are held."""
+    cfg = tiny_sdar(denoising_steps=steps, confidence_threshold=threshold)
+    engine = tiny_engine(block_size=8)
+    masked = temperature > 0 and threshold < 0.5
+    params, cache, lanes, tables, known = _megastep_inputs(cfg, engine, temperature, masked)
+    static = dict(n_steps=3 * (steps + 1), need_mask=masked, all_greedy=temperature == 0,
+                  want_logprobs=want_lp, cfg=cfg, engine=engine)
+    got = jax.jit(lambda *a: _megastep_blocks(*a, **static))(params, cache, lanes, tables, known)
+    want = jax.jit(lambda *a: _plain_megastep_blocks(*a, **static))(
+        params, cache, lanes, tables, known)
+    S, B = known.shape
+    aux = np.asarray(want[4])
+    ran = aux[3 * S * B: -2].reshape(3, S)
+    assert ran[:, 4].sum() == 0 and ran[:, 0].all()            # the idle lane; a plain one
+    assert ran[:, 5].tolist() == [1, 1, 0]                       # the budget ended in block 2
+    assert (aux[-2] > 0) == (threshold < 0.5) and aux[-2] + aux[-1] > 0
+    if threshold < 0.5 and steps > 1:     # some first pass revealed MORE than its quota
+        first = (aux[: 3 * S * B].reshape(3, S, B) == 0).sum(axis=2)
+        assert first.max() > hidden_at_most(B, steps)[0] - hidden_at_most(B, steps)[1]
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[4]), aux)
+    if want_lp:
+        for a, b in zip(got[1], want[1]):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    else:
+        assert got[1] is None and want[1] is None
+    for a, b in zip(got[2], want[2]):     # but the garbage page, where dead lanes' rows collide
+        np.testing.assert_array_equal(np.asarray(a)[:-1], np.asarray(b)[:-1])
+    # the sparse layers' counts are summed over every pass, the clean pass's too
+    assert got[3].shape == (5,) and int(got[3][1]) == 3 * (steps + 1) * cfg.num_layers
+
+
+@pytest.mark.parametrize("B,steps,want", [
+    (4, 2, (4, 2, 0)), (4, 4, (4, 3, 2, 1, 0)), (4, 1, (4, 0)), (4, 3, (4, 2, 1, 0)),
+    (8, 3, (8, 5, 2, 0)), (8, 8, (8, 7, 6, 5, 4, 3, 2, 1, 0))])
+def test_the_bound_on_hidden_places_is_unmask_blocks_quota(B, steps, want):
+    """``hidden_at_most`` against what ``unmask_block`` leaves hidden where
+    the threshold never fires: step by step the same counts, and a lane that
+    opened with known places stays under them."""
+    assert hidden_at_most(B, steps) == want
+    rs = np.random.RandomState(B * 10 + steps)
+    hidden = jnp.asarray(np.stack([np.ones(B, bool), np.arange(B) >= 1, np.arange(B) >= B - 1]))
+    for p in range(steps):
+        assert int(hidden[0].sum()) == want[p]
+        assert all(int(n) <= want[p] for n in hidden.sum(axis=1))
+        reveal, _ = unmask_block(jnp.asarray(rs.rand(3, B), jnp.float32), hidden, jnp.int32(p),
+                                 steps=steps, threshold=2.0)
+        again, _ = unmask_block(jnp.asarray(rs.rand(3, B), jnp.float32), hidden, p,
+                                steps=steps, threshold=2.0)     # a step known at trace time
+        assert reveal.sum(axis=1).tolist() == again.sum(axis=1).tolist()
+        hidden = hidden & ~reveal
+    assert not bool(hidden.any())
+
+
+def _head_products(jaxpr, vocab: int) -> list[int]:
+    """The row count of each product whose result is ``vocab`` wide, sub-programs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and eqn.outvars[0].aval.shape[-1] == vocab:
+            found.append(int(np.prod(eqn.outvars[0].aval.shape[:-1])))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _head_products(sub, vocab)
+    return found
+
+
+def _switches(jaxpr, branches: int) -> list:
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond" and len(eqn.params["branches"]) == branches:
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _switches(sub, branches)
+    return found
+
+
+@pytest.mark.parametrize("steps", [1, 2, 4])
+def test_a_pass_has_a_head_of_its_hidden_places_and_the_clean_pass_none(steps):
+    """The one product with the vocabulary a pass has lies in the branch the
+    pass's number picks: ``S x H_p`` rows in pass ``p``, none in the clean
+    pass, and none outside the switch."""
+    cfg = tiny_sdar(denoising_steps=steps)
+    engine = tiny_engine(block_size=8)
+    args = _megastep_inputs(cfg, engine, 0.7, False)
+    S = args[2].shape[0]
+    jaxpr = jax.make_jaxpr(lambda *a: _megastep_blocks(
+        *a, n_steps=2 * (steps + 1), need_mask=False, all_greedy=False, want_logprobs=True,
+        cfg=cfg, engine=engine))(*args).jaxpr
+    (switch,) = _switches(jaxpr, steps + 1)
+    by_pass = [_head_products(branch.jaxpr, cfg.vocab_size) for branch in switch.params["branches"]]
+    assert by_pass == [[S * H] if H else [] for H in hidden_at_most(cfg.block_length, steps)]
+    assert by_pass[-1] == [] and sorted(_head_products(jaxpr, cfg.vocab_size)) == sorted(
+        sum(by_pass, []))
+
+
+# what the parent commit (PR 42) served, the engine of ``make_core()``, PROMPT[:30], 21 tokens
+PARENT_GREEDY = [178, 241, 332, 244, 244, 30, 132, 241, 241, 178, 175, 241, 232, 168, 232, 283, 283,
+                 293, 241, 241, 156]
+PARENT_SEEDED = [241, 125, 241, 244, 150, 381, 246, 144, 381, 283, 343, 155, 233, 232, 306, 283, 175,
+                 293, 125, 241, 109]
+
+
+@pytest.mark.parametrize("sampling,want", [
+    (dict(temperature=0.0), PARENT_GREEDY),
+    (dict(temperature=0.8, top_k=20, seed=7), PARENT_SEEDED)], ids=["greedy", "seeded"])
+def test_served_tokens_are_the_parent_commits(sampling, want):
+    core = make_core()
+    seq = core.add_request(PreprocessedRequest(
+        model="m", token_ids=PROMPT[:30], request_id="t", sampling=SamplingOptions(**sampling),
+        stop=StopConditions(max_tokens=21, ignore_eos=True), output=OutputOptions()))
+    assert run_to_completion(core, [seq])[0]["t"] == want
+
+
+def test_the_rows_that_went_through_the_head_are_counted():
+    from chipbench.readers import prometheus
+    from dynamo_tpu.runtime.metrics import MetricsRegistry
+    from dynamo_tpu.runtime.status_server import _EngineCounters
+
+    for steps in (2, 4):
+        core = make_core(file_with(steps))
+        seq = core.add_request(_req(PROMPT[:34], "c", 25, ignore_eos=True))
+        run_to_completion(core, [seq])
+        st = core.exec_stats
+        B = core.cfg.block_length
+        assert st["denoise_forwards"] == steps * st["commit_forwards"] > 0
+        assert st["block_rows"] == (st["denoise_forwards"] + st["commit_forwards"]) * B
+        assert st["head_rows"] * (steps + 1) * B == st["block_rows"] * sum(hidden_at_most(B, steps))
+        assert st["head_rows"] * 2 == st["block_rows"]      # 6 of 12 rows; 10 of 20
+    registry = MetricsRegistry()
+    registry.registry.register(_EngineCounters(core.step_phase_seconds, core.scheduler_stats))
+    text = registry.render().decode()
+    assert prometheus.total([text], "dynamo_engine_block_head_rows_total") == st["head_rows"]
+    assert prometheus.total([text], "dynamo_engine_block_rows_total") == st["block_rows"]
 
 
 # -- the pieces -------------------------------------------------------------------
