@@ -165,6 +165,11 @@ class Sequence:
     # are held back from the prefill wave and open the first block as known
     # places (EngineCore._plan_blocks). 0 for every other model.
     tail: int = 0
+    # A block-diffusion lane's PENDING block: the newest block's ``B`` tokens,
+    # revealed and streamed, whose final K/V (its clean rows) ride the first
+    # pass of the lane's next block. They lie past the cursor (``processed``)
+    # until then; a lane that ends, is cancelled or is preempted drops them.
+    pending_block: list[int] = field(default_factory=list)
 
     @property
     def prompt_len(self) -> int:
@@ -609,7 +614,7 @@ def _expert_stats_sum(stats: list | None):
 
 
 def _megastep_body(
-    params, cache, lanes, block_tables, feed,
+    params, cache, lanes, block_tables, feed, known=None,
     *, n_steps, need_mask, all_greedy=False, want_logprobs=False,
     cfg, engine, mesh=None,
 ):
@@ -635,10 +640,11 @@ def _megastep_body(
     dispatch.
 
     The per-lane inputs arrive packed (:func:`pack_lanes`) beside the
-    block tables and the feedback source."""
+    block tables and the feedback source (and, for a block-diffusion model
+    alone, ``known``: :func:`_megastep_blocks`)."""
     if cfg.block_length:
         return _megastep_blocks(
-            params, cache, lanes, block_tables, feed, n_steps=n_steps,
+            params, cache, lanes, block_tables, feed, known, n_steps=n_steps,
             need_mask=need_mask, all_greedy=all_greedy,
             want_logprobs=want_logprobs, cfg=cfg, engine=engine, mesh=mesh)
     (tokens, positions, active, seeds, counters, temperature, top_k, top_p,
@@ -677,41 +683,59 @@ def _megastep_body(
 
 
 def _megastep_blocks(
-    params, cache, lanes, block_tables, known,
+    params, cache, lanes, block_tables, feed, known,
     *, n_steps, need_mask, all_greedy, want_logprobs, cfg, engine, mesh=None,
 ):
     """:func:`_megastep_body` of a block-diffusion model
-    (``cfg.block_length = B > 0``): a scanned iteration is ONE forward over
-    ``B`` rows a lane (model.block_hidden), and a dispatch runs ``n_steps /
-    (steps + 1)`` whole blocks, ``steps = cfg.denoising_steps`` denoising
-    passes and a clean pass each. A block starts as ``cfg.mask_token_id``
-    at its hidden places (``known`` ``[S, B]``: a prompt's tail opens a
-    lane's FIRST block of the dispatch as known places, -1 where hidden;
-    every later block is all hidden). A denoising pass samples each hidden
-    place from its OWN row with the request's sampler (key ``(seed,
-    position x steps + step)``: a place's draw is the same whatever
-    neighbours, preemption or blocks a dispatch it meets), takes the
-    sample's probability under the raw row as its confidence, and reveals
-    by ``sampler.unmask_block``. Every pass writes the block's K/V at the
-    block's own positions; the clean pass's stay, and the lane's cursor
-    moves ``B`` on. Hidden places are a MASK OF PLACES, never ``id ==
+    (``cfg.block_length = B > 0``): a scanned iteration is ONE forward
+    (model.block_hidden), and a dispatch runs ``n_steps / (steps + 1)`` whole
+    blocks a lane, ``steps = cfg.denoising_steps`` denoising passes each. A
+    block starts as ``cfg.mask_token_id`` at its hidden places (``known[:,
+    0]`` ``[S, B]``: a prompt's tail opens a lane's FIRST block of the
+    dispatch as known places, -1 where hidden; every later block is all
+    hidden). A denoising pass samples each hidden place from its OWN row with
+    the request's sampler (key ``(seed, position x steps + step)``: a place's
+    draw is the same whatever neighbours, preemption or blocks a dispatch it
+    meets), takes the sample's probability under the raw row as its
+    confidence, and reveals by ``sampler.unmask_block``; the last step
+    reveals what is left. Hidden places are a MASK OF PLACES, never ``id ==
     mask_token_id``: a prompt token with that id is a known token.
+
+    **Where a block's K/V become final: in the NEXT block's first pass.** The
+    K/V a block leaves are those of its revealed tokens, each seeing the
+    block both ways over the causal past: its CLEAN rows. They are no pass
+    of their own (that pass streamed every touched expert and the lane's
+    past for nothing else): pass 0 of the lane's next block runs them beside
+    that block's places (model.block_hidden's ``pending``), in this dispatch
+    or the next, and that pass reads the same weights anyway. Every pass has
+    the one static shape ``[S current blocks | S pending blocks]``; in the
+    passes after a block's first the pending half is dead. So a lane always
+    has at most one block whose clean rows have yet to run, its PENDING
+    block: the dispatch's last block of a live lane comes back pending, and
+    the next dispatch's first pass is handed it: from the output of the
+    dispatch in flight where the host has not seen it yet (``feed``, flat
+    and padded, the lane's ``_L_FEED`` column naming the first place of its
+    last block there, as a next-token lane's names its token), else from the
+    host (``known[:, 1]``, -1 where the lane has none: fresh from a wave).
+    A lane the device sees end (a stop, its budget) is dead for the blocks
+    after, and its last block's clean rows are never run: no one will read
+    them. The host's cursor moves over a block when its clean rows have run
+    and not before (``EngineCore._plan_blocks``).
 
     **Which pass runs the head on which rows.** Only a hidden place's logits,
     draw and confidence are ever read, and after ``p`` steps a live lane
-    holds at most ``H_p`` hidden places (``sampler.hidden_at_most``: 4, 2, 0
-    at ``B`` 4 and 2 steps). So pass ``p`` hands the head and the sampler
-    ``S x H_p`` rows: each lane's hidden places in ascending order (spare
-    slots, where a prompt's tail or the threshold left fewer, point at the
-    lane's place 0 and are read by no one; a dead lane reveals nothing, so
-    any ``H_p`` of its places do), and spreads the draws back over ``[S,
-    B]``. Pass 0 (``H_0 = B``) takes every row as it lies; the clean pass
-    (``H = 0``) stops at the final norm: it writes the K/V, counts its
-    experts, and has no head, no draw, no confidence. The passes stay ONE
-    scanned body, the stack once a program, and ``lax.switch`` on the
-    pass's number picks the head of that pass's shape: written out, the
-    three passes cost the v5e a head computed three times a pass (XLA
-    rematerialised the logits) and 15 s of set-up (PERF.md section 6, PR 43).
+    holds at most ``H_p`` hidden places (``sampler.hidden_at_most``: 4, 2 at
+    ``B`` 4 and 2 steps). So pass ``p`` hands the head and the sampler ``S x
+    H_p`` rows of the CURRENT half: each lane's hidden places in ascending
+    order (spare slots, where a prompt's tail or the threshold left fewer,
+    point at the lane's place 0 and are read by no one; a dead lane reveals
+    nothing, so any ``H_p`` of its places do), and spreads the draws back
+    over ``[S, B]``. Pass 0 (``H_0 = B``) takes every row as it lies. The
+    pending half has no head, no draw, no confidence. The passes stay ONE
+    scanned body, the stack once a program, and ``lax.switch`` on the pass's
+    number picks the head of that pass's shape: written out, the passes cost
+    the v5e a head computed once a copy a pass (XLA rematerialised the
+    logits) and 15 s of set-up (PERF.md section 6, PR 43).
 
     A lane goes dead for the dispatch's later blocks once its budget of
     places to generate is spent or a revealed place holds a watched id (past
@@ -730,6 +754,11 @@ def _megastep_blocks(
     watch, min_left = lanes[:, _L_WATCH:], lanes[:, _L_MIN_LEFT]
     lane, place = jnp.arange(S, dtype=jnp.int32), jnp.arange(B, dtype=jnp.int32)
     K = LOGPROBS_K
+    # each lane's pending block as the dispatch starts: the dispatch in
+    # flight's output where the lane names a place of it, else the host's
+    fed = lanes[:, _L_FEED, None]
+    pending = gather_feedback(feed, known[:, 1], jnp.where(fed >= 0, fed + place[None, :], -1))
+    known = known[:, 0]
 
     def blank_lp():   # chosen, top ids, top log-probabilities of a block's places
         return (jnp.zeros((S, B), jnp.float32), jnp.zeros((S, B, K), jnp.int32),
@@ -738,10 +767,7 @@ def _megastep_blocks(
     def head(H, x, hidden, pos, p):
         """A branch of ``one_pass``'s switch: pass ``p``'s head and draws on
         ``H`` rows a lane, spread back over ``[S, B]``: (the draws, their
-        confidences, the log-probability arrays or None). ``H`` 0, the clean
-        pass: zeros, which no one reads."""
-        if not H:
-            return jnp.zeros((S, B), jnp.int32), jnp.zeros((S, B), jnp.float32), blank_lp()
+        confidences, the log-probability arrays or None)."""
         with jax.named_scope("unmask"):
             if H < B:
                 # slot j of a lane: its j-th hidden place (place 0 where it has fewer)
@@ -774,44 +800,44 @@ def _megastep_blocks(
                       spread(top_lps - lse[:, None]))
             return spread(x0), spread(jnp.exp(chosen)), lp
 
-    heads = [partial(head, H) for H in hidden_at_most(B, steps)]
-
-    def one_pass(carry, p):
-        toks, hidden, step_of, lp, cache, pos, act, counts = carry
-        stats = _expert_stats_list(cfg)
-        x, cache = block_hidden(
-            params, cache, jnp.where(hidden, cfg.mask_token_id, toks),
-            block_tables, pos, act, cfg, engine, mesh, expert_stats=stats)
-        x0, conf, new = jax.lax.switch(p, heads, x, hidden, pos, p)
-        with jax.named_scope("unmask"):
-            # the clean pass finds no place of a live lane hidden and reveals none
-            reveal, by_threshold = unmask_block(
-                conf, hidden, p, steps=steps, threshold=cfg.confidence_threshold)
-            reveal = reveal & act[:, None]
-            toks = jnp.where(reveal, x0, toks)
-            step_of = jnp.where(reveal, p, step_of)
-            hidden = hidden & ~reveal
-            n = jnp.sum(reveal, axis=1)
-            counts = counts + jnp.stack([
-                jnp.sum(jnp.where(by_threshold, n, 0)),
-                jnp.sum(jnp.where(by_threshold, 0, n))]).astype(jnp.int32)
-            if want_logprobs:
-                lp = tuple(jnp.where(reveal if a.ndim == 2 else reveal[..., None], a, old)
-                           for a, old in zip(new, lp))
-        return (toks, hidden, step_of, lp, cache, pos, act, counts), _expert_stats_sum(stats)
+    heads = [partial(head, H) for H in hidden_at_most(B, steps)[:steps]]
 
     def one_block(carry, b):
-        cache, pos, alive, budget, floor, counts = carry
+        cache, pos, alive, budget, floor, counts, clean = carry
         act = active & alive
+
+        def one_pass(carry, p):
+            toks, hidden, step_of, lp, cache, counts = carry
+            stats = _expert_stats_list(cfg)
+            x, cache = block_hidden(
+                params, cache, jnp.where(hidden, cfg.mask_token_id, toks),
+                block_tables, pos, act, cfg, engine, mesh, expert_stats=stats,
+                pending=jnp.where(p == 0, clean, -1))
+            x0, conf, new = jax.lax.switch(p, heads, x, hidden, pos, p)
+            with jax.named_scope("unmask"):
+                reveal, by_threshold = unmask_block(
+                    conf, hidden, p, steps=steps, threshold=cfg.confidence_threshold)
+                reveal = reveal & act[:, None]
+                toks = jnp.where(reveal, x0, toks)
+                step_of = jnp.where(reveal, p, step_of)
+                hidden = hidden & ~reveal
+                n = jnp.sum(reveal, axis=1)
+                counts = counts + jnp.stack([
+                    jnp.sum(jnp.where(by_threshold, n, 0)),
+                    jnp.sum(jnp.where(by_threshold, 0, n))]).astype(jnp.int32)
+                if want_logprobs:
+                    lp = tuple(jnp.where(reveal if a.ndim == 2 else reveal[..., None], a, old)
+                               for a, old in zip(new, lp))
+            return (toks, hidden, step_of, lp, cache, counts), _expert_stats_sum(stats)
+
         # the dispatch's first block may open with known places (a prompt's tail)
         opens = (b == 0) & (known >= 0)
         toks = jnp.where(opens, known, 0)
         hidden = ~opens
-        (toks, _, step_of, lp, cache, _, _, counts), stats = jax.lax.scan(
+        (toks, _, step_of, lp, cache, counts), stats = jax.lax.scan(
             one_pass,
-            (toks, hidden, jnp.full((S, B), -1, jnp.int32), blank_lp(), cache, pos, act,
-             counts),
-            jnp.arange(steps + 1))
+            (toks, hidden, jnp.full((S, B), -1, jnp.int32), blank_lp(), cache, counts),
+            jnp.arange(steps))
         with jax.named_scope("unmask"):
             # the places this block generated, in order: 1, 2, ... at its hidden places
             ordinal = jnp.cumsum(hidden, axis=1) * hidden
@@ -823,12 +849,14 @@ def _megastep_blocks(
             pos = pos + B * act.astype(jnp.int32)
         if stats is not None:
             stats = jnp.sum(stats, axis=0)
-        return (cache, pos, alive, budget, floor, counts), (toks, step_of, lp, act, stats)
+        # this block is the lane's pending one now; a lane that did not run it has none
+        return ((cache, pos, alive, budget, floor, counts, jnp.where(act[:, None], toks, -1)),
+                (toks, step_of, lp, act, stats))
 
-    (cache, _, _, _, _, counts), (tokens, step_of, lps, ran, stats) = jax.lax.scan(
+    (cache, _, _, _, _, counts, _), (tokens, step_of, lps, ran, stats) = jax.lax.scan(
         one_block,
         (cache, position, jnp.ones_like(active), lanes[:, _L_BUDGET], min_left,
-         jnp.zeros(2, jnp.int32)),
+         jnp.zeros(2, jnp.int32), pending),
         jnp.arange(n_blocks))
     if stats is not None:
         stats = jnp.sum(stats, axis=0)
@@ -1919,18 +1947,25 @@ class EngineCore:
             # Window-pool blocks given back while their sequence went on
             # (_release_window_behind); 0 for a model without such a pool.
             "window_blocks_released": 0,
-            # A block-diffusion model (_plan_blocks): forwards counted once
-            # a live lane a pass, by the pass's kind; blocks whose clean
-            # pass moved a lane's cursor on; places revealed on the device,
-            # by the rule that revealed them; places generated and not kept
-            # (after a cut, or in a block run past a stop only the host saw);
-            # rows of those forwards, and the rows of them that went through
-            # the head and the sampler (sampler.hidden_at_most a lane a pass).
+            # A block-diffusion model (_plan_blocks): denoising passes counted
+            # once a live lane a pass; blocks whose clean rows ran (beside a
+            # later block's first pass: no forward of their own); the live
+            # rows of those passes, a pending block's clean rows among them,
+            # and the rows that went through the head and the sampler
+            # (sampler.hidden_at_most a lane a pass); blocks revealed and
+            # kept; places revealed on the device, by the rule that revealed
+            # them; places generated and not kept (after a cut, or in a block
+            # run past a stop only the host saw).
             "denoise_forwards": 0,
             "commit_forwards": 0,
             "block_rows": 0,
             "head_rows": 0,
             "blocks_committed": 0,
+            # kept blocks whose clean rows rode a next block's first pass and
+            # moved the cursor on; revealed blocks whose clean rows never ran
+            # (the lane ended, was cancelled or was preempted first)
+            "block_clean_folded": 0,
+            "block_pending_dropped": 0,
             "places_revealed_threshold": 0,
             "places_revealed_quota": 0,
             "block_places_discarded": 0,
@@ -1974,8 +2009,11 @@ class EngineCore:
         # phases, one width at a time, never pair up. A megastep gathers
         # inside its own program (unpack_lanes), from the same source.
         self._feed = jax.jit(gather_feedback)
+        # (a block-diffusion lane yields its blocks' places a dispatch)
+        blk = model_cfg.block_length
         self._feed_width = (
-            engine_cfg.megastep * self._spec_R
+            (engine_cfg.megastep // (model_cfg.denoising_steps + 1) * blk if blk
+             else engine_cfg.megastep * self._spec_R)
             * max(engine_cfg.decode_buckets[-1], engine_cfg.prefill_batch)
         )
         self._feed_pad = jax.jit(pad_feedback, static_argnames=("width",))
@@ -2229,6 +2267,12 @@ class EngineCore:
 
     def _eff_generated(self, seq: Sequence) -> int:
         return seq.generated + self._adv3(seq)[2]
+
+    def _eff_block_start(self, seq: Sequence) -> int:
+        """First place of a block-diffusion lane's next block: past the
+        cursor, the pending block and the blocks of the step in flight
+        (:meth:`_eff_processed` for every other model, which has none)."""
+        return self._eff_processed(seq) + len(seq.pending_block)
 
     def _feed_src(self, seq: Sequence) -> int | None:
         """Flat index of this lane's newest sampled token in the in-flight
@@ -3613,7 +3657,7 @@ class EngineCore:
         the optimistic overlay so an in-flight step's writes are already
         covered)."""
         bs = self.engine.block_size
-        base = self._eff_processed(seq)
+        base = self._eff_block_start(seq)
         if not self._hold_window(seq, base, n_tokens):
             return False
         # never more than a table holds (a block-diffusion lane's last
@@ -3643,7 +3687,8 @@ class EngineCore:
         self.sched_stats["preemptions"] += 1
         self._release_blocks(seq)
         if seq.prefill_done:
-            new_prompt = seq.hashed.all_tokens()
+            # a pending block's tokens were streamed; its K/V were not final
+            new_prompt = seq.hashed.all_tokens() + seq.pending_block
             if seq.pending is not None:
                 new_prompt.append(seq.pending)
             # a block-diffusion lane that has not run its first block yet
@@ -3653,6 +3698,7 @@ class EngineCore:
             if self.cfg.block_length:
                 seq.tail = seq.prompt_len % self.cfg.block_length
         seq.pending = None
+        self._drop_pending_block(seq)
         # The rebuilt prompt absorbs every emitted token; keeping
         # out_tokens too would double-count them in the drafter's lookup
         # history after re-admission.
@@ -3663,6 +3709,14 @@ class EngineCore:
         seq.hashed = None
         self.running.remove(seq)
         self.waiting.appendleft(seq)
+
+    def _drop_pending_block(self, seq: Sequence) -> None:
+        """A block-diffusion lane that ends, is cancelled or is preempted
+        leaves its pending block as it is: no pass is spent on K/V no later
+        block will read (a resumed lane's wave recomputes them as prompt)."""
+        if seq.pending_block:
+            seq.pending_block = []
+            self.exec_stats["block_pending_dropped"] += 1
 
     def _release_blocks(self, seq: Sequence) -> None:
         """Release a sequence's block refs EXACTLY once: uncommitted
@@ -3728,13 +3782,17 @@ class EngineCore:
         A block-diffusion model's megastep (:func:`_megastep_blocks`) comes
         with ``opens`` (aligned with seqs): the tokens each lane's first
         block opens with. A lane's position is then its next block's first
-        place and its budget the places it may still generate, and in the
-        feedback source's place goes ``[B, block_length]`` of ``opens``, -1
-        where a place is hidden: a block starts from mask tokens, so
-        nothing of the step in flight is fed to it, only its K/V, which the
-        device orders. ``land()`` then yields ``[n_blocks, B, block_length]``
-        tokens and ``land_aux()`` the steps, live lanes and reveal counts,
-        flat."""
+        place and its budget the places it may still generate. A block
+        starts from mask tokens, so no TOKEN of the step in flight is fed to
+        it; what is fed is the lane's PENDING block, whose clean rows ride
+        the dispatch's first pass: ``feed_lanes`` names the first place of
+        the lane's last block in the in-flight dispatch's output, and where
+        that has landed (or the lane rode no such dispatch) the tokens go
+        down from the host (``Sequence.pending_block``) beside the known
+        places, ``[B, 2, block_length]``, -1 where a place is hidden or a
+        lane has no pending block. ``land()`` then yields ``[n_blocks, B,
+        block_length]`` tokens and ``land_aux()`` the steps, live lanes and
+        reveal counts, flat."""
         self.clock.mark("assemble")
         B = self._decode_width(len(seqs))
         seqs = seqs[:B]
@@ -3773,7 +3831,7 @@ class EngineCore:
                 feed_idx[i] = feed_lanes[i]
             elif not blk:
                 tokens[i] = seq.pending
-            positions[i] = self._eff_processed(seq)
+            positions[i] = self._eff_block_start(seq)
             self._table_row(tables[i], seq)
             active[i] = True
             temp[i] = seq.sampling.temperature
@@ -3784,10 +3842,12 @@ class EngineCore:
             self._arm_stop_inputs(seq, i, watch, budgets, min_left)
         made = np.full(B, yields, np.int32)
         if blk:
-            known = np.full((B, blk), -1, np.int32)
+            known = np.full((B, 2, blk), -1, np.int32)
             for i, k in enumerate(opens[:B]):
-                known[i, : len(k)] = k
+                known[i, 0, : len(k)] = k
                 made[i] -= len(k)
+                if seqs[i].pending_block and (feed_idx is None or feed_idx[i] < 0):
+                    known[i, 1] = seqs[i].pending_block
         finishing = int(np.count_nonzero(budgets <= made))
         need_mask = any(
             s.sampling.top_k > 0 or s.sampling.top_p < 1.0 for s in seqs
@@ -3800,18 +3860,17 @@ class EngineCore:
         )
         self.clock.mark("h2d")
         # Two transfers, and the step in flight's output where a lane
-        # reads its token from it (zeros of that shape where none does);
-        # a block model's third is its known places.
-        if blk:
-            third = self._put_batch(known)
-        else:
-            third = self._no_feed if feed_idx is None else self._feed_source()
-        args = (self._put_batch(lanes), self._put_batch(tables), third)
+        # reads its token (a block model's lane: its pending block) from it
+        # (zeros of that shape where none does); a block model's third is
+        # its known places and the pending blocks the host holds.
+        args = (self._put_batch(lanes), self._put_batch(tables),
+                self._no_feed if feed_idx is None else self._feed_source(),
+                *((self._put_batch(known),) if blk else ()))
         steps = self.cfg.denoising_steps
         self._mark_dispatch(
             "megastep" if n_steps > 1 else "decode",
             len(seqs), B, n_steps, len(seqs) * yields, B * yields,
-            **({"block": blk, "steps": steps, "passes": steps + 1} if blk else {}),
+            **({"block": blk, "steps": steps, "passes": steps} if blk else {}),
         )
         # On a pp engine the FUSED pp megastep: the whole wavefront chain
         # — stage hops, sampling, stop flags — is one dispatch, armed
@@ -3951,6 +4010,7 @@ class EngineCore:
 
         for seq in [s for s in self.running if s.cancelled]:
             self.running.remove(seq)
+            self._drop_pending_block(seq)
             self._release_blocks(seq)
 
         self._admit()
@@ -4247,16 +4307,32 @@ class EngineCore:
 
     def _plan_blocks(self) -> _PlannedStep | None:
         """Plan one megastep of a block-diffusion model: ``megastep / (steps +
-        1)`` whole blocks a lane in ONE dispatch (:func:`_megastep_blocks`).
-        Every lane's headroom for those blocks is grown before the dispatch,
-        as :meth:`_plan_decode` grows a chain's. A lane's cursor
-        (``num_computed_tokens``) moves ``B`` on at a block's clean pass and
-        by nothing before it: the passes of a block in flight overwrite
-        its K/V in place, past the cursor, where no sequence that goes on
-        reads (PR 35's invariant). The commit side is the authority on what
-        is kept: it scans each block's generated places in order for EOS,
-        stop ids and the budget, emits what is kept as one chunk, ends the
-        request at a cut and counts the places after it as discarded."""
+        1)`` whole blocks a lane in ONE dispatch (:func:`_megastep_blocks`),
+        ``steps`` passes each. Every lane's headroom for those blocks is
+        grown before the dispatch, as :meth:`_plan_decode` grows a chain's.
+
+        **What the cursor promises.** ``num_computed_tokens`` (``processed``)
+        moves ``B`` on over a block when the block's CLEAN rows have run,
+        which is in the first pass of the lane's next block, and by nothing
+        before: below it every K/V is final, and everything that reads or
+        publishes a lane's K/V (the hash chain and the allocator's commit,
+        KV events, prefix reuse, a transfer) stops there, so a page is
+        published only after its last block's clean rows. Past it lie the
+        lane's pending block (``Sequence.pending_block``: revealed,
+        streamed, its K/V those of a pass with places still masked) and the
+        passes of the blocks in flight, which overwrite their K/V in place
+        where no sequence that goes on reads (PR 35's invariant). Tokens are
+        streamed when revealed. The dispatch's last block of a live lane
+        comes back pending; the next dispatch is planned before this one
+        lands (the pipelined loop), so it names the block in this one's
+        output (``feed_index``) and the device gathers it; no drain is
+        added. A lane that ends, is cancelled or is preempted drops its
+        pending block (:meth:`_drop_pending_block`).
+
+        The commit side is the authority on what is kept: it scans each
+        block's generated places in order for EOS, stop ids and the budget,
+        emits what is kept as one chunk, ends the request at a cut and
+        counts the places after it as discarded."""
         ready = self._block_candidates()
         if not ready:
             return None
@@ -4266,34 +4342,45 @@ class EngineCore:
         ready = self._grow_or_preempt(ready, n_blocks * B)
         if not ready:
             return None
-        ready = ready[: self._decode_width(len(ready))]
+        S = self._decode_width(len(ready))
+        ready = ready[:S]
         t_decode = time.time()
         # the places a lane's first block of this dispatch opens with: a
         # prompt's tail, once, before the lane's first block
-        known = [s.prompt[s.wave_len:] if self._eff_processed(s) < s.prompt_len else []
+        known = [s.prompt[s.wave_len:] if self._eff_block_start(s) < s.prompt_len else []
                  for s in ready]
         now = time.time()
         for s in ready:
             self._mark_first_sched(s, now)   # a prompt of no whole block rode no wave
-        pend = self._dispatch_megastep(ready, n_steps, opens=known)
+        # a lane's pending block: in the output of the dispatch in flight
+        # (its last block there), or with the host, or none yet
+        feed_lanes = [self._feed_src(s) for s in ready]
+        rides = [f is not None or bool(s.pending_block) for f, s in zip(feed_lanes, ready)]
+        pend = self._dispatch_megastep(ready, n_steps, feed_lanes=feed_lanes, opens=known)
         adv = {s.request_id: (0, n_blocks * B, n_blocks * B - len(k))
                for s, k in zip(ready, known)}
+        # where the next dispatch finds each lane's last block in ``tokens
+        # [n_blocks, S, B]``, flat: by request, so a lane that changes slot
+        # gets its own
+        feed_index = {s.request_id: ((n_blocks - 1) * S + i) * B for i, s in enumerate(ready)}
 
         # dynalint: holds-lock(_step_lock) — commits run inside the step
         def commit() -> list[tuple[Sequence, LLMEngineOutput]]:
             outputs: list[tuple[Sequence, LLMEngineOutput]] = []
             toks, lps = pend.land()                 # [n_blocks, S, B]
             aux = pend.land_aux()
-            S = toks.shape[1]
             step_of = aux[: n_blocks * S * B].reshape(n_blocks, S, B)
-            ran = aux[n_blocks * S * B: -2].reshape(n_blocks, S)
+            ran = aux[n_blocks * S * B: -2].reshape(n_blocks, S)[:, : len(ready)]
             st = self.exec_stats
             st["places_revealed_threshold"] += int(aux[-2])
             st["places_revealed_quota"] += int(aux[-1])
-            lane_blocks = int(ran[:, : len(ready)].sum())
+            lane_blocks = int(ran.sum())
+            # clean rows ran beside every block a lane ran but its first of the
+            # dispatch, and beside that one where the lane brought a pending block
+            cleaned = int(ran[1:].sum()) + int(ran[0, np.asarray(rides, bool)].sum())
             st["denoise_forwards"] += lane_blocks * steps
-            st["commit_forwards"] += lane_blocks
-            st["block_rows"] += lane_blocks * (steps + 1) * B
+            st["commit_forwards"] += cleaned
+            st["block_rows"] += (lane_blocks * steps + cleaned) * B
             st["head_rows"] += lane_blocks * sum(hidden_at_most(B, steps))
             live = {id(s) for s in self.running}
             emitted_total = kept_blocks = 0
@@ -4310,17 +4397,22 @@ class EngineCore:
                 for b in range(n_blocks):
                     if not ran[b, i]:
                         break   # the device saw the lane end; so will the scan
+                    if seq.pending_block:
+                        # its clean rows ran in this block's first pass: the
+                        # cursor moves over it, and its page may be published
+                        self._commit_completed(seq, seq.hashed.extend(seq.pending_block))
+                        seq.processed += len(seq.pending_block)
+                        seq.pending_block = []
+                        st["block_clean_folded"] += 1
                     first = t if b == 0 else 0
                     k, finish = self._scan_stop(seq, toks[b, i, first:])
                     block = [int(x) for x in toks[b, i, : first + k]]
-                    self._commit_completed(seq, seq.hashed.extend(block))
-                    seq.processed += first + k
                     if entries is not None:
-                        at = seq.processed - first - k
                         entries += [
                             dict(_lp_entry(block[j], lps[0][b, i, j], lps[1][b, i, j],
                                            lps[2][b, i, j], seq.logprobs),
-                                 block=(at + j) // B, step=int(step_of[b, i, j]), place=j)
+                                 block=(seq.processed + j) // B, step=int(step_of[b, i, j]),
+                                 place=j)
                             for j in range(first, first + k)]
                     seq.generated += k
                     emitted += block[first:]
@@ -4332,7 +4424,10 @@ class EngineCore:
                             entries[-1]["cut"] = [
                                 [j, int(step_of[b, i, j]), int(toks[b, i, j])]
                                 for j in range(first + k, B)]
+                        # the request's last block: no later block, so no clean rows
+                        st["block_pending_dropped"] += 1
                         break
+                    seq.pending_block = block
                 outputs.append((seq, self._emit_chunk(seq, emitted, entries, finish)))
                 emitted_total += len(emitted)
                 if finish is not None:
@@ -4340,8 +4435,8 @@ class EngineCore:
                     self._finish(seq)
             st["blocks_committed"] += kept_blocks
             st["block_places_discarded"] += made - emitted_total
-            st["megastep_issued_lane_iters"] += len(ready) * n_steps
-            st["megastep_useful_lane_iters"] += kept_blocks * (steps + 1)
+            st["megastep_issued_lane_iters"] += len(ready) * n_blocks * steps
+            st["megastep_useful_lane_iters"] += kept_blocks * steps
             self._tracer.record(
                 "engine_megastep", t_decode, time.time(),
                 attrs={
@@ -4355,7 +4450,8 @@ class EngineCore:
             return outputs
 
         return _PlannedStep(
-            core=self, commit_fn=commit, adv=adv, finishing=pend.finishing)
+            core=self, commit_fn=commit, adv=adv, feed_tokens=pend.toks,
+            feed_index=feed_index, finishing=pend.finishing)
 
     # -- speculative decoding (draft + batched ragged verify) ---------------
 

@@ -54,6 +54,13 @@ v5e, round 2):
   ``block_attention``), in a step's pass (:func:`block_hidden`, and
   :func:`block_logits` on the rows whose places can still be hidden) and in
   a prefill wave alike; pages, allocator and prefix index see nothing new.
+  A generated block's K/V become FINAL when its revealed tokens run as
+  clean rows, which is beside the first pass of the lane's next block
+  (:func:`block_hidden`'s ``pending`` half; ``core._megastep_blocks``) or
+  in a wave that takes them as prompt; until then they are those of a
+  pass with places still masked, they lie past the lane's cursor
+  (``num_computed_tokens``), and nothing that reads or publishes K/V looks
+  there.
 """
 
 from __future__ import annotations
@@ -2070,42 +2077,71 @@ def block_hidden(
     engine: EngineConfig,
     mesh=None,
     expert_stats: list | None = None,
+    pending: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """One pass of a block-diffusion step up to the final norm: every lane's
     block of ``B = cfg.block_length`` places through the stack, each row
     seeing its block both ways beside the lane's causal past. Returns
     (``[S x B, h]`` normed hidden states, a row a place; cache) and NO
-    logits: the clean pass of a block is this and nothing more. The block's
-    K/V are WRITTEN, at its own positions, every pass: a denoising pass's
-    are overwritten by the next and the clean pass's stay (nothing reads
-    them in between but the pass that wrote them). Dead lanes write the
-    garbage page. Thin assembly over :func:`forward_hidden`, as
-    :func:`verify_tokens` is over :func:`forward_tokens`."""
+    logits. The block's K/V are WRITTEN, at its own positions, every pass: a
+    denoising pass's are overwritten by the next, and the last one's are
+    those of a block with places still masked, which nothing but the pass
+    that wrote them ever reads.
+
+    **Where a block's K/V become final.** ``pending`` ``[S, B]``: the
+    REVEALED tokens of each lane's block before this one, -1 for a lane
+    whose rows do not ride this pass (only a block's first has any: the
+    caller hands -1 in the others). The batch is then ``[S current blocks | S
+    pending blocks]``, block-major, one static shape whatever rides: a
+    pending block lies ``B`` before its lane's current one, writes its K/V
+    before the layer's attention like every row, and sees the past and
+    itself both ways (its block ends where the current one starts), while
+    the current block sees the past, the pending block as just written, and
+    itself. Those are a block's final K/V: its revealed tokens seen both
+    ways over a causal past, the ones a prefill wave would write for the
+    same tokens. A pending half no lane rides is dead: garbage page, no
+    expert group (``row_valid``), and the attention call is told ``S`` live
+    blocks and never visits it. Nothing of the pending rows is returned.
+
+    Dead lanes write the garbage page. Thin assembly over
+    :func:`forward_hidden`, as :func:`verify_tokens` is over
+    :func:`forward_tokens`."""
     S, B = tokens.shape
     bs = engine.block_size
     with jax.named_scope("kv_write"):
+        live = S
+        if pending is not None:
+            rides = (pending[:, 0] >= 0) & active
+            tokens = jnp.concatenate([tokens, jnp.where(rides[:, None], pending, 0)])
+            positions = jnp.concatenate([positions, positions - B])
+            active = jnp.concatenate([active, rides])
+            block_tables = jnp.concatenate([block_tables, block_tables])
+            live = jnp.where(jnp.any(rides), 2 * S, S)
+        n = tokens.shape[0]
         positions = jnp.where(active, positions, 0)
-        pos = positions[:, None] + jnp.arange(B, dtype=jnp.int32)[None, :]   # [S, B]
+        pos = positions[:, None] + jnp.arange(B, dtype=jnp.int32)[None, :]   # [n, B]
         page = jnp.take_along_axis(block_tables, pos // bs, axis=1)
         write_pages = jnp.where(active[:, None], page, engine.garbage_block).reshape(-1)
         write_offs = (pos % bs).reshape(-1)
         kv_lens = (positions + B).astype(jnp.int32)
-        cu = B * jnp.arange(S + 1, dtype=jnp.int32)
-        num_seqs = jnp.array([S], jnp.int32)
-    return forward_hidden(
+        cu = B * jnp.arange(n + 1, dtype=jnp.int32)
+        num_seqs = jnp.asarray(live, jnp.int32).reshape(1)
+    x, cache = forward_hidden(
         params, cache, tokens.reshape(-1), pos.reshape(-1), write_pages,
         write_offs, kv_lens, block_tables, cu, num_seqs, cfg, engine, mesh,
         expert_stats=expert_stats, block_shape="block-decode",
     )
+    return x[: S * B], cache
 
 
 def block_logits(params: Params, hidden: jax.Array, rows: jax.Array | None,
                  cfg: ModelConfig) -> jax.Array:
     """The head of a block pass on the rows asked for: ``[len(rows), vocab]``
     float32 logits of ``hidden[rows]`` (:func:`block_hidden`'s ``[S x B,
-    h]``), or of every row, a row a place, with ``rows`` None. A denoising
-    pass asks for the places that can still be hidden, the clean pass for
-    none (it does not come here): ``core._megastep_blocks``."""
+    h]``), or of every row, a row a place, with ``rows`` None. A pass asks
+    for the places that can still be hidden; a block's clean rows (the
+    pending half of :func:`block_hidden`) never come here:
+    ``core._megastep_blocks``."""
     with jax.named_scope("lm_head"):
         return _logits(hidden if rows is None else hidden[rows], params, cfg)
 

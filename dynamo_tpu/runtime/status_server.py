@@ -290,14 +290,27 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
     ),
     "block_rows": (
         "engine_block_rows",
-        "Rows of a block-diffusion model's forwards: block_length a live "
-        "lane a pass, the clean pass included",
+        "Live rows of a block-diffusion model's passes: block_length a live "
+        "lane a pass, and as many again where a pending block's clean rows "
+        "rode the pass",
     ),
     "head_rows": (
         "engine_block_head_rows",
         "Of those rows, the ones that went through the head and the "
-        "sampler: a pass's places that could still be hidden, none in the "
-        "clean pass",
+        "sampler: a pass's places that could still be hidden, none of the "
+        "clean rows",
+    ),
+    "block_clean_folded": (
+        "engine_block_clean_folded",
+        "Kept blocks whose clean rows (their final K/V) rode the first pass "
+        "of the lane's next block and moved the lane's cursor over them; "
+        "over engine_blocks_committed: the share of blocks that cost no "
+        "pass of their own",
+    ),
+    "block_pending_dropped": (
+        "engine_block_pending_dropped",
+        "Revealed blocks whose clean rows never ran: the lane ended, was "
+        "cancelled or was preempted first (a request's last block)",
     ),
     "block_places_discarded": (
         "engine_block_places_discarded",
@@ -311,9 +324,10 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
 # doc, label, {label value: stats key}).
 BLOCK_COUNTERS: tuple[tuple[str, str, str, dict[str, str]], ...] = (
     ("engine_denoise_forwards",
-     "Forwards over a block's rows, counted once a live lane a pass: the "
-     "denoising passes, and the clean pass that writes the block's K/V",
-     "pass", {"denoise": "denoise_forwards", "commit": "commit_forwards"}),
+     "Passes a live lane of a block-diffusion model ran, counted once a "
+     "lane a pass. Every pass is a denoising pass: a block's clean rows "
+     "ride the next block's first (engine_block_clean_folded)",
+     "pass", {"denoise": "denoise_forwards"}),
     ("engine_places_revealed",
      "Places a denoising pass revealed, by the rule: every hidden place "
      "over the confidence threshold, or the step's quota of the surest",

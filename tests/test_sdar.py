@@ -157,8 +157,12 @@ def test_a_prompts_tail_opens_the_first_block(whole, tail):
         core, FILE, {"prompt_ids": PROMPT[:whole + tail], "max_tokens": 9, "top": 5})
     assert verdict["ok"] and verdict["max_abs_diff"] < TIGHT
     assert got["served"][1]["cached_tokens"] == whole
-    # the first block generated B - tail places: its forwards are counted once a lane
-    assert core.exec_stats["denoise_forwards"] == 2 * core.exec_stats["commit_forwards"]
+    # the first block generated B - tail places: its passes are counted once a lane, two a
+    # block, and every block but a request's last had its clean rows ride the next one's
+    st = core.exec_stats
+    assert st["block_pending_dropped"] == 2                    # the probe is sent twice
+    assert st["denoise_forwards"] == 2 * (st["commit_forwards"] + st["block_pending_dropped"])
+    assert st["commit_forwards"] == st["block_clean_folded"]
 
 
 @pytest.mark.parametrize("fault", ["fp8", "causal", "order"])
@@ -344,6 +348,146 @@ def test_a_prefix_hit_on_a_page_of_generated_blocks():
     assert got["served"][0]["cached_tokens"] == 32
 
 
+# -- a block's clean rows ride the next block's first pass -----------------------------
+
+def _step_until(core, seq, generated):
+    got = []
+    while seq.generated < generated:
+        for _, out in core.step():
+            got += list(out.token_ids)
+    return got
+
+
+@pytest.mark.parametrize("how", ["finished", "cancelled", "preempted"])
+def test_a_lane_that_ends_with_a_block_pending_spends_no_pass_on_it(how):
+    """The stream is the parent's, every pass is a denoising pass of a block
+    that was streamed, and the pending block's clean rows are never run: the
+    counters say so (a resumed lane's wave recomputes the block as prompt)."""
+    core = make_core(async_exec=False)
+    seq = core.add_request(_req(PROMPT[:30], "t", 21, ignore_eos=True))
+    st = core.exec_stats
+    if how == "finished":
+        got = run_to_completion(core, [seq])[0]["t"]
+        dropped = 1
+    else:
+        got = _step_until(core, seq, 10)
+        # 2 + 4 + 4 + 4 places: the fourth block is revealed, streamed, and pending
+        assert seq.generated == 14 and seq.pending_block == got[10:14]
+        assert seq.processed == 40 and len(seq.hashed.all_tokens()) == 40
+        if how == "cancelled":
+            core.cancel_request(seq)
+            passes = st["denoise_forwards"]
+            while core.has_work():
+                assert core.step() == []
+            assert st["denoise_forwards"] == passes and not core.running
+            assert st["block_pending_dropped"] == 1 and seq.pending_block == []
+            assert got == PARENT_GREEDY[:14]
+            assert st["commit_forwards"] == st["block_clean_folded"] == 3
+            return
+        with core._step_lock:
+            core._preempt(seq)
+        assert st["block_pending_dropped"] == 1 and seq.pending_block == []
+        assert seq.prompt == PROMPT[:30] + got and seq.tail == 0   # 44 tokens: 11 whole blocks
+        got += run_to_completion(core, [seq])[0]["t"]
+        dropped = 2
+    assert got == PARENT_GREEDY and st["block_pending_dropped"] == dropped
+    # blocks revealed: 6 (2 + 4 x 4 + 3 of 4); two passes each and nothing else
+    blocks = 6
+    assert st["blocks_committed"] == blocks and st["denoise_forwards"] == 2 * blocks
+    assert st["commit_forwards"] == st["block_clean_folded"] == blocks - dropped
+    assert st["block_rows"] == (2 * blocks + blocks - dropped) * 4
+
+
+@pytest.mark.parametrize("async_exec", [False, True], ids=["sync", "async"])
+@pytest.mark.parametrize("blocks", [1, 2])
+def test_a_page_is_published_only_after_its_last_blocks_clean_rows(async_exec, blocks):
+    """A page of 8 tokens holds two blocks. Its second block's clean rows ride
+    the first pass of the block after it, in a LATER dispatch where the page
+    ends with the dispatch: the page is committed to the allocator, hashed
+    and sent as a KV event at that dispatch's landing and not before, under
+    both loops; the cursor never passes a block that is not clean; and what
+    is published is final: a wave over the same tokens writes the same page."""
+    core = make_core(async_exec=async_exec, megastep_k=3 * blocks)
+    log = core._exec_log = []
+    stored = []
+    core.allocator.on_stored = lambda hashes, parent: (
+        stored.extend(hashes), log.extend(("stored", len(stored) - len(hashes) + j)
+                                         for j in range(len(hashes))))
+    seq = core.add_request(_req(PROMPT[:16], "p", 29, ignore_eos=True))
+    out, snaps = [], {}
+    while seq.finish is None:
+        for _, o in core.step():
+            out += list(o.token_ids)
+        assert seq.processed % 4 == 0 and seq.committed_blocks * 8 <= seq.processed
+        assert seq.hashed is None or len(seq.hashed.all_tokens()) == seq.processed
+        assert seq.processed + len(seq.pending_block) <= 16 + seq.generated + 3
+        for page in range(len(snaps), seq.committed_blocks):
+            snaps[page] = [np.asarray(layer[seq.block_ids[page]]) for layer in core.cache]
+    while core.has_work():
+        core.step()
+    # 16 + 29 = 45 tokens: the prompt's two pages by the wave (dispatch 1), then pages 2-4
+    # (generated blocks 0-5); the block after page 4 is the request's last, cut at 1 of 4
+    assert len(stored) == 5 and len(snaps) == 5
+    at = {e: i for i, e in enumerate(log)}
+    for page in (2, 3, 4):
+        cleans = 2 * (page - 2) + 2              # the generated block whose first pass cleans it
+        landing = ("land", 2 + cleans // blocks)   # megastep m is dispatch m + 2
+        assert at[landing] < at[("stored", page)]
+        assert all(at[("land", n)] <= at[landing] or at[("land", n)] > at[("stored", page)]
+                   for kind, n in log if kind == "land")
+    # final: a fresh engine's wave over prompt + stream writes pages 2-4 as they were published
+    fresh = make_core()
+    again = fresh.add_request(_req(PROMPT[:16] + out[:24], "w", 1, ignore_eos=True))
+    while again.prefilled < 40:
+        fresh.step()
+    for page in (2, 3, 4):
+        for a, b in zip(snaps[page], (np.asarray(l[again.block_ids[page]]) for l in fresh.cache)):
+            np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+def test_the_pending_block_is_fed_on_the_device_and_a_lane_that_moves_gets_its_own(monkeypatch):
+    """The pipelined loop stays pipelined: dispatch N + 1 is enqueued before N
+    lands, so the host cannot hold the pending tokens when it plans; it names
+    the first place of the lane's last block in N's output, by REQUEST. When
+    the lane in slot 0 ends, the others move down a slot and are fed the
+    blocks of the slots they had; the streams are the sync loop's."""
+    prompts = [PROMPT[:30], PROMPT[:17], PROMPT[:64]]
+    lengths = [9, 33, 25]
+    want, _ = _streams(prompts, lengths, async_exec=False, megastep_k=3)
+    core = make_core(async_exec=True, megastep_k=6)
+    log = core._exec_log = []
+    plans = []
+    dispatch = core._dispatch_megastep
+
+    def spy(seqs, n_steps, feed_lanes=None, opens=None):
+        plans.append(([s.request_id for s in seqs], list(feed_lanes), len(log),
+                      [list(s.pending_block) for s in seqs]))
+        return dispatch(seqs, n_steps, feed_lanes=feed_lanes, opens=opens)
+
+    monkeypatch.setattr(core, "_dispatch_megastep", spy)
+    seqs = [core.add_request(_req(p, f"s{i}", n, ignore_eos=True))
+            for i, (p, n) in enumerate(zip(prompts, lengths))]
+    got = run_to_completion(core, seqs)[0]
+    assert got == want
+    S, B, fed, moved = 4, 4, 0, 0                       # a dispatch's width; two blocks each
+    for (before, _, _, _), (lanes, feeds, when, held) in zip(plans, plans[1:]):
+        for slot, (rid, feed, pending) in enumerate(zip(lanes, feeds, held)):
+            if feed is None:
+                continue
+            # the step in flight has not landed: the host holds an OLDER block, or none
+            landed = [n for kind, n in log[:when] if kind == "land"]
+            dispatched = [n for kind, n in log[:when] if kind == "dispatch"]
+            assert max(dispatched) not in landed
+            assert feed == ((2 - 1) * S + before.index(rid)) * B
+            fed += 1
+            moved += before.index(rid) != slot
+    assert fed > 6 and moved >= 2
+    st = core.exec_stats
+    assert st["pipelined_dispatches"] > 0 and st["drains"] == 0
+    assert st["block_pending_dropped"] == 3
+    assert st["block_clean_folded"] == st["blocks_committed"] - 3
+
+
 # -- the head and the sampler only where a place can still be hidden -----------------
 
 def _plain_megastep_blocks(params, cache, lanes, block_tables, known, *, n_steps, need_mask,
@@ -450,27 +594,65 @@ def _megastep_inputs(cfg, engine, temperature, masked, seed=0):
     return params, cache, jnp.asarray(lanes), tables, jnp.asarray(known)
 
 
+def _folded(known, pending=None):
+    """``core._megastep_blocks``'s ``known``: the places a lane's first block opens
+    with beside the pending block the host holds for it (none: -1)."""
+    none = jnp.full_like(known, -1)
+    return jnp.stack([known, none if pending is None else pending], axis=1)
+
+
+NO_FEED = jnp.zeros(1024, jnp.int32)
+
+
+def _positions_of(lanes, tables, pages, page, blocks_of):
+    """A mask over ``[pages, page]`` of the cache: the places of lane ``i``'s blocks
+    ``blocks_of[i]`` (counted from the lane's position), through its table row."""
+    B = CFG.block_length
+    mask = np.zeros((pages, page), bool)
+    for i, blocks in enumerate(blocks_of):
+        for b in blocks:
+            for pos in range(int(lanes[i, core_mod._L_POSITION]) + b * B,
+                             int(lanes[i, core_mod._L_POSITION]) + (b + 1) * B):
+                mask[int(tables[i, pos // page]), pos % page] = True
+    return mask
+
+
 @pytest.mark.parametrize("steps,temperature,threshold,want_lp", [
     (steps, temperature, threshold, True)
     for steps in (1, 2, 4) for temperature in (0.0, 0.7) for threshold in (0.9, 0.004)
 ] + [(2, 0.7, 0.9, False), (4, 0.0, 0.004, False)])
 def test_the_megastep_is_bit_equal_to_its_plain_form(steps, temperature, threshold, want_lp):
-    """Tokens, the step that revealed each place, the lanes that ran, the two
-    reveal counts, the log-probabilities and every page of the cache: the
-    same bits as with the head and the sampler on every row of every pass.
-    A threshold of 0.004 reveals MORE than the quota (a pass then finds
+    """The plain form (a clean pass of its own after every block's denoising
+    passes, the head and the sampler on every row of every pass) stays the
+    DEFINITION. Held to it over two dispatches, three blocks then one: the
+    tokens, the step that revealed each place, the lanes that ran and the two
+    reveal counts to the bit; the log-probabilities and the cache to 1e-5,
+    because a row's products now run in a batch of ``2 S B`` rows beside its
+    lane's pending block where the plain form's ran among ``S B`` (the CPU's
+    readings are 0 to a few 1e-7). The cache is the plain form's everywhere
+    but in each lane's LAST block run, whose clean rows have yet to ride a
+    pass: after the first dispatch that block holds the K/V of a pass with
+    places still masked; the second dispatch brings it final, fed from the
+    first one's output on the device (lane 7: handed down by the host), and
+    leaves its own block pending in turn. A lane the device saw end (5: its
+    budget, 6: a watched id) keeps its last block as it was: no pass is spent
+    on it. A threshold of 0.004 reveals MORE than the quota (a pass then finds
     fewer hidden places than its slots); seeded lanes ask for top-k / top-p
     where the threshold fires, so both samplers are held."""
     cfg = tiny_sdar(denoising_steps=steps, confidence_threshold=threshold)
     engine = tiny_engine(block_size=8)
     masked = temperature > 0 and threshold < 0.5
     params, cache, lanes, tables, known = _megastep_inputs(cfg, engine, temperature, masked)
-    static = dict(n_steps=3 * (steps + 1), need_mask=masked, all_greedy=temperature == 0,
-                  want_logprobs=want_lp, cfg=cfg, engine=engine)
-    got = jax.jit(lambda *a: _megastep_blocks(*a, **static))(params, cache, lanes, tables, known)
-    want = jax.jit(lambda *a: _plain_megastep_blocks(*a, **static))(
-        params, cache, lanes, tables, known)
     S, B = known.shape
+    static = dict(need_mask=masked, all_greedy=temperature == 0, want_logprobs=want_lp,
+                  cfg=cfg, engine=engine)
+    folded = jax.jit(lambda *a, n: _megastep_blocks(*a, n_steps=n, **static),
+                     static_argnames="n")
+    plain = jax.jit(lambda *a, n: _plain_megastep_blocks(*a, n_steps=n, **static),
+                    static_argnames="n")
+    n1 = 3 * (steps + 1)
+    got = folded(params, cache, lanes, tables, NO_FEED, _folded(known), n=n1)
+    want = plain(params, cache, lanes, tables, known, n=n1)
     aux = np.asarray(want[4])
     ran = aux[3 * S * B: -2].reshape(3, S)
     assert ran[:, 4].sum() == 0 and ran[:, 0].all()            # the idle lane; a plain one
@@ -479,17 +661,51 @@ def test_the_megastep_is_bit_equal_to_its_plain_form(steps, temperature, thresho
     if threshold < 0.5 and steps > 1:     # some first pass revealed MORE than its quota
         first = (aux[: 3 * S * B].reshape(3, S, B) == 0).sum(axis=2)
         assert first.max() > hidden_at_most(B, steps)[0] - hidden_at_most(B, steps)[1]
-    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
-    np.testing.assert_array_equal(np.asarray(got[4]), aux)
-    if want_lp:
-        for a, b in zip(got[1], want[1]):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    else:
-        assert got[1] is None and want[1] is None
-    for a, b in zip(got[2], want[2]):     # but the garbage page, where dead lanes' rows collide
-        np.testing.assert_array_equal(np.asarray(a)[:-1], np.asarray(b)[:-1])
-    # the sparse layers' counts are summed over every pass, the clean pass's too
-    assert got[3].shape == (5,) and int(got[3][1]) == 3 * (steps + 1) * cfg.num_layers
+
+    def same(got, want, pending):
+        """Tokens and aux to the bit, log-probabilities and every page but the
+        garbage page (where dead lanes' rows collide) and the ``pending`` places."""
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+        np.testing.assert_array_equal(np.asarray(got[4]), np.asarray(want[4]))
+        if want_lp:
+            for a, b in zip(got[1], want[1]):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+        else:
+            assert got[1] is None and want[1] is None
+        for a, b in zip(got[2], want[2]):
+            np.testing.assert_allclose(np.asarray(a)[~pending], np.asarray(b)[~pending], atol=1e-5)
+
+    lanes_np, page, pages = np.asarray(lanes), engine.block_size, cache[0].shape[0]
+    last = [[int(ran[:, i].sum()) - 1] if ran[:, i].any() else [] for i in range(S)]
+    pending = _positions_of(lanes_np, tables, pages, page, last)
+    pending[-1] = True
+    same(got, want, pending)
+    if threshold > 0.5:       # a plain lane's last block is NOT final yet: its last pass saw masks
+        mine = _positions_of(lanes_np, tables, pages, page, [[2]] + [[]] * (S - 1))
+        assert float(np.abs(np.asarray(got[2][0])[mine] - np.asarray(want[2][0])[mine]).max()) > 1e-3
+    # the sparse layers' counts are summed over every pass: ``steps`` a block
+    assert got[3].shape == (5,) and int(got[3][1]) == 3 * steps * cfg.num_layers
+
+    # the second dispatch, one block: lanes 0-3 fed from the first one's output on the
+    # device, lane 7 handed its block by the host; 4-6 are over
+    alive = np.asarray([1, 1, 1, 1, 0, 0, 0, 1], np.int32)
+    again = lanes_np.copy()
+    again[:, core_mod._L_POSITION] += 3 * B
+    again[:, core_mod._L_ACTIVE] = alive
+    plain_lanes = jnp.asarray(again)
+    again[:, core_mod._L_FEED] = np.where(alive == 1, (2 * S + np.arange(S)) * B, -1)
+    again[7, core_mod._L_FEED] = -1
+    from_host = np.full((S, B), -1, np.int32)
+    from_host[7] = np.asarray(got[0])[2, 7]
+    nothing = jnp.full((S, B), -1, jnp.int32)
+    feed = jnp.pad(got[0].reshape(-1), (0, NO_FEED.shape[0] - got[0].size))
+    got2 = folded(params, got[2], jnp.asarray(again), tables, feed,
+                  _folded(nothing, jnp.asarray(from_host)), n=steps + 1)
+    want2 = plain(params, want[2], plain_lanes, tables, nothing, n=steps + 1)
+    assert np.asarray(want2[4])[S * B: -2].tolist() == alive.tolist()
+    pending = _positions_of(lanes_np, tables, pages, page, [[3] if alive[i] else last[i] for i in range(S)])
+    pending[-1] = True
+    same(got2, want2, pending)
 
 
 @pytest.mark.parametrize("B,steps,want", [
@@ -535,23 +751,62 @@ def _switches(jaxpr, branches: int) -> list:
     return found
 
 
+def _count(jaxpr, primitive: str) -> int:
+    """Equations of that primitive, sub-programs included."""
+    return sum((eqn.primitive.name == primitive)
+               + sum(_count(sub, primitive) for sub in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
 @pytest.mark.parametrize("steps", [1, 2, 4])
 def test_a_pass_has_a_head_of_its_hidden_places_and_the_clean_pass_none(steps):
     """The one product with the vocabulary a pass has lies in the branch the
-    pass's number picks: ``S x H_p`` rows in pass ``p``, none in the clean
-    pass, and none outside the switch."""
+    pass's number picks: ``S x H_p`` rows of the CURRENT half in pass ``p``
+    and none outside the switch; the clean rows (the pending half of a
+    block's first pass) go through no head, and there is no clean pass."""
     cfg = tiny_sdar(denoising_steps=steps)
     engine = tiny_engine(block_size=8)
-    args = _megastep_inputs(cfg, engine, 0.7, False)
+    *args, known = _megastep_inputs(cfg, engine, 0.7, False)
     S = args[2].shape[0]
     jaxpr = jax.make_jaxpr(lambda *a: _megastep_blocks(
         *a, n_steps=2 * (steps + 1), need_mask=False, all_greedy=False, want_logprobs=True,
-        cfg=cfg, engine=engine))(*args).jaxpr
-    (switch,) = _switches(jaxpr, steps + 1)
+        cfg=cfg, engine=engine))(*args, NO_FEED, _folded(known)).jaxpr
+    want = [[S * H] for H in hidden_at_most(cfg.block_length, steps)[:steps]]
+    if steps == 1:     # one pass a block: no switch, the head as it lies
+        assert not _switches(jaxpr, 2) and _head_products(jaxpr, cfg.vocab_size) == want[0]
+        return
+    (switch,) = _switches(jaxpr, steps)
     by_pass = [_head_products(branch.jaxpr, cfg.vocab_size) for branch in switch.params["branches"]]
-    assert by_pass == [[S * H] if H else [] for H in hidden_at_most(cfg.block_length, steps)]
-    assert by_pass[-1] == [] and sorted(_head_products(jaxpr, cfg.vocab_size)) == sorted(
-        sum(by_pass, []))
+    assert by_pass == want
+    assert sorted(_head_products(jaxpr, cfg.vocab_size)) == sorted(sum(by_pass, []))
+
+
+@pytest.mark.parametrize("steps", [2, 4])
+def test_the_block_megastep_holds_one_stack(steps):
+    """Every pass of every block is the ONE scanned body: a layer's two
+    grouped products (gate/up, down) appear ``num_layers`` times in the
+    whole program, at the one shape ``[S current blocks | S pending blocks]``,
+    whatever the blocks a dispatch and the steps a block; and nothing in the
+    compiled text is a rematerialised copy (on the v5e, written-out passes
+    cost a head computed once a copy and 15 s of set-up: PERF.md, PR 43)."""
+    cfg = tiny_sdar(denoising_steps=steps)
+    engine = tiny_engine(block_size=8, num_kv_blocks=640)
+    S, B = 64, cfg.block_length                  # 2 S B = 512 rows: a wave's grouped product
+    shapes = (
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)),
+        jax.eval_shape(lambda: init_cache(cfg, engine)),
+        jax.ShapeDtypeStruct((S, core_mod.LANE_COLS), jnp.int32),
+        jax.ShapeDtypeStruct((S, 10), jnp.int32),
+        jax.ShapeDtypeStruct((2 * S * B,), jnp.int32),
+        jax.ShapeDtypeStruct((S, 2, B), jnp.int32))
+    program = jax.jit(lambda *a: _megastep_blocks(
+        *a, n_steps=2 * (steps + 1), need_mask=False, all_greedy=False, want_logprobs=False,
+        cfg=cfg, engine=engine))
+    jaxpr = jax.make_jaxpr(program)(*shapes).jaxpr
+    assert _count(jaxpr, "ragged_dot_general") == 2 * cfg.num_layers
+    assert _count(jaxpr, "scan") == 2              # the blocks, and a block's passes
+    text = program.lower(*shapes).compile().as_text()
+    assert ".remat" not in text
 
 
 # what the parent commit (PR 42) served, the engine of ``make_core()``, PROMPT[:30], 21 tokens
@@ -583,15 +838,23 @@ def test_the_rows_that_went_through_the_head_are_counted():
         run_to_completion(core, [seq])
         st = core.exec_stats
         B = core.cfg.block_length
-        assert st["denoise_forwards"] == steps * st["commit_forwards"] > 0
+        # every block's clean rows rode the next block's first pass, but the last one's
+        assert st["block_pending_dropped"] == 1
+        assert st["denoise_forwards"] == steps * (st["commit_forwards"] + 1) > 0
         assert st["block_rows"] == (st["denoise_forwards"] + st["commit_forwards"]) * B
-        assert st["head_rows"] * (steps + 1) * B == st["block_rows"] * sum(hidden_at_most(B, steps))
-        assert st["head_rows"] * 2 == st["block_rows"]      # 6 of 12 rows; 10 of 20
+        assert st["head_rows"] * steps == st["denoise_forwards"] * sum(hidden_at_most(B, steps))
+        assert st["head_rows"] * 2 == st["block_rows"] + B      # 6 of 12 rows; 10 of 20
     registry = MetricsRegistry()
     registry.registry.register(_EngineCounters(core.step_phase_seconds, core.scheduler_stats))
     text = registry.render().decode()
     assert prometheus.total([text], "dynamo_engine_block_head_rows_total") == st["head_rows"]
     assert prometheus.total([text], "dynamo_engine_block_rows_total") == st["block_rows"]
+    # the two counters of the fold, and the passes a live lane ran: every pass a denoising one
+    assert prometheus.total([text], "dynamo_engine_block_clean_folded_total") == \
+        st["block_clean_folded"] == st["blocks_committed"] - 1 > 0
+    assert prometheus.total([text], "dynamo_engine_block_pending_dropped_total") == 1
+    assert prometheus.total([text], "dynamo_engine_denoise_forwards_total") == st["denoise_forwards"]
+    assert prometheus.total([text], "dynamo_engine_denoise_forwards_total", {"pass": "commit"}) is None
 
 
 # -- the pieces -------------------------------------------------------------------
@@ -753,7 +1016,8 @@ def test_counters_of_a_run():
     # 34 = 8 whole blocks + a tail of 2; 25 tokens: 2 + 5 whole blocks + 3 of 4
     assert st["blocks_committed"] == 7
     assert st["places_revealed_quota"] + st["places_revealed_threshold"] >= 26
-    assert st["denoise_forwards"] == 2 * st["commit_forwards"] >= 14
+    assert st["denoise_forwards"] == 2 * 7 and st["commit_forwards"] == st["block_clean_folded"] == 6
+    assert st["block_pending_dropped"] == 1
     assert st["committed_tokens"] == 25 and st["block_places_discarded"] >= 1
     traced = traced_calls()
     assert traced.get(("block-decode", "reference"), 0) > 0
