@@ -149,7 +149,7 @@ class ModelConfig:
     # allows (:attr:`kv_head_pairs`). The engine clears it for a dense
     # model served with an option the pair does not carry (a mesh, int8
     # pages), which then keeps the page and the options it had
-    # (core._unpaired_where_not_carried).
+    # (options._unpaired_where_not_carried).
     kv_pairing: bool = True
     # -- window and full attention layers mixed (Laguna) ----------------------
     # A "sliding_attention" layer's query at position p sees the keys at p -
@@ -725,7 +725,7 @@ class EngineConfig:
     disk_kv_blocks: int = 4096
     # None: on, unless the model's cache cannot find a block again by its
     # hash (a model with window layers: off, and True is refused by name;
-    # core._resolve_window_pool). The engine holds the resolved bool.
+    # options._resolve_window_pool). The engine holds the resolved bool.
     enable_prefix_caching: bool | None = None
     # Decode batch buckets: compile decode at these widths only.
     decode_buckets: tuple[int, ...] = (8, 16, 32, 64)

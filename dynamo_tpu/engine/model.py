@@ -1627,7 +1627,7 @@ def conv_layer(
     preempted while its step was in flight restarts from full, hashed
     blocks, which no later position writes). Speculative decoding
     rejects rows it has written and goes on: it is refused for a model
-    with conv layers (core._refuse_uncarried_options).
+    with conv layers (options._refuse_uncarried_options).
 
     Scopes: ``conv/in_proj``, ``conv/state`` (the gather and the scatter
     of state rows), ``conv/mix`` (the gates and the taps),
