@@ -33,7 +33,6 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Any, Callable
 
 import jax
@@ -48,12 +47,7 @@ from dynamo_tpu.engine.fair_queue import FairQueue
 from dynamo_tpu.engine.prefill_cover import cheapest_cover
 from dynamo_tpu.runtime.engine import EngineOverloadedError
 from dynamo_tpu.runtime import wire
-from dynamo_tpu.engine.model import (
-    embed_forward,
-    expert_call_shape,
-    init_cache,
-    init_params,
-)
+from dynamo_tpu.engine.model import embed_forward, expert_call_shape
 # The device programs and the lane format. What the dispatchers call, and what
 # others read through this module: chipbench/rehearse_v5e.py the two serving
 # programs and ``_program``; tests/test_sdar.py and tests/test_host_leg.py the
@@ -101,6 +95,7 @@ from dynamo_tpu.ops.ragged_attention import traced_impl
 from dynamo_tpu.engine.sampler import gather_feedback, hidden_at_most, pad_feedback
 from dynamo_tpu.llm.kv_router.protocols import ForwardPassMetrics, KvStats, WorkerStats
 from dynamo_tpu.spec import SpecConfig, SpecStats, propose_ngram, resolve_spec_config
+from dynamo_tpu.parallel.placement import place
 from dynamo_tpu.parallel.multihost import (
     fetch_replicated,
     fetch_replicated_many,
@@ -214,21 +209,6 @@ class Sequence:
         across mixed steps so a long prompt streams instead of
         monopolizing one."""
         return self.processed
-
-
-def _check_fuse_tp(params, tp: int) -> None:
-    """The fused wqkv/wgu column layout is tp-dependent; serving params
-    fused for a different tp would produce silently wrong logits
-    (permuted q/k/v and gate/up columns). Fail loudly instead."""
-    from dynamo_tpu.engine.model import params_fuse_tp
-
-    fused = params_fuse_tp(params)
-    if fused != tp:
-        raise ValueError(
-            f"params were fused for tp={fused} but the serving mesh has "
-            f"tp={tp}; reload with load_hf_llama(path, tp={tp}) or "
-            f"init_params(rng, cfg, tp={tp})"
-        )
 
 
 class _NeedDrain(Exception):
@@ -510,96 +490,8 @@ class EngineCore:
         self.eos_token_ids = set(eos_token_ids)
         self.mesh = mesh
         self.pp_mesh = pp_mesh
-        self._pp = 1
-        self._pp_micro = 1
-        self._dp = 1
-        self._batch_shardings = None
-        if pp_mesh is not None:
-            from dynamo_tpu.parallel.pipeline import (
-                cache_sharding_pp,
-                pp_param_specs,
-                shard_params_pp,
-            )
-
-            pp = int(pp_mesh.shape["pp"])
-            self._pp = pp
-            # Microbatch count: the wavefront schedule needs M >= pp for
-            # the ring-fed token feedback; M = pp also makes per-step lm-
-            # head traffic match the unpipelined engine (V/pp per stage).
-            self._pp_micro = pp
-            if params is not None:
-                # int8 params ({'w','scale'} dict leaves) shard like any
-                # stacked layer array: both members carry the layer axis
-                # first, so shard_params_pp places the pair per stage.
-                _check_fuse_tp(params, 1)  # pp stages keep tp=1 layouts
-                params = shard_params_pp(params, model_cfg, pp_mesh)
-            else:
-                from jax.sharding import NamedSharding
-
-                specs = pp_param_specs(model_cfg, pp)
-                params = jax.jit(
-                    init_params,
-                    static_argnums=(1,),
-                    out_shardings=jax.tree.map(
-                        lambda s: NamedSharding(pp_mesh, s), specs,
-                        is_leaf=lambda x: isinstance(
-                            x, jax.sharding.PartitionSpec
-                        ),
-                    ),
-                )(jax.random.PRNGKey(seed), model_cfg)
-            self.params = params
-            # pp keeps the STACKED [L, ...] cache — the layer axis is the
-            # stage sharding (parallel/pipeline.py).
-            from dynamo_tpu.engine.model import init_cache_stacked
-
-            self.cache = jax.jit(
-                partial(init_cache_stacked, model_cfg, engine_cfg),
-                out_shardings=cache_sharding_pp(
-                    pp_mesh, quantized=engine_cfg.kv_quantized
-                ),
-            )()
-        elif mesh is not None:
-            from dynamo_tpu.parallel.sharding import (
-                cache_sharding,
-                decode_batch_shardings,
-                param_shardings,
-                shard_params,
-            )
-
-            self._dp = int(mesh.shape["dp"])
-            self._batch_shardings = decode_batch_shardings(mesh)
-            tp = int(mesh.shape["tp"])
-            if params is not None:
-                _check_fuse_tp(params, tp)
-            if params is None:
-                # Initialize directly into the sharded layout — no
-                # single-device staging (a 70B pytree never fits one chip).
-                params = jax.jit(
-                    init_params,
-                    static_argnums=(1, 2),
-                    out_shardings=param_shardings(model_cfg, mesh),
-                )(jax.random.PRNGKey(seed), model_cfg, tp)
-            else:
-                params = shard_params(params, model_cfg, mesh)
-            self.params = params
-            self.cache = jax.jit(
-                partial(init_cache, model_cfg, engine_cfg),
-                out_shardings=cache_sharding(
-                    mesh,
-                    quantized=engine_cfg.kv_quantized,
-                    num_layers=model_cfg.num_layers,
-                ),
-            )()
-        else:
-            if params is not None:
-                _check_fuse_tp(params, 1)
-                # Host pytrees (engine/loader.py returns numpy) land on
-                # device ONCE here; device arrays pass through untouched.
-                params = jax.device_put(params)
-            self.params = params if params is not None else init_params(
-                jax.random.PRNGKey(seed), model_cfg
-            )
-            self.cache = init_cache(model_cfg, engine_cfg)
+        (self.params, self.cache, self._dp, self._pp, self._pp_micro,
+         self._batch_shardings) = place(model_cfg, engine_cfg, params, seed, mesh, pp_mesh)
         # A window model's blocks are found again by no one (prefix caching
         # is off for it): their KV events are not published.
         self.allocator = DeviceBlockAllocator(

@@ -13,12 +13,20 @@ Shape source of truth: ``jax.eval_shape`` over ``model.init_params`` /
 ``model.init_cache`` with the very PartitionSpecs the engine serves under
 (`param_partition_specs`) — the plan counts exactly the arrays the engine
 allocates, not a hand formula that can drift from the code.
+
+:func:`place` is the other half: where the engine's weights and cache are
+put, by kind of mesh (``EngineCore.__init__`` calls it after
+``engine/options.py:resolve``).
 """
 
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from functools import partial
+from typing import Any, NamedTuple
+
+import jax
 
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
 
@@ -69,7 +77,6 @@ def memory_plan(
     output column (matching model.quantize_params). dp never divides —
     every dp replica holds full params and its own cache.
     """
-    import jax
     from jax.sharding import PartitionSpec
 
     from dynamo_tpu.engine.model import init_cache, init_params
@@ -114,3 +121,127 @@ def memory_plan(
         cache_bytes_per_chip=cache_bytes,
         headroom_frac=headroom_frac,
     )
+
+
+def _check_fuse_tp(params, tp: int) -> None:
+    """The fused wqkv/wgu column layout is tp-dependent; serving params
+    fused for a different tp would produce silently wrong logits
+    (permuted q/k/v and gate/up columns). Fail loudly instead."""
+    from dynamo_tpu.engine.model import params_fuse_tp
+
+    fused = params_fuse_tp(params)
+    if fused != tp:
+        raise ValueError(
+            f"params were fused for tp={fused} but the serving mesh has "
+            f"tp={tp}; reload with load_hf_llama(path, tp={tp}) or "
+            f"init_params(rng, cfg, tp={tp})"
+        )
+
+
+class Placed(NamedTuple):
+    """What :func:`place` hands ``EngineCore.__init__``."""
+
+    params: Any
+    cache: Any
+    dp: int
+    pp: int
+    pp_micro: int
+    batch_shardings: Any
+
+
+def place(model_cfg, engine_cfg, params, seed: int, mesh, pp_mesh) -> Placed:
+    """The weights and the cache where the kind of mesh puts them:
+    staged over ``pp_mesh`` (parallel/pipeline.py, the STACKED cache),
+    sharded over ``mesh`` (parallel/sharding.py), or on the one default
+    device. ``params`` None initialises them in place, seeded. Weights
+    that were fused for another tp are refused (:func:`_check_fuse_tp`);
+    what the configurations and the meshes alone decide was checked
+    before (engine/options.py)."""
+    from dynamo_tpu.engine.model import init_cache, init_params
+
+    dp = pp = pp_micro = 1
+    batch_shardings = None
+    if pp_mesh is not None:
+        from dynamo_tpu.parallel.pipeline import (
+            cache_sharding_pp,
+            pp_param_specs,
+            shard_params_pp,
+        )
+
+        pp = int(pp_mesh.shape["pp"])
+        # Microbatch count: the wavefront schedule needs M >= pp for
+        # the ring-fed token feedback; M = pp also makes per-step lm-
+        # head traffic match the unpipelined engine (V/pp per stage).
+        pp_micro = pp
+        if params is not None:
+            # int8 params ({'w','scale'} dict leaves) shard like any
+            # stacked layer array: both members carry the layer axis
+            # first, so shard_params_pp places the pair per stage.
+            _check_fuse_tp(params, 1)  # pp stages keep tp=1 layouts
+            params = shard_params_pp(params, model_cfg, pp_mesh)
+        else:
+            from jax.sharding import NamedSharding
+
+            specs = pp_param_specs(model_cfg, pp)
+            params = jax.jit(
+                init_params,
+                static_argnums=(1,),
+                out_shardings=jax.tree.map(
+                    lambda s: NamedSharding(pp_mesh, s), specs,
+                    is_leaf=lambda x: isinstance(
+                        x, jax.sharding.PartitionSpec
+                    ),
+                ),
+            )(jax.random.PRNGKey(seed), model_cfg)
+        # pp keeps the STACKED [L, ...] cache — the layer axis is the
+        # stage sharding (parallel/pipeline.py).
+        from dynamo_tpu.engine.model import init_cache_stacked
+
+        cache = jax.jit(
+            partial(init_cache_stacked, model_cfg, engine_cfg),
+            out_shardings=cache_sharding_pp(
+                pp_mesh, quantized=engine_cfg.kv_quantized
+            ),
+        )()
+    elif mesh is not None:
+        from dynamo_tpu.parallel.sharding import (
+            cache_sharding,
+            decode_batch_shardings,
+            param_shardings,
+            shard_params,
+        )
+
+        dp = int(mesh.shape["dp"])
+        batch_shardings = decode_batch_shardings(mesh)
+        tp = int(mesh.shape["tp"])
+        if params is not None:
+            _check_fuse_tp(params, tp)
+        if params is None:
+            # Initialize directly into the sharded layout — no
+            # single-device staging (a 70B pytree never fits one chip).
+            params = jax.jit(
+                init_params,
+                static_argnums=(1, 2),
+                out_shardings=param_shardings(model_cfg, mesh),
+            )(jax.random.PRNGKey(seed), model_cfg, tp)
+        else:
+            params = shard_params(params, model_cfg, mesh)
+        cache = jax.jit(
+            partial(init_cache, model_cfg, engine_cfg),
+            out_shardings=cache_sharding(
+                mesh,
+                quantized=engine_cfg.kv_quantized,
+                num_layers=model_cfg.num_layers,
+            ),
+        )()
+    else:
+        if params is not None:
+            _check_fuse_tp(params, 1)
+            # Host pytrees (engine/loader.py returns numpy) land on
+            # device ONCE here; device arrays pass through untouched.
+            params = jax.device_put(params)
+        params = params if params is not None else init_params(
+            jax.random.PRNGKey(seed), model_cfg
+        )
+        cache = init_cache(model_cfg, engine_cfg)
+    return Placed(params, cache, dp, pp, pp_micro, batch_shardings)
