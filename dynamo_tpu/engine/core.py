@@ -22,6 +22,13 @@ Design notes:
   reality (parity: reference worker KV events, kv_router/publisher.rs).
 - Preemption = release everything + token-replay re-prefill (the same
   trick request migration uses across workers, migration.rs).
+
+This module is the step loop: admission, the planners, the dispatchers,
+commit, emission, the statistics. What the loop is built from lives beside
+it and imports nothing from here: the device programs (engine/programs.py),
+the KV that leaves the device (engine/kv_transfer.py, inherited), what an
+engine may be built with (engine/options.py) and where weights and cache
+are placed (parallel/placement.py).
 """
 
 from __future__ import annotations
@@ -47,57 +54,28 @@ from dynamo_tpu.engine.fair_queue import FairQueue
 from dynamo_tpu.engine.prefill_cover import cheapest_cover
 from dynamo_tpu.runtime.engine import EngineOverloadedError
 from dynamo_tpu.engine.model import embed_forward, expert_call_shape
-# The device programs and the lane format. What the dispatchers call, and what
-# others read through this module: chipbench/rehearse_v5e.py the two serving
-# programs and ``_program``; tests/test_sdar.py and tests/test_host_leg.py the
-# lane format, and they REBIND ``pack_lanes`` here, where ``_dispatch_megastep``
-# looks it up.
+# The device programs and the lane format: what ``__init__`` and the megastep's
+# dispatcher use, and what others read through this module. chipbench/
+# rehearse_v5e.py reads the two serving programs and ``_program``;
+# tests/test_sdar.py and tests/test_host_leg.py read the lane format, and they
+# REBIND ``pack_lanes`` here, where ``_dispatch_megastep`` looks it up.
 from dynamo_tpu.engine.programs import (  # noqa: F401
-    LANE_COLS,
-    MEGASTEP_WATCH_W,
-    _L_ACTIVE,
-    _L_BUDGET,
-    _L_COUNTER,
-    _L_FEED,
-    _L_MIN_LEFT,
-    _L_POSITION,
-    _L_SEED,
-    _L_TEMPERATURE,
-    _L_TOKEN,
-    _L_TOP_K,
-    _L_TOP_P,
-    _L_WATCH,
-    _megastep_blocks,
-    _megastep_body,
-    _megastep_draft_body,
-    _megastep_fused_body,
-    _pp_decode_chain,
-    _pp_prefill_and_sample,
-    _prefill_and_sample,
-    _program,
-    _ring_prefill_and_sample,
-    pack_lanes,
-    unpack_lanes,
+    LANE_COLS, MEGASTEP_WATCH_W, _L_ACTIVE, _L_BUDGET, _L_COUNTER, _L_FEED, _L_MIN_LEFT,
+    _L_POSITION, _L_SEED, _L_TEMPERATURE, _L_TOKEN, _L_TOP_K, _L_TOP_P, _L_WATCH,
+    _megastep_blocks, _megastep_body, _prefill_and_sample, _program, compile_programs,
+    pack_lanes, unpack_lanes,
 )
 # What an engine may be built with. tests/chipbench/test_chipbench_sdar.py and
 # tests/test_sdar.py read ``_resolve_block_megastep`` through this module.
-from dynamo_tpu.engine.options import (  # noqa: F401
-    _BLOCK_STEP,
-    _resolve_block_megastep,
-    resolve,
-)
+from dynamo_tpu.engine.options import _BLOCK_STEP, _resolve_block_megastep, resolve  # noqa: F401
 # What leaves the device and what comes back: the engine inherits it.
 from dynamo_tpu.engine.kv_transfer import (  # noqa: F401
-    ImportResult,
-    KvTransfer,
-    _copy_pages_fn,
-    _gather_pages_fn,
-    _scatter_pages_fn,
+    ImportResult, KvTransfer, _copy_pages_fn, _gather_pages_fn, _scatter_pages_fn,
     _slice_page_fn,
 )
 from dynamo_tpu.ops import grouped_matmul
 from dynamo_tpu.ops.ragged_attention import traced_impl
-from dynamo_tpu.engine.sampler import gather_feedback, hidden_at_most, pad_feedback
+from dynamo_tpu.engine.sampler import hidden_at_most
 from dynamo_tpu.llm.kv_router.protocols import ForwardPassMetrics, KvStats, WorkerStats
 from dynamo_tpu.spec import SpecConfig, SpecStats, propose_ngram, resolve_spec_config
 from dynamo_tpu.parallel.placement import place
@@ -463,20 +441,6 @@ class EngineCore(KvTransfer):
         )
         self._ring_H = engine_cfg.spec_window + engine_cfg.spec_ngram_max
         self.spec_stats = SpecStats()
-        # What the sparse layers counted, by the program that ran them:
-        # int64 [5] each (model._shared_sparse_mlp), landed with the
-        # tokens of every dispatch.
-        self.expert_stats = {
-            phase: np.zeros(5, np.int64) for phase in ("decode", "prefill")
-        }
-        # Where the rows of a model with conv layers found the state they
-        # read (model.conv_layer), counted at dispatch on the host: the
-        # pages written by an earlier iteration of the same megastep, by
-        # the sequence's own previous dispatch, or by whoever filled a
-        # shared block (a prefix hit; a resume after preemption).
-        self.conv_state_reads = {
-            source: 0 for source in ("same_step", "earlier_dispatch", "prefix_hit")
-        }
         self.cfg = model_cfg
         self.engine = engine_cfg
         self.eos_token_ids = set(eos_token_ids)
@@ -499,7 +463,6 @@ class EngineCore(KvTransfer):
             engine_cfg.num_window_blocks, bs, enable_prefix_caching=False,
         ) if model_cfg.windowed else None
         self._init_tiers(on_tier_stored, on_tier_removed)
-
         # The page programs (engine/kv_transfer.py), over this model's planes.
         ut = model_cfg.ut_steps
         self._slice_page = jax.jit(_program(_slice_page_fn, ut=ut))
@@ -576,6 +539,77 @@ class EngineCore(KvTransfer):
         # engine thread — the callback must be non-blocking and must not
         # re-enter the core (hop to the event loop to publish).
         self.on_chunk_commit = None
+        # Hold deadlines (monotonic): a decode-side timeout must not pin
+        # prefill blocks forever. Touched by the transfer endpoints, swept
+        # at the top of each step (before admission needs the blocks).
+        self._held_deadline: dict[str, float] = {}
+        self._init_counters()
+        # -- async pipelined execution (plan/dispatch/commit) ---------------
+        # At most ONE step is in flight; its _PlannedStep carries the
+        # optimistic advances the next plan overlays and the
+        # device-resident sampled tokens the next dispatch gathers from.
+        self._inflight: _PlannedStep | None = None
+        # Crash/stall flight recorder (ISSUE 13): one record per step
+        # with outputs — step shape, lane cursors, cumulative dispatch
+        # counters — dumped to a redacted JSON artifact on SIGTERM
+        # drain, stall-deadline fire, breaker open, and chaos kill. The
+        # record is a host-side dict append on the COMMIT side (never
+        # plan/dispatch); the backend CLI renames it to the worker id.
+        from dynamo_tpu.obs.flight_recorder import FlightRecorder
+
+        self.flight = FlightRecorder(f"engine-{id(self) & 0xFFFF:04x}")
+        # Test hook: set to [] to record ("dispatch", n) / ("land", n)
+        # events — the pipelining contract is that dispatch n+1 precedes
+        # the landing of step n's outputs in steady-state decode.
+        self._exec_log: list[tuple[str, int]] | None = None
+        self._dispatch_no = 0
+        # Decode-ready lanes (running, prefill done) as the step's planner
+        # counted them: what a wave leaves waiting (_mark_dispatch).
+        self._decode_ready = 0
+        # The serving programs (engine/programs.py). Attributes, read at every
+        # dispatch: chipbench/rehearse_v5e.py rebinds two on a live engine.
+        programs = compile_programs(
+            model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh, self._pp_micro)
+        self._prefill, self._decode = programs["_prefill"], programs["_decode"]
+        self._fused, self._drafted = programs["_fused"], programs["_drafted"]
+        self._prefill_pp, self._decode_pp = programs["_prefill_pp"], programs["_decode_pp"]
+        self._ring = programs["_ring"]
+        self._feed, self._feed_pad = programs["_feed"], programs["_feed_pad"]
+        # (a block-diffusion lane yields its blocks' places a dispatch)
+        blk = model_cfg.block_length
+        self._feed_width = (
+            (engine_cfg.megastep // (model_cfg.denoising_steps + 1) * blk if blk
+             else engine_cfg.megastep * self._spec_R)
+            * max(engine_cfg.decode_buckets[-1], engine_cfg.prefill_batch)
+        )
+        # What a megastep is handed where no lane is fed: zeros of the
+        # padded source's shape, placed as a step's sampled tokens are
+        # (_replicate_out), so that the megastep compiles once for both.
+        self._no_feed = jnp.zeros(self._feed_width, jnp.int32)
+        if mesh is not None or pp_mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self._no_feed = jax.device_put(
+                self._no_feed, NamedSharding(mesh or pp_mesh, PartitionSpec()))
+        self.sp_mesh = sp_mesh
+
+    def _init_counters(self) -> None:
+        """What the engine counts, zeroed: read by the statistics methods
+        at the end of this class and by the worker's /metrics."""
+        # What the sparse layers counted, by the program that ran them:
+        # int64 [5] each (model._shared_sparse_mlp), landed with the
+        # tokens of every dispatch.
+        self.expert_stats = {
+            phase: np.zeros(5, np.int64) for phase in ("decode", "prefill")
+        }
+        # Where the rows of a model with conv layers found the state they
+        # read (model.conv_layer), counted at dispatch on the host: the
+        # pages written by an earlier iteration of the same megastep, by
+        # the sequence's own previous dispatch, or by whoever filled a
+        # shared block (a prefix hit; a resume after preemption).
+        self.conv_state_reads = {
+            source: 0 for source in ("same_step", "earlier_dispatch", "prefix_hit")
+        }
         # Disagg transfer accounting (imported vs dropped must be
         # distinguishable — a half-dropped transfer silently recomputes on
         # the decode side). Surfaced via metrics().
@@ -586,10 +620,6 @@ class EngineCore(KvTransfer):
             "dropped_blocks": 0,
             "partial_transfers": 0,
         }
-        # Hold deadlines (monotonic): a decode-side timeout must not pin
-        # prefill blocks forever. Touched by the transfer endpoints, swept
-        # at the top of each step (before admission needs the blocks).
-        self._held_deadline: dict[str, float] = {}
         # Scheduler observability (status-server gauges + bench
         # attribution): the chunked-vs-waves decision needs visible queue
         # depth, per-step budget utilization, and preemption counts.
@@ -604,11 +634,6 @@ class EngineCore(KvTransfer):
             "shed_total": 0,
             "deadline_expired_total": 0,
         }
-        # -- async pipelined execution (plan/dispatch/commit) ---------------
-        # At most ONE step is in flight; its _PlannedStep carries the
-        # optimistic advances the next plan overlays and the
-        # device-resident sampled tokens the next dispatch gathers from.
-        self._inflight: _PlannedStep | None = None
         # Execution-pipeline counters (status surface + tests): drains
         # count forced pipeline flushes (block pressure mid-plan).
         self.exec_stats = {
@@ -686,132 +711,13 @@ class EngineCore(KvTransfer):
             "places_revealed_quota": 0,
             "block_places_discarded": 0,
         }
-        # Crash/stall flight recorder (ISSUE 13): one record per step
-        # with outputs — step shape, lane cursors, cumulative dispatch
-        # counters — dumped to a redacted JSON artifact on SIGTERM
-        # drain, stall-deadline fire, breaker open, and chaos kill. The
-        # record is a host-side dict append on the COMMIT side (never
-        # plan/dispatch); the backend CLI renames it to the worker id.
-        from dynamo_tpu.obs.flight_recorder import FlightRecorder
-
-        self.flight = FlightRecorder(f"engine-{id(self) & 0xFFFF:04x}")
-        # Test hook: set to [] to record ("dispatch", n) / ("land", n)
-        # events — the pipelining contract is that dispatch n+1 precedes
-        # the landing of step n's outputs in steady-state decode.
-        self._exec_log: list[tuple[str, int]] | None = None
-        self._dispatch_no = 0
-        # Decode-ready lanes (running, prefill done) as the step's planner
-        # counted them: what a wave leaves waiting (_mark_dispatch).
-        self._decode_ready = 0
         # Admission-time prefix-cache accounting (kv_prefix_cache_admitted_*
         # gauges). Separate from the allocator's match_prefix counters:
         # those count router/disagg probes, these count admitted sequences
         # whose prefix (device cache + host-tier onboard) was served.
         self._admit_prefix_queries = 0
         self._admit_prefix_hits = 0
-
-        self._prefill = jax.jit(
-            _program(_prefill_and_sample, cfg=model_cfg, engine=engine_cfg, mesh=mesh),
-            static_argnames=("need_mask", "all_greedy", "want_logprobs", "want_mm"),
-            donate_argnums=(1,),
-        )
-        # Device-resident token feedback: the next step's token buffer
-        # gathers just-sampled ids straight from the previous dispatch's
-        # device output (sampler.gather_feedback) — no D2H→H2D round trip
-        # on the decode critical path. The source is first padded to ONE
-        # flat width (_feed_pad: a program per output shape), so the
-        # gather compiles per token-buffer width and not per (previous
-        # width, next width) pair: serving crosses widths that warm-up's
-        # phases, one width at a time, never pair up. A megastep gathers
-        # inside its own program (unpack_lanes), from the same source.
-        self._feed = jax.jit(gather_feedback)
-        # (a block-diffusion lane yields its blocks' places a dispatch)
-        blk = model_cfg.block_length
-        self._feed_width = (
-            (engine_cfg.megastep // (model_cfg.denoising_steps + 1) * blk if blk
-             else engine_cfg.megastep * self._spec_R)
-            * max(engine_cfg.decode_buckets[-1], engine_cfg.prefill_batch)
-        )
-        self._feed_pad = jax.jit(pad_feedback, static_argnames=("width",))
-        # What a megastep is handed where no lane is fed: zeros of the
-        # padded source's shape, placed as a step's sampled tokens are
-        # (_replicate_out), so that the megastep compiles once for both.
-        self._no_feed = jnp.zeros(self._feed_width, jnp.int32)
-        if mesh is not None or pp_mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            self._no_feed = jax.device_put(
-                self._no_feed, NamedSharding(mesh or pp_mesh, PartitionSpec()))
-        self.sp_mesh = sp_mesh
-        self._ring = None
-        if sp_mesh is not None:
-            self._ring = jax.jit(
-                _program(
-                    _ring_prefill_and_sample,
-                    cfg=model_cfg, engine=engine_cfg, sp_mesh=sp_mesh,
-                ),
-                static_argnames=("need_mask", "all_greedy", "want_logprobs"),
-                donate_argnums=(1,),
-            )
         self._ring_prefills = 0  # observability: ring-path invocations
-        self._decode = jax.jit(
-            _program(_megastep_body, cfg=model_cfg, engine=engine_cfg, mesh=mesh),
-            static_argnames=("n_steps", "need_mask", "all_greedy", "want_logprobs"),
-            donate_argnums=(1,),
-        )
-        # The UNIVERSAL megastep (ISSUE 12): ragged first iteration
-        # (prefill chunks + decode rows + verify rows) fused with
-        # n_steps-1 scanned decode iterations in one dispatch; verify
-        # accept/reject resolves on device.
-        self._fused = jax.jit(
-            _program(
-                _megastep_fused_body, cfg=model_cfg, engine=engine_cfg,
-                mesh=mesh,
-            ),
-            static_argnames=(
-                "n_steps", "need_mask", "all_greedy", "want_logprobs",
-                "want_mm",
-            ),
-            donate_argnums=(1,),
-        )
-        # On-device drafting megastep (ISSUE 18): same ragged first
-        # iteration, but the n_steps-1 scanned iterations are
-        # verify-SHAPED — each round suffix-matches the per-lane history
-        # ring, verifies the fresh draft R-wide, resolves accept/reject,
-        # and redrafts, so draft→verify→accept loops inside one dispatch.
-        self._drafted = jax.jit(
-            _program(
-                _megastep_draft_body, cfg=model_cfg, engine=engine_cfg,
-                mesh=mesh,
-                ngram_max_static=engine_cfg.spec_ngram_max,
-            ),
-            static_argnames=(
-                "n_steps", "need_mask", "all_greedy", "want_logprobs",
-                "want_mm",
-            ),
-            donate_argnums=(1,),
-        )
-        self._prefill_pp = None
-        self._decode_pp = None
-        if pp_mesh is not None:
-            self._prefill_pp = jax.jit(
-                _program(
-                    _pp_prefill_and_sample, cfg=model_cfg, engine=engine_cfg,
-                    pp_mesh=pp_mesh, n_micro=self._pp_micro,
-                ),
-                static_argnames=("need_mask", "all_greedy", "want_logprobs"),
-                donate_argnums=(1,),
-            )
-            self._decode_pp = jax.jit(
-                _program(
-                    _pp_decode_chain, cfg=model_cfg, engine=engine_cfg,
-                    pp_mesh=pp_mesh, n_micro=self._pp_micro,
-                ),
-                static_argnames=(
-                    "n_steps", "need_mask", "all_greedy", "want_logprobs"
-                ),
-                donate_argnums=(1,),
-            )
 
     # -- request intake (any thread) --------------------------------------
 

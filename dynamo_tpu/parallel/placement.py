@@ -24,7 +24,6 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, NamedTuple
 
 import jax
 
@@ -138,25 +137,15 @@ def _check_fuse_tp(params, tp: int) -> None:
         )
 
 
-class Placed(NamedTuple):
-    """What :func:`place` hands ``EngineCore.__init__``."""
-
-    params: Any
-    cache: Any
-    dp: int
-    pp: int
-    pp_micro: int
-    batch_shardings: Any
-
-
-def place(model_cfg, engine_cfg, params, seed: int, mesh, pp_mesh) -> Placed:
-    """The weights and the cache where the kind of mesh puts them:
-    staged over ``pp_mesh`` (parallel/pipeline.py, the STACKED cache),
-    sharded over ``mesh`` (parallel/sharding.py), or on the one default
-    device. ``params`` None initialises them in place, seeded. Weights
-    that were fused for another tp are refused (:func:`_check_fuse_tp`);
-    what the configurations and the meshes alone decide was checked
-    before (engine/options.py)."""
+def place(model_cfg, engine_cfg, params, seed: int, mesh, pp_mesh) -> tuple:
+    """``(params, cache, dp, pp, pp_micro, batch_shardings)``: the weights
+    and the cache where the kind of mesh puts them, with its sizes: staged
+    over ``pp_mesh`` (parallel/pipeline.py, the STACKED cache), sharded
+    over ``mesh`` (parallel/sharding.py), or on the one default device.
+    ``params`` None initialises them in place, seeded. Weights fused for
+    another tp are refused (:func:`_check_fuse_tp`); what the
+    configurations and the meshes alone decide was checked before
+    (engine/options.py)."""
     from dynamo_tpu.engine.model import init_cache, init_params
 
     dp = pp = pp_micro = 1
@@ -244,4 +233,4 @@ def place(model_cfg, engine_cfg, params, seed: int, mesh, pp_mesh) -> Placed:
             jax.random.PRNGKey(seed), model_cfg
         )
         cache = init_cache(model_cfg, engine_cfg)
-    return Placed(params, cache, dp, pp, pp_micro, batch_shardings)
+    return params, cache, dp, pp, pp_micro, batch_shardings
