@@ -72,7 +72,7 @@ PRAGMA_ALLOWLIST: dict[tuple[str, str], int] = {
     # import_blocks_direct takes two instances of EngineCore._step_lock
     # under a global id()-ordered acquisition — mutual pulls can never
     # deadlock, which the analysis cannot prove but review did.
-    ("dynamo_tpu/engine/core.py", "lock-order"): 1,
+    ("dynamo_tpu/engine/kv_transfer.py", "lock-order"): 1,
 }
 
 
@@ -135,6 +135,17 @@ def test_deadlock_cycle_detected():
     assert len(lock_order) == 1, [str(f) for f in findings]
     msg = lock_order[0].message
     assert "_alock" in msg and "_block" in msg and "cycle" in msg
+
+
+def test_a_mixins_lock_is_its_inheritors():
+    # KvTransfer takes the step lock EngineCore constructs (ISSUE 45): a
+    # base's ``self._lock`` and its inheritor's are one identity.
+    findings = fixture_findings(["deadlock_pkg/mixin.py"])
+    lock_order = [f for f in findings if f.rule == C.RULE_LOCK_ORDER]
+    assert len(lock_order) == 1, [str(f) for f in findings]
+    msg = lock_order[0].message
+    assert "EngineSide._alock" in msg and "EngineSide._block" in msg and "cycle" in msg
+    assert "TransferSide._" not in msg
 
 
 def test_three_lock_cycle_reported_not_crashed():

@@ -58,7 +58,11 @@ PRAGMA_ALLOWLIST: dict[tuple[str, str, str], int] = {
     # plus the original _finish/_sweep_expired_holds/transfer endpoints.
     # +1 in ISSUE 12: the universal-megastep fused commit closure
     # (_plan_fused.commit) joins the verified chain.
-    ("dynamo_tpu/engine/core.py", "holds-lock", "_step_lock"): 17,
+    # 17 since ISSUE 12, in two files since ISSUE 45: the transfer
+    # endpoints' three (_sweep_expired_holds, _touch_hold,
+    # _account_transfer) moved with KvTransfer.
+    ("dynamo_tpu/engine/core.py", "holds-lock", "_step_lock"): 14,
+    ("dynamo_tpu/engine/kv_transfer.py", "holds-lock", "_step_lock"): 3,
     # Intentional syncs inside blocking-host-sync hot paths: the
     # double-buffered landing point (_PendingFetch.land — tokens +
     # batched logprobs, land_aux for the on-device draft round
@@ -66,7 +70,9 @@ PRAGMA_ALLOWLIST: dict[tuple[str, str, str], int] = {
     # which land with the tokens), np.asarray over host block-id lists (dispatch
     # assembly + ring prefill), and the host-tier page staging in
     # _stage_page (host buffer, not a device array).
-    ("dynamo_tpu/engine/core.py", "sync-ok", ""): 7,
+    # 7 in all; _stage_page's is KvTransfer's (ISSUE 45).
+    ("dynamo_tpu/engine/core.py", "sync-ok", ""): 6,
+    ("dynamo_tpu/engine/kv_transfer.py", "sync-ok", ""): 1,
     # Host-buffer asarray sites cleared by the dynacheck transitive-
     # blocking sweep: packed-page unpacking and pp microbatch planning
     # operate on host arrays only.
@@ -136,6 +142,7 @@ def test_registry_covers_promised_modules():
     # built for (ISSUE 1): engine core, block allocator, kv_router.
     files = set(C.GUARDED_BY)
     assert "dynamo_tpu/engine/core.py" in files
+    assert "dynamo_tpu/engine/kv_transfer.py" in files
     assert "dynamo_tpu/engine/block_allocator.py" in files
     assert any(f.startswith("dynamo_tpu/llm/kv_router/") for f in files)
 
@@ -214,6 +221,9 @@ def test_host_sync_hot_paths_cover_engine_core():
         "_dispatch_ragged", "_dispatch_megastep", "_plan_megastep",
         "_plan_step",
     } <= funcs
+    # the pipeline's device bodies, in the file the device programs live in
+    assert {"_pp_prefill_and_sample", "_pp_decode_chain"} <= C.HOT_STEP_FUNCS[
+        "dynamo_tpu/engine/programs.py"]
 
 
 def test_pragma_spans_cover_multiline_statements():

@@ -113,6 +113,8 @@ class Project:
     functions: dict[str, FuncInfo] = field(default_factory=dict)
     # class name -> {path of files defining it}
     classes: dict[str, set[str]] = field(default_factory=dict)
+    # class name -> its bases' names (a mixin's ``self`` is its inheritor's)
+    bases: dict[str, set[str]] = field(default_factory=dict)
     # known locks: (scope, attr) -> defining (path, line)
     locks: dict[LockId, tuple[str, int]] = field(default_factory=dict)
     # callers index (filled by resolve): func key -> [(caller key, CallSite)]
@@ -132,6 +134,22 @@ class Project:
     # dynalint sync-ok pragma lines (path, line): a transitive finding whose
     # blocking site is an intentional, already-reviewed sync is not news.
     sync_ok_lines: set[tuple[str, int]] = field(default_factory=set)
+
+    def lock_owner(self, cls: str, attr: str) -> str:
+        """The class whose ``self.<attr>`` lock an instance of ``cls``
+        holds: ``cls`` itself, or the one class up or down its line of
+        inheritance that constructs it (EngineCore builds the step lock
+        its base KvTransfer takes)."""
+        if (cls, attr) in self.locks:
+            return cls
+        line, todo = {cls}, [cls]
+        while todo:
+            c = todo.pop()
+            kin = self.bases.get(c, set()) | {d for d, b in self.bases.items() if c in b}
+            todo.extend(kin - line)
+            line |= kin
+        owners = [c for c in line if (c, attr) in self.locks]
+        return owners[0] if len(owners) == 1 else cls
 
     def suppressed(self, rule: str, path: str, line: int) -> bool:
         return (path, line) in self.allow_lines.get(rule, ())
@@ -317,7 +335,7 @@ class _FileScanner(ast.NodeVisitor):
             parts = d.split(".")
             if len(parts) == 2:
                 if self._class_stack:
-                    lid = (self._class_stack[-1], parts[1])
+                    lid = (self.project.lock_owner(self._class_stack[-1], parts[1]), parts[1])
                     if lid in self.project.locks or lock_like:
                         return lid
                 return None
@@ -859,6 +877,8 @@ def build_project(paths: list[Path], repo_root: Path) -> Project:
         for node in ast.walk(tree):
             if isinstance(node, ast.ClassDef):
                 project.classes.setdefault(node.name, set()).add(rel)
+                project.bases.setdefault(node.name, set()).update(
+                    d.rsplit(".", 1)[-1] for d in map(dotted_name, node.bases) if d)
         pre.append((rel, source, tree))
     for rel, source, tree in pre:
         _collect_locks(rel, tree, project)

@@ -287,7 +287,7 @@ WIRE_PLANE_FILES: dict[str, tuple[str, ...]] = {
     "dynamo_tpu/llm/kv_pool/peer_client.py": ("kvstream", "kvimport"),
     "dynamo_tpu/backends/jax/main.py": ("kvstream", "kvimport"),
     "dynamo_tpu/backends/mocker/main.py": ("kvstream",),
-    "dynamo_tpu/engine/core.py": ("kvimport",),
+    "dynamo_tpu/engine/kv_transfer.py": ("kvimport",),
 }
 
 # Call names whose dict-literal arguments are frame SEND sites: a raw

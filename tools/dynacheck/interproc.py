@@ -506,8 +506,9 @@ def check_registry_drift(project: Project) -> list[Finding]:
                 continue
             if lock == C.EXTERNAL:
                 continue
+            owner = scope and project.lock_owner(scope, lock)
             lock_exists = any(
-                lid[1] == lock and (scope is None or lid[0] == scope)
+                lid[1] == lock and (scope is None or lid[0] == owner)
                 for lid in project.locks
             )
             if not lock_exists:

@@ -82,7 +82,13 @@ GUARDED_BY = {
         # endpoints (server thread) and by step() (engine thread).
         ("EngineCore", "_held"): "_step_lock",
         ("EngineCore", "_held_deadline"): "_step_lock",
-        ("EngineCore", "transfer_stats"): "_step_lock",
+    },
+    "dynamo_tpu/engine/kv_transfer.py": {
+        # The same bookkeeping, where the transfer endpoints touch it
+        # (EngineCore inherits KvTransfer: one object, one lock).
+        ("KvTransfer", "_held"): "_step_lock",
+        ("KvTransfer", "_held_deadline"): "_step_lock",
+        ("KvTransfer", "transfer_stats"): "_step_lock",
     },
     "dynamo_tpu/engine/block_allocator.py": {
         # DeviceBlockAllocator is externally synchronized: every caller
@@ -175,9 +181,11 @@ HOT_STEP_FUNCS: dict[str, set[str]] = {
         "_merge_plans", "_dispatch_ragged", "_dispatch_megastep",
         "_dispatch_fused", "_assemble_ragged", "_grow_or_preempt",
         "_admit", "land",
-        # pp fast path (ISSUE 20): the fused pipeline device bodies — a
-        # host sync inside either would land INSIDE the traced wavefront
-        # scan and serialize every stage hop.
+    },
+    # pp fast path (ISSUE 20): the fused pipeline device bodies — a
+    # host sync inside either would land INSIDE the traced wavefront
+    # scan and serialize every stage hop.
+    "dynamo_tpu/engine/programs.py": {
         "_pp_prefill_and_sample", "_pp_decode_chain",
     },
     # pp microbatch planning (ISSUE 20): runs on the plan side of every
