@@ -755,7 +755,7 @@ class EngineConfig:
     # blocks a megastep, ``ModelConfig.denoising_steps + 1`` forwards each
     # (the last writes the clean block's K/V): the engine holds
     # ``megastep_k`` resolved to the largest multiple of that it holds, at
-    # least one block (core._resolve_block_megastep), so ``megastep`` is
+    # least one block (options._resolve_block_megastep), so ``megastep`` is
     # the forwards a dispatch fuses for every model.
 
     # Sequence-parallel long-context prefill: prompts at least this long

@@ -56,7 +56,7 @@ v5e, round 2):
   a prefill wave alike; pages, allocator and prefix index see nothing new.
   A generated block's K/V become FINAL when its revealed tokens run as
   clean rows, which is beside the first pass of the lane's next block
-  (:func:`block_hidden`'s ``pending`` half; ``core._megastep_blocks``) or
+  (:func:`block_hidden`'s ``pending`` half; ``programs._megastep_blocks``) or
   in a wave that takes them as prompt; until then they are those of a
   pass with places still masked, they lie past the lane's cursor
   (``num_computed_tokens``), and nothing that reads or publishes K/V looks
@@ -2141,7 +2141,7 @@ def block_logits(params: Params, hidden: jax.Array, rows: jax.Array | None,
     h]``), or of every row, a row a place, with ``rows`` None. A pass asks
     for the places that can still be hidden; a block's clean rows (the
     pending half of :func:`block_hidden`) never come here:
-    ``core._megastep_blocks``."""
+    ``programs._megastep_blocks``."""
     with jax.named_scope("lm_head"):
         return _logits(hidden if rows is None else hidden[rows], params, cfg)
 

@@ -283,7 +283,7 @@ def hidden_at_most(B: int, steps: int) -> tuple[int, ...]:
     every hidden place where they are fewer): 4, 2, 0 at ``B`` 4 and 2
     steps; 4, 3, 2, 1, 0 at 4. The last, 0, is what the last step leaves:
     a block's clean rows, which no head reads. A pass needs the head and the
-    sampler on no more rows a lane than that (``core._megastep_blocks``)."""
+    sampler on no more rows a lane than that (``programs._megastep_blocks``)."""
     out = [B]
     for step in range(steps):
         out.append(out[-1] - step_quota(B, steps, step))
