@@ -145,6 +145,9 @@ def _copy_pages_fn(src, dst, sids, dids, ut):
 
 
 class KvTransfer:
+    """The engine's methods that move KV pages off and onto the device
+    (the module's docstring lists the engine state they reach)."""
+
     def _init_tiers(self, on_tier_stored, on_tier_removed) -> None:
         """The host (G2) and disk (G3) pools ``self.engine`` asks for, and
         the tier callbacks; the allocator's eviction hook demotes into them."""
