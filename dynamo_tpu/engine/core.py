@@ -57,7 +57,7 @@ from dynamo_tpu.engine.model import embed_forward, expert_call_shape
 # The device programs and the lane format: what ``__init__`` and the megastep's
 # dispatcher use, and what others read through this module. chipbench/
 # rehearse_v5e.py reads the two serving programs and ``_program``;
-# tests/test_sdar.py and tests/test_host_leg.py read the lane format, and they
+# tests/test_sdar_megastep.py and tests/test_host_leg.py read the lane format, and they
 # REBIND ``pack_lanes`` here, where ``_dispatch_megastep`` looks it up.
 from dynamo_tpu.engine.programs import (  # noqa: F401
     LANE_COLS, MEGASTEP_WATCH_W, _L_ACTIVE, _L_BUDGET, _L_COUNTER, _L_FEED, _L_MIN_LEFT,
@@ -66,7 +66,7 @@ from dynamo_tpu.engine.programs import (  # noqa: F401
     pack_lanes, unpack_lanes,
 )
 # What an engine may be built with. tests/chipbench/test_chipbench_sdar.py and
-# tests/test_sdar.py read ``_resolve_block_megastep`` through this module.
+# tests/test_sdar_megastep.py read ``_resolve_block_megastep`` through this module.
 from dynamo_tpu.engine.options import _BLOCK_STEP, _resolve_block_megastep, resolve  # noqa: F401
 # What leaves the device and what comes back: the engine inherits it.
 from dynamo_tpu.engine.kv_transfer import (  # noqa: F401
