@@ -54,24 +54,14 @@ from dynamo_tpu.engine.fair_queue import FairQueue
 from dynamo_tpu.engine.prefill_cover import cheapest_cover
 from dynamo_tpu.runtime.engine import EngineOverloadedError
 from dynamo_tpu.engine.model import embed_forward, expert_call_shape
-# The device programs and the lane format: what ``__init__`` and the megastep's
-# dispatcher use, and what others read through this module. chipbench/
-# rehearse_v5e.py reads the two serving programs and ``_program``;
-# tests/test_sdar_megastep.py and tests/test_host_leg.py read the lane format, and they
+from dynamo_tpu.engine.kv_transfer import KvTransfer
+# chipbench/rehearse_v5e.py reads the two serving programs through this module
+# and tests/chipbench/test_chipbench_sdar.py ``_resolve_block_megastep``; tests
 # REBIND ``pack_lanes`` here, where ``_dispatch_megastep`` looks it up.
-from dynamo_tpu.engine.programs import (  # noqa: F401
-    LANE_COLS, MEGASTEP_WATCH_W, _L_ACTIVE, _L_BUDGET, _L_COUNTER, _L_FEED, _L_MIN_LEFT,
-    _L_POSITION, _L_SEED, _L_TEMPERATURE, _L_TOKEN, _L_TOP_K, _L_TOP_P, _L_WATCH,
-    _megastep_blocks, _megastep_body, _prefill_and_sample, _program, compile_programs,
-    pack_lanes, unpack_lanes,
-)
-# What an engine may be built with. tests/chipbench/test_chipbench_sdar.py and
-# tests/test_sdar_megastep.py read ``_resolve_block_megastep`` through this module.
 from dynamo_tpu.engine.options import _BLOCK_STEP, _resolve_block_megastep, resolve  # noqa: F401
-# What leaves the device and what comes back: the engine inherits it.
-from dynamo_tpu.engine.kv_transfer import (  # noqa: F401
-    ImportResult, KvTransfer, _copy_pages_fn, _gather_pages_fn, _scatter_pages_fn,
-    _slice_page_fn,
+from dynamo_tpu.engine.programs import (  # noqa: F401
+    MEGASTEP_WATCH_W, _megastep_body, _prefill_and_sample, _program, compile_programs,
+    pack_lanes,
 )
 from dynamo_tpu.ops import grouped_matmul
 from dynamo_tpu.ops.ragged_attention import traced_impl
@@ -463,15 +453,6 @@ class EngineCore(KvTransfer):
             engine_cfg.num_window_blocks, bs, enable_prefix_caching=False,
         ) if model_cfg.windowed else None
         self._init_tiers(on_tier_stored, on_tier_removed)
-        # The page programs (engine/kv_transfer.py), over this model's planes.
-        ut = model_cfg.ut_steps
-        self._slice_page = jax.jit(_program(_slice_page_fn, ut=ut))
-        self._gather_pages = jax.jit(_program(_gather_pages_fn, ut=ut))
-        self._scatter_pages = jax.jit(_program(_scatter_pages_fn, ut=ut), donate_argnums=(0,))
-        # Device-direct cache->cache block copy (one program: gather from
-        # the source cache, scatter into ours — no host staging and no
-        # intermediate buffer). Requires matching layouts on both cores.
-        self._copy_pages_from = jax.jit(_program(_copy_pages_fn, ut=ut), donate_argnums=(1,))
 
         self._inbox: deque[Sequence] = deque()   # thread-safe enqueue
         # Admission queue: per-tenant deficit-round-robin over prompt
@@ -543,6 +524,16 @@ class EngineCore(KvTransfer):
         # prefill blocks forever. Touched by the transfer endpoints, swept
         # at the top of each step (before admission needs the blocks).
         self._held_deadline: dict[str, float] = {}
+        # Disagg transfer accounting (imported vs dropped must be
+        # distinguishable — a half-dropped transfer silently recomputes on
+        # the decode side). Surfaced via metrics().
+        self.transfer_stats = {
+            "transfers": 0,
+            "imported_blocks": 0,
+            "skipped_cached_blocks": 0,
+            "dropped_blocks": 0,
+            "partial_transfers": 0,
+        }
         self._init_counters()
         # -- async pipelined execution (plan/dispatch/commit) ---------------
         # At most ONE step is in flight; its _PlannedStep carries the
@@ -568,13 +559,9 @@ class EngineCore(KvTransfer):
         self._decode_ready = 0
         # The serving programs (engine/programs.py). Attributes, read at every
         # dispatch: chipbench/rehearse_v5e.py rebinds two on a live engine.
-        programs = compile_programs(
+        (self._prefill, self._ring, self._decode, self._fused, self._drafted,
+         self._prefill_pp, self._decode_pp, self._feed, self._feed_pad) = compile_programs(
             model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh, self._pp_micro)
-        self._prefill, self._decode = programs["_prefill"], programs["_decode"]
-        self._fused, self._drafted = programs["_fused"], programs["_drafted"]
-        self._prefill_pp, self._decode_pp = programs["_prefill_pp"], programs["_decode_pp"]
-        self._ring = programs["_ring"]
-        self._feed, self._feed_pad = programs["_feed"], programs["_feed_pad"]
         # (a block-diffusion lane yields its blocks' places a dispatch)
         blk = model_cfg.block_length
         self._feed_width = (
@@ -609,16 +596,6 @@ class EngineCore(KvTransfer):
         # shared block (a prefix hit; a resume after preemption).
         self.conv_state_reads = {
             source: 0 for source in ("same_step", "earlier_dispatch", "prefix_hit")
-        }
-        # Disagg transfer accounting (imported vs dropped must be
-        # distinguishable — a half-dropped transfer silently recomputes on
-        # the decode side). Surfaced via metrics().
-        self.transfer_stats = {
-            "transfers": 0,
-            "imported_blocks": 0,
-            "skipped_cached_blocks": 0,
-            "dropped_blocks": 0,
-            "partial_transfers": 0,
         }
         # Scheduler observability (status-server gauges + bench
         # attribution): the chunked-vs-waves decision needs visible queue

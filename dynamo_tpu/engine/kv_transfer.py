@@ -28,8 +28,6 @@ class to this list):
 - ``running``: a hold that is still prefilling is served from here.
 - ``transfer_stats``: the import's counters.
 - ``_release_blocks``: gives a hold's blocks back.
-- ``_slice_page``, ``_gather_pages``, ``_scatter_pages``,
-  ``_copy_pages_from``: the page programs below, jitted by the constructor.
 
 (End of the list.)
 """
@@ -48,6 +46,7 @@ import numpy as np
 from dynamo_tpu.engine.block_allocator import OutOfBlocksError
 from dynamo_tpu.engine.config import UnsupportedModelOption
 from dynamo_tpu.engine.options import _TWO_POOLS, _TWO_SHAPES
+from dynamo_tpu.engine.programs import _program
 from dynamo_tpu.parallel.multihost import fetch_replicated
 from dynamo_tpu.runtime import wire
 from dynamo_tpu.tokens import compute_seq_hashes
@@ -149,8 +148,17 @@ class KvTransfer:
     (the module's docstring lists the engine state they reach)."""
 
     def _init_tiers(self, on_tier_stored, on_tier_removed) -> None:
-        """The host (G2) and disk (G3) pools ``self.engine`` asks for, and
-        the tier callbacks; the allocator's eviction hook demotes into them."""
+        """The page programs over this model's planes, the host (G2) and
+        disk (G3) pools ``self.engine`` asks for, and the tier callbacks;
+        the allocator's eviction hook demotes into the pools."""
+        ut = self.cfg.ut_steps
+        self._slice_page = jax.jit(_program(_slice_page_fn, ut=ut))
+        self._gather_pages = jax.jit(_program(_gather_pages_fn, ut=ut))
+        self._scatter_pages = jax.jit(_program(_scatter_pages_fn, ut=ut), donate_argnums=(0,))
+        # Device-direct cache->cache block copy (one program: gather from
+        # the source cache, scatter into ours — no host staging and no
+        # intermediate buffer). Requires matching layouts on both cores.
+        self._copy_pages_from = jax.jit(_program(_copy_pages_fn, ut=ut), donate_argnums=(1,))
         self.host_pool = None
         self.disk_pool = None
         self.offload = None
@@ -553,19 +561,6 @@ class KvTransfer:
             self._held_deadline[request_id] = (
                 time.monotonic() + self.engine.held_block_ttl_s
             )
-
-    def chunk_cursor(self, request_id: str) -> tuple[int, bool]:
-        """The streaming-handoff cursor: (committed blocks readable now,
-        prefill finished). KeyError when the request holds nothing —
-        either never seen or already released (pullers fall back)."""
-        with self._step_lock:
-            seq = self._held.get(request_id)
-            if seq is not None:
-                return seq.committed_blocks, True
-            seq = self._streaming_seq(request_id)
-            if seq is None:
-                raise KeyError(f"no held blocks for request {request_id}")
-            return seq.committed_blocks, False
 
     def release_held(self, request_id: str) -> None:
         with self._step_lock:

@@ -309,19 +309,17 @@ def resolve(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh):
             raise ValueError(
                 f"pp={pp} must divide vocab_size={model_cfg.vocab_size}"
             )
-        # as many microbatches as stages (parallel/placement.py)
-        for b in engine_cfg.prefill_buckets:
-            if b % pp:
-                raise ValueError(
-                    f"prefill bucket {b} not a multiple of pp microbatch "
-                    f"count {pp}"
-                )
-        for b in engine_cfg.decode_buckets:
-            if b % pp:
-                raise ValueError(
-                    f"decode bucket {b} not a multiple of pp microbatch "
-                    f"count {pp}"
-                )
+        from dynamo_tpu.parallel.pipeline import pp_microbatches
+
+        micro = pp_microbatches(pp)
+        for kind, buckets in (("prefill", engine_cfg.prefill_buckets),
+                              ("decode", engine_cfg.decode_buckets)):
+            for b in buckets:
+                if b % micro:
+                    raise ValueError(
+                        f"{kind} bucket {b} not a multiple of pp microbatch "
+                        f"count {micro}"
+                    )
     elif mesh is not None:
         dp = int(mesh.shape["dp"])
         for b in engine_cfg.decode_buckets:

@@ -845,41 +845,38 @@ _CHAIN = ("n_steps", *_ONE)
 _CHAIN_MM = (*_CHAIN, "want_mm")
 
 
-def compile_programs(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh, pp_micro) -> dict:
-    """The engine's jitted programs, by the attribute the dispatchers read
-    each from (``EngineCore.__init__`` binds them; a ring's and a
-    pipeline's are None where there is no such mesh). Every serving
-    program takes the cache second and is given it (``donate_argnums``);
-    nothing compiles here: a program is lowered at its first call."""
+def compile_programs(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh, pp_micro) -> tuple:
+    """The engine's jitted programs, in the order ``EngineCore.__init__``
+    binds them to the attributes the dispatchers read: ``_prefill``,
+    ``_ring``, ``_decode``, ``_fused``, ``_drafted``, ``_prefill_pp``,
+    ``_decode_pp``, ``_feed``, ``_feed_pad`` (a ring's and a pipeline's
+    are None where there is no such mesh). Every serving program takes the
+    cache second and is given it (``donate_argnums``); nothing compiles
+    here: a program is lowered at its first call."""
     both = dict(cfg=model_cfg, engine=engine_cfg)
     on_mesh = dict(both, mesh=mesh)
     staged = dict(both, pp_mesh=pp_mesh, n_micro=pp_micro)
     ring, pipe = sp_mesh is not None, pp_mesh is not None
-    # attribute, program (None: not built), what is bound, its static names
+    # program (None: not built), what is bound, its static names
     table = (
-        ("_prefill", _prefill_and_sample, on_mesh, _ONE_MM),
-        ("_ring", _ring_prefill_and_sample if ring else None, dict(both, sp_mesh=sp_mesh), _ONE),
-        ("_decode", _megastep_body, on_mesh, _CHAIN),
+        (_prefill_and_sample, on_mesh, _ONE_MM),
+        (_ring_prefill_and_sample if ring else None, dict(both, sp_mesh=sp_mesh), _ONE),
+        (_megastep_body, on_mesh, _CHAIN),
         # The UNIVERSAL megastep (ISSUE 12): ragged first iteration
         # (prefill chunks + decode rows + verify rows) fused with
         # n_steps-1 scanned decode iterations in one dispatch; verify
         # accept/reject resolves on device.
-        ("_fused", _megastep_fused_body, on_mesh, _CHAIN_MM),
+        (_megastep_fused_body, on_mesh, _CHAIN_MM),
         # On-device drafting megastep (ISSUE 18): same ragged first
         # iteration, but the n_steps-1 scanned iterations are
         # verify-SHAPED — each round suffix-matches the per-lane history
         # ring, verifies the fresh draft R-wide, resolves accept/reject,
         # and redrafts, so draft→verify→accept loops inside one dispatch.
-        ("_drafted", _megastep_draft_body,
+        (_megastep_draft_body,
          dict(on_mesh, ngram_max_static=engine_cfg.spec_ngram_max), _CHAIN_MM),
-        ("_prefill_pp", _pp_prefill_and_sample if pipe else None, staged, _ONE),
-        ("_decode_pp", _pp_decode_chain if pipe else None, staged, _CHAIN),
+        (_pp_prefill_and_sample if pipe else None, staged, _ONE),
+        (_pp_decode_chain if pipe else None, staged, _CHAIN),
     )
-    programs = {
-        attr: fn and jax.jit(
-            _program(fn, **bound), static_argnames=static, donate_argnums=(1,))
-        for attr, fn, bound, static in table
-    }
     # Device-resident token feedback: the next step's token buffer
     # gathers just-sampled ids straight from the previous dispatch's
     # device output (sampler.gather_feedback) — no D2H→H2D round trip
@@ -889,6 +886,9 @@ def compile_programs(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh, pp_micro) ->
     # width, next width) pair: serving crosses widths that warm-up's
     # phases, one width at a time, never pair up. A megastep gathers
     # inside its own program (unpack_lanes), from the same source.
-    programs["_feed"] = jax.jit(gather_feedback)
-    programs["_feed_pad"] = jax.jit(pad_feedback, static_argnames=("width",))
-    return programs
+    return (
+        *(fn and jax.jit(_program(fn, **bound), static_argnames=static, donate_argnums=(1,))
+          for fn, bound, static in table),
+        jax.jit(gather_feedback),
+        jax.jit(pad_feedback, static_argnames=("width",)),
+    )

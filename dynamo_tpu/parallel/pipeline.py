@@ -70,6 +70,14 @@ def make_pp_mesh(pp: int, devices=None) -> Mesh:
     return Mesh(np.asarray(devices[:pp]), ("pp",))
 
 
+def pp_microbatches(pp: int) -> int:
+    """Microbatches a step runs as over ``pp`` stages: the wavefront
+    schedule needs M >= pp for the ring-fed token feedback; M = pp also
+    makes per-step lm-head traffic match the unpipelined engine (V/pp per
+    stage). The buckets are held to it (engine/options.py)."""
+    return pp
+
+
 def pp_param_specs(cfg: ModelConfig, pp: int) -> dict[str, Any]:
     """PartitionSpecs for `model.init_params` pytrees under PP: stacked
     layer arrays shard axis 0 over ``pp``; embeddings/norms replicate

@@ -150,23 +150,23 @@ def place(model_cfg, engine_cfg, params, seed: int, mesh, pp_mesh) -> tuple:
 
     dp = pp = pp_micro = 1
     batch_shardings = None
+    tp = int(mesh.shape["tp"]) if mesh is not None else 1  # pp stages keep tp=1 layouts
+    if params is not None:
+        _check_fuse_tp(params, tp)
     if pp_mesh is not None:
         from dynamo_tpu.parallel.pipeline import (
             cache_sharding_pp,
+            pp_microbatches,
             pp_param_specs,
             shard_params_pp,
         )
 
         pp = int(pp_mesh.shape["pp"])
-        # Microbatch count: the wavefront schedule needs M >= pp for
-        # the ring-fed token feedback; M = pp also makes per-step lm-
-        # head traffic match the unpipelined engine (V/pp per stage).
-        pp_micro = pp
+        pp_micro = pp_microbatches(pp)
         if params is not None:
             # int8 params ({'w','scale'} dict leaves) shard like any
             # stacked layer array: both members carry the layer axis
             # first, so shard_params_pp places the pair per stage.
-            _check_fuse_tp(params, 1)  # pp stages keep tp=1 layouts
             params = shard_params_pp(params, model_cfg, pp_mesh)
         else:
             from jax.sharding import NamedSharding
@@ -202,9 +202,6 @@ def place(model_cfg, engine_cfg, params, seed: int, mesh, pp_mesh) -> tuple:
 
         dp = int(mesh.shape["dp"])
         batch_shardings = decode_batch_shardings(mesh)
-        tp = int(mesh.shape["tp"])
-        if params is not None:
-            _check_fuse_tp(params, tp)
         if params is None:
             # Initialize directly into the sharded layout — no
             # single-device staging (a 70B pytree never fits one chip).
@@ -225,7 +222,6 @@ def place(model_cfg, engine_cfg, params, seed: int, mesh, pp_mesh) -> tuple:
         )()
     else:
         if params is not None:
-            _check_fuse_tp(params, 1)
             # Host pytrees (engine/loader.py returns numpy) land on
             # device ONCE here; device arrays pass through untouched.
             params = jax.device_put(params)

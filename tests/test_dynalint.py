@@ -145,6 +145,12 @@ def test_registry_covers_promised_modules():
     assert "dynamo_tpu/engine/kv_transfer.py" in files
     assert "dynamo_tpu/engine/block_allocator.py" in files
     assert any(f.startswith("dynamo_tpu/llm/kv_router/") for f in files)
+    # held-block bookkeeping and the import's counters: one lock, in the step
+    # loop's file and in the file of the endpoints it inherits
+    for path, cls in (("dynamo_tpu/engine/core.py", "EngineCore"),
+                      ("dynamo_tpu/engine/kv_transfer.py", "KvTransfer")):
+        for attr in ("_held", "_held_deadline", "transfer_stats"):
+            assert C.GUARDED_BY[path][(cls, attr)] == "_step_lock", (path, attr)
 
 
 # ---------------------------------------------------------------------------

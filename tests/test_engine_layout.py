@@ -10,6 +10,7 @@ at every dispatch.
 from __future__ import annotations
 
 import ast
+import dataclasses
 import re
 from pathlib import Path
 
@@ -81,27 +82,40 @@ def test_kv_transfer_touches_the_engine_state_its_docstring_lists():
     assert issubclass(EngineCore, kv_transfer.KvTransfer)
 
 
+# what ``resolve`` fills in beside ``enable_prefix_caching`` (None -> True), as
+# the parent's four functions in ``core.py`` returned it for the same input
+RESOLVED = {"tiny-laguna": dict(num_window_blocks=80, enable_prefix_caching=False),
+            "tiny-sdar": dict(megastep_k=3)}
+NAMES = ("_prefill_and_sample", None, "_megastep_body", "_megastep_fused_body",
+         "_megastep_draft_body", None, None, "gather_feedback", "pad_feedback")
+
+
 @pytest.mark.parametrize("preset", TINY)
-def test_the_programs_keep_their_names_and_resolve_is_the_four_functions(preset):
+def test_the_programs_keep_their_names_and_resolve_fills_in_what_it_did(preset):
     cfg, eng = PRESETS[preset](), tiny_engine(megastep_k=4, **ENGINE.get(preset, {}))
     model_cfg, engine_cfg = options.resolve(cfg, eng, None, None, None)
-    # the four functions, in the order EngineCore.__init__ always ran them
-    want_model = options._unpaired_where_not_carried(cfg, eng, None, None, None)
-    options._refuse_uncarried_options(want_model, eng, None, None, None)
-    want_engine = options._resolve_block_megastep(
-        want_model, options._resolve_window_pool(want_model, eng))
-    assert (model_cfg, engine_cfg) == (want_model, want_engine)
-    assert engine_cfg.enable_prefix_caching is (not cfg.windowed)
+    assert model_cfg == cfg
+    assert engine_cfg == dataclasses.replace(
+        eng, **{"enable_prefix_caching": True, **RESOLVED.get(preset, {})})
 
+    # ``_prefill``, ``_ring``, ``_decode``, ``_fused``, ``_drafted``, ``_prefill_pp``,
+    # ``_decode_pp``, ``_feed``, ``_feed_pad``: the profile's module names
+    # (chipbench/layer_metrics/*.json, chipbench/trace/); no ring, no pipeline
     built = programs.compile_programs(model_cfg, engine_cfg, None, None, None, 1)
-    # the profile's module names (chipbench/layer_metrics/*.json, chipbench/trace/)
-    assert built["_prefill"].__name__ == "_prefill_and_sample"
-    assert built["_decode"].__name__ == "_megastep_body"
-    assert {k: v.__name__ for k, v in built.items() if v is not None} == {
-        "_prefill": "_prefill_and_sample", "_decode": "_megastep_body",
-        "_fused": "_megastep_fused_body", "_drafted": "_megastep_draft_body",
-        "_feed": "gather_feedback", "_feed_pad": "pad_feedback"}
-    assert built["_ring"] is built["_prefill_pp"] is built["_decode_pp"] is None
+    assert tuple(p and p.__name__ for p in built) == NAMES
+
+
+@pytest.mark.parametrize("kind, buckets", [("prefill", (24, 64)), ("decode", (3, 4))])
+def test_buckets_are_held_to_the_pipelines_one_microbatch_count(kind, buckets):
+    # one definition (parallel/pipeline.py) read by ``resolve`` and by ``place``
+    from dynamo_tpu.parallel.pipeline import make_pp_mesh, pp_microbatches
+
+    assert pp_microbatches(3) == 3
+    eng = tiny_engine(**{"prefill_buckets": (24, 48), f"{kind}_buckets": buckets})
+    with pytest.raises(ValueError, match=f"{kind} bucket {buckets[-1]} not a multiple of pp "
+                                         "microbatch count 3"):
+        options.resolve(dataclasses.replace(PRESETS["tiny"](), num_layers=3, vocab_size=258),
+                        eng, None, None, make_pp_mesh(3))
 
 
 def test_what_others_read_through_core_and_rebind_on_a_live_engine():
@@ -110,9 +124,7 @@ def test_what_others_read_through_core_and_rebind_on_a_live_engine():
     assert core_mod._megastep_body is programs._megastep_body
     assert core_mod._program is programs._program
     assert core_mod._resolve_block_megastep is options._resolve_block_megastep
-    assert core_mod.ImportResult is kv_transfer.ImportResult
-    assert core_mod.pack_lanes is programs.pack_lanes
-    assert core_mod.LANE_COLS == programs._L_WATCH + core_mod.MEGASTEP_WATCH_W
+    assert core_mod.pack_lanes is programs.pack_lanes   # tests/test_host_leg.py rebinds it
 
     # chipbench/rehearse_v5e.py:88-89 rebinds the two on a built engine
     core = EngineCore(PRESETS["tiny"](), tiny_engine(megastep_k=4), seed=0)

@@ -20,7 +20,7 @@ from dynamo_tpu.backends.jax.main import StepKvEvents
 from dynamo_tpu.engine import EngineCore, tiny_engine, tiny_model
 from dynamo_tpu.engine import core as core_mod
 from dynamo_tpu.engine.config import tiny_laguna, tiny_lfm2
-from dynamo_tpu.engine.core import (
+from dynamo_tpu.engine.programs import (
     LANE_COLS,
     MEGASTEP_WATCH_W,
     pack_lanes,

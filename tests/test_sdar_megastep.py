@@ -30,8 +30,10 @@ from dynamo_tpu.engine.config import (
     tiny_moe,
     tiny_sdar,
 )
-from dynamo_tpu.engine import core as core_mod
-from dynamo_tpu.engine.core import EngineCore, _megastep_blocks, _resolve_block_megastep
+from dynamo_tpu.engine import programs
+from dynamo_tpu.engine.core import EngineCore
+from dynamo_tpu.engine.options import _resolve_block_megastep
+from dynamo_tpu.engine.programs import _megastep_blocks
 from dynamo_tpu.engine.model import block_hidden, block_logits, init_cache, init_params
 from dynamo_tpu.engine.sampler import (
     LOGPROBS_K,
@@ -71,12 +73,12 @@ def _plain_megastep_blocks(params, cache, lanes, block_tables, known, *, n_steps
     B, steps = cfg.block_length, cfg.denoising_steps
     n_blocks, S, K = n_steps // (steps + 1), lanes.shape[0], LOGPROBS_K
     f32 = lambda col: jax.lax.bitcast_convert_type(lanes[:, col], jnp.float32)  # noqa: E731
-    position, active = lanes[:, core_mod._L_POSITION], lanes[:, core_mod._L_ACTIVE] != 0
-    seeds = jnp.repeat(lanes[:, core_mod._L_SEED], B)
+    position, active = lanes[:, programs._L_POSITION], lanes[:, programs._L_ACTIVE] != 0
+    seeds = jnp.repeat(lanes[:, programs._L_SEED], B)
     temperature, top_k, top_p = (
-        jnp.repeat(f32(core_mod._L_TEMPERATURE), B), jnp.repeat(lanes[:, core_mod._L_TOP_K], B),
-        jnp.repeat(f32(core_mod._L_TOP_P), B))
-    watch, min_left = lanes[:, core_mod._L_WATCH:], lanes[:, core_mod._L_MIN_LEFT]
+        jnp.repeat(f32(programs._L_TEMPERATURE), B), jnp.repeat(lanes[:, programs._L_TOP_K], B),
+        jnp.repeat(f32(programs._L_TOP_P), B))
+    watch, min_left = lanes[:, programs._L_WATCH:], lanes[:, programs._L_MIN_LEFT]
     place = jnp.arange(B, dtype=jnp.int32)
 
     def one_pass(carry, p):
@@ -131,7 +133,7 @@ def _plain_megastep_blocks(params, cache, lanes, block_tables, known, *, n_steps
 
     (cache, _, _, _, _, counts), (tokens, step_of, lps, ran) = jax.lax.scan(
         one_block,
-        (cache, position, jnp.ones_like(active), lanes[:, core_mod._L_BUDGET], min_left,
+        (cache, position, jnp.ones_like(active), lanes[:, programs._L_BUDGET], min_left,
          jnp.zeros(2, jnp.int32)),
         jnp.arange(n_blocks))
     aux = jnp.concatenate([step_of.reshape(-1), ran.astype(jnp.int32).reshape(-1), counts])
@@ -149,9 +151,9 @@ def _megastep_inputs(cfg, engine, temperature, masked, seed=0):
     params = init_params(jax.random.PRNGKey(seed), cfg)
     cache = tuple(jnp.asarray(rs.randn(*c.shape), c.dtype) for c in init_cache(cfg, engine))
     tables = jnp.asarray(np.arange(S * pages).reshape(S, pages), jnp.int32)
-    watch = np.full((S, core_mod.MEGASTEP_WATCH_W), -1, np.int32)
-    watch[6, :] = np.arange(40, 40 + core_mod.MEGASTEP_WATCH_W * 40, 40)   # some id will come
-    lanes = core_mod.pack_lanes(
+    watch = np.full((S, programs.MEGASTEP_WATCH_W), -1, np.int32)
+    watch[6, :] = np.arange(40, 40 + programs.MEGASTEP_WATCH_W * 40, 40)   # some id will come
+    lanes = programs.pack_lanes(
         tokens=np.zeros(S, np.int32), feed_idx=None,
         positions=np.asarray([8, 16, 12, 8, 8, 20, 8, 24], np.int32),
         active=np.asarray([1, 1, 1, 1, 0, 1, 1, 1], np.int32),
@@ -184,8 +186,8 @@ def _positions_of(lanes, tables, pages, page, blocks_of):
     mask = np.zeros((pages, page), bool)
     for i, blocks in enumerate(blocks_of):
         for b in blocks:
-            for pos in range(int(lanes[i, core_mod._L_POSITION]) + b * B,
-                             int(lanes[i, core_mod._L_POSITION]) + (b + 1) * B):
+            for pos in range(int(lanes[i, programs._L_POSITION]) + b * B,
+                             int(lanes[i, programs._L_POSITION]) + (b + 1) * B):
                 mask[int(tables[i, pos // page]), pos % page] = True
     return mask
 
@@ -263,11 +265,11 @@ def test_the_megastep_is_bit_equal_to_its_plain_form(steps, temperature, thresho
     # device, lane 7 handed its block by the host; 4-6 are over
     alive = np.asarray([1, 1, 1, 1, 0, 0, 0, 1], np.int32)
     again = lanes_np.copy()
-    again[:, core_mod._L_POSITION] += 3 * B
-    again[:, core_mod._L_ACTIVE] = alive
+    again[:, programs._L_POSITION] += 3 * B
+    again[:, programs._L_ACTIVE] = alive
     plain_lanes = jnp.asarray(again)
-    again[:, core_mod._L_FEED] = np.where(alive == 1, (2 * S + np.arange(S)) * B, -1)
-    again[7, core_mod._L_FEED] = -1
+    again[:, programs._L_FEED] = np.where(alive == 1, (2 * S + np.arange(S)) * B, -1)
+    again[7, programs._L_FEED] = -1
     from_host = np.full((S, B), -1, np.int32)
     from_host[7] = np.asarray(got[0])[2, 7]
     nothing = jnp.full((S, B), -1, jnp.int32)
@@ -368,7 +370,7 @@ def test_the_block_megastep_holds_one_stack(steps):
     shapes = (
         jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)),
         jax.eval_shape(lambda: init_cache(cfg, engine)),
-        jax.ShapeDtypeStruct((S, core_mod.LANE_COLS), jnp.int32),
+        jax.ShapeDtypeStruct((S, programs.LANE_COLS), jnp.int32),
         jax.ShapeDtypeStruct((S, 10), jnp.int32),
         jax.ShapeDtypeStruct((2 * S * B,), jnp.int32),
         jax.ShapeDtypeStruct((S, 2, B), jnp.int32))

@@ -74,21 +74,19 @@ BLOCKING_ROOTS = {
 
 EXTERNAL = "<external>"
 
+# Held-block bookkeeping and the import's counters are touched by the disagg
+# transfer endpoints (server thread, engine/kv_transfer.py) and by step()
+# (engine thread): EngineCore inherits KvTransfer, one object, one lock.
+_HELD_BLOCKS = ("_held", "_held_deadline", "transfer_stats")
+
 GUARDED_BY = {
     "dynamo_tpu/engine/core.py": {
         # add_request() is documented as callable from any thread.
         ("EngineCore", "_req_counter"): "_lock",
-        # Held-block bookkeeping is touched by the disagg transfer
-        # endpoints (server thread) and by step() (engine thread).
-        ("EngineCore", "_held"): "_step_lock",
-        ("EngineCore", "_held_deadline"): "_step_lock",
+        **{("EngineCore", attr): "_step_lock" for attr in _HELD_BLOCKS},
     },
     "dynamo_tpu/engine/kv_transfer.py": {
-        # The same bookkeeping, where the transfer endpoints touch it
-        # (EngineCore inherits KvTransfer: one object, one lock).
-        ("KvTransfer", "_held"): "_step_lock",
-        ("KvTransfer", "_held_deadline"): "_step_lock",
-        ("KvTransfer", "transfer_stats"): "_step_lock",
+        ("KvTransfer", attr): "_step_lock" for attr in _HELD_BLOCKS
     },
     "dynamo_tpu/engine/block_allocator.py": {
         # DeviceBlockAllocator is externally synchronized: every caller
