@@ -5,6 +5,7 @@
     python chip_smoke.py --cpu-tiny --cpu-preset tiny-lfm2   # the same, a hybrid model
     python chip_smoke.py --cpu-tiny --cpu-preset tiny-laguna # the same, window + full layers
     python chip_smoke.py --cpu-tiny --cpu-preset tiny-sdar   # the same, generation by blocks
+    python chip_smoke.py --cpu-tiny --cpu-preset tiny-mimo   # the same, the wide-key page
 
 Starts the three processes a user starts (README "Run it"): the control-
 plane store, the JAX worker and the OpenAI frontend with the KV router.
@@ -543,7 +544,8 @@ def judge_attention_traced(role: str, got: dict[str, float], platform: str) -> N
     """``got``: ``{"<shape>/<impl>": calls traced}`` of one worker."""
     traced = {k for k, v in got.items() if v}
     if role != "prefill" and not any(
-            k.split("/")[0] in ("decode", "latent-decode", "block-decode") for k in traced):
+            k.split("/")[0] in ("decode", "latent-decode", "block-decode", "gqa-decode")
+            for k in traced):
         raise PhaseFailed(f"{role}: no decode-shaped attention call was traced: {got}")
     if platform == "tpu" and any(k.endswith("/reference") for k in traced):
         raise PhaseFailed(f"{role}: attention ran the jnp reference on a TPU: {got}")
@@ -551,6 +553,10 @@ def judge_attention_traced(role: str, got: dict[str, float], platform: str) -> N
         raise PhaseFailed(
             f"{role}: latent decode attention ran its jnp path on a TPU, not the "
             f"paged kernel: {got}")
+    if platform == "tpu" and {"gqa-decode/jnp", "window-gqa-decode/jnp"} & traced:
+        raise PhaseFailed(
+            f"{role}: wide-key decode attention ran the chunked jnp walk on a TPU, not "
+            f"the paged kernel: {got}")
 
 
 def judge_window(role: str, startup: dict, traced: dict[str, float]) -> None:
@@ -564,8 +570,9 @@ def judge_window(role: str, startup: dict, traced: dict[str, float]) -> None:
     means the window layers keep, or walk, more than their window."""
     if not startup.get("window_blocks"):
         return
-    if role != "prefill" and not any(k.startswith("window-decode/") and v
-                                     for k, v in traced.items()):
+    if role != "prefill" and not any(
+            k.startswith(("window-decode/", "window-gqa-decode/")) and v
+            for k, v in traced.items()):
         raise PhaseFailed(f"{role}: a window model traced no window-decode call: {traced}")
     bs, window = startup["block_size"], startup["sliding_window"]
     chunk = max(int(b) for b in startup["prefill_bucket_ms"]) + startup["megastep_k"]
@@ -850,12 +857,13 @@ def main() -> int:
                        help="two one-chip workers: --role prefill and --role decode")
     which.add_argument("--kernel-check-child", action="store_true",
                        help=argparse.SUPPRESS)
-    ap.add_argument("--cpu-preset", choices=["tiny", "tiny-lfm2", "tiny-laguna", "tiny-sdar"],
+    ap.add_argument("--cpu-preset", choices=["tiny", "tiny-lfm2", "tiny-laguna", "tiny-sdar", "tiny-mimo"],
                     default="tiny",
                     help="what --cpu-tiny serves: the dense tiny preset, the "
                          "hybrid one (conv layers beside paired 64-wide heads), "
-                         "the one of window and full attention layers (two pools), or "
-                         "the one that generates by diffusion over blocks")
+                         "the one of window and full attention layers (two pools), "
+                         "the one that generates by diffusion over blocks, or the one "
+                         "whose key is wider than its value (two pools of unequal pages)")
     ap.add_argument("--corrupt", action="store_true", help=argparse.SUPPRESS)
     ap.add_argument("--inject", choices=["worker-start", "bad-request",
                                          "kernel-mismatch"], default=None,

@@ -600,6 +600,7 @@ async def run_jax_worker(
     startup["attention"] = core.cfg.attention
     startup["kv_bytes_per_token"] = core.kv_bytes_per_token
     startup["cache_layers"] = core.cfg.cache_layer_counts
+    startup.update(core.cache_by_kind())
     startup["state_bytes_per_block"] = core.cfg.state_bytes_per_block()
     startup["prefix_caching"] = bool(core.engine.enable_prefix_caching)
     if core.cfg.windowed:

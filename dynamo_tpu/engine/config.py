@@ -30,7 +30,7 @@ class UnsupportedModelOption(NotImplementedError):
     carry, refused at start-up. ``option`` names it (``kv_dtype``, ``tp``,
     ``pp``, ``ring_prefill``, ``spec_decode``, ``host_kv_blocks``,
     ``disk_kv_dir``, ``disagg``, ``peer_kv``, ``quant``,
-    ``prefix_caching``)."""
+    ``prefix_caching``; ``mm_embeds``, the one a request brings)."""
 
     def __init__(self, option: str, model: str, why: str):
         super().__init__(f"{option} is not carried for model {model!r}: {why}")
@@ -160,7 +160,8 @@ class ModelConfig:
     # blocks a sequence gives back as they slide out.
     sliding_window: int = 0
     # Query heads of each layer (``num_attention_heads_per_layer``); None:
-    # num_heads everywhere. KV heads and head_dim are one for all layers.
+    # num_heads everywhere. KV heads by kind: :meth:`kv_heads_of`; the key's
+    # and the value's width: ``head_dim`` and :attr:`value_dim`.
     heads_per_layer: tuple[int, ...] | None = None
     # Rope parameters by published layer kind (``rope_parameters``):
     # {"full_attention": {...}, "sliding_attention": {...}}, each with
@@ -173,6 +174,22 @@ class ModelConfig:
     # A gate on each head's attention output, sigmoid(norm(x) Wg) with Wg
     # [h, heads] (``gating`` "per-head"), before the output projection.
     attn_gate: bool = False
+    # -- keys wider than values, KV heads and a sink by layer kind (MiMo-V2) --
+    # With attention "gqa", ``v_head_dim`` (above) > 0 states a VALUE head's
+    # width beside ``head_dim``, the query's and the key's (192 beside 128):
+    # :attr:`wide_key`. Such a model's pages hold exactly ``head_dim +
+    # v_head_dim`` values a KV head a token, in whole lane rows
+    # (ops/gqa_attention.py, "The page"), and its attention is that module's.
+    # KV heads of a ``sliding_attention`` layer (``swa_num_key_value_heads``);
+    # 0: ``num_kv_heads``, the full layers', on every layer.
+    window_kv_heads: int = 0
+    # Published layer kinds whose softmax has a SINK: a learned logit a query
+    # head (``sink`` [heads], float32) that joins the row's maximum and
+    # denominator and carries no value (``add_swa_attention_sink_bias``).
+    attn_sinks: tuple[str, ...] = ()
+    # What every value is multiplied by as it is projected
+    # (``attention_value_scale``; equal to multiplying the heads' output).
+    attn_value_scale: float = 1.0
     # -- generation by diffusion over blocks (SDAR) ---------------------------
     # ``block_length`` B > 0: the model generates B places at a time. A
     # query at position p sees key j iff ``j // B <= p // B``: every
@@ -207,6 +224,7 @@ class ModelConfig:
             object.__setattr__(self, "experts_held", tuple(self.experts_held))
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        object.__setattr__(self, "attn_sinks", tuple(self.attn_sinks))
         self._check_latent_sparse()
         self._check_hybrid()
         self._check_windowed()
@@ -243,7 +261,8 @@ class ModelConfig:
                     f"rope_scaling type {scaling.get('type')!r}: only 'yarn'"
                 )
         else:
-            stray = [f for f in latent if getattr(self, f)]
+            # v_head_dim with "gqa" is a value narrower than its key: wide_key
+            stray = [f for f in latent if f != "v_head_dim" and getattr(self, f)]
             if stray or self.rope_scaling is not None:
                 raise ValueError(
                     f"{stray or ['rope_scaling']} set with attention='gqa': "
@@ -365,9 +384,12 @@ class ModelConfig:
             )
 
     def _check_windowed(self) -> None:
-        """``sliding_window``, ``heads_per_layer``, ``rope_by_kind`` and
-        ``attn_gate``: a field that does not apply, or a combination no
-        program was compared for, raises by name."""
+        """``sliding_window``, ``heads_per_layer``, ``rope_by_kind``,
+        ``attn_gate`` and what a wide key brings (``v_head_dim`` with "gqa",
+        ``window_kv_heads``, ``attn_sinks``, ``attn_value_scale``): a field
+        that does not apply, or a combination no program was compared for,
+        raises by name. KV heads and the value's width by layer kind are
+        STATED (:meth:`kv_heads_of`, :attr:`value_dim`), never assumed equal."""
         windowed = self.windowed
         if windowed != (self.sliding_window > 0):
             raise ValueError(
@@ -375,12 +397,30 @@ class ModelConfig:
                 f"{self.layer_types}: a 'sliding_attention' layer needs a window, "
                 "and only such a layer reads one")
         per_layer = self.heads_per_layer is not None or self.rope_by_kind is not None
-        if not (windowed or per_layer or self.attn_gate):
+        by_kind = (self.wide_key or self.window_kv_heads or self.attn_sinks
+                   or self.attn_value_scale != 1.0)
+        if not (windowed or per_layer or self.attn_gate or by_kind):
             return
         if not windowed:
             raise ValueError(
-                "heads_per_layer, rope_by_kind or attn_gate set without a "
-                "'sliding_attention' layer: only a model with such layers reads them")
+                "heads_per_layer, rope_by_kind, attn_gate, v_head_dim (with "
+                "attention='gqa'), window_kv_heads, attn_sinks or attn_value_scale "
+                "set without a 'sliding_attention' layer: only a model with such "
+                "layers reads them")
+        if by_kind and not self.wide_key:
+            raise NotImplementedError(
+                "window_kv_heads, attn_sinks or attn_value_scale without v_head_dim: "
+                "KV heads by kind, a sink and a value scale are the wide-key "
+                "attention's (ops/gqa_attention.py); the library kernel has none")
+        if self.wide_key:
+            if 2 * self.head_dim != 3 * self.v_head_dim or self.attn_gate \
+                    or any(self.kv_heads_of(k) % 2 for k in ("attention", "window")):
+                raise NotImplementedError(
+                    f"head_dim={self.head_dim} beside v_head_dim={self.v_head_dim}: "
+                    "the wide-key page holds a key of 1.5 values' width, an even "
+                    "count of KV heads a layer kind, and no attn_gate")
+            if not set(self.attn_sinks) <= {"full_attention", "sliding_attention"}:
+                raise ValueError(f"attn_sinks={self.attn_sinks} names no layer kind that attends")
         if self.latent or self.hybrid or self.ut_steps > 1 or self.sandwich_norm \
                 or self.attn_qkv_bias or self.qk_norm or self.kv_head_pairs:
             raise NotImplementedError(
@@ -391,12 +431,17 @@ class ModelConfig:
         if self.is_moe and not self.shared_sparse:
             raise NotImplementedError(
                 "these layers beside the softmax-routed (mixtral) MLP are not implemented")
-        if self.heads_per_layer is not None and (
-                len(self.heads_per_layer) != self.num_layers
-                or any(n <= 0 or n % self.num_kv_heads for n in self.heads_per_layer)):
+        if self.heads_per_layer is not None and len(self.heads_per_layer) != self.num_layers:
             raise ValueError(
-                f"heads_per_layer={self.heads_per_layer} must give each of the "
-                f"{self.num_layers} layers a multiple of num_kv_heads={self.num_kv_heads}")
+                f"heads_per_layer={self.heads_per_layer} must name each of the "
+                f"{self.num_layers} layers")
+        if any(
+                self.heads_of(l) <= 0 or self.heads_of(l) % self.kv_heads_of(self.layer_kind(l))
+                for l in range(self.num_layers)):
+            raise ValueError(
+                f"heads_per_layer={self.heads_per_layer or self.num_heads} must give each "
+                f"of the {self.num_layers} layers a multiple of its kind's KV heads "
+                f"({self.num_kv_heads} full, {self.kv_heads_of('window')} window)")
         for kind, rp in self.rope_by_kind or ():
             rp = dict(rp)
             if kind not in ("full_attention", "sliding_attention") \
@@ -436,6 +481,27 @@ class ModelConfig:
     def heads_of(self, l: int) -> int:
         """Query heads of layer ``l``."""
         return self.num_heads if self.heads_per_layer is None else self.heads_per_layer[l]
+
+    @property
+    def wide_key(self) -> bool:
+        """GQA whose value head (``v_head_dim``) is narrower than its key
+        and query head (``head_dim``): pages, writes and attention are
+        ops/gqa_attention.py's, in both layer kinds."""
+        return not self.latent and self.v_head_dim > 0
+
+    @property
+    def value_dim(self) -> int:
+        """A value head's width (the key's unless ``v_head_dim`` says)."""
+        return self.v_head_dim or self.head_dim
+
+    def kv_heads_of(self, kind: str) -> int:
+        """KV heads of the layers that cache ``kind`` (:meth:`layer_kind`)."""
+        return self.window_kv_heads if kind == "window" and self.window_kv_heads \
+            else self.num_kv_heads
+
+    def has_sink(self, kind: str) -> bool:
+        """The softmax of the layers that cache ``kind`` has a sink."""
+        return any(_CACHE_KIND.get(published) == kind for published in self.attn_sinks)
 
     def rope_of(self, kind: str) -> dict:
         """Rope parameters of the layers of published ``kind``
@@ -513,8 +579,11 @@ class ModelConfig:
         V odd); with :attr:`kv_head_pairs` ``(block_size, num_kv_heads, 2
         * head_dim)``, the same bytes with two heads a row; latent, the
         ``(rows, lanes)`` that hold ``block_size x (kv_lora_rank +
-        qk_rope_head_dim)`` values (ops/latent_attention.py, "The page").
-        "window": the same page as "attention", in a pool of its own.
+        qk_rope_head_dim)`` values (ops/latent_attention.py, "The page");
+        with :attr:`wide_key` the ``(rows, lanes)`` that hold ``block_size x
+        kv_heads_of(kind) x (head_dim + v_head_dim)`` (ops/gqa_attention.py,
+        "The page"). "window": the "attention" page at the WINDOW layers' KV
+        heads (:meth:`kv_heads_of`), in a pool of its own.
         "conv": ``(conv_L_cache - 1, h / 128, 128)``, the newest rows of
         ``u`` written in the block, each in whole 128-lane rows (a ``[2,
         h]`` tail would pad its 2 sublanes to a tile's 16); whatever
@@ -534,6 +603,11 @@ class ModelConfig:
             from dynamo_tpu.ops.latent_attention import latent_page_shape
 
             return latent_page_shape(block_size, self.kv_lora_rank, self.qk_rope_head_dim)
+        if self.wide_key:
+            from dynamo_tpu.ops.gqa_attention import gqa_page_shape
+
+            return gqa_page_shape(block_size, self.kv_heads_of(kind), self.head_dim,
+                                  self.value_dim)
         if self.kv_head_pairs:
             return (block_size, self.num_kv_heads, 2 * self.head_dim)
         return (block_size, 2 * self.num_kv_heads, self.head_dim)
@@ -557,8 +631,8 @@ class ModelConfig:
         if not self.windowed:
             return 0
         blocks = self.sliding_window // block_size + 1
-        return (self.cache_layers("window") * blocks * block_size * self.kv_unit_values
-                * jnp.dtype(self.jax_dtype).itemsize)
+        return (self.cache_layers("window") * blocks * block_size
+                * self.kv_unit_values_of("window") * jnp.dtype(self.jax_dtype).itemsize)
 
     def state_bytes_per_block(self) -> int:
         """Bytes of convolution state one block holds over all conv
@@ -568,12 +642,27 @@ class ModelConfig:
         return (self.cache_layers("conv") * (self.conv_L_cache - 1)
                 * self.hidden_size * jnp.dtype(self.jax_dtype).itemsize)
 
-    @property
-    def kv_unit_values(self) -> int:
-        """Values one token caches in one layer's plane."""
+    def kv_unit_values_of(self, kind: str) -> int:
+        """Values one token caches in one plane of the layers that cache
+        ``kind`` ("attention" or "window")."""
         if self.latent:
             return self.kv_lora_rank + self.qk_rope_head_dim
-        return 2 * self.num_kv_heads * self.head_dim
+        return self.kv_heads_of(kind) * (self.head_dim + self.value_dim)
+
+    @property
+    def kv_unit_values(self) -> int:
+        """Values one token caches in one FULL layer's plane."""
+        return self.kv_unit_values_of("attention")
+
+    def bytes_per_block(self, block_size: int, kind: str) -> int:
+        """Bytes one block of the pool of ``kind`` holds over all the layers
+        that cache it, at the model's dtype: what /health and /metrics give
+        by kind (MiMo at block 32: 2 x 80 KB "attention", 5 x 160 KB
+        "window")."""
+        n = self.cache_layers(kind)
+        for dim in self.kv_page_tail(block_size, kind):
+            n *= dim
+        return n * jnp.dtype(self.jax_dtype).itemsize
 
     @property
     def num_cache_layers(self) -> int:
@@ -614,6 +703,10 @@ class ModelConfig:
         qk_norms = 2 * self.head_dim if self.qk_norm else 0
         q_size = self.q_size_of(l)
         gate = h * self.heads_of(l) if self.attn_gate else 0
+        if self.wide_key:   # [q | k | v] of unequal widths a kind, wo from the values' width
+            kind, n = self.layer_kind(l), self.heads_of(l)
+            return (h * (q_size + self.kv_unit_values_of(kind)) + n * self.value_dim * h
+                    + (n if self.has_sink(kind) else 0))
         return h * (q_size + 2 * self.kv_size) + q_size * h + qk_norms + gate
 
     def _conv_params(self) -> int:
@@ -1323,6 +1416,86 @@ def tiny_laguna(vocab_size: int = 384, experts_held=(0, 2)) -> ModelConfig:
     )
 
 
+_MIMO_ROPE = {
+    # a third of each head rotated, from the front: int(192 x 0.334) = 64
+    "full_attention": {"rope_theta": 10000000, "rope_type": "default",
+                       "partial_rotary_factor": 0.334},
+    "sliding_attention": {"rope_theta": 10000, "rope_type": "default",
+                          "partial_rotary_factor": 0.334},
+}
+_F, _W = "full_attention", "sliding_attention"
+
+
+def mimo_v25_ep16_7l() -> ModelConfig:
+    """MiMo-V2.5 (Xiaomi, model_type "mimo_v2") as ONE chip of sixteen holds
+    its first stage: layers 0-6 of the 48 (full attention at 0 and 5 on 4 KV
+    heads, window-128 attention with a sink logit a head at 1-4 and 6 on 8;
+    64 query heads, keys 192 wide beside values 128 wide, a third of each
+    head rotated, values scaled by 0.707), the leading dense layer, then six
+    sparse layers with 16 of each one's 256 sigmoid-routed, bias-chosen
+    experts (rank 0, 8 a token, no shared one), an eighth of the
+    vocabulary. 6.86 GB in bf16."""
+    return ModelConfig(
+        name="mimo-v2.5-ep16-7l",
+        vocab_size=19072,
+        hidden_size=4096,
+        intermediate_size=16384,
+        num_layers=7,
+        num_heads=64,
+        num_kv_heads=4,
+        head_dim=192,
+        v_head_dim=128,
+        rms_norm_eps=1e-5,
+        layer_types=(_F, _W, _W, _W, _W, _F, _W),
+        sliding_window=128,
+        window_kv_heads=8,
+        attn_sinks=(_W,),
+        attn_value_scale=0.707,
+        rope_by_kind=_MIMO_ROPE,
+        first_dense_layers=1,
+        moe_intermediate_size=2048,
+        num_experts=256,
+        num_experts_per_tok=8,
+        router_scoring="sigmoid",
+        router_bias=True,
+        experts_held=(0, 16),
+    )
+
+
+def tiny_mimo(vocab_size: int = 384, experts_held=(0, 4)) -> ModelConfig:
+    """MiMo's shape at test size, every ratio kept: keys 24 wide beside
+    values 16 wide, 32 query heads on 2 KV heads (full, groups of 16) and 4
+    (window 8 with a sink, groups of 8), a third of a head rotated; one
+    dense layer then four sparse ones of 16 bias-chosen experts, 4 a token,
+    this chip holding a quarter of them."""
+    return ModelConfig(
+        name="tiny-mimo",
+        vocab_size=vocab_size,
+        hidden_size=64,
+        intermediate_size=160,
+        num_layers=5,
+        num_heads=32,
+        num_kv_heads=2,
+        head_dim=24,
+        v_head_dim=16,
+        rms_norm_eps=1e-5,
+        dtype="float32",
+        layer_types=(_F, _W, _W, _F, _W),
+        sliding_window=8,
+        window_kv_heads=4,
+        attn_sinks=(_W,),
+        attn_value_scale=0.707,
+        rope_by_kind=_MIMO_ROPE,
+        first_dense_layers=1,
+        moe_intermediate_size=32,
+        num_experts=16,
+        num_experts_per_tok=4,
+        router_scoring="sigmoid",
+        router_bias=True,
+        experts_held=experts_held,
+    )
+
+
 def tiny_loop(vocab_size: int = 384) -> ModelConfig:
     """The looped stack (Ouro's shape) at test size: 3 layers x 3 passes."""
     return ModelConfig(
@@ -1383,6 +1556,7 @@ PRESETS = {
     "lfm2-24b-a2b-10l": lfm2_24b_a2b_10l,
     "laguna-s-2.1-ep8-9l": laguna_s21_ep8_9l,
     "sdar-30b-a3b-6l": sdar_30b_a3b_6l,
+    "mimo-v2.5-ep16-7l": mimo_v25_ep16_7l,
     "tiny": tiny_model,
     "tiny-moe": tiny_moe,
     "tiny-loop": tiny_loop,
@@ -1390,4 +1564,5 @@ PRESETS = {
     "tiny-lfm2": tiny_lfm2,
     "tiny-laguna": tiny_laguna,
     "tiny-sdar": tiny_sdar,
+    "tiny-mimo": tiny_mimo,
 }

@@ -58,7 +58,12 @@ from dynamo_tpu.engine.kv_transfer import KvTransfer
 # chipbench/rehearse_v5e.py reads the two serving programs through this module
 # and tests/chipbench/test_chipbench_sdar.py ``_resolve_block_megastep``; tests
 # REBIND ``pack_lanes`` here, where ``_dispatch_megastep`` looks it up.
-from dynamo_tpu.engine.options import _BLOCK_STEP, _resolve_block_megastep, resolve  # noqa: F401
+from dynamo_tpu.engine.options import (  # noqa: F401
+    _BLOCK_STEP,
+    _resolve_block_megastep,
+    refuse_mm_embeds,
+    resolve,
+)
 from dynamo_tpu.engine.programs import (  # noqa: F401
     MEGASTEP_WATCH_W, _megastep_body, _prefill_and_sample, _program, compile_programs,
     pack_lanes,
@@ -764,6 +769,7 @@ class EngineCore(KvTransfer):
                 "wired yet (route spec requests to a tp/dp worker)"
             )
         if pre.mm and pre.mm.get("embeds") is not None:
+            refuse_mm_embeds(self.cfg)
             if self.pp_mesh is not None:
                 # Reject at admission (a NotImplementedError inside the
                 # prefill wave would fail every co-scheduled request).
@@ -989,7 +995,8 @@ class EngineCore(KvTransfer):
             "window": self.cfg.sliding_window,
             "heads": "/".join(str(n) for n in heads.values()),
             "attn_window": traced_impl(
-                "window-ragged" if kind == "prefill" else "window-decode"),
+                ("window-gqa-" if self.cfg.wide_key else "window-")
+                + ("ragged" if kind == "prefill" else "decode")),
         }
 
     def _experts_traced(self, kind: str, tokens: int, width: int = 1) -> dict[str, str]:
@@ -4085,6 +4092,7 @@ class EngineCore(KvTransfer):
         st["kv_cache_layers"] = self.cfg.num_cache_layers
         st["kv_bytes_per_token"] = self.kv_bytes_per_token
         st["cache_layers"] = self.cfg.cache_layer_counts
+        st.update(self.cache_by_kind())
         st["state_bytes_per_block"] = self.cfg.state_bytes_per_block()
         st["conv_state_reads"] = dict(self.conv_state_reads)
         st["prefix_caching"] = bool(self.engine.enable_prefix_caching)
@@ -4112,6 +4120,26 @@ class EngineCore(KvTransfer):
         km = k * self._pp_micro
         st["pp_pipe_occupancy"] = km / (km + self._pp - 1)
         return st
+
+    def cache_by_kind(self) -> dict[str, dict]:
+        """``cache_page_shape`` and ``cache_bytes_per_block`` by the kind of
+        layer that caches them, as /health and /metrics give them: the page
+        of ONE layer (``ModelConfig.kv_page_tail``) and the bytes a block of
+        that kind's pool holds over all its layers."""
+        bs = self.engine.block_size
+        kinds = [k for k, n in self.cfg.cache_layer_counts.items() if n]
+
+        def block_bytes(kind: str) -> int:
+            if kind == "conv":
+                return self.cfg.state_bytes_per_block()
+            if self.engine.kv_quantized:    # int8 pages and their scales
+                return self.kv_bytes_per_token * bs
+            return self.cfg.bytes_per_block(bs, kind)
+
+        return {
+            "cache_page_shape": {k: list(self.cfg.kv_page_tail(bs, k)) for k in kinds},
+            "cache_bytes_per_block": {k: block_bytes(k) for k in kinds},
+        }
 
     def step_phase_seconds(self) -> dict[tuple[str, str], float]:
         """Cumulative engine-loop seconds keyed ``(phase, blocks)``, the

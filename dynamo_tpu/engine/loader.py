@@ -149,6 +149,68 @@ def _windowed_config(hf: dict, experts_held) -> ModelConfig:
     )
 
 
+# Published model types whose key is wider than their value, with KV heads,
+# rope's base and a sink logit by layer kind (``hybrid_layer_pattern``: 0 full,
+# 1 sliding-window), and bias-chosen sigmoid experts without a shared one
+# (MiMo-V2).
+WIDE_KEY_TYPES = ("mimo_v2",)
+_PATTERN_KINDS = ("full_attention", "sliding_attention")
+
+
+def _wide_key_config(hf: dict, experts_held) -> ModelConfig:
+    """The published keys as ``chipbench/architectures/mimo_v2.py`` reads
+    them (the language model only: a checkpoint's towers and MTP layers are
+    not loaded); what the file gives no equation for is listed under
+    ``assumed`` in the benchmark's configuration of it."""
+    L = hf["num_hidden_layers"]
+    freq = list(hf.get("moe_layer_freq") or [1] * L)
+    dense = freq.index(1) if 1 in freq else L
+    if 0 in freq[dense:]:
+        raise NotImplementedError("a dense MLP layer after a sparse one is not implemented")
+    if hf.get("scoring_func", "sigmoid") != "sigmoid" or hf.get("n_shared_experts") \
+            or hf.get("topk_method", "noaux_tc") != "noaux_tc" \
+            or (hf.get("rope_scaling") or {}).get("rope_type", "default") != "default":
+        raise NotImplementedError(
+            "only sigmoid scores with a choice bias (noaux_tc), no shared expert and "
+            "a plain rope are implemented")
+    rotated = {"rope_type": "default",
+               "partial_rotary_factor": hf.get("partial_rotary_factor", 1)}
+    return ModelConfig(
+        name=hf["model_type"],
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=L,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        window_kv_heads=hf.get("swa_num_key_value_heads", 0),
+        head_dim=hf["head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        attn_value_scale=hf.get("attention_value_scale", 1.0),
+        rms_norm_eps=hf.get("layernorm_epsilon", 1e-5),
+        tie_embeddings=hf.get("tie_word_embeddings", False),
+        layer_types=tuple(_PATTERN_KINDS[i] for i in hf["hybrid_layer_pattern"]),
+        sliding_window=hf["sliding_window"],
+        rope_by_kind={
+            "full_attention": {**rotated, "rope_theta": hf["rope_theta"]},
+            "sliding_attention": {**rotated, "rope_theta": hf["swa_rope_theta"]},
+        },
+        attn_sinks=tuple(kind for kind, key in zip(_PATTERN_KINDS, (
+            "add_full_attention_sink_bias", "add_swa_attention_sink_bias")) if hf.get(key)),
+        first_dense_layers=dense,
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_experts=hf["n_routed_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        router_scoring="sigmoid",
+        router_bias=True,
+        n_group=hf.get("n_group", 1),
+        topk_group=hf.get("topk_group", 1),
+        norm_topk_prob=hf.get("norm_topk_prob", True),
+        routed_scaling_factor=hf.get("routed_scaling_factor") or 1.0,
+        experts_held=experts_held,
+    )
+
+
 # Published model types that generate by diffusion over blocks on a
 # Qwen3-MoE body (SDAR): QK-normed GQA, softmax-routed whole experts.
 BLOCK_SPARSE_TYPES = ("sdar_moe",)
@@ -198,6 +260,8 @@ def config_from_hf(path: str | Path, experts_held=None) -> ModelConfig:
         return _latent_sparse_config(hf, experts_held)
     if hf.get("model_type") in WINDOWED_TYPES:
         return _windowed_config(hf, experts_held)
+    if hf.get("model_type") in WIDE_KEY_TYPES:
+        return _wide_key_config(hf, experts_held)
     if hf.get("model_type") in BLOCK_SPARSE_TYPES and experts_held is None:
         return _block_sparse_config(hf)
     if hf.get("model_type") in HYBRID_CONV_TYPES and experts_held is None:
@@ -205,7 +269,7 @@ def config_from_hf(path: str | Path, experts_held=None) -> ModelConfig:
     if experts_held is not None:
         raise ValueError(
             f"experts_held={experts_held} for model_type {hf.get('model_type')!r}: "
-            f"only {LATENT_SPARSE_TYPES + WINDOWED_TYPES} state a share"
+            f"only {LATENT_SPARSE_TYPES + WINDOWED_TYPES + WIDE_KEY_TYPES} state a share"
         )
     head_dim = hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"]
     return ModelConfig(
@@ -504,6 +568,76 @@ def _load_windowed(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]:
     return params
 
 
+def _load_wide_key(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]:
+    """The tree of a model of the wide-key page (``model.
+    _init_attention_by_kind`` with ``cfg.wide_key``: ``attn`` / ``attn_window``
+    one entry a layer of that kind, ``wqkv`` = ``[q | k | v]`` at the kind's
+    KV heads and the two widths, ``sink`` float32 where the kind has one;
+    ``dense_mlp``; ``moe`` with the choice bias and no shared expert) from
+    the checkpoint's names: ``self_attn.{q_proj, k_proj, v_proj, o_proj}``,
+    ``self_attn.attention_sink_bias [heads]``, ``mlp.gate`` with
+    ``mlp.gate.e_score_correction_bias [E]``, ``mlp.experts.<e>.*`` (the HELD
+    experts only). Rotate-half rope: no permutation."""
+    np_dt = np.dtype(dt)
+    L, Ld = cfg.num_layers, cfg.first_dense_layers
+    lo, hi = cfg.experts_held_range
+
+    def t(key: str) -> np.ndarray:
+        return np.asarray(sd[key], np.float32)
+
+    def stack(name: str, layers) -> np.ndarray:
+        return np.asarray(
+            np.stack([t(f"model.layers.{l}.{name}.weight").T for l in layers]), np_dt)
+
+    def norms(name: str) -> np.ndarray:
+        return np.asarray(
+            np.stack([t(f"model.layers.{l}.{name}.weight") for l in range(L)]), np_dt)
+
+    def gate_up(prefix: str, layers) -> np.ndarray:
+        return np.concatenate(
+            [stack(f"{prefix}.gate_proj", layers), stack(f"{prefix}.up_proj", layers)], axis=-1)
+
+    def attention(kind: str) -> dict[str, Any]:
+        layers = cfg.layers_of(kind)
+        group = {
+            "wqkv": np.concatenate(
+                [stack(f"self_attn.{n}", layers) for n in ("q_proj", "k_proj", "v_proj")],
+                axis=-1),
+            "wo": stack("self_attn.o_proj", layers),
+        }
+        if cfg.has_sink(kind):
+            group["sink"] = np.stack(
+                [t(f"model.layers.{l}.self_attn.attention_sink_bias") for l in layers])
+        return group
+
+    sparse = range(Ld, L)
+    params: dict[str, Any] = {
+        "layers": {"attn_norm": norms("input_layernorm"),
+                   "mlp_norm": norms("post_attention_layernorm")},
+        "attn": attention("attention"),
+        "attn_window": attention("window"),
+        "moe": {
+            "w_router": stack("mlp.gate", sparse),
+            "expert_bias": np.stack(
+                [t(f"model.layers.{l}.mlp.gate.e_score_correction_bias") for l in sparse]),
+            # one array a sparse layer (model._init_shared_sparse_mlp)
+            "w_gu": tuple(np.stack(
+                [gate_up(f"mlp.experts.{e}", [l])[0] for e in range(lo, hi)]) for l in sparse),
+            "w_down": tuple(np.stack(
+                [stack(f"mlp.experts.{e}.down_proj", [l])[0] for e in range(lo, hi)])
+                for l in sparse),
+        },
+    }
+    if Ld:
+        params["dense_mlp"] = {
+            "wgu": np.asarray(_fuse_np(
+                [stack("mlp.gate_proj", range(Ld)), stack("mlp.up_proj", range(Ld))], tp),
+                np_dt),
+            "w_down": stack("mlp.down_proj", range(Ld)),
+        }
+    return params
+
+
 def _load_block_sparse(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]:
     """An sdar_moe checkpoint into the tree ``model.init_params`` builds for
     it (``layers``: norms, ``wqkv``, ``wo``, the head norms; ``moe``: router
@@ -551,7 +685,7 @@ def load_hf_llama(
     experts_held: tuple[int, int] | None = None,
 ) -> tuple[ModelConfig, Any]:
     """Returns (ModelConfig, params pytree) from an HF llama/qwen2/ouro/
-    axk1/lfm2_moe/laguna checkpoint (``experts_held``: see :func:`config_from_hf`).
+    axk1/lfm2_moe/laguna/mimo_v2 checkpoint (``experts_held``: see :func:`config_from_hf`).
 
     ``tp`` fixes the shard-blocked layout of the fused wqkv/wgu projections
     (model.fuse_qkv/fuse_gu) and must match the serving mesh's tp axis.
@@ -582,6 +716,7 @@ def load_hf_llama(
             )
         np_dt = np.dtype(dt)
         load = (_load_hybrid_conv if cfg.hybrid else
+                _load_wide_key if cfg.wide_key else
                 _load_windowed if cfg.windowed else
                 _load_block_sparse if cfg.block_length else _load_latent_sparse)
         params = load(cfg, sd, dt, tp)

@@ -76,6 +76,11 @@ from jax import shard_map
 
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
 from dynamo_tpu.ops import expert_stream, grouped_matmul
+from dynamo_tpu.ops.gqa_attention import (
+    gqa_decode_attention,
+    gqa_ragged_attention,
+    write_gqa_rows,
+)
 from dynamo_tpu.ops.latent_attention import (
     latent_decode_attention,
     latent_ragged_attention,
@@ -377,11 +382,14 @@ _GATE_LOGIT_SCALE = 1.4
 
 def _init_attention_by_kind(rng: jax.Array, cfg: ModelConfig, dense, tp: int) -> dict:
     """The attention leaves of a model whose full and window layers differ
-    in their query heads: ``attn`` (one entry a full layer) and
-    ``attn_window`` (one a window layer), each ``wqkv [h, (n + 2 n_kv)
-    d]``, ``wo [n d, h]`` and, with ``cfg.attn_gate``, ``wg [h, n]`` for
-    ITS ``n`` query heads. Layer ``l``'s leaves are drawn from key ``l``
-    whatever its kind."""
+    in their heads: ``attn`` (one entry a full layer) and ``attn_window``
+    (one a window layer), each ``wqkv [h, (n + 2 n_kv) d]``, ``wo [n d,
+    h]`` and, with ``cfg.attn_gate``, ``wg [h, n]`` for ITS ``n`` query
+    heads. With ``cfg.wide_key`` the kind's own KV heads and a value
+    narrower than its key: ``wqkv [h, n dk + n_kv dk + n_kv dv]`` (``[q | k
+    | v]``), ``wo [n dv, h]`` and, where the kind's softmax has one
+    (``cfg.has_sink``), ``sink [n]`` float32 (:func:`_sink_logits`). Layer
+    ``l``'s leaves are drawn from key ``l`` whatever its kind."""
     h, d = cfg.hidden_size, cfg.head_dim
     key = lambda l, n: jax.random.fold_in(jax.random.fold_in(rng, 70 + n), l)  # noqa: E731
     out: dict[str, dict] = {}
@@ -389,18 +397,37 @@ def _init_attention_by_kind(rng: jax.Array, cfg: ModelConfig, dense, tp: int) ->
         layers = cfg.layers_of(kind)
         if not layers:
             continue
+        k_size = cfg.kv_heads_of(kind) * d
+        v_size = cfg.kv_heads_of(kind) * cfg.value_dim
         group: dict[str, list] = {"wqkv": [], "wo": []}
         for l in layers:
             q = cfg.q_size_of(l)
-            group["wqkv"].append(fuse_qkv(
-                dense(key(l, 0), (h, q), h), dense(key(l, 1), (h, cfg.kv_size), h),
-                dense(key(l, 2), (h, cfg.kv_size), h), tp))
-            group["wo"].append(dense(key(l, 3), (q, h), q))
+            o = cfg.heads_of(l) * cfg.value_dim
+            wq, wk, wv = (dense(key(l, 0), (h, q), h), dense(key(l, 1), (h, k_size), h),
+                          dense(key(l, 2), (h, v_size), h))
+            group["wqkv"].append(jnp.concatenate([wq, wk, wv], axis=-1) if cfg.wide_key
+                                 else fuse_qkv(wq, wk, wv, tp))
+            group["wo"].append(dense(key(l, 3), (o, h), o))
             if cfg.attn_gate:
                 group.setdefault("wg", []).append(dense(
                     key(l, 4), (h, q // d), h / _GATE_LOGIT_SCALE ** 2))
+            if cfg.wide_key and cfg.has_sink(kind):
+                group.setdefault("sink", []).append(_sink_logits(key(l, 5), cfg, q // d))
         out[_GROUP_OF_KIND[kind]] = {k: jnp.stack(v) for k, v in group.items()}
     return out
+
+
+def _sink_logits(key, cfg: ModelConfig, heads: int) -> jax.Array:
+    """A window layer's sink logits ``[heads]`` float32, drawn around
+    ``log(sliding_window) - 0.5``, half a unit either way: on random weights
+    a row's scores are ~N(0, 1), so a full window's keys sum to
+    ``sliding_window x e^0.5`` and such a sink takes a fifth to a third of
+    the row's mass (more before the window is full): enough that a sink
+    left out, or taken from the wrong head, moves the logits past the
+    comparison's tolerance (the control's ``sink`` fault, PERF.md section
+    6, PR 46), and not so much that the window's keys stop mattering."""
+    return (math.log(cfg.sliding_window) - 0.5
+            + 0.5 * jax.random.normal(key, (heads,), jnp.float32))
 
 
 def _init_conv_operators(rng: jax.Array, cfg: ModelConfig, dense) -> dict:
@@ -1392,6 +1419,13 @@ def dense_layer(
     layer's leaves each head's output is gated by ``sigmoid(y wg)`` of the
     SAME normed input, inside ``o_proj`` (scope ``attn_gate``).
 
+    ``cfg.wide_key`` (a key wider than its value, MiMo): ``wqkv`` is ``[q |
+    k | v]`` at the layer kind's OWN KV heads (``cfg.kv_heads_of``) and the
+    two widths, the values scaled by ``cfg.attn_value_scale`` as they are
+    projected; K and V go to the page of ops/gqa_attention.py
+    (:func:`write_gqa_rows`) and attention is that module's in both shapes
+    and both kinds, with the layer's ``sink`` leaf where its kind has one.
+
     ``blocks`` (a block-diffusion model, :func:`block_rows`): the rows are
     whole diffusion blocks and each sees its block both ways beside the
     causal past, one decode-shaped call with a block's rows folded into
@@ -1416,10 +1450,17 @@ def dense_layer(
         qkv = _dot(y, lp["wqkv"])
         if "bqkv" in lp:  # Qwen2-family qkv bias (fused column order)
             qkv = qkv + lp["bqkv"]
-        qkv = qkv.astype(dt)
-        q, k, v = split_qkv(qkv, cfg, tp)
+        if cfg.wide_key:   # [q | k | v], the kind's KV heads, v narrower and scaled
+            n_kv = cfg.kv_heads_of("window" if window else "attention")
+            k0 = qkv.shape[1] - n_kv * (cfg.head_dim + cfg.value_dim)
+            v0 = qkv.shape[1] - n_kv * cfg.value_dim
+            q, k = qkv[:, :k0].astype(dt), qkv[:, k0:v0].astype(dt)
+            v = (cfg.attn_value_scale * qkv[:, v0:]).astype(dt).reshape(T, n_kv, -1)
+        else:
+            n_kv = cfg.num_kv_heads
+            q, k, v = split_qkv(qkv.astype(dt), cfg, tp)
         q = q.reshape(T, -1, cfg.head_dim)
-        k = k.reshape(T, cfg.num_kv_heads, cfg.head_dim)
+        k = k.reshape(T, n_kv, cfg.head_dim)
         if "q_layernorm" in lp:  # per head, BEFORE rope
             with jax.named_scope("qk_norm"):
                 q = rms_norm(q, lp["q_layernorm"], cfg.rms_norm_eps)
@@ -1427,14 +1468,27 @@ def dense_layer(
         q = rope_apply(q, *rope_cs)
         k = rope_apply(k, *rope_cs)
     with jax.named_scope("kv_write"):
-        kvn = _interleave_kv(k.reshape(T, cfg.kv_size), v, cfg)
-        cache_l = write_kv(cache_l, write_pages, write_offs, kvn)
+        if cfg.wide_key:
+            cache_l = write_gqa_rows(cache_l, write_pages, write_offs, k, v)
+        else:
+            kvn = _interleave_kv(k.reshape(T, cfg.kv_size), v, cfg)
+            cache_l = write_kv(cache_l, write_pages, write_offs, kvn)
     if isinstance(cache_l, dict):
         kv_pages, kv_scales = cache_l["kv"], cache_l["scale"]
     else:
         kv_pages, kv_scales = cache_l, None
     with jax.named_scope("attn"):
-        if blocks is not None:
+        if cfg.wide_key:
+            # ops/gqa_attention.py: the kernel in the decode shape, the
+            # chunked walk in a wave; the sink a leaf of the kinds that have one
+            with jax.named_scope("window" if window else "full"):
+                kw = dict(n_kv=n_kv, sm_scale=sm_scale, window=window, sinks=lp.get("sink"))
+                if cu_q_lens is None:
+                    attn = gqa_decode_attention(q, kv_pages, kv_lens, block_tables, **kw)
+                else:
+                    attn = gqa_ragged_attention(
+                        q, kv_pages, kv_lens, block_tables, cu_q_lens, num_seqs, **kw)
+        elif blocks is not None:
             block_ends, block_pages, num_blocks, shape = blocks
             with jax.named_scope("block"):
                 attn = block_attention(

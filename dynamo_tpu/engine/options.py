@@ -18,6 +18,8 @@ that one place names all of it (ROADMAP D16):
   with window layers, conv state pages, paired KV heads or latent rows,
   where the cache is built: it guards ``init_cache`` for callers that build
   no engine.
+- ``EngineCore.add_request`` calls :func:`refuse_mm_embeds` for a request
+  that brings multimodal embedding rows to a model of the wide-key page.
 - ``backends/jax/main.py``: ``--quant`` for a model with ``layer_groups``
   (before the weights are initialised), and ``--role prefill|decode`` for
   such a model (after the engine is built: its blocks do not leave the
@@ -174,6 +176,19 @@ def _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh) -> 
     for option, why in refused.items():
         if why:
             raise UnsupportedModelOption(option, model_cfg.name, why)
+
+
+def refuse_mm_embeds(model_cfg) -> None:
+    """A REQUEST's multimodal embedding rows, refused by name as the request
+    is admitted (the one refusal here that no start-up can make): a model
+    of the wide-key page is served as its text model alone (the published
+    configuration has no key of a vision or audio tower), and embedding rows
+    spliced into a wave were never compared through that page."""
+    if model_cfg.wide_key:
+        raise UnsupportedModelOption(
+            "mm_embeds", model_cfg.name,
+            "the text model alone is served: no tower is modelled, and embedding "
+            "rows were never compared through the wide-key page")
 
 
 def resolve(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh):

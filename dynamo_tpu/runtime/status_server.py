@@ -464,9 +464,11 @@ class _EngineCounters:
             "dynamo_engine_attention_calls_traced",
             "Attention calls traced into step programs, by the shape the "
             "caller stated (decode: one query token a sequence, the "
-            "kernel's decode grid; ragged) and the implementation chosen "
-            "(library: the Pallas kernel; reference: jnp); the choice is "
-            "static per compiled program",
+            "kernel's decode grid; ragged; window-, latent- and gqa- before "
+            "them for a window layer's call, the latent page's and the "
+            "wide-key page's) and the implementation chosen (library: the "
+            "library's Pallas kernel; pallas: a first-party one; reference, "
+            "jnp: no kernel); the choice is static per compiled program",
             labels=["service", "shape", "impl"],
         )
         for (shape, impl), n in sorted(traced_calls().items()):
@@ -500,6 +502,28 @@ class _EngineCounters:
         for kind, n in sorted(stats.get("cache_layers", {}).items()):
             kinds.add_metric(["engine", kind], float(n))
         yield kinds
+        block_bytes = GaugeMetricFamily(
+            "dynamo_engine_cache_bytes_per_block",
+            "Bytes one block of a cache pool holds over all the layers of that "
+            "kind, at the cache's dtype: the pools of a model whose window "
+            "layers have KV heads of their own hold blocks of different bytes",
+            labels=["service", "kind"],
+        )
+        for kind, n in sorted(stats.get("cache_bytes_per_block", {}).items()):
+            block_bytes.add_metric(["engine", kind], float(n))
+        yield block_bytes
+        page = GaugeMetricFamily(
+            "dynamo_engine_cache_page_values",
+            "Values in one layer's page of a block, by the layer's kind and "
+            "the page's shape (ModelConfig.kv_page_tail)",
+            labels=["service", "kind", "shape"],
+        )
+        for kind, shape in sorted(stats.get("cache_page_shape", {}).items()):
+            n = 1
+            for dim in shape:
+                n *= dim
+            page.add_metric(["engine", kind, "x".join(str(d) for d in shape)], float(n))
+        yield page
         reads = CounterMetricFamily(
             "dynamo_engine_conv_state_reads",
             "Times a sequence's rows read convolution state from the pages, "

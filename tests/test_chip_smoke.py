@@ -90,6 +90,32 @@ def test_a_window_models_worker_is_held_to_its_window():
             "aggregated", {"decode/library": 3.0, "window-decode/reference": 6.0}, "tpu")
 
 
+@pytest.mark.parametrize("traced,platform,error", [
+    ({"gqa-decode/pallas": 2.0, "window-gqa-decode/pallas": 5.0, "gqa-ragged/jnp": 2.0,
+      "window-gqa-ragged/jnp": 5.0}, "tpu", None),
+    ({"gqa-decode/jnp": 2.0, "window-gqa-decode/jnp": 3.0}, "cpu", None),
+    ({"gqa-decode/jnp": 2.0, "window-gqa-decode/pallas": 5.0}, "tpu", "chunked jnp walk on a TPU"),
+    ({"gqa-decode/pallas": 2.0, "window-gqa-decode/jnp": 5.0}, "tpu", "chunked jnp walk on a TPU"),
+    ({"gqa-ragged/jnp": 2.0, "window-gqa-ragged/jnp": 5.0}, "tpu", "no decode-shaped"),
+], ids=["the-kernel-on-a-tpu", "the-walk-on-the-cpu", "full-layers-walked-on-a-tpu",
+        "window-layers-walked-on-a-tpu", "no-decode-call"])
+def test_a_wide_key_models_worker_is_held_to_its_kernel(traced, platform, error):
+    """A model of the wide-key page (MiMo): its decode steps trace ``gqa-decode``
+    and ``window-gqa-decode``, on a TPU the Pallas kernel in BOTH layer kinds (its
+    waves are the chunked ``jnp`` walk, said as such), and its window pool is
+    held to its window like Laguna's."""
+    import chip_smoke
+
+    startup = {"window_blocks": 272, "block_size": 32, "sliding_window": 128, "megastep_k": 8,
+               "prefill_bucket_ms": {"256": 1.0, "2048": 9.0}, "window_table_blocks": 70}
+    if error is None:
+        chip_smoke.judge_attention_traced("aggregated", traced, platform)
+        chip_smoke.judge_window("aggregated", startup, traced)
+    else:
+        with pytest.raises(chip_smoke.PhaseFailed, match=error):
+            chip_smoke.judge_attention_traced("aggregated", traced, platform)
+
+
 def test_a_block_models_worker_is_held_to_its_block_calls(monkeypatch):
     """``judge_blocks``: a worker that says it generates by blocks must have
     traced a block-decode call and no causal one; on a TPU never the jnp

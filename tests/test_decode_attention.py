@@ -323,6 +323,30 @@ def test_mosaic_compiles_the_latent_decode_kernel_for_a_v5e(one_chip, lanes):
     assert "latent_decode_attention_kernel" in compiled.as_text()
 
 
+@pytest.mark.parametrize("kind,lanes", [("full", 32), ("full", 16), ("window", 32)])
+def test_mosaic_compiles_the_wide_key_decode_kernel_for_a_v5e(one_chip, kind, lanes):
+    """MiMo's decode call at the cell's two decode widths (64 query heads on
+    4 KV heads of a full layer, pages of ``[320, 128]``, a table of 466
+    pages; on 8 of a window layer with a sink, pages of ``[640, 128]``, the
+    70-column window table): the first-party kernel of ops/gqa_attention.py
+    (PR 46) at the module's constants."""
+    from dynamo_tpu.ops import gqa_attention as ga
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    n_kv, width, n_pages, window = (4, 466, 15361, None) if kind == "full" else (8, 70, 273, 128)
+    pages = sds((n_pages, *ga.gqa_page_shape(32, n_kv, 192, 128)), jnp.bfloat16)
+    assert ga.decode_impl("tpu", pages, n_kv) == "pallas"
+    compiled = jax.jit(
+        lambda q, p, lens, tables, sinks: ga.gqa_decode_pallas(
+            q, p, lens, tables, sinks if window else None, n_kv=n_kv, sm_scale=192 ** -0.5,
+            window=window)
+    ).lower(
+        sds((lanes, 64, 192), jnp.bfloat16), pages, sds((lanes,), jnp.int32),
+        sds((lanes, width), jnp.int32), sds((64,), jnp.float32),
+    ).compile()
+    assert "gqa_decode_attention_kernel" in compiled.as_text()
+
+
 @pytest.mark.parametrize("rows,k,held,h,im", [
     (2048, 4, 64, 2048, 1536),      # lfm2-24b-hybrid-decode, its widest wave
     (512, 4, 64, 2048, 1536),       # ... and a narrow one
