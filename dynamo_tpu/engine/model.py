@@ -856,8 +856,11 @@ def _mlp(x, lp, cfg: ModelConfig, tp: int, mesh=None, row_valid=None,
 # rehearsals, int8, odd widths) :func:`_experts_all_rows`, the loop of XLA
 # products that is the definition of both (``"all_rows"``).
 _EXPERTS_ALL_ROWS_MAX = 256
-# Above it: the chosen pairs sorted by expert and one grouped product over
-# them (:func:`_experts_grouped`), whatever the count of held experts.
+# Above it: the chosen pairs sorted by expert and the experts' SwiGLU over
+# them (:func:`_experts_grouped`), whatever the count of held experts: on a
+# TPU one kernel that streams each TOUCHED expert's weights once
+# (``ops/expert_stream.py:grouped_impl`` says where: ``"grouped/stream"``, PR
+# 47), else two grouped products (``ops/grouped_matmul.py:impl``).
 
 
 def expert_call_shape(rows: int) -> str:
@@ -865,6 +868,15 @@ def expert_call_shape(rows: int) -> str:
     prefill wave), else ``"step"``: the ``shape`` under which a sparse
     layer's call is counted (``ops/grouped_matmul.py:count_traced``)."""
     return "wave" if rows > _EXPERTS_ALL_ROWS_MAX else "step"
+
+
+def wave_impl(backend: str, dtype, rows_a_group: float, w_gu, w_down) -> str:
+    """The implementation a wave's chosen pairs get (the ``impl`` of
+    :func:`_experts_grouped`; ``grouped/<it>`` is the call's label):
+    ``"stream"`` where ``ops/expert_stream.py:grouped_impl`` takes the call,
+    else ``ops/grouped_matmul.py:impl``'s ``"pallas"`` or ``"ragged_dot"``."""
+    return (expert_stream.grouped_impl(backend, dtype, rows_a_group, w_gu, w_down)
+            or grouped_matmul.impl(backend, dtype, w_gu, w_down))
 
 
 def route_sigmoid(xf: jax.Array, w_router: jax.Array, cfg: ModelConfig,
@@ -974,24 +986,27 @@ def _experts_all_rows(xf, w_held, w_gu, w_down):
                              unroll=math.gcd(_EXPERTS_LOOP_UNROLL, w_gu.shape[0]))
 
 
-def _sorted_pairs(chosen_held, w_held, k: int, tile: int):
+def _sorted_pairs(chosen_held, w_held, k: int, tile: int, align: int = 1):
     """The permutation that puts the chosen (row, held expert) pairs in
     expert order, without a sort. A pair's place is its expert's offset
-    (the exclusive ``cumsum`` of the experts' counts) + its rank among
-    that expert's rows (``cumsum`` down its column); a row's pairs, at most
-    ``k``, come out in ascending expert order because its places ascend
-    with the expert. Places past ``sum(counts)`` belong to no group: a row
-    with fewer than ``k`` held experts points its spare pairs there (place
-    ``P``, weight 0).
+    (the exclusive ``cumsum`` of the experts' counts, each rounded up to
+    ``align`` places: the streamed kernel's groups start on whole sublane
+    tiles, ``ops/expert_stream.py``) + its rank among that expert's rows
+    (``cumsum`` down its column); a row's pairs, at most ``k``, come out in
+    ascending expert order because its places ascend with the expert.
+    Places that hold no pair belong to no group: a row with fewer than
+    ``k`` held experts points its spare pairs past them all (place ``P``,
+    weight 0).
 
     Returns (``rows`` ``[P]`` int32: the row each sorted place reads, 0
-    where no pair lands; ``counts`` ``[Eh]`` int32; ``place`` ``[N, k]``
-    int32; ``weight`` ``[N, k]`` float32), ``P`` = ``N x k`` rounded up to
-    whole ``tile``s (the caller's slabs)."""
+    where no pair lands; ``counts`` ``[Eh]`` int32; ``place`` ``[N,
+    k]`` int32; ``weight`` ``[N, k]`` float32), ``P`` = ``N x k + (align - 1)
+    x Eh`` rounded up to whole ``tile``s (the caller's slabs)."""
     N, Eh = chosen_held.shape
-    P = -(-N * k // tile) * tile
+    P = -(-(N * k + (align - 1) * Eh) // tile) * tile
     counts = jnp.sum(chosen_held, axis=0, dtype=jnp.int32)
-    offset = jnp.cumsum(counts) - counts
+    padded = -(-counts // align) * align
+    offset = jnp.cumsum(padded) - padded
     rank = jnp.cumsum(chosen_held, axis=0, dtype=jnp.int32) - 1
     dest = jnp.where(chosen_held, offset[None, :] + rank, P)             # [N, Eh]
     neg, expert = jax.lax.top_k(-dest, k)          # the k smallest places, ascending
@@ -1004,9 +1019,12 @@ def _sorted_pairs(chosen_held, w_held, k: int, tile: int):
 
 # Bytes of temporaries the grouped layer may hold at a time: the sorted
 # rows, both products' results and the activation of one SLAB of the
-# sorted places. LFM2's widest wave (8,192 places of 27 KB) is one slab;
-# A.X-K1's (16,384 of 62 KB, a tenth of them live) would hold 1.0 GB at
-# once and goes 4,096 places at a time, as many slabs as hold a pair.
+# sorted places (the streamed kernel keeps the middle two in VMEM; its slabs
+# are cut by the same count, so that a chip that holds few of the experts
+# gathers no more empty places than it did). LFM2's widest wave (8,192
+# places of 27 KB) is one slab; A.X-K1's (16,384 of 62 KB, a tenth of them
+# live) would hold 1.0 GB at once and goes ~4,096 places at a time, as many
+# slabs as hold a pair.
 _GROUPED_SLAB_BYTES = 256 * 2 ** 20
 
 
@@ -1026,34 +1044,45 @@ def _experts_grouped(xf, w_held, chosen_held, w_gu, w_down, *, k: int, impl: str
     slab of the sorted places at a time (:func:`_slab_places`; one slab
     where they fit, else as many as hold a pair, so a chip that holds few
     of the experts gathers and multiplies what it holds): the slab's rows
-    gathered once, two grouped products over them
-    (``ops/grouped_matmul.py``: a held expert's weights are read once
-    whatever the width, and only the tiles that hold a pair are
-    multiplied), and a weighted combine (``grouped_matmul.combine``: a
-    row's terms added in ascending expert order, as
-    :func:`_experts_all_rows` adds them, its other terms being exact
-    zeros, so a token's sum has one order in a wave and in a decode step).
-    bf16 operands and float32 sums as :func:`_swiglu`. ``k``: pairs a row
+    gathered once, the experts' SwiGLU over them, and a weighted combine
+    (``grouped_matmul.combine``: a row's terms added in ascending expert
+    order, as :func:`_experts_all_rows` adds them, its other terms being
+    exact zeros, so a token's sum has one order in a wave and in a decode
+    step). ``impl`` ``"stream"``: ONE kernel (``ops/expert_stream.py:
+    expert_stream_grouped``) that reads each touched expert's weights once
+    and keeps the gate/up sums and the activation in VMEM; else two grouped
+    products (``ops/grouped_matmul.py``) with ``silu(g) * u`` between them.
+    Either way a held expert's weights are read once a slab whatever the
+    width, only the tiles that hold a pair are multiplied, and the operands
+    are bf16 and the sums float32 as :func:`_swiglu`'s. ``k``: pairs a row
     at most (the experts a token, or all that are held if fewer);
     ``all_held``: the chip holds every expert the router chooses among, so
     each row's ``k`` pairs are all here. Jitted, so that the sparse layers
     of a program trace it once. ``[N, h]`` float32."""
     N, h = xf.shape
-    S = _slab_places(N * k, h, w_down.shape[1], xf.dtype.itemsize,
-                     grouped_matmul.tile_rows(impl))
-    rows, counts, place, weight = _sorted_pairs(chosen_held, w_held, k, S)
-    end = jnp.cumsum(counts)
-    start, total = end - counts, end[-1]
+    Eh = w_gu.shape[0]
+    stream = impl == "stream"
+    align = expert_stream.GROUP_ALIGN if stream else 1
+    S = _slab_places(N * k + (align - 1) * Eh, h, w_down.shape[1], xf.dtype.itemsize,
+                     expert_stream.SLAB_ROWS if stream else grouped_matmul.tile_rows(impl))
+    rows, counts, place, weight = _sorted_pairs(chosen_held, w_held, k, S, align)
+    padded = -(-counts // align) * align
+    start = jnp.cumsum(padded) - padded
+    end, total = start + counts, jnp.sum(padded)
 
     def slab(s, out):
         lo = s * S
         x = xf[jax.lax.dynamic_slice_in_dim(rows, lo, S)]
-        sizes = jnp.clip(end, lo, lo + S) - jnp.clip(start, lo, lo + S)
-        gu = grouped_matmul.grouped_matmul(x, w_gu, sizes, impl=impl)
-        g, u = jnp.split(gu, 2, axis=-1)
-        act = (jax.nn.silu(g) * u).astype(xf.dtype)
-        y = grouped_matmul.grouped_matmul(act, w_down, sizes, impl=impl)
-        # a place of another slab, or past sum(counts), is none here: never computed
+        first = jnp.clip(start, lo, lo + S)
+        sizes = jnp.clip(end, lo, lo + S) - first
+        if stream:
+            y = expert_stream.expert_stream_grouped(x, first - lo, sizes, w_gu, w_down)
+        else:
+            gu = grouped_matmul.grouped_matmul(x, w_gu, sizes, impl=impl)
+            g, u = jnp.split(gu, 2, axis=-1)
+            act = (jax.nn.silu(g) * u).astype(xf.dtype)
+            y = grouped_matmul.grouped_matmul(act, w_down, sizes, impl=impl)
+        # a place of another slab, or past the groups, is none here: never computed
         at = place - lo
         at = jnp.where((at >= 0) & (at < jnp.minimum(total - lo, S)), at, S)
         return grouped_matmul.combine(out, y, at, weight, full=all_held)
@@ -1092,28 +1121,30 @@ def _shared_sparse_mlp(x, lp, cfg: ModelConfig, row_valid=None,
         Eh = hi - lo
         shape_name = expert_call_shape(N)
         backend = jax.default_backend()
-        grouped = grouped_matmul.impl(
-            backend, xf.dtype, lp["w_gu"], lp["w_down"],
-        ) if shape_name == "wave" else None
+        k = min(cfg.num_experts_per_tok, Eh)
+        counts = jnp.sum(chosen_held, axis=0, dtype=jnp.int32)
+        grouped = wave_impl(
+            backend, xf.dtype, N * cfg.num_experts_per_tok / cfg.num_experts,
+            lp["w_gu"], lp["w_down"]) if shape_name == "wave" else None
         path = f"grouped/{grouped}" if grouped else expert_stream.impl(
             backend, xf.dtype, N, lp["w_gu"], lp["w_down"])
         grouped_matmul.count_traced(shape_name, path)
         if expert_stats is not None:
+            if grouped == "stream":
+                visited = expert_stream.grouped_rows_visited(counts)
+            elif grouped:
+                visited = grouped_matmul.rows_visited(counts, grouped_matmul.tile_rows(grouped))
+            else:
+                visited = jnp.int32(Eh * N)
             expert_stats.append(jnp.stack([
-                jnp.sum(jnp.any(chosen_held, axis=0)), jnp.int32(1),
-                jnp.sum(chosen_held),
-                jnp.sum(row_valid) * cfg.num_experts_per_tok,
-                grouped_matmul.rows_visited(
-                    jnp.sum(chosen_held, axis=0, dtype=jnp.int32),
-                    grouped_matmul.tile_rows(grouped),
-                ) if grouped else jnp.int32(Eh * N),
+                jnp.sum(counts > 0), jnp.int32(1), jnp.sum(counts),
+                jnp.sum(row_valid) * cfg.num_experts_per_tok, visited,
             ]).astype(jnp.int32))
     with jax.named_scope("experts"):
         if grouped:
             out = _experts_grouped(
                 xf, w_held, chosen_held, lp["w_gu"], lp["w_down"],
-                k=min(cfg.num_experts_per_tok, Eh), impl=grouped,
-                all_held=Eh == cfg.num_experts)
+                k=k, impl=grouped, all_held=Eh == cfg.num_experts)
         elif path == "stream/pallas":
             out = expert_stream.expert_stream(xf, w_held, lp["w_gu"], lp["w_down"])
         else:

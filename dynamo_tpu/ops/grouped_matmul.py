@@ -9,7 +9,12 @@ what the result holds there is unspecified, and the caller masks it.
 It is what a sparse MLP's prefill wave needs (``model._experts_grouped``):
 the chosen (row, expert) pairs sorted by expert, every held expert's
 weights read once whatever the wave's width, and only the tiles of rows
-that hold a pair multiplied.
+that hold a pair multiplied. Since PR 47 a wave whose groups are bound by
+their weights' bytes (every cell's, today) takes the whole layer as ONE
+kernel instead (``ops/expert_stream.py:expert_stream_grouped``); the two
+products here serve the calls that kernel does not take (groups many tiles
+tall, the CPU, int8, odd widths), and :func:`combine` and the counter serve
+both.
 
 One algorithm, the implementation chosen from what the call can observe
 (:func:`impl`, as ``ops/latent_attention.py:decode_impl`` chooses):
@@ -143,8 +148,9 @@ def combine(out: jax.Array, y: jax.Array, place: jax.Array, weight: jax.Array, *
 # Sparse layers' expert calls traced since the process started, by the
 # shape of the call ("wave": more rows than every-expert-on-every-row
 # serves, a prefill wave; "step": a decode step's rows) and the path it
-# got ("grouped/pallas", "grouped/ragged_dot" for a wave; for a step
-# "stream/pallas", ``ops/expert_stream.py``'s kernel, or "all_rows", the
+# got ("grouped/stream", ``ops/expert_stream.py``'s grouped kernel,
+# "grouped/pallas" or "grouped/ragged_dot" for a wave; for a step
+# "stream/pallas", ``ops/expert_stream.py``'s step kernel, or "all_rows", the
 # loop of XLA products: every held expert on every row either way). Static
 # per compiled program, so counted at trace time, as
 # ``ops/ragged_attention.py`` counts the attention calls.

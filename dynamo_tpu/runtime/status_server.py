@@ -481,8 +481,10 @@ class _EngineCounters:
             "Sparse layers' expert calls traced into step programs, by "
             "shape (wave: more rows than every expert on every row serves, "
             "a prefill wave; step: a decode step's rows) and the path "
-            "chosen (grouped/pallas, grouped/ragged_dot: one grouped "
-            "product over the chosen pairs sorted by expert; "
+            "chosen (grouped/stream: the chosen pairs sorted by expert "
+            "through one Pallas kernel that streams each touched expert's "
+            "weights once; grouped/pallas, grouped/ragged_dot: two grouped "
+            "products over them; "
             "stream/pallas, all_rows: every held expert on every row, as "
             "one Pallas kernel that streams each expert's weights once or "
             "as a loop of XLA products)",

@@ -376,6 +376,42 @@ def test_mosaic_compiles_the_grouped_expert_layer_for_a_v5e(one_chip, rows, k, h
     assert compiled.memory_analysis().temp_size_in_bytes < rows * k * (2 * h + 12 * im + 6 * h)
 
 
+@pytest.mark.parametrize("rows,k,experts,held,h,im", [
+    (1024, 8, 128, 128, 2048, 768),     # sdar30b-block-decode, a pass of its megastep
+    (2048, 8, 128, 128, 2048, 768),     # ... and its widest wave
+    (2048, 4, 64, 64, 2048, 1536),      # lfm2-24b-hybrid-decode
+    (2048, 10, 256, 32, 3072, 1024),    # laguna-s21-longctx-agents
+    (2048, 8, 192, 12, 7168, 2048),     # axk1-ep16-decode: three slabs
+    (2048, 8, 256, 16, 4096, 2048),     # mimo-v25-ep16-longctx
+], ids=["sdar-1024", "sdar-2048", "lfm2-2048", "laguna-2048", "axk1-2048", "mimo-2048"])
+def test_mosaic_compiles_the_streamed_grouped_layer_for_a_v5e(
+        one_chip, rows, k, experts, held, h, im):
+    """The grouped expert layer as ONE kernel (``ops/expert_stream.py:
+    expert_stream_grouped``, PR 47) at the five sparse cells' shapes, at the
+    blocks the module chooses: one Mosaic call a slab, the gate/up sums and
+    the activation in its VMEM, so the layer's temporaries are the sorted
+    rows and the result and nothing ``[places, 2 im]``."""
+    from dynamo_tpu.engine import model
+    from dynamo_tpu.ops import expert_stream as es
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    w_gu, w_down = sds((held, h, 2 * im), jnp.bfloat16), sds((held, im, h), jnp.bfloat16)
+    assert es.grouped_impl("tpu", jnp.bfloat16, rows * k / experts, w_gu, w_down) == "stream"
+    compiled = jax.jit(
+        lambda *a: model._experts_grouped(*a, k=min(k, held), impl="stream",
+                                          all_held=held == experts)
+    ).lower(
+        sds((rows, h), jnp.bfloat16), sds((rows, held), jnp.float32), sds((rows, held), jnp.bool_),
+        w_gu, w_down,
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "expert_stream_grouped_kernel" in text
+    places = model._slab_places(rows * min(k, held) + 15 * held, h, im, 2, es.SLAB_ROWS)
+    # (1.02-1.04 x the bf16 rows and the float32 result of one slab, by this reading)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.15 * places * (2 * h + 4 * h)
+
+
 @pytest.mark.parametrize("rows", [32, 128, 256])
 @pytest.mark.parametrize("held,h,im", [(64, 2048, 1536), (12, 7168, 2048)], ids=["lfm2", "axk1"])
 def test_mosaic_compiles_the_expert_stream_kernel_for_a_v5e(one_chip, held, h, im, rows):

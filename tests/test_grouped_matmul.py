@@ -2,7 +2,8 @@
 over the groups, the choice of implementation and of the Pallas kernel's
 blocks at the two sparse cells' shapes, the rows a call visits, and the
 tool that times one sparse layer alone (``tools/experts_bench.py``) at toy
-shapes. (Mosaic compiling the kernel for a described v5e at the cells'
+shapes, the streamed grouped kernel of ``ops/expert_stream.py`` among its
+variants. (Mosaic compiling the kernel for a described v5e at the cells'
 shapes: ``tests/test_decode_attention.py``, which holds the topology.)"""
 
 import json
@@ -89,16 +90,16 @@ def test_the_experts_bench_runs_at_toy_shapes_and_refuses_the_cpu_otherwise(tmp_
 
     with pytest.raises(SystemExit, match="no TPU"):
         experts_bench.main(["--shapes", "lfm2", "--rows", "128"])
-    rc = experts_bench.main(["--toy", "--rows", "128,512", "--routing", "random,one", "--pieces",
-                             "--out", str(tmp_path)])
+    rc = experts_bench.main(["--toy", "--shapes", "lfm2,axk1", "--rows", "128,512",
+                             "--routing", "random,one", "--pieces", "--out", str(tmp_path)])
     assert rc == 0
     printed = capsys.readouterr().out
     assert "toy shapes" in printed and "grouped/ragged_dot" in printed and "permutation" in printed
     table = json.loads((tmp_path / "table.json").read_text())
     assert table["toy"] and not any("error" in line for line in table["lines"])
     by = {(l["shape"], l["rows"], l["routing"], l["variant"]): l for l in table["lines"]}
-    # a step's width gets the stream kernel (interpreted here) beside the eight, a wave's does not
-    assert len(by) == 2 * 2 * (9 + 8)
+    # a step's width gets the stream kernel (interpreted here) beside the nine, a wave's does not
+    assert len(by) == 2 * 2 * (10 + 9)
     for shape in ("lfm2", "axk1"):
         for routing in ("random", "one"):
             assert by[shape, 128, routing, "stream/pallas auto"]["max_abs_diff"] < 5e-2
@@ -110,6 +111,14 @@ def test_the_experts_bench_runs_at_toy_shapes_and_refuses_the_cpu_otherwise(tmp_
             assert by[shape, 512, routing, "serving"]["rows_computed"] == wide["pairs_held"]
             assert by[shape, 512, routing, "all_rows"]["rows_computed"] == held * 512
             assert "rows_computed" not in by[shape, 128, routing, "serving"]
+            # the streamed kernel (interpreted): a touched expert's rows rounded up by under 64
+            stream = by[shape, 512, routing, "grouped/stream auto"]
+            assert stream["max_abs_diff"] < 5e-2 and stream["wave_impl"] == "ragged_dot"
+            assert wide["pairs_held"] <= stream["rows_computed"] < (
+                wide["pairs_held"] + 64 * stream["experts_touched"])
+            assert stream["rows_computed"] % 64 == 0 and 0 < stream["experts_touched"] <= held
+            assert stream["touched_bytes_ms"] == pytest.approx(
+                stream["bytes_ms"] * stream["experts_touched"] / held)
     # every row on held expert 0 first: a share then holds more pairs than an even router sends it
     assert by["axk1", 512, "one", "all_rows"]["pairs_held"] > by["axk1", 512, "random", "all_rows"]["pairs_held"]
     assert by["lfm2", 512, "one", "all_rows"]["pairs_held"] == 512 * 4      # every expert held: every pair
@@ -121,6 +130,30 @@ def test_the_experts_bench_runs_at_toy_shapes_and_refuses_the_cpu_otherwise(tmp_
     assert rc == 0 and all(l["in_loop"] == 2 and "error" not in l for l in looped.values())
     assert looped["stream/pallas auto"]["max_abs_diff"] < 5e-2
     assert looped["stream/pallas 128x128x2"]["max_abs_diff"] < 5e-2
-    assert set(experts_bench.SHAPES) == set(experts_bench.TOY) == {"lfm2", "axk1"}
+    assert set(experts_bench.SHAPES) == set(experts_bench.TOY) == {
+        "lfm2", "axk1", "sdar", "laguna", "mimo"}
     assert experts_bench.SHAPES["lfm2"] == (2048, 1536, 64, 64, 4)
     assert experts_bench.SHAPES["axk1"] == (7168, 2048, 192, 12, 8)
+    assert experts_bench.SHAPES["sdar"] == (2048, 768, 128, 128, 8)
+    assert experts_bench.SHAPES["laguna"] == (3072, 1024, 256, 32, 10)
+    assert experts_bench.SHAPES["mimo"] == (4096, 2048, 256, 16, 8)
+
+
+@pytest.mark.parametrize("shape", ["sdar", "laguna", "mimo"])
+def test_the_experts_bench_times_the_streamed_kernel_at_the_other_cells_toy_shapes(
+        shape, tmp_path):
+    """``sdar`` (every expert held, a router as skewed as the cell's),
+    ``laguna`` and ``mimo`` (a chip's share of the experts): the streamed
+    kernel at stated items beside the module's own, and its pieces."""
+    from tools import experts_bench
+
+    rc = experts_bench.main(["--toy", "--shapes", shape, "--rows", "384", "--routing", "skewed",
+                             "--items", "auto,256x2", "--out", str(tmp_path)])
+    lines = {l["variant"]: l for l in json.loads((tmp_path / "table.json").read_text())["lines"]}
+    assert rc == 0 and not any("error" in l for l in lines.values())
+    assert set(lines) == {"serving", "all_rows", "grouped/ragged_dot", "grouped/stream auto",
+                          "grouped/stream 256x2"}
+    for tag in ("grouped/stream auto", "grouped/stream 256x2"):
+        assert lines[tag]["max_abs_diff"] < 5e-2
+        assert lines[tag]["rows_computed"] >= lines[tag]["pairs_held"] > 0
+    assert lines["serving"]["experts_touched"] <= experts_bench.TOY[shape][3]
