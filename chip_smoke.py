@@ -553,10 +553,11 @@ def judge_attention_traced(role: str, got: dict[str, float], platform: str) -> N
         raise PhaseFailed(
             f"{role}: latent decode attention ran its jnp path on a TPU, not the "
             f"paged kernel: {got}")
-    if platform == "tpu" and {"gqa-decode/jnp", "window-gqa-decode/jnp"} & traced:
+    if platform == "tpu" and {"gqa-decode/jnp", "window-gqa-decode/jnp", "gqa-ragged/jnp",
+                              "window-gqa-ragged/jnp"} & traced:
         raise PhaseFailed(
-            f"{role}: wide-key decode attention ran the chunked jnp walk on a TPU, not "
-            f"the paged kernel: {got}")
+            f"{role}: wide-key attention ran the chunked jnp walk on a TPU, not its "
+            f"paged kernel (decode steps and waves have one each): {got}")
 
 
 def judge_window(role: str, startup: dict, traced: dict[str, float]) -> None:

@@ -1510,8 +1510,8 @@ def dense_layer(
         kv_pages, kv_scales = cache_l, None
     with jax.named_scope("attn"):
         if cfg.wide_key:
-            # ops/gqa_attention.py: the kernel in the decode shape, the
-            # chunked walk in a wave; the sink a leaf of the kinds that have one
+            # ops/gqa_attention.py: a kernel of its own in each shape on a TPU
+            # (the chunked walk on the CPU); the sink a leaf of the kinds that have one
             with jax.named_scope("window" if window else "full"):
                 kw = dict(n_kv=n_kv, sm_scale=sm_scale, window=window, sinks=lp.get("sink"))
                 if cu_q_lens is None:

@@ -91,19 +91,26 @@ def test_a_window_models_worker_is_held_to_its_window():
 
 
 @pytest.mark.parametrize("traced,platform,error", [
-    ({"gqa-decode/pallas": 2.0, "window-gqa-decode/pallas": 5.0, "gqa-ragged/jnp": 2.0,
-      "window-gqa-ragged/jnp": 5.0}, "tpu", None),
-    ({"gqa-decode/jnp": 2.0, "window-gqa-decode/jnp": 3.0}, "cpu", None),
+    ({"gqa-decode/pallas": 2.0, "window-gqa-decode/pallas": 5.0, "gqa-ragged/pallas": 2.0,
+      "window-gqa-ragged/pallas": 5.0}, "tpu", None),
+    ({"gqa-decode/jnp": 2.0, "window-gqa-decode/jnp": 3.0, "gqa-ragged/jnp": 2.0,
+      "window-gqa-ragged/jnp": 5.0}, "cpu", None),
     ({"gqa-decode/jnp": 2.0, "window-gqa-decode/pallas": 5.0}, "tpu", "chunked jnp walk on a TPU"),
     ({"gqa-decode/pallas": 2.0, "window-gqa-decode/jnp": 5.0}, "tpu", "chunked jnp walk on a TPU"),
-    ({"gqa-ragged/jnp": 2.0, "window-gqa-ragged/jnp": 5.0}, "tpu", "no decode-shaped"),
-], ids=["the-kernel-on-a-tpu", "the-walk-on-the-cpu", "full-layers-walked-on-a-tpu",
-        "window-layers-walked-on-a-tpu", "no-decode-call"])
+    ({"gqa-decode/pallas": 2.0, "window-gqa-decode/pallas": 5.0, "gqa-ragged/jnp": 2.0,
+      "window-gqa-ragged/pallas": 5.0}, "tpu", "chunked jnp walk on a TPU"),
+    ({"gqa-decode/pallas": 2.0, "window-gqa-decode/pallas": 5.0, "gqa-ragged/pallas": 2.0,
+      "window-gqa-ragged/jnp": 5.0}, "tpu", "chunked jnp walk on a TPU"),
+    ({"gqa-ragged/pallas": 2.0, "window-gqa-ragged/pallas": 5.0}, "tpu", "no decode-shaped"),
+], ids=["the-kernels-on-a-tpu", "the-walk-on-the-cpu", "full-layers-walked-on-a-tpu",
+        "window-layers-walked-on-a-tpu", "full-waves-walked-on-a-tpu",
+        "window-waves-walked-on-a-tpu", "no-decode-call"])
 def test_a_wide_key_models_worker_is_held_to_its_kernel(traced, platform, error):
     """A model of the wide-key page (MiMo): its decode steps trace ``gqa-decode``
-    and ``window-gqa-decode``, on a TPU the Pallas kernel in BOTH layer kinds (its
-    waves are the chunked ``jnp`` walk, said as such), and its window pool is
-    held to its window like Laguna's."""
+    and ``window-gqa-decode`` and its waves ``gqa-ragged`` and
+    ``window-gqa-ragged``, on a TPU a Pallas kernel in BOTH shapes and BOTH layer
+    kinds (since PR 48 a wave is no longer the chunked ``jnp`` walk), and its
+    window pool is held to its window like Laguna's."""
     import chip_smoke
 
     startup = {"window_blocks": 272, "block_size": 32, "sliding_window": 128, "megastep_k": 8,

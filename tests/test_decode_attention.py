@@ -347,6 +347,33 @@ def test_mosaic_compiles_the_wide_key_decode_kernel_for_a_v5e(one_chip, kind, la
     assert "gqa_decode_attention_kernel" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows", [2048, 512])
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_mosaic_compiles_the_wide_key_wave_kernel_for_a_v5e(one_chip, kind, rows):
+    """MiMo's wave call at the cell's widest bucket and at the probe's (64
+    query heads on 4 KV heads of a full layer over the 466-column table; on
+    8 of a window layer with a sink over the 70-column window table; a
+    wave's 8 table rows): the first-party kernel of ops/gqa_attention.py (PR
+    48) at the module's constants, its states and a tile's queries inside
+    the VMEM limit it asks for."""
+    from dynamo_tpu.ops import gqa_attention as ga
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    n_kv, width, n_pages, window = (4, 466, 15361, None) if kind == "full" else (8, 70, 273, 128)
+    pages = sds((n_pages, *ga.gqa_page_shape(32, n_kv, 192, 128)), jnp.bfloat16)
+    assert ga.decode_impl("tpu", pages, n_kv) == "pallas"
+    compiled = jax.jit(
+        lambda q, p, lens, tables, cu, live, sinks: ga.gqa_ragged_pallas(
+            q, p, lens, tables, cu, live, sinks if window else None, n_kv=n_kv,
+            sm_scale=192 ** -0.5, window=window)
+    ).lower(
+        sds((rows, 64, 192), jnp.bfloat16), pages, sds((8,), jnp.int32),
+        sds((8, width), jnp.int32), sds((9,), jnp.int32), sds((1,), jnp.int32),
+        sds((64,), jnp.float32),
+    ).compile()
+    assert "gqa_ragged_attention_kernel" in compiled.as_text()
+
+
 @pytest.mark.parametrize("rows,k,held,h,im", [
     (2048, 4, 64, 2048, 1536),      # lfm2-24b-hybrid-decode, its widest wave
     (512, 4, 64, 2048, 1536),       # ... and a narrow one

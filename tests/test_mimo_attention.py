@@ -1,8 +1,9 @@
 """``ops/gqa_attention.py`` on the CPU: the wide-key page (a key of 1.5
 values' width), its writer, and the two shapes of its attention against the
 whole-gather definition and the textbook: groups of 16 and 8, the sink,
-shifted (window) tables, ragged pieces; the decode kernel under Pallas's TPU
-interpret mode at the published page (192 / 128, a page of 32 tokens)."""
+shifted (window) tables, ragged pieces; the decode kernel and the wave kernel
+under Pallas's TPU interpret mode at the published page (192 / 128, a page of
+32 tokens)."""
 
 from __future__ import annotations
 
@@ -208,6 +209,89 @@ def test_the_kernel_holds_bfloat16_pages_to_the_textbook():
                                _textbook(c, sm_scale=192 ** -0.5), atol=1e-2)
 
 
+# -- the wave kernel under Pallas's TPU interpret mode --------------------------
+
+_KINDS = {"full": dict(n_kv=4, G=16, sink=False, window=None),
+          "window": dict(n_kv=8, G=8, sink=True, window=128)}
+
+
+def _wave(c, window, num=None, **kw):
+    from jax.experimental.pallas import tpu as pltpu
+
+    q, pages, kv_lens, tables, cu, live = _args(c)
+    if num is not None:
+        live = jnp.asarray([num], jnp.int32)
+    with pltpu.force_tpu_interpret_mode():
+        # ended here, as the decode kernel's call is (:func:`_kernel`)
+        got = jax.block_until_ready(ga.gqa_ragged_pallas(
+            q, pages, kv_lens, tables, cu, live, c["sinks"], n_kv=c["n_kv"],
+            sm_scale=192 ** -0.5, window=window, **kw))
+    want = ga.gqa_attention_ref(q, pages, kv_lens, tables, cu, live, n_kv=c["n_kv"],
+                                sm_scale=192 ** -0.5, window=window, sinks=c["sinks"])
+    return got, want
+
+
+_SMALL = dict(queries_per_item=32, pages_per_block=4, product_rows=128)
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+@pytest.mark.parametrize("lens,q_lens,num,grid", [
+    ([363], [263], None, {}),
+    ([171], [71], None, _SMALL),
+    ([40, 9, 30, 64, 100], [20, 9, 5, 30, 17], None, _SMALL),
+    ([70, 200, 1, 33], [1, 40, 1, 1], None, _SMALL),
+    ([70, 200, 33, 9, 50], [33, 40, 33, 9, 50], 3, _SMALL),
+    ([250, 31], [90, 31], None, dict(queries_per_item=64, pages_per_block=8, blocks_in_ring=2,
+                                     product_rows=128)),
+], ids=["two-items-and-7-rows-behind-a-prefix-at-the-modules-constants",
+        "two-items-and-7-rows-behind-a-prefix", "sequences-share-a-tile", "sequences-of-one-row",
+        "dead-sequences-behind-num-seqs", "another-grid"])
+def test_the_wave_kernel_is_the_definition(kind, lens, q_lens, num, grid):
+    """The ragged call at the published page, a full layer's (4 KV heads, no
+    sink) and a window layer's (8 KV heads, the sink, window 128): items of
+    one sequence's rows in one tile of the flat batch, the KV blocks an item
+    can see by DMA through the table, the mask in the blocks the bounds cut,
+    a tile two sequences share stored under each one's rows, the rows past
+    the last live sequence zero."""
+    k = _KINDS[kind]
+    c = _case(lens, q_lens, n_kv=k["n_kv"], G=k["G"], sink=k["sink"], width=12, seed=11)
+    got, want = _wave(c, k["window"], num, **grid)
+    assert got.shape == (sum(q_lens), 64, 128) and got.dtype == c["q"].dtype
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    n = int(c["cu"][len(lens) if num is None else num])
+    assert not np.asarray(got[n:]).any()
+    np.testing.assert_allclose(np.asarray(got[:n]),
+                               _textbook(c, sm_scale=192 ** -0.5, window=k["window"])[:n],
+                               atol=2e-5)
+
+
+def test_the_wave_kernel_on_a_shifted_table_gives_the_unshifted_result():
+    """A window layer's wave: the table from the page of the oldest key the
+    chunk's FIRST query sees and ``kv_lens`` less the tokens before it."""
+    lens, q_lens = np.asarray([300, 77, 190]), np.asarray([40, 77, 3])
+    c = _case(lens, q_lens, **{k: v for k, v in _KINDS["window"].items() if k != "window"},
+              width=12, seed=5)
+    whole, _ = _wave(c, 128, **_SMALL)
+    first = np.maximum(lens - q_lens - 127, 0) // 32
+    tables = np.asarray(c["tables"])
+    shifted = np.stack([np.roll(tables[s], -first[s])[:8] for s in range(3)])
+    got, want = _wave({**c, "lens": jnp.asarray(lens - 32 * first),
+                       "tables": jnp.asarray(shifted)}, 128, **_SMALL)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(whole), atol=2e-6)
+
+
+def test_the_wave_kernel_holds_bfloat16_pages_to_the_textbook():
+    """bfloat16 pages and queries, float32 scores and sums, the weights in
+    bfloat16 against the values: the textbook on the values the cache holds,
+    at the tolerance the decode kernel is held to."""
+    c = _case([70, 200, 33], [33, 72, 1], n_kv=8, G=8, dtype=jnp.bfloat16, sink=True)
+    got, _ = _wave(c, 128, **_SMALL)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               _textbook(c, sm_scale=192 ** -0.5, window=128), atol=1e-2)
+
+
 def test_calls_are_counted_by_shape_and_the_gather_is_refused_on_a_tpu(monkeypatch):
     c = _case([5, 9], n_kv=2, G=4, dk=24, dv=16, ps=4, width=4, sink=True)
     before = ragged_attention.traced_calls()
@@ -226,3 +310,19 @@ def test_calls_are_counted_by_shape_and_the_gather_is_refused_on_a_tpu(monkeypat
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pytest.raises(RuntimeError, match="never a TPU program's path"):
         ga.gqa_attention_ref(*_args(c), **kw)
+    # ... in BOTH shapes: a wave's call on a TPU traces the wave kernel (traced
+    # only: nothing here can run it), and still the walk at the tiny page
+    sds = jax.ShapeDtypeStruct
+    wave = (sds((48, 32, 192), jnp.bfloat16), big, sds((3,), jnp.int32), sds((3, 5), jnp.int32),
+            sds((4,), jnp.int32), sds((1,), jnp.int32))
+    assert ga.ragged_impl("tpu", big, 4, 32) == "pallas" and ga.ragged_impl("cpu", big, 4, 32) == "jnp"
+    assert ga.ragged_impl("tpu", big, 4, 16) == "jnp"     # a group of 4: no whole tile of rows
+    for window in (None, 128):
+        out = jax.eval_shape(lambda *a: ga.gqa_ragged_attention(
+            *a, n_kv=4, sm_scale=0.07, window=window), *wave)
+        assert out.shape == (48, 32, 128)
+        jax.eval_shape(lambda *a: ga.gqa_ragged_attention(*a, window=window, **kw), *_args(c))
+    last = ragged_attention.traced_calls()
+    for shape in ("gqa-ragged", "window-gqa-ragged"):
+        assert last.get((shape, "pallas"), 0) == after.get((shape, "pallas"), 0) + 1
+        assert last.get((shape, "jnp"), 0) == after.get((shape, "jnp"), 0) + 1
