@@ -6,6 +6,7 @@
     python chip_smoke.py --cpu-tiny --cpu-preset tiny-laguna # the same, window + full layers
     python chip_smoke.py --cpu-tiny --cpu-preset tiny-sdar   # the same, generation by blocks
     python chip_smoke.py --cpu-tiny --cpu-preset tiny-mimo   # the same, the wide-key page
+    python chip_smoke.py --cpu-tiny --cpu-preset tiny-olmo-hybrid  # the same, a slab a lane
 
 Starts the three processes a user starts (README "Run it"): the control-
 plane store, the JAX worker and the OpenAI frontend with the KV router.
@@ -455,6 +456,14 @@ def serve_phase(mode: str, inject: str | None, report: dict) -> None:
         report["experts_traced"] = check_calls_traced(
             workers, head["device"]["platform"],
             "dynamo_engine_expert_calls_traced_total", judge_experts_traced)
+        report["linear_traced"] = check_calls_traced(
+            workers, head["device"]["platform"],
+            "dynamo_engine_linear_calls_traced_total", judge_linear_traced)
+        for role, st in report["startup"].items():
+            if st.get("state_slots") and not any(
+                    k.startswith("step/") and v for k, v in report["linear_traced"][role].items()):
+                raise PhaseFailed(f"{role}: a model with linear-attention layers traced no "
+                                  f"state step: {report['linear_traced'][role]}")
         for role, st in report["startup"].items():
             judge_window(role, st, report["attention_traced"][role])
             judge_blocks(role, st, report["attention_traced"][role],
@@ -538,6 +547,15 @@ def judge_experts_traced(role: str, got: dict[str, float], platform: str) -> Non
         raise PhaseFailed(
             f"{role}: a sparse prefill wave ran every held expert on every row on a "
             f"TPU, not the grouped product over the chosen pairs: {got}")
+
+
+def judge_linear_traced(role: str, got: dict[str, float], platform: str) -> None:
+    """``got``: ``{"<shape>/<impl>": calls traced}`` of one worker (no series
+    for a model without linear-attention layers)."""
+    if platform == "tpu" and got.get("step/jnp"):
+        raise PhaseFailed(
+            f"{role}: a linear-attention layer's decode state step ran its jnp path on "
+            f"a TPU, not the kernel that reads and writes each lane's state once: {got}")
 
 
 def judge_attention_traced(role: str, got: dict[str, float], platform: str) -> None:
@@ -858,13 +876,15 @@ def main() -> int:
                        help="two one-chip workers: --role prefill and --role decode")
     which.add_argument("--kernel-check-child", action="store_true",
                        help=argparse.SUPPRESS)
-    ap.add_argument("--cpu-preset", choices=["tiny", "tiny-lfm2", "tiny-laguna", "tiny-sdar", "tiny-mimo"],
+    ap.add_argument("--cpu-preset", choices=["tiny", "tiny-lfm2", "tiny-laguna", "tiny-sdar", "tiny-mimo",
+                                             "tiny-olmo-hybrid"],
                     default="tiny",
                     help="what --cpu-tiny serves: the dense tiny preset, the "
                          "hybrid one (conv layers beside paired 64-wide heads), "
                          "the one of window and full attention layers (two pools), "
-                         "the one that generates by diffusion over blocks, or the one "
-                         "whose key is wider than its value (two pools of unequal pages)")
+                         "the one that generates by diffusion over blocks, the one "
+                         "whose key is wider than its value (two pools of unequal pages), "
+                         "or the one of linear-attention layers (a slab a lane)")
     ap.add_argument("--corrupt", action="store_true", help=argparse.SUPPRESS)
     ap.add_argument("--inject", choices=["worker-start", "bad-request",
                                          "kernel-mismatch"], default=None,
@@ -939,6 +959,7 @@ def main() -> int:
     print(f"served attention lowering: {report['attention']}")
     print(f"served attention traced (shape/impl: calls): {report['attention_traced']}")
     print(f"served experts traced (shape/impl: calls): {report['experts_traced']}")
+    print(f"served linear state calls traced (shape/impl: calls): {report['linear_traced']}")
     print("a token's account since start, ms (decode / behind a wave / behind "
           f"the host; warm-up and compiles included): {report['token_account']}")
     for c in report.get("kernels", {}).get("checks", ()):

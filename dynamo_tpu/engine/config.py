@@ -14,9 +14,10 @@ from dataclasses import dataclass, field
 import jax.numpy as jnp
 
 # What ModelConfig.layer_types may name, as published.
-LAYER_KINDS = ("conv", "full_attention", "sliding_attention")
+LAYER_KINDS = ("conv", "full_attention", "sliding_attention", "linear_attention")
 # What a layer caches (ModelConfig.layer_kind), by its published kind.
-_CACHE_KIND = {"conv": "conv", "full_attention": "attention", "sliding_attention": "window"}
+_CACHE_KIND = {"conv": "conv", "full_attention": "attention", "sliding_attention": "window",
+               "linear_attention": "linear"}
 
 _DTYPES = {
     "bfloat16": jnp.bfloat16,
@@ -49,7 +50,9 @@ class ModelConfig:
     num_heads: int = 32
     num_kv_heads: int = 8
     head_dim: int = 128
-    rope_theta: float = 500000.0
+    # None: NO rotary embedding (a model whose position reaches its attention
+    # layers through recurrent ones: ``rope_parameters.rope_theta`` null).
+    rope_theta: float | None = 500000.0
     rms_norm_eps: float = 1e-5
     dtype: str = "bfloat16"
     # Byte-level models (test tokenizer) tie embeddings to save params.
@@ -143,14 +146,35 @@ class ModelConfig:
     conv_L_cache: int = 0
     conv_bias: bool = False
     # RMSNorm over each head's values of q and of k (weights
-    # ``q_layernorm`` / ``k_layernorm`` [head_dim]) BEFORE rope.
+    # ``q_layernorm`` / ``k_layernorm`` [head_dim]) BEFORE rope; with
+    # ``qk_norm_over`` "projection" over the WHOLE projection of q and of k
+    # (weights ``[q_size]`` / ``[kv_size]``), before the split into heads.
     qk_norm: bool = False
+    qk_norm_over: str = "head"
+    # The one norm of each sub-layer is on its OUTPUT, ``x + norm(f(x))``, and
+    # its input is the stream as it is (``attn_norm`` / ``mlp_norm`` are then
+    # those output norms); ``sandwich_norm`` has both.
+    post_norm: bool = False
     # Cache 64-wide KV heads two to a 128-wide row where the geometry
     # allows (:attr:`kv_head_pairs`). The engine clears it for a dense
     # model served with an option the pair does not carry (a mesh, int8
     # pages), which then keeps the page and the options it had
     # (options._unpaired_where_not_carried).
     kv_pairing: bool = True
+    # -- gated-delta-rule layers among attention layers ----------------------
+    # A "linear_attention" layer (``layer_types``) keeps no K/V: per sequence
+    # a FLOAT32 state ``[linear_num_value_heads, linear_key_head_dim,
+    # linear_value_head_dim]`` and the ``linear_conv_kernel_dim - 1`` newest
+    # rows of its depthwise convolution's input, whatever the context, in a
+    # slab indexed by a LANE SLOT and not by a block id (:meth:`layer_kind`
+    # "linear"; ops/linear_attention.py; model.linear_layer).
+    # ``linear_allow_neg_eigval``: beta = 2 sigmoid(.), in (0, 2).
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+    linear_allow_neg_eigval: bool = False
     # -- window and full attention layers mixed (Laguna) ----------------------
     # A "sliding_attention" layer's query at position p sees the keys at p -
     # sliding_window + 1 .. p (its own position counted) and no others, so
@@ -355,11 +379,19 @@ class ModelConfig:
         if self.qk_norm and self.latent:
             raise ValueError("qk_norm set with attention='mla': the latent "
                              "attention has norms of its own")
+        if self.qk_norm_over not in ("head", "projection") or (
+                self.qk_norm_over != "head" and not self.qk_norm):
+            raise ValueError(f"qk_norm_over={self.qk_norm_over!r}: 'head' or, with qk_norm, "
+                             "'projection'")
+        if self.post_norm and (self.sandwich_norm or self.latent or self.ut_steps > 1):
+            raise NotImplementedError(
+                "post_norm with sandwich_norm, attention='mla' or ut_steps > 1 is not implemented")
         if self.layer_types is None:
             stray = [f for f, d in (("conv_L_cache", 0), ("conv_bias", False))
                      if getattr(self, f) != d]
             if stray:
                 raise ValueError(f"{stray} set without layer_types: only a conv layer reads them")
+            self._check_linear()
             return
         kinds = set(self.layer_types)
         if len(self.layer_types) != self.num_layers or not kinds <= set(LAYER_KINDS):
@@ -367,6 +399,7 @@ class ModelConfig:
                 f"layer_types must name each of the {self.num_layers} layers "
                 f"one of {LAYER_KINDS}; got {self.layer_types}"
             )
+        self._check_linear()
         if "conv" not in kinds:
             return
         if self.conv_L_cache < 2:
@@ -382,6 +415,37 @@ class ModelConfig:
             raise NotImplementedError(
                 "conv layers beside the softmax-routed (mixtral) MLP are not implemented"
             )
+
+    _LINEAR_FIELDS = ("linear_num_key_heads", "linear_num_value_heads", "linear_key_head_dim",
+                      "linear_value_head_dim", "linear_conv_kernel_dim")
+
+    def _check_linear(self) -> None:
+        """The ``linear_*`` fields and what was compared beside a
+        ``linear_attention`` layer: a field that does not apply, or a
+        combination no program was compared for, raises by name."""
+        if not self.linear:
+            stray = [f for f in (*self._LINEAR_FIELDS, "linear_allow_neg_eigval")
+                     if getattr(self, f)]
+            if stray:
+                raise ValueError(f"{stray} set without a 'linear_attention' layer: only "
+                                 "such a layer reads them")
+            return
+        missing = [f for f in self._LINEAR_FIELDS if getattr(self, f) <= 0]
+        if missing or self.linear_conv_kernel_dim < 2:
+            raise ValueError(f"a 'linear_attention' layer needs {missing or self._LINEAR_FIELDS} "
+                             "> 0 and at least 2 taps")
+        if self.linear_num_key_heads != self.linear_num_value_heads:
+            raise NotImplementedError(
+                f"linear_num_key_heads={self.linear_num_key_heads} beside "
+                f"linear_num_value_heads={self.linear_num_value_heads}: a key head shared by "
+                "several value heads is not implemented")
+        if (self.hybrid or self.windowed or self.latent or self.ut_steps > 1 or self.is_moe
+                or self.sandwich_norm or self.attn_qkv_bias or self.kv_head_pairs
+                or self.block_length):
+            raise NotImplementedError(
+                "linear_attention layers with conv or sliding_attention layers, "
+                "attention='mla', ut_steps > 1, experts, sandwich_norm, attn_qkv_bias, "
+                "paired 64-wide heads or block_length > 0 are not implemented")
 
     def _check_windowed(self) -> None:
         """``sliding_window``, ``heads_per_layer``, ``rope_by_kind``,
@@ -474,9 +538,9 @@ class ModelConfig:
     @property
     def layer_groups(self) -> bool:
         """The layers' operators are kept apart by kind in the parameter
-        tree (``attn``, ``attn_window``, ``conv``), one entry a layer of the
+        tree (``attn``, ``attn_window``, ``conv``, ``linear``), one entry a layer of the
         kind: their shapes differ."""
-        return self.hybrid or self.windowed
+        return self.hybrid or self.windowed or self.linear
 
     def heads_of(self, l: int) -> int:
         """Query heads of layer ``l``."""
@@ -524,11 +588,25 @@ class ModelConfig:
         """Some layers keep a rolling convolution state and no K/V."""
         return self.layer_types is not None and "conv" in self.layer_types
 
+    @property
+    def linear(self) -> bool:
+        """Some layers keep a gated-delta-rule state a SEQUENCE (a slot of
+        the slab) and no K/V."""
+        return self.layer_types is not None and "linear_attention" in self.layer_types
+
+    @property
+    def linear_channels(self) -> int:
+        """Channels of a linear layer's depthwise convolution: ``[q | k |
+        v]`` before it."""
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
+
     def layer_kind(self, l: int) -> str:
         """What layer ``l`` caches: "attention" (pages of K/V, or latent
-        rows, for as long as the sequence lives), "conv" (state pages) or
+        rows, for as long as the sequence lives), "conv" (state pages),
         "window" (pages of K/V in the window pool, held while a later query
-        may still see them)."""
+        may still see them) or "linear" (a slot of the slab a sequence:
+        :meth:`slab_shapes`)."""
         return "attention" if self.layer_types is None else _CACHE_KIND[self.layer_types[l]]
 
     def layers_of(self, kind: str) -> tuple[int, ...]:
@@ -543,6 +621,28 @@ class ModelConfig:
         geometry alone, unless :attr:`kv_pairing` was cleared."""
         return (self.kv_pairing and not self.latent and self.head_dim == 64
                 and self.num_kv_heads % 2 == 0)
+
+    @property
+    def cache_kv_heads(self) -> int:
+        """KV heads a page of the plain layout HOLDS: ``num_kv_heads``, but
+        rounded up to a count whose ``2 n`` combined heads the attention
+        kernel's page tiles (1, 2, 4 or a multiple of 8 sublane rows at the
+        dtype's packing: 30 heads of bfloat16 are kept as 32, the two spare
+        ones zero) for a model of 128-wide heads whose layers lie in groups
+        (one chip's programs, every mesh refused: no shard of heads to keep
+        whole). The library kernel refuses any other count at trace time.
+        What a token's K/V IS stays ``kv_unit_values_of``."""
+        n = self.num_kv_heads
+        if (self.latent or self.wide_key or self.kv_head_pairs or not self.layer_groups
+                or self.head_dim % 128):
+            return n
+        packing = 4 // jnp.dtype(self.jax_dtype).itemsize
+
+        def tiled(m: int) -> bool:
+            rows, odd = divmod(2 * m, packing)
+            return not odd and (rows in (1, 2, 4, 8) or rows % 8 == 0)
+
+        return next(m for m in range(n, n + 9) if tiled(m))
 
     @property
     def shared_sparse(self) -> bool:
@@ -576,7 +676,7 @@ class ModelConfig:
         """Trailing shape of one page of ``block_size`` tokens in one
         layer's plane, by the layer's kind (:meth:`layer_kind`).
         "attention": ``(block_size, 2 * num_kv_heads, head_dim)`` (K even,
-        V odd); with :attr:`kv_head_pairs` ``(block_size, num_kv_heads, 2
+        V odd; :attr:`cache_kv_heads` where the kernel tiles no such count); with :attr:`kv_head_pairs` ``(block_size, num_kv_heads, 2
         * head_dim)``, the same bytes with two heads a row; latent, the
         ``(rows, lanes)`` that hold ``block_size x (kv_lora_rank +
         qk_rope_head_dim)`` values (ops/latent_attention.py, "The page");
@@ -610,7 +710,26 @@ class ModelConfig:
                                   self.value_dim)
         if self.kv_head_pairs:
             return (block_size, self.num_kv_heads, 2 * self.head_dim)
-        return (block_size, 2 * self.num_kv_heads, self.head_dim)
+        return (block_size, 2 * self.cache_kv_heads, self.head_dim)
+
+    def slab_shapes(self, slots: int) -> dict[str, tuple[int, ...]]:
+        """One linear layer's slab of ``slots`` lane slots (the last the
+        garbage slot): ``state [slots, H / p, dk, p dv]``, FLOAT32 whatever
+        the model's dtype, ``p`` heads side by side in a tile so that its rows
+        are whole 128-lane rows (ops/linear_attention.py, "The slab": 2 at the
+        published ``dv`` 192), and ``conv [slots, K - 1, channels / 128,
+        128]`` at the model's dtype, the convolution's newest input rows
+        oldest first in whole 128-lane rows (as the "conv" page keeps its
+        own)."""
+        from dynamo_tpu.ops.linear_attention import heads_per_tile
+
+        ch, rows = self.linear_channels, self.linear_conv_kernel_dim - 1
+        H, dv = self.linear_num_value_heads, self.linear_value_head_dim
+        p = heads_per_tile(H, dv)
+        return {
+            "state": (slots, H // p, self.linear_key_head_dim, p * dv),
+            "conv": (slots, rows, ch // 128, 128) if ch % 128 == 0 else (slots, rows, 1, ch),
+        }
 
     def cache_layers(self, kind: str) -> int:
         """Page arrays of one kind (``"attention"`` planes count a looped
@@ -620,8 +739,9 @@ class ModelConfig:
     @property
     def cache_layer_counts(self) -> dict[str, int]:
         """``{"attention": n, "conv": n}``, as /health and /metrics give
-        it; with ``"window"`` for a model that has such layers."""
-        kinds = ("attention", "conv") + (("window",) if self.windowed else ())
+        it; with ``"window"`` / ``"linear"`` for a model that has such layers."""
+        kinds = ("attention", "conv") + (("window",) if self.windowed else ()) + (
+            ("linear",) if self.linear else ())
         return {kind: self.cache_layers(kind) for kind in kinds}
 
     def window_bytes_per_sequence(self, block_size: int) -> int:
@@ -641,6 +761,18 @@ class ModelConfig:
             return 0
         return (self.cache_layers("conv") * (self.conv_L_cache - 1)
                 * self.hidden_size * jnp.dtype(self.jax_dtype).itemsize)
+
+    def state_bytes_per_sequence(self) -> int:
+        """Bytes of recurrent state one sequence holds over all linear
+        layers, whatever its context: the float32 state and the
+        convolution's rows (0 for a model without such layers)."""
+        if not self.linear:
+            return 0
+        H, dk, dv = (self.linear_num_value_heads, self.linear_key_head_dim,
+                     self.linear_value_head_dim)
+        return self.cache_layers("linear") * (
+            H * dk * dv * 4 + (self.linear_conv_kernel_dim - 1) * self.linear_channels
+            * jnp.dtype(self.jax_dtype).itemsize)
 
     def kv_unit_values_of(self, kind: str) -> int:
         """Values one token caches in one plane of the layers that cache
@@ -700,8 +832,11 @@ class ModelConfig:
             return (h * rq + rq + rq * H * (dn + dr)       # wq_a, q_norm, wq_b
                     + h * (rkv + dr) + rkv                 # wkv_a, kv_norm
                     + rkv * H * (dn + dv) + H * dv * h)    # wkv_b, wo
-        qk_norms = 2 * self.head_dim if self.qk_norm else 0
         q_size = self.q_size_of(l)
+        qk_norms = 0
+        if self.qk_norm:
+            qk_norms = (q_size + self.kv_size if self.qk_norm_over == "projection"
+                        else 2 * self.head_dim)
         gate = h * self.heads_of(l) if self.attn_gate else 0
         if self.wide_key:   # [q | k | v] of unequal widths a kind, wo from the values' width
             kind, n = self.layer_kind(l), self.heads_of(l)
@@ -714,6 +849,16 @@ class ModelConfig:
         taps ``[conv_L_cache, h]`` and ``out_proj [h, h]``."""
         h = self.hidden_size
         return 3 * h * h + self.conv_L_cache * h + h * h
+
+    def _linear_params(self) -> int:
+        """One linear layer's mixer: q and k ``[h, H dk]``, v, the output
+        gate and the output projection ``[h, H dv]``, the two ``[h, H]`` maps
+        of beta and the decay, the taps, ``A_log``, ``dt_bias`` and the
+        output norm ``[dv]``."""
+        h, H = self.hidden_size, self.linear_num_value_heads
+        dk, dv = self.linear_key_head_dim, self.linear_value_head_dim
+        return (2 * h * H * dk + 3 * h * H * dv + 2 * h * H
+                + self.linear_conv_kernel_dim * self.linear_channels + 2 * H + dv)
 
     def _mlp_params(self) -> int:
         """All layers' MLP weights as HELD here: a dense SwiGLU, the
@@ -739,9 +884,9 @@ class ModelConfig:
         norms = (4 if self.sandwich_norm else 2) * h
         n_conv = len(self.layers_of("conv"))
         attn = sum(self._attn_params(l) for l in range(self.num_layers)
-                   if self.layer_kind(l) != "conv")
+                   if self.layer_kind(l) not in ("conv", "linear"))
         total = (
-            v * h + attn
+            v * h + attn + len(self.layers_of("linear")) * self._linear_params()
             + n_conv * self._conv_params() + self.num_layers * norms
             + self._mlp_params() + h + (0 if self.tie_embeddings else h * v)
         )
@@ -1012,6 +1157,18 @@ class EngineConfig:
     @property
     def garbage_block(self) -> int:
         return self.num_kv_blocks
+
+    @property
+    def state_slots(self) -> int:
+        """Lane slots of a linear layer's slab (ModelConfig.slab_shapes): one
+        a sequence that can run at once, and the garbage slot."""
+        return self.max_num_seqs + 1
+
+    @property
+    def garbage_slot(self) -> int:
+        """The slab's last slot: what padding rows, dead megastep iterations
+        and dead lanes read (as zeros) and write."""
+        return self.max_num_seqs
 
 
 # -- presets ---------------------------------------------------------------
@@ -1545,6 +1702,74 @@ def tiny_engine(**overrides) -> EngineConfig:
     return EngineConfig(**defaults)
 
 
+_OLMO_HYBRID_PERIOD = ("linear_attention",) * 3 + ("full_attention",)
+
+
+def olmo_hybrid_7b_pp2_16l() -> ModelConfig:
+    """Olmo-Hybrid-7B (allenai, model_type "olmo_hybrid") as stage 0 of a
+    two-stage pipeline holds it: layers 0-15 of the 32 whole, four periods
+    of three gated-delta-rule layers (30 heads, keys 96 and values 192 wide,
+    a four-tap depthwise convolution on q, k and v, beta in (0, 2)) and one
+    multi-head attention layer (30 heads of 128 on 30 KV heads, RMSNorm over
+    the whole q and k projections, NO rotary embedding), a dense SwiGLU of
+    11,008 in every layer, each sub-layer normed on its output, the whole
+    vocabulary untied. 8.20 GB in bf16."""
+    return ModelConfig(
+        name="olmo-hybrid-7b-pp2-16l",
+        vocab_size=100352,
+        hidden_size=3840,
+        intermediate_size=11008,
+        num_layers=16,
+        num_heads=30,
+        num_kv_heads=30,
+        head_dim=128,
+        rope_theta=None,
+        rms_norm_eps=1e-6,
+        layer_types=4 * _OLMO_HYBRID_PERIOD,
+        qk_norm=True,
+        qk_norm_over="projection",
+        post_norm=True,
+        linear_num_key_heads=30,
+        linear_num_value_heads=30,
+        linear_key_head_dim=96,
+        linear_value_head_dim=192,
+        linear_conv_kernel_dim=4,
+        linear_allow_neg_eigval=True,
+    )
+
+
+def tiny_olmo_hybrid(vocab_size: int = 384) -> ModelConfig:
+    """Olmo-Hybrid's shape at test size: a period of three gated-delta-rule
+    layers (2 heads, keys 32 and values 64 wide, four taps) and one
+    attention layer of 3 heads of 128 (a count the kernel's page does not
+    tile: kept as 4, :attr:`ModelConfig.cache_kv_heads`) with
+    whole-projection QK-norm and no rope, then one more linear layer;
+    float32."""
+    return ModelConfig(
+        name="tiny-olmo-hybrid",
+        vocab_size=vocab_size,
+        hidden_size=384,
+        intermediate_size=320,
+        num_layers=5,
+        num_heads=3,
+        num_kv_heads=3,
+        head_dim=128,
+        rope_theta=None,
+        rms_norm_eps=1e-6,
+        dtype="float32",
+        layer_types=_OLMO_HYBRID_PERIOD + ("linear_attention",),
+        qk_norm=True,
+        qk_norm_over="projection",
+        post_norm=True,
+        linear_num_key_heads=2,
+        linear_num_value_heads=2,
+        linear_key_head_dim=32,
+        linear_value_head_dim=64,
+        linear_conv_kernel_dim=4,
+        linear_allow_neg_eigval=True,
+    )
+
+
 PRESETS = {
     "llama3-8b": llama3_8b,
     "llama3-70b": llama3_70b,
@@ -1557,6 +1782,7 @@ PRESETS = {
     "laguna-s-2.1-ep8-9l": laguna_s21_ep8_9l,
     "sdar-30b-a3b-6l": sdar_30b_a3b_6l,
     "mimo-v2.5-ep16-7l": mimo_v25_ep16_7l,
+    "olmo-hybrid-7b-pp2-16l": olmo_hybrid_7b_pp2_16l,
     "tiny": tiny_model,
     "tiny-moe": tiny_moe,
     "tiny-loop": tiny_loop,
@@ -1565,4 +1791,5 @@ PRESETS = {
     "tiny-laguna": tiny_laguna,
     "tiny-sdar": tiny_sdar,
     "tiny-mimo": tiny_mimo,
+    "tiny-olmo-hybrid": tiny_olmo_hybrid,
 }

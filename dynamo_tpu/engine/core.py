@@ -69,6 +69,7 @@ from dynamo_tpu.engine.programs import (  # noqa: F401
     pack_lanes,
 )
 from dynamo_tpu.ops import grouped_matmul
+from dynamo_tpu.ops.linear_attention import traced_impl as linear_traced_impl
 from dynamo_tpu.ops.ragged_attention import traced_impl
 from dynamo_tpu.engine.sampler import hidden_at_most
 from dynamo_tpu.llm.kv_router.protocols import ForwardPassMetrics, KvStats, WorkerStats
@@ -120,6 +121,10 @@ class Sequence:
     # query's window and were given back (EngineCore._hold_window).
     win_first: int = 0
     win_ids: list[int] = field(default_factory=list)
+    # The lane SLOT of a model with linear layers (their slab's index:
+    # model.linear_layer), held from admission to the end, preemption or
+    # cancel; -1: none (every other model, and a sequence not running).
+    slot: int = -1
     # -- progress --
     prefilled: int = 0      # prompt tokens with K/V written
     processed: int = 0      # all tokens with K/V written
@@ -458,6 +463,10 @@ class EngineCore(KvTransfer):
             engine_cfg.num_window_blocks, bs, enable_prefix_caching=False,
         ) if model_cfg.windowed else None
         self._init_tiers(on_tier_stored, on_tier_removed)
+        # The free lane slots of a model with linear layers (None: no slab):
+        # one a sequence that runs; the slab's last slot is the garbage slot.
+        self._free_slots: list[int] | None = (
+            list(range(engine_cfg.max_num_seqs - 1, -1, -1)) if model_cfg.linear else None)
 
         self._inbox: deque[Sequence] = deque()   # thread-safe enqueue
         # Admission queue: per-tenant deficit-round-robin over prompt
@@ -670,6 +679,10 @@ class EngineCore(KvTransfer):
             # Window-pool blocks given back while their sequence went on
             # (_release_window_behind); 0 for a model without such a pool.
             "window_blocks_released": 0,
+            # Tokens a sequence of a model with linear layers ran AGAIN after a
+            # preemption: no block holds its state, so it replays from
+            # position 0 (_preempt); 0 while nothing is preempted.
+            "state_replayed_tokens": 0,
             # A block-diffusion model (_plan_blocks): denoising passes counted
             # once a live lane a pass; blocks whose clean rows ran (beside a
             # later block's first pass: no forward of their own); the live
@@ -978,6 +991,8 @@ class EngineCore(KvTransfer):
                 + ("ragged" if kind == "prefill" else "decode")
             ),
             attention=self.cfg.attention,
+            **({"linear": linear_traced_impl("scan" if kind == "prefill" else "step")}
+               if self.cfg.linear else {}),
             **self._window_traced(kind),
             **self._experts_traced(kind, padded, width),
             **attrs,
@@ -1152,6 +1167,8 @@ class EngineCore(KvTransfer):
         bs = self.engine.block_size
         watermark = 0.01 * self.allocator.capacity
         while self.waiting and len(self.running) < self.engine.max_num_seqs:
+            if self._free_slots is not None and not self._free_slots:
+                return   # every lane slot of the slab is held
             # Deficit-round-robin head: FIFO head with fairness off or a
             # single tenant; pop() charges the admitted prompt's token
             # cost to its tenant once admission actually succeeds.
@@ -1197,6 +1214,8 @@ class EngineCore(KvTransfer):
             seq.num_cached_tokens = ncached * bs
             seq.prefilled = seq.processed = ncached * bs
             seq.hashed = TokenBlockSequence(seq.prompt[: seq.prefilled], bs)
+            if self._free_slots is not None:
+                seq.slot = self._free_slots.pop()
             self.running.append(seq)
 
     # -- device-step assembly ---------------------------------------------
@@ -1222,8 +1241,14 @@ class EngineCore(KvTransfer):
         """``rows`` block tables that point nowhere: every column the
         garbage page. A window model's row is three parts sent as one
         (model.split_tables): the full pool's table, the index of the
-        window table's first block, the window pool's table."""
+        window table's first block, the window pool's table. A model with
+        linear layers: the table and one column more, the sequence's lane
+        slot (the garbage slot here)."""
         P = self.engine.max_blocks_per_seq
+        if self._free_slots is not None:   # [blocks | the lane slot] (model.split_slots)
+            t = np.full((rows, P + 1), self.engine.garbage_block, np.int32)
+            t[:, P] = self.engine.garbage_slot
+            return t
         if self.window_allocator is None:
             return np.full((rows, P), self.engine.garbage_block, np.int32)
         W = self.engine.window_table_blocks(self.cfg.sliding_window)
@@ -1237,6 +1262,8 @@ class EngineCore(KvTransfer):
         the plan that is being dispatched grew and slid them
         (:meth:`_grow_blocks`, :meth:`_hold_window`)."""
         row[: len(seq.block_ids)] = seq.block_ids
+        if self._free_slots is not None:
+            row[self.engine.max_blocks_per_seq] = seq.slot
         if self.window_allocator is not None:
             P = self.engine.max_blocks_per_seq
             row[P] = seq.win_first
@@ -2147,6 +2174,10 @@ class EngineCore(KvTransfer):
         the prefix cache at re-admission."""
         log.info("preempting %s (generated=%d)", seq.request_id, seq.generated)
         self.sched_stats["preemptions"] += 1
+        if self._free_slots is not None:
+            # no block holds a linear layer's state: re-admitted, the sequence
+            # matches nothing and replays all it had run into a fresh slot
+            self.exec_stats["state_replayed_tokens"] += seq.processed
         self._release_blocks(seq)
         if seq.prefill_done:
             # a pending block's tokens were streamed; its K/V were not final
@@ -2195,6 +2226,9 @@ class EngineCore(KvTransfer):
         for bid in seq.win_ids:   # the window pool's: held by no one else
             self.window_allocator.free_partial(bid)
         seq.win_ids, seq.win_first = [], 0
+        if seq.slot >= 0:   # the lane slot: whoever takes it next starts at position 0
+            self._free_slots.append(seq.slot)
+            seq.slot = -1
 
     def _arm_stop_inputs(
         self, seq: Sequence, i: int, watch: np.ndarray,
@@ -4022,12 +4056,16 @@ class EngineCore(KvTransfer):
                 scratch_engine = embed_engine = dataclasses.replace(
                     scratch_engine, num_kv_blocks=blocks, num_window_blocks=blocks,
                     max_model_len=blocks * bs)
-            self._embed_scratch = cache_for_blocks(self.cfg, scratch_engine, blocks)
+            if self.cfg.linear:   # the table is the scratch's blocks and the slot's column
+                scratch_engine = embed_engine = dataclasses.replace(
+                    scratch_engine, num_kv_blocks=blocks, max_model_len=blocks * bs)
+            # (a model with linear layers: one lane slot and the garbage slot)
+            self._embed_scratch = cache_for_blocks(self.cfg, scratch_engine, blocks, slots=2)
             self._embed_fn = jax.jit(
                 _program(embed_forward, cfg=self.cfg, engine=embed_engine, mesh=self.mesh),
                 donate_argnums=(1,),
             )
-        garbage = self._embed_scratch[0].shape[0] // self.cfg.ut_steps - 1
+        garbage = -(-self.engine.prefill_buckets[-1] // bs)   # the scratch's blocks
         tokens = np.zeros(bucket, np.int32)
         tokens[:T] = token_ids
         valid = np.zeros(bucket, bool)
@@ -4039,6 +4077,8 @@ class EngineCore(KvTransfer):
         if self.cfg.windowed:   # [full | first = 0 | window], the same blocks
             tables = np.concatenate(
                 [tables, np.zeros((1, 1), np.int32), tables], axis=1)
+        if self.cfg.linear:     # [blocks | lane slot 0]; position 0 reads zeros
+            tables = np.concatenate([tables, np.zeros((1, 1), np.int32)], axis=1)
         pooled, self._embed_scratch = self._embed_fn(
             self.params,
             self._embed_scratch,
@@ -4095,6 +4135,10 @@ class EngineCore(KvTransfer):
         st.update(self.cache_by_kind())
         st["state_bytes_per_block"] = self.cfg.state_bytes_per_block()
         st["conv_state_reads"] = dict(self.conv_state_reads)
+        st["state_bytes_per_sequence"] = self.cfg.state_bytes_per_sequence()
+        if self._free_slots is not None:   # the slab's lane slots, the garbage slot apart
+            st["state_slots"] = {"held": self.engine.max_num_seqs - len(self._free_slots),
+                                 "free": len(self._free_slots)}
         st["prefix_caching"] = bool(self.engine.enable_prefix_caching)
         if self.window_allocator is not None:
             # The window pool: its size, what is held now and what was given
@@ -4127,7 +4171,8 @@ class EngineCore(KvTransfer):
         of ONE layer (``ModelConfig.kv_page_tail``) and the bytes a block of
         that kind's pool holds over all its layers."""
         bs = self.engine.block_size
-        kinds = [k for k, n in self.cfg.cache_layer_counts.items() if n]
+        # (a linear layer's slab is indexed by lane slot: no block holds any of it)
+        kinds = [k for k, n in self.cfg.cache_layer_counts.items() if n and k != "linear"]
 
         def block_bytes(kind: str) -> int:
             if kind == "conv":

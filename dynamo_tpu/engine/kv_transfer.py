@@ -45,7 +45,7 @@ import numpy as np
 
 from dynamo_tpu.engine.block_allocator import OutOfBlocksError
 from dynamo_tpu.engine.config import UnsupportedModelOption
-from dynamo_tpu.engine.options import _TWO_POOLS, _TWO_SHAPES
+from dynamo_tpu.engine.options import _LANE_STATE, _TWO_POOLS, _TWO_SHAPES
 from dynamo_tpu.engine.programs import _program
 from dynamo_tpu.parallel.multihost import fetch_replicated
 from dynamo_tpu.runtime import wire
@@ -229,6 +229,8 @@ class KvTransfer:
             raise UnsupportedModelOption(option, self.cfg.name, _TWO_SHAPES)
         if self.cfg.windowed:
             raise UnsupportedModelOption(option, self.cfg.name, _TWO_POOLS)
+        if self.cfg.linear:
+            raise UnsupportedModelOption(option, self.cfg.name, _LANE_STATE)
 
     @property
     def kv_page_shape(self) -> tuple[int, ...]:

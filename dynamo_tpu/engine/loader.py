@@ -104,6 +104,42 @@ def _hybrid_conv_config(hf: dict) -> ModelConfig:
     )
 
 
+# Published model types whose layers are gated-delta-rule linear attention
+# among multi-head attention without rope (Olmo-Hybrid).
+HYBRID_LINEAR_TYPES = ("olmo_hybrid",)
+
+
+def _hybrid_linear_config(hf: dict) -> ModelConfig:
+    rope = hf.get("rope_parameters") or {}
+    if rope.get("rope_theta") is not None or hf.get("attention_bias"):
+        raise NotImplementedError(
+            f"{hf['model_type']}: a rotary embedding (rope_theta="
+            f"{rope.get('rope_theta')!r}) or attention_bias is not modelled for it")
+    return ModelConfig(
+        name=hf["model_type"],
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["hidden_size"] // hf["num_attention_heads"],
+        rope_theta=None,
+        rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+        tie_embeddings=hf.get("tie_word_embeddings", False),
+        layer_types=tuple(hf["layer_types"]),
+        qk_norm=True,
+        qk_norm_over="projection",
+        post_norm=True,
+        linear_num_key_heads=hf["linear_num_key_heads"],
+        linear_num_value_heads=hf["linear_num_value_heads"],
+        linear_key_head_dim=hf["linear_key_head_dim"],
+        linear_value_head_dim=hf["linear_value_head_dim"],
+        linear_conv_kernel_dim=hf["linear_conv_kernel_dim"],
+        linear_allow_neg_eigval=hf.get("linear_allow_neg_eigval", False),
+    )
+
+
 # Published model types whose attention layers are of two kinds by
 # ``layer_types``, full and sliding-window, with query heads per layer, rope
 # parameters per kind and a per-head output gate (Laguna).
@@ -266,6 +302,8 @@ def config_from_hf(path: str | Path, experts_held=None) -> ModelConfig:
         return _block_sparse_config(hf)
     if hf.get("model_type") in HYBRID_CONV_TYPES and experts_held is None:
         return _hybrid_conv_config(hf)
+    if hf.get("model_type") in HYBRID_LINEAR_TYPES and experts_held is None:
+        return _hybrid_linear_config(hf)
     if experts_held is not None:
         raise ValueError(
             f"experts_held={experts_held} for model_type {hf.get('model_type')!r}: "
@@ -499,6 +537,71 @@ def _load_hybrid_conv(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]
     return params
 
 
+def _load_hybrid_linear(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]:
+    """The tree of a model with linear-attention and attention layers
+    (``model.init_params``: ``layers`` the two OUTPUT norms and the SwiGLU of
+    every layer, ``linear`` / ``attn`` one entry a layer of that kind) from
+    the checkpoint's names, ASSUMED from the family's code (no checkpoint is
+    here to try): ``post_attention_layernorm``, ``post_feedforward_layernorm``,
+    ``mlp.{gate_proj, up_proj, down_proj}``; a linear layer's
+    ``linear_attn.{q_proj, k_proj, v_proj, g_proj, b_proj, a_proj, o_proj}``,
+    ``linear_attn.{q_conv1d, k_conv1d, v_conv1d}.weight`` (``[channels, 1,
+    K]`` each, which become the taps ``[K, q | k | v]``),
+    ``linear_attn.{A_log, dt_bias}`` and ``linear_attn.o_norm``; an attention
+    layer's ``self_attn.{q_proj, k_proj, v_proj, o_proj, q_norm, k_norm}``
+    (no rope, so no permutation); the final norm is ``model.norm``."""
+    np_dt = np.dtype(dt)
+    L = cfg.num_layers
+
+    def t(key: str) -> np.ndarray:
+        return np.asarray(sd[key], np.float32)
+
+    def proj(l: int, name: str) -> np.ndarray:
+        return t(f"model.layers.{l}.{name}.weight").T  # [in, out]
+
+    def stack(name: str, layers, fix=lambda w: w) -> np.ndarray:
+        return np.asarray(np.stack([fix(proj(l, name)) for l in layers]), np_dt)
+
+    def leaves(name: str, layers, dtype=np_dt) -> np.ndarray:
+        return np.asarray(np.stack([t(f"model.layers.{l}.{name}") for l in layers]), dtype)
+
+    lin, attn = cfg.layers_of("linear"), cfg.layers_of("attention")
+
+    def cat(names, layers) -> np.ndarray:
+        return np.concatenate([stack(n, layers) for n in names], axis=-1)
+
+    return {
+        "layers": {
+            "attn_norm": leaves("post_attention_layernorm.weight", range(L)),
+            "mlp_norm": leaves("post_feedforward_layernorm.weight", range(L)),
+            "wgu": np.asarray(_fuse_np(
+                [stack("mlp.gate_proj", range(L)), stack("mlp.up_proj", range(L))], tp), np_dt),
+            "w_down": stack("mlp.down_proj", range(L)),
+        },
+        "linear": {
+            "w_qkv": cat([f"linear_attn.{n}_proj" for n in "qkv"], lin),
+            "w_z": stack("linear_attn.g_proj", lin),
+            "w_ba": cat(["linear_attn.b_proj", "linear_attn.a_proj"], lin),
+            # published [channels, 1, K] a part -> [K, q | k | v]: tap j, K - 1 - j back
+            "conv_w": np.asarray(np.stack([np.concatenate(
+                [t(f"model.layers.{l}.linear_attn.{n}_conv1d.weight")[:, 0, :].T for n in "qkv"],
+                axis=-1) for l in lin]), np_dt),
+            "A_log": leaves("linear_attn.A_log", lin, np.float32),
+            "dt_bias": leaves("linear_attn.dt_bias", lin, np.float32),
+            "o_norm": leaves("linear_attn.o_norm.weight", lin),
+            "w_out": stack("linear_attn.o_proj", lin),
+        },
+        "attn": {
+            "wqkv": np.asarray(_fuse_np(
+                [stack(f"self_attn.{n}", attn) for n in ("q_proj", "k_proj", "v_proj")],
+                tp), np_dt),
+            "wo": stack("self_attn.o_proj", attn),
+            "q_layernorm": leaves("self_attn.q_norm.weight", attn),
+            "k_layernorm": leaves("self_attn.k_norm.weight", attn),
+        },
+    }
+
+
 def _load_windowed(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]:
     """The tree of a model with full and window attention layers
     (``model._init_attention_by_kind``: ``layers`` the two norms of every
@@ -711,11 +814,12 @@ def load_hf_llama(
         if quant is not None or tp != 1:
             raise NotImplementedError(
                 f"quant={quant!r} / tp={tp} for {cfg.name!r}: experts, latent "
-                "projections, conv operators and layers of more than one kind load "
+                "projections, conv and linear-attention operators and layers of more than one kind load "
                 "unquantised, in the tp=1 layout"
             )
         np_dt = np.dtype(dt)
         load = (_load_hybrid_conv if cfg.hybrid else
+                _load_hybrid_linear if cfg.linear else
                 _load_wide_key if cfg.wide_key else
                 _load_windowed if cfg.windowed else
                 _load_block_sparse if cfg.block_length else _load_latent_sparse)

@@ -61,6 +61,11 @@ v5e, round 2):
   pass with places still masked, they lie past the lane's cursor
   (``num_computed_tokens``), and nothing that reads or publishes K/V looks
   there.
+- **A slab a lane** (``linear_attention`` layers): a layer whose mixer is a
+  gated delta rule keeps a float32 state a SEQUENCE, too large to keep a
+  block; its cache entry is a slab indexed by a lane slot
+  (:func:`linear_layer`, ops/linear_attention.py), the slot one more column
+  of the block table (:func:`split_slots`).
 """
 
 from __future__ import annotations
@@ -81,6 +86,7 @@ from dynamo_tpu.ops.gqa_attention import (
     gqa_ragged_attention,
     write_gqa_rows,
 )
+from dynamo_tpu.ops import linear_attention
 from dynamo_tpu.ops.latent_attention import (
     latent_decode_attention,
     latent_ragged_attention,
@@ -279,19 +285,30 @@ def init_params(rng: jax.Array, cfg: ModelConfig, tp: int = 1) -> Params:
         # A model with conv layers keeps its attention leaves apart, one
         # entry an ATTENTION layer (``attn``), beside ``conv``.
         La = len(cfg.layers_of("attention"))
-        attn = extra.setdefault("attn", {}) if cfg.hybrid else layers
+        attn = extra.setdefault("attn", {}) if cfg.hybrid or cfg.linear else layers
         wq = dense(keys[1], (La, h, cfg.q_size), h)
         wk = dense(keys[2], (La, h, cfg.kv_size), h)
         wv = dense(keys[3], (La, h, cfg.kv_size), h)
         attn["wqkv"] = fuse_qkv(wq, wk, wv, tp)
         attn["wo"] = dense(keys[4], (La, cfg.q_size, h), cfg.q_size)
         if cfg.qk_norm:
-            for i, name in enumerate(("q_layernorm", "k_layernorm")):
+            whole = cfg.qk_norm_over == "projection"
+            for n, (name, size) in enumerate((("q_layernorm", cfg.q_size),
+                                              ("k_layernorm", cfg.kv_size))):
                 attn[name] = _varied_ones(
-                    jax.random.fold_in(rng, 80 + i), (La, cfg.head_dim), dt,
-                    _qk_norm_gain(cfg))
+                    jax.random.fold_in(rng, 80 + n),
+                    (La, size if whole else cfg.head_dim), dt, _qk_norm_gain(cfg))
     if cfg.hybrid:
         extra["conv"] = _init_conv_operators(rng, cfg, dense)
+    if cfg.linear:
+        extra["linear"] = _init_linear_operators(rng, cfg, dense)
+    if cfg.post_norm:   # the one norm a sub-layer: varied, so that its place shows
+        for n, name in enumerate(("attn_norm", "mlp_norm")):
+            layers[name] = _varied_ones(jax.random.fold_in(rng, 85 + n), (L, h), dt)
+    if cfg.linear:      # a linear mixer's output norm: :data:`_LINEAR_OUT_NORM`
+        scale = jnp.asarray([[_LINEAR_OUT_NORM if cfg.layer_kind(l) == "linear" else 1.0]
+                             for l in range(L)], jnp.float32)
+        layers["attn_norm"] = (scale * layers["attn_norm"].astype(jnp.float32)).astype(dt)
     if cfg.attn_qkv_bias:
         # Qwen2-family qkv bias, in the same shard-blocked fused column
         # order as wqkv (random fused == fused random for init; the
@@ -372,7 +389,8 @@ _QK_NORM_GAIN_BLOCKS = 1.6
 
 
 # The parameter group of each cache kind's operators (``cfg.layer_groups``).
-_GROUP_OF_KIND = {"attention": "attn", "window": "attn_window", "conv": "conv"}
+_GROUP_OF_KIND = {"attention": "attn", "window": "attn_window", "conv": "conv",
+                  "linear": "linear"}
 # A gate's logits are drawn this many times the fan-in scale: on a normed
 # input they are then ~N(0, 1.4^2) and the gates sigmoid of them, spread
 # over (0.2, 0.8) and not all near 0.5, so that a gate dropped, or taken
@@ -442,6 +460,69 @@ def _init_conv_operators(rng: jax.Array, cfg: ModelConfig, dense) -> dict:
         "in_proj": dense(key(0), (Lc, h, 3 * h), h),
         "conv_w": dense(key(1), (Lc, K, h), K),
         "out_proj": dense(key(2), (Lc, h, h), h),
+    }
+
+
+# What the two small maps of a linear layer (beta's logits and the decay's) are
+# drawn UNDER the fan-in scale by, and what its output norm is drawn around
+# (:func:`_init_linear_operators` says why, with the readings).
+_SMALL_MAP_DIVISOR = 10.0
+_LINEAR_OUT_NORM = 0.25
+
+
+def _init_linear_operators(rng: jax.Array, cfg: ModelConfig, dense) -> dict:
+    """The linear layers' leaves, one entry a LINEAR layer: ``w_qkv [h, 2 H
+    dk + H dv]`` (columns ``[q | k | v]``, the published ``q_proj``,
+    ``k_proj``, ``v_proj``), ``w_z [h, H dv]`` (the output gate's,
+    ``g_proj``), ``w_ba [h, 2 H]`` (``b_proj`` then ``a_proj``: beta's logits
+    and the decay's), the depthwise taps ``conv_w [K, channels]`` over ``[q |
+    k | v]`` (tap ``j`` multiplies the input ``K - 1 - j`` positions back:
+    the published ``conv1d.weight[:, 0, j]``), ``A_log [H]`` and ``dt_bias
+    [H]`` float32, the output norm ``o_norm [dv]`` and ``w_out [H dv, h]``.
+
+    The decay ``alpha = exp(-exp(A_log) softplus(x W_a + dt_bias))`` is drawn
+    to spread over about 0.9-0.999, head by head: ``dt = softplus(dt_bias)``
+    log-uniform in [0.001, 0.1] (the family's initialisation) and
+    ``exp(A_log)`` uniform in [0.8, 1.25] (a state that forgets in ten
+    tokens hides the recurrence from any comparison). The taps are drawn at
+    ``K^-0.5`` each, unequal, so that their order shows.
+
+    **Two scales are drawn under the fan-in scale, both settled by controls
+    on the v5e at the published widths** (PERF.md section 6, PR 50; the
+    cell's own comparison, tolerance 0.15 untouched). (1) ``W_b`` and ``W_a``
+    at a TENTH of it (:data:`_SMALL_MAP_DIVISOR`): the stream of a model
+    normed on its sub-layers' OUTPUTS grows with depth (root-mean-square up
+    to ~5 here), so at the fan-in scale beta's logit saturates and ``beta =
+    2 sigmoid(.)`` is 0 or 2 for most tokens: the identity or a REFLECTION,
+    which both keep every rounding error the state has ever taken, where
+    ``beta`` near 1 overwrites it along ``k``; and the decay's logit beside
+    ``dt_bias`` of -2 .. -7 puts ``alpha`` near 0 for half the tokens. Sound
+    readings over three seeds, probes of 96 + 17 and 320 + 33 tokens: at the
+    fan-in scale **0.126-0.157 and 0.191-0.233 (not correct)**, at a tenth
+    0.085-0.109 and 0.091-0.107. (2) A linear mixer's output norm
+    (``attn_norm`` of a linear layer) around :data:`_LINEAR_OUT_NORM` where
+    the other output norms are drawn around 1: a recurrence carries what
+    bfloat16 rounds off its inputs to every later token (the state ITSELF
+    rounded to bfloat16 after each update reads 0.12-0.25 against the
+    reference), so on random weights a linear layer gives the stream several
+    times an attention layer's noise; drawn lower it is a smaller share of
+    the stream and still far from unseen (``decay`` / ``neg_eigval`` /
+    ``fp8`` read 10 x the tolerance and more at every scale tried). A trained
+    checkpoint's decays, gates and norms are not known here."""
+    h, Ll, K = cfg.hidden_size, len(cfg.layers_of("linear")), cfg.linear_conv_kernel_dim
+    H, dk, dv = cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    key = lambda n: jax.random.fold_in(rng, 100 + n)  # noqa: E731
+    dt = jnp.exp(jax.random.uniform(
+        key(5), (Ll, H), jnp.float32, math.log(0.001), math.log(0.1)))
+    return {
+        "w_qkv": dense(key(0), (Ll, h, cfg.linear_channels), h),
+        "w_z": dense(key(1), (Ll, h, H * dv), h),
+        "w_ba": dense(key(2), (Ll, h, 2 * H), h * _SMALL_MAP_DIVISOR ** 2),
+        "conv_w": dense(key(4), (Ll, K, cfg.linear_channels), K),
+        "A_log": jnp.log(jax.random.uniform(key(6), (Ll, H), jnp.float32, 0.8, 1.25)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),      # softplus^-1(dt)
+        "o_norm": _varied_ones(key(7), (Ll, dv), cfg.jax_dtype),
+        "w_out": dense(key(8), (Ll, H * dv, h), H * dv),
     }
 
 
@@ -563,7 +644,7 @@ def _init_shared_sparse_mlp(rng: jax.Array, cfg: ModelConfig, dense, tp: int) ->
 def layer_params(params: Params, l: int, cfg: ModelConfig) -> dict:
     """Layer ``l``'s leaves: ``params["layers"]`` at ``l``; where the
     layers are of two kinds (``cfg.layer_groups``), its operator's from
-    ``attn``, ``attn_window`` or ``conv`` at its index among its kind; and, where the
+    ``attn``, ``attn_window``, ``conv`` or ``linear`` at its index among its kind; and, where the
     MLPs are kept apart (``cfg.shared_sparse``), the dense MLP of a
     leading layer or the sparse one of the others."""
     lp = jax.tree.map(lambda a: a[l], params["layers"])
@@ -646,16 +727,28 @@ def init_cache(cfg: ModelConfig, engine: EngineConfig, dtype=None) -> tuple:
 
 
 def cache_for_blocks(cfg: ModelConfig, engine: EngineConfig, blocks: int, dtype=None,
-                     window_blocks: int | None = None) -> tuple:
+                     window_blocks: int | None = None, slots: int | None = None) -> tuple:
     """:func:`init_cache` for ``blocks`` blocks and a garbage page: every
     layer's array ``[pages, *cfg.kv_page_tail(block_size, kind)]`` of ITS
     kind, indexed by the same block ids. A conv layer's pages hold its
     state (:func:`conv_layer`), an attention layer's its K/V. A WINDOW
     layer's array is the window pool's: ``window_blocks`` blocks (as many
     as ``blocks`` where not given) and a garbage page, under block ids of
-    that pool's own."""
+    that pool's own. A LINEAR layer's entry is no page array but its slab,
+    ``{"state", "conv"}`` of ``slots`` lane slots (``engine.state_slots``
+    where not given; ``ModelConfig.slab_shapes``), the state float32."""
     dtype = dtype or cfg.jax_dtype
     window_blocks = blocks if window_blocks is None else window_blocks
+    if cfg.linear:
+        if engine.kv_quantized:
+            _refuse_int8_latent(cfg)
+        slab = cfg.slab_shapes(engine.state_slots if slots is None else slots)
+        return tuple(
+            {"state": jnp.zeros(slab["state"], jnp.float32),
+             "conv": jnp.zeros(slab["conv"], dtype)}
+            if cfg.layer_kind(l) == "linear"
+            else jnp.zeros((blocks + 1, *cfg.kv_page_tail(engine.block_size)), dtype)
+            for l in range(cfg.num_layers))
     shapes = []
     for l in range(cfg.num_layers):
         kind = cfg.layer_kind(l)
@@ -701,6 +794,10 @@ def init_cache_stacked(
 
 
 def _refuse_int8_latent(cfg: ModelConfig) -> None:
+    if cfg.linear:
+        raise NotImplementedError(
+            "kv_dtype='int8' with linear_attention layers: the full layers' pages "
+            "beside a float32 slab were not compared as int8")
     if cfg.windowed:
         raise NotImplementedError(
             "kv_dtype='int8' with sliding_attention layers: the window pool's "
@@ -1397,6 +1494,7 @@ def write_kv(cache_l, write_pages: jax.Array, write_offs: jax.Array, kvn: jax.Ar
 
 def _interleave_kv(k: jax.Array, v: jax.Array, cfg: ModelConfig) -> jax.Array:
     """[T, kv_size] x2 -> [T, 2*n_kv, d] with K at even, V at odd heads;
+    (``cfg.cache_kv_heads`` of them, the spare ones zero);
     with ``cfg.kv_head_pairs`` two heads a row, ``[T, n_kv, 2 d]``: rows
     ``[k_2j | k_2j+1]`` even, ``[v_2j | v_2j+1]`` odd (a reshape: adjacent
     heads are adjacent columns)."""
@@ -1404,7 +1502,11 @@ def _interleave_kv(k: jax.Array, v: jax.Array, cfg: ModelConfig) -> jax.Array:
     n, d = cfg.num_kv_heads, cfg.head_dim
     if cfg.kv_head_pairs:
         n, d = n // 2, 2 * d
-    return jnp.stack([k.reshape(T, n, d), v.reshape(T, n, d)], axis=2).reshape(T, 2 * n, d)
+    kv = jnp.stack([k.reshape(T, n, d), v.reshape(T, n, d)], axis=2)
+    spare = cfg.cache_kv_heads - cfg.num_kv_heads   # zero heads the page's tiling asks for
+    if spare:
+        kv = jnp.pad(kv, ((0, 0), (0, spare), (0, 0), (0, 0)))
+    return kv.reshape(T, 2 * (n + spare), d)
 
 
 def dense_layer(
@@ -1429,7 +1531,8 @@ def dense_layer(
 ) -> tuple[jax.Array, jax.Array]:
     """One transformer block over a ragged token batch: attn-norm → fused
     qkv (→ per-head RMSNorm of q and k where the layer has
-    ``q_layernorm``) → rope → in-place page scatter → ragged paged
+    ``q_layernorm``; over the whole projection with ``cfg.qk_norm_over``
+    "projection") → rope (none where ``cfg.rope_theta`` is None) → in-place page scatter → ragged paged
     attention (KV heads in pairs where ``cfg.kv_head_pairs``) → wo →
     mlp. Shared by :func:`forward_hidden` (per-layer tuple cache) and the
     pipeline-parallel stage body (parallel/pipeline.py — stage-stacked
@@ -1471,13 +1574,15 @@ def dense_layer(
     what they were."""
     T = x.shape[0]
     sm_scale = cfg.head_dim ** -0.5
-    if rope_cs is None:
+    if rope_cs is None and cfg.rope_theta is not None:
         rope_cs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
     # The dtype of what the matmuls read; ``x`` itself is wider in a
     # looped stack (:func:`_run_stack`), and the same everywhere else.
     dt = lp["attn_norm"].dtype
     with jax.named_scope("qkv"):
-        y = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps).astype(dt)
+        # cfg.post_norm: the stream as it is; the norm is on the output
+        y = x.astype(dt) if cfg.post_norm else rms_norm(
+            x, lp["attn_norm"], cfg.rms_norm_eps).astype(dt)
         qkv = _dot(y, lp["wqkv"])
         if "bqkv" in lp:  # Qwen2-family qkv bias (fused column order)
             qkv = qkv + lp["bqkv"]
@@ -1490,14 +1595,20 @@ def dense_layer(
         else:
             n_kv = cfg.num_kv_heads
             q, k, v = split_qkv(qkv.astype(dt), cfg, tp)
-        q = q.reshape(T, -1, cfg.head_dim)
-        k = k.reshape(T, n_kv, cfg.head_dim)
-        if "q_layernorm" in lp:  # per head, BEFORE rope
+        whole = cfg.qk_norm_over == "projection"
+        if "q_layernorm" in lp and whole:  # over the whole projection, before the heads
             with jax.named_scope("qk_norm"):
                 q = rms_norm(q, lp["q_layernorm"], cfg.rms_norm_eps)
                 k = rms_norm(k, lp["k_layernorm"], cfg.rms_norm_eps)
-        q = rope_apply(q, *rope_cs)
-        k = rope_apply(k, *rope_cs)
+        q = q.reshape(T, -1, cfg.head_dim)
+        k = k.reshape(T, n_kv, cfg.head_dim)
+        if "q_layernorm" in lp and not whole:  # per head, BEFORE rope
+            with jax.named_scope("qk_norm"):
+                q = rms_norm(q, lp["q_layernorm"], cfg.rms_norm_eps)
+                k = rms_norm(k, lp["k_layernorm"], cfg.rms_norm_eps)
+        if rope_cs is not None:   # None: cfg.rope_theta None, no rotary embedding
+            q = rope_apply(q, *rope_cs)
+            k = rope_apply(k, *rope_cs)
     with jax.named_scope("kv_write"):
         if cfg.wide_key:
             cache_l = write_gqa_rows(cache_l, write_pages, write_offs, k, v)
@@ -1544,10 +1655,15 @@ def dense_layer(
                 sm_scale=sm_scale,
             )
         else:
+            spare = cfg.cache_kv_heads - cfg.num_kv_heads
+            if spare:   # group 1: a zero query head a zero KV head of the page
+                q = jnp.pad(q, ((0, 0), (0, spare), (0, 0)))
             attn = ragged_paged_attention(
                 q, kv_pages, kv_lens, block_tables, cu_q_lens, num_seqs,
                 sm_scale=sm_scale, kv_scales=kv_scales,
             )
+            if spare:
+                attn = attn[:, :q.shape[1] - spare]
     if "wg" in lp:
         with jax.named_scope("o_proj"), jax.named_scope("attn_gate"):
             gate = jax.nn.sigmoid(_dot(y, lp["wg"]))             # [T, heads] f32
@@ -1645,6 +1761,17 @@ def latent_layer(
     return x, cache_l
 
 
+def _operator_scope(operator: str, stage: str, part: str) -> contextlib.ExitStack:
+    """``stage/operator/part`` as nested ``jax.named_scope``s. ``stage``: the
+    section a trace's reader knows this stage of a block by
+    (chipbench/trace/phases.py lists the dense layer's); ``<operator>/<part>``
+    inside it: the operator's own names (``conv``, ``linear``)."""
+    stack = contextlib.ExitStack()
+    for name in (stage, operator, part):
+        stack.enter_context(jax.named_scope(name))
+    return stack
+
+
 def conv_state_rows(state_l, block_tables, pos, slots: int, block_size: int):
     """The cached ``u`` at positions ``pos`` ``[S, m]`` of sequences whose
     blocks are ``block_tables`` ``[S, pages]``: ``[S, m, h]``, zero before
@@ -1725,14 +1852,7 @@ def conv_layer(
     dt = lp["attn_norm"].dtype
     decode = cu_q_lens is None
 
-    def scope(stage: str, part: str):
-        # ``stage``: the section a trace's reader knows this stage of a
-        # block by (chipbench/trace/phases.py lists the dense layer's);
-        # ``conv/<part>`` inside it: the operator's own names.
-        stack = contextlib.ExitStack()
-        for name in (stage, "conv", part):
-            stack.enter_context(jax.named_scope(name))
-        return stack
+    scope = functools.partial(_operator_scope, "conv")
 
     with scope("qkv", "in_proj"):
         y = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps).astype(dt)
@@ -1776,6 +1896,144 @@ def conv_layer(
     return x, state_l
 
 
+def split_slots(block_tables: jax.Array, cfg: ModelConfig, engine: EngineConfig):
+    """A model with linear layers sends each sequence's lane SLOT as one more
+    column of its block table, ``[S, P + 1]`` (``P =
+    engine.max_blocks_per_seq``; ``EngineCore._table_row``): ``(tables [S,
+    P], slots [S])``. Every other model: ``(tables, None)``."""
+    if not cfg.linear:
+        return block_tables, None
+    P = engine.max_blocks_per_seq
+    return block_tables[:, :P], block_tables[:, P]
+
+
+def linear_layer(
+    x: jax.Array,            # [T, h]
+    lp: dict,                # ONE linear layer's params (:func:`layer_params`)
+    slab: dict,              # ONE layer's slab {"state", "conv"} (cache_for_blocks)
+    positions: jax.Array,
+    write_pages: jax.Array,
+    slots: jax.Array,        # [S] i32: each sequence's lane slot
+    cu_q_lens: jax.Array | None,  # None: the decode shape (one row a sequence)
+    cfg: ModelConfig,
+    engine: EngineConfig,
+) -> tuple[jax.Array, dict]:
+    """One block whose mixer is a gated delta rule, over a ragged token
+    batch (``H`` heads, keys ``dk`` and values ``dv`` wide, ``K`` taps):
+    ``[q~ | k~ | v~] = x W_qkv``; a depthwise causal convolution of ``K`` taps
+    over those channels (zero before position 0), then SiLU; ``q = q / |q|
+    dk^-1/2``, ``k = k / |k|`` per head; ``beta = 2 sigmoid(x W_b)`` (the
+    factor 2 with ``cfg.linear_allow_neg_eigval``), ``alpha = exp(-exp(A_log)
+    softplus(x W_a + dt_bias))`` per head in float32; the state's recurrence
+    and its read-out ``o`` (ops/linear_attention.py); ``y = norm_dv(o) *
+    silu(x W_z)`` per head; ``x + norm(y W_out)``, then the layer's MLP. The
+    mixer reads the stream AS IT IS and its output is normed
+    (``cfg.post_norm``).
+
+    **The state** lives in the layer's SLAB, indexed by a lane slot a
+    sequence holds from admission to its end (``slots``; the last slot is
+    the garbage slot): ``state [slots, H / p, dk, p dv]`` float32 (``p``
+    heads side by side in a tile: ops/linear_attention.py, "The slab") and
+    ``conv [slots, K - 1, channels / 128, 128]``, the convolution's newest input
+    rows, oldest first, rounded to the model dtype before they are used OR
+    kept, so that a row's arithmetic does not depend on where a chunk was
+    cut. A sequence's first row (position 0) reads zeros whatever its slot
+    held, by a select; so does every row of the garbage slot (padding, a
+    dead lane). No block holds a state: a prefix hit cannot find one
+    (``prefix_caching`` is refused for such a model) and a preempted
+    sequence replays from position 0 into a fresh slot.
+
+    **The invariant is :func:`conv_layer`'s**: the state is updated IN
+    PLACE, so a sequence that goes on from a cursor must never have written
+    a position past it. Every such write the engine makes is by a sequence
+    that then ENDS (a megastep's iterations after the host finds a stop the
+    device could not see; the one-step-ahead dispatch of a lane that ended
+    in the step before: its slot is given to no one before that dispatch
+    was enqueued, and whoever takes it next starts at position 0 and reads
+    zeros), or goes to the garbage slot (padding rows, a megastep's
+    iterations of a lane the device saw stop: ``active`` false), or is
+    discarded WITH the slot (a lane preempted while its step was in flight
+    restarts from position 0). Speculative decoding rejects rows it has
+    written and goes on: refused (options._refuse_uncarried_options).
+
+    Scopes, each inside the dense layer's stage it stands for: ``qkv`` >
+    ``linear/in_proj``, ``kv_write`` > ``linear/conv``, ``attn`` >
+    ``linear/state_step`` (decode) or ``linear/state_scan`` (ragged),
+    ``attn`` > ``linear/gate_norm``, ``o_proj`` > ``linear/out_proj``, then
+    the MLP's."""
+    T = x.shape[0]
+    K = cfg.linear_conv_kernel_dim
+    H, dk, dv = cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    dt = lp["attn_norm"].dtype
+    decode = cu_q_lens is None
+    state, rows = slab["state"], slab["conv"]
+    garbage = state.shape[0] - 1
+
+    scope = functools.partial(_operator_scope, "linear")
+
+    with scope("qkv", "in_proj"):
+        y = x.astype(dt)
+        pre = _dot(y, lp["w_qkv"]).astype(dt)                     # [T, channels]
+        z = _dot(y, lp["w_z"])                                    # [T, H dv] f32
+        ba = _dot(y, lp["w_ba"])                                  # [T, 2 H] f32
+        beta = jax.nn.sigmoid(ba[:, :H]) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
+        g = -jnp.exp(lp["A_log"]) * jax.nn.softplus(ba[:, H:] + lp["dt_bias"])   # log alpha
+    with scope("kv_write", "conv"):
+        if decode:   # a dead lane (active false: its K/V row goes to the garbage page)
+            slots = jnp.where(write_pages == engine.garbage_block, garbage, slots)
+            start_pos, q_len = positions, None
+        else:
+            starts, ends = cu_q_lens[:-1], cu_q_lens[1:]
+            q_len = ends - starts
+            start_pos = positions[jnp.minimum(starts, T - 1)]
+        fresh = (start_pos == 0) | (slots == garbage)
+        old = linear_attention.zero_where_fresh(rows[slots], fresh)
+        old = old.reshape(old.shape[0], K - 1, -1)                # [S, K-1, channels]
+        w = lp["conv_w"].astype(jnp.float32)                      # [K, channels]
+        c = w[K - 1] * pre.astype(jnp.float32)
+        for j in range(1, K):                                     # the input, j rows back
+            if decode:
+                prev = old[:, K - 1 - j]
+            else:
+                prev = jnp.roll(pre, j, axis=0)
+                for i in range(j):   # row i of a sequence's chunk: from the slab
+                    at = jnp.where(i < q_len, starts + i, T)
+                    prev = prev.at[at].set(old[:, K - 1 + i - j], mode="drop")
+            c = c + w[K - 1 - j] * prev.astype(jnp.float32)
+        c = jax.nn.silu(c)
+        if decode:
+            new = jnp.concatenate([old[:, 1:], pre[:, None]], axis=1)
+        else:   # the K - 1 newest rows at the chunk's end: the chunk's, else the slab's
+            m = jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+            r = q_len[:, None] - (K - 1) + m                      # chunk row of new row m
+            mine = pre[jnp.clip(starts[:, None] + r, 0, T - 1)]
+            kept = jnp.take_along_axis(
+                old, jnp.clip(m + q_len[:, None], 0, K - 2)[..., None], axis=1)
+            new = jnp.where((r >= 0)[..., None], mine, kept)
+        rows = rows.at[slots].set(new.reshape(new.shape[0], *rows.shape[1:]))
+        q = linear_attention.l2_normalize(c[:, :H * dk].reshape(T, H, dk), 1e-6) * dk ** -0.5
+        k = linear_attention.l2_normalize(c[:, H * dk:2 * H * dk].reshape(T, H, dk), 1e-6)
+        v = c[:, 2 * H * dk:].reshape(T, H, dv)
+    if decode:
+        with scope("attn", "state_step"):
+            o, state = linear_attention.gdn_step(
+                state, slots, q, k, v, jnp.exp(g), beta, fresh)
+    else:
+        with scope("attn", "state_scan"):
+            o, state = linear_attention.gdn_scan(
+                state, slots, fresh, q, k, v, g, beta, cu_q_lens)
+    with scope("attn", "gate_norm"):
+        o = rms_norm(o, lp["o_norm"].astype(jnp.float32), cfg.rms_norm_eps)
+        # (the gate stays flat as its product gives it: gated per head in [T, H, dv]
+        # the v5e's compiler re-lays W_z out, all layers of it, at every dispatch)
+        gated = (o.reshape(T, H * dv) * jax.nn.silu(z)).astype(dt)
+    with scope("o_proj", "out_proj"):
+        a = rms_norm(_dot(gated, lp["w_out"]).astype(x.dtype), lp["attn_norm"], cfg.rms_norm_eps)
+        x = x + a
+    x = _residual_mlp(x, lp, cfg, 1, None)
+    return x, {"state": state, "conv": rows}
+
+
 def _attn_out_and_mlp(x, attn, lp, cfg: ModelConfig, tp: int, mesh,
                       row_valid=None, expert_stats: list | None = None):
     """The block after attention: ``x + attn Wo``, then ``x + mlp(norm x)``.
@@ -1787,18 +2045,25 @@ def _attn_out_and_mlp(x, attn, lp, cfg: ModelConfig, tp: int, mesh,
         a = _dot(attn, lp["wo"]).astype(x.dtype)
         if cfg.sandwich_norm:
             a = rms_norm(a, lp["attn_post_norm"], cfg.rms_norm_eps)
+        if cfg.post_norm:
+            a = rms_norm(a, lp["attn_norm"], cfg.rms_norm_eps)
         x = x + a
     return _residual_mlp(x, lp, cfg, tp, mesh, row_valid, expert_stats)
 
 
 def _residual_mlp(x, lp, cfg: ModelConfig, tp: int, mesh,
                   row_valid=None, expert_stats: list | None = None):
-    """``x + mlp(norm x)``, the second half of every block (scope ``mlp``)."""
+    """``x + mlp(norm x)``, the second half of every block (scope ``mlp``);
+    with ``cfg.post_norm`` ``x + norm(mlp(x))``, the one norm on the output."""
     with jax.named_scope("mlp"):
-        y = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps).astype(lp["mlp_norm"].dtype)
+        dt = lp["mlp_norm"].dtype
+        y = x.astype(dt) if cfg.post_norm else rms_norm(
+            x, lp["mlp_norm"], cfg.rms_norm_eps).astype(dt)
         m = _mlp(y, lp, cfg, tp, mesh, row_valid, expert_stats)
         if cfg.sandwich_norm:
             m = rms_norm(m, lp["mlp_post_norm"], cfg.rms_norm_eps)
+        if cfg.post_norm:
+            m = rms_norm(m.astype(x.dtype), lp["mlp_norm"], cfg.rms_norm_eps)
         x = x + m
     return x
 
@@ -1885,8 +2150,8 @@ def forward_hidden(
             x = jnp.where(mm_mask[:, None], mm_embeds.astype(x.dtype), x)
         if cfg.latent:
             rope_cs = latent_rope_tables(positions, cfg)
-        elif cfg.windowed:
-            rope_cs = None   # a pair of tables a layer kind, below
+        elif cfg.windowed or cfg.rope_theta is None:
+            rope_cs = None   # a pair of tables a layer kind, below; or no rope at all
         else:
             rope_cs = rope_tables(positions, cfg.head_dim, cfg.rope_theta)
         # Padding rows (and a megastep's dead lanes) write the garbage
@@ -1898,6 +2163,7 @@ def forward_hidden(
             blocks = (*block_rows(positions, block_tables, cu_q_lens, num_seqs,
                                   cfg.block_length), block_shape)
     block_tables, win_first, win_tables = split_tables(block_tables, cfg, engine)
+    block_tables, slots = split_slots(block_tables, cfg, engine)
     if win_tables is not None:
         with jax.named_scope("qkv"):
             rope_cs = {kind: kind_rope_tables(positions, cfg, kind)
@@ -1930,6 +2196,9 @@ def forward_hidden(
                 tp=tp, mesh=mesh, rope_cs=rope_cs[cfg.layer_types[l]],
                 row_valid=row_valid, expert_stats=expert_stats, window=window,
             )
+        if "A_log" in lp:  # a linear layer's leaves (cfg.layer_types)
+            return linear_layer(
+                x, lp, cache_l, positions, write_pages, slots, cu_q_lens, cfg, engine)
         if "in_proj" in lp:  # a conv layer's leaves (cfg.layer_types)
             return conv_layer(
                 x, lp, cache_l, positions, write_pages, block_tables,

@@ -39,6 +39,9 @@ log = logging.getLogger("dynamo_tpu.engine")
 
 
 _TWO_SHAPES = "one block holds pages of two shapes; [planes, *page] carries one"
+_LANE_STATE = ("the linear layers' state lies in a slab indexed by lane slot; no block "
+               "holds it, so a block that leaves the device, or is found again by its "
+               "hash, carries the full layers' pages and not the state at its end")
 _TWO_POOLS = ("the window layers' pages lie in a pool of their own, whose blocks "
               "are given back as they slide out; a block that leaves the device, "
               "or is found again by its hash, carries the full layers' pages only")
@@ -46,8 +49,9 @@ _TWO_POOLS = ("the window layers' pages lie in a pool of their own, whose blocks
 
 def _resolve_window_pool(model_cfg, engine_cfg):
     """``engine_cfg`` with what a model's cache decides filled in.
-    ``enable_prefix_caching`` None becomes True, and False for a model with
-    window layers: a window block is not content-addressed, so a prefix
+    ``enable_prefix_caching`` None becomes True; False for a model with
+    linear-attention layers (no block holds their state); and False for a
+    model with window layers: a window block is not content-addressed, so a prefix
     hit would find the full layers' pages and not the window layers' newest
     ``sliding_window`` tokens (asked for by name, it is refused:
     :func:`_refuse_uncarried_options`). ``num_window_blocks`` 0 becomes
@@ -56,6 +60,11 @@ def _resolve_window_pool(model_cfg, engine_cfg):
     sequence has to fit with room to spare."""
     windowed = model_cfg.windowed
     prefix = engine_cfg.enable_prefix_caching
+    if model_cfg.linear and prefix is None:
+        log.info("model %s has linear_attention layers: prefix caching is off "
+                 "(no block holds their state)", model_cfg.name)
+        engine_cfg = dataclasses.replace(engine_cfg, enable_prefix_caching=False)
+        prefix = False
     if not windowed:
         if engine_cfg.num_window_blocks:
             raise ValueError(
@@ -127,40 +136,47 @@ def _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh) -> 
     layers (``model_cfg.hybrid``) keeps pages of two shapes, its 64-wide
     KV heads in pairs, and a rolling state that does not forgive a write
     past the cursor (model.conv_layer, "The invariant"). Every option
-    that page, or that layer, does not carry is refused here, at
-    start-up and by name (:class:`UnsupportedModelOption`), not at the
+    that page, or that layer, does not carry is refused here (for a model
+    with ``linear_attention`` layers, whose state lies in a slab a lane that no
+    block holds, also the prefix cache asked for by name, as a windowed
+    model's), at start-up and by name (:class:`UnsupportedModelOption`), not at the
     first request that meets it. Carried by all: the prefix cache,
     preemption and recompute, embeddings, both schedulers, the megastep;
     by the latent page also the host and disk tiers, the disagg payload
     and peer pulls, which a hybrid cache refuses (a block that leaves the
     device is ``[planes, *page]`` of ONE shape:
     ``EngineCore.kv_page_shape``)."""
-    hybrid, windowed = model_cfg.hybrid, model_cfg.windowed
+    hybrid, windowed, linear = model_cfg.hybrid, model_cfg.windowed, model_cfg.linear
     blocks = model_cfg.block_length > 0
     # one chip's programs: the layers no mesh rule, stage body or verify row knows
-    if not (model_cfg.latent or model_cfg.shared_sparse or hybrid or windowed or blocks):
+    if not (model_cfg.latent or model_cfg.shared_sparse or hybrid or windowed or blocks
+            or linear):
         return
-    stays = _TWO_SHAPES if hybrid else _TWO_POOLS
+    stays = _TWO_SHAPES if hybrid else _LANE_STATE if linear else _TWO_POOLS
     refused = {
         "scheduling": blocks and engine_cfg.scheduling == "chunked" and (
             _BLOCK_STEP + "a mixed step's decode rows are one token a lane, and its "
             "chunks are not held to whole blocks"),
-        "prefix_caching": windowed and engine_cfg.enable_prefix_caching is True
-        and _TWO_POOLS,
+        "prefix_caching": (windowed or linear) and engine_cfg.enable_prefix_caching is True
+        and stays,
         "kv_dtype": engine_cfg.kv_quantized and (
-            model_cfg.latent or hybrid or windowed or blocks) and (
+            model_cfg.latent or hybrid or windowed or blocks or linear) and (
             "int8 pages keep a scale per slot and KV head; "
             + ("a latent page has no heads" if model_cfg.latent else
+               "the full layers' pages beside a float32 slab were not compared as int8"
+               if linear else
                "a block in flight is quantised anew every pass, which was not compared"
                if blocks else
                "the window pool's pages were not compared as int8" if windowed else
                "conv state pages and paired heads have no such scale")),
-        "host_kv_blocks": (hybrid or windowed) and engine_cfg.host_kv_blocks > 0 and stays,
-        "disk_kv_dir": (hybrid or windowed) and bool(engine_cfg.disk_kv_dir) and stays,
+        "host_kv_blocks": (hybrid or windowed or linear) and engine_cfg.host_kv_blocks > 0
+        and stays,
+        "disk_kv_dir": (hybrid or windowed or linear) and bool(engine_cfg.disk_kv_dir) and stays,
         "tp": mesh is not None
         and "no sharding rule for the latent projections, the held experts (a "
             "share is stated with experts_held, not with a mesh), conv "
-            "operators, paired KV heads or layers of unequal head counts",
+            "operators, linear-attention operators and their slab, paired KV heads "
+            "or layers of unequal head counts",
         "pp": pp_mesh is not None
         and "the pipeline's stage body is the dense layer's",
         "ring_prefill": (sp_mesh is not None or engine_cfg.ring_prefill_threshold > 0)
@@ -169,6 +185,8 @@ def _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh) -> 
             _BLOCK_STEP + "there is no next token to draft" if blocks else
             "a rejected draft has already overwritten the convolution's rolling "
             "state past the cursor the lane goes on from" if hybrid else
+            "a rejected draft has already updated the linear layers' state in place "
+            "past the cursor the lane goes on from" if linear else
             "a window block is given back by the cursor a verify row may fall "
             "behind" if windowed else
             "verify rows were not compared with the reference for this model"),

@@ -165,6 +165,13 @@ SCHEDULER_GAUGES: dict[str, tuple[str, str]] = {
         "(their newest conv_L_cache - 1 rows, whatever the block size); 0 "
         "for a model without conv layers",
     ),
+    "state_bytes_per_sequence": (
+        "engine_state_bytes_per_sequence",
+        "Bytes of recurrent state one sequence holds over all linear-attention "
+        "layers, whatever its context (the float32 state and the convolution's "
+        "newest rows, in a slab indexed by lane slot); 0 for a model without "
+        "such layers",
+    ),
     "experts_held": (
         "engine_experts_held",
         "Routed experts of each sparse layer this worker holds (its share "
@@ -282,6 +289,12 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
         "engine_window_blocks_released",
         "Window-pool blocks that slid wholly out of every later query's "
         "window and were given back while their sequence went on",
+    ),
+    "state_replayed_tokens": (
+        "engine_state_replayed_tokens",
+        "Tokens a sequence of a model with linear-attention layers had run when "
+        "it was preempted: no block holds their state, so it runs them again "
+        "from position 0 into a fresh lane slot",
     ),
     "blocks_committed": (
         "engine_blocks_committed",
@@ -474,8 +487,30 @@ class _EngineCounters:
         for (shape, impl), n in sorted(traced_calls().items()):
             traced.add_metric(["engine", shape, impl], float(n))
         yield traced
-        from dynamo_tpu.ops import grouped_matmul
+        from dynamo_tpu.ops import grouped_matmul, linear_attention
 
+        linear = CounterMetricFamily(
+            "dynamo_engine_linear_calls_traced",
+            "Linear-attention (gated delta rule) state calls traced into step "
+            "programs, by shape (step: one row a lane, the decode step's read and "
+            "write of every live lane's state; scan: the chunked scan of a ragged "
+            "batch) and the implementation chosen (pallas: the first-party "
+            "kernel; jnp: no kernel)",
+            labels=["service", "shape", "impl"],
+        )
+        for (shape, impl), n in sorted(linear_attention.traced_calls().items()):
+            linear.add_metric(["engine", shape, impl], float(n))
+        yield linear
+        slots = GaugeMetricFamily(
+            "dynamo_engine_state_slots",
+            "Lane slots of the linear-attention layers' slab, by state: held by "
+            "a running sequence or free (the garbage slot apart); no series for "
+            "a model without such layers",
+            labels=["service", "state"],
+        )
+        for state, n in sorted(stats.get("state_slots", {}).items()):
+            slots.add_metric(["engine", state], float(n))
+        yield slots
         experts = CounterMetricFamily(
             "dynamo_engine_expert_calls_traced",
             "Sparse layers' expert calls traced into step programs, by "
@@ -497,8 +532,9 @@ class _EngineCounters:
             "dynamo_engine_cache_layers",
             "Page arrays the cache holds, by what a layer of that kind "
             "caches: attention (planes of K/V, or latent rows), conv (the "
-            "short convolution's state pages) or window (K/V of a sliding "
-            "window, in a pool of its own)",
+            "short convolution's state pages), window (K/V of a sliding "
+            "window, in a pool of its own) or linear (a slab of float32 state "
+            "indexed by lane slot)",
             labels=["service", "kind"],
         )
         for kind, n in sorted(stats.get("cache_layers", {}).items()):

@@ -323,6 +323,38 @@ def test_mosaic_compiles_the_latent_decode_kernel_for_a_v5e(one_chip, lanes):
     assert "latent_decode_attention_kernel" in compiled.as_text()
 
 
+@pytest.mark.parametrize("lanes", [48, 32])
+def test_mosaic_compiles_the_linear_state_step_kernel_for_a_v5e(one_chip, lanes):
+    """Olmo-Hybrid's decode step of ONE linear layer at the cell's two decode
+    widths (30 heads, a float32 tile of ``[96, 192]`` a head, two side by
+    side, a slab of 49 lane slots): the first-party kernel of
+    ops/linear_attention.py at the module's constants, the state aliased in
+    place. Beside it the full layers' library call at group 1 on the page as
+    KEPT: 32 KV heads, ``(32, 64, 128)`` (the kernel refuses the published 30:
+    "can not be XLA fully tiled"; ``ModelConfig.cache_kv_heads``)."""
+    from dynamo_tpu.ops import linear_attention as la
+
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    state = sds((49, 15, 96, 384))
+    assert la.step_impl("tpu", state) == "pallas"
+    compiled = jax.jit(la.gdn_step_pallas).lower(
+        state, sds((lanes,), jnp.int32), sds((lanes, 30, 96)), sds((lanes, 30, 96)),
+        sds((lanes, 30, 192)), sds((lanes, 30)), sds((lanes, 30)), sds((lanes,), jnp.bool_),
+    ).compile()
+    assert "gdn_step_kernel" in compiled.as_text()
+    # in place: the slab is not copied to make the output (106 MB a layer a step)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+    attn = jax.jit(lambda *a: ra.pallas_ragged_attention(*a, sm_scale=SM_SCALE)).lower(
+        sds((lanes, 32, HEAD_DIM), jnp.bfloat16), sds((2049, 32, 64, HEAD_DIM), jnp.bfloat16),
+        sds((lanes,), jnp.int32), sds((lanes, 128), jnp.int32), None, sds((1,), jnp.int32),
+    ).compile()
+    assert "ragged_paged_attention_kernel" in attn.as_text()
+    with pytest.raises(ValueError, match="fully tiled"):
+        jax.jit(lambda *a: ra.pallas_ragged_attention(*a, sm_scale=SM_SCALE)).lower(
+            sds((lanes, 30, HEAD_DIM), jnp.bfloat16), sds((2049, 32, 60, HEAD_DIM), jnp.bfloat16),
+            sds((lanes,), jnp.int32), sds((lanes, 128), jnp.int32), None, sds((1,), jnp.int32))
+
+
 @pytest.mark.parametrize("kind,lanes", [("full", 32), ("full", 16), ("window", 32)])
 def test_mosaic_compiles_the_wide_key_decode_kernel_for_a_v5e(one_chip, kind, lanes):
     """MiMo's decode call at the cell's two decode widths (64 query heads on
