@@ -1655,15 +1655,15 @@ def dense_layer(
                 sm_scale=sm_scale,
             )
         else:
-            spare = cfg.cache_kv_heads - cfg.num_kv_heads
-            if spare:   # group 1: a zero query head a zero KV head of the page
-                q = jnp.pad(q, ((0, 0), (0, spare), (0, 0)))
+            # The page may keep spare KV heads (cfg.cache_kv_heads: zeros the
+            # library kernel's tiling asks for): the entry is told the
+            # model's own count. Its first-party decode kernel reads the
+            # published heads alone; the library kernel gets zero queries
+            # for the spare ones there (ops/ragged_attention.py).
             attn = ragged_paged_attention(
                 q, kv_pages, kv_lens, block_tables, cu_q_lens, num_seqs,
-                sm_scale=sm_scale, kv_scales=kv_scales,
+                sm_scale=sm_scale, kv_scales=kv_scales, num_kv_heads=cfg.num_kv_heads,
             )
-            if spare:
-                attn = attn[:, :q.shape[1] - spare]
     if "wg" in lp:
         with jax.named_scope("o_proj"), jax.named_scope("attn_gate"):
             gate = jax.nn.sigmoid(_dot(y, lp["wg"]))             # [T, heads] f32

@@ -76,6 +76,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops.page_ring import page_chain, start_chain
 from dynamo_tpu.ops.ragged_attention import _NEG_INF, _count_traced
 
 # Query rows a block and pages a chunk of the ragged walk: a turn is 128
@@ -415,58 +416,9 @@ def _decode_kernel(
     quarters = sorted({max(1, N * k // 4) for k in (1, 2, 3, 4)})
     lane, B = pl.program_id(0), pl.num_programs(0)
 
-    def each_page(lane, blk, slot, wait: bool):
-        """Start, or await, the copy of every page of block ``blk`` that
-        ``lane`` has. Where the block is whole no page is tested, and one
-        wait (for as many bytes as the buffer holds) awaits them all."""
-        first = blk * N
-        have = pl.cdiv(lens_ref[lane], ps) - first
-        entry = lane * width + first
-
-        def copy(p, page):
-            return pltpu.make_async_copy(pages_ref.at[page], buf.at[slot, p], sems.at[slot])
-
-        @pl.when(have >= N)
-        def _():
-            if wait:
-                # (a wait reads its descriptor's size alone)
-                pltpu.make_async_copy(buf.at[slot], buf.at[slot], sems.at[slot]).wait()
-            else:
-                # every entry read before the first copy starts: a start is a
-                # fence to the scheduler, the reads and their sums are not
-                ids = [tables_ref[entry + p] for p in range(N)]
-                for p in range(N):
-                    copy(p, ids[p]).start()
-
-        @pl.when(have < N)
-        def _():
-            for p in range(N):
-                @pl.when(p < have)
-                def _(p=p):
-                    c = copy(p, 0 if wait else tables_ref[entry + p])
-                    c.wait() if wait else c.start()
-
-    def fetch(ahead):
-        """Ask for the link the fetch cursor ``ahead`` = (lane, block,
-        slot) stands on, if there is one, and move it on a link."""
-        f_lane, f_blk, f_slot = ahead
-        pl.when(f_lane < B)(lambda: each_page(f_lane, f_blk, f_slot, False))
-        more = f_blk + 1 < pl.cdiv(lens_ref[jnp.minimum(f_lane, B - 1)], span)
-        return (jnp.where(more, f_lane, jnp.minimum(f_lane + 1, B)),
-                jnp.where(more, f_blk + 1, 0),
-                jnp.where(f_slot + 1 == K, 0, f_slot + 1))
-
-    @pl.when(lane == 0)
-    def _():
-        # A page that was never asked for is multiplied by a weight of 0:
-        # it has to hold numbers.
-        buf[...] = jnp.zeros(buf.shape, buf.dtype)
-        ahead = (0, 0, 0)
-        for _ in range(K - 1):
-            ahead = fetch(ahead)
-        ring_ref[0] = 0
-        for i in range(3):
-            ring_ref[1 + i] = ahead[i]
+    each_page, fetch = page_chain(lambda lane: lens_ref[lane], tables_ref, pages_ref, buf, sems,
+                                  width=width, page_tokens=ps, lanes=B)
+    pl.when(lane == 0)(lambda: start_chain(fetch, buf, ring_ref))
 
     n_tok = lens_ref[lane]
     n_blk = pl.cdiv(n_tok, span)

@@ -23,16 +23,34 @@ Query token ``i`` of sequence ``s`` sits at absolute position
 — exactly chunked-prefill causality; a decode step is the ``q_len_s == 1``
 special case.
 
-On TPU dispatches to the Pallas kernel
-(jax.experimental.pallas.ops.tpu.ragged_paged_attention); elsewhere (CPU
-test meshes) runs a vectorized jnp reference with identical semantics.
-A decode-shaped call runs the same kernel under a grid of its own: one
-sequence a query block, so that a sequence's pass computes on that
-sequence's rows alone (:func:`decode_shape_grid`). Which implementation
-a program got is logged once at trace time (:func:`_announce`) — the
-reference on a TPU is a warning, never silent — and counted by shape
-and implementation (:func:`traced_calls`,
-``dynamo_engine_attention_calls_traced_total`` on /metrics).
+Three implementations, chosen from what a call can observe
+(:func:`decode_impl`: no flag, no option, no model's name) and counted by
+shape and implementation (:func:`traced_calls`,
+``dynamo_engine_attention_calls_traced_total`` on /metrics; logged once at
+trace time, :func:`_announce`: the reference on a TPU is a warning, never
+silent):
+
+- ``impl="library"``: on a TPU the library Pallas kernel
+  (jax.experimental.pallas.ops.tpu.ragged_paged_attention) under this
+  module's grids. A decode-shaped call runs it under a grid of its own: one
+  sequence a query block, so that a sequence's pass computes on that
+  sequence's rows alone (:func:`decode_shape_grid`).
+- ``impl="pallas"`` (PR 51): a DECODE-shaped call of GROUP 1 (multi-head
+  layers: as many query heads as KV heads) on a TPU over bfloat16 pages of
+  128-wide heads in whole tiles, no window and no int8 scales, takes the
+  first-party kernel of ops/mha_attention.py. At group 1 the library
+  kernel's pass is one query row against a block converted to float32;
+  the first-party kernel keeps K and V bfloat16 into the MXU and streams
+  the pages through the ring the other first-party decode kernels use
+  (ops/page_ring.py). It reads THE SAME PAGE: the layout above, its writer
+  (model.write_kv), the waves' library call, the prefix cache and the
+  transfer format are untouched (a head-major page would make the kernel
+  trivial and would need a wave kernel, a writer and a transfer format of
+  its own). Its device op's name contains ``ragged_paged_attention`` like
+  the library's, which is how a trace's readers find either.
+- ``impl="reference"``: elsewhere (CPU test meshes; shapes neither kernel
+  tiles) a vectorized jnp reference with identical semantics.
+
 Heads HALF a lane row wide come in through :func:`paired_heads_attention`
 (two KV heads a 128-wide row, the same cache bytes, the same kernel).
 A layer that attends a sliding WINDOW says ``window=``: the caller hands it
@@ -305,15 +323,42 @@ def split_query_chunks(
     )
 
 
+def decode_impl(backend: str, q, kv_pages, cu_q_lens, *, kv_scales=None,
+                window: int | None = None, num_kv_heads: int | None = None) -> str:
+    """The label of a call's counter, from what the call can observe and
+    nothing else (no flag, no model's name): ``"pallas"``, the first-party
+    kernel of ops/mha_attention.py, for a DECODE-shaped call of GROUP 1 (as
+    many query heads as the model has KV heads) on a TPU over real-valued
+    bfloat16 pages of 128-wide heads in whole tiles, no window
+    (``mha_attention.fits``); else ``"library"`` where the library kernel's
+    shapes hold on a TPU, else ``"reference"``."""
+    from dynamo_tpu.ops import mha_attention
+
+    d, page_size = q.shape[-1], kv_pages.shape[1]
+    if (cu_q_lens is None and kv_scales is None and window is None
+            and q.shape[1] == (num_kv_heads or kv_pages.shape[2] // 2)
+            and mha_attention.fits(backend, q, kv_pages)):
+        return "pallas"
+    return "library" if backend == "tpu" and d % 128 == 0 and page_size % 8 == 0 else "reference"
+
+
 def ragged_paged_attention(
     q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs, *,
     sm_scale: float, kv_scales=None, window: int | None = None,
-    query_chunk: int | None = None,
+    query_chunk: int | None = None, num_kv_heads: int | None = None,
 ) -> jax.Array:
-    """Backend dispatch: Pallas kernel on TPU, jnp reference elsewhere.
+    """Backend dispatch: a Pallas kernel on TPU, jnp reference elsewhere.
     ``cu_q_lens=None`` states the decode shape (module docstring): the
-    kernel then runs its decode grid, and the reference reads it as
-    ``arange(S + 1)``.
+    library kernel then runs its decode grid, and the reference reads it as
+    ``arange(S + 1)``; a decode-shaped call of GROUP 1 takes the first-party
+    kernel where its geometry fits (:func:`decode_impl`).
+
+    ``num_kv_heads`` (a page that keeps MORE KV heads than the model has,
+    ``ModelConfig.cache_kv_heads``: the spare ones' rows are zeros): ``q``
+    holds the published heads. The first-party kernel computes on them
+    alone; for the library kernel and the reference, which read the group
+    off the page, the spare heads' queries go in as zeros and their output
+    is dropped.
 
     **A window** (``window=w``): a query at position ``p`` sees the keys at
     ``p - w + 1 .. p``. The library kernel's ``sliding_window`` only masks
@@ -348,15 +393,31 @@ def ragged_paged_attention(
         f"head_dim={d}, page_size={page_size}, q_heads={q.shape[1]}, "
         f"combined_kv_heads={kv_pages.shape[2]}, kv={kv_pages.dtype}"
     )
-    use_kernel = backend == "tpu" and d % 128 == 0 and page_size % 8 == 0
     shape = "decode" if cu_q_lens is None else "ragged"
+    impl = decode_impl(backend, q, kv_pages, cu_q_lens, kv_scales=kv_scales, window=window,
+                       num_kv_heads=num_kv_heads)
+    use_kernel = impl == "library"
     if cu_q_lens is not None and query_chunk and q.shape[0] > query_chunk:
         kv_lens, page_indices, cu_q_lens, num_seqs = split_query_chunks(
             q.shape[0], kv_lens, page_indices, cu_q_lens, num_seqs,
             chunk=query_chunk, page_size=page_size, window=window)
     if window is not None:
         shape, geometry = f"window-{shape}", f"{geometry}, window={window}"
-    _count_traced(shape, "library" if use_kernel else "reference")
+    _count_traced(shape, impl)
+    if impl == "pallas":
+        from dynamo_tpu.ops import mha_attention
+
+        _announce(
+            logging.INFO,
+            f"ragged attention: first-party Pallas TPU kernel, group 1 ({geometry}); decode "
+            f"shape, {mha_attention.block_pages(kv_pages, page_indices.shape[1])} pages a KV block",
+        )
+        return mha_attention.mha_decode_pallas(
+            q, kv_pages, kv_lens, page_indices, num_seqs, sm_scale=sm_scale)
+    heads = q.shape[1]
+    spare = kv_pages.shape[2] // 2 - (num_kv_heads or kv_pages.shape[2] // 2)
+    if spare:   # a zero query head (a group of them) a spare KV head of the page
+        q = jnp.pad(q, ((0, 0), (0, spare * (heads // num_kv_heads)), (0, 0)))
     if use_kernel:
         _announce(
             logging.INFO,
@@ -400,14 +461,16 @@ def ragged_paged_attention(
                 # read traffic is a capacity-only fallback (see docstring).
                 kv_pages = dequantize_kv(kv_pages, kv_scales).astype(q.dtype)
             kv_scales = None
-        return pallas_ragged_attention(
+        out = pallas_ragged_attention(
             q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs,
             sm_scale=sm_scale, window=window,
         )
-    return ragged_paged_attention_ref(
-        q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs,
-        sm_scale=sm_scale, kv_scales=kv_scales, window=window,
-    )
+    else:
+        out = ragged_paged_attention_ref(
+            q, kv_pages, kv_lens, page_indices, cu_q_lens, num_seqs,
+            sm_scale=sm_scale, kv_scales=kv_scales, window=window,
+        )
+    return out[:, :heads] if spare else out
 
 
 def block_attention(

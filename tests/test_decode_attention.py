@@ -636,3 +636,161 @@ def test_the_traced_counter_is_on_metrics():
         'dynamo_engine_attention_calls_traced_total{impl="library"')
         and 'shape="decode"' in l)
     assert 'service="engine"' in line and float(line.split()[-1]) >= 28
+
+
+# -- the first-party kernel of group-1 decode calls (PR 51, ops/mha_attention.py) ---
+
+
+def _mha_case(heads: int, page_heads: int, page_size: int, lens, seed: int = 0):
+    """A decode call of ``heads`` query heads over pages that keep
+    ``page_heads`` KV heads, the spare heads' rows and every slot past a
+    lane's ``kv_len`` holding large finite numbers: a kernel that reads
+    either is far off."""
+    rng = np.random.RandomState(seed)
+    width = -(-max(lens) // page_size) + 1
+    n_pages = len(lens) * width + 1
+    kv = rng.randn(n_pages, page_size, 2 * page_heads, HEAD_DIM).astype(np.float32)
+    kv[:, :, 2 * heads:] *= 50.0
+    tables = rng.permutation(n_pages)[: len(lens) * width].reshape(len(lens), width)
+    for s, n in enumerate(lens):
+        for j in range(width):
+            kv[tables[s, j], int(np.clip(n - j * page_size, 0, page_size)):] *= 100.0
+    q = rng.randn(len(lens), heads, HEAD_DIM)
+    return (jnp.asarray(q, jnp.bfloat16), jnp.asarray(kv, jnp.bfloat16),
+            jnp.asarray(lens, jnp.int32), jnp.asarray(tables, jnp.int32))
+
+
+# a KV block of the kernel here: 128 tokens (4 pages of 32, 8 of 16)
+@pytest.mark.parametrize("lens,live", [
+    ((1, 200), 2),            # a lane of one token beside a longer one
+    ((127, 1), 2),            # one under a block's edge
+    ((128, 129), 2),          # at it and one over
+    ((300, 40), 2),           # a partial last page, blocks of a lane behind another's
+    ((257, 64, 500), 2),      # a lane past num_seqs: zeros, and no link of the chain
+    ((90, 33), 0),            # no live lane at all
+], ids=["one", "under", "at-over", "partial", "dead-lane", "none-live"])
+@pytest.mark.parametrize("heads,page_heads,page_size", [(16, 16, 32), (30, 32, 32), (6, 8, 16)],
+                         ids=["16of16", "30of32", "6of8-small-page"])
+def test_the_group_one_kernel_gives_the_references_attention(
+        heads, page_heads, page_size, lens, live):
+    """``mha_decode_pallas`` under Pallas' TPU interpreter against
+    ``ragged_paged_attention_ref`` (the spare heads' queries zeros, as the
+    library kernel gets them) and the float64 softmax."""
+    from dynamo_tpu.ops.mha_attention import mha_decode_pallas
+
+    q, kv, kv_lens, tables = _mha_case(heads, page_heads, page_size, lens, seed=len(lens) + heads)
+    num_seqs = jnp.asarray([live], jnp.int32)
+    got = jax.block_until_ready(mha_decode_pallas(
+        q, kv, kv_lens, tables, num_seqs, sm_scale=SM_SCALE,
+        pages_per_block=128 // page_size, blocks_in_ring=2, interpret=True))
+    assert got.shape == q.shape and got.dtype == q.dtype
+    want = ra.ragged_paged_attention_ref(
+        jnp.pad(q, ((0, 0), (0, page_heads - heads), (0, 0))), kv, kv_lens, tables, None,
+        num_seqs, sm_scale=SM_SCALE)[:, :heads]
+    _close(got, np.asarray(want, np.float64))
+    exact = _dense_softmax(q[:live], kv[:, :, :2 * heads], kv_lens[:live], tables[:live])
+    _close(got[:live], exact)
+    assert not np.asarray(got[live:], np.float32).any()
+
+
+@pytest.mark.parametrize("lanes,heads,page_heads,width,n_pages", [
+    (8, 16, 16, 64, 676),       # ouro2p6b-reason-decode
+    (48, 30, 32, 128, 2049),    # olmo-hybrid-7b-reason-decode, its two decode widths
+    (16, 30, 32, 128, 2049),
+])
+def test_mosaic_compiles_the_group_one_decode_kernel_for_a_v5e(
+        one_chip, lanes, heads, page_heads, width, n_pages):
+    """The first-party kernel at the two cells' shapes and the module's
+    constants; the page array is handed over by a bitcast, no copy."""
+    from dynamo_tpu.ops import mha_attention as ma
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    q = sds((lanes, heads, HEAD_DIM), jnp.bfloat16)
+    pages = sds((n_pages, PAGE_SIZE, 2 * page_heads, HEAD_DIM), jnp.bfloat16)
+    assert ma.fits("tpu", q, pages)
+    text = jax.jit(
+        lambda *a: ma.mha_decode_pallas(*a, sm_scale=SM_SCALE)
+    ).lower(q, pages, sds((lanes,), jnp.int32), sds((lanes, width), jnp.int32),
+            sds((1,), jnp.int32)).compile().as_text()
+    assert "ragged_paged_attention_mha_decode_kernel" in text
+    assert not [l for l in text.splitlines() if " copy(" in l and f"bf16[{n_pages}," in l]
+
+
+def _rule_case(name: str):
+    """(q heads, KV heads of the page, head width, kwargs of the entry, what
+    ``num_kv_heads`` says)."""
+    cu = jnp.arange(9, dtype=jnp.int32)
+    return {
+        "group-1": (16, 16, 128, {}, None),
+        "group-1-spare-heads": (30, 32, 128, {}, 30),
+        "group-7": (28, 4, 128, {}, None),
+        "group-2-of-16": (32, 16, 128, {}, None),
+        "window": (16, 16, 128, {"window": 64}, None),
+        "kv_scales": (16, 16, 128, {"kv_scales": True}, None),
+        "ragged": (16, 16, 128, {"cu_q_lens": cu}, None),
+        "ragged-spare-heads": (30, 32, 128, {"cu_q_lens": cu}, 30),
+        "head-64": (16, 16, 64, {}, None),
+    }[name]
+
+
+@pytest.mark.parametrize("backend,name,shape,impl,kernel", [
+    ("tpu", "group-1", "decode", "pallas", "ragged_paged_attention_mha_decode_kernel"),
+    ("tpu", "group-1-spare-heads", "decode", "pallas", "ragged_paged_attention_mha_decode_kernel"),
+    ("tpu", "group-7", "decode", "library", "ragged_paged_attention_kernel"),
+    ("tpu", "group-2-of-16", "decode", "library", "ragged_paged_attention_kernel"),
+    ("tpu", "window", "window-decode", "library", "ragged_paged_attention_kernel"),
+    ("tpu", "kv_scales", "decode", "library", "ragged_paged_attention_kernel"),
+    ("tpu", "ragged", "ragged", "library", "ragged_paged_attention_kernel"),
+    ("tpu", "ragged-spare-heads", "ragged", "library", "ragged_paged_attention_kernel"),
+    ("tpu", "head-64", "decode", "reference", None),
+    ("cpu", "group-1", "decode", "reference", None),
+    ("cpu", "group-1-spare-heads", "decode", "reference", None),
+])
+def test_a_group_one_decode_call_on_a_tpu_gets_the_first_party_kernel(
+        monkeypatch, backend, name, shape, impl, kernel):
+    """The rule of ops/ragged_attention.py, from what a call can observe:
+    decode shape, group 1, a TPU, 128-wide heads, bfloat16 pages, no window
+    and no int8 scales -> ``impl="pallas"``; every other call what it got
+    before PR 51, counted as before."""
+    monkeypatch.setattr(ra.jax, "default_backend", lambda: backend)
+    heads, page_heads, d, kw, num_kv_heads = _rule_case(name)
+    kw = dict(kw)
+    cu = kw.pop("cu_q_lens", None)
+    kv = jnp.zeros((9, PAGE_SIZE, 2 * page_heads, d), jnp.bfloat16)
+    if kw.pop("kv_scales", False):
+        kv, kw["kv_scales"] = kv.astype(jnp.int8), jnp.ones(kv.shape[:3], jnp.float32)
+    q = jnp.zeros((8, heads, d), jnp.bfloat16)
+    before = ra.traced_calls()
+    jaxpr = jax.make_jaxpr(lambda q, kv, lens, tables: ra.ragged_paged_attention(
+        q, kv, lens, tables, cu, jnp.asarray([8], jnp.int32), sm_scale=1.0,
+        num_kv_heads=num_kv_heads, **kw))(
+        q, kv, jnp.ones((8,), jnp.int32), jnp.zeros((8, 4), jnp.int32))
+    assert _delta(before) == {(shape, impl): 1}
+    assert [n for n, _ in _pallas_calls(jaxpr.jaxpr)] == ([kernel] if kernel else [])
+    assert jaxpr.out_avals[0].shape == q.shape
+
+
+def test_spare_heads_of_a_page_get_zero_queries_where_the_library_kernel_reads_them():
+    """``num_kv_heads=30`` over a page of 32 on the CPU (the reference, as
+    the library kernel on a TPU's ragged calls): what 30 heads get is what
+    they got with the model padding ``q`` itself, whatever the spare rows
+    hold."""
+    q, kv, lens, tables = _mha_case(30, 32, PAGE_SIZE, (70, 5, 33))
+    num_seqs = jnp.asarray([3], jnp.int32)
+    got = ra.ragged_paged_attention(q, kv, lens, tables, None, num_seqs, sm_scale=SM_SCALE,
+                                    num_kv_heads=30)
+    assert got.shape == q.shape
+    _close(got, _dense_softmax(q, kv[:, :, :60], lens, tables))
+
+
+def test_the_decode_bench_refuses_the_cpu(monkeypatch):
+    """``tools/attn_decode_bench.py`` times kernels from a device trace; on
+    the CPU there is none to time."""
+    from tools import attn_decode_bench
+
+    monkeypatch.setattr("sys.argv", ["attn_decode_bench", "--shapes", "olmo-48", "--quick"])
+    with pytest.raises(SystemExit, match="no TPU"):
+        attn_decode_bench.main()
+    assert attn_decode_bench.geometry("olmo-48")[:4] == (48, 30, 30, 128)
+    assert [t for t, _ in attn_decode_bench.variants("olmo-48", True)][:3] == [
+        "serving", "library q1_p16", "mha_p8_r3"]

@@ -65,12 +65,17 @@ SHAPES = {
     "1p5b-16": (16, 12, 2, 256, 11265, ("loguniform", 160, 2560)),
     "1p5b-32": (32, 12, 2, 256, 11265, ("loguniform", 160, 2560)),
     "ouro": (8, 16, 16, 64, 676, ("uniform", 128, 608)),
+    # olmo-hybrid-7b-reason-decode's full layers: 30 heads, a group of ONE
+    # (as ouro's 16), in pages that keep 32 (PAGE_KV_HEADS)
+    "olmo-48": (48, 30, 30, 128, 2049, ("uniform", 128, 2560)),
     # laguna-s21-longctx-agents: the full layers' pool, and the window
     # layers' (tables of EngineConfig.window_table_blocks columns)
     "laguna-full-48": (48, 48, 8, 338, 16385, ("uniform", 4096, 10752)),
     "laguna-window-48": (48, 72, 8, 82, 1025, ("uniform", 4096, 10752)),
 }
 WINDOWS = {"laguna-window-48": 512}   # shape -> its layers' sliding window
+PAGE_KV_HEADS = {"olmo-48": 32}       # shape -> KV heads its pages keep, the spare ones zero
+MHA_PAGES = (4, 8, 16)   # pages a KV block of the group-1 kernel; main() may set it
 # lanes, heads, r, dr, page-table width, pages in one layer's array,
 # contexts: axk1-ep16-decode at its two decode widths.
 LATENT_SHAPES = {
@@ -134,34 +139,42 @@ def make_case(shape: str, seed: int):
         contexts = (kind, min(lo, span), min(hi, span))
     lens, tables, blocks = _contexts_and_tables(rng, lanes, width, n_pages, contexts, PAGE_SIZE)
     q = jnp.asarray(rng.randn(lanes, n_q, HEAD_DIM), jnp.bfloat16)
-    kv = jnp.asarray(
-        rng.randn(n_pages, PAGE_SIZE, 2 * n_kv, HEAD_DIM), jnp.bfloat16)
+    kv = rng.randn(n_pages, PAGE_SIZE, 2 * PAGE_KV_HEADS.get(shape, n_kv), HEAD_DIM)
+    kv[:, :, 2 * n_kv:] = 0.0
+    kv = jnp.asarray(kv, jnp.bfloat16)
     need_bytes = blocks * PAGE_SIZE * 2 * n_kv * HEAD_DIM * 2
     return (q, kv, jnp.asarray(lens), jnp.asarray(tables)), need_bytes, lens
 
 
 def variants(shape: str, quick: bool):
     """[(tag, fn(q, kv, lens, tables))], the serving decode path first."""
+    import jax
     import jax.numpy as jnp
     from jax.experimental.pallas.ops.tpu.ragged_paged_attention import (
         ragged_paged_attention as library,
     )
 
+    from dynamo_tpu.ops.mha_attention import (
+        _KERNEL_BLOCKS_IN_RING,
+        block_pages,
+        mha_decode_pallas,
+    )
     from dynamo_tpu.ops.ragged_attention import (
         decode_shape_grid,
         ragged_paged_attention,
         ragged_paged_attention_ref,
     )
 
-    lanes, _, _, width, _, _ = geometry(shape)
+    lanes, n_q, n_kv, width, _, _ = geometry(shape)
     serving_grid = decode_shape_grid(PAGE_SIZE, width)
     sm = HEAD_DIM ** -0.5
     window = WINDOWS.get(shape)
+    spare = PAGE_KV_HEADS.get(shape, n_kv) - n_kv
 
     def serving(q, kv, lens, tables):
         return ragged_paged_attention(
             q, kv, lens, tables, None, jnp.asarray([lanes], jnp.int32),
-            sm_scale=sm, window=window)
+            sm_scale=sm, window=window, num_kv_heads=n_kv)
 
     def reference(q, kv, lens, tables):
         return ragged_paged_attention_ref(
@@ -170,11 +183,36 @@ def variants(shape: str, quick: bool):
 
     def lib(qb, pages):
         def fn(q, kv, lens, tables):
+            q = jnp.pad(q, ((0, 0), (0, spare), (0, 0)))   # zero queries for the spare heads
             return library(
                 q, kv, lens, tables, jnp.arange(lanes + 1, dtype=jnp.int32),
                 jnp.asarray([lanes], jnp.int32), sm_scale=sm, sliding_window=window,
-                num_queries_per_block=qb, num_kv_pages_per_block=pages)
+                num_queries_per_block=qb, num_kv_pages_per_block=pages)[:, :n_q]
         return fn
+
+    def mha(pages, ring):
+        def fn(q, kv, lens, tables):
+            return mha_decode_pallas(
+                q, kv, lens, tables, jnp.asarray([lanes], jnp.int32), sm_scale=sm,
+                pages_per_block=pages, blocks_in_ring=ring)
+        return fn
+
+    if n_q == n_kv and not window:
+        # A group of ONE: the serving entry is the first-party kernel at its
+        # constants (ops/mha_attention.py); beside it the library kernel at
+        # the decode grid (what served before PR 51: the largest difference
+        # is between the two kernels) and the first-party kernel over pages a
+        # KV block x blocks in its ring.
+        out = [("serving", serving), ("library q{}_p{}".format(*serving_grid), lib(*serving_grid))]
+        page = jax.ShapeDtypeStruct((1, PAGE_SIZE, 2 * (n_kv + spare), HEAD_DIM), jnp.bfloat16)
+        served = (block_pages(page, width), _KERNEL_BLOCKS_IN_RING)
+        for pages in MHA_PAGES:
+            for ring in (3,) if quick else (2, 3, 4):
+                # (the serving entry's pair left out: one program compiles to
+                # one executable under the first name)
+                if pages <= width and (pages, ring) != served:
+                    out.append((f"mha_p{pages}_r{ring}", mha(pages, ring)))
+        return out
 
     # The serving path IS one of the grids: the same program compiles to
     # one executable under the first name, so the sweep leaves that one out.
@@ -338,7 +376,7 @@ def bench_shape(shape: str, seed: int, quick: bool, hbm_bytes_per_s: float):
 
 
 def main() -> int:
-    global PAGE_SIZE, LATENT_PAGES
+    global PAGE_SIZE, LATENT_PAGES, MHA_PAGES
     ap = argparse.ArgumentParser()
     ap.add_argument("--shapes", default=",".join([*SHAPES, *LATENT_SHAPES]))
     ap.add_argument("--seed", type=int, default=2147483659)
@@ -348,8 +386,11 @@ def main() -> int:
                     help="a few grids only (a smoke run of the tool)")
     ap.add_argument("--latent-pages", default=",".join(map(str, LATENT_PAGES)),
                     help="pages a KV block of the latent kernel, swept")
+    ap.add_argument("--mha-pages", default=",".join(map(str, MHA_PAGES)),
+                    help="pages a KV block of the group-1 kernel, swept")
     args = ap.parse_args()
     PAGE_SIZE = args.page_size
+    MHA_PAGES = tuple(int(n) for n in args.mha_pages.split(","))
     LATENT_PAGES = tuple(int(n) for n in args.latent_pages.split(","))
 
     from dynamo_tpu.device import device_info, device_peaks, enable_compile_cache
