@@ -25,4 +25,10 @@ Layer map (bottom → top):
   ring attention for long context.
 """
 
+import time as _time
+
+# The first line of the program's own code a process runs: where the
+# start-up clock's ``interpreter`` stage ends (tracing/startclock.py).
+FIRST_LINE_NS = _time.perf_counter_ns()
+
 __version__ = "0.1.0"

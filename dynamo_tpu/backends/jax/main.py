@@ -57,6 +57,7 @@ from dynamo_tpu.runtime import Context, DistributedRuntime
 from dynamo_tpu.runtime import chaos
 from dynamo_tpu.runtime.tasks import spawn_logged
 from dynamo_tpu.runtime.worker import dynamo_worker
+from dynamo_tpu.tracing import startclock
 
 log = logging.getLogger("dynamo_tpu.backends.jax")
 
@@ -294,6 +295,7 @@ def build_engine(
 
     Imported lazily so the CLI can print --help without touching jax.
     """
+    startclock.mark("weights")
     from dynamo_tpu.engine import (
         EngineConfig,
         EngineCore,
@@ -493,10 +495,29 @@ async def run_jax_worker(
         if (engine_overrides or {}).get("held_block_ttl_s", 0) != 0:
             raise ValueError("held_block_ttl_s must be 0 under multi-host")
         engine_overrides = dict(engine_overrides or {}, held_block_ttl_s=0)
+    # The start-up clock (tracing/startclock.py) is the one source of the
+    # start-up's timings, and the compile log's events are booked on it by
+    # stage. /health shows both from here on, not once warm-up is over: the
+    # minutes before "serving model" are the ones an operator waits through.
+    from dynamo_tpu import device
+    from dynamo_tpu.runtime.status_server import bind_startup_gauges
+
+    clock = startclock.running()
+    clock.mark("runtime_connect")
+    compile_log = device.compile_log()
+    compile_log.sink = clock.compile_event
+    startup: dict[str, Any] = {}
+    if runtime.status is not None:
+        runtime.status.health_sections.update(
+            startup=lambda: {**startup, "clock": clock.snapshot()},
+            compile=compile_log.snapshot,
+        )
+        bind_startup_gauges(runtime.status, clock)
+    if nnodes > 1:
         return await _run_multihost(
             runtime, model_name, preset, namespace, component,
             engine_overrides, tokenizer, seed, served_event, core_out,
-            tp, dp, quant, moe_dispatch, model_path, nnodes, node_rank,
+            tp, dp, quant, moe_dispatch, model_path, nnodes, node_rank, clock,
         )
     worker_id = runtime.primary_lease_id
     kv_pub = KvEventPublisher(runtime.store, namespace, component, worker_id)
@@ -529,17 +550,12 @@ async def run_jax_worker(
     # first jit takes tens of seconds, and blocking the loop that long
     # starves the store lease keepalive (ttl 10s) — the worker would
     # arrive at registration with its lease already expired.
-    from dynamo_tpu import device
-
-    compile_log = device.compile_log()
-    startup: dict[str, Any] = {}
-
     def _build():
         # Refuse a fallback device BEFORE minutes of CPU work on a model
         # sized for a chip: JAX falls back to the CPU when libtpu finds
         # no TPU, and only an explicit CPU request makes that a plan.
+        clock.mark("backend_init")
         info = device.require_accelerator("jax worker")
-        t0 = time.perf_counter()
         built = build_engine(
             preset,
             engine_overrides,
@@ -559,8 +575,10 @@ async def run_jax_worker(
         )
         import jax
 
+        clock.mark("device_settle")
         jax.block_until_ready((built[0].params, built[0].cache))
-        startup["build_seconds"] = round(time.perf_counter() - t0, 2)
+        clock.mark("inventory")
+        startup["build_seconds"] = round(clock.seconds(*startclock.BUILD_STAGES), 2)
         startup["memory_after_init"] = device.memory_stats()
         startup["param_bytes_per_device"] = device.bytes_per_device(
             built[0].params
@@ -574,10 +592,8 @@ async def run_jax_worker(
     core.step_scope = kv_events.step
     log.info(
         "jax worker device: platform=%s device_kind=%r devices=%d "
-        "(engine built in %.1f s; peak bytes after init %s; bytes per "
-        "device: params %s, cache %s)",
+        "(peak bytes after init %s; bytes per device: params %s, cache %s)",
         device_info["platform"], device_info["kind"], device_info["count"],
-        startup["build_seconds"],
         [m["peak_bytes_in_use"] for m in startup["memory_after_init"]],
         startup["param_bytes_per_device"], startup["cache_bytes_per_device"],
     )
@@ -649,18 +665,16 @@ async def run_jax_worker(
     if warm_up:
         from dynamo_tpu.engine.warmup import warm_up as _warm_up
 
-        t0 = time.perf_counter()
         startup["warmup_phases"] = await asyncio.to_thread(_warm_up, core)
-        startup["warmup_seconds"] = round(time.perf_counter() - t0, 2)
+        startup["warmup_seconds"] = round(clock.seconds(*startclock.WARMUP_STAGES), 2)
         # What the waves planner decides by (JSON keys are strings).
         startup["prefill_bucket_ms"] = {
             str(b): ms for b, ms in core.prefill_bucket_ms.items()
         }
+    clock.mark("register")
     if runtime.status is not None:
         runtime.status.health_sections.update(
             device=lambda: device_info,
-            startup=lambda: startup,
-            compile=compile_log.snapshot,
             memory=device.memory_stats,
         )
 
@@ -1070,12 +1084,14 @@ async def run_jax_worker(
 
     await endpoint.serve(handler)
     await register_llm(endpoint, _model_card(model_name, tokenizer, core))
+    clock.close()
     log.info(
         "jax %s worker %d serving model %r (preset %s, %d kv blocks) on "
         "%s %r x%d",
         role, worker_id, model_name, preset, core.engine.num_kv_blocks,
         device_info["platform"], device_info["kind"], device_info["count"],
     )
+    log.info("%s", clock.table())
     if served_event is not None:
         served_event.set()
     await runtime.wait_for_shutdown()
@@ -1099,6 +1115,7 @@ async def _run_multihost(
     model_path: str | None,
     nnodes: int,
     node_rank: int,
+    clock: startclock.StartClock,
 ) -> None:
     """Leader (rank 0) serves; followers replay its step records so every
     process issues identical programs over the global mesh."""
@@ -1161,6 +1178,7 @@ async def _run_multihost(
         )
         if core_out is not None:
             core_out.append(core)
+        clock.mark("register")
         # No step record may fire before every follower subscribes.
         await LeaderBarrier(
             runtime.store, barrier_name(namespace, component), nnodes - 1
@@ -1191,10 +1209,12 @@ async def _run_multihost(
 
         await endpoint.serve(handler)
         await register_llm(endpoint, _model_card(model_name, tokenizer, core))
+        clock.close()
         log.info(
             "multihost leader %d serving %r over %d nodes (preset %s)",
             worker_id, model_name, nnodes, preset,
         )
+        log.info("%s", clock.table())
         if served_event is not None:
             served_event.set()
         await runtime.wait_for_shutdown()
@@ -1207,11 +1227,13 @@ async def _run_multihost(
     )
     if core_out is not None:
         core_out.append(core)
+    clock.mark("register")
     ready = asyncio.Event()
     follower = asyncio.create_task(
         run_follower(runtime, core, namespace, component, nnodes, ready_event=ready)
     )
     await ready.wait()
+    clock.close()
     if served_event is not None:
         served_event.set()
     shutdown = asyncio.create_task(runtime.wait_for_shutdown())

@@ -159,13 +159,16 @@ class CompileLog:
     layers make that visible), plus persistent-cache hit and miss
     counts. Host-side listeners only; nothing is added to a dispatch."""
 
-    _BACKEND = "/jax/core/compile/backend_compile_duration"
-    _TRACE_LOWER = (
-        "/jax/core/compile/jaxpr_trace_duration",
-        "/jax/core/compile/jaxpr_to_mlir_module_duration",
-    )
-    _HIT = "/jax/compilation_cache/cache_hits"
-    _MISS = "/jax/compilation_cache/cache_misses"
+    # JAX's duration events by what they time
+    _KINDS = {
+        "/jax/core/compile/jaxpr_trace_duration": "trace",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+        "/jax/core/compile/backend_compile_duration": "backend",
+    }
+    _CACHE = {
+        "/jax/compilation_cache/cache_hits": "hit",
+        "/jax/compilation_cache/cache_misses": "miss",
+    }
     # Programs quicker than this are summed but not listed one by one
     # (every jnp op outside a jit is a tiny program of its own).
     _LISTED_FROM_SECONDS = 0.5
@@ -177,6 +180,10 @@ class CompileLog:
         self._trace_lower = 0.0
         self._hits = 0
         self._misses = 0
+        # ``sink(kind, name, seconds)`` hears every event this log counts
+        # (kind: a value of _KINDS or _CACHE), after it is counted: the
+        # start-up clock books them by stage (tracing/startclock.py).
+        self.sink = None
 
     def install(self) -> "CompileLog":
         import jax.monitoring as monitoring
@@ -186,25 +193,31 @@ class CompileLog:
         return self
 
     def _on_duration(self, event: str, seconds: float, **kw) -> None:
-        if event in self._TRACE_LOWER:
-            with self._lock:
-                self._trace_lower += seconds
+        kind = self._KINDS.get(event)
+        if kind is None:
             return
-        if event != self._BACKEND:
-            return
+        name = str(kw.get("fun_name", "?"))
         with self._lock:
-            self._total += seconds
-            if seconds >= self._LISTED_FROM_SECONDS:
-                self._programs.append(
-                    (str(kw.get("fun_name", "?")), round(seconds, 2))
-                )
+            if kind != "backend":
+                self._trace_lower += seconds
+            else:
+                self._total += seconds
+                if seconds >= self._LISTED_FROM_SECONDS:
+                    self._programs.append((name, round(seconds, 2)))
+        if self.sink is not None:
+            self.sink(kind, name, seconds)
 
     def _on_event(self, event: str, **kw) -> None:
+        kind = self._CACHE.get(event)
+        if kind is None:
+            return
         with self._lock:
-            if event == self._HIT:
+            if kind == "hit":
                 self._hits += 1
-            elif event == self._MISS:
+            else:
                 self._misses += 1
+        if self.sink is not None:
+            self.sink(kind, "", 0.0)
 
     def snapshot(self) -> dict:
         with self._lock:

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dynamo_tpu import tracing
+from dynamo_tpu.tracing import startclock
 from dynamo_tpu.tracing.stepclock import PHASES, StepClock
 from dynamo_tpu.engine.block_allocator import DeviceBlockAllocator, OutOfBlocksError
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
@@ -448,6 +449,7 @@ class EngineCore(KvTransfer):
         self.pp_mesh = pp_mesh
         (self.params, self.cache, self._dp, self._pp, self._pp_micro,
          self._batch_shardings) = place(model_cfg, engine_cfg, params, seed, mesh, pp_mesh)
+        startclock.mark("engine_init")
         # A window model's blocks are found again by no one (prefix caching
         # is off for it): their KV events are not published.
         self.allocator = DeviceBlockAllocator(

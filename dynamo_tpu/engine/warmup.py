@@ -35,6 +35,7 @@ import time
 import numpy as np
 
 from dynamo_tpu.engine.core import EngineCore
+from dynamo_tpu.tracing import startclock
 from dynamo_tpu.llm.protocols.common import (
     PreprocessedRequest,
     SamplingOptions,
@@ -91,8 +92,11 @@ _on_one_stack_chunk.__code__ = _on_one_stack_chunk.__code__.replace(
 
 
 def warm_up(core: EngineCore) -> dict[str, float]:
-    """Run the warm-up traffic; returns wall seconds per phase (compile
-    seconds per program come from :class:`dynamo_tpu.device.CompileLog`).
+    """Run the warm-up traffic; returns wall seconds per phase. The
+    context's start-up clock (tracing/startclock.py; one of this call's own
+    where none runs) keeps the time: a row a program in its ``warmup``
+    stage, with the compile events :class:`dynamo_tpu.device.CompileLog`
+    hears while the row is open, then ``waves_timed``.
 
     The allocator's KV-event callbacks are detached for the duration and
     the warm-up blocks are dropped from the prefix cache afterwards, so
@@ -130,7 +134,8 @@ def _warm_up(core: EngineCore) -> dict[str, float]:
     bs = eng.block_size
     # A wave that cannot be admitted would wait for blocks forever.
     block_budget = int(eng.num_kv_blocks * 0.9)
-    phases: dict[str, float] = {}
+    clock = startclock.running()
+    clock.mark("warmup")
 
     def prompts(n: int, length: int) -> list[list[int]]:
         return [rng.randint(1, vocab, size=length).tolist() for _ in range(n)]
@@ -148,10 +153,9 @@ def _warm_up(core: EngineCore) -> dict[str, float]:
                 length = min(bucket // n, max_prompt)
                 if n * length <= prev or n * -(-length // bs) > block_budget:
                     break  # one wave cannot fill this bucket (or larger)
-                t0 = time.perf_counter()
-                _run(core, prompts(n, length), 1, temperature,
-                     f"{name}-prefill{bucket}")
-                phases[f"prefill T={bucket} {name}"] = time.perf_counter() - t0
+                with clock.row(f"prefill T={bucket} {name}"):
+                    _run(core, prompts(n, length), 1, temperature,
+                         f"{name}-prefill{bucket}")
                 waves.append((bucket, n, length))
                 prev = bucket
             prev = 0
@@ -160,31 +164,29 @@ def _warm_up(core: EngineCore) -> dict[str, float]:
                 length = min(bs, max_prompt)
                 if n <= prev or n * -(-(length + gen) // bs) > block_budget:
                     break  # max_num_seqs (or the cache) never reaches it
-                t0 = time.perf_counter()
-                _run(core, prompts(n, length), gen, temperature,
-                     f"{name}-decode{width}")
-                phases[f"decode B={width} k={k} {name}"] = (
-                    time.perf_counter() - t0
-                )
+                with clock.row(f"decode B={width} k={k} {name}"):
+                    _run(core, prompts(n, length), gen, temperature,
+                         f"{name}-decode{width}")
                 prev = width
         # Everything is compiled: the host's cost per dispatch counts from
         # here, and each bucket's wave is timed on a fresh set of prompts.
         core.count_host_floor_from_here()
-        t0 = time.perf_counter()
+        clock.mark("waves_timed")
         table = {}
         for bucket, n, length in waves:
             seconds = _run(core, prompts(n, length), 1, 1.0,
                            f"timed-prefill{bucket}")
             table[bucket] = round(1e3 * seconds, 3)
-        phases["prefill waves timed"] = time.perf_counter() - t0
+        # handing over: the clean-up below is the first of ``register``
+        clock.mark("register")
     finally:
         core.clear_kv_cache()
         alloc.on_stored, alloc.on_removed = saved
     core.prefill_bucket_ms = table
-    for phase, seconds in phases.items():
-        log.info("warm-up %-28s %6.1f s", phase, seconds)
     log.info(
         "prefill wave ms by bucket: %s (host floor so far %.1f ms a dispatch)",
         table, core.host_floor_ms(),
     )
-    return {p: round(s, 2) for p, s in phases.items()}
+    phases = clock.row_walls()
+    phases["prefill waves timed"] = round(clock.seconds("waves_timed"), 2)
+    return phases

@@ -28,6 +28,7 @@ from functools import partial
 import jax
 
 from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+from dynamo_tpu.tracing import startclock
 
 # v5e: 16 GiB HBM per chip.
 V5E_HBM_BYTES = 16 * 1024**3
@@ -142,7 +143,8 @@ def place(model_cfg, engine_cfg, params, seed: int, mesh, pp_mesh) -> tuple:
     and the cache where the kind of mesh puts them, with its sizes: staged
     over ``pp_mesh`` (parallel/pipeline.py, the STACKED cache), sharded
     over ``mesh`` (parallel/sharding.py), or on the one default device.
-    ``params`` None initialises them in place, seeded. Weights fused for
+    ``params`` None initialises them in place, seeded; the start-up clock's
+    ``weights`` stage ends where the cache's arrays begin. Weights fused for
     another tp are refused (:func:`_check_fuse_tp`); what the
     configurations and the meshes alone decide was checked before
     (engine/options.py)."""
@@ -186,6 +188,7 @@ def place(model_cfg, engine_cfg, params, seed: int, mesh, pp_mesh) -> tuple:
         # stage sharding (parallel/pipeline.py).
         from dynamo_tpu.engine.model import init_cache_stacked
 
+        startclock.mark("cache_alloc")
         cache = jax.jit(
             partial(init_cache_stacked, model_cfg, engine_cfg),
             out_shardings=cache_sharding_pp(
@@ -212,6 +215,7 @@ def place(model_cfg, engine_cfg, params, seed: int, mesh, pp_mesh) -> tuple:
             )(jax.random.PRNGKey(seed), model_cfg, tp)
         else:
             params = shard_params(params, model_cfg, mesh)
+        startclock.mark("cache_alloc")
         cache = jax.jit(
             partial(init_cache, model_cfg, engine_cfg),
             out_shardings=cache_sharding(
@@ -228,5 +232,6 @@ def place(model_cfg, engine_cfg, params, seed: int, mesh, pp_mesh) -> tuple:
         params = params if params is not None else init_params(
             jax.random.PRNGKey(seed), model_cfg
         )
+        startclock.mark("cache_alloc")
         cache = init_cache(model_cfg, engine_cfg)
     return params, cache, dp, pp, pp_micro, batch_shardings

@@ -1088,6 +1088,36 @@ def bind_store_gauges(status: "SystemStatusServer | None", store) -> None:
     _bind_store_gauges(status.metrics, status.before_render, store)
 
 
+def bind_startup_gauges(status: "SystemStatusServer | None", clock) -> None:
+    """Export a worker's start-up clock (tracing/startclock.py) on /metrics:
+    ``dynamo_worker_startup_seconds{stage}``, a series a stage the worker
+    has left, and ``dynamo_worker_start_to_serving_seconds`` once it
+    serves: what a planner's scale-up lag and a start-up probe's deadline
+    are set from. No-op when the status server is disabled."""
+    if status is None:
+        return
+
+    def sync() -> None:
+        snap = clock.snapshot()
+        serving = snap["stage_now"] is None
+        for stage, seconds in snap["stages"].items():
+            if serving or (seconds and stage != snap["stage_now"]):
+                status.metrics.scoped(service="worker", stage=stage).gauge(
+                    "worker_startup_seconds",
+                    "Seconds of the worker's start-up spent in the stage "
+                    "(the stages partition process start to 'serving model')",
+                ).set(seconds)
+        if serving:
+            status.metrics.scoped(service="worker").gauge(
+                "worker_start_to_serving_seconds",
+                "Seconds from the operating system's start of the worker's "
+                "process to 'serving model'",
+            ).set(snap["total_s"])
+            status.before_render.remove(sync)   # closed: nothing moves again
+
+    status.before_render.append(sync)
+
+
 class SystemStatusServer:
     def __init__(
         self,

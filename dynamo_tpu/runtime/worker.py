@@ -20,6 +20,7 @@ from typing import Any, Awaitable, Callable
 from dynamo_tpu.runtime.component import DistributedRuntime
 from dynamo_tpu.runtime.config import RuntimeConfig
 from dynamo_tpu.runtime.logging_setup import setup_logging
+from dynamo_tpu.tracing import startclock
 
 log = logging.getLogger("dynamo_tpu.worker")
 
@@ -30,6 +31,9 @@ def dynamo_worker(
     def decorator(fn: Callable[..., Awaitable[Any]]) -> Callable[..., Any]:
         @functools.wraps(fn)
         def entry(*args: Any, **kwargs: Any) -> Any:
+            # The process's start-up clock: its imports end here. The JAX
+            # worker reports it (backends/jax/main.py); nobody else reads it.
+            startclock.open_process_clock().mark("runtime_connect")
             cfg = config or RuntimeConfig.from_env()
             setup_logging(cfg.log_level, cfg.logging_jsonl)
             return asyncio.run(_run(fn, cfg, *args, **kwargs))
