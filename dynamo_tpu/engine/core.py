@@ -368,7 +368,6 @@ class _RaggedBatch:
     mm_mask: np.ndarray
     need_mask: bool
     want_lp: bool
-    all_greedy: bool
     want_mm: bool
 
 
@@ -643,6 +642,10 @@ class EngineCore(KvTransfer):
             # these, and < 1.0 is the amortization working.
             "megastep_dispatches": 0,
             "single_step_dispatches": 0,
+            # Dispatches by the sampler's branch (_count_sampling): every
+            # lane at temperature 0, or some lane draws.
+            "dispatches_greedy": 0,
+            "dispatches_drawn": 0,
             "committed_tokens": 0,
             # Of those, the tokens decode iterations gave: all but each
             # stream's first (the denominator of the clock's lane-seconds).
@@ -933,6 +936,19 @@ class EngineCore(KvTransfer):
         stream, never blocking. A ragged dispatch's token buffer; a
         megastep gathers inside its own program (:func:`unpack_lanes`)."""
         return self._feed(self._feed_source(), host_tokens, jnp.asarray(src_idx))
+
+    def _count_sampling(
+        self, temp: np.ndarray, top_k: np.ndarray, top_p: np.ndarray
+    ) -> bool:
+        """Count a dispatch by the branch its sampler takes on the device
+        (``sampler._sample``: the arg-max alone where no lane of the batch
+        draws, padding included) and return its ``need_mask``: whether a
+        lane that DRAWS asks for top-k / top-p. A lane at temperature 0
+        gets its arg-max whatever else it asks for, so a greedy batch
+        never compiles the masked variant."""
+        draws = temp > 0.0
+        self.exec_stats["dispatches_drawn" if draws.any() else "dispatches_greedy"] += 1
+        return bool((draws & ((top_k > 0) | (top_p < 1.0))).any())
 
     def _note_dispatch(self) -> int:
         """Dispatch-side bookkeeping for the pipelining invariants: the
@@ -1369,7 +1385,9 @@ class EngineCore(KvTransfer):
         gather = np.zeros((S, R), np.int32)
         counters = np.zeros((S, R), np.int32)
         seeds = np.zeros(S, np.int32)
-        temp = np.ones(S, np.float32)
+        # a padded lane is at temperature 0: the device takes the sampler's
+        # arg-max branch when no lane draws, padding included
+        temp = np.zeros(S, np.float32)
         top_k = np.zeros(S, np.int32)
         top_p = np.ones(S, np.float32)
 
@@ -1415,11 +1433,8 @@ class EngineCore(KvTransfer):
             t += chunk
         cu[1 : len(rows) + 1] = np.cumsum([len(tl) for _, tl, _, _ in rows])
         cu[len(rows) + 1 :] = cu[len(rows)]
-        need_mask = any(
-            s.sampling.top_k > 0 or s.sampling.top_p < 1.0 for s, _, _, _ in rows
-        )
+        need_mask = self._count_sampling(temp, top_k, top_p)
         want_lp = any(s.logprobs is not None for s, _, _, _ in rows)
-        all_greedy = all(s.sampling.temperature == 0.0 for s, _, _, _ in rows)
 
         # Multimodal splice (separate compiled variant): override rows
         # whose prompt position falls inside an image span with the
@@ -1455,7 +1470,7 @@ class EngineCore(KvTransfer):
             counters=counters, seeds=seeds, temp=temp, top_k=top_k,
             top_p=top_p, feed_idx=feed_idx, mm_embeds=mm_embeds,
             mm_mask=mm_mask, need_mask=need_mask, want_lp=want_lp,
-            all_greedy=all_greedy, want_mm=want_mm,
+            want_mm=want_mm,
         )
 
     def _dispatch_ragged(
@@ -1505,8 +1520,7 @@ class EngineCore(KvTransfer):
         last_rows, counters, seeds = b.last_rows, b.counters, b.seeds
         temp, top_k, top_p = b.temp, b.top_k, b.top_p
         feed_idx, mm_embeds, mm_mask = b.feed_idx, b.mm_embeds, b.mm_mask
-        need_mask, want_lp = b.need_mask, b.want_lp
-        all_greedy, want_mm = b.all_greedy, b.want_mm
+        need_mask, want_lp, want_mm = b.need_mask, b.want_lp, b.want_mm
 
         if self.pp_mesh is not None:
             # want_mm cannot be true here: add_request rejects mm
@@ -1556,8 +1570,7 @@ class EngineCore(KvTransfer):
                 self.params,
                 self.cache,
                 *args,
-                need_mask=need_mask and not all_greedy,
-                all_greedy=all_greedy,
+                need_mask=need_mask,
                 want_logprobs=want_lp,
             )
         else:
@@ -1599,8 +1612,7 @@ class EngineCore(KvTransfer):
                 self.params,
                 self.cache,
                 *args,
-                need_mask=need_mask and not all_greedy,
-                all_greedy=all_greedy,
+                need_mask=need_mask,
                 want_logprobs=want_lp,
                 want_mm=want_mm,
             )
@@ -1721,8 +1733,7 @@ class EngineCore(KvTransfer):
             self.cache,
             *args,
             n_steps=n_steps,
-            need_mask=b.need_mask and not b.all_greedy,
-            all_greedy=b.all_greedy,
+            need_mask=b.need_mask,
             want_logprobs=b.want_lp,
             want_mm=b.want_mm,
         )
@@ -1874,8 +1885,7 @@ class EngineCore(KvTransfer):
             self.cache,
             *args,
             n_steps=n_steps,
-            need_mask=b.need_mask and not b.all_greedy,
-            all_greedy=b.all_greedy,
+            need_mask=b.need_mask,
             want_logprobs=b.want_lp,
             want_mm=b.want_mm,
         )
@@ -2060,8 +2070,10 @@ class EngineCore(KvTransfer):
         write_pages[:P_len] = ids[pos[:P_len] // bs]
         write_offs = pos % bs
         want_lp = seq.logprobs is not None
-        all_greedy = seq.sampling.temperature == 0.0
-        need_mask = seq.sampling.top_k > 0 or seq.sampling.top_p < 1.0
+        temp = np.array([seq.sampling.temperature], np.float32)
+        top_k = np.array([seq.sampling.top_k], np.int32)
+        top_p = np.array([seq.sampling.top_p], np.float32)
+        need_mask = self._count_sampling(temp, top_k, top_p)
         self.clock.mark("h2d")
         args = (
             jnp.asarray(tokens),
@@ -2070,9 +2082,9 @@ class EngineCore(KvTransfer):
             jnp.asarray(P_len - 1, jnp.int32),
             jnp.asarray([seq.seed], np.int32),
             jnp.asarray([seq.generated], np.int32),
-            jnp.asarray([seq.sampling.temperature], np.float32),
-            jnp.asarray([seq.sampling.top_k], np.int32),
-            jnp.asarray([seq.sampling.top_p], np.float32),
+            jnp.asarray(temp),
+            jnp.asarray(top_k),
+            jnp.asarray(top_p),
         )
         self._mark_dispatch("prefill", 1, 1, 1, P_len, T)
         self._dispatch_no += 1
@@ -2080,8 +2092,7 @@ class EngineCore(KvTransfer):
             self.params,
             self.cache,
             *args,
-            need_mask=need_mask and not all_greedy,
-            all_greedy=all_greedy,
+            need_mask=need_mask,
             want_logprobs=want_lp,
         )
         self.clock.in_flight(self._dispatch_no, toks)
@@ -2311,7 +2322,7 @@ class EngineCore(KvTransfer):
         positions = np.zeros(B, np.int32)
         tables = self._blank_tables(B)
         active = np.zeros(B, bool)
-        temp = np.ones(B, np.float32)
+        temp = np.zeros(B, np.float32)   # padding draws nothing (_assemble_ragged)
         top_k = np.zeros(B, np.int32)
         top_p = np.ones(B, np.float32)
         seeds = np.zeros(B, np.int32)
@@ -2347,11 +2358,8 @@ class EngineCore(KvTransfer):
                 if seqs[i].pending_block and (feed_idx is None or feed_idx[i] < 0):
                     known[i, 1] = seqs[i].pending_block
         finishing = int(np.count_nonzero(budgets <= made))
-        need_mask = any(
-            s.sampling.top_k > 0 or s.sampling.top_p < 1.0 for s in seqs
-        )
+        need_mask = self._count_sampling(temp, top_k, top_p)
         want_lp = any(s.logprobs is not None for s in seqs)
-        all_greedy = all(s.sampling.temperature == 0.0 for s in seqs)
         lanes = pack_lanes(
             tokens, feed_idx, positions, active, seeds, counters, temp,
             top_k, top_p, watch, budgets, min_left,
@@ -2380,8 +2388,7 @@ class EngineCore(KvTransfer):
             self.cache,
             *args,
             n_steps=n_steps,
-            need_mask=need_mask and not all_greedy,
-            all_greedy=all_greedy,
+            need_mask=need_mask,
             want_logprobs=want_lp,
         )
         self.clock.mark("plan")

@@ -116,7 +116,7 @@ def _expert_stats_sum(stats: list | None):
 
 def _megastep_body(
     params, cache, lanes, block_tables, feed, known=None,
-    *, n_steps, need_mask, all_greedy=False, want_logprobs=False,
+    *, n_steps, need_mask, want_logprobs=False,
     cfg, engine, mesh=None,
 ):
     """The decode MEGASTEP: ``n_steps`` fused decode+sample iterations in
@@ -146,8 +146,8 @@ def _megastep_body(
     if cfg.block_length:
         return _megastep_blocks(
             params, cache, lanes, block_tables, feed, known, n_steps=n_steps,
-            need_mask=need_mask, all_greedy=all_greedy,
-            want_logprobs=want_logprobs, cfg=cfg, engine=engine, mesh=mesh)
+            need_mask=need_mask, want_logprobs=want_logprobs,
+            cfg=cfg, engine=engine, mesh=mesh)
     (tokens, positions, active, seeds, counters, temperature, top_k, top_p,
      watch, budgets, min_left) = unpack_lanes(lanes, feed)
 
@@ -162,7 +162,7 @@ def _megastep_body(
         with jax.named_scope("sample"):
             nxt = sample_seeded(
                 logits, seeds, counters + i, temperature, top_k, top_p,
-                need_mask=need_mask, all_greedy=all_greedy,
+                need_mask=need_mask,
             )
             # Dead lanes pad the output with their last live token — a
             # deterministic, pinnable value (the host stop-scan resolves
@@ -185,7 +185,7 @@ def _megastep_body(
 
 def _megastep_blocks(
     params, cache, lanes, block_tables, feed, known,
-    *, n_steps, need_mask, all_greedy, want_logprobs, cfg, engine, mesh=None,
+    *, n_steps, need_mask, want_logprobs, cfg, engine, mesh=None,
 ):
     """:func:`_megastep_body` of a block-diffusion model
     (``cfg.block_length = B > 0``): a scanned iteration is ONE forward
@@ -291,7 +291,7 @@ def _megastep_blocks(
             seeds, temperature, top_k, top_p = (jnp.repeat(a, H) for a in lane_sampling)
             x0 = sample_seeded(
                 logits, seeds, counters, temperature, top_k, top_p,
-                need_mask=need_mask, all_greedy=all_greedy)
+                need_mask=need_mask)
             lse = jax.scipy.special.logsumexp(logits, axis=-1)
             chosen = jnp.take_along_axis(logits, x0[:, None], axis=-1)[:, 0] - lse
             lp = None
@@ -380,7 +380,7 @@ def _megastep_fused_body(
     base_pos,                # write position of the first scan write at acc=0
     seeds, temp, top_k, top_p,
     watch, budgets, min_left,
-    *, n_steps, need_mask, all_greedy=False, want_logprobs=False,
+    *, n_steps, need_mask, want_logprobs=False,
     want_mm=False, cfg, engine, mesh=None,
 ):
     """The UNIVERSAL megastep (ISSUE 12): ONE device dispatch fuses an
@@ -418,7 +418,7 @@ def _megastep_fused_body(
     with jax.named_scope("sample"):
         t0 = sample_seeded(
             logits, seeds_r, counters_r, temp_r, top_k_r, top_p_r,
-            need_mask=need_mask, all_greedy=all_greedy,
+            need_mask=need_mask,
         )
         lp0 = token_logprobs(logits, t0) if want_logprobs else None
         S = draft.shape[0]
@@ -441,7 +441,7 @@ def _megastep_fused_body(
         with jax.named_scope("sample"):
             nxt = sample_seeded(
                 logits, seeds, counters0 + gen, temp, top_k, top_p,
-                need_mask=need_mask, all_greedy=all_greedy,
+                need_mask=need_mask,
             )
             out_tok = jnp.where(act, nxt, tok)
             lp = token_logprobs(logits, out_tok) if want_logprobs else None
@@ -491,7 +491,7 @@ def _megastep_draft_body(
     hist, hist_len,          # [S, H] right-aligned history ring, [S] lengths
     dd,                      # [S] bool — lanes that draft on device
     win, nmin, nmax, kmax,   # [S] per-lane resolved drafter knobs
-    *, n_steps, need_mask, all_greedy=False, want_logprobs=False,
+    *, n_steps, need_mask, want_logprobs=False,
     want_mm=False, ngram_max_static, cfg, engine, mesh=None,
 ):
     """The ON-DEVICE-DRAFTING megastep (ISSUE 18): the universal
@@ -531,7 +531,7 @@ def _megastep_draft_body(
     )
     t0 = sample_seeded(
         logits, seeds_r, counters_r, temp_r, top_k_r, top_p_r,
-        need_mask=need_mask, all_greedy=all_greedy,
+        need_mask=need_mask,
     )
     lp0 = token_logprobs(logits, t0) if want_logprobs else None
     S = draft.shape[0]
@@ -571,7 +571,7 @@ def _megastep_draft_body(
         cnt = ((counters0 + gen)[:, None] + jR[None, :]).reshape(-1)
         nxt = sample_seeded(
             logits, rep(seeds), cnt, rep(temp), rep(top_k), rep(top_p),
-            need_mask=need_mask, all_greedy=all_greedy,
+            need_mask=need_mask,
         )
         ns = nxt.reshape(S, R)
         accj, nxt_tok = resolve_verify(ns, dtoks, dlen)
@@ -634,7 +634,7 @@ def _replicate_out(x, mesh):
 def _ring_prefill_and_sample(
     params, cache, tokens, write_pages, write_offs, last_row,
     seeds, counters, temperature, top_k, top_p,
-    *, need_mask, all_greedy=False, want_logprobs=False, cfg, engine, sp_mesh,
+    *, need_mask, want_logprobs=False, cfg, engine, sp_mesh,
 ):
     """One dense sequence-parallel prefill (ring attention over sp) +
     fused first-token sampling for a single long prompt."""
@@ -645,7 +645,7 @@ def _ring_prefill_and_sample(
     with jax.named_scope("sample"):
         toks = sample_seeded(
             logits, seeds, counters, temperature, top_k, top_p,
-            need_mask=need_mask, all_greedy=all_greedy,
+            need_mask=need_mask,
         )
         lps = token_logprobs(logits, toks) if want_logprobs else None
     return toks, lps, cache
@@ -655,7 +655,7 @@ def _prefill_and_sample(
     params, cache, tokens, positions, write_pages, write_offs,
     kv_lens, block_tables, cu_q_lens, num_seqs, last_rows,
     seeds, counters, temperature, top_k, top_p, mm_embeds, mm_mask,
-    *, need_mask, all_greedy=False, want_logprobs=False, want_mm=False,
+    *, need_mask, want_logprobs=False, want_mm=False,
     cfg, engine, mesh=None,
 ):
     """One ragged prefill wave + fused first-token sampling: every row of
@@ -675,7 +675,7 @@ def _prefill_and_sample(
     with jax.named_scope("sample"):
         toks = sample_seeded(
             logits, seeds, counters, temperature, top_k, top_p,
-            need_mask=need_mask, all_greedy=all_greedy,
+            need_mask=need_mask,
         )
         lps = token_logprobs(logits, toks) if want_logprobs else None
     return (_replicate_out(toks, mesh), _replicate_out(lps, mesh), cache,
@@ -686,7 +686,7 @@ def _pp_prefill_and_sample(
     params, cache, mb_tokens, mb_positions, mb_pages, mb_offs,
     mb_kv_lens, block_tables, mb_cu, num_seqs, mb_last_local, mb_last_mask,
     seeds, counters, temperature, top_k, top_p,
-    *, need_mask, all_greedy=False, want_logprobs=False,
+    *, need_mask, want_logprobs=False,
     cfg, engine, pp_mesh, n_micro,
 ):
     """Prefill wave under pipeline parallelism: the GPipe shard_map
@@ -702,7 +702,7 @@ def _pp_prefill_and_sample(
     with jax.named_scope("sample"):
         toks = sample_seeded(
             logits, seeds, counters, temperature, top_k, top_p,
-            need_mask=need_mask, all_greedy=all_greedy,
+            need_mask=need_mask,
         )
         lps = token_logprobs(logits, toks) if want_logprobs else None
     return (
@@ -712,7 +712,7 @@ def _pp_prefill_and_sample(
 
 def _pp_decode_chain(
     params, cache, lanes, block_tables, feed,
-    *, n_steps, need_mask, all_greedy=False, want_logprobs=False,
+    *, n_steps, need_mask, want_logprobs=False,
     cfg, engine, pp_mesh, n_micro,
 ):
     """Wavefront pipeline-parallel decode: ``B`` lanes split into ``M``
@@ -794,7 +794,7 @@ def _pp_decode_chain(
         te = ec // M
         nxt = sample_seeded(
             logits, seeds_g[ge], cnt_g[ge] + te, temp_g[ge], k_g[ge], p_g[ge],
-            need_mask=need_mask, all_greedy=all_greedy,
+            need_mask=need_mask,
         )
         # Dead lanes pad with their last live token (same pinnable value
         # as _megastep_body — the host stop-scan resolves the repeated
@@ -830,16 +830,21 @@ def _pp_decode_chain(
 
 
 def _program(fn, **bound):
-    """``functools.partial`` that keeps the function's name. JAX names a
+    """``fn`` with ``bound`` given, under ``fn``'s name. JAX names a
     compiled program after ``__name__`` — in compile logs and events, IR
-    dumps and profiler traces — and a bare partial has none, so every
-    serving program would read ``<unknown>``."""
-    p = partial(fn, **bound)
-    p.__name__ = fn.__name__
-    return p
+    dumps and profiler traces — and a ``functools.partial`` has none, so
+    every serving program would read ``<unknown>``. A closure over
+    ``(*args, **kw)`` and no partial, because ``jax.jit`` refuses a static
+    name the signature cannot take, and a caller may name one a program
+    no longer has (chipbench/rehearse_v5e.py)."""
+    def program(*args, **kw):
+        return fn(*args, **bound, **kw)
+
+    program.__name__ = program.__qualname__ = fn.__name__
+    return program
 
 
-_ONE = ("need_mask", "all_greedy", "want_logprobs")
+_ONE = ("need_mask", "want_logprobs")
 _ONE_MM = (*_ONE, "want_mm")
 _CHAIN = ("n_steps", *_ONE)
 _CHAIN_MM = (*_CHAIN, "want_mm")

@@ -2,15 +2,22 @@
 
 Per-request sampling params arrive as arrays (one lane per sequence), so a
 single compiled program serves any mix of greedy and sampled requests —
-no per-request recompiles, no host round trip per token.
+no per-request recompiles, no host round trip per token. Whether ANY lane
+draws is read on the device too, from the batch's own ``temperature``
+vector: one ``lax.cond`` whose true branch (every lane at temperature 0,
+the common served case) is the arg-max alone, with no keys folded and no
+draw over ``[B, V]``, and whose false branch is the seeded draw. It is NOT
+a static argument: a static one compiles, and warm-up then has to run,
+every serving program once a kind.
 
 Full-vocab sorts are the classic decode-step killer (O(V log V) over 128k
 vocab per token), so masking works on a ``k_cap``-sized `lax.top_k` slice:
 top-k is exact for k <= k_cap and the nucleus is computed within those
 top-k_cap candidates (the standard serving approximation — vLLM caps the
-same way). Batches with no top-k/top-p lanes skip the partial sort
-entirely (``need_mask=False`` — a second compiled variant, chosen by the
-host per batch).
+same way). Batches with no top-k/top-p lane among those that draw skip the
+partial sort entirely (``need_mask=False``, the programs warm-up compiles;
+``need_mask=True`` is the one static choice left, a second compiled
+variant chosen by the host per batch and compiled on first use).
 
 Capability parity: the sampling options the reference extracts in its
 preprocessor (`lib/llm/src/protocols/common`) and hands to vLLM; here the
@@ -67,7 +74,6 @@ def sample_seeded(
     top_p: jax.Array,         # [B] float32
     *,
     need_mask: bool = True,
-    all_greedy: bool = False,
 ) -> jax.Array:               # [B] int32
     """THE seeded-sampling entry every compiled program uses — prefill
     waves, decode megasteps, pp wavefronts, ring prefill, verify rows.
@@ -76,17 +82,16 @@ def sample_seeded(
     neighbors, scheduler, chain length, or pipelining: any path that
     samples position ``counter`` of request ``seed`` draws the same
     token. Scanned callers pass ``counters + i`` per inner iteration —
-    which is why megastep output at k=8 matches k=1 exactly."""
-    if all_greedy:
-        return sample(
-            logits, jax.random.PRNGKey(0), temperature, top_k, top_p,
-            need_mask=False, all_greedy=True,
-        )
-    base = jax.random.PRNGKey(0)
-    keys = jax.vmap(
-        lambda s, c: jax.random.fold_in(jax.random.fold_in(base, s), c)
-    )(seeds, counters)
-    return sample(logits, keys, temperature, top_k, top_p, need_mask=need_mask)
+    which is why megastep output at k=8 matches k=1 exactly. The keys
+    are folded where they are drawn from: a batch with every lane at
+    temperature 0 folds none (:func:`_sample`)."""
+    def keys() -> jax.Array:
+        base = jax.random.PRNGKey(0)
+        return jax.vmap(
+            lambda s, c: jax.random.fold_in(jax.random.fold_in(base, s), c)
+        )(seeds, counters)
+
+    return _sample(logits, keys, temperature, top_k, top_p, need_mask=need_mask)
 
 
 def stop_flags(
@@ -331,30 +336,50 @@ def sample(
     top_p: jax.Array,         # [B] float32; >= 1 => disabled
     *,
     need_mask: bool = True,   # static: False skips top-k/top-p entirely
-    all_greedy: bool = False,  # static: every lane temperature==0
     k_cap: int = DEFAULT_TOP_CAP,
 ) -> jax.Array:               # [B] int32
-    B, V = logits.shape
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    if all_greedy:
-        # Whole-batch greedy (the common served case at temperature=0):
-        # skip the gumbel draw over [B, V] entirely.
-        return greedy
-    temp = jnp.maximum(temperature, 1e-6)[:, None]
-    scaled = logits / temp
+    return _sample(logits, lambda: rng, temperature, top_k, top_p,
+                   need_mask=need_mask, k_cap=k_cap)
 
-    def draw(values: jax.Array) -> jax.Array:
-        if rng.ndim == 2:
-            # Per-lane keys: each request draws from its own seeded
-            # stream, reproducible regardless of batch neighbors.
-            return jax.vmap(jax.random.categorical)(rng, values).astype(jnp.int32)
-        return jax.random.categorical(rng, values, axis=-1).astype(jnp.int32)
 
-    if not need_mask:
-        sampled = draw(scaled)
-        return jnp.where(temperature <= 0.0, greedy, sampled)
+def _sample(logits, keys, temperature, top_k, top_p, *, need_mask, k_cap=DEFAULT_TOP_CAP):
+    """:func:`sample` with its key(s) as a function, called where they are
+    drawn from. Without a mask the body is ONE conditional on whether any
+    lane draws, a scalar read from the batch itself and so a real branch on
+    the device (inside a megastep's scan too). Each branch reads the logits
+    as often as it needs and no more: the arg-max is NOT hoisted in front,
+    which would cost the drawn branch a second pass over ``[B, V]``."""
+    def greedy() -> jax.Array:
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    cap = min(k_cap, V)
+    def drawn() -> jax.Array:
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        if need_mask:
+            cap = min(k_cap, logits.shape[1])
+            sampled = _draw_masked(keys(), scaled, top_k, top_p, cap)
+        else:
+            sampled = _draw(keys(), scaled)
+        return jnp.where(temperature <= 0.0, greedy(), sampled)
+
+    if need_mask:
+        return drawn()
+    # Whole-batch greedy (the common served case at temperature=0) skips
+    # the keys and the gumbel draw over [B, V] entirely; a lane at
+    # temperature 0 in a batch that draws gets its arg-max too.
+    return jax.lax.cond(jnp.all(temperature <= 0.0), greedy, drawn)
+
+
+def _draw(rng: jax.Array, values: jax.Array) -> jax.Array:
+    if rng.ndim == 2:
+        # Per-lane keys: each request draws from its own seeded
+        # stream, reproducible regardless of batch neighbors.
+        return jax.vmap(jax.random.categorical)(rng, values).astype(jnp.int32)
+    return jax.random.categorical(rng, values, axis=-1).astype(jnp.int32)
+
+
+def _draw_masked(rng, scaled, top_k, top_p, cap: int) -> jax.Array:
+    """A draw a lane from ``scaled`` under its top-k / top-p, within the
+    ``cap`` largest candidates (the module docstring)."""
     vals, idx = jax.lax.top_k(scaled, cap)  # [B, cap] descending
     ranks = jnp.arange(cap, dtype=jnp.int32)[None, :]
     k = jnp.where(top_k > 0, jnp.minimum(top_k, cap), cap)[:, None]
@@ -366,12 +391,11 @@ def sample(
     keep_p = cum_prev < jnp.where(top_p >= 1.0, 2.0, top_p)[:, None]
 
     masked = jnp.where(keep_k & keep_p, vals, -jnp.inf)
-    choice = draw(masked)  # index into the capped candidate set
+    choice = _draw(rng, masked)  # index into the capped candidate set
     sampled_masked = jnp.take_along_axis(idx, choice[:, None], axis=-1)[:, 0]
     # Pure-temperature lanes in a masked batch keep full-vocab sampling
     # (categorical is sort-free); only lanes that asked for top-k/top-p
     # get the capped candidate set.
-    sampled_full = draw(scaled)
+    sampled_full = _draw(rng, scaled)
     lane_masked = (top_k > 0) | (top_p < 1.0)
-    sampled = jnp.where(lane_masked, sampled_masked, sampled_full)
-    return jnp.where(temperature <= 0.0, greedy, sampled)
+    return jnp.where(lane_masked, sampled_masked, sampled_full)

@@ -11,9 +11,12 @@ drives synthetic requests through the normal ``add_request``/``step``
 path before ``register_llm``, and the deadline stays what it is.
 
 Covered: every prefill bucket one wave can reach and every decode width
-``max_num_seqs`` can reach at the full megastep length, for the two
-sampling programs ordinary requests select (sampled without a top-k/top-p
-mask — the OpenAI default — and greedy). On the pipelined loop a decode
+``max_num_seqs`` can reach at the full megastep length, for the one
+sampling program ordinary requests select: without a top-k/top-p mask, the
+OpenAI default. It serves sampled and greedy requests alike (whether any
+lane of a batch draws is a conditional on the device, engine/sampler.py),
+so one pass at temperature 1.0 compiles it and a first greedy request
+finds nothing left to compile. On the pipelined loop a decode
 phase runs two megasteps, so that both kinds of output (a prefill
 wave's, a megastep's) have fed a token buffer of that width: a megastep
 takes its lanes' inputs as one packed array and gathers the fed tokens
@@ -45,15 +48,15 @@ from dynamo_tpu.llm.protocols.common import (
 log = logging.getLogger("dynamo_tpu.engine.warmup")
 
 
-def _run(core: EngineCore, prompts: list[list[int]], max_tokens: int,
-         temperature: float, tag: str) -> float:
-    """Serve ``prompts`` to the end; returns the seconds the step loop took."""
+def _run(core: EngineCore, prompts: list[list[int]], max_tokens: int, tag: str) -> float:
+    """Serve ``prompts`` to the end, every lane drawing (temperature 1.0,
+    no mask); returns the seconds the step loop took."""
     seqs = [
         core.add_request(PreprocessedRequest(
             model="warmup",
             token_ids=p,
             request_id=f"warmup-{tag}-{i}",
-            sampling=SamplingOptions(temperature=temperature, seed=i),
+            sampling=SamplingOptions(temperature=1.0, seed=i),
             stop=StopConditions(max_tokens=max_tokens, ignore_eos=True),
         ))
         for i, p in enumerate(prompts)
@@ -112,8 +115,8 @@ def warm_up(core: EngineCore) -> dict[str, float]:
     every waiting token, which is what lets a wave here fill its bucket
     and compile it; a table that grew bucket by bucket could price four
     512 waves under the 2,048 wave that was about to be compiled, and
-    serving would compile that program on a user's request. One sampling
-    kind is timed: the two differ by the sampler. An engine that skips
+    serving would compile that program on a user's request. The waves are
+    timed as they were compiled, at temperature 1.0. An engine that skips
     warm-up (in-process test engines, multi-host workers, whose
     schedulers run in lockstep and so may read no local clock) keeps an
     empty table and plans as before; that is why there is no option."""
@@ -134,6 +137,7 @@ def _warm_up(core: EngineCore) -> dict[str, float]:
     bs = eng.block_size
     # A wave that cannot be admitted would wait for blocks forever.
     block_budget = int(eng.num_kv_blocks * 0.9)
+    own = startclock.current() is None   # no worker's clock runs: this call's own
     clock = startclock.running()
     clock.mark("warmup")
 
@@ -145,43 +149,43 @@ def _warm_up(core: EngineCore) -> dict[str, float]:
     alloc.on_stored = lambda hashes, parent: None
     alloc.on_removed = lambda hashes: None
     try:
-        for temperature, name in ((1.0, "sampled"), (0.0, "greedy")):
-            prev = 0
-            waves = []  # (bucket, prompts, tokens each) that filled a bucket
-            for bucket in eng.prefill_buckets:
-                n = min(eng.prefill_batch, lanes)
-                length = min(bucket // n, max_prompt)
-                if n * length <= prev or n * -(-length // bs) > block_budget:
-                    break  # one wave cannot fill this bucket (or larger)
-                with clock.row(f"prefill T={bucket} {name}"):
-                    _run(core, prompts(n, length), 1, temperature,
-                         f"{name}-prefill{bucket}")
-                waves.append((bucket, n, length))
-                prev = bucket
-            prev = 0
-            for width in eng.decode_buckets:
-                n = min(width, lanes)
-                length = min(bs, max_prompt)
-                if n <= prev or n * -(-(length + gen) // bs) > block_budget:
-                    break  # max_num_seqs (or the cache) never reaches it
-                with clock.row(f"decode B={width} k={k} {name}"):
-                    _run(core, prompts(n, length), gen, temperature,
-                         f"{name}-decode{width}")
-                prev = width
+        prev = 0
+        waves = []  # (bucket, prompts, tokens each) that filled a bucket
+        for bucket in eng.prefill_buckets:
+            n = min(eng.prefill_batch, lanes)
+            length = min(bucket // n, max_prompt)
+            if n * length <= prev or n * -(-length // bs) > block_budget:
+                break  # one wave cannot fill this bucket (or larger)
+            with clock.row(f"prefill T={bucket}"):
+                _run(core, prompts(n, length), 1, f"prefill{bucket}")
+            waves.append((bucket, n, length))
+            prev = bucket
+        prev = 0
+        for width in eng.decode_buckets:
+            n = min(width, lanes)
+            length = min(bs, max_prompt)
+            if n <= prev or n * -(-(length + gen) // bs) > block_budget:
+                break  # max_num_seqs (or the cache) never reaches it
+            with clock.row(f"decode B={width} k={k}"):
+                _run(core, prompts(n, length), gen, f"decode{width}")
+            prev = width
         # Everything is compiled: the host's cost per dispatch counts from
         # here, and each bucket's wave is timed on a fresh set of prompts.
         core.count_host_floor_from_here()
         clock.mark("waves_timed")
         table = {}
         for bucket, n, length in waves:
-            seconds = _run(core, prompts(n, length), 1, 1.0,
-                           f"timed-prefill{bucket}")
+            seconds = _run(core, prompts(n, length), 1, f"timed-prefill{bucket}")
             table[bucket] = round(1e3 * seconds, 3)
         # handing over: the clean-up below is the first of ``register``
         clock.mark("register")
     finally:
         core.clear_kv_cache()
         alloc.on_stored, alloc.on_removed = saved
+        if own:
+            # left running, it would be taken for the clock of the next
+            # worker this process starts
+            clock.close()
     core.prefill_bucket_ms = table
     log.info(
         "prefill wave ms by bucket: %s (host floor so far %.1f ms a dispatch)",

@@ -333,9 +333,9 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
     ),
 }
 
-# Counters of a block-diffusion model with ONE label: stats key -> (name,
-# doc, label, {label value: stats key}).
-BLOCK_COUNTERS: tuple[tuple[str, str, str, dict[str, str]], ...] = (
+# Counters with ONE label: (name, doc, label, {label value: stats key}).
+# A block-diffusion model's, then every model's.
+LABELLED_COUNTERS: tuple[tuple[str, str, str, dict[str, str]], ...] = (
     ("engine_denoise_forwards",
      "Passes a live lane of a block-diffusion model ran, counted once a "
      "lane a pass. Every pass is a denoising pass: a block's clean rows "
@@ -345,6 +345,12 @@ BLOCK_COUNTERS: tuple[tuple[str, str, str, dict[str, str]], ...] = (
      "Places a denoising pass revealed, by the rule: every hidden place "
      "over the confidence threshold, or the step's quota of the surest",
      "by", {"threshold": "places_revealed_threshold", "quota": "places_revealed_quota"}),
+    ("engine_dispatches_by_sampling",
+     "Device dispatches by the branch the sampler's conditional takes in "
+     "them (engine/sampler.py): greedy, every lane of the batch at "
+     "temperature 0, the arg-max alone; drawn, some lane draws. One "
+     "compiled program serves both",
+     "kind", {"greedy": "dispatches_greedy", "drawn": "dispatches_drawn"}),
 )
 
 
@@ -447,7 +453,7 @@ class _EngineCounters:
             family = CounterMetricFamily(f"dynamo_{name}", doc, labels=["service"])
             family.add_metric(["engine"], float(stats.get(key, 0) or 0))
             yield family
-        for name, doc, label, keys in BLOCK_COUNTERS:
+        for name, doc, label, keys in LABELLED_COUNTERS:
             family = CounterMetricFamily(f"dynamo_{name}", doc, labels=["service", label])
             for value, key in keys.items():
                 family.add_metric(["engine", value], float(stats.get(key, 0) or 0))

@@ -374,12 +374,18 @@ def open_process_clock() -> StartClock:
     return clock
 
 
+def current() -> StartClock | None:
+    """The context's clock while it runs, else None."""
+    clock = _CURRENT.get()
+    return clock if clock is not None and clock.stage_now is not None else None
+
+
 def running() -> StartClock:
     """The context's clock while it runs (the process's, in a worker's own
     process), else one opened now and made the context's (an in-process
     worker of a test: no interpreter and no imports of its own)."""
-    clock = _CURRENT.get()
-    if clock is None or clock.stage_now is None:
+    clock = current()
+    if clock is None:
         clock = StartClock()
         _CURRENT.set(clock)
     return clock

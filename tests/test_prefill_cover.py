@@ -221,16 +221,15 @@ def test_warm_up_compiles_every_bucket_and_leaves_a_whole_table():
     cold = core._prefill._cache_size()
     phases = warm_up(core)
     for bucket in eng.prefill_buckets:
-        for kind in ("sampled", "greedy"):
-            assert f"prefill T={bucket} {kind}" in phases
-    # a program per bucket and sampling kind, and the timed waves add none
-    assert core._prefill._cache_size() - cold == 2 * len(eng.prefill_buckets)
+        assert f"prefill T={bucket}" in phases
+    # a program per bucket, sampled and greedy in one, and the timed waves add none
+    assert core._prefill._cache_size() - cold == len(eng.prefill_buckets)
     assert all(table == {} for table in seen), "a wave of warm-up was planned by a table"
     assert sorted(core.prefill_bucket_ms) == list(eng.prefill_buckets)
     assert all(ms > 0 for ms in core.prefill_bucket_ms.values())
-    # each bucket once per sampling kind and once more to be timed; the two
-    # decode phases prefill 5 prompts of a block each (40 tokens) first
-    assert core.prefill_waves == {24: 3, 48: 3 + 2, 96: 3}
+    # each bucket once and once more to be timed; the decode phase
+    # prefills 5 prompts of a block each (40 tokens) first
+    assert core.prefill_waves == {24: 2, 48: 2 + 1, 96: 2}
     assert core.exec_stats["prefill_cut_waves"] == 0
     # the host's floor counts from the end of the compiles
     assert 0 < core.host_floor_ms() < 1e3
@@ -238,7 +237,7 @@ def test_warm_up_compiles_every_bucket_and_leaves_a_whole_table():
     # and serving afterwards compiles nothing, cut or whole
     core.prefill_bucket_ms = {24: 1.0, 48: 10.0, 96: 100.0}
     _serve(core, [_req(_prompt(8, 70), "a")])
-    assert core._prefill._cache_size() - cold == 2 * len(eng.prefill_buckets)
+    assert core._prefill._cache_size() - cold == len(eng.prefill_buckets)
 
 
 def test_an_engine_that_skipped_warm_up_has_no_table():

@@ -66,7 +66,7 @@ from tests.test_sdar import (
 # -- the head and the sampler only where a place can still be hidden -----------------
 
 def _plain_megastep_blocks(params, cache, lanes, block_tables, known, *, n_steps, need_mask,
-                           all_greedy, want_logprobs, cfg, engine):
+                           want_logprobs, cfg, engine):
     """The block megastep in its plain form, kept here as what
     ``core._megastep_blocks`` is held to: ONE scanned body for every pass,
     the clean pass too, with the head and the sampler on every row of it."""
@@ -88,7 +88,7 @@ def _plain_megastep_blocks(params, cache, lanes, block_tables, known, *, n_steps
         logits = block_logits(params, x, None, cfg)
         counters = ((pos[:, None] + place[None, :]) * steps + p).reshape(-1)
         x0 = sample_seeded(logits, seeds, counters, temperature, top_k, top_p,
-                           need_mask=need_mask, all_greedy=all_greedy)
+                           need_mask=need_mask)
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         chosen = jnp.take_along_axis(logits, x0[:, None], axis=-1)[:, 0] - lse
         reveal, by_threshold = unmask_block(
@@ -219,8 +219,8 @@ def test_the_megastep_is_bit_equal_to_its_plain_form(steps, temperature, thresho
     masked = temperature > 0 and threshold < 0.5
     params, cache, lanes, tables, known = _megastep_inputs(cfg, engine, temperature, masked)
     S, B = known.shape
-    static = dict(need_mask=masked, all_greedy=temperature == 0, want_logprobs=want_lp,
-                  cfg=cfg, engine=engine)
+    # ONE program: a greedy case and a drawing one differ by the lanes' temperatures
+    static = dict(need_mask=masked, want_logprobs=want_lp, cfg=cfg, engine=engine)
     folded = jax.jit(lambda *a, n: _megastep_blocks(*a, n_steps=n, **static),
                      static_argnames="n")
     plain = jax.jit(lambda *a, n: _plain_megastep_blocks(*a, n_steps=n, **static),
@@ -316,13 +316,17 @@ def _head_products(jaxpr, vocab: int) -> list[int]:
     return found
 
 
-def _switches(jaxpr, branches: int) -> list:
+def _switches(jaxpr, branches: int, vocab: int) -> list:
+    """The conditionals of ``branches`` branches that hold a product with the
+    vocabulary: the passes' switch, and not the sampler's own conditional
+    (does any lane draw), which lies inside a pass's branch after its head."""
     found = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "cond" and len(eqn.params["branches"]) == branches:
+        if (eqn.primitive.name == "cond" and len(eqn.params["branches"]) == branches
+                and any(_head_products(b.jaxpr, vocab) for b in eqn.params["branches"])):
             found.append(eqn)
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += _switches(sub, branches)
+            found += _switches(sub, branches, vocab)
     return found
 
 
@@ -344,13 +348,14 @@ def test_a_pass_has_a_head_of_its_hidden_places_and_the_clean_pass_none(steps):
     *args, known = _megastep_inputs(cfg, engine, 0.7, False)
     S = args[2].shape[0]
     jaxpr = jax.make_jaxpr(lambda *a: _megastep_blocks(
-        *a, n_steps=2 * (steps + 1), need_mask=False, all_greedy=False, want_logprobs=True,
+        *a, n_steps=2 * (steps + 1), need_mask=False, want_logprobs=True,
         cfg=cfg, engine=engine))(*args, NO_FEED, _folded(known)).jaxpr
     want = [[S * H] for H in hidden_at_most(cfg.block_length, steps)[:steps]]
     if steps == 1:     # one pass a block: no switch, the head as it lies
-        assert not _switches(jaxpr, 2) and _head_products(jaxpr, cfg.vocab_size) == want[0]
+        assert not _switches(jaxpr, 2, cfg.vocab_size)
+        assert _head_products(jaxpr, cfg.vocab_size) == want[0]
         return
-    (switch,) = _switches(jaxpr, steps)
+    (switch,) = _switches(jaxpr, steps, cfg.vocab_size)
     by_pass = [_head_products(branch.jaxpr, cfg.vocab_size) for branch in switch.params["branches"]]
     assert by_pass == want
     assert sorted(_head_products(jaxpr, cfg.vocab_size)) == sorted(sum(by_pass, []))
@@ -375,7 +380,7 @@ def test_the_block_megastep_holds_one_stack(steps):
         jax.ShapeDtypeStruct((2 * S * B,), jnp.int32),
         jax.ShapeDtypeStruct((S, 2, B), jnp.int32))
     program = jax.jit(lambda *a: _megastep_blocks(
-        *a, n_steps=2 * (steps + 1), need_mask=False, all_greedy=False, want_logprobs=False,
+        *a, n_steps=2 * (steps + 1), need_mask=False, want_logprobs=False,
         cfg=cfg, engine=engine))
     jaxpr = jax.make_jaxpr(program)(*shapes).jaxpr
     assert _count(jaxpr, "ragged_dot_general") == 2 * cfg.num_layers

@@ -104,9 +104,9 @@ def test_serving_compiles_no_feedback_program_after_warm_up():
     assert any(p.startswith("decode B=7") for p in phases)
     warm = programs()
     # no gather of a megastep's own; a padding per output shape (a prefill
-    # wave's, and a megastep's per width); a megastep per width and
-    # sampling kind
-    assert tuple(w - c for w, c in zip(warm, cold)) == (0, 3, 4)
+    # wave's, and a megastep's per width); a megastep per width, sampled
+    # and greedy in one
+    assert tuple(w - c for w, c in zip(warm, cold)) == (0, 3, 2)
     fed = core.exec_stats["pipelined_dispatches"]
 
     def req(i, n_prompt, max_tokens):
