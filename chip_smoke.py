@@ -459,11 +459,15 @@ def serve_phase(mode: str, inject: str | None, report: dict) -> None:
         report["linear_traced"] = check_calls_traced(
             workers, head["device"]["platform"],
             "dynamo_engine_linear_calls_traced_total", judge_linear_traced)
+        report["ssm_traced"] = check_calls_traced(
+            workers, head["device"]["platform"],
+            "dynamo_engine_ssm_calls_traced_total", judge_ssm_traced)
         for role, st in report["startup"].items():
+            steps = {**report["linear_traced"][role], **report["ssm_traced"][role]}
             if st.get("state_slots") and not any(
-                    k.startswith("step/") and v for k, v in report["linear_traced"][role].items()):
-                raise PhaseFailed(f"{role}: a model with linear-attention layers traced no "
-                                  f"state step: {report['linear_traced'][role]}")
+                    k.startswith("step/") and v for k, v in steps.items()):
+                raise PhaseFailed(f"{role}: a model with linear-attention or mamba layers "
+                                  f"traced no state step: {steps}")
         for role, st in report["startup"].items():
             judge_window(role, st, report["attention_traced"][role])
             judge_blocks(role, st, report["attention_traced"][role],
@@ -556,6 +560,15 @@ def judge_linear_traced(role: str, got: dict[str, float], platform: str) -> None
         raise PhaseFailed(
             f"{role}: a linear-attention layer's decode state step ran its jnp path on "
             f"a TPU, not the kernel that reads and writes each lane's state once: {got}")
+
+
+def judge_ssm_traced(role: str, got: dict[str, float], platform: str) -> None:
+    """``got``: ``{"<shape>/<impl>": calls traced}`` of one worker (no series
+    for a model without mamba layers)."""
+    if platform == "tpu" and got.get("step/jnp"):
+        raise PhaseFailed(
+            f"{role}: a mamba layer's decode state step ran its jnp path on a TPU, not "
+            f"the kernel that reads and writes each lane's state once: {got}")
 
 
 def judge_attention_traced(role: str, got: dict[str, float], platform: str) -> None:
@@ -877,14 +890,15 @@ def main() -> int:
     which.add_argument("--kernel-check-child", action="store_true",
                        help=argparse.SUPPRESS)
     ap.add_argument("--cpu-preset", choices=["tiny", "tiny-lfm2", "tiny-laguna", "tiny-sdar", "tiny-mimo",
-                                             "tiny-olmo-hybrid"],
+                                             "tiny-olmo-hybrid", "tiny-nemotron-h"],
                     default="tiny",
                     help="what --cpu-tiny serves: the dense tiny preset, the "
                          "hybrid one (conv layers beside paired 64-wide heads), "
                          "the one of window and full attention layers (two pools), "
                          "the one that generates by diffusion over blocks, the one "
                          "whose key is wider than its value (two pools of unequal pages), "
-                         "or the one of linear-attention layers (a slab a lane)")
+                         "the one of linear-attention layers (a slab a lane), or the one of "
+                         "one-sub-layer blocks (Mamba-2 mixers in the slab, experts alone)")
     ap.add_argument("--corrupt", action="store_true", help=argparse.SUPPRESS)
     ap.add_argument("--inject", choices=["worker-start", "bad-request",
                                          "kernel-mismatch"], default=None,
@@ -960,6 +974,7 @@ def main() -> int:
     print(f"served attention traced (shape/impl: calls): {report['attention_traced']}")
     print(f"served experts traced (shape/impl: calls): {report['experts_traced']}")
     print(f"served linear state calls traced (shape/impl: calls): {report['linear_traced']}")
+    print(f"served ssm state calls traced (shape/impl: calls): {report['ssm_traced']}")
     print("a token's account since start, ms (decode / behind a wave / behind "
           f"the host; warm-up and compiles included): {report['token_account']}")
     for c in report.get("kernels", {}).get("checks", ()):

@@ -405,7 +405,7 @@ def build_engine(
 
         raise UnsupportedModelOption(
             "quant", model_cfg.name,
-            "conv and linear-attention operators, layers of more than one kind "
+            "conv, linear-attention and mamba operators, layers of more than one kind "
             "and experts are served unquantised (no int8 init for them)",
         )
     if quant == "int8":
@@ -610,8 +610,8 @@ async def run_jax_worker(
             f"role={role!r} hands blocks to a peer; "
             + ("one block holds pages of two shapes and [planes, *page] carries one"
                if core.cfg.hybrid else
-               "the linear layers' state lies in a slab a lane and no block holds it"
-               if core.cfg.linear else
+               "the linear or mamba layers' state lies in a slab a lane and no block holds it"
+               if core.cfg.has_slab else
                "the window layers' pages lie in a pool of their own and do not "
                "leave the device"),
         )
@@ -621,14 +621,14 @@ async def run_jax_worker(
     startup.update(core.cache_by_kind())
     startup["state_bytes_per_block"] = core.cfg.state_bytes_per_block()
     startup["prefix_caching"] = bool(core.engine.enable_prefix_caching)
-    if core.cfg.linear:
+    if core.cfg.has_slab:
         startup["state_bytes_per_sequence"] = core.cfg.state_bytes_per_sequence()
         startup["state_slots"] = core.engine.max_num_seqs
         log.info(
-            "%d linear layers: %d B of state a sequence in a slab of %d lane slots "
+            "%d %s layers: %d B of state a sequence in a slab of %d lane slots "
             "(and a garbage slot); prefix caching off",
-            core.cfg.cache_layers("linear"), startup["state_bytes_per_sequence"],
-            startup["state_slots"])
+            core.cfg.cache_layers(core.cfg.slab_kind), core.cfg.slab_kind,
+            startup["state_bytes_per_sequence"], startup["state_slots"])
     if core.cfg.windowed:
         startup["window_blocks"] = core.engine.num_window_blocks
         startup["sliding_window"] = core.cfg.sliding_window

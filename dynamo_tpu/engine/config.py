@@ -14,10 +14,14 @@ from dataclasses import dataclass, field
 import jax.numpy as jnp
 
 # What ModelConfig.layer_types may name, as published.
-LAYER_KINDS = ("conv", "full_attention", "sliding_attention", "linear_attention")
-# What a layer caches (ModelConfig.layer_kind), by its published kind.
+LAYER_KINDS = ("conv", "full_attention", "sliding_attention", "linear_attention",
+               "mamba", "moe")
+# What a layer caches (ModelConfig.layer_kind), by its published kind ("none": a
+# block that is a feed-forward alone caches nothing).
 _CACHE_KIND = {"conv": "conv", "full_attention": "attention", "sliding_attention": "window",
-               "linear_attention": "linear"}
+               "linear_attention": "linear", "mamba": "ssm", "moe": "none"}
+# The cache kinds whose entry is a slab a lane slot (ModelConfig.slab_shapes).
+SLAB_KINDS = ("linear", "ssm")
 
 _DTYPES = {
     "bfloat16": jnp.bfloat16,
@@ -175,6 +179,35 @@ class ModelConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 0
     linear_allow_neg_eigval: bool = False
+    # -- Mamba-2 state-space layers, blocks of ONE sub-layer (nemotron_h) -----
+    # A stack whose ``layer_types`` hold "mamba" or "moe" is one of blocks
+    # ``x + f(norm(x))`` with ``f`` a mixer OR a feed-forward, never both
+    # (:attr:`single_sublayer`), by ``layer_types``: "mamba" (a Mamba-2 mixer:
+    # :meth:`layer_kind` "ssm", a slot of the slab a sequence as a linear
+    # layer's; ops/ssm.py, model.ssm_layer), "full_attention" (its attention
+    # half alone) or "moe" (the dropless sparse MLP alone: caches NOTHING,
+    # :meth:`layer_kind` "none"). The one norm a block is ``attn_norm``.
+    # A "mamba" layer: ``ssm_num_heads`` heads of ``ssm_head_dim`` (``d_in`` =
+    # their product), ``ssm_n_groups`` groups that share ``B`` and ``C`` of
+    # ``ssm_state_size``, a depthwise causal convolution of ``ssm_conv_kernel``
+    # taps (a bias with ``ssm_conv_bias``) over ``[x | B | C]``, a FLOAT32 state
+    # ``[ssm_num_heads, ssm_head_dim, ssm_state_size]`` a sequence; the ragged
+    # shape runs in chunks of ``ssm_chunk_size``.
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_n_groups: int = 0
+    ssm_conv_kernel: int = 0
+    ssm_chunk_size: int = 0
+    ssm_conv_bias: bool = False
+    # What an expert of the dropless sparse MLP (and its shared expert) computes:
+    # "swiglu" ``(silu(x Wg) * (x Wu)) Wd`` from a fused ``[Wg | Wu]``, or
+    # "relu2" ``relu(x Wu)^2 Wd``: two matrices, no gate.
+    mlp_activation: str = "swiglu"
+    # The shared expert's whole width where it is published apart from the
+    # routed experts' (``moe_shared_expert_intermediate_size``); 0:
+    # ``num_shared_experts x moe_intermediate_size``.
+    shared_expert_intermediate_size: int = 0
     # -- window and full attention layers mixed (Laguna) ----------------------
     # A "sliding_attention" layer's query at position p sees the keys at p -
     # sliding_window + 1 .. p (its own position counted) and no others, so
@@ -299,6 +332,7 @@ class ModelConfig:
             "topk_group": 1, "routed_scaling_factor": 1.0,
             "num_shared_experts": 0, "experts_held": None,
             "router_bias": False, "router_norm_eps": 1e-20,
+            "mlp_activation": "swiglu", "shared_expert_intermediate_size": 0,
         }
         if not self.shared_sparse:
             stray = [f for f, d in sparse_only.items() if getattr(self, f) != d]
@@ -311,11 +345,19 @@ class ModelConfig:
                 raise ValueError("router_scoring='sigmoid' needs moe_intermediate_size > 0")
             return
         E, k = self.num_experts, self.num_experts_per_tok
+        if self.mlp_activation not in ("swiglu", "relu2"):
+            raise ValueError(f"unknown mlp_activation {self.mlp_activation!r} (swiglu or relu2)")
+        if self.shared_expert_intermediate_size and not self.num_shared_experts:
+            raise ValueError("shared_expert_intermediate_size set with no shared expert")
         if self.router_scoring == "softmax" and (
                 self.n_group > 1 or self.router_bias or self.routed_scaling_factor != 1.0):
             raise NotImplementedError(
                 "router_scoring='softmax' on the dropless layer with groups, a "
                 "choice bias or a scaling factor is not implemented")
+        if self.single_sublayer and self.first_dense_layers:
+            raise NotImplementedError(
+                "first_dense_layers with 'mamba' or 'moe' layers: a block that is a dense MLP "
+                "alone is not implemented")
         if not 0 <= self.first_dense_layers < self.num_layers:
             raise ValueError(
                 f"first_dense_layers={self.first_dense_layers} of "
@@ -392,6 +434,7 @@ class ModelConfig:
             if stray:
                 raise ValueError(f"{stray} set without layer_types: only a conv layer reads them")
             self._check_linear()
+            self._check_ssm()
             return
         kinds = set(self.layer_types)
         if len(self.layer_types) != self.num_layers or not kinds <= set(LAYER_KINDS):
@@ -400,6 +443,7 @@ class ModelConfig:
                 f"one of {LAYER_KINDS}; got {self.layer_types}"
             )
         self._check_linear()
+        self._check_ssm()
         if "conv" not in kinds:
             return
         if self.conv_L_cache < 2:
@@ -441,11 +485,55 @@ class ModelConfig:
                 "several value heads is not implemented")
         if (self.hybrid or self.windowed or self.latent or self.ut_steps > 1 or self.is_moe
                 or self.sandwich_norm or self.attn_qkv_bias or self.kv_head_pairs
-                or self.block_length):
+                or self.block_length or self.single_sublayer):
             raise NotImplementedError(
-                "linear_attention layers with conv or sliding_attention layers, "
+                "linear_attention layers with conv, sliding_attention, mamba or moe layers, "
                 "attention='mla', ut_steps > 1, experts, sandwich_norm, attn_qkv_bias, "
                 "paired 64-wide heads or block_length > 0 are not implemented")
+
+    _SSM_FIELDS = ("ssm_num_heads", "ssm_head_dim", "ssm_state_size", "ssm_n_groups",
+                   "ssm_conv_kernel", "ssm_chunk_size")
+
+    def _check_ssm(self) -> None:
+        """The ``ssm_*`` fields and what was compared in a stack of
+        one-sub-layer blocks: a field that does not apply, or a combination no
+        program was compared for, raises by name."""
+        kinds = set(self.layer_types or ())
+        if not self.single_sublayer:
+            stray = [f for f in (*self._SSM_FIELDS, "ssm_conv_bias") if getattr(self, f)]
+            if stray:
+                raise ValueError(
+                    f"{stray} set with no 'mamba' layer: only a 'mamba' layer reads "
+                    "the ssm_* fields")
+            return
+        if not kinds <= {"mamba", "full_attention", "moe"} or "moe" not in kinds \
+                or "mamba" not in kinds:
+            raise NotImplementedError(
+                f"layer_types={self.layer_types}: blocks of one "
+                "sub-layer are 'mamba', 'full_attention' and 'moe' (a dense MLP alone, "
+                "conv, sliding_attention and linear_attention blocks are not "
+                "implemented), with at least one 'mamba' and one 'moe' layer")
+        missing = [f for f in self._SSM_FIELDS if getattr(self, f) <= 0]
+        if missing or self.ssm_conv_kernel < 2:
+            raise ValueError(f"a 'mamba' layer needs {missing or self._SSM_FIELDS} > 0 and "
+                             "at least 2 taps")
+        if self.ssm_num_heads % self.ssm_n_groups:
+            raise ValueError(f"ssm_n_groups={self.ssm_n_groups} must divide "
+                             f"ssm_num_heads={self.ssm_num_heads}")
+        if not self.shared_sparse or self.router_scoring != "sigmoid":
+            raise NotImplementedError(
+                "a 'moe' block is the dropless sigmoid-routed sparse MLP "
+                "(moe_intermediate_size > 0, router_scoring='sigmoid'); the mixtral "
+                "path and a softmax router were not compared in it")
+        if (self.latent or self.ut_steps > 1 or self.sandwich_norm or self.post_norm
+                or self.attn_qkv_bias or self.qk_norm or self.kv_head_pairs
+                or self.block_length or self.rope_theta is not None
+                or self.head_dim % 128):
+            raise NotImplementedError(
+                "'mamba' and 'moe' layers with attention='mla', ut_steps > 1, sandwich_norm, "
+                "post_norm, attn_qkv_bias, qk_norm, paired 64-wide heads, block_length "
+                "> 0, a rotary embedding (rope_theta not None) or a head that is no "
+                "whole 128-lane row is not implemented")
 
     def _check_windowed(self) -> None:
         """``sliding_window``, ``heads_per_layer``, ``rope_by_kind``,
@@ -540,7 +628,7 @@ class ModelConfig:
         """The layers' operators are kept apart by kind in the parameter
         tree (``attn``, ``attn_window``, ``conv``, ``linear``), one entry a layer of the
         kind: their shapes differ."""
-        return self.hybrid or self.windowed or self.linear
+        return self.hybrid or self.windowed or self.linear or self.ssm
 
     def heads_of(self, l: int) -> int:
         """Query heads of layer ``l``."""
@@ -595,6 +683,75 @@ class ModelConfig:
         return self.layer_types is not None and "linear_attention" in self.layer_types
 
     @property
+    def ssm(self) -> bool:
+        """Some layers are Mamba-2 mixers: a state a SEQUENCE (a slot of the
+        slab) and no K/V."""
+        return self.layer_types is not None and "mamba" in self.layer_types
+
+    @property
+    def single_sublayer(self) -> bool:
+        """A block is a mixer OR a feed-forward alone, never both: what a
+        "mamba" or a "moe" layer in ``layer_types`` says of the whole stack."""
+        return bool({"mamba", "moe"} & set(self.layer_types or ()))
+
+    @property
+    def has_slab(self) -> bool:
+        """Some layers' cache entry is a slab indexed by a lane slot: a
+        sequence holds a slot while it runs (EngineCore), no block holds its
+        state, and the table carries the slot (model.split_slots)."""
+        return self.linear or self.ssm
+
+    @property
+    def slab_kind(self) -> str | None:
+        """The cache kind of the layers that keep a slab ("linear" or "ssm")."""
+        return "linear" if self.linear else "ssm" if self.ssm else None
+
+    @property
+    def ssm_inner(self) -> int:
+        """``d_in`` of a "mamba" layer: heads x head width."""
+        return self.ssm_num_heads * self.ssm_head_dim
+
+    @property
+    def ssm_channels(self) -> int:
+        """Channels of a "mamba" layer's depthwise convolution: ``[x | B | C]``."""
+        return self.ssm_inner + 2 * self.ssm_n_groups * self.ssm_state_size
+
+    @property
+    def sparse_layers(self) -> tuple[int, ...]:
+        """The layers that hold the dropless sparse MLP (entry ``i`` of the
+        ``moe`` group is layer ``sparse_layers[i]``'s): the "moe" blocks of a
+        ``single_sublayer`` stack, else every layer behind the leading
+        ``first_dense_layers``."""
+        if not self.shared_sparse:
+            return ()
+        if self.single_sublayer:
+            return self.layers_of("none")
+        return tuple(range(self.first_dense_layers, self.num_layers))
+
+    @property
+    def gated_mlp(self) -> bool:
+        """An expert has a gate: its first matrix is a fused ``[Wg | Wu]``."""
+        return self.mlp_activation == "swiglu"
+
+    @property
+    def expert_stored_width(self) -> int:
+        """Columns of a routed expert's first matrix (a gated one: of each
+        half) AS STORED: ``moe_intermediate_size``, but for an un-gated expert
+        rounded up to whole 128-lane rows (1,856 -> 1,920) with ZERO columns
+        and zero rows of ``w_down`` behind them, so that the stream and
+        grouped kernels (ops/expert_stream.py) take the layer. Exact:
+        ``relu(0)^2 = 0`` times a zero row. A layout, not a width: counts of
+        parameters and bytes stay at the published width."""
+        im = self.moe_intermediate_size
+        return im if self.gated_mlp else -(-im // 128) * 128
+
+    @property
+    def shared_expert_width(self) -> int:
+        """The shared expert's whole width."""
+        return (self.shared_expert_intermediate_size
+                or self.num_shared_experts * self.moe_intermediate_size)
+
+    @property
     def linear_channels(self) -> int:
         """Channels of a linear layer's depthwise convolution: ``[q | k |
         v]`` before it."""
@@ -605,8 +762,8 @@ class ModelConfig:
         """What layer ``l`` caches: "attention" (pages of K/V, or latent
         rows, for as long as the sequence lives), "conv" (state pages),
         "window" (pages of K/V in the window pool, held while a later query
-        may still see them) or "linear" (a slot of the slab a sequence:
-        :meth:`slab_shapes`)."""
+        may still see them), "linear" or "ssm" (a slot of the slab a sequence:
+        :meth:`slab_shapes`) or "none" (a feed-forward block: nothing)."""
         return "attention" if self.layer_types is None else _CACHE_KIND[self.layer_types[l]]
 
     def layers_of(self, kind: str) -> tuple[int, ...]:
@@ -713,14 +870,22 @@ class ModelConfig:
         return (block_size, 2 * self.cache_kv_heads, self.head_dim)
 
     def slab_shapes(self, slots: int) -> dict[str, tuple[int, ...]]:
-        """One linear layer's slab of ``slots`` lane slots (the last the
-        garbage slot): ``state [slots, H / p, dk, p dv]``, FLOAT32 whatever
+        """One slab layer's slab of ``slots`` lane slots (the last the
+        garbage slot). A "mamba" layer: ``state [slots, H, P, N]`` FLOAT32 and
+        ``conv [slots, K - 1, channels / 128, 128]`` at the model's dtype. A
+        linear layer: ``state [slots, H / p, dk, p dv]``, FLOAT32 whatever
         the model's dtype, ``p`` heads side by side in a tile so that its rows
         are whole 128-lane rows (ops/linear_attention.py, "The slab": 2 at the
         published ``dv`` 192), and ``conv [slots, K - 1, channels / 128,
         128]`` at the model's dtype, the convolution's newest input rows
         oldest first in whole 128-lane rows (as the "conv" page keeps its
         own)."""
+        if self.ssm:   # N is the minor dimension: whole lane rows at 128, no packing
+            ch, rows = self.ssm_channels, self.ssm_conv_kernel - 1
+            return {
+                "state": (slots, self.ssm_num_heads, self.ssm_head_dim, self.ssm_state_size),
+                "conv": (slots, rows, ch // 128, 128) if ch % 128 == 0 else (slots, rows, 1, ch),
+            }
         from dynamo_tpu.ops.linear_attention import heads_per_tile
 
         ch, rows = self.linear_channels, self.linear_conv_kernel_dim - 1
@@ -739,9 +904,10 @@ class ModelConfig:
     @property
     def cache_layer_counts(self) -> dict[str, int]:
         """``{"attention": n, "conv": n}``, as /health and /metrics give
-        it; with ``"window"`` / ``"linear"`` for a model that has such layers."""
+        it; with ``"window"`` / ``"linear"`` / ``"ssm"`` and ``"none"`` for a model
+        that has such layers."""
         kinds = ("attention", "conv") + (("window",) if self.windowed else ()) + (
-            ("linear",) if self.linear else ())
+            ("linear",) if self.linear else ()) + (("ssm", "none") if self.ssm else ())
         return {kind: self.cache_layers(kind) for kind in kinds}
 
     def window_bytes_per_sequence(self, block_size: int) -> int:
@@ -763,9 +929,14 @@ class ModelConfig:
                 * self.hidden_size * jnp.dtype(self.jax_dtype).itemsize)
 
     def state_bytes_per_sequence(self) -> int:
-        """Bytes of recurrent state one sequence holds over all linear
+        """Bytes of recurrent state one sequence holds over all slab
         layers, whatever its context: the float32 state and the
         convolution's rows (0 for a model without such layers)."""
+        if self.ssm:
+            return self.cache_layers("ssm") * (
+                self.ssm_inner * self.ssm_state_size * 4
+                + (self.ssm_conv_kernel - 1) * self.ssm_channels
+                * jnp.dtype(self.jax_dtype).itemsize)
         if not self.linear:
             return 0
         H, dk, dv = (self.linear_num_value_heads, self.linear_key_head_dim,
@@ -860,19 +1031,30 @@ class ModelConfig:
         return (2 * h * H * dk + 3 * h * H * dv + 2 * h * H
                 + self.linear_conv_kernel_dim * self.linear_channels + 2 * H + dv)
 
+    def _ssm_params(self) -> int:
+        """One "mamba" layer's mixer at its published widths: ``in_proj [h, 2
+        d_in + 2 G N + H]``, the taps and their bias, ``A_log``, ``D`` and
+        ``dt_bias`` ``[H]``, the gated norm ``[d_in]`` and ``out_proj [d_in,
+        h]``."""
+        h, H, d_in, ch = self.hidden_size, self.ssm_num_heads, self.ssm_inner, self.ssm_channels
+        return (h * (d_in + ch + H) + ch * (self.ssm_conv_kernel + self.ssm_conv_bias)
+                + 3 * H + d_in + d_in * h)
+
     def _mlp_params(self) -> int:
         """All layers' MLP weights as HELD here: a dense SwiGLU, the
         mixtral path's router and experts, or the sigmoid-routed layer's
         router (full width), held experts and shared experts behind
-        ``first_dense_layers`` dense ones."""
+        ``first_dense_layers`` dense ones (at the PUBLISHED expert width:
+        :attr:`expert_stored_width` is a layout)."""
         h, i, L = self.hidden_size, self.intermediate_size, self.num_layers
         if self.shared_sparse:
             im = self.moe_intermediate_size
+            mats = 3 if self.gated_mlp else 2
             sparse = (h * self.num_experts
                       + (self.num_experts if self.router_bias else 0)
-                      + (self.num_experts_held + self.num_shared_experts) * 3 * h * im)
+                      + mats * h * (self.num_experts_held * im + self.shared_expert_width))
             return (self.first_dense_layers * 3 * h * i
-                    + (L - self.first_dense_layers) * sparse)
+                    + len(self.sparse_layers) * sparse)
         if self.is_moe:
             return L * (h * self.num_experts + self.num_experts * 3 * h * i)
         return L * 3 * h * i
@@ -881,12 +1063,13 @@ class ModelConfig:
         """Parameter footprint at the configured dtype, of what this
         chip holds (``experts_held``)."""
         h, v = self.hidden_size, self.vocab_size
-        norms = (4 if self.sandwich_norm else 2) * h
+        norms = (4 if self.sandwich_norm else 1 if self.single_sublayer else 2) * h
         n_conv = len(self.layers_of("conv"))
         attn = sum(self._attn_params(l) for l in range(self.num_layers)
-                   if self.layer_kind(l) not in ("conv", "linear"))
+                   if self.layer_kind(l) in ("attention", "window"))
         total = (
             v * h + attn + len(self.layers_of("linear")) * self._linear_params()
+            + (len(self.layers_of("ssm")) * self._ssm_params() if self.ssm else 0)
             + n_conv * self._conv_params() + self.num_layers * norms
             + self._mlp_params() + h + (0 if self.tie_embeddings else h * v)
         )
@@ -1770,6 +1953,93 @@ def tiny_olmo_hybrid(vocab_size: int = 384) -> ModelConfig:
     )
 
 
+# ``hybrid_override_pattern`` "MEMEM*E": the period nemotron_h's 52 layers open
+# with five times over (M a Mamba-2 mixer, E experts, * attention).
+_NEMOTRON_PERIOD = ("mamba", "moe", "mamba", "moe", "mamba", "full_attention", "moe")
+
+
+def nemotron3_nano_ep2_14l() -> ModelConfig:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B (model_type "nemotron_h") as ONE chip
+    of a v5e-8 holds it, two chips sharing each layer's experts and four
+    pipeline stages the depth: layers 0-13 of the 52 (two periods ``MEMEM*E``:
+    6 Mamba-2 mixers of 64 heads x 64 with a 128-wide state in 8 groups, 6
+    expert blocks, 2 attention blocks of 32 heads over 2 KV heads of 128 with
+    NO rotary embedding), each block ONE sub-layer; experts 0-63 of each
+    layer's 128 (sigmoid scores, a bias on the choice, 6 a token, x 2.5,
+    ``relu(x Wu)^2 Wd`` 1,856 wide) beside a shared expert 3,712 wide; the
+    whole vocabulary untied. 9.87 GB in bf16 at the published widths (the
+    routed experts are STORED 1,920 wide: ``expert_stored_width``)."""
+    return ModelConfig(
+        name="nemotron-3-nano-30b-a3b-ep2-14l",
+        vocab_size=131072,
+        hidden_size=2688,
+        intermediate_size=1856,
+        num_layers=14,
+        num_heads=32,
+        num_kv_heads=2,
+        head_dim=128,
+        rope_theta=None,
+        rms_norm_eps=1e-5,
+        layer_types=2 * _NEMOTRON_PERIOD,
+        ssm_num_heads=64,
+        ssm_head_dim=64,
+        ssm_state_size=128,
+        ssm_n_groups=8,
+        ssm_conv_kernel=4,
+        ssm_chunk_size=128,
+        ssm_conv_bias=True,
+        num_experts=128,
+        num_experts_per_tok=6,
+        moe_intermediate_size=1856,
+        router_scoring="sigmoid",
+        routed_scaling_factor=2.5,
+        num_shared_experts=1,
+        shared_expert_intermediate_size=3712,
+        experts_held=(0, 2),
+        router_bias=True,
+        mlp_activation="relu2",
+    )
+
+
+def tiny_nemotron_h(vocab_size: int = 384, experts_held=(0, 2)) -> ModelConfig:
+    """nemotron_h's shape at test size: the period ``MEMEM*E`` of one-sub-layer
+    blocks (Mamba-2 of 4 heads x 16 with a 32-wide state in 2 groups, chunks
+    of 16 rows; attention of 2 heads over 1 KV head of 128, no rope; 8
+    experts, 2 a token, half of them held, ``relu^2`` 96 wide (stored 128)
+    beside a shared expert 192 wide); float32."""
+    return ModelConfig(
+        name="tiny-nemotron-h",
+        vocab_size=vocab_size,
+        hidden_size=256,
+        intermediate_size=96,
+        num_layers=7,
+        num_heads=2,
+        num_kv_heads=1,
+        head_dim=128,
+        rope_theta=None,
+        rms_norm_eps=1e-5,
+        dtype="float32",
+        layer_types=_NEMOTRON_PERIOD,
+        ssm_num_heads=4,
+        ssm_head_dim=16,
+        ssm_state_size=32,
+        ssm_n_groups=2,
+        ssm_conv_kernel=4,
+        ssm_chunk_size=16,
+        ssm_conv_bias=True,
+        num_experts=8,
+        num_experts_per_tok=2,
+        moe_intermediate_size=96,
+        router_scoring="sigmoid",
+        routed_scaling_factor=2.5,
+        num_shared_experts=1,
+        shared_expert_intermediate_size=192,
+        experts_held=experts_held,
+        router_bias=True,
+        mlp_activation="relu2",
+    )
+
+
 PRESETS = {
     "llama3-8b": llama3_8b,
     "llama3-70b": llama3_70b,
@@ -1783,6 +2053,7 @@ PRESETS = {
     "sdar-30b-a3b-6l": sdar_30b_a3b_6l,
     "mimo-v2.5-ep16-7l": mimo_v25_ep16_7l,
     "olmo-hybrid-7b-pp2-16l": olmo_hybrid_7b_pp2_16l,
+    "nemotron-3-nano-30b-a3b-ep2-14l": nemotron3_nano_ep2_14l,
     "tiny": tiny_model,
     "tiny-moe": tiny_moe,
     "tiny-loop": tiny_loop,
@@ -1792,4 +2063,5 @@ PRESETS = {
     "tiny-sdar": tiny_sdar,
     "tiny-mimo": tiny_mimo,
     "tiny-olmo-hybrid": tiny_olmo_hybrid,
+    "tiny-nemotron-h": tiny_nemotron_h,
 }

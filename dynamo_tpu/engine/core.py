@@ -50,7 +50,7 @@ from dynamo_tpu import tracing
 from dynamo_tpu.tracing import startclock
 from dynamo_tpu.tracing.stepclock import PHASES, StepClock
 from dynamo_tpu.engine.block_allocator import DeviceBlockAllocator, OutOfBlocksError
-from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+from dynamo_tpu.engine.config import SLAB_KINDS, EngineConfig, ModelConfig
 from dynamo_tpu.engine.fair_queue import FairQueue
 from dynamo_tpu.engine.prefill_cover import cheapest_cover
 from dynamo_tpu.runtime.engine import EngineOverloadedError
@@ -71,6 +71,7 @@ from dynamo_tpu.engine.programs import (  # noqa: F401
 )
 from dynamo_tpu.ops import grouped_matmul
 from dynamo_tpu.ops.linear_attention import traced_impl as linear_traced_impl
+from dynamo_tpu.ops.ssm import traced_impl as ssm_traced_impl
 from dynamo_tpu.ops.ragged_attention import traced_impl
 from dynamo_tpu.engine.sampler import hidden_at_most
 from dynamo_tpu.llm.kv_router.protocols import ForwardPassMetrics, KvStats, WorkerStats
@@ -467,7 +468,7 @@ class EngineCore(KvTransfer):
         # The free lane slots of a model with linear layers (None: no slab):
         # one a sequence that runs; the slab's last slot is the garbage slot.
         self._free_slots: list[int] | None = (
-            list(range(engine_cfg.max_num_seqs - 1, -1, -1)) if model_cfg.linear else None)
+            list(range(engine_cfg.max_num_seqs - 1, -1, -1)) if model_cfg.has_slab else None)
 
         self._inbox: deque[Sequence] = deque()   # thread-safe enqueue
         # Admission queue: per-tenant deficit-round-robin over prompt
@@ -1011,6 +1012,8 @@ class EngineCore(KvTransfer):
             attention=self.cfg.attention,
             **({"linear": linear_traced_impl("scan" if kind == "prefill" else "step")}
                if self.cfg.linear else {}),
+            **({"ssm": ssm_traced_impl("scan" if kind == "prefill" else "step")}
+               if self.cfg.ssm else {}),
             **self._window_traced(kind),
             **self._experts_traced(kind, padded, width),
             **attrs,
@@ -4065,7 +4068,7 @@ class EngineCore(KvTransfer):
                 scratch_engine = embed_engine = dataclasses.replace(
                     scratch_engine, num_kv_blocks=blocks, num_window_blocks=blocks,
                     max_model_len=blocks * bs)
-            if self.cfg.linear:   # the table is the scratch's blocks and the slot's column
+            if self.cfg.has_slab:   # the table is the scratch's blocks and the slot's column
                 scratch_engine = embed_engine = dataclasses.replace(
                     scratch_engine, num_kv_blocks=blocks, max_model_len=blocks * bs)
             # (a model with linear layers: one lane slot and the garbage slot)
@@ -4086,7 +4089,7 @@ class EngineCore(KvTransfer):
         if self.cfg.windowed:   # [full | first = 0 | window], the same blocks
             tables = np.concatenate(
                 [tables, np.zeros((1, 1), np.int32), tables], axis=1)
-        if self.cfg.linear:     # [blocks | lane slot 0]; position 0 reads zeros
+        if self.cfg.has_slab:   # [blocks | lane slot 0]; position 0 reads zeros
             tables = np.concatenate([tables, np.zeros((1, 1), np.int32)], axis=1)
         pooled, self._embed_scratch = self._embed_fn(
             self.params,
@@ -4181,7 +4184,9 @@ class EngineCore(KvTransfer):
         that kind's pool holds over all its layers."""
         bs = self.engine.block_size
         # (a linear layer's slab is indexed by lane slot: no block holds any of it)
-        kinds = [k for k, n in self.cfg.cache_layer_counts.items() if n and k != "linear"]
+        # (nor a feed-forward block's "none")
+        kinds = [k for k, n in self.cfg.cache_layer_counts.items()
+                 if n and k not in (*SLAB_KINDS, "none")]
 
         def block_bytes(kind: str) -> int:
             if kind == "conv":

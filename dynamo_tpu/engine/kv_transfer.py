@@ -229,7 +229,7 @@ class KvTransfer:
             raise UnsupportedModelOption(option, self.cfg.name, _TWO_SHAPES)
         if self.cfg.windowed:
             raise UnsupportedModelOption(option, self.cfg.name, _TWO_POOLS)
-        if self.cfg.linear:
+        if self.cfg.has_slab:
             raise UnsupportedModelOption(option, self.cfg.name, _LANE_STATE)
 
     @property

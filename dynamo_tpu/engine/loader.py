@@ -140,6 +140,61 @@ def _hybrid_linear_config(hf: dict) -> ModelConfig:
     )
 
 
+# Published model types whose blocks are ONE sub-layer by
+# ``hybrid_override_pattern``: a Mamba-2 mixer, attention without rope, or
+# sigmoid-routed un-gated experts (Nemotron-3-Nano).
+HYBRID_SSM_TYPES = ("nemotron_h",)
+_SSM_LETTERS = {"M": "mamba", "*": "full_attention", "E": "moe"}
+
+
+def _hybrid_ssm_config(hf: dict, experts_held) -> ModelConfig:
+    """The published keys as ``chipbench/architectures/nemotron_h.py`` reads
+    them."""
+    pattern = hf["hybrid_override_pattern"]
+    if (len(pattern) != hf["num_hidden_layers"] or set(pattern) - set(_SSM_LETTERS)
+            or hf.get("mlp_hidden_act", "relu2") != "relu2" or hf.get("attention_bias")
+            or hf.get("mlp_bias") or hf.get("mamba_proj_bias") or hf.get("use_bias")):
+        raise NotImplementedError(
+            f"{hf['model_type']}: hybrid_override_pattern {pattern!r} beside num_hidden_layers="
+            f"{hf['num_hidden_layers']}: each layer one of {sorted(_SSM_LETTERS)} (a '-' block "
+            "is not modelled), mlp_hidden_act relu2, and no bias in a projection")
+    return ModelConfig(
+        name=hf["model_type"],
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"],
+        rope_theta=None,
+        rms_norm_eps=hf.get("norm_eps", hf.get("layer_norm_epsilon", 1e-5)),
+        tie_embeddings=hf.get("tie_word_embeddings", False),
+        layer_types=tuple(_SSM_LETTERS[c] for c in pattern),
+        ssm_num_heads=hf["mamba_num_heads"],
+        ssm_head_dim=hf["mamba_head_dim"],
+        ssm_state_size=hf["ssm_state_size"],
+        ssm_n_groups=hf["n_groups"],
+        ssm_conv_kernel=hf["conv_kernel"],
+        ssm_chunk_size=hf["chunk_size"],
+        ssm_conv_bias=hf.get("use_conv_bias", True),
+        num_experts=hf["n_routed_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        router_scoring="sigmoid",
+        n_group=hf.get("n_group", 1),
+        topk_group=hf.get("topk_group", 1),
+        norm_topk_prob=hf.get("norm_topk_prob", True),
+        routed_scaling_factor=hf.get("routed_scaling_factor", 1.0),
+        num_shared_experts=hf.get("n_shared_experts", 0),
+        shared_expert_intermediate_size=(
+            hf.get("moe_shared_expert_intermediate_size", 0) if hf.get("n_shared_experts") else 0),
+        experts_held=experts_held,
+        router_bias=True,
+        mlp_activation="relu2",
+    )
+
+
 # Published model types whose attention layers are of two kinds by
 # ``layer_types``, full and sliding-window, with query heads per layer, rope
 # parameters per kind and a per-head output gate (Laguna).
@@ -304,10 +359,13 @@ def config_from_hf(path: str | Path, experts_held=None) -> ModelConfig:
         return _hybrid_conv_config(hf)
     if hf.get("model_type") in HYBRID_LINEAR_TYPES and experts_held is None:
         return _hybrid_linear_config(hf)
+    if hf.get("model_type") in HYBRID_SSM_TYPES:
+        return _hybrid_ssm_config(hf, experts_held)
     if experts_held is not None:
         raise ValueError(
-            f"experts_held={experts_held} for model_type {hf.get('model_type')!r}: "
-            f"only {LATENT_SPARSE_TYPES + WINDOWED_TYPES + WIDE_KEY_TYPES} state a share"
+            f"experts_held={experts_held} for model_type {hf.get('model_type')!r}: only "
+            f"{LATENT_SPARSE_TYPES + WINDOWED_TYPES + WIDE_KEY_TYPES + HYBRID_SSM_TYPES} "
+            "state a share"
         )
     head_dim = hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"]
     return ModelConfig(
@@ -535,6 +593,79 @@ def _load_hybrid_conv(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]
             "w_down": stack("feed_forward.w2", range(Ld)),
         }
     return params
+
+
+def _load_hybrid_ssm(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]:
+    """The tree of a model of one-sub-layer blocks (``model.init_params``:
+    ``layers`` the one norm a block, ``ssm`` / ``attn`` / ``moe`` one entry a
+    layer of that kind) from the checkpoint's names, ASSUMED from the family's
+    code (no checkpoint is here to try): ``backbone.embeddings``,
+    ``backbone.layers.N.norm`` and ``backbone.layers.N.mixer.*`` whatever the
+    block is: a Mamba-2 mixer's ``in_proj`` (``[z | x | B | C | dt]`` rows,
+    split into ``w_zx`` and ``w_dt``), ``conv1d.{weight [channels, 1, K],
+    bias}``, ``A_log``, ``D``, ``dt_bias``, ``norm`` and ``out_proj``; an
+    attention block's ``{q_proj, k_proj, v_proj, o_proj}`` (no rope, so no
+    permutation); an expert block's ``gate.{weight [E, h],
+    e_score_correction_bias}``, ``experts.E.{up_proj, down_proj}`` for the HELD
+    experts (stored with zero columns / rows up to
+    ``cfg.expert_stored_width``) and ``shared_experts.{up_proj, down_proj}``;
+    the final norm is ``backbone.norm_f``."""
+    np_dt = np.dtype(dt)
+
+    def t(key: str) -> np.ndarray:
+        return np.asarray(sd[key], np.float32)
+
+    def proj(l: int, name: str) -> np.ndarray:
+        return t(f"backbone.layers.{l}.mixer.{name}.weight").T  # [in, out]
+
+    def stack(name: str, layers, fix=lambda w: w) -> np.ndarray:
+        return np.asarray(np.stack([fix(proj(l, name)) for l in layers]), np_dt)
+
+    def leaves(name: str, layers, dtype=np_dt) -> np.ndarray:
+        return np.asarray(np.stack([t(f"backbone.layers.{l}.{name}") for l in layers]), dtype)
+
+    ssm, attn, moe = cfg.layers_of("ssm"), cfg.layers_of("attention"), cfg.sparse_layers
+    H = cfg.ssm_num_heads
+    lo, hi = cfg.experts_held_range
+    im, pad = cfg.moe_intermediate_size, cfg.expert_stored_width - cfg.moe_intermediate_size
+
+    def held(l: int, name: str, pad_axis: int) -> np.ndarray:
+        w = np.stack([proj(l, f"experts.{e}.{name}") for e in range(lo, hi)])   # [Eh, in, out]
+        width = [(0, 0)] * 3
+        width[pad_axis] = (0, pad)
+        return np.asarray(np.pad(w, width), np_dt)
+
+    return {
+        "embed": np.asarray(t("backbone.embeddings.weight"), np_dt),
+        "final_norm": np.asarray(t("backbone.norm_f.weight"), np_dt),
+        "layers": {"attn_norm": leaves("norm.weight", range(cfg.num_layers))},
+        "ssm": {
+            "w_zx": stack("in_proj", ssm, lambda w: w[:, :-H]),
+            "w_dt": stack("in_proj", ssm, lambda w: w[:, -H:]),
+            # published [channels, 1, K] -> [K, channels]: tap j, K - 1 - j back
+            "conv_w": np.asarray(np.stack(
+                [t(f"backbone.layers.{l}.mixer.conv1d.weight")[:, 0, :].T for l in ssm]), np_dt),
+            "conv_b": leaves("mixer.conv1d.bias", ssm),
+            "A_log": leaves("mixer.A_log", ssm, np.float32),
+            "D": leaves("mixer.D", ssm, np.float32),
+            "dt_bias": leaves("mixer.dt_bias", ssm, np.float32),
+            "ssm_norm": leaves("mixer.norm.weight", ssm),
+            "w_out": stack("out_proj", ssm),
+        },
+        "attn": {
+            "wqkv": np.asarray(_fuse_np(
+                [stack(n, attn) for n in ("q_proj", "k_proj", "v_proj")], tp), np_dt),
+            "wo": stack("o_proj", attn),
+        },
+        "moe": {
+            "w_router": stack("gate", moe),
+            "expert_bias": leaves("mixer.gate.e_score_correction_bias", moe, np.float32),
+            "w_gu": tuple(held(l, "up_proj", 2) for l in moe),
+            "w_down": tuple(held(l, "down_proj", 1) for l in moe),
+            "shared_wgu": stack("shared_experts.up_proj", moe),
+            "shared_down": stack("shared_experts.down_proj", moe),
+        },
+    }
 
 
 def _load_hybrid_linear(cfg: ModelConfig, sd: dict, dt, tp: int) -> dict[str, Any]:
@@ -814,22 +945,24 @@ def load_hf_llama(
         if quant is not None or tp != 1:
             raise NotImplementedError(
                 f"quant={quant!r} / tp={tp} for {cfg.name!r}: experts, latent "
-                "projections, conv and linear-attention operators and layers of more than one kind load "
+                "projections, conv, linear-attention and mamba operators and layers of more than one kind load "
                 "unquantised, in the tp=1 layout"
             )
         np_dt = np.dtype(dt)
         load = (_load_hybrid_conv if cfg.hybrid else
                 _load_hybrid_linear if cfg.linear else
+                _load_hybrid_ssm if cfg.ssm else
                 _load_wide_key if cfg.wide_key else
                 _load_windowed if cfg.windowed else
                 _load_block_sparse if cfg.block_length else _load_latent_sparse)
         params = load(cfg, sd, dt, tp)
         final_norm = "model.embedding_norm.weight" if cfg.hybrid else "model.norm.weight"
-        params.update({
-            "embed": np.asarray(t("model.embed_tokens.weight"), np_dt),
-            "final_norm": np.asarray(t(final_norm), np_dt),
-            "fuse_tp": np.asarray(tp, np.int32),
-        })
+        params["fuse_tp"] = np.asarray(tp, np.int32)
+        if not cfg.ssm:   # (nemotron_h names its trunk ``backbone``: _load_hybrid_ssm)
+            params.update({
+                "embed": np.asarray(t("model.embed_tokens.weight"), np_dt),
+                "final_norm": np.asarray(t(final_norm), np_dt),
+            })
         if not cfg.tie_embeddings:
             params["lm_head"] = np.asarray(t("lm_head.weight").T, np_dt)
         log.info("loaded %s: %d layers, vocab %d, routed experts [%d, %d) of %d",

@@ -65,7 +65,12 @@ v5e, round 2):
   gated delta rule keeps a float32 state a SEQUENCE, too large to keep a
   block; its cache entry is a slab indexed by a lane slot
   (:func:`linear_layer`, ops/linear_attention.py), the slot one more column
-  of the block table (:func:`split_slots`).
+  of the block table (:func:`split_slots`). A Mamba-2 ("mamba") layer keeps
+  its state the same way (:func:`ssm_layer`, ops/ssm.py).
+- **Blocks of one sub-layer** (``cfg.single_sublayer``): a block is a mixer OR
+  a feed-forward, ``x + f(norm(x))`` under ONE norm (``attn_norm``):
+  :func:`ssm_layer`, :func:`dense_layer` without its MLP, :func:`mlp_block`
+  (whose cache entry is empty: it caches nothing).
 """
 
 from __future__ import annotations
@@ -79,14 +84,14 @@ import jax
 import jax.numpy as jnp
 from jax import shard_map
 
-from dynamo_tpu.engine.config import EngineConfig, ModelConfig
+from dynamo_tpu.engine.config import SLAB_KINDS, EngineConfig, ModelConfig
 from dynamo_tpu.ops import expert_stream, grouped_matmul
 from dynamo_tpu.ops.gqa_attention import (
     gqa_decode_attention,
     gqa_ragged_attention,
     write_gqa_rows,
 )
-from dynamo_tpu.ops import linear_attention
+from dynamo_tpu.ops import linear_attention, ssm
 from dynamo_tpu.ops.latent_attention import (
     latent_decode_attention,
     latent_ragged_attention,
@@ -276,6 +281,8 @@ def init_params(rng: jax.Array, cfg: ModelConfig, tp: int = 1) -> Params:
         "attn_norm": jnp.ones((L, h), dt),
         "mlp_norm": jnp.ones((L, h), dt),
     }
+    if cfg.single_sublayer:   # ONE norm a block, varied so that its place shows
+        layers = {"attn_norm": _varied_ones(jax.random.fold_in(rng, 85), (L, h), dt)}
     extra: dict[str, Any] = {}
     if cfg.latent:
         layers.update(_init_latent_attention(rng, cfg, dense))
@@ -285,7 +292,7 @@ def init_params(rng: jax.Array, cfg: ModelConfig, tp: int = 1) -> Params:
         # A model with conv layers keeps its attention leaves apart, one
         # entry an ATTENTION layer (``attn``), beside ``conv``.
         La = len(cfg.layers_of("attention"))
-        attn = extra.setdefault("attn", {}) if cfg.hybrid or cfg.linear else layers
+        attn = extra.setdefault("attn", {}) if cfg.hybrid or cfg.has_slab else layers
         wq = dense(keys[1], (La, h, cfg.q_size), h)
         wk = dense(keys[2], (La, h, cfg.kv_size), h)
         wv = dense(keys[3], (La, h, cfg.kv_size), h)
@@ -302,6 +309,8 @@ def init_params(rng: jax.Array, cfg: ModelConfig, tp: int = 1) -> Params:
         extra["conv"] = _init_conv_operators(rng, cfg, dense)
     if cfg.linear:
         extra["linear"] = _init_linear_operators(rng, cfg, dense)
+    if cfg.ssm:
+        extra["ssm"] = _init_ssm_operators(rng, cfg, dense)
     if cfg.post_norm:   # the one norm a sub-layer: varied, so that its place shows
         for n, name in enumerate(("attn_norm", "mlp_norm")):
             layers[name] = _varied_ones(jax.random.fold_in(rng, 85 + n), (L, h), dt)
@@ -390,7 +399,7 @@ _QK_NORM_GAIN_BLOCKS = 1.6
 
 # The parameter group of each cache kind's operators (``cfg.layer_groups``).
 _GROUP_OF_KIND = {"attention": "attn", "window": "attn_window", "conv": "conv",
-                  "linear": "linear"}
+                  "linear": "linear", "ssm": "ssm"}
 # A gate's logits are drawn this many times the fan-in scale: on a normed
 # input they are then ~N(0, 1.4^2) and the gates sigmoid of them, spread
 # over (0.2, 0.8) and not all near 0.5, so that a gate dropped, or taken
@@ -526,6 +535,45 @@ def _init_linear_operators(rng: jax.Array, cfg: ModelConfig, dense) -> dict:
     }
 
 
+def _init_ssm_operators(rng: jax.Array, cfg: ModelConfig, dense) -> dict:
+    """The "mamba" layers' leaves, one entry a MAMBA layer. The published
+    ``in_proj [h, 2 d_in + 2 G N + H]`` (columns ``[z | x | B | C | dt]``) is
+    kept as TWO matrices, ``w_zx [h, d_in + channels]`` (``[z | x | B | C]``)
+    and ``w_dt [h, H]``: 10,304 columns are 80.5 lane rows, and the 64 of
+    ``dt`` feed float32 arithmetic of their own (as :func:`_init_linear_operators`
+    splits ``w_qkv``, ``w_z``, ``w_ba``). The depthwise taps ``conv_w [K,
+    channels]`` over ``[x | B | C]`` (tap ``j`` multiplies the input ``K - 1 -
+    j`` positions back: the published ``conv1d.weight[:, 0, j]``) and their
+    bias ``conv_b [channels]``; ``A_log``, ``D`` and ``dt_bias`` ``[H]``
+    float32; the gated norm ``ssm_norm [d_in]`` and ``w_out [d_in, h]``.
+
+    Drawn as the family initialises them: ``dt = softplus(dt_bias)``
+    log-uniform in [0.001, 0.1], ``A = exp(A_log)`` uniform in [1, 16] (the
+    decay ``exp(-A dt)`` then spreads from 0.2 to 0.999 head by head), the
+    convolution's bias uniform in +-``K^-0.5`` (a ``Conv1d``'s default), ``D``
+    around 1. The taps are drawn at ``K^-0.5`` each, unequal, so that their
+    order shows; every matrix at the fan-in scale."""
+    h, Ls, K = cfg.hidden_size, len(cfg.layers_of("ssm")), cfg.ssm_conv_kernel
+    H, d_in, ch = cfg.ssm_num_heads, cfg.ssm_inner, cfg.ssm_channels
+    key = lambda n: jax.random.fold_in(rng, 120 + n)  # noqa: E731
+    dt = jnp.exp(jax.random.uniform(
+        key(5), (Ls, H), jnp.float32, math.log(0.001), math.log(0.1)))
+    out = {
+        "w_zx": dense(key(0), (Ls, h, d_in + ch), h),
+        "w_dt": dense(key(1), (Ls, h, H), h),
+        "conv_w": dense(key(2), (Ls, K, ch), K),
+        "A_log": jnp.log(jax.random.uniform(key(6), (Ls, H), jnp.float32, 1.0, 16.0)),
+        "D": 1.0 + 0.1 * jax.random.normal(key(7), (Ls, H), jnp.float32),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),      # softplus^-1(dt)
+        "ssm_norm": _varied_ones(key(8), (Ls, d_in), cfg.jax_dtype),
+        "w_out": dense(key(9), (Ls, d_in, h), d_in),
+    }
+    if cfg.ssm_conv_bias:
+        out["conv_b"] = jax.random.uniform(
+            key(3), (Ls, ch), jnp.float32, -K ** -0.5, K ** -0.5).astype(cfg.jax_dtype)
+    return out
+
+
 def _init_latent_attention(rng: jax.Array, cfg: ModelConfig, dense) -> dict:
     """The latent attention's leaves, ``[L, ...]`` each: ``wq_a [h, rq]``
     with ``q_norm [rq]``, ``wq_b [rq, H (dn + dr)]`` (per head nope then
@@ -551,6 +599,23 @@ def _init_latent_attention(rng: jax.Array, cfg: ModelConfig, dense) -> dict:
     }
 
 
+# What an UN-GATED expert's down-projection is drawn under a gated one's by:
+# on unit-variance inputs ``relu(u)^2`` has a root-mean-square of sqrt(3/2) =
+# 1.22 where ``silu(g) * u`` has 0.60, so at one scale of weights a relu^2
+# expert's term is twice a SwiGLU's, and a term that enters or leaves the
+# chosen k moves the stream by twice as much. From a control on the v5e
+# (PERF.md section 6, PR 54: nemotron_h's cell, 64 of 128 experts held, 6 a
+# token beside a shared expert, the cell's own comparison at 320 + 33 tokens,
+# two seeds, tolerance 0.15 untouched; max |diff| sound / routed scaling
+# factor left out / bias on the choice left out): at 1 / k 0.105-0.130 /
+# 0.38-0.51 / 0.48-0.51 (too near the tolerance: half the experts are held,
+# so every second flip of a token's 6th and 7th expert shows), at 1 / 2k
+# **0.042-0.043 / 0.158-0.167 / 0.21-0.29**, at 1 / 3k 0.043 / 0.10-0.13
+# (the scaling factor no longer caught), at 1 / 4k 0.037-0.044 / 0.087-0.100 /
+# 0.12-0.13; with the routed terms zeroed 0.028-0.038.
+_RELU2_DOWN_DIVISOR = 2
+
+
 def _routed_down_divisor(cfg: ModelConfig) -> int:
     """What a routed expert's down-projection is drawn UNDER the fan-in
     scale by (:func:`_init_shared_sparse_mlp` says why it is drawn under
@@ -565,8 +630,10 @@ def _routed_down_divisor(cfg: ModelConfig) -> int:
     probes read 0.067-0.121 and the zeroed terms 0.186-0.265, caught; at
     ``1 / 4k`` sound 0.051-0.101 and the zeroed terms 0.106-0.122, NOT
     caught: the largest scale that passes is the smallest that still
-    sees the layer."""
-    return cfg.num_experts_per_tok * (1 if cfg.num_shared_experts else 2)
+    sees the layer. Twice either for an un-gated ``relu^2`` expert
+    (:data:`_RELU2_DOWN_DIVISOR`)."""
+    return (cfg.num_experts_per_tok * (1 if cfg.num_shared_experts else 2)
+            * (1 if cfg.gated_mlp else _RELU2_DOWN_DIVISOR))
 
 
 def _init_shared_sparse_mlp(rng: jax.Array, cfg: ModelConfig, dense, tp: int) -> dict:
@@ -605,24 +672,28 @@ def _init_shared_sparse_mlp(rng: jax.Array, cfg: ModelConfig, dense, tp: int) ->
     leaf keep the fan-in scale."""
     h, i, im = cfg.hidden_size, cfg.intermediate_size, cfg.moe_intermediate_size
     Ld = cfg.first_dense_layers
-    Ls = cfg.num_layers - Ld
+    Ls = len(cfg.sparse_layers)
     lo, hi = cfg.experts_held_range
     ns = cfg.num_shared_experts
     key = lambda n: jax.random.fold_in(rng, 60 + n)  # noqa: E731
+    # An un-gated expert's two matrices are drawn at the published width and
+    # STORED with zero columns / rows behind it (``cfg.expert_stored_width``).
+    pad = cfg.expert_stored_width - im
+    halves = 2 if cfg.gated_mlp else 1
 
-    def experts(k, shape, fan_in):  # Ls x [Eh, ...], expert e from key e
-        return tuple(
-            jnp.stack([
+    def experts(k, shape, fan_in, pad_axis):  # Ls x [Eh, ...], expert e from key e
+        def layer(s):
+            w = jnp.stack([
                 dense(jax.random.fold_in(jax.random.fold_in(k, e), s), shape, fan_in)
                 for e in range(lo, hi)
             ])
-            for s in range(Ls)
-        )
+            return jnp.pad(w, [(0, pad * (a == pad_axis)) for a in range(3)]) if pad else w
+        return tuple(layer(s) for s in range(Ls))
 
     out = {"moe": {
         "w_router": dense(key(0), (Ls, h, cfg.num_experts), h),
-        "w_gu": experts(key(1), (h, 2 * im), h),
-        "w_down": experts(key(2), (im, h), im * _routed_down_divisor(cfg) ** 2),
+        "w_gu": experts(key(1), (h, halves * im), h, pad_axis=2),
+        "w_down": experts(key(2), (im, h), im * _routed_down_divisor(cfg) ** 2, pad_axis=1),
     }}
     if cfg.router_bias:
         # Non-zero, so that it changes the choice for a measurable share
@@ -631,8 +702,9 @@ def _init_shared_sparse_mlp(rng: jax.Array, cfg: ModelConfig, dense, tp: int) ->
         out["moe"]["expert_bias"] = 0.05 * jax.random.normal(
             key(8), (Ls, cfg.num_experts), jnp.float32)
     if ns:
-        out["moe"]["shared_wgu"] = dense(key(3), (Ls, h, 2 * ns * im), h)
-        out["moe"]["shared_down"] = dense(key(4), (Ls, ns * im, h), ns * im)
+        sw = cfg.shared_expert_width
+        out["moe"]["shared_wgu"] = dense(key(3), (Ls, h, halves * sw), h)
+        out["moe"]["shared_down"] = dense(key(4), (Ls, sw, h), sw)
     if Ld:
         out["dense_mlp"] = {
             "wgu": fuse_gu(dense(key(5), (Ld, h, i), h), dense(key(6), (Ld, h, i), h), tp),
@@ -644,19 +716,22 @@ def _init_shared_sparse_mlp(rng: jax.Array, cfg: ModelConfig, dense, tp: int) ->
 def layer_params(params: Params, l: int, cfg: ModelConfig) -> dict:
     """Layer ``l``'s leaves: ``params["layers"]`` at ``l``; where the
     layers are of two kinds (``cfg.layer_groups``), its operator's from
-    ``attn``, ``attn_window``, ``conv`` or ``linear`` at its index among its kind; and, where the
-    MLPs are kept apart (``cfg.shared_sparse``), the dense MLP of a
-    leading layer or the sparse one of the others."""
+    ``attn``, ``attn_window``, ``conv``, ``linear`` or ``ssm`` at its index among its kind; and,
+    where the MLPs are kept apart (``cfg.shared_sparse``), the dense MLP of a
+    leading layer or the sparse one of ``cfg.sparse_layers``."""
     lp = jax.tree.map(lambda a: a[l], params["layers"])
-    if cfg.layer_groups:
-        kind = cfg.layer_kind(l)
+    kind = cfg.layer_kind(l)
+    if cfg.layer_groups and kind in _GROUP_OF_KIND:   # ("none": a feed-forward block)
         at = cfg.layers_of(kind).index(l)
         lp.update({k: v[at] for k, v in params[_GROUP_OF_KIND[kind]].items()})
-    if cfg.shared_sparse:
-        Ld = cfg.first_dense_layers
-        group, at = ("dense_mlp", l) if l < Ld else ("moe", l - Ld)
-        # ``v[at]``: a stacked leaf's slice, or the experts' own array
-        lp.update({k: v[at] for k, v in params[group].items()})
+    if l in cfg.sparse_layers:
+        group, at = "moe", cfg.sparse_layers.index(l)
+    elif l < cfg.first_dense_layers:
+        group, at = "dense_mlp", l
+    else:   # a mixer block of a one-sub-layer stack, or a model of dense MLPs in ``layers``
+        return lp
+    # ``v[at]``: a stacked leaf's slice, or the experts' own array
+    lp.update({k: v[at] for k, v in params[group].items()})
     return lp
 
 
@@ -734,21 +809,26 @@ def cache_for_blocks(cfg: ModelConfig, engine: EngineConfig, blocks: int, dtype=
     state (:func:`conv_layer`), an attention layer's its K/V. A WINDOW
     layer's array is the window pool's: ``window_blocks`` blocks (as many
     as ``blocks`` where not given) and a garbage page, under block ids of
-    that pool's own. A LINEAR layer's entry is no page array but its slab,
-    ``{"state", "conv"}`` of ``slots`` lane slots (``engine.state_slots``
-    where not given; ``ModelConfig.slab_shapes``), the state float32."""
+    that pool's own. A LINEAR or MAMBA layer's entry is no page array but its
+    slab, ``{"state", "conv"}`` of ``slots`` lane slots (``engine.state_slots``
+    where not given; ``ModelConfig.slab_shapes``), the state float32; a
+    feed-forward block's (``layer_kind`` "none") is ``{}``, no leaf at all."""
     dtype = dtype or cfg.jax_dtype
     window_blocks = blocks if window_blocks is None else window_blocks
-    if cfg.linear:
+    if cfg.has_slab:
         if engine.kv_quantized:
             _refuse_int8_latent(cfg)
         slab = cfg.slab_shapes(engine.state_slots if slots is None else slots)
-        return tuple(
-            {"state": jnp.zeros(slab["state"], jnp.float32),
-             "conv": jnp.zeros(slab["conv"], dtype)}
-            if cfg.layer_kind(l) == "linear"
-            else jnp.zeros((blocks + 1, *cfg.kv_page_tail(engine.block_size)), dtype)
-            for l in range(cfg.num_layers))
+
+        def entry(kind: str):
+            if kind in SLAB_KINDS:
+                return {"state": jnp.zeros(slab["state"], jnp.float32),
+                        "conv": jnp.zeros(slab["conv"], dtype)}
+            if kind == "none":   # a feed-forward block caches nothing: no leaf
+                return {}
+            return jnp.zeros((blocks + 1, *cfg.kv_page_tail(engine.block_size)), dtype)
+
+        return tuple(entry(cfg.layer_kind(l)) for l in range(cfg.num_layers))
     shapes = []
     for l in range(cfg.num_layers):
         kind = cfg.layer_kind(l)
@@ -794,10 +874,10 @@ def init_cache_stacked(
 
 
 def _refuse_int8_latent(cfg: ModelConfig) -> None:
-    if cfg.linear:
+    if cfg.has_slab:
         raise NotImplementedError(
-            "kv_dtype='int8' with linear_attention layers: the full layers' pages "
-            "beside a float32 slab were not compared as int8")
+            "kv_dtype='int8' with linear_attention or mamba layers: the full layers' "
+            "pages beside a float32 slab were not compared as int8")
     if cfg.windowed:
         raise NotImplementedError(
             "kv_dtype='int8' with sliding_attention layers: the window pool's "
@@ -967,12 +1047,13 @@ def expert_call_shape(rows: int) -> str:
     return "wave" if rows > _EXPERTS_ALL_ROWS_MAX else "step"
 
 
-def wave_impl(backend: str, dtype, rows_a_group: float, w_gu, w_down) -> str:
+def wave_impl(backend: str, dtype, rows_a_group: float, w_gu, w_down,
+              gated: bool = True) -> str:
     """The implementation a wave's chosen pairs get (the ``impl`` of
     :func:`_experts_grouped`; ``grouped/<it>`` is the call's label):
     ``"stream"`` where ``ops/expert_stream.py:grouped_impl`` takes the call,
     else ``ops/grouped_matmul.py:impl``'s ``"pallas"`` or ``"ragged_dot"``."""
-    return (expert_stream.grouped_impl(backend, dtype, rows_a_group, w_gu, w_down)
+    return (expert_stream.grouped_impl(backend, dtype, rows_a_group, w_gu, w_down, gated)
             or grouped_matmul.impl(backend, dtype, w_gu, w_down))
 
 
@@ -1037,11 +1118,16 @@ def route_softmax(xf: jax.Array, w_router: jax.Array, cfg: ModelConfig,
 _ROUTERS = {"sigmoid": route_sigmoid, "softmax": route_softmax}
 
 
-def _swiglu(x, w_gu, w_down):
-    """``(silu(x Wg) * (x Wu)) Wd`` with ``w_gu = [Wg | Wu]``; float32 out."""
+def _swiglu(x, w_gu, w_down, gated: bool = True):
+    """``(silu(x Wg) * (x Wu)) Wd`` with ``w_gu = [Wg | Wu]``; float32 out.
+    Not ``gated`` (``cfg.mlp_activation`` "relu2"): ``relu(x Wu)^2 Wd`` with
+    ``w_gu = Wu`` alone."""
     gu = jnp.dot(x, w_gu, preferred_element_type=jnp.float32)
-    g, u = jnp.split(gu, 2, axis=-1)
-    act = (jax.nn.silu(g) * u).astype(x.dtype)
+    if gated:
+        g, u = jnp.split(gu, 2, axis=-1)
+        act = (jax.nn.silu(g) * u).astype(x.dtype)
+    else:
+        act = jnp.square(jnp.maximum(gu, 0.0)).astype(x.dtype)
     return jnp.dot(act, w_down, preferred_element_type=jnp.float32)
 
 
@@ -1056,7 +1142,7 @@ def _swiglu(x, w_gu, w_down):
 _EXPERTS_LOOP_UNROLL = 4
 
 
-def _experts_all_rows(xf, w_held, w_gu, w_down):
+def _experts_all_rows(xf, w_held, w_gu, w_down, gated: bool = True):
     """Every held expert on every row, the rows not routed to it weighted
     zero: ``[N, h]`` float32. The same bytes and operations whatever the
     routing. The DEFINITION of a step's expert layer, and its path on every
@@ -1069,12 +1155,13 @@ def _experts_all_rows(xf, w_held, w_gu, w_down):
     published widths) beside the weights for the whole megastep. ONE
     ``fori_loop`` over the experts whose body indexes their arrays (the
     slice fuses into the products: nothing is copied), however many are
-    held."""
+    held. ``gated``: :func:`_swiglu`'s."""
     def body(e, out):
         y = _swiglu(
             xf,
             jax.lax.dynamic_index_in_dim(w_gu, e, keepdims=False),
             jax.lax.dynamic_index_in_dim(w_down, e, keepdims=False),
+            gated,
         )
         return out + jax.lax.dynamic_slice_in_dim(w_held, e, 1, axis=1) * y
 
@@ -1133,9 +1220,9 @@ def _slab_places(places: int, h: int, im: int, itemsize: int, tile: int) -> int:
     return -(-places // (slabs * tile)) * tile
 
 
-@functools.partial(jax.jit, static_argnames=("k", "impl", "all_held"))
+@functools.partial(jax.jit, static_argnames=("k", "impl", "all_held", "gated"))
 def _experts_grouped(xf, w_held, chosen_held, w_gu, w_down, *, k: int, impl: str,
-                     all_held: bool):
+                     all_held: bool, gated: bool = True):
     """Each chosen (row, held expert) pair and nothing else, dropless at
     any skew: the pairs sorted by expert (:func:`_sorted_pairs`), then a
     slab of the sorted places at a time (:func:`_slab_places`; one slab
@@ -1154,12 +1241,14 @@ def _experts_grouped(xf, w_held, chosen_held, w_gu, w_down, *, k: int, impl: str
     are bf16 and the sums float32 as :func:`_swiglu`'s. ``k``: pairs a row
     at most (the experts a token, or all that are held if fewer);
     ``all_held``: the chip holds every expert the router chooses among, so
-    each row's ``k`` pairs are all here. Jitted, so that the sparse layers
-    of a program trace it once. ``[N, h]`` float32."""
+    each row's ``k`` pairs are all here; ``gated``: :func:`_swiglu`'s (an
+    un-gated expert's activation is ``relu(.)^2`` of ONE product). Jitted, so
+    that the sparse layers of a program trace it once. ``[N, h]`` float32."""
     N, h = xf.shape
     Eh = w_gu.shape[0]
     stream = impl == "stream"
     align = expert_stream.GROUP_ALIGN if stream else 1
+    # (an un-gated expert's temporaries are counted as a gated one's: more slabs, never fewer)
     S = _slab_places(N * k + (align - 1) * Eh, h, w_down.shape[1], xf.dtype.itemsize,
                      expert_stream.SLAB_ROWS if stream else grouped_matmul.tile_rows(impl))
     rows, counts, place, weight = _sorted_pairs(chosen_held, w_held, k, S, align)
@@ -1173,11 +1262,15 @@ def _experts_grouped(xf, w_held, chosen_held, w_gu, w_down, *, k: int, impl: str
         first = jnp.clip(start, lo, lo + S)
         sizes = jnp.clip(end, lo, lo + S) - first
         if stream:
-            y = expert_stream.expert_stream_grouped(x, first - lo, sizes, w_gu, w_down)
+            y = expert_stream.expert_stream_grouped(x, first - lo, sizes, w_gu, w_down,
+                                                    gated=gated)
         else:
             gu = grouped_matmul.grouped_matmul(x, w_gu, sizes, impl=impl)
-            g, u = jnp.split(gu, 2, axis=-1)
-            act = (jax.nn.silu(g) * u).astype(xf.dtype)
+            if gated:
+                g, u = jnp.split(gu, 2, axis=-1)
+                act = (jax.nn.silu(g) * u).astype(xf.dtype)
+            else:
+                act = jnp.square(jnp.maximum(gu, 0.0)).astype(xf.dtype)
             y = grouped_matmul.grouped_matmul(act, w_down, sizes, impl=impl)
         # a place of another slab, or past the groups, is none here: never computed
         at = place - lo
@@ -1192,7 +1285,8 @@ def _experts_grouped(xf, w_held, chosen_held, w_gu, w_down, *, k: int, impl: str
 
 def _shared_sparse_mlp(x, lp, cfg: ModelConfig, row_valid=None,
                        expert_stats: list | None = None):
-    """``sum_e w_e SwiGLU_e(x) + SwiGLU_shared(x)`` over THIS chip's share
+    """``sum_e w_e SwiGLU_e(x) + SwiGLU_shared(x)`` (``relu(x Wu)^2 Wd`` for
+    each where ``cfg.mlp_activation`` is "relu2") over THIS chip's share
     of the routed experts (``cfg.experts_held``): the router runs at its
     full width, only the held experts' terms are added (what the absent
     ones would add is left out, and that partial result goes on), the
@@ -1208,6 +1302,7 @@ def _shared_sparse_mlp(x, lp, cfg: ModelConfig, row_valid=None,
     xf = x.reshape(-1, shape[-1])
     N = xf.shape[0]
     lo, hi = cfg.experts_held_range
+    gated = cfg.gated_mlp
     with jax.named_scope("router"):
         weights, chosen = _ROUTERS[cfg.router_scoring](
             xf, lp["w_router"], cfg, bias=lp.get("expert_bias"))
@@ -1222,9 +1317,9 @@ def _shared_sparse_mlp(x, lp, cfg: ModelConfig, row_valid=None,
         counts = jnp.sum(chosen_held, axis=0, dtype=jnp.int32)
         grouped = wave_impl(
             backend, xf.dtype, N * cfg.num_experts_per_tok / cfg.num_experts,
-            lp["w_gu"], lp["w_down"]) if shape_name == "wave" else None
+            lp["w_gu"], lp["w_down"], gated) if shape_name == "wave" else None
         path = f"grouped/{grouped}" if grouped else expert_stream.impl(
-            backend, xf.dtype, N, lp["w_gu"], lp["w_down"])
+            backend, xf.dtype, N, lp["w_gu"], lp["w_down"], gated)
         grouped_matmul.count_traced(shape_name, path)
         if expert_stats is not None:
             if grouped == "stream":
@@ -1241,14 +1336,15 @@ def _shared_sparse_mlp(x, lp, cfg: ModelConfig, row_valid=None,
         if grouped:
             out = _experts_grouped(
                 xf, w_held, chosen_held, lp["w_gu"], lp["w_down"],
-                k=k, impl=grouped, all_held=Eh == cfg.num_experts)
+                k=k, impl=grouped, all_held=Eh == cfg.num_experts, gated=gated)
         elif path == "stream/pallas":
-            out = expert_stream.expert_stream(xf, w_held, lp["w_gu"], lp["w_down"])
+            out = expert_stream.expert_stream(xf, w_held, lp["w_gu"], lp["w_down"],
+                                              gated=gated)
         else:
-            out = _experts_all_rows(xf, w_held, lp["w_gu"], lp["w_down"])
+            out = _experts_all_rows(xf, w_held, lp["w_gu"], lp["w_down"], gated)
     if "shared_wgu" in lp:
         with jax.named_scope("shared_expert"):
-            out = out + _swiglu(xf, lp["shared_wgu"], lp["shared_down"])
+            out = out + _swiglu(xf, lp["shared_wgu"], lp["shared_down"], gated)
     return out.astype(x.dtype).reshape(shape)
 
 
@@ -1897,14 +1993,66 @@ def conv_layer(
 
 
 def split_slots(block_tables: jax.Array, cfg: ModelConfig, engine: EngineConfig):
-    """A model with linear layers sends each sequence's lane SLOT as one more
-    column of its block table, ``[S, P + 1]`` (``P =
+    """A model with slab layers (linear or mamba) sends each sequence's lane
+    SLOT as one more column of its block table, ``[S, P + 1]`` (``P =
     engine.max_blocks_per_seq``; ``EngineCore._table_row``): ``(tables [S,
     P], slots [S])``. Every other model: ``(tables, None)``."""
-    if not cfg.linear:
+    if not cfg.has_slab:
         return block_tables, None
     P = engine.max_blocks_per_seq
     return block_tables[:, :P], block_tables[:, P]
+
+
+def _slab_conv(pre, rows, slots, positions, write_pages, cu_q_lens, w, bias,
+               engine: EngineConfig):
+    """The depthwise causal convolution of a slab layer (linear or mamba) over
+    ``pre [T, channels]`` (the model dtype), then SiLU: ``silu(bias + sum_j w_j
+    pre_{t-K+1+j})``, zeros before position 0. ``rows [slots, K - 1, channels
+    / 128, 128]``: the slab's newest input rows a lane slot, oldest first;
+    ``w [K, channels]``, ``bias [channels]`` or None. A sequence's first
+    row (position 0) and every row of the garbage slot read zeros by a
+    select; a dead decode lane (its K/V row goes to the garbage page) is sent
+    to the garbage slot. Returns ``(c [T, channels] float32, rows, slots,
+    fresh [S])`` with ``slots`` and ``fresh`` as the state's update must use
+    them."""
+    T, K = pre.shape[0], w.shape[0]
+    decode = cu_q_lens is None
+    garbage = rows.shape[0] - 1
+    if decode:   # a dead lane (active false: its K/V row goes to the garbage page)
+        slots = jnp.where(write_pages == engine.garbage_block, garbage, slots)
+        start_pos, q_len = positions, None
+    else:
+        starts, ends = cu_q_lens[:-1], cu_q_lens[1:]
+        q_len = ends - starts
+        start_pos = positions[jnp.minimum(starts, T - 1)]
+    fresh = (start_pos == 0) | (slots == garbage)
+    old = linear_attention.zero_where_fresh(rows[slots], fresh)
+    old = old.reshape(old.shape[0], K - 1, -1)                # [S, K-1, channels]
+    w = w.astype(jnp.float32)                                 # [K, channels]
+    c = w[K - 1] * pre.astype(jnp.float32)
+    for j in range(1, K):                                     # the input, j rows back
+        if decode:
+            prev = old[:, K - 1 - j]
+        else:
+            prev = jnp.roll(pre, j, axis=0)
+            for i in range(j):   # row i of a sequence's chunk: from the slab
+                at = jnp.where(i < q_len, starts + i, T)
+                prev = prev.at[at].set(old[:, K - 1 + i - j], mode="drop")
+        c = c + w[K - 1 - j] * prev.astype(jnp.float32)
+    if bias is not None:
+        c = c + bias.astype(jnp.float32)
+    c = jax.nn.silu(c)
+    if decode:
+        new = jnp.concatenate([old[:, 1:], pre[:, None]], axis=1)
+    else:   # the K - 1 newest rows at the chunk's end: the chunk's, else the slab's
+        m = jnp.arange(K - 1, dtype=jnp.int32)[None, :]
+        r = q_len[:, None] - (K - 1) + m                      # chunk row of new row m
+        mine = pre[jnp.clip(starts[:, None] + r, 0, T - 1)]
+        kept = jnp.take_along_axis(
+            old, jnp.clip(m + q_len[:, None], 0, K - 2)[..., None], axis=1)
+        new = jnp.where((r >= 0)[..., None], mine, kept)
+    rows = rows.at[slots].set(new.reshape(new.shape[0], *rows.shape[1:]))
+    return c, rows, slots, fresh
 
 
 def linear_layer(
@@ -1962,12 +2110,10 @@ def linear_layer(
     ``attn`` > ``linear/gate_norm``, ``o_proj`` > ``linear/out_proj``, then
     the MLP's."""
     T = x.shape[0]
-    K = cfg.linear_conv_kernel_dim
     H, dk, dv = cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
     dt = lp["attn_norm"].dtype
     decode = cu_q_lens is None
     state, rows = slab["state"], slab["conv"]
-    garbage = state.shape[0] - 1
 
     scope = functools.partial(_operator_scope, "linear")
 
@@ -1979,38 +2125,8 @@ def linear_layer(
         beta = jax.nn.sigmoid(ba[:, :H]) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
         g = -jnp.exp(lp["A_log"]) * jax.nn.softplus(ba[:, H:] + lp["dt_bias"])   # log alpha
     with scope("kv_write", "conv"):
-        if decode:   # a dead lane (active false: its K/V row goes to the garbage page)
-            slots = jnp.where(write_pages == engine.garbage_block, garbage, slots)
-            start_pos, q_len = positions, None
-        else:
-            starts, ends = cu_q_lens[:-1], cu_q_lens[1:]
-            q_len = ends - starts
-            start_pos = positions[jnp.minimum(starts, T - 1)]
-        fresh = (start_pos == 0) | (slots == garbage)
-        old = linear_attention.zero_where_fresh(rows[slots], fresh)
-        old = old.reshape(old.shape[0], K - 1, -1)                # [S, K-1, channels]
-        w = lp["conv_w"].astype(jnp.float32)                      # [K, channels]
-        c = w[K - 1] * pre.astype(jnp.float32)
-        for j in range(1, K):                                     # the input, j rows back
-            if decode:
-                prev = old[:, K - 1 - j]
-            else:
-                prev = jnp.roll(pre, j, axis=0)
-                for i in range(j):   # row i of a sequence's chunk: from the slab
-                    at = jnp.where(i < q_len, starts + i, T)
-                    prev = prev.at[at].set(old[:, K - 1 + i - j], mode="drop")
-            c = c + w[K - 1 - j] * prev.astype(jnp.float32)
-        c = jax.nn.silu(c)
-        if decode:
-            new = jnp.concatenate([old[:, 1:], pre[:, None]], axis=1)
-        else:   # the K - 1 newest rows at the chunk's end: the chunk's, else the slab's
-            m = jnp.arange(K - 1, dtype=jnp.int32)[None, :]
-            r = q_len[:, None] - (K - 1) + m                      # chunk row of new row m
-            mine = pre[jnp.clip(starts[:, None] + r, 0, T - 1)]
-            kept = jnp.take_along_axis(
-                old, jnp.clip(m + q_len[:, None], 0, K - 2)[..., None], axis=1)
-            new = jnp.where((r >= 0)[..., None], mine, kept)
-        rows = rows.at[slots].set(new.reshape(new.shape[0], *rows.shape[1:]))
+        c, rows, slots, fresh = _slab_conv(
+            pre, rows, slots, positions, write_pages, cu_q_lens, lp["conv_w"], None, engine)
         q = linear_attention.l2_normalize(c[:, :H * dk].reshape(T, H, dk), 1e-6) * dk ** -0.5
         k = linear_attention.l2_normalize(c[:, H * dk:2 * H * dk].reshape(T, H, dk), 1e-6)
         v = c[:, 2 * H * dk:].reshape(T, H, dv)
@@ -2034,6 +2150,77 @@ def linear_layer(
     return x, {"state": state, "conv": rows}
 
 
+def ssm_layer(
+    x: jax.Array,            # [T, h]
+    lp: dict,                # ONE mamba layer's params (:func:`layer_params`)
+    slab: dict,              # ONE layer's slab {"state", "conv"} (cache_for_blocks)
+    positions: jax.Array,
+    write_pages: jax.Array,
+    slots: jax.Array,        # [S] i32: each sequence's lane slot
+    cu_q_lens: jax.Array | None,  # None: the decode shape (one row a sequence)
+    cfg: ModelConfig,
+    engine: EngineConfig,
+) -> tuple[jax.Array, dict]:
+    """One block that is a Mamba-2 mixer ALONE, ``x + mixer(norm(x))``, over a
+    ragged token batch (``H`` heads of ``P`` channels, ``d_in = H P``, a state
+    ``N`` wide, ``G`` groups, ``K`` taps): ``[z | xBC] = u W_zx`` and ``dt~ = u
+    W_dt`` (the published ``in_proj``, split: :func:`_init_ssm_operators`); a
+    depthwise causal convolution of ``K`` taps with a bias over ``xBC``, then
+    SiLU (:func:`_slab_conv`); ``xBC`` split into ``x [H, P]``, ``B [G, N]``,
+    ``C [G, N]``; ``dt = softplus(dt~ + dt_bias)`` per head in float32, NOT
+    clamped, ``log a = -exp(A_log) dt``; the state's recurrence and its
+    read-out ``y = S C + D x`` (ops/ssm.py); ``y * silu(z)``, THEN an RMSNorm
+    over each group of ``d_in / G`` channels under one weight ``[d_in]`` (the
+    gate before the norm); ``W_out``. No MLP: the feed-forward is a block of
+    its own (:func:`mlp_block`).
+
+    **The state** lives in the layer's SLAB exactly as :func:`linear_layer`'s
+    does (``state [slots, H, P, N]`` float32, ``conv [slots, K - 1, channels /
+    128, 128]``), under the same invariant: nothing written past a cursor is
+    read by a sequence that goes on; a fresh or garbage slot reads zeros by a
+    select.
+
+    Scopes, each inside the dense layer's stage it stands for: ``qkv`` >
+    ``ssm/in_proj``, ``kv_write`` > ``ssm/conv``, ``attn`` > ``ssm/ssd_step``
+    (decode) or ``ssm/ssd_scan`` (ragged), ``attn`` > ``ssm/gate_norm``,
+    ``o_proj`` > ``ssm/out_proj``."""
+    T = x.shape[0]
+    H, P, N, G = cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_size, cfg.ssm_n_groups
+    d_in = H * P
+    dt = lp["attn_norm"].dtype
+    state, rows = slab["state"], slab["conv"]
+
+    scope = functools.partial(_operator_scope, "ssm")
+
+    with scope("qkv", "in_proj"):
+        u = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps).astype(dt)
+        zx = _dot(u, lp["w_zx"])                                  # [T, d_in + channels] f32
+        z, pre = zx[:, :d_in], zx[:, d_in:].astype(dt)
+        step = jax.nn.softplus(_dot(u, lp["w_dt"]) + lp["dt_bias"])   # [T, H] f32
+        la = -jnp.exp(lp["A_log"]) * step                         # log a
+    with scope("kv_write", "conv"):
+        c, rows, slots, fresh = _slab_conv(
+            pre, rows, slots, positions, write_pages, cu_q_lens, lp["conv_w"],
+            lp.get("conv_b"), engine)
+        xs = c[:, :d_in].reshape(T, H, P)
+        Bm = c[:, d_in:d_in + G * N].reshape(T, G, N)
+        Cm = c[:, d_in + G * N:].reshape(T, G, N)
+    if cu_q_lens is None:
+        with scope("attn", "ssd_step"):
+            y, state = ssm.ssd_step(state, slots, xs, step, jnp.exp(la), Bm, Cm, lp["D"], fresh)
+    else:
+        with scope("attn", "ssd_scan"):
+            y, state = ssm.ssd_scan(state, slots, fresh, xs, step, la, Bm, Cm, lp["D"],
+                                    cu_q_lens, chunk=cfg.ssm_chunk_size)
+    with scope("attn", "gate_norm"):
+        gated = (y.reshape(T, d_in) * jax.nn.silu(z)).reshape(T, G, d_in // G)
+        gated = rms_norm(gated, lp["ssm_norm"].astype(jnp.float32).reshape(G, d_in // G),
+                         cfg.rms_norm_eps).reshape(T, d_in).astype(dt)
+    with scope("o_proj", "out_proj"):
+        x = x + _dot(gated, lp["w_out"]).astype(x.dtype)
+    return x, {"state": state, "conv": rows}
+
+
 def _attn_out_and_mlp(x, attn, lp, cfg: ModelConfig, tp: int, mesh,
                       row_valid=None, expert_stats: list | None = None):
     """The block after attention: ``x + attn Wo``, then ``x + mlp(norm x)``.
@@ -2048,6 +2235,8 @@ def _attn_out_and_mlp(x, attn, lp, cfg: ModelConfig, tp: int, mesh,
         if cfg.post_norm:
             a = rms_norm(a, lp["attn_norm"], cfg.rms_norm_eps)
         x = x + a
+    if cfg.single_sublayer:   # the block IS its mixer: the feed-forward is a block of its own
+        return x
     return _residual_mlp(x, lp, cfg, tp, mesh, row_valid, expert_stats)
 
 
@@ -2066,6 +2255,17 @@ def _residual_mlp(x, lp, cfg: ModelConfig, tp: int, mesh,
             m = rms_norm(m.astype(x.dtype), lp["mlp_norm"], cfg.rms_norm_eps)
         x = x + m
     return x
+
+
+def mlp_block(x, lp, cfg: ModelConfig, row_valid=None, expert_stats: list | None = None):
+    """A block that is a feed-forward ALONE (``cfg.single_sublayer``, layer
+    kind "moe"): ``x + mlp(norm x)`` under the block's one norm
+    (``attn_norm``), scope ``mlp`` as the second half of a two-sub-layer block
+    has it. It reads no position and caches nothing."""
+    with jax.named_scope("mlp"):
+        dt = lp["attn_norm"].dtype
+        y = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps).astype(dt)
+        return x + _shared_sparse_mlp(y, lp, cfg, row_valid, expert_stats)
 
 
 # -- the unified forward ----------------------------------------------------
@@ -2196,6 +2396,11 @@ def forward_hidden(
                 tp=tp, mesh=mesh, rope_cs=rope_cs[cfg.layer_types[l]],
                 row_valid=row_valid, expert_stats=expert_stats, window=window,
             )
+        if "w_zx" in lp:   # a mamba layer's leaves (cfg.layer_types)
+            return ssm_layer(
+                x, lp, cache_l, positions, write_pages, slots, cu_q_lens, cfg, engine)
+        if cfg.single_sublayer and "w_router" in lp:   # a feed-forward block: no cache entry
+            return mlp_block(x, lp, cfg, row_valid, expert_stats), cache_l
         if "A_log" in lp:  # a linear layer's leaves (cfg.layer_types)
             return linear_layer(
                 x, lp, cache_l, positions, write_pages, slots, cu_q_lens, cfg, engine)

@@ -39,7 +39,7 @@ log = logging.getLogger("dynamo_tpu.engine")
 
 
 _TWO_SHAPES = "one block holds pages of two shapes; [planes, *page] carries one"
-_LANE_STATE = ("the linear layers' state lies in a slab indexed by lane slot; no block "
+_LANE_STATE = ("the linear or mamba layers' state lies in a slab indexed by lane slot; no block "
                "holds it, so a block that leaves the device, or is found again by its "
                "hash, carries the full layers' pages and not the state at its end")
 _TWO_POOLS = ("the window layers' pages lie in a pool of their own, whose blocks "
@@ -60,8 +60,8 @@ def _resolve_window_pool(model_cfg, engine_cfg):
     sequence has to fit with room to spare."""
     windowed = model_cfg.windowed
     prefix = engine_cfg.enable_prefix_caching
-    if model_cfg.linear and prefix is None:
-        log.info("model %s has linear_attention layers: prefix caching is off "
+    if model_cfg.has_slab and prefix is None:
+        log.info("model %s has linear_attention or mamba layers: prefix caching is off "
                  "(no block holds their state)", model_cfg.name)
         engine_cfg = dataclasses.replace(engine_cfg, enable_prefix_caching=False)
         prefix = False
@@ -146,7 +146,8 @@ def _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh) -> 
     and peer pulls, which a hybrid cache refuses (a block that leaves the
     device is ``[planes, *page]`` of ONE shape:
     ``EngineCore.kv_page_shape``)."""
-    hybrid, windowed, linear = model_cfg.hybrid, model_cfg.windowed, model_cfg.linear
+    # (``linear``: a slab a lane slot, a gated delta rule's or a Mamba-2 mixer's)
+    hybrid, windowed, linear = model_cfg.hybrid, model_cfg.windowed, model_cfg.has_slab
     blocks = model_cfg.block_length > 0
     # one chip's programs: the layers no mesh rule, stage body or verify row knows
     if not (model_cfg.latent or model_cfg.shared_sparse or hybrid or windowed or blocks
@@ -175,7 +176,7 @@ def _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh) -> 
         "tp": mesh is not None
         and "no sharding rule for the latent projections, the held experts (a "
             "share is stated with experts_held, not with a mesh), conv "
-            "operators, linear-attention operators and their slab, paired KV heads "
+            "operators, linear-attention or mamba operators and their slab, paired KV heads "
             "or layers of unequal head counts",
         "pp": pp_mesh is not None
         and "the pipeline's stage body is the dense layer's",
@@ -185,7 +186,7 @@ def _refuse_uncarried_options(model_cfg, engine_cfg, mesh, sp_mesh, pp_mesh) -> 
             _BLOCK_STEP + "there is no next token to draft" if blocks else
             "a rejected draft has already overwritten the convolution's rolling "
             "state past the cursor the lane goes on from" if hybrid else
-            "a rejected draft has already updated the linear layers' state in place "
+            "a rejected draft has already updated the linear or mamba layers' state in place "
             "past the cursor the lane goes on from" if linear else
             "a window block is given back by the cursor a verify row may fall "
             "behind" if windowed else

@@ -81,6 +81,15 @@ because a wave's rows differ a group: adding a place's row to its token's is
 a one-row update of an (8, 128)-tiled float32 array at a dynamic row, which
 Mosaic refuses (PR 36). So they share the chain, the blocks, the ring and
 the mathematics, and differ in where the rows come from and the result goes.
+
+**An un-gated expert** (``gated=False``, ``ModelConfig.mlp_activation``
+"relu2"; PR 54): ``relu(x Wu)^2 Wd``, so ``w_gu`` is ``Wu [Eh, h, im]`` alone,
+the first product's sums are ``[N, im]`` and the activation is their clamped
+square; the chain, the ring and the blocks are the same. Where the published
+width is no whole count of 128-lane rows (nemotron_h: 1,856 = 14.5 rows) the
+weights are STORED with zero columns of ``Wu`` and zero rows of ``Wd`` up to
+the next whole row (1,920): ``relu(0)^2`` times a zero row adds exactly
+nothing, and both kernels take the layer as it is.
 """
 
 from __future__ import annotations
@@ -111,31 +120,41 @@ def _widest(n: int, row_bytes: int) -> int:
     return max(fits, default=128)
 
 
+def _gu_width(im: int, gated: bool) -> int:
+    """Columns of ``w_gu[e]``: ``[Wg | Wu]`` of a gated expert, ``Wu`` alone of
+    an un-gated one (``relu(x Wu)^2 Wd``)."""
+    return 2 * im if gated else im
+
+
 def vmem_bytes(rows: int, h: int, im: int, itemsize: int, tk: int, ti: int,
-               ring: int = _RING) -> int:
+               ring: int = _RING, gated: bool = True) -> int:
     """What the kernel holds in VMEM at ``rows`` rows: both rings, the rows,
     the weights' columns, the three float32 sums, the activation, and as
     much again as the widest sum for the products' own temporaries."""
-    sums = rows * (2 * im + 2 * h) * 4
-    return (ring * (tk * 2 * im + ti * h) * itemsize + rows * (h + im) * itemsize
-            + rows * 128 * 4 + sums + rows * max(2 * im, h) * 4)
+    W = _gu_width(im, gated)
+    sums = rows * (W + 2 * h) * 4
+    return (ring * (tk * W + ti * h) * itemsize + rows * (h + im) * itemsize
+            + rows * 128 * 4 + sums + rows * max(W, h) * 4)
 
 
-def _k_blocks(h: int, im: int, itemsize: int) -> tuple[int, int] | None:
+def _k_blocks(h: int, im: int, itemsize: int, gated: bool = True) -> tuple[int, int] | None:
     """(``tk``, ``ti``): rows of ``w_gu[e]`` and of ``w_down[e]`` a block, the
     largest whole-lane divisors of ``h`` and ``im`` under ``_BLOCK_BYTES``;
-    None where a width is no multiple of 128 lanes. They follow the widths
-    alone, so both kernels cut a row's float32 sums alike."""
+    None where a width is no multiple of 128 lanes (``im`` as STORED: an
+    un-gated expert 1,856 wide is kept 1,920 wide, zeros behind it:
+    ``ModelConfig.expert_stored_width``). They follow the widths alone, so
+    both kernels cut a row's float32 sums alike."""
     if h % 128 or im % 128:
         return None
-    return _widest(h, 2 * im * itemsize), _widest(im, h * itemsize)
+    return _widest(h, _gu_width(im, gated) * itemsize), _widest(im, h * itemsize)
 
 
-def blocks(rows: int, h: int, im: int, itemsize: int) -> tuple[int, int] | None:
+def blocks(rows: int, h: int, im: int, itemsize: int,
+           gated: bool = True) -> tuple[int, int] | None:
     """:func:`_k_blocks`' (``tk``, ``ti``), or None where there are none or
     the kernel would not fit ``_VMEM_LIMIT`` at ``rows`` rows."""
-    cut = _k_blocks(h, im, itemsize)
-    if cut is None or vmem_bytes(rows, h, im, itemsize, *cut) > _VMEM_LIMIT:
+    cut = _k_blocks(h, im, itemsize, gated)
+    if cut is None or vmem_bytes(rows, h, im, itemsize, *cut, gated=gated) > _VMEM_LIMIT:
         return None
     return cut
 
@@ -145,14 +164,15 @@ def _one_float_dtype(dtype, w_gu: jax.Array, w_down: jax.Array) -> bool:
     return w_gu.dtype == w_down.dtype == dtype and dtype in (jnp.bfloat16, jnp.float32)
 
 
-def impl(backend: str, dtype, rows: int, w_gu: jax.Array, w_down: jax.Array) -> str:
+def impl(backend: str, dtype, rows: int, w_gu: jax.Array, w_down: jax.Array,
+         gated: bool = True) -> str:
     """Which implementation ``rows`` rows of ``dtype`` against every held
     expert get on ``backend``: ``"stream/pallas"`` on a TPU where rows and
     weights are floats of one width and :func:`blocks` finds a fit; else
     ``"all_rows"`` (``model._experts_all_rows``). The label of the call's
     counter."""
     fits = _one_float_dtype(dtype, w_gu, w_down) and blocks(
-        rows, w_gu.shape[1], w_down.shape[1], jnp.dtype(dtype).itemsize) is not None
+        rows, w_gu.shape[1], w_down.shape[1], jnp.dtype(dtype).itemsize, gated) is not None
     return "stream/pallas" if backend == "tpu" and fits else "all_rows"
 
 
@@ -203,6 +223,7 @@ def _stream_kernel(
     gu_ref,      # VMEM [N, 2 im] f32 — x Wgu, summed over the K slabs
     act_ref,     # VMEM [B, N, ti] — silu(g) * u, a K slab of down a leading index
     y_ref,       # VMEM [N, h] f32 — act Wdown, summed over the K slabs
+    *, gated: bool = True,
 ):
     """Every block of every held expert is one LINK of a chain: expert
     ``e``'s ``A = h / tk`` gate/up slabs, then its ``B = im / ti`` down
@@ -214,7 +235,7 @@ def _stream_kernel(
     A, N, tk = x_ref.shape
     B, _, ti = act_ref.shape
     R, Eh = gu_buf.shape[0], gu_hbm.shape[0]
-    im = gu_buf.shape[2] // 2
+    im = B * ti
     links = A + B
     total = Eh * links
 
@@ -238,7 +259,10 @@ def _stream_kernel(
 
         gu_ref[...] = jnp.zeros(gu_ref.shape, gu_ref.dtype)
         jax.lax.fori_loop(0, A, gate_up, None)
-        act = (jax.nn.silu(gu_ref[:, :im]) * gu_ref[:, im:]).astype(act_ref.dtype)
+        if gated:
+            act = (jax.nn.silu(gu_ref[:, :im]) * gu_ref[:, im:]).astype(act_ref.dtype)
+        else:   # relu(x Wu)^2: gu_ref is [N, im]
+            act = jnp.square(jnp.maximum(gu_ref[...], 0.0)).astype(act_ref.dtype)
         for b in range(B):
             act_ref[b] = act[:, b * ti:(b + 1) * ti]
 
@@ -260,33 +284,35 @@ def _stream_kernel(
 
 # jitted so that a program's sparse layers, and every program of a width,
 # share ONE trace and lowering of the kernel
-@functools.partial(jax.jit, static_argnames=("tk", "ti", "ring", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tk", "ti", "ring", "interpret", "gated"))
 def expert_stream(xf, w_held, w_gu, w_down, *, tk: int | None = None, ti: int | None = None,
-                  ring: int = _RING, interpret: bool = False):
+                  ring: int = _RING, interpret: bool = False, gated: bool = True):
     """``[N, h]`` float32 (module docstring). ``tk``, ``ti``: rows of
     ``w_gu[e]`` and ``w_down[e]`` a block (:func:`blocks`' unless stated: a
-    tool sweeps them); ``ring``: blocks in the ring. Needs shapes
+    tool sweeps them); ``ring``: blocks in the ring; ``gated`` False: ``w_gu``
+    is ``Wu [Eh, h, im]`` alone and an expert is ``relu(x Wu)^2 Wd``. Needs shapes
     :func:`impl` accepts; the rows are padded to whole sublane tiles."""
     N, h = xf.shape
     Eh, im = w_down.shape[:2]
+    W = _gu_width(im, gated)
     if tk is None or ti is None:
-        tk, ti = blocks(N, h, im, xf.dtype.itemsize)
+        tk, ti = blocks(N, h, im, xf.dtype.itemsize, gated)
     A, B = h // tk, im // ti
     pad = (-N) % (32 // xf.dtype.itemsize)      # whole sublane tiles: 16 rows of bf16, 8 of f32
     x = jnp.pad(xf, ((0, pad), (0, 0))).reshape(N + pad, A, tk).swapaxes(0, 1)
     w = jnp.pad(w_held.astype(jnp.float32), ((0, pad), (0, 0)))
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
-        _stream_kernel,
+        functools.partial(_stream_kernel, gated=gated),
         in_specs=[whole, whole, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=whole,
         out_shape=jax.ShapeDtypeStruct((N + pad, h), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((ring, tk, 2 * im), w_gu.dtype),
+            pltpu.VMEM((ring, tk, W), w_gu.dtype),
             pltpu.VMEM((ring, ti, h), w_down.dtype),
             pltpu.SemaphoreType.DMA((ring,)),
-            pltpu.VMEM((N + pad, 2 * im), jnp.float32),
+            pltpu.VMEM((N + pad, W), jnp.float32),
             pltpu.VMEM((B, N + pad, ti), xf.dtype),
             pltpu.VMEM((N + pad, h), jnp.float32),
         ],
@@ -346,30 +372,32 @@ def grouped_rows_visited(count: jax.Array) -> jax.Array:
 
 
 def grouped_vmem_bytes(h: int, im: int, itemsize: int, tk: int, ti: int, item_rows: int,
-                       ring: int) -> int:
+                       ring: int, gated: bool = True) -> int:
     """What :func:`_grouped_kernel` holds in VMEM: both rings, an item's rows
     twice (the next item's land while this one's are multiplied), its float32
     gate/up sums, its activation, its float32 results twice (the item
     before's leave while this one's are summed), and as much again as the
     widest sum for the products' own temporaries."""
-    return (ring * (tk * 2 * im + ti * h) * itemsize
-            + item_rows * (2 * h * itemsize + 2 * im * 4 + im * itemsize + 2 * h * 4)
-            + item_rows * max(2 * im, h) * 4)
+    W = _gu_width(im, gated)
+    return (ring * (tk * W + ti * h) * itemsize
+            + item_rows * (2 * h * itemsize + W * 4 + im * itemsize + 2 * h * 4)
+            + item_rows * max(W, h) * 4)
 
 
 def grouped_blocks(h: int, im: int, itemsize: int, item_rows: int = _ITEM_ROWS,
-                   ring: int = _GROUPED_RING) -> tuple[int, int, int, int] | None:
+                   ring: int = _GROUPED_RING,
+                   gated: bool = True) -> tuple[int, int, int, int] | None:
     """(``tk``, ``ti``, rows an item, blocks in the ring) of the grouped
     kernel: :func:`blocks`' ``tk`` and ``ti`` (they follow the widths alone,
     so a row's float32 sums are cut as a step's are), the tallest item of
     ``_CHUNKS`` up to ``item_rows`` and then the deepest ring up to ``ring``
     (at least ``_RING``, or ``ring`` if less) that fit ``_VMEM_LIMIT``; None
     where a width is no multiple of 128 lanes or nothing fits."""
-    cut = _k_blocks(h, im, itemsize)
+    cut = _k_blocks(h, im, itemsize, gated)
     if cut is None:
         return None
     tk, ti = cut
-    fits = lambda t, r: grouped_vmem_bytes(h, im, itemsize, tk, ti, t, r) <= _VMEM_LIMIT
+    fits = lambda t, r: grouped_vmem_bytes(h, im, itemsize, tk, ti, t, r, gated) <= _VMEM_LIMIT
     for t in (c for c in _CHUNKS if c <= item_rows):
         if fits(t, min(ring, _RING)):
             return tk, ti, t, max(r for r in range(min(ring, _RING), ring + 1) if fits(t, r))
@@ -377,7 +405,7 @@ def grouped_blocks(h: int, im: int, itemsize: int, item_rows: int = _ITEM_ROWS,
 
 
 def grouped_impl(backend: str, dtype, rows_a_group: float, w_gu: jax.Array,
-                 w_down: jax.Array) -> str | None:
+                 w_down: jax.Array, gated: bool = True) -> str | None:
     """``"stream"`` where a wave's chosen pairs get :func:`expert_stream_grouped`
     on ``backend``: a TPU, rows and weights floats of one width,
     :func:`grouped_blocks` finds a fit, and a held expert is expected to hold
@@ -386,7 +414,7 @@ def grouped_impl(backend: str, dtype, rows_a_group: float, w_gu: jax.Array,
     and the two grouped products of ``ops/grouped_matmul.py`` (whose ``impl``
     then says which) serve the call."""
     fits = _one_float_dtype(dtype, w_gu, w_down) and grouped_blocks(
-        w_gu.shape[1], w_down.shape[1], jnp.dtype(dtype).itemsize) is not None
+        w_gu.shape[1], w_down.shape[1], jnp.dtype(dtype).itemsize, gated=gated) is not None
     return "stream" if backend == "tpu" and fits and rows_a_group <= _GROUPED_ROWS_MAX else None
 
 
@@ -406,7 +434,7 @@ def _grouped_kernel(
     gu_ref,      # VMEM [T, 2 im] f32
     act_ref,     # VMEM [T, im]
     y_ref,       # VMEM [2, T, h] f32 — an item's results, and the item before's on their way out
-    *, tk: int, ti: int,
+    *, tk: int, ti: int, gated: bool = True,
 ):
     """A work ITEM is one touched expert and up to ``T`` of its rows. The
     items' blocks of weights are the links of ONE chain, as
@@ -467,7 +495,7 @@ def _grouped_kernel(
         each_chunk(i, lambda *at: rows_in(i, *at).wait(), copied=True)
 
         def zero(rows, places):
-            gu_ref[rows] = jnp.zeros((rows.size, 2 * im), jnp.float32)
+            gu_ref[rows] = jnp.zeros((rows.size, gu_ref.shape[1]), jnp.float32)
 
         each_chunk(i, zero)
 
@@ -491,8 +519,12 @@ def _grouped_kernel(
             results_left(i - 2)
 
         def activate(rows, places):
-            act_ref[rows] = (jax.nn.silu(gu_ref[rows, :im]) * gu_ref[rows, im:]).astype(
-                act_ref.dtype)
+            if gated:
+                act_ref[rows] = (jax.nn.silu(gu_ref[rows, :im]) * gu_ref[rows, im:]).astype(
+                    act_ref.dtype)
+            else:   # relu(x Wu)^2: gu_ref is [T, im]
+                act_ref[rows] = jnp.square(jnp.maximum(gu_ref[rows], 0.0)).astype(
+                    act_ref.dtype)
             y_ref[half, rows] = jnp.zeros((rows.size, h), jnp.float32)
 
         each_chunk(i, activate)
@@ -543,11 +575,13 @@ def _items(start, count, slots: int, item_rows: int):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "tk", "ti", "ring", "item_rows", "interpret"))
+    "tk", "ti", "ring", "item_rows", "interpret", "gated"))
 def expert_stream_grouped(x, start, count, w_gu, w_down, *, tk: int | None = None,
                           ti: int | None = None, ring: int = _GROUPED_RING,
-                          item_rows: int = _ITEM_ROWS, interpret: bool = False):
+                          item_rows: int = _ITEM_ROWS, interpret: bool = False,
+                          gated: bool = True):
     """``y [P, h]`` float32 with ``y[start[e] + r] = SwiGLU_e(x[start[e] + r])``
+    (``relu(x Wu)^2 Wd`` where not ``gated``: :func:`expert_stream`'s)
     for ``r < count[e]``, every held expert ``e`` (module docstring); what the
     other rows of ``y`` hold is unspecified (they are never written). ``start``
     ascends with ``e``, each a multiple of ``GROUP_ALIGN`` as ``P`` is, the groups
@@ -555,15 +589,16 @@ def expert_stream_grouped(x, start, count, w_gu, w_down, *, tk: int | None = Non
     them); they, ``tk`` and ``ti`` are the module's own unless a tool sweeps them."""
     P, h = x.shape
     Eh, im = w_down.shape[:2]
-    fit = grouped_blocks(h, im, x.dtype.itemsize, item_rows, ring)
+    fit = grouped_blocks(h, im, x.dtype.itemsize, item_rows, ring, gated)
     item_rows, ring = fit[2:]
+    W = _gu_width(im, gated)
     if tk is None or ti is None:
         tk, ti = fit[:2]
     table = _items(start.astype(jnp.int32), count.astype(jnp.int32),
                    Eh + P // item_rows, item_rows)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
-        functools.partial(_grouped_kernel, tk=tk, ti=ti),
+        functools.partial(_grouped_kernel, tk=tk, ti=ti, gated=gated),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(1,),
@@ -571,12 +606,12 @@ def expert_stream_grouped(x, start, count, w_gu, w_down, *, tk: int | None = Non
             out_specs=hbm,
             scratch_shapes=[
                 pltpu.VMEM((2, item_rows, h), x.dtype),
-                pltpu.VMEM((ring, tk, 2 * im), w_gu.dtype),
+                pltpu.VMEM((ring, tk, W), w_gu.dtype),
                 pltpu.VMEM((ring, ti, h), w_down.dtype),
                 pltpu.SemaphoreType.DMA((ring,)),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA((2,)),
-                pltpu.VMEM((item_rows, 2 * im), jnp.float32),
+                pltpu.VMEM((item_rows, W), jnp.float32),
                 pltpu.VMEM((item_rows, im), x.dtype),
                 pltpu.VMEM((2, item_rows, h), jnp.float32),
             ],

@@ -100,6 +100,12 @@ _PREFILL_QUERIES_PER_BLOCK = 128
 # block more: ~2.6 ms a full layer for a 2,048-token chunk at a context of
 # 8,192, beside ~2.1 ms of attention FLOPs (PERF.md section 5).
 _WIDE_HEADS_MIN = 33
+# ... and a pass's rows, queries x GROUP, hold its scores and their exponentials
+# in float32: 128 queries x a group of 16 (nemotron_h: 32 query heads on 2 KV
+# heads) are 2,048 rows a pass and were refused by 0.7 MB of 16 (compiled for
+# the v5e, PR 54); a wave's query block is cut so that a pass has at most this
+# many rows (64 queries there; every group up to 8 keeps its 128).
+_PREFILL_PASS_ROWS_MAX = 1024
 _WIDE_HEADS_QUERIES_PER_BLOCK = 32
 # Their KV block: 1,024 tokens, not the 8 pages of the other ragged calls.
 # A pass of the kernel's body (one KV head of one KV block for one query
@@ -267,7 +273,9 @@ def pallas_ragged_attention(
         rows, heads = q.shape[:2]
         qb, pages = _SMALL_QUERIES_PER_BLOCK, _SMALL_KV_PAGES_PER_BLOCK
         if rows > 64 and heads < _WIDE_HEADS_MIN:
-            qb = min(_PREFILL_QUERIES_PER_BLOCK, rows)
+            group = max(1, heads // max(1, kv_pages.shape[2] // 2))
+            qb = min(_PREFILL_QUERIES_PER_BLOCK, rows,
+                     max(_SMALL_QUERIES_PER_BLOCK, _PREFILL_PASS_ROWS_MAX // group))
         elif rows > 64:
             qb = min(_WIDE_HEADS_QUERIES_PER_BLOCK, rows)
             pages = max(1, _WIDE_HEADS_KV_TOKENS_PER_BLOCK // kv_pages.shape[1])

@@ -168,7 +168,7 @@ SCHEDULER_GAUGES: dict[str, tuple[str, str]] = {
     "state_bytes_per_sequence": (
         "engine_state_bytes_per_sequence",
         "Bytes of recurrent state one sequence holds over all linear-attention "
-        "layers, whatever its context (the float32 state and the convolution's "
+        "or mamba layers, whatever its context (the float32 state and the convolution's "
         "newest rows, in a slab indexed by lane slot); 0 for a model without "
         "such layers",
     ),
@@ -292,7 +292,7 @@ ENGINE_COUNTERS: dict[str, tuple[str, str]] = {
     ),
     "state_replayed_tokens": (
         "engine_state_replayed_tokens",
-        "Tokens a sequence of a model with linear-attention layers had run when "
+        "Tokens a sequence of a model with linear-attention or mamba layers had run when "
         "it was preempted: no block holds their state, so it runs them again "
         "from position 0 into a fresh lane slot",
     ),
@@ -493,7 +493,7 @@ class _EngineCounters:
         for (shape, impl), n in sorted(traced_calls().items()):
             traced.add_metric(["engine", shape, impl], float(n))
         yield traced
-        from dynamo_tpu.ops import grouped_matmul, linear_attention
+        from dynamo_tpu.ops import grouped_matmul, linear_attention, ssm
 
         linear = CounterMetricFamily(
             "dynamo_engine_linear_calls_traced",
@@ -507,9 +507,21 @@ class _EngineCounters:
         for (shape, impl), n in sorted(linear_attention.traced_calls().items()):
             linear.add_metric(["engine", shape, impl], float(n))
         yield linear
+        ssm_calls = CounterMetricFamily(
+            "dynamo_engine_ssm_calls_traced",
+            "Mamba-2 (state-space) state calls traced into step programs, by "
+            "shape (step: one row a lane, the decode step's read and write of "
+            "every live lane's state; scan: the chunked scan of a ragged batch) "
+            "and the implementation chosen (pallas: the first-party kernel; "
+            "jnp: no kernel)",
+            labels=["service", "shape", "impl"],
+        )
+        for (shape, impl), n in sorted(ssm.traced_calls().items()):
+            ssm_calls.add_metric(["engine", shape, impl], float(n))
+        yield ssm_calls
         slots = GaugeMetricFamily(
             "dynamo_engine_state_slots",
-            "Lane slots of the linear-attention layers' slab, by state: held by "
+            "Lane slots of the linear-attention or mamba layers' slab, by state: held by "
             "a running sequence or free (the garbage slot apart); no series for "
             "a model without such layers",
             labels=["service", "state"],
@@ -539,8 +551,9 @@ class _EngineCounters:
             "Page arrays the cache holds, by what a layer of that kind "
             "caches: attention (planes of K/V, or latent rows), conv (the "
             "short convolution's state pages), window (K/V of a sliding "
-            "window, in a pool of its own) or linear (a slab of float32 state "
-            "indexed by lane slot)",
+            "window, in a pool of its own), linear or ssm (a slab of float32 "
+            "state indexed by lane slot) or none (a block that is a feed-forward "
+            "alone caches nothing)",
             labels=["service", "kind"],
         )
         for kind, n in sorted(stats.get("cache_layers", {}).items()):

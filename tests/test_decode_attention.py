@@ -493,6 +493,59 @@ def test_mosaic_compiles_the_expert_stream_kernel_for_a_v5e(one_chip, held, h, i
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * rows * h * 4
 
 
+@pytest.mark.parametrize("kernel", ["ssd_step-128", "ssd_step-32", "stream-128", "stream-32",
+                                    "grouped-2048", "attn-2048"])
+def test_mosaic_compiles_nemotrons_kernels_for_a_v5e(one_chip, kernel):
+    """Nemotron-3-Nano's two new kernels' worth of calls at the cell's shapes
+    (PR 54): a Mamba-2 layer's decode step (64 heads, a float32 tile of ``[64,
+    128]`` a head, a slab of 129 lane slots, the state aliased in place), and
+    the UN-GATED expert layer 1,856 wide stored as 1,920 (``relu(x Wu)^2 Wd``:
+    ``w_gu`` is ``[64, 2688, 1920]``) through the stream kernel at both decode
+    widths and through the grouped kernel at the widest wave."""
+    from dynamo_tpu.engine import model
+    from dynamo_tpu.ops import expert_stream as es
+    from dynamo_tpu.ops import ssm
+
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    name, rows = kernel.split("-")
+    rows = int(rows)
+    if name == "attn":   # a wave of 32 query heads on 2 KV heads: 64 queries a block, not 128
+        compiled = jax.jit(
+            lambda *a: ra.pallas_ragged_attention(*a, sm_scale=128 ** -0.5)).lower(
+            sds((rows, 32, 128), jnp.bfloat16), sds((12289, 32, 4, 128), jnp.bfloat16),
+            sds((8,), jnp.int32), sds((8, 128), jnp.int32), sds((9,), jnp.int32),
+            sds((1,), jnp.int32)).compile()
+        assert "ragged_paged_attention_kernel" in compiled.as_text()
+        return
+    if name == "ssd_step":
+        state = sds((129, 64, 64, 128))
+        assert ssm.step_impl("tpu", state) == "pallas"
+        compiled = jax.jit(ssm.ssd_step_pallas).lower(
+            state, sds((rows,), jnp.int32), sds((rows, 64, 64)), sds((rows, 64)), sds((rows, 64)),
+            sds((rows, 8, 128)), sds((rows, 8, 128)), sds((64,)), sds((rows,), jnp.bool_),
+        ).compile()
+        assert "ssd_step_kernel" in compiled.as_text()
+        # in place: the slab is not copied to make the output (270 MB a layer a step)
+        assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
+        return
+    held, h, im = 64, 2688, 1920
+    w_gu, w_down = sds((held, h, im), jnp.bfloat16), sds((held, im, h), jnp.bfloat16)
+    if name == "stream":
+        assert es.impl("tpu", jnp.bfloat16, rows, w_gu, w_down, gated=False) == "stream/pallas"
+        assert es.impl("tpu", jnp.bfloat16, rows, sds((held, h, 1856), jnp.bfloat16),
+                       sds((held, 1856, h), jnp.bfloat16), gated=False) == "all_rows"
+        compiled = jax.jit(lambda *a: es.expert_stream(*a, gated=False)).lower(
+            sds((rows, h), jnp.bfloat16), sds((rows, held)), w_gu, w_down).compile()
+        assert "expert_stream_kernel" in compiled.as_text()
+        return
+    assert es.grouped_impl("tpu", jnp.bfloat16, rows * 6 / 128, w_gu, w_down, gated=False) == "stream"
+    compiled = jax.jit(
+        lambda *a: model._experts_grouped(*a, k=6, impl="stream", all_held=False, gated=False)
+    ).lower(sds((rows, h), jnp.bfloat16), sds((rows, held)), sds((rows, held), jnp.bool_),
+            w_gu, w_down).compile()
+    assert "expert_stream_grouped_kernel" in compiled.as_text()
+
+
 @pytest.mark.parametrize("page_size,width,grid", [
     (32, 256, (1, 16)),    # the three cells: what the sweep chose
     (32, 2, (1, 2)),       # never more pages than the table holds

@@ -12,6 +12,11 @@ roofline as the benchmark's ``linear_state_roofline`` counts the bytes (every
 live lane's 2,211,840 B of state read once and written once), and the
 largest difference from the ``jax.numpy`` step on the same arguments.
 
+``--shape nemotron`` (PR 54): a Mamba-2 layer's step instead
+(``ops/ssm.py``), the cell ``nemotron3-nano-ep2-decode``'s: 64 heads of 64
+channels, a state 128 wide in 8 groups, 2,097,152 B a lane; ``--heads`` then
+sweeps the heads a grid step of ``ssd_step_pallas`` (whole groups of 8).
+
 Refuses to run without a TPU: a time from the CPU says nothing here.
 
 Usage (through the chip tool, from the repo root):
@@ -30,8 +35,9 @@ from pathlib import Path
 
 import numpy as np
 
-# heads, key width, value width
-SHAPES = {"olmo": (30, 96, 192)}
+# heads, key width, value width (nemotron: heads, channels a head, state width)
+SHAPES = {"olmo": (30, 96, 192), "nemotron": (64, 64, 128)}
+_SSM_GROUPS = 8
 
 
 def make_case(shape: str, lanes: int, seed: int):
@@ -44,6 +50,14 @@ def make_case(shape: str, lanes: int, seed: int):
 
     H, dk, dv = SHAPES[shape]
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    if shape == "nemotron":   # (state, slots, x, dt, a, B, C, D, fresh)
+        state = jax.random.normal(ks[0], (lanes + 1, H, dk, dv), jnp.float32)
+        slots = jnp.asarray(np.random.RandomState(seed).permutation(lanes), jnp.int32)
+        dt = jnp.exp(jax.random.uniform(ks[2], (lanes, H), jnp.float32, -6.9, -2.3))
+        return (state, slots, jax.random.normal(ks[1], (lanes, H, dk)), dt,
+                jnp.exp(-8.0 * dt), jax.random.normal(ks[3], (lanes, _SSM_GROUPS, dv)),
+                jax.random.normal(ks[4], (lanes, _SSM_GROUPS, dv)), jnp.ones((H,), jnp.float32),
+                jnp.zeros((lanes,), bool)), 2 * lanes * H * dk * dv * 4
     state = pack_heads(jax.random.normal(ks[0], (lanes + 1, H, dk, dv), jnp.float32),
                        heads_per_tile(H, dv))            # the slab's layout
     slots = jnp.asarray(np.random.RandomState(seed).permutation(lanes), jnp.int32)
@@ -72,24 +86,28 @@ def main() -> int:
                          f"this backend is {jax.default_backend()}")
     from dynamo_tpu.device import device_info, device_peaks
     from dynamo_tpu.ops import linear_attention as la
+    from dynamo_tpu.ops import ssm
 
+    step_jnp, step_pallas = ((ssm.ssd_step_jnp, ssm.ssd_step_pallas) if args.shape == "nemotron"
+                             else (la.gdn_step_jnp, la.gdn_step_pallas))
     hbm_bytes_per_s = device_peaks(device_info()["kind"]).hbm_gbps * 1e9
     rows = []
     for lanes in (int(n) for n in args.lanes.split(",")):
         case, need = make_case(args.shape, lanes, seed=lanes)
-        want_o, want_state = jax.jit(la.gdn_step_jnp)(*case)
-        impls = [("jnp", la.gdn_step_jnp)] + [
-            (f"pallas/{g}", functools.partial(la.gdn_step_pallas, heads_per_block=g))
+        want_o, want_state = jax.jit(step_jnp)(*case)
+        impls = [("jnp", step_jnp)] + [
+            (f"pallas/{g}", functools.partial(step_pallas, heads_per_block=g))
             for g in (int(n) for n in args.heads.split(","))]
         for name, call in impls:
 
             @functools.partial(jax.jit, donate_argnums=(0,))
-            def loop(state, slots, q, k, v, alpha, beta, fresh, call=call):
+            def loop(state, slots, first, *rest, call=call):
                 def body(_, carry):   # each turn reads the state the last one wrote
                     state, o = carry
-                    o, state = call(state, slots, q, k, v + 0 * o, alpha, beta, fresh)
+                    # (the first [lanes, H, width] operand: q, or a mamba step's x)
+                    o, state = call(state, slots, first + 0 * jnp.sum(o), *rest)
                     return state, o
-                return jax.lax.fori_loop(0, args.calls, body, (state, jnp.zeros_like(v)))
+                return jax.lax.fori_loop(0, args.calls, body, (state, jnp.zeros_like(want_o)))
 
             try:
                 o, state = jax.jit(call)(*case)

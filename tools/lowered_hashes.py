@@ -20,7 +20,7 @@ import json
 import sys
 
 PRESETS = ("tiny", "tiny-moe", "tiny-loop", "tiny-axk1", "tiny-lfm2", "tiny-laguna",
-           "tiny-sdar", "tiny-mimo", "tiny-olmo-hybrid")
+           "tiny-sdar", "tiny-mimo", "tiny-olmo-hybrid", "tiny-nemotron-h")
 
 
 def main() -> int:
@@ -44,7 +44,8 @@ def main() -> int:
         width = eng.max_blocks_per_seq
         if cfg.windowed:
             width += 1 + eng.window_table_blocks(cfg.sliding_window)
-        if getattr(cfg, "linear", False):   # the lane slot's column (model.split_slots)
+        if getattr(cfg, "has_slab", getattr(cfg, "linear", False)):   # the lane slot's column
+            # (model.split_slots)
             width += 1
         S, T = 4, 32
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
