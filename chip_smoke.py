@@ -788,17 +788,21 @@ def kernel_check_child(corrupt: bool) -> int:
                        "not_run": "cpu: the TPU interpreter cannot execute it"})
 
     # (i-b) the decode-shaped path (``cu_q_lens=None``: what a megastep's
-    # attention runs, the library kernel at one sequence a query block)
-    # through the serving entry, at the three cells' decode shapes and at
+    # attention runs: a first-party kernel where the geometry fits one, group
+    # 1 or grouped, else the library kernel at one sequence a query block)
+    # through the serving entry, at the cells' decode shapes and at
     # `--block-size` 128 with 8 KV heads (where a KV block counted in
     # pages outgrew VMEM): lanes, heads, page size, page-table width, one
     # layer's page array, contexts.
     from dynamo_tpu.ops.ragged_attention import ragged_paged_attention
 
     def decode_case(lanes, heads, kv_heads, ps, width, pages, lo, hi):
+        """``pages``: one layer's page array as the cell has it, or as many
+        pages as the drawn contexts fill where that is more."""
         lens = rng.randint(lo, hi, lanes).astype(np.int32)
         lens[:4] = [1, ps, ps + 1, hi]      # an inactive lane; page edges
         tables = np.zeros((lanes, width), np.int32)
+        pages = max(pages, 1 + int(np.sum(-(-lens // ps))))
         perm, used = rng.permutation(pages - 1), 0
         for s_, n in enumerate(lens):
             need = -(-int(n) // ps)
@@ -817,16 +821,25 @@ def kernel_check_child(corrupt: bool) -> int:
                 jnp.asarray([0, 1], jnp.int32), one, sm_scale=sm)
             for s_ in range(q.shape[0])])
 
+    from dynamo_tpu.ops.ragged_attention import first_party_decode
+
     if on_tpu:
+        # (Laguna's and Nemotron's at their narrow decode widths and Laguna's
+        # contexts cut short: the reference is a program a lane, and the
+        # phase has 900 s)
         for label, geometry in [
             ("7B: 32 lanes, 28/4 heads", (32, 28, 4, 32, 256, 3073, 384, 1536)),
             ("1.5B: 8 lanes, 12/2 heads", (8, 12, 2, 32, 256, 11265, 160, 2560)),
             ("1.5B: 32 lanes, 12/2 heads", (32, 12, 2, 32, 256, 11265, 160, 2560)),
             ("Ouro: 8 lanes, 16/16 heads", (8, 16, 16, 32, 64, 676, 128, 608)),
+            ("Laguna's full layers: 16 lanes, 48/8 heads", (16, 48, 8, 32, 338, 0, 1024, 4096)),
+            ("Nemotron: 32 lanes, 32/2 heads", (32, 32, 2, 32, 128, 0, 256, 2048)),
             ("32 lanes, 32/8 heads, 128-token pages", (32, 32, 8, 128, 64, 769, 384, 1536)),
         ]:
             case = decode_case(*geometry)
             lanes_ = jnp.asarray([geometry[0]], jnp.int32)
+            took = first_party_decode("tpu", case[0], case[1], None)
+            label += f" ({'first-party kernel, ' + took[0] if took else 'library kernel'})"
             check(f"decode-shaped attention, {label}",
                   lambda: jax.jit(lambda *a: ragged_paged_attention(
                       *a, None, lanes_, sm_scale=sm))(*case),
@@ -841,11 +854,13 @@ def kernel_check_child(corrupt: bool) -> int:
     # (i-c) 64-wide heads cached in pairs (a model of head width 64 and an
     # even number of KV heads: LFM2's 32/8 heads): the serving entry for
     # them, against the per-head reference on pages of 64-wide heads that
-    # hold the same K and V. On a TPU the pair gets the library kernel.
+    # hold the same K and V. On a TPU the pair's decode call gets the
+    # first-party kernel of grouped heads (32 query heads on 4 paired rows).
     from dynamo_tpu.ops.ragged_attention import paired_heads_attention
 
     def paired_case(lanes, heads, kv_heads, ps, width, pages, lo, hi):
         q, kv, lens, tables = decode_case(lanes, heads, kv_heads // 2, ps, width, pages, lo, hi)
+        pages = kv.shape[0]
         half = d // 2        # kv [pages, ps, 2 x kv_heads / 2, 128] = pairs, K even V odd
         plain = kv.reshape(pages, ps, kv_heads // 2, 2, 2, half).transpose(
             0, 1, 2, 4, 3, 5).reshape(pages, ps, 2 * kv_heads, half)

@@ -35,19 +35,22 @@ silent):
   module's grids. A decode-shaped call runs it under a grid of its own: one
   sequence a query block, so that a sequence's pass computes on that
   sequence's rows alone (:func:`decode_shape_grid`).
-- ``impl="pallas"`` (PR 51): a DECODE-shaped call of GROUP 1 (multi-head
-  layers: as many query heads as KV heads) on a TPU over bfloat16 pages of
-  128-wide heads in whole tiles, no window and no int8 scales, takes the
-  first-party kernel of ops/mha_attention.py. At group 1 the library
-  kernel's pass is one query row against a block converted to float32;
-  the first-party kernel keeps K and V bfloat16 into the MXU and streams
-  the pages through the ring the other first-party decode kernels use
-  (ops/page_ring.py). It reads THE SAME PAGE: the layout above, its writer
-  (model.write_kv), the waves' library call, the prefix cache and the
-  transfer format are untouched (a head-major page would make the kernel
-  trivial and would need a wave kernel, a writer and a transfer format of
-  its own). Its device op's name contains ``ragged_paged_attention`` like
-  the library's, which is how a trace's readers find either.
+- ``impl="pallas"`` (PR 51, PR 55): a DECODE-shaped call on a TPU over
+  bfloat16 pages of 128-wide heads, no window and no int8 scales, takes a
+  first-party kernel where its geometry fits (:func:`first_party_decode`):
+  GROUP 1 (multi-head layers: as many query heads as KV heads) the kernel
+  of ops/mha_attention.py, a GROUP of 2 or more query heads a KV head the
+  kernel of ops/grouped_attention.py. The library kernel's decode pass
+  converts each block to float32 and moves whole 16-page blocks; the
+  first-party kernels keep K and V bfloat16 into the MXU and stream the
+  pages in use, and no others, through the ring the other first-party
+  decode kernels use (ops/page_ring.py). They read THE SAME PAGE: the
+  layout above, its writer (model.write_kv), the waves' library call, the
+  prefix cache and the transfer format are untouched (a head-major page
+  would make the kernels trivial and would need a wave kernel, a writer and
+  a transfer format of its own). Their device ops' names contain
+  ``ragged_paged_attention`` like the library's, which is how a trace's
+  readers find any of them.
 - ``impl="reference"``: elsewhere (CPU test meshes; shapes neither kernel
   tiles) a vectorized jnp reference with identical semantics.
 
@@ -331,21 +334,39 @@ def split_query_chunks(
     )
 
 
+def first_party_decode(backend: str, q, kv_pages, cu_q_lens, *, kv_scales=None,
+                       window: int | None = None, num_kv_heads: int | None = None):
+    """``(what the log calls it, the kernel's entry, its ``block_pages``)`` of
+    the first-party kernel a call takes, or ``None``: a DECODE-shaped call
+    (``cu_q_lens is None``) over real-valued pages with no window, from its
+    geometry alone. GROUP 1 (as many query heads as the model has KV heads)
+    has ops/mha_attention.py where its ``fits`` holds; a GROUP of 2 or more
+    over every KV head the page keeps has ops/grouped_attention.py where its
+    ``fits`` holds."""
+    from dynamo_tpu.ops import grouped_attention, mha_attention
+
+    if cu_q_lens is not None or kv_scales is not None or window is not None:
+        return None
+    page_heads = kv_pages.shape[2] // 2
+    if q.shape[1] == (num_kv_heads or page_heads):
+        if mha_attention.fits(backend, q, kv_pages):
+            return "group 1", mha_attention.mha_decode_pallas, mha_attention.block_pages
+    elif num_kv_heads in (None, page_heads) and grouped_attention.fits(backend, q, kv_pages):
+        return (f"a group of {q.shape[1] // page_heads}", grouped_attention.grouped_decode_pallas,
+                grouped_attention.block_pages)
+    return None
+
+
 def decode_impl(backend: str, q, kv_pages, cu_q_lens, *, kv_scales=None,
                 window: int | None = None, num_kv_heads: int | None = None) -> str:
     """The label of a call's counter, from what the call can observe and
-    nothing else (no flag, no model's name): ``"pallas"``, the first-party
-    kernel of ops/mha_attention.py, for a DECODE-shaped call of GROUP 1 (as
-    many query heads as the model has KV heads) on a TPU over real-valued
-    bfloat16 pages of 128-wide heads in whole tiles, no window
-    (``mha_attention.fits``); else ``"library"`` where the library kernel's
-    shapes hold on a TPU, else ``"reference"``."""
-    from dynamo_tpu.ops import mha_attention
-
+    nothing else (no flag, no model's name): ``"pallas"`` where a
+    first-party decode kernel takes it (:func:`first_party_decode`); else
+    ``"library"`` where the library kernel's shapes hold on a TPU, else
+    ``"reference"``."""
     d, page_size = q.shape[-1], kv_pages.shape[1]
-    if (cu_q_lens is None and kv_scales is None and window is None
-            and q.shape[1] == (num_kv_heads or kv_pages.shape[2] // 2)
-            and mha_attention.fits(backend, q, kv_pages)):
+    if first_party_decode(backend, q, kv_pages, cu_q_lens, kv_scales=kv_scales, window=window,
+                          num_kv_heads=num_kv_heads):
         return "pallas"
     return "library" if backend == "tpu" and d % 128 == 0 and page_size % 8 == 0 else "reference"
 
@@ -402,8 +423,8 @@ def ragged_paged_attention(
         f"combined_kv_heads={kv_pages.shape[2]}, kv={kv_pages.dtype}"
     )
     shape = "decode" if cu_q_lens is None else "ragged"
-    impl = decode_impl(backend, q, kv_pages, cu_q_lens, kv_scales=kv_scales, window=window,
-                       num_kv_heads=num_kv_heads)
+    choice = dict(kv_scales=kv_scales, window=window, num_kv_heads=num_kv_heads)
+    impl = decode_impl(backend, q, kv_pages, cu_q_lens, **choice)
     use_kernel = impl == "library"
     if cu_q_lens is not None and query_chunk and q.shape[0] > query_chunk:
         kv_lens, page_indices, cu_q_lens, num_seqs = split_query_chunks(
@@ -413,15 +434,13 @@ def ragged_paged_attention(
         shape, geometry = f"window-{shape}", f"{geometry}, window={window}"
     _count_traced(shape, impl)
     if impl == "pallas":
-        from dynamo_tpu.ops import mha_attention
-
+        said, kernel, block_pages = first_party_decode(backend, q, kv_pages, cu_q_lens, **choice)
         _announce(
             logging.INFO,
-            f"ragged attention: first-party Pallas TPU kernel, group 1 ({geometry}); decode "
-            f"shape, {mha_attention.block_pages(kv_pages, page_indices.shape[1])} pages a KV block",
+            f"ragged attention: first-party Pallas TPU kernel, {said} ({geometry}); decode "
+            f"shape, {block_pages(kv_pages, page_indices.shape[1])} pages a KV block",
         )
-        return mha_attention.mha_decode_pallas(
-            q, kv_pages, kv_lens, page_indices, num_seqs, sm_scale=sm_scale)
+        return kernel(q, kv_pages, kv_lens, page_indices, num_seqs, sm_scale=sm_scale)
     heads = q.shape[1]
     spare = kv_pages.shape[2] // 2 - (num_kv_heads or kv_pages.shape[2] // 2)
     if spare:   # a zero query head (a group of them) a spare KV head of the page

@@ -585,7 +585,9 @@ def test_on_a_tpu_the_decode_shape_gets_one_sequence_a_query_block(monkeypatch, 
     the benchmark's readers look for, and the choice is said once."""
     monkeypatch.setattr(ra.jax, "default_backend", lambda: "tpu")
     ra._announce.cache_clear()
-    q, kv, lens, tables = _case(32, 28, 4)
+    # (4 heads on 4 of a page's 8 combined rows: a group of one whose token is
+    # no octet of heads, which neither first-party kernel takes)
+    q, kv, lens, tables = _case(32, 4, 4)
     num_seqs = jnp.asarray([32], jnp.int32)
 
     before = ra.traced_calls()
@@ -765,8 +767,13 @@ def test_mosaic_compiles_the_group_one_decode_kernel_for_a_v5e(
         lambda *a: ma.mha_decode_pallas(*a, sm_scale=SM_SCALE)
     ).lower(q, pages, sds((lanes,), jnp.int32), sds((lanes, width), jnp.int32),
             sds((1,), jnp.int32)).compile().as_text()
-    assert "ragged_paged_attention_mha_decode_kernel" in text
+    assert MHA_KERNEL in text
     assert not [l for l in text.splitlines() if " copy(" in l and f"bf16[{n_pages}," in l]
+
+
+MHA_KERNEL = "ragged_paged_attention_mha_decode_kernel"
+GROUPED_KERNEL = "ragged_paged_attention_grouped_decode_kernel"
+LIBRARY_KERNEL = "ragged_paged_attention_kernel"
 
 
 def _rule_case(name: str):
@@ -776,35 +783,58 @@ def _rule_case(name: str):
     return {
         "group-1": (16, 16, 128, {}, None),
         "group-1-spare-heads": (30, 32, 128, {}, 30),
-        "group-7": (28, 4, 128, {}, None),
+        "group-7": (28, 4, 128, {}, None),               # qwen7b-decode-batch
+        "group-6": (12, 2, 128, {}, None),               # qwen1p5b-chat-steady
+        "group-8-paired": (32, 4, 128, {}, None),        # lfm2: 4 paired rows of 128
+        "group-6-of-8": (48, 8, 128, {}, None),          # laguna's full layers
+        "group-16": (32, 2, 128, {}, None),              # nemotron's attention blocks
         "group-2-of-16": (32, 16, 128, {}, None),
+        "group-7-of-1": (7, 1, 128, {}, None),           # a page of 8 KB: not measured
+        "group-2-spare-heads": (60, 32, 128, {}, 30),
         "window": (16, 16, 128, {"window": 64}, None),
+        "window-group-9": (72, 8, 128, {"window": 512}, None),
         "kv_scales": (16, 16, 128, {"kv_scales": True}, None),
+        "kv_scales-group-7": (28, 4, 128, {"kv_scales": True}, None),
         "ragged": (16, 16, 128, {"cu_q_lens": cu}, None),
+        "ragged-group-7": (28, 4, 128, {"cu_q_lens": cu}, None),
         "ragged-spare-heads": (30, 32, 128, {"cu_q_lens": cu}, 30),
         "head-64": (16, 16, 64, {}, None),
+        "head-64-group-4": (16, 4, 64, {}, None),
     }[name]
 
 
 @pytest.mark.parametrize("backend,name,shape,impl,kernel", [
-    ("tpu", "group-1", "decode", "pallas", "ragged_paged_attention_mha_decode_kernel"),
-    ("tpu", "group-1-spare-heads", "decode", "pallas", "ragged_paged_attention_mha_decode_kernel"),
-    ("tpu", "group-7", "decode", "library", "ragged_paged_attention_kernel"),
-    ("tpu", "group-2-of-16", "decode", "library", "ragged_paged_attention_kernel"),
-    ("tpu", "window", "window-decode", "library", "ragged_paged_attention_kernel"),
-    ("tpu", "kv_scales", "decode", "library", "ragged_paged_attention_kernel"),
-    ("tpu", "ragged", "ragged", "library", "ragged_paged_attention_kernel"),
-    ("tpu", "ragged-spare-heads", "ragged", "library", "ragged_paged_attention_kernel"),
+    ("tpu", "group-1", "decode", "pallas", MHA_KERNEL),
+    ("tpu", "group-1-spare-heads", "decode", "pallas", MHA_KERNEL),
+    ("tpu", "group-7", "decode", "pallas", GROUPED_KERNEL),
+    ("tpu", "group-6", "decode", "pallas", GROUPED_KERNEL),
+    ("tpu", "group-8-paired", "decode", "pallas", GROUPED_KERNEL),
+    ("tpu", "group-6-of-8", "decode", "pallas", GROUPED_KERNEL),
+    ("tpu", "group-16", "decode", "pallas", GROUPED_KERNEL),
+    ("tpu", "group-2-of-16", "decode", "pallas", GROUPED_KERNEL),
+    ("tpu", "group-7-of-1", "decode", "library", LIBRARY_KERNEL),
+    ("tpu", "group-2-spare-heads", "decode", "library", LIBRARY_KERNEL),
+    ("tpu", "window", "window-decode", "library", LIBRARY_KERNEL),
+    ("tpu", "window-group-9", "window-decode", "library", LIBRARY_KERNEL),
+    ("tpu", "kv_scales", "decode", "library", LIBRARY_KERNEL),
+    ("tpu", "kv_scales-group-7", "decode", "library", LIBRARY_KERNEL),
+    ("tpu", "ragged", "ragged", "library", LIBRARY_KERNEL),
+    ("tpu", "ragged-group-7", "ragged", "library", LIBRARY_KERNEL),
+    ("tpu", "ragged-spare-heads", "ragged", "library", LIBRARY_KERNEL),
     ("tpu", "head-64", "decode", "reference", None),
+    ("tpu", "head-64-group-4", "decode", "reference", None),
     ("cpu", "group-1", "decode", "reference", None),
     ("cpu", "group-1-spare-heads", "decode", "reference", None),
+    ("cpu", "group-7", "decode", "reference", None),
 ])
-def test_a_group_one_decode_call_on_a_tpu_gets_the_first_party_kernel(
+def test_a_decode_call_on_a_tpu_gets_the_first_party_kernel_its_geometry_fits(
         monkeypatch, backend, name, shape, impl, kernel):
     """The rule of ops/ragged_attention.py, from what a call can observe:
-    decode shape, group 1, a TPU, 128-wide heads, bfloat16 pages, no window
-    and no int8 scales -> ``impl="pallas"``; every other call what it got
-    before PR 51, counted as before."""
+    decode shape, a TPU, 128-wide heads, bfloat16 pages, no window and no
+    int8 scales -> ``impl="pallas"``: group 1 the kernel of
+    ops/mha_attention.py, a group of 2 or more that of
+    ops/grouped_attention.py; every other call the library kernel or the
+    reference, counted as before."""
     monkeypatch.setattr(ra.jax, "default_backend", lambda: backend)
     heads, page_heads, d, kw, num_kv_heads = _rule_case(name)
     kw = dict(kw)
@@ -813,6 +843,7 @@ def test_a_group_one_decode_call_on_a_tpu_gets_the_first_party_kernel(
     if kw.pop("kv_scales", False):
         kv, kw["kv_scales"] = kv.astype(jnp.int8), jnp.ones(kv.shape[:3], jnp.float32)
     q = jnp.zeros((8, heads, d), jnp.bfloat16)
+    assert ra.decode_impl(backend, q, kv, cu, num_kv_heads=num_kv_heads, **kw) == impl
     before = ra.traced_calls()
     jaxpr = jax.make_jaxpr(lambda q, kv, lens, tables: ra.ragged_paged_attention(
         q, kv, lens, tables, cu, jnp.asarray([8], jnp.int32), sm_scale=1.0,
@@ -821,6 +852,107 @@ def test_a_group_one_decode_call_on_a_tpu_gets_the_first_party_kernel(
     assert _delta(before) == {(shape, impl): 1}
     assert [n for n, _ in _pallas_calls(jaxpr.jaxpr)] == ([kernel] if kernel else [])
     assert jaxpr.out_avals[0].shape == q.shape
+
+
+def test_the_log_says_which_first_party_kernel_a_call_took(monkeypatch, caplog):
+    monkeypatch.setattr(ra.jax, "default_backend", lambda: "tpu")
+    ra._announce.cache_clear()
+    with caplog.at_level("INFO", logger="dynamo_tpu.ops.ragged_attention"):
+        for heads, page_heads in ((28, 4), (16, 16)):
+            jax.eval_shape(lambda q, kv, lens, tables: ra.ragged_paged_attention(
+                q, kv, lens, tables, None, jnp.asarray([8], jnp.int32), sm_scale=1.0),
+                jnp.zeros((8, heads, HEAD_DIM), jnp.bfloat16),
+                jnp.zeros((9, PAGE_SIZE, 2 * page_heads, HEAD_DIM), jnp.bfloat16),
+                jnp.ones((8,), jnp.int32), jnp.zeros((8, 256), jnp.int32))
+    said = [r.message for r in caplog.records if "first-party" in r.message]
+    assert len(said) == 2
+    assert "a group of 7" in said[0] and "16 pages a KV block" in said[0]
+    assert "group 1" in said[1]
+
+
+@pytest.mark.parametrize("shape", ["block-decode", "block-ragged"])
+def test_a_block_call_keeps_the_library_kernel(monkeypatch, shape):
+    """``block_attention`` (SDAR's folded call of 128 heads) asks no
+    ``decode_impl``: the library kernel at the decode grid, as before PR 55."""
+    monkeypatch.setattr(ra.jax, "default_backend", lambda: "tpu")
+    before = ra.traced_calls()
+    jaxpr = jax.make_jaxpr(lambda q, kv, ends, tables: ra.block_attention(
+        q, kv, ends, tables, jnp.asarray([8], jnp.int32), block_length=4, sm_scale=1.0,
+        shape=shape))(
+        jnp.zeros((32, 32, HEAD_DIM), jnp.bfloat16),
+        jnp.zeros((9, PAGE_SIZE, 8, HEAD_DIM), jnp.bfloat16),
+        jnp.ones((8,), jnp.int32), jnp.zeros((8, 4), jnp.int32))
+    assert _delta(before) == {(shape, "library"): 1}
+    assert _pallas_calls(jaxpr.jaxpr) == [(LIBRARY_KERNEL, (1, 8))]
+
+
+# -- the first-party kernel of grouped decode calls (PR 55, ops/grouped_attention.py)
+
+
+def _grouped_case(heads: int, n_kv: int, lens, seed: int = 0):
+    """As :func:`_mha_case`: every slot past a lane's ``kv_len`` holds large
+    finite numbers, the pages shuffled."""
+    q, kv, kv_lens, tables = _mha_case(n_kv, n_kv, PAGE_SIZE, lens, seed=seed)
+    q = np.random.RandomState(seed + 1).randn(len(lens), heads, HEAD_DIM)
+    return jnp.asarray(q, jnp.bfloat16), kv, kv_lens, tables
+
+
+# a KV block of the kernel here: its least, four quarters of whole tiles of 128
+# word rows (4 pages of 32 tokens at 4 KV heads and at 8, 8 pages at 2)
+@pytest.mark.parametrize("lens,live", [
+    ((1, 200), 2),               # a lane of one token beside a longer one
+    ((257, 64, 500), 2),         # contexts that end mid-page; a dead lane after the live ones
+    ((300, 40, 129, 1, 512), 5), # a block's edge, a quarter's, blocks of a lane behind another's
+    ((90, 33), 0),               # no live lane at all
+], ids=["one", "dead-lane", "edges", "none-live"])
+@pytest.mark.parametrize("heads,n_kv", [(28, 4), (12, 2), (32, 4), (48, 8), (32, 2)],
+                         ids=["28of4", "12of2", "32of4", "48of8", "32of2"])
+def test_the_grouped_kernel_gives_the_references_attention(heads, n_kv, lens, live):
+    """``grouped_decode_pallas`` under Pallas' TPU interpreter against
+    ``ragged_paged_attention_ref`` and the float64 softmax, at the cells'
+    heads: a group that is no power of two (7, 6) is padded in VMEM."""
+    from dynamo_tpu.ops.grouped_attention import grouped_decode_pallas, quarter_pages
+
+    q, kv, kv_lens, tables = _grouped_case(heads, n_kv, lens, seed=len(lens) + heads)
+    num_seqs = jnp.asarray([live], jnp.int32)
+    got = jax.block_until_ready(grouped_decode_pallas(
+        q, kv, kv_lens, tables, num_seqs, sm_scale=SM_SCALE,
+        pages_per_block=4 * quarter_pages(PAGE_SIZE, n_kv), blocks_in_ring=2, interpret=True))
+    assert got.shape == q.shape and got.dtype == q.dtype
+    want = ra.ragged_paged_attention_ref(q, kv, kv_lens, tables, None, num_seqs,
+                                         sm_scale=SM_SCALE)
+    _close(got, np.asarray(want, np.float64))
+    _close(got[:live], _dense_softmax(q[:live], kv, kv_lens[:live], tables[:live]))
+    assert not np.asarray(got[live:], np.float32).any()
+
+
+@pytest.mark.parametrize("lanes,heads,n_kv,width,n_pages", [
+    (32, 28, 4, 256, 3073),      # qwen7b-decode-batch
+    (8, 12, 2, 256, 11265),      # qwen1p5b-chat-steady, narrowest
+    (32, 12, 2, 256, 11265),     # ... and widest
+    (128, 32, 4, 128, 16385),    # lfm2-24b-hybrid-decode (paired rows), its two decode widths
+    (32, 32, 4, 128, 16385),
+    (48, 48, 8, 338, 16385),     # laguna-s21-longctx-agents' full layers, its two
+    (16, 48, 8, 338, 16385),
+    (128, 32, 2, 128, 12289),    # nemotron3-nano-ep2-decode, its two
+    (32, 32, 2, 128, 12289),
+])
+def test_mosaic_compiles_the_grouped_decode_kernel_for_a_v5e(
+        one_chip, lanes, heads, n_kv, width, n_pages):
+    """The first-party kernel at the cells' shapes and the module's
+    constants; the page array is handed over by a bitcast, no copy."""
+    from dynamo_tpu.ops import grouped_attention as ga
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    q = sds((lanes, heads, HEAD_DIM), jnp.bfloat16)
+    pages = sds((n_pages, PAGE_SIZE, 2 * n_kv, HEAD_DIM), jnp.bfloat16)
+    assert ga.fits("tpu", q, pages)
+    text = jax.jit(
+        lambda *a: ga.grouped_decode_pallas(*a, sm_scale=SM_SCALE)
+    ).lower(q, pages, sds((lanes,), jnp.int32), sds((lanes, width), jnp.int32),
+            sds((1,), jnp.int32)).compile().as_text()
+    assert GROUPED_KERNEL in text
+    assert not [l for l in text.splitlines() if " copy(" in l and f"bf16[{n_pages}," in l]
 
 
 def test_spare_heads_of_a_page_get_zero_queries_where_the_library_kernel_reads_them():
@@ -847,3 +979,21 @@ def test_the_decode_bench_refuses_the_cpu(monkeypatch):
     assert attn_decode_bench.geometry("olmo-48")[:4] == (48, 30, 30, 128)
     assert [t for t, _ in attn_decode_bench.variants("olmo-48", True)][:3] == [
         "serving", "library q1_p16", "mha_p8_r3"]
+
+
+@pytest.mark.parametrize("shape,geometry,tags", [
+    ("7b", (32, 28, 4, 256), ["serving", "library q1_p16", "gqa_p4_r3", "gqa_p8_r3", "gqa_p32_r3"]),
+    ("lfm2-128", (128, 32, 4, 128), ["serving", "library q1_p16", "gqa_p4_r3", "gqa_p8_r3",
+                                     "gqa_p32_r3"]),
+    ("nemotron-128", (128, 32, 2, 128), ["serving", "library q1_p16", "gqa_p8_r3", "gqa_p16_r3"]),
+    ("laguna-full-48", (48, 48, 8, 338), ["serving", "library q1_p16", "gqa_p4_r3", "gqa_p16_r3",
+                                          "gqa_p32_r3"]),
+])
+def test_the_decode_bench_sweeps_the_grouped_kernel_beside_the_library(shape, geometry, tags):
+    """A grouped shape's rows: the serving entry, the library kernel at the
+    decode grid, the first-party kernel over ``--gqa-pages`` (the serving
+    entry's own pair, 1 MB x 3, left out: one program, one executable)."""
+    from tools import attn_decode_bench
+
+    assert attn_decode_bench.geometry(shape)[:4] == geometry
+    assert [t for t, _ in attn_decode_bench.variants(shape, True)] == tags

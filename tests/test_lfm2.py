@@ -470,11 +470,12 @@ def test_a_dense_model_of_64_wide_heads_keeps_what_the_pair_does_not_carry(optio
                                atol=0.05 if option == "kv_dtype" else TIGHT)
 
 
-@pytest.mark.parametrize("backend,impl", [("tpu", "library"), ("cpu", "reference")])
+@pytest.mark.parametrize("backend,impl", [("tpu", "pallas"), ("cpu", "reference")])
 def test_paired_heads_get_the_kernel_on_a_tpu_by_geometry(backend, impl, monkeypatch):
     """A 64-wide head alone would get the ``jnp`` reference ON a TPU; as a
-    pair it is 128 lanes wide and gets the library kernel (chosen at trace
-    time; the kernel itself is not run here)."""
+    pair it is 128 lanes wide and its decode call, 32 query heads on 4 paired
+    rows, gets the first-party kernel of grouped heads (the library kernel
+    until PR 55; chosen at trace time; the kernel itself is not run here)."""
     monkeypatch.setattr(ragged_attention.jax, "default_backend", lambda: backend)
     monkeypatch.setattr(ragged_attention, "_TRACED", ragged_attention._TRACED.copy())
     monkeypatch.setattr(ragged_attention, "_TRACED_IMPLS", dict(ragged_attention._TRACED_IMPLS))

@@ -31,12 +31,22 @@ arguments: no kernel to time, but the largest difference says whether the
 kernel's groups of 9 heads are the reference's. ``laguna-full-48`` is the
 same cell's full layers (48 heads on 8, the whole context).
 
+A GROUPED shape (more query heads than KV heads, no window: ``7b``,
+``1p5b-*``, ``lfm2-128``, ``nemotron-128``, ``laguna-full-48``) prints the
+serving entry (the first-party kernel of ``ops/grouped_attention.py`` where
+its ``fits`` holds), the library kernel at the decode grid, and the
+first-party kernel over ``--gqa-pages`` pages a KV block x blocks in its
+ring, each with its share of the HBM rate of the pages in use; a group of ONE
+(``ouro``, ``olmo-48``) the same with ``ops/mha_attention.py`` and
+``--mha-pages``.
+
 Refuses to run without a TPU: a time from the CPU says nothing here.
 
 Usage (through the chip tool, from the repo root):
     python -m tools.attn_decode_bench [--shapes 7b,1p5b-32,ouro,axk1-128]
                                       [--quick] [--page-size 32]
                                       [--latent-pages 8,16,32,64]
+                                      [--mha-pages 4,8,16] [--gqa-pages 4,8,16,32]
 ``--page-size`` re-cuts a dense shape's cache and tables into pages of
 another size (the worker's ``--block-size``), the same tokens in all.
 Writes ``chiprun_out/attn_decode_bench/table.json`` beside the table.
@@ -72,10 +82,19 @@ SHAPES = {
     # layers' (tables of EngineConfig.window_table_blocks columns)
     "laguna-full-48": (48, 48, 8, 338, 16385, ("uniform", 4096, 10752)),
     "laguna-window-48": (48, 72, 8, 82, 1025, ("uniform", 4096, 10752)),
+    # lfm2-24b-hybrid-decode: 32 query heads of 64 against 8 KV heads, cached
+    # in pairs (4 rows of 128 a token: paired_heads_attention); prompts
+    # 256-768 and outputs to 2,048 (traffic/hybrid-decode.json)
+    "lfm2-128": (128, 32, 4, 128, 16385, ("uniform", 256, 2816)),
+    # nemotron3-nano-ep2-decode's two attention blocks: 32 heads on 2;
+    # prompts 256-768 and outputs to 1,280 (traffic/ep-decode.json)
+    "nemotron-128": (128, 32, 2, 128, 12289, ("uniform", 256, 2048)),
 }
 WINDOWS = {"laguna-window-48": 512}   # shape -> its layers' sliding window
 PAGE_KV_HEADS = {"olmo-48": 32}       # shape -> KV heads its pages keep, the spare ones zero
 MHA_PAGES = (4, 8, 16)   # pages a KV block of the group-1 kernel; main() may set it
+GQA_PAGES = (4, 8, 16, 32)   # ... of the grouped kernel
+RINGS: tuple[int, ...] = ()  # blocks in a first-party kernel's ring; main() may set it
 # lanes, heads, r, dr, page-table width, pages in one layer's array,
 # contexts: axk1-ep16-decode at its two decode widths.
 LATENT_SHAPES = {
@@ -154,11 +173,7 @@ def variants(shape: str, quick: bool):
         ragged_paged_attention as library,
     )
 
-    from dynamo_tpu.ops.mha_attention import (
-        _KERNEL_BLOCKS_IN_RING,
-        block_pages,
-        mha_decode_pallas,
-    )
+    from dynamo_tpu.ops import grouped_attention, mha_attention
     from dynamo_tpu.ops.ragged_attention import (
         decode_shape_grid,
         ragged_paged_attention,
@@ -190,35 +205,45 @@ def variants(shape: str, quick: bool):
                 num_queries_per_block=qb, num_kv_pages_per_block=pages)[:, :n_q]
         return fn
 
-    def mha(pages, ring):
+    def first_party(entry, pages, ring):
         def fn(q, kv, lens, tables):
-            return mha_decode_pallas(
-                q, kv, lens, tables, jnp.asarray([lanes], jnp.int32), sm_scale=sm,
-                pages_per_block=pages, blocks_in_ring=ring)
+            return entry(q, kv, lens, tables, jnp.asarray([lanes], jnp.int32), sm_scale=sm,
+                         pages_per_block=pages, blocks_in_ring=ring)
         return fn
 
-    if n_q == n_kv and not window:
-        # A group of ONE: the serving entry is the first-party kernel at its
-        # constants (ops/mha_attention.py); beside it the library kernel at
-        # the decode grid (what served before PR 51: the largest difference
-        # is between the two kernels) and the first-party kernel over pages a
-        # KV block x blocks in its ring.
+    if not window:
+        # The serving entry is a first-party kernel at its constants where
+        # its ``fits`` holds (a group of ONE: ops/mha_attention.py; a group:
+        # ops/grouped_attention.py); beside it the library kernel at the
+        # decode grid (what served before: the largest difference is between
+        # the two kernels) and the first-party kernel over pages a KV block x
+        # blocks in its ring.
         out = [("serving", serving), ("library q{}_p{}".format(*serving_grid), lib(*serving_grid))]
         page = jax.ShapeDtypeStruct((1, PAGE_SIZE, 2 * (n_kv + spare), HEAD_DIM), jnp.bfloat16)
-        served = (block_pages(page, width), _KERNEL_BLOCKS_IN_RING)
-        for pages in MHA_PAGES:
-            for ring in (3,) if quick else (2, 3, 4):
+        if n_q == n_kv:
+            module, entry, tag, sweep = (mha_attention, mha_attention.mha_decode_pallas,
+                                         "mha", MHA_PAGES)
+            granule = mha_attention.granule_pages(PAGE_SIZE)
+        else:
+            module, entry, tag, sweep = (grouped_attention, grouped_attention.grouped_decode_pallas,
+                                         "gqa", GQA_PAGES)
+            granule = 4 * grouped_attention.quarter_pages(PAGE_SIZE, n_kv)
+        served = (module.block_pages(page, width), module._KERNEL_BLOCKS_IN_RING)
+        for pages in sweep:
+            for ring in RINGS or ((module._KERNEL_BLOCKS_IN_RING,) if quick else (2, 3, 4)):
                 # (the serving entry's pair left out: one program compiles to
                 # one executable under the first name)
-                if pages <= width and (pages, ring) != served:
-                    out.append((f"mha_p{pages}_r{ring}", mha(pages, ring)))
-        return out
+                if pages <= width and pages % granule == 0 and (pages, ring) != served:
+                    out.append((f"{tag}_p{pages}_r{ring}", first_party(entry, pages, ring)))
+        if quick or n_q == n_kv:
+            return out
+    else:
+        # The serving path IS one of the grids: the same program compiles to
+        # one executable under the first name, so the sweep leaves that one out.
+        out = [("serving q{}_p{}".format(*serving_grid), serving)]
+        out.append(("reference", reference))   # a table narrow enough for the jnp path's gather
 
-    # The serving path IS one of the grids: the same program compiles to
-    # one executable under the first name, so the sweep leaves that one out.
-    out = [("serving q{}_p{}".format(*serving_grid), serving)]
-    if window:   # a table narrow enough for the jnp path's gather
-        out.append(("reference", reference))
+    # the library kernel's grids
     qbs = (1, 8) if quick else (1, 2, 4, 8, 16, 32)
     # KV blocks of 128 ... 1024 tokens: 4 ... 32 pages of 32.
     tokens = (256, 512) if quick else (128, 256, 384, 512, 768, 1024)
@@ -376,7 +401,7 @@ def bench_shape(shape: str, seed: int, quick: bool, hbm_bytes_per_s: float):
 
 
 def main() -> int:
-    global PAGE_SIZE, LATENT_PAGES, MHA_PAGES
+    global PAGE_SIZE, LATENT_PAGES, MHA_PAGES, GQA_PAGES, RINGS
     ap = argparse.ArgumentParser()
     ap.add_argument("--shapes", default=",".join([*SHAPES, *LATENT_SHAPES]))
     ap.add_argument("--seed", type=int, default=2147483659)
@@ -388,9 +413,16 @@ def main() -> int:
                     help="pages a KV block of the latent kernel, swept")
     ap.add_argument("--mha-pages", default=",".join(map(str, MHA_PAGES)),
                     help="pages a KV block of the group-1 kernel, swept")
+    ap.add_argument("--gqa-pages", default=",".join(map(str, GQA_PAGES)),
+                    help="pages a KV block of the grouped kernel, swept")
+    ap.add_argument("--rings", default="",
+                    help="blocks in a first-party kernel's ring, swept (default: the "
+                         "module's with --quick, else 2,3,4)")
     args = ap.parse_args()
     PAGE_SIZE = args.page_size
     MHA_PAGES = tuple(int(n) for n in args.mha_pages.split(","))
+    GQA_PAGES = tuple(int(n) for n in args.gqa_pages.split(","))
+    RINGS = tuple(int(n) for n in args.rings.split(",") if n)
     LATENT_PAGES = tuple(int(n) for n in args.latent_pages.split(","))
 
     from dynamo_tpu.device import device_info, device_peaks, enable_compile_cache
